@@ -2,10 +2,9 @@
 
 The kernelgen tier (ops/kernelgen) compiles each ``fused_elementwise``
 sub-program into generated Pallas kernels; a sub-op with no
-``KERNEL_RULES`` entry makes the WHOLE group fall back loudly to the
-reference replay at run time (``kernelgen.fallbacks`` counter, warn-once,
-``PT_STRICT_KERNELS=1`` raises).  This pass reports the same gap
-statically, per fused op, with sub-op names — the static face of
+``KERNEL_RULES`` entry makes the launch raise ``KernelgenUnsupported`` at
+run time (there is no reroute to the replay).  This pass reports the same
+gap statically, per fused op, with sub-op names — the static face of
 ``kernelgen.unsupported_sub_ops``.
 
 It also flags the dual failure: a KERNEL_TIER op (softmax / layer_norm /
@@ -18,10 +17,9 @@ control-flow-pinned output).  Raw never-optimized programs (no
 fused_elementwise anywhere) are skipped: there is no evidence the
 rewriter ran at all.
 
-Severity is info: the replay fallback is bitwise-correct, just unfused —
-ci_smoke's strict-kernelgen zoo gate holds the bench programs to zero
-fallbacks so coverage regressions surface in CI rather than as perf
-regressions.
+Severity is info: the fuse pass only groups ops that have a rule, so a
+gap can only come from a hand-built or disk-loaded program — and only
+matters where the kernel tier is on.
 """
 from ..engine import register_pass
 
@@ -80,14 +78,13 @@ def run(ctx):
                 seen.add(sub_type)
                 diags.append(ctx.diag(
                     'D016', 'info',
-                    'fused sub-op "%s" has no KERNEL_RULES entry: this '
-                    'fused_elementwise group falls back from its '
-                    'generated Pallas kernel (PT_KERNELGEN=1) to the '
-                    'reference replay' % sub_type,
+                    'fused sub-op "%s" has no KERNEL_RULES entry: with '
+                    'the kernel tier on, launching this '
+                    'fused_elementwise group raises '
+                    'KernelgenUnsupported' % sub_type,
                     block=block, op=op, op_index=i,
                     fixit='add a KERNEL_RULES entry '
-                          '(ops/kernelgen/rules.py), or set '
-                          'PT_KERNELGEN=0 to silence the runtime '
-                          'warning',
+                          '(ops/kernelgen/rules.py), or run with '
+                          'PT_KERNELGEN=0',
                     pass_name='kernelgen_coverage'))
     return diags

@@ -12,13 +12,20 @@ version, backend platform + chip kind) — and stored in two tiers:
   L1  in-process map, LRU-bounded by ``PT_EXEC_CACHE_MAX`` (default 64).
       Evictions count into the ``pt_exec_cache_evictions`` metric; the
       seed executor grew this map without limit across programs.
-  L2  on-disk store under ``PT_CACHE_DIR`` (default ``~/.cache/paddle_tpu``)
-      holding executables serialized through JAX's AOT path
-      (``jit(fn).lower(...).compile()`` + ``serialize_executable``).  A
-      backend that cannot serialize executables falls back to caching the
-      lowered StableHLO text — inspectable, and the XLA-level persistent
-      cache (``jax_compilation_cache_dir``, wired below as the backstop)
-      still shortcuts the backend compile on the retrace.
+  L2  on-disk store under ``cache_dir()`` holding executables serialized
+      through JAX's AOT path (``jit(fn).lower(...).compile()`` +
+      ``serialize_executable``).  A backend that cannot serialize
+      executables falls back to caching the lowered StableHLO text —
+      inspectable, and JAX's own persistent cache (same directory) still
+      shortcuts the backend compile on the retrace.
+
+``cache_dir()`` is the ONE place the directory is decided:
+``$JAX_COMPILATION_CACHE_DIR`` when set (JAX reads that variable itself,
+so no code here touches ``jax_compilation_cache_dir`` then), else
+``<checkout>/.jax_cache``.  JAX's persistent cache, the L2 store
+(``v<FMT>/``) and the kernelgen autotune choices (``autotune/``) all live
+under it, so whoever runs the program can place — and keep — every
+compile artifact by setting one variable.
 
 Corrupt, truncated, or version-mismatched disk entries are MISSES, never
 errors: the entry is deleted and the caller recompiles.  Disable the disk
@@ -41,9 +48,10 @@ __all__ = ['launch_fingerprint', 'callable_fingerprint',
            'cache_dir', 'disk_enabled', 'ensure_xla_cache_backstop']
 
 # bump when the on-disk payload layout changes: old entries become misses
-CACHE_FORMAT = 1
+CACHE_FORMAT = 2
 
-_DEFAULT_DIR = os.path.join(os.path.expanduser('~'), '.cache', 'paddle_tpu')
+_PACKAGE_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_DEFAULT_DIR = os.path.join(os.path.dirname(_PACKAGE_DIR), '.jax_cache')
 
 
 def disk_enabled():
@@ -51,7 +59,7 @@ def disk_enabled():
 
 
 def cache_dir():
-    return os.environ.get('PT_CACHE_DIR', _DEFAULT_DIR)
+    return os.environ.get('JAX_COMPILATION_CACHE_DIR') or _DEFAULT_DIR
 
 
 # ------------------------------------------------------------ fingerprints
@@ -75,6 +83,28 @@ def program_fingerprint(program):
     return fp
 
 
+_SOURCE_DIGEST = []
+
+
+def source_digest():
+    """sha256 over every ``paddle_tpu/**/*.py`` (relative path + bytes).
+    The ProgramDesc names ops, not their implementations: without this an
+    edited op impl would be served the previous build's executable from a
+    cache that outlives the edit, and measure as "unchanged"."""
+    if not _SOURCE_DIGEST:
+        h = hashlib.sha256()
+        for root, dirs, files in os.walk(_PACKAGE_DIR):
+            dirs.sort()
+            for name in sorted(files):
+                if name.endswith('.py'):
+                    path = os.path.join(root, name)
+                    h.update(os.path.relpath(path, _PACKAGE_DIR).encode())
+                    with open(path, 'rb') as f:
+                        h.update(f.read())
+        _SOURCE_DIGEST.append(h.hexdigest())
+    return _SOURCE_DIGEST[0]
+
+
 def _environment_blob():
     """Everything outside the program that decides executable validity."""
     import jax
@@ -91,6 +121,7 @@ def _environment_blob():
         'backend': backend,
         'x64': bool(jax.config.jax_enable_x64),
         'amp_flow': os.environ.get('PT_AMP_FLOW', 'conv'),
+        'source': source_digest(),
     }
 
 
@@ -194,7 +225,8 @@ class DiskCache(object):
 
     Payloads are pickled dicts carrying either a serialized executable
     (``tier='exec'``: the (bytes, in_tree, out_tree) triple from
-    ``serialize_executable.serialize``) or the lowered StableHLO text
+    ``serialize_executable.serialize`` plus the ids of the devices it was
+    compiled for, in assignment order) or the lowered StableHLO text
     (``tier='stablehlo'``).  Every load failure — unpickleable, truncated,
     foreign format, deserialize error — deletes the entry and reports a
     miss."""
@@ -225,9 +257,9 @@ class DiskCache(object):
 
         try:
             # transient OSErrors (a racing writer's os.replace mid-flight
-            # on a shared PT_CACHE_DIR, NFS hiccups, injected cache_read
-            # faults) retry with backoff; a missing entry is an ordinary
-            # miss and never retries
+            # on a shared cache directory, NFS hiccups, injected
+            # cache_read faults) retry with backoff; a missing entry is an
+            # ordinary miss and never retries
             payload = retry_with_backoff(_read, retry_on=(OSError,),
                                          give_up_on=(FileNotFoundError,),
                                          name='cache_read')
@@ -241,10 +273,17 @@ class DiskCache(object):
                     payload.get('fingerprint') != fingerprint):
                 raise ValueError('format/fingerprint mismatch')
             if payload['tier'] == 'exec':
+                import jax
                 from jax.experimental import serialize_executable as se
                 serialized, in_tree, out_tree = payload['payload']
-                compiled = se.deserialize_and_load(serialized, in_tree,
-                                                   out_tree)
+                # load onto the devices it was compiled for: left to
+                # itself deserialize_and_load takes EVERY device of the
+                # backend, and a one-device executable then dies at its
+                # first call on any host with more than one
+                by_id = {d.id: d for d in jax.devices()}
+                compiled = se.deserialize_and_load(
+                    serialized, in_tree, out_tree,
+                    execution_devices=[by_id[i] for i in payload['devices']])
                 _obs.metrics.counter('compile_cache.bytes_read').inc(
                     os.path.getsize(path))
                 return compiled, 'exec'
@@ -264,7 +303,10 @@ class DiskCache(object):
         if compiled is not None:
             try:
                 from jax.experimental import serialize_executable as se
-                payload = {'tier': 'exec', 'payload': se.serialize(compiled)}
+                payload = {
+                    'tier': 'exec', 'payload': se.serialize(compiled),
+                    'devices': [d.id for d in compiled.runtime_executable()
+                                .local_devices()]}
             except Exception:  # noqa: BLE001 - backend can't serialize
                 payload = None
         if payload is None and lowered is not None:
@@ -325,24 +367,18 @@ _XLA_WIRED = [False]
 
 
 def ensure_xla_cache_backstop():
-    """Point jax's persistent compilation cache at ``$PT_CACHE_DIR/xla``.
+    """Make sure JAX's persistent compilation cache is on, in
+    ``cache_dir()``.
 
     This is the third tier: when only StableHLO could be cached (or a jit
     fallback retraces), the retrace still happens in Python but XLA's
-    backend compile — the dominant cost — is served from disk.  A user
-    who already configured ``jax_compilation_cache_dir`` wins; we never
-    override."""
+    backend compile — the dominant cost — is served from disk.  With
+    ``$JAX_COMPILATION_CACHE_DIR`` set JAX has already configured itself
+    from it and nothing is touched here."""
     if _XLA_WIRED[0] or not disk_enabled():
         return
     _XLA_WIRED[0] = True
+    if os.environ.get('JAX_COMPILATION_CACHE_DIR'):
+        return
     import jax
-    try:
-        if jax.config.jax_compilation_cache_dir:
-            return
-        jax.config.update('jax_compilation_cache_dir',
-                          os.path.join(cache_dir(), 'xla'))
-        jax.config.update('jax_persistent_cache_min_compile_time_secs',
-                          float(os.environ.get('PT_CACHE_XLA_MIN_S', '0')))
-        jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
-    except Exception:  # noqa: BLE001 - older jaxlib without these knobs
-        pass
+    jax.config.update('jax_compilation_cache_dir', cache_dir())

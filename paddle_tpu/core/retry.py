@@ -1,7 +1,7 @@
 """Shared transient-failure retry: bounded exponential backoff.
 
 Disk I/O on shared infrastructure fails transiently — two processes
-racing on one ``PT_CACHE_DIR``, NFS hiccups, a checkpoint volume briefly
+racing on one cache directory, NFS hiccups, a checkpoint volume briefly
 remounting.  Treating every such error as fatal turned BENCH-grade soaks
 into dead rounds; swallowing them silently hides real corruption.  This
 module gives every disk-touching subsystem (core/compile_cache.py, io.py,

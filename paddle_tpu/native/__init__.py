@@ -8,26 +8,55 @@ parsing, shuffle buffering and batch assembly run in C++ threads off the
 GIL, overlapping the TPU step (see src/datafeed.cc).
 
 The shared library is compiled on first use with g++ (no pip deps; bound via
-ctypes).  If no toolchain is available the pure-NumPy fallback in
-`fallback.py` provides identical semantics.
+ctypes) and is never committed: it is rebuilt whenever the sha256 of
+`src/datafeed.cc` differs from the one stored beside it, so a binary that
+travelled with a copy of the tree cannot outlive its source.  With no
+compiler on the machine the pure-NumPy path in `fallback.py` provides
+identical semantics; with a compiler, a failed build raises.
 """
 import ctypes
+import hashlib
 import os
+import shutil
 import subprocess
 import threading
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, 'src', 'datafeed.cc')
 _LIB_PATH = os.path.join(_HERE, 'libptdatafeed.so')
+_HASH_PATH = _LIB_PATH + '.sha256'
 _lock = threading.Lock()
 _lib = None
-_build_err = None
+_no_compiler = False
 
 
-def _build():
+def _src_hash():
+    with open(_SRC, 'rb') as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _built_hash():
+    try:
+        with open(_HASH_PATH) as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
+def _build(src_hash):
+    # built beside the target and renamed into place: a process racing
+    # this one never loads a half-written library
+    tmp = '%s.tmp.%d' % (_LIB_PATH, os.getpid())
     cmd = ['g++', '-O2', '-shared', '-fPIC', '-std=c++14', '-pthread',
-           _SRC, '-o', _LIB_PATH]
-    subprocess.run(cmd, check=True, capture_output=True)
+           _SRC, '-o', tmp]
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True)
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError('building %s failed (rc=%d):\n%s'
+                           % (_LIB_PATH, e.returncode, e.stderr)) from e
+    os.replace(tmp, _LIB_PATH)
+    with open(_HASH_PATH, 'w') as f:
+        f.write(src_hash + '\n')
 
 
 def _bind(lib):
@@ -62,18 +91,19 @@ def _bind(lib):
 
 
 def get_lib():
-    """Load (building if needed) the native library, or None on failure."""
-    global _lib, _build_err
+    """Load (building if needed) the native library.  None only when the
+    machine has no g++; a failed build or load raises."""
+    global _lib, _no_compiler
     with _lock:
-        if _lib is not None or _build_err is not None:
+        if _lib is not None or _no_compiler:
             return _lib
-        try:
-            if (not os.path.exists(_LIB_PATH) or
-                    os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)):
-                _build()
-            _lib = _bind(ctypes.CDLL(_LIB_PATH))
-        except Exception as e:  # no toolchain / sandboxed build failure
-            _build_err = e
+        src_hash = _src_hash()
+        if not os.path.exists(_LIB_PATH) or _built_hash() != src_hash:
+            if shutil.which('g++') is None:
+                _no_compiler = True
+                return None
+            _build(src_hash)
+        _lib = _bind(ctypes.CDLL(_LIB_PATH))
         return _lib
 
 

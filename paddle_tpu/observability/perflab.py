@@ -3,13 +3,12 @@ the mandatory provenance block, the append-only ledger, and the
 baseline comparison math.
 
 The lab exists because perf numbers without provenance are unreliable
-evidence: 2 of the first 5 bench rounds (BENCH_r02, r05) silently
-recorded CPU-fallback numbers after a PJRT-init hang, and nothing in
-the JSON made them distinguishable from real TPU rounds.  Every record
-written through this module carries the backend it ACTUALLY ran on,
-the device kind, jax/jaxlib versions, the git sha, and the fallback
-reason (or null) — and ``compare_records`` refuses to diff a
-cpu-fallback candidate against a TPU baseline instead of passing it.
+evidence.  Every record written through this module carries the
+platform it ACTUALLY ran on, the device kind, jax/jaxlib versions and
+the git sha — and ``compare_records`` refuses to diff records from
+different platforms instead of passing them.  Nothing falls back: a
+tool that wants the chip and finds none fails before it writes a record
+(tools/_harness.require_device).
 
 Metric classes (declared per scenario in ``export.SCHEMA`` under the
 ``perflab.<scenario>`` sections — see that table for the spec
@@ -50,7 +49,7 @@ BASELINE_SCHEMA = 'perflab-baseline/1'
 DEFAULT_TIMING_TOLERANCE = 0.5
 
 PROVENANCE_KEYS = ('backend', 'device_kind', 'platform', 'jax', 'jaxlib',
-                   'git_sha', 'python', 'fallback')
+                   'git_sha', 'python')
 
 
 def scenario_names():
@@ -87,32 +86,25 @@ def git_sha():
     return 'unknown'
 
 
-def provenance(fallback=None):
+def provenance():
     """The mandatory provenance block: the backend the calling process
-    ACTUALLY initialized (not what a probe subprocess saw), jax/jaxlib
-    versions, git sha, and the fallback reason (or None when the
-    backend is the one that was asked for)."""
+    ACTUALLY initialized, jax/jaxlib versions and the git sha."""
     import jax
+    import jaxlib
     dev0 = jax.devices()[0]
-    try:
-        import jaxlib
-        jaxlib_ver = getattr(jaxlib, '__version__', 'unknown')
-    except Exception:
-        jaxlib_ver = 'unknown'
     return {
-        'backend': 'cpu-fallback' if fallback else dev0.platform,
+        'backend': dev0.platform,
         'platform': dev0.platform,
         'device_kind': str(dev0.device_kind),
         'jax': jax.__version__,
-        'jaxlib': jaxlib_ver,
+        'jaxlib': jaxlib.__version__,
         'git_sha': git_sha(),
         'python': '%d.%d.%d' % sys.version_info[:3],
-        'fallback': fallback,
     }
 
 
 def build_record(scenario, metrics, spread=None, config=None,
-                 prov=None, fallback=None, ts=None):
+                 prov=None, ts=None):
     """Assemble + validate one ledger record.  ``spread`` maps timing
     metrics to their raw best-of-K samples; ``config`` is the geometry
     the scenario ran at (compared records must match it exactly)."""
@@ -120,7 +112,7 @@ def build_record(scenario, metrics, spread=None, config=None,
         'schema': RECORD_SCHEMA,
         'scenario': scenario,
         'ts': round(time.time() if ts is None else ts, 3),
-        'provenance': prov if prov is not None else provenance(fallback),
+        'provenance': prov if prov is not None else provenance(),
         'config': dict(config or {}),
         'metrics': dict(metrics),
         'spread': {k: list(v) for k, v in (spread or {}).items()},
@@ -173,7 +165,7 @@ def validate_record(rec):
     for k in PROVENANCE_KEYS:
         if k not in prov:
             _fail(scenario, 'provenance missing %r' % k)
-        if k != 'fallback' and prov[k] in (None, ''):
+        if prov[k] in (None, ''):
             _fail(scenario, 'provenance[%r] is null' % k)
     specs = metric_specs(scenario)
     metrics = rec.get('metrics')
@@ -250,7 +242,7 @@ def latest_per_scenario(records):
 
 
 def maybe_ledger(scenario, metrics, spread=None, config=None,
-                 fallback=None, ledger=None):
+                 ledger=None):
     """The shared scenario-record writer for the bench/soak tools: if a
     ledger path is given (or PT_PERF_LEDGER is set), build a provenanced
     record and append it.  Never raises — a broken ledger must not kill
@@ -260,7 +252,7 @@ def maybe_ledger(scenario, metrics, spread=None, config=None,
         return None
     try:
         rec = build_record(scenario, metrics, spread=spread,
-                           config=config, fallback=fallback)
+                           config=config)
         return append_record(path, rec)
     except Exception as e:  # noqa: BLE001 - telemetry is best-effort here
         print('perflab: ledger append failed for %r: %s' % (scenario, e),
@@ -285,9 +277,8 @@ def compare_records(base, cand, thresholds=None,
     Returns {'scenario', 'status': 'ok'|'regression'|'refused',
     'regressions': [...], 'improvements': [...], 'skipped': [...],
     'reason': ...}.  Refusals are structural: a comparison that would
-    be meaningless (cpu-fallback vs TPU, different platform, different
-    geometry) is REFUSED with a reason, never silently passed — the
-    BENCH_r02/r05 failure mode is unrepresentable."""
+    be meaningless (different platform, different geometry) is REFUSED
+    with a reason, never silently passed."""
     scenario = cand.get('scenario') or base.get('scenario')
     out = {'scenario': scenario, 'status': 'ok', 'reason': None,
            'regressions': [], 'improvements': [], 'skipped': []}
@@ -308,11 +299,6 @@ def compare_records(base, cand, thresholds=None,
         return refuse('baseline is a failure record: %s'
                       % base.get('error'))
     bp, cp = base.get('provenance') or {}, cand.get('provenance') or {}
-    if cp.get('fallback') and bp.get('platform') == 'tpu':
-        return refuse(
-            'cpu-fallback candidate vs TPU baseline: candidate fell back '
-            '(%s) — re-run on TPU or bless a CPU baseline explicitly'
-            % cp.get('fallback'))
     if bp.get('platform') != cp.get('platform'):
         return refuse('backend mismatch: baseline platform %r vs '
                       'candidate %r — timings and counters are not '

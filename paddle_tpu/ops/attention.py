@@ -7,8 +7,10 @@ max/sum, O(T) memory instead of the T×T score matrix.  Padding is handled
 with a per-row valid-K-length vector (pad is always a suffix in the padded
 batch layout), causal masking with block-level position comparison.
 
-Falls back to the composed jnp implementation when pallas is unavailable
-(CPU test backend runs the kernel in interpret mode).
+Shapes the kernels cannot tile, and short contexts where the composed
+einsum path measured faster, take the composed jnp implementation by a
+static shape rule; an eligible shape runs the kernel or raises.  On the
+CPU backend (tests) the kernels run in Pallas interpret mode.
 """
 import functools
 
@@ -17,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register
+from . import _pallas
 
 _NEG_INF = -1e30
 _LSE_LANES = 8   # trailing broadcast dim that makes (1, bq) rows tileable
@@ -282,7 +285,7 @@ _FWD_PALLAS_MIN_T = 512
 
 
 def flash_attention(q, k, v, causal=False, scale=None, k_len=None,
-                    block_q=128, block_k=128, interpret=None):
+                    block_q=128, block_k=128, mesh=None):
     """q: [B, H, T, D]; k/v: [B, Hkv, T, D] (Hkv may divide H — GQA/MQA,
     served without repeating K/V); k_len: optional int32 [B] valid lengths.
 
@@ -301,34 +304,28 @@ def flash_attention(q, k, v, causal=False, scale=None, k_len=None,
         k_len = jnp.full((q.shape[0],), Tk, jnp.int32)
     k_len = k_len.astype(jnp.int32)
     bq, bk = min(block_q, Tq), min(block_k, Tk)
-    if Tq % bq or Tk % bk or D % 8 or Tk < _FWD_PALLAS_MIN_T:
-        # shapes the kernel can't tile, or short-context sizes where the
-        # composed path measures faster — composed (jax AD backward)
+    if Tq % bq or Tk % bk or D % 8 or Tk < _FWD_PALLAS_MIN_T \
+            or not _pallas.single_device(mesh):
+        # shapes the kernel can't tile, short-context sizes where the
+        # composed path measures faster, or a launch over several
+        # devices — composed (jax AD backward)
         return _ref_attention(q, k, v, causal, scale, k_len)
     pallas_bwd = B * H * Tq * Tk * 2 > _BWD_PALLAS_SCORE_BYTES
 
     @jax.custom_vjp
     def _attn(q, k, v, kl):
-        out, _ = _flash_forward(q, k, v, kl, causal, scale, bq, bk,
-                                interpret)
+        out, _ = _flash_forward(q, k, v, kl, causal, scale, bq, bk)
         return out
 
     def _fwd(q, k, v, kl):
-        out, lse = _flash_forward(q, k, v, kl, causal, scale, bq, bk,
-                                  interpret)
+        out, lse = _flash_forward(q, k, v, kl, causal, scale, bq, bk)
         return out, (q, k, v, kl, out, lse)
 
     def _bwd(res, g):
         q, k, v, kl, out, lse = res
         if pallas_bwd:
-            try:
-                return _flash_backward(q, k, v, kl, out, lse, g, causal,
-                                       scale, bq, bk, interpret) + (None,)
-            except Exception as e:  # pragma: no cover - backend-specific
-                from ._fallback import kernel_fallback
-                kernel_fallback(
-                    'flash_attention_bwd', e,
-                    detail='composed gradient materializes the T^2 scores')
+            return _flash_backward(q, k, v, kl, out, lse, g, causal,
+                                   scale, bq, bk) + (None,)
         _, pullback = jax.vjp(
             lambda q, k, v: _ref_attention(q, k, v, causal, scale, kl),
             q, k, v)
@@ -336,13 +333,7 @@ def flash_attention(q, k, v, causal=False, scale=None, k_len=None,
         return dq, dk, dv, None
 
     _attn.defvjp(_fwd, _bwd)
-    try:
-        return _attn(q, k, v, k_len)
-    except Exception as e:  # pragma: no cover - depends on backend
-        from ._fallback import kernel_fallback
-        kernel_fallback('flash_attention', e,
-                        detail='composed implementation, O(T^2) memory')
-        return _ref_attention(q, k, v, causal, scale, k_len)
+    return _attn(q, k, v, k_len)
 
 
 def _kv_row_map(H, Hkv, g):
@@ -353,15 +344,12 @@ def _kv_row_map(H, Hkv, g):
     return kv_row
 
 
-def _flash_forward(q, k, v, k_len, causal, scale, block_q, block_k,
-                   interpret=None):
+def _flash_forward(q, k, v, k_len, causal, scale, block_q, block_k):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     B, H, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
     g = H // Hkv
-    if interpret is None:
-        interpret = jax.default_backend() != 'tpu'
     qr = q.reshape(B * H, Tq, D)
     kr = k.reshape(B * Hkv, Tk, D)
     vr = v.reshape(B * Hkv, Tk, D)
@@ -393,20 +381,18 @@ def _flash_forward(q, k, v, k_len, causal, scale, block_q, block_k,
         out_shape=[jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
                    jax.ShapeDtypeStruct((B * H, Tq, _LSE_LANES),
                                         jnp.float32)],
-        interpret=interpret,
+        interpret=_pallas.interpret(),
     )(klr, qr, kr, vr)
     return out.reshape(B, H, Tq, D), lse
 
 
 def _flash_backward(q, k, v, k_len, out, lse, g_out, causal, scale,
-                    block_q, block_k, interpret=None):
+                    block_q, block_k):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     B, H, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
     g = H // Hkv
-    if interpret is None:
-        interpret = jax.default_backend() != 'tpu'
     qr = q.reshape(B * H, Tq, D)
     kr = k.reshape(B * Hkv, Tk, D)
     vr = v.reshape(B * Hkv, Tk, D)
@@ -442,7 +428,7 @@ def _flash_backward(q, k, v, k_len, out, lse, g_out, causal, scale,
         dq_kernel,
         grid_spec=dq_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-        interpret=interpret,
+        interpret=_pallas.interpret(),
     )(jnp.repeat(k_len, H), qr, kr, vr, dor, lse, delta)
 
     # dK/dV: grid over kv rows × k blocks with the GQA group innermost.
@@ -505,7 +491,7 @@ def _flash_backward(q, k, v, k_len, out, lse, g_out, causal, scale,
         grid_spec=dkv_spec,
         out_shape=[jax.ShapeDtypeStruct((B * Hkv, Tk, D), jnp.float32),
                    jax.ShapeDtypeStruct((B * Hkv, Tk, D), jnp.float32)],
-        interpret=interpret,
+        interpret=_pallas.interpret(),
     )(jnp.repeat(k_len, Hkv), qr, kr, vr, dor, lse, delta)
     return (dq.reshape(B, H, Tq, D),
             dk.reshape(B, Hkv, Tk, D).astype(k.dtype),
@@ -522,7 +508,8 @@ def flash_attention_op(ctx, ins, attrs):
         k_len = k_len.reshape(-1)
     return {'Out': flash_attention(
         q, k, v, causal=attrs.get('causal', False),
-        scale=attrs.get('scale', None), k_len=k_len)}
+        scale=attrs.get('scale', None), k_len=k_len,
+        mesh=getattr(ctx, 'mesh', None))}
 
 
 @register('ring_attention')
@@ -544,7 +531,8 @@ def ring_attention_op(ctx, ins, attrs):
         from ..parallel.ring_attention import ring_attention
         return {'Out': ring_attention(q, k, v, mesh, axis_name=axis,
                                       causal=causal, scale=scale)}
-    return {'Out': flash_attention(q, k, v, causal=causal, scale=scale)}
+    return {'Out': flash_attention(q, k, v, causal=causal, scale=scale,
+                                   mesh=mesh)}
 
 
 # --------------------------------------------------- KV-cache read path

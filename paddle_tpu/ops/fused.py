@@ -54,17 +54,16 @@ def _run_sub_op(ctx, sub, env, amp):
 
 @register('fused_elementwise')
 def fused_elementwise(ctx, ins, attrs):
+    from . import _pallas
     from . import kernelgen as _kg
     fx = getattr(ctx, 'forensic', None)
-    if _kg.enabled() and fx is None:
+    if _kg.enabled() and fx is None and \
+            _pallas.single_device(getattr(ctx, 'mesh', None)):
         # a forensic lowering never hands the group to kernelgen: the
         # whole point is probing INSIDE the fused sub-program, which a
         # single generated kernel hides.  Production launches keep the
         # kernel tier — only the replay runner pays the granularity tax.
-        try:
-            return _kg.run_fused(ctx, ins, attrs)
-        except Exception as e:        # noqa: BLE001 — loud by contract
-            _kg.note_fallback(e)      # raises under PT_STRICT_KERNELS
+        return _kg.run_fused(ctx, ins, attrs)
     xs = ins.get('X', [])
     xs = xs if isinstance(xs, (list, tuple)) else [xs]
     env = dict(zip(attrs['arg_names'], xs))

@@ -23,6 +23,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import _pallas
+
 _BLOCK = 256
 # Measured gate (TPU v5 lite, end-to-end A/B): at 8192 rows the kernel
 # is 1.7x in isolation and ~+0.7% end-to-end on the transformer bench;
@@ -51,19 +53,7 @@ def _gather_kernel(ids_ref, tbl_ref, out_ref, sem, *, block):
     jax.lax.fori_loop(0, block, wait, 0)
 
 
-def _any_memory_space(pltpu):
-    """The HBM/'leave it where it is' memory space moved between jax
-    releases: ``pltpu.ANY`` (<=0.4.x, where MemorySpace doesn't exist)
-    vs ``pltpu.MemorySpace.ANY`` (newer).  BENCH_r04 lost the kernel to
-    exactly this kind of API drift surfacing as a runtime TypeError and
-    silently rerouting to jnp.take — resolve it explicitly."""
-    any_space = getattr(pltpu, 'ANY', None)
-    if any_space is not None:
-        return any_space
-    return pltpu.MemorySpace.ANY
-
-
-def _pallas_gather(tbl, ids, interpret):
+def _pallas_gather(tbl, ids):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     N = ids.shape[0]
@@ -71,7 +61,7 @@ def _pallas_gather(tbl, ids, interpret):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(N // _BLOCK,),
-        in_specs=[pl.BlockSpec(memory_space=_any_memory_space(pltpu))],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((_BLOCK, 1, D), lambda i, ids: (i, 0, 0)),
         scratch_shapes=[pltpu.SemaphoreType.DMA],
     )
@@ -79,22 +69,21 @@ def _pallas_gather(tbl, ids, interpret):
         functools.partial(_gather_kernel, block=_BLOCK),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, 1, D), tbl.dtype),
-        interpret=interpret,
+        interpret=_pallas.interpret(),
     )(ids, tbl.reshape(V, 1, D))
     return out.reshape(N, D)
 
 
 def _eligible(w, idx_flat):
-    # PT_PALLAS_GATHER=0 is the kill-switch: a Mosaic LOWERING failure
-    # surfaces when the whole step compiles — after tracing, where the
-    # try/except in embedding_gather can no longer reroute — so a
-    # platform where this kernel won't compile needs the env gate, not
-    # the runtime fallback.
+    # float32 tables only: a bf16 [V, 1, D] table packs two rows into one
+    # sublane, and Mosaic refuses the one-row slice ("Slice shape along
+    # dimension 1 must be aligned to tiling (2), but is 1", PERF.md PR 21).
+    # PT_PALLAS_GATHER=0 keeps XLA's gather for A/B runs.
     return (os.environ.get('PT_PALLAS_GATHER', '1') != '0' and
             idx_flat.shape[0] >= _MIN_ROWS and
             idx_flat.shape[0] % _BLOCK == 0 and
             w.shape[1] % 128 == 0 and
-            w.dtype in (jnp.float32, jnp.bfloat16))
+            w.dtype == jnp.float32)
 
 
 @functools.lru_cache(maxsize=None)
@@ -108,8 +97,7 @@ def _make_kernel_gather(V, D, dtype_name):
 
     @jax.custom_vjp
     def kernel_gather(w, idx_flat):
-        interpret = jax.default_backend() != 'tpu'
-        return _pallas_gather(w, idx_flat, interpret)
+        return _pallas_gather(w, idx_flat)
 
     def fwd(w, idx_flat):
         return kernel_gather(w, idx_flat), (idx_flat,)
@@ -129,11 +117,9 @@ def _kernel_gather(w, idx_flat):
 
 
 def embedding_gather(w, idx):
-    """rows of `w` at `idx` (any idx shape), via the DMA kernel when the
-    shapes qualify; falls back to jnp.take otherwise (trace-time
-    failures only — see _eligible for the compile-time kill-switch).
-    Fallbacks are LOUD: counted as kernel.fallbacks, warned once, and
-    fatal under PT_STRICT_KERNELS=1 (ops/_fallback.py)."""
+    """rows of `w` at `idx` (any idx shape): the DMA kernel when the
+    shapes qualify (`_eligible`), jnp.take otherwise.  An eligible
+    gather runs the kernel or raises — there is no reroute."""
     idx_flat = idx.reshape(-1).astype(jnp.int32)
     if _eligible(w, idx_flat):
         # match jnp.take's semantics exactly: negative ids wrap (numpy
@@ -144,11 +130,7 @@ def embedding_gather(w, idx):
         wrapped = jnp.where(idx_flat < 0, idx_flat + V, idx_flat)
         oob = (wrapped < 0) | (wrapped >= V)
         safe = jnp.clip(wrapped, 0, V - 1)
-        try:
-            out = _kernel_gather(w, safe)
-            out = jnp.where(oob[:, None], jnp.nan, out)
-            return out.reshape(tuple(idx.shape) + (w.shape[1],))
-        except Exception as e:  # pragma: no cover - backend-specific
-            from ._fallback import kernel_fallback
-            kernel_fallback('embedding_gather', e, detail='using jnp.take')
+        out = _kernel_gather(w, safe)
+        out = jnp.where(oob[:, None], jnp.nan, out)
+        return out.reshape(tuple(idx.shape) + (w.shape[1],))
     return jnp.take(w, idx, axis=0)

@@ -11,63 +11,79 @@ Entry points:
   kernels key into the same per-signature memo, RNG keys from the
   traced ``(base_key, stream)`` pair.
 
-Both paths fall back LOUDLY through ops/_fallback.py on any failure
-(``kernelgen.fallbacks`` counter, warn-once, ``PT_STRICT_KERNELS=1``
-raises naming the unsupported sub-op) to the bitwise-reference replay.
+There is no reroute: a group the tier takes on lowers or the launch
+raises (``KernelgenUnsupported`` names the sub-op; a Mosaic refusal
+surfaces when the whole step compiles).  Which *kinds* of generated
+kernel the tier takes on is ``pallas_kinds()``; sub-ops of the other
+kinds run their registered impl as plain XLA steps inside the plan.
 
-Env vars (docs/kernels.md has the full table): ``PT_KERNELGEN``
-(default: ON when the backend is TPU, OFF elsewhere — an explicit 0/1
-always wins; the interpret-mode tier is a CPU test vehicle, ~9x slower
+Env vars (docs/kernels.md has the full table): ``PT_KERNELGEN`` (unset:
+on TPU the kinds Mosaic compiles, nothing elsewhere; ``1``: every kind;
+``0``: off — the interpret-mode tier is a CPU test vehicle, ~9x slower
 than XLA fusion), ``PT_KERNELGEN_BLOCK`` (static base block size,
-default 1024), ``PT_KERNELGEN_INTERPRET`` (force/forbid interpret
-mode; default: interpret unless the backend is TPU), ``PT_AUTOTUNE``
-(0/1/cached — kernelgen/autotune.py block-size search + persistence).
+default 1024), ``PT_AUTOTUNE`` (0/1/cached — kernelgen/autotune.py
+block-size search + persistence).  Interpret mode is the CPU backend's
+and only its (ops/_pallas.py).
 """
 import os
 
-from .rules import KERNEL_RULES, rule_names
-from .builder import (KernelgenUnsupported, clear_plans, plan_for,
+from .rules import ALL_KINDS, KERNEL_RULES, rule_names
+from .builder import (KernelgenUnsupported, clear_plans, plan_for, plans,
                       rng_rule_types)
 from ...core.registry import register_emit
+from .._pallas import single_device
 
 __all__ = ['KERNEL_RULES', 'KernelgenUnsupported', 'KERNELGEN_VERSION',
-           'enabled', 'config_token', 'fingerprint_extra', 'rule_names',
-           'run_fused', 'run_fused_emit', 'plan_for',
-           'clear_plan_cache', 'note_fallback', 'unsupported_sub_ops']
+           'ALL_KINDS', 'TPU_DEFAULT_KINDS', 'pallas_kinds', 'enabled',
+           'config_token', 'fingerprint_extra', 'rule_names',
+           'run_fused', 'run_fused_emit', 'plan_for', 'plans',
+           'clear_plan_cache', 'unsupported_sub_ops']
 
 # bump on any change to plan building / kernel emission semantics: it
 # feeds the compile-cache fingerprint and the emitter memo key
-KERNELGEN_VERSION = 2
+KERNELGEN_VERSION = 3
+
+# What an unset PT_KERNELGEN turns on for a TPU backend: the kinds that
+# compile through Mosaic (chip_smoke.py's `kernels` phase compiles each).
+# 'ew' is off: Mosaic on jax 0.9.0 / libtpu 0.0.34 refuses its (1,)
+# VMEM scalars, its scalar-core pow and its in-kernel 1-D tile — the
+# messages are in PERF.md, PR 21, for ROADMAP D4.
+TPU_DEFAULT_KINDS = ('attention', 'row')
 
 
-def enabled():
-    """Default ON when the backend is TPU (the tier IS the compute path
-    there); default OFF elsewhere, where kernels would run under the
-    Pallas interpreter — a bitwise test vehicle, not a fast path.  An
-    explicit PT_KERNELGEN always wins, both directions."""
+def pallas_kinds():
+    """The kinds that lower to generated Pallas kernels right now
+    (sorted tuple).  ``PT_KERNELGEN=1``: all of them; ``=0``: none;
+    unset: ``TPU_DEFAULT_KINDS`` on a TPU backend and none elsewhere,
+    where kernels would run under the Pallas interpreter — a bitwise
+    test vehicle, not a fast path."""
     v = os.environ.get('PT_KERNELGEN')
     if v is None:
         import jax
-        return jax.default_backend() == 'tpu'
-    return v in ('1', 'true', 'True')
+        return TPU_DEFAULT_KINDS if jax.default_backend() == 'tpu' else ()
+    return ALL_KINDS if v in ('1', 'true', 'True') else ()
+
+
+def enabled():
+    return bool(pallas_kinds())
 
 
 def config_token():
-    """Launch-signature / emitter-memo component: is the tier on, which
-    codegen generation, and the autotune mode (a mode flip can change
-    every kernel's block shapes, so memoized traces must not survive
-    it)."""
+    """Launch-signature / emitter-memo component: which kinds are on,
+    which codegen generation, and the autotune mode (a mode flip can
+    change every kernel's block shapes, so memoized traces must not
+    survive it)."""
     from . import autotune
-    return ('kernelgen', 1 if enabled() else 0, KERNELGEN_VERSION,
+    return ('kernelgen', pallas_kinds(), KERNELGEN_VERSION,
             autotune.mode())
 
 
 def fingerprint_extra():
-    """AOT disk-cache fingerprint component: version + rule coverage
-    (a new rule changes which sub-programs lower, so cached executables
-    from an older table must not be reused) + autotune mode (tuned and
-    untuned builds compile different block shapes)."""
-    return ('kernelgen', KERNELGEN_VERSION, rule_names(),
+    """AOT disk-cache fingerprint component: version + kinds on + rule
+    coverage (a new rule changes which sub-programs lower, so cached
+    executables from an older table must not be reused) + autotune mode
+    (tuned and untuned builds compile different block shapes)."""
+    return ('kernelgen', KERNELGEN_VERSION, pallas_kinds(), rule_names(),
             _autotune_mode())
 
 
@@ -90,18 +106,6 @@ def unsupported_sub_ops(attrs):
 
 def clear_plan_cache():
     clear_plans()
-
-
-def note_fallback(exc):
-    """Count + route one kernelgen failure through the PR-6 loud
-    fallback contract (raises under PT_STRICT_KERNELS=1)."""
-    from .. import _fallback
-    from ...observability import metrics
-    metrics.counter('kernelgen.fallbacks').inc()
-    detail = ''
-    if isinstance(exc, KernelgenUnsupported):
-        detail = "unsupported sub-op '%s' (%s)" % (exc.sub_op, exc.why)
-    _fallback.kernel_fallback('kernelgen', exc, detail)
 
 
 def _in_avals(xs):
@@ -188,13 +192,10 @@ def _fctx_parts(fctx):
 
 @register_emit('fused_elementwise')
 def _emit_fused(fctx, ins, attrs):
-    """Emitter dispatch: generated kernels when the tier is on, else
-    (or on loud fallback) the inline reference replay."""
+    """Emitter dispatch: generated kernels when the tier is on, else the
+    inline reference replay."""
     key, streams, amp, mesh = _fctx_parts(fctx)
-    if enabled():
-        try:
-            return run_fused_emit(key, streams, amp, ins, attrs)
-        except Exception as e:        # noqa: BLE001 — loud by contract
-            note_fallback(e)          # raises under PT_STRICT_KERNELS
+    if enabled() and single_device(mesh):
+        return run_fused_emit(key, streams, amp, ins, attrs)
     from ...core.emit.emitter import _replay_fused
     return _replay_fused(ins, attrs, amp, mesh, key, streams)

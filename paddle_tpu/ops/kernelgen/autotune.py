@@ -8,7 +8,7 @@ one warmup + best-of-N wall-clock executions per candidate, with inputs
 synthesized FRESH for every run so kernels that donate their buffers
 (``input_output_aliases``) never time against an already-consumed arg.
 
-The winner persists in the PR-3 AOT disk cache directory
+The winner persists in the compile-cache directory
 (``compile_cache.cache_dir()/autotune/<sha256>.json``) keyed by the
 signature plus ``kernelgen.fingerprint_extra()``, so a fleet tunes once
 and every later process starts warm.  Lookup order per signature:
@@ -116,17 +116,37 @@ def _disk_store(path, kind, signature, choice, timings):
 def time_thunk(thunk, warmup=1, runs=2):
     """Best-of-``runs`` wall seconds of ``thunk()`` (blocked to ready).
     The thunk must synthesize its own inputs per call — donated buffers
-    are consumed by each execution."""
+    are consumed by each execution.
+
+    Searches start while the executor traces a step.  On that thread
+    every jax call is staged into the step's trace, and the clock would
+    time the staging of a kernel that never runs.  A trace belongs to its
+    thread, so the candidate runs on a thread of its own, where calls
+    execute."""
+    import threading
     import jax
-    best = None
-    for i in range(warmup + runs):
-        t0 = time.perf_counter()
-        out = thunk()
-        jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
-        if i >= warmup and (best is None or dt < best):
-            best = dt
-    return best
+    box = {}
+
+    def work():
+        try:
+            best = None
+            for i in range(warmup + runs):
+                t0 = time.perf_counter()
+                out = thunk()
+                jax.block_until_ready(out)
+                dt = time.perf_counter() - t0
+                if i >= warmup and (best is None or dt < best):
+                    best = dt
+            box['best'] = best
+        except BaseException as e:  # noqa: BLE001 - re-raised below
+            box['error'] = e
+
+    worker = threading.Thread(target=work, name='pt-autotune')
+    worker.start()
+    worker.join()
+    if 'error' in box:
+        raise box['error']
+    return box['best']
 
 
 def synth_value(shape, dtype):

@@ -15,15 +15,19 @@ plan) and partitions them into three step kinds:
     with them lane-for-lane, so hoisting them BETWEEN kernels preserves
     bitwise parity while keeping every compute op inside a kernel.
 ``dstep``
-    A dedicated whole-op kernel (rule kinds ``row``/``attention``:
-    softmax, layer_norm, flash_attention).  The op's logical inputs are
+    A dedicated whole-op step.  For rule kinds ``row``/``attention``
+    (softmax, layer_norm, flash_attention) the op's logical inputs are
     materialized, its ``rule.step`` runs one generated kernel (a row
     reduction or the flash-attention call), and its outputs re-enter the
     plan as materialized values — with the executor's per-sub-op AMP
     cast policy (core/executor._amp_sub_ins/_amp_sub_outs) applied
     around the step exactly as the replay path applies it.  Block
     shapes come from kernelgen/autotune.py (searched + persisted per
-    signature; ``rule.tune`` declares the candidates).
+    signature; ``rule.tune`` declares the candidates).  A sub-op whose
+    kind is not in ``pallas_kinds()`` takes the same slot with its
+    registered impl as the step: plain XLA, the replay path's exact
+    computation, so a plan can hold Pallas row kernels while the
+    elementwise kind is off.
 ``kernel``
     A maximal run of elementwise/optimizer/rng-body sub-ops lowered into
     ONE ``pl.pallas_call``.  Every tensor is flattened to 1-D and tiled
@@ -58,19 +62,21 @@ the forward is bitwise-equal to) with the drawn keys as residuals;
 per-output stop_gradient therefore applies exactly as on the replay
 path.
 
-On CPU the generated calls run under ``interpret=True``
-(``PT_KERNELGEN_INTERPRET`` overrides); there is no silent fallback
-between the test and the kernel (the PR-6 gather lesson).
+On the CPU backend, and only there, the generated calls run under
+``interpret=True`` (ops/_pallas.py); there is no fallback between the
+test and the kernel.
 """
 import os
 
-__all__ = ['KernelgenUnsupported', 'plan_for', 'clear_plans',
+from .._pallas import interpret as _interpret
+
+__all__ = ['KernelgenUnsupported', 'plan_for', 'plans', 'clear_plans',
            'rng_rule_types']
 
 
 class KernelgenUnsupported(Exception):
     """A sub-op (or shape pattern) the rule table can't lower; carries
-    the sub-op name for PT_STRICT_KERNELS' loud raise and D016."""
+    the sub-op name for the raise and D016."""
 
     def __init__(self, sub_op, why):
         self.sub_op = sub_op
@@ -85,24 +91,6 @@ _BLOCK_CAP = 65536    # refuse lcm-lifted block sizes past this (VMEM)
 
 def _block_base():
     return int(os.environ.get('PT_KERNELGEN_BLOCK', '1024'))
-
-
-def _interpret():
-    import jax
-    on_tpu = jax.default_backend() == 'tpu'
-    v = os.environ.get('PT_KERNELGEN_INTERPRET')
-    if v is None:
-        return not on_tpu
-    want = v in ('1', 'true', 'True')
-    if not want and not on_tpu:
-        # an explicit =0 means "real Mosaic lowering" — impossible off
-        # TPU; raising here (not deep inside a Mosaic error) keeps the
-        # misconfiguration loud instead of silently interpreting
-        raise KernelgenUnsupported(
-            'kernelgen',
-            'PT_KERNELGEN_INTERPRET=0 but the backend is %r — no TPU, '
-            'interpret disabled' % jax.default_backend())
-    return want
 
 
 _RNG_TYPES = None
@@ -260,11 +248,20 @@ class _Seg(object):
 
 
 class _Plan(object):
-    __slots__ = ('fn', 'n_rng', 'n_kernels', 'n_glue', 'kernel_ops',
-                 'groups', 'n_donated', 'n_dsteps', 'tuned')
+    """``fn(xs, keys)`` is the plan; ``ref(xs, keys)`` the replay it must
+    equal; ``attrs`` / ``in_avals`` / ``amp`` say what it was built for
+    (chip_smoke.py re-runs every plan against ``ref`` on the device)."""
+    __slots__ = ('fn', 'ref', 'attrs', 'in_avals', 'amp', 'n_rng',
+                 'n_kernels', 'n_glue', 'kernel_ops', 'groups',
+                 'n_donated', 'n_dsteps', 'n_xla', 'tuned')
 
 
 _PLANS = {}
+
+
+def plans():
+    """Every plan built so far in this process."""
+    return list(_PLANS.values())
 
 
 def clear_plans():
@@ -278,14 +275,15 @@ def plan_for(attrs, in_avals, amp, allow_search=True):
     reaches here under eval_shape) get a plan built on cached/default
     autotune choices — never a timed search."""
     from ...core.emit.emitter import _canon_attrs
-    from . import autotune
+    from . import autotune, pallas_kinds
+    kinds = pallas_kinds()
     key = (_canon_attrs('fused_elementwise', attrs), tuple(in_avals),
            bool(amp), _interpret(), _block_base(), autotune.mode(),
-           bool(allow_search))
+           bool(allow_search), kinds)
     plan = _PLANS.get(key)
     if plan is None:
         plan = _build_plan(attrs, tuple(in_avals), bool(amp),
-                           bool(allow_search))
+                           bool(allow_search), kinds)
         _PLANS[key] = plan
     return plan
 
@@ -381,10 +379,13 @@ def _tune_step(stype, rule, sattrs, avals_d, allow_search):
                            timer, spec.get('default'), allow_search)
 
 
-def _build_plan(attrs, in_avals, amp, allow_search=True):
+def _build_plan(attrs, in_avals, amp, allow_search=True, kinds=None):
     import jax
     import jax.numpy as jnp
-    from .rules import KERNEL_RULES
+    from ...core.registry import get_op
+    from .rules import ALL_KINDS, KERNEL_RULES
+    if kinds is None:
+        kinds = ALL_KINDS
 
     sub_ops = attrs['sub_ops']
     arg_names = list(attrs['arg_names'])
@@ -426,7 +427,7 @@ def _build_plan(attrs, in_avals, amp, allow_search=True):
     steps = []
     seg = [None]
     stats = {'kernels': 0, 'kernel_ops': 0, 'glue': 0, 'donated': 0,
-             'dsteps': 0}
+             'dsteps': 0, 'xla': 0}
     all_groups = []
     tuned = []
 
@@ -530,8 +531,12 @@ def _build_plan(attrs, in_avals, amp, allow_search=True):
             aval[ok] = (tuple(v.shape), str(v.dtype))
             continue
 
-        # -------------------- dedicated whole-op kernels (row/attention)
-        if rule.kind in ('row', 'attention'):
+        # ------------- dedicated whole-op steps: a row/attention kernel,
+        # or the registered impl as plain XLA when the op's kind is off
+        # (compute and rng-body rules all lower into the 'ew' kernel)
+        dedicated = rule.kind in ('row', 'attention')
+        pallas = (rule.kind if dedicated else 'ew') in kinds
+        if dedicated or not pallas:
             if any(loc[key_of(n)][0] == 'sym'
                    for names in sub['inputs'].values() for n in names):
                 _flush(i)
@@ -540,10 +545,20 @@ def _build_plan(attrs, in_avals, amp, allow_search=True):
                 in_mids[slot] = [_as_mat(key_of(n)) for n in names]
                 if names:
                     in_avals_d[slot] = aval[key_of(names[0])]
-            tune = _tune_step(stype, rule, sub['attrs'], in_avals_d,
-                              allow_search)
-            if tune is not None:
-                tuned.append(tune)
+            if pallas:
+                tune = _tune_step(stype, rule, sub['attrs'], in_avals_d,
+                                  allow_search)
+                if tune is not None:
+                    tuned.append(tune)
+
+                def run(ins_, keys, rule=rule, sattrs=sub['attrs'],
+                        av=_AvalsView(dict(in_avals_d)), tune=tune):
+                    return rule.step(ins_, sattrs, av, tune, interp)
+            else:
+                def run(ins_, keys, impl=get_op(stype).impl,
+                        sattrs=sub['attrs'], si=this_si):
+                    return impl(_OneKeyCtx(None if si is None
+                                           else keys[si]), ins_, sattrs)
             out_bind = {}
             for slot, names in sub['outputs'].items():
                 binds = []
@@ -559,9 +574,8 @@ def _build_plan(attrs, in_avals, amp, allow_search=True):
                     aval[ok] = (tuple(v.shape), str(v.dtype))
                     binds.append(mid)
                 out_bind[slot] = binds
-            steps.append(('dstep', sub, rule, in_mids, out_bind,
-                          dict(in_avals_d), tune))
-            stats['dsteps'] += 1
+            steps.append(('dstep', sub, run, in_mids, out_bind))
+            stats['dsteps' if pallas else 'xla'] += 1
             continue
 
         # --------------------------------------- in-kernel compute op
@@ -723,7 +737,7 @@ def _build_plan(attrs, in_avals, amp, allow_search=True):
                 _, mid, fn, ins_ = st
                 mats[mid] = fn(*[mats[m] for m in ins_])
             elif kind == 'dstep':
-                _, sub, rule, in_mids, out_bind, avals_d, tune = st
+                _, sub, run, in_mids, out_bind = st
                 ins_vals = {}
                 for slot, mids_ in in_mids.items():
                     vals = [mats[m] for m in mids_]
@@ -732,9 +746,7 @@ def _build_plan(attrs, in_avals, amp, allow_search=True):
                 if amp:
                     ins_vals = _ex._amp_sub_ins(sub['type'], ins_vals,
                                                 amp)
-                outs = rule.step(ins_vals, sub['attrs'],
-                                 _AvalsView(avals_d), tune, interp) \
-                    or {}
+                outs = run(ins_vals, keys) or {}
                 if amp:
                     outs = _ex._amp_sub_outs(sub['type'], sub['attrs'],
                                              outs, amp)
@@ -780,6 +792,8 @@ def _build_plan(attrs, in_avals, amp, allow_search=True):
 
     plan = _Plan()
     plan.fn = fn
+    plan.ref = ref_replay
+    plan.attrs, plan.in_avals, plan.amp = attrs, in_avals, amp
     plan.n_rng = rng_si
     plan.n_kernels = stats['kernels']
     plan.n_glue = stats['glue']
@@ -787,6 +801,7 @@ def _build_plan(attrs, in_avals, amp, allow_search=True):
     plan.n_donated = stats['donated']
     plan.groups = all_groups
     plan.n_dsteps = stats['dsteps']
+    plan.n_xla = stats['xla']
     plan.tuned = tuned
     return plan
 

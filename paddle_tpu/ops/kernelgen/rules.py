@@ -62,7 +62,12 @@ from jax import lax
 from ...core.dtypes import jax_dtype
 from ...core.registry import get_op
 
-__all__ = ['KERNEL_RULES', 'KRule', 'rule_names']
+__all__ = ['KERNEL_RULES', 'KRule', 'rule_names', 'ALL_KINDS']
+
+# The kinds of generated kernel a plan can hold: 'ew' is the flat 1-D
+# elementwise / optimizer / rng-body kernel, 'row' the softmax /
+# layer_norm row kernels, 'attention' the flash-attention dispatch.
+ALL_KINDS = ('attention', 'ew', 'row')
 
 
 class KRule(object):
@@ -365,29 +370,32 @@ def _layer_norm_step(ins, attrs, info, tune, interpret):
             m = md + c
             y = (d - md) * lax.rsqrt(v + eps)
         if s_ref is not None:
-            y = y * s_ref[...].reshape(1, cols)
+            y = y * s_ref[...]
         if b_ref is not None:
-            y = y + b_ref[...].reshape(1, cols)
+            y = y + b_ref[...]
         y_ref[...] = y.astype(y_ref.dtype)
-        m_ref[...] = m.reshape(-1)
-        v_ref[...] = v.reshape(-1)
+        m_ref[...] = m
+        v_ref[...] = v
 
+    # every ref is 2-D: Mosaic lays a rank-1 f32 array out in 1024-lane
+    # tiles, so (br,) statistic blocks and in-kernel (cols,)->(1, cols)
+    # reshapes do not compile; (br, 1) / (1, cols) blocks do
     in_specs = [pl.BlockSpec((br, cols), lambda i: (i, 0))]
     args = [x.reshape(rows, cols)]
     for p in (scale, bias):
         if p is not None:
-            in_specs.append(pl.BlockSpec((cols,), lambda i: (0,)))
-            args.append(p.reshape(cols))
+            in_specs.append(pl.BlockSpec((1, cols), lambda i: (0, 0)))
+            args.append(p.reshape(1, cols))
     y2, m1, v1 = pl.pallas_call(
         kernel,
         grid=(pl.cdiv(rows, br),),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((br, cols), lambda i: (i, 0)),
-                   pl.BlockSpec((br,), lambda i: (i,)),
-                   pl.BlockSpec((br,), lambda i: (i,))],
+                   pl.BlockSpec((br, 1), lambda i: (i, 0)),
+                   pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((rows, cols), x.dtype),
-                   jax.ShapeDtypeStruct((rows,), jnp.float32),
-                   jax.ShapeDtypeStruct((rows,), jnp.float32)],
+                   jax.ShapeDtypeStruct((rows, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
         interpret=interpret,
     )(*args)
     lead = tuple(x.shape[:begin])

@@ -15,18 +15,11 @@ __all__ = ['make_mesh', 'default_mesh', 'set_default_mesh', 'shard_map']
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (check_rep → check_vma rename),
-    always with replication checking off (we use psum-to-replicate)."""
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    """jax.shard_map with replication checking off (we use
+    psum-to-replicate)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
 
 _default_mesh = [None]
 

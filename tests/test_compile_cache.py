@@ -3,7 +3,7 @@
   * fingerprint stability — the same program+launch signature hashes the
     same across processes; any keyed component (fetch set, K, AMP,
     check_nan, feed shapes) changes the key
-  * warm start — a second FRESH PROCESS over a shared PT_CACHE_DIR loads
+  * warm start — a second FRESH PROCESS over a shared JAX_COMPILATION_CACHE_DIR loads
     executables from disk instead of compiling (asserted on both the
     cache-hit counters and the compile-time collapse)
   * the in-process LRU bound (PT_EXEC_CACHE_MAX) + eviction counter
@@ -114,7 +114,7 @@ import os, sys, time
 os.environ['JAX_PLATFORMS'] = 'cpu'
 os.environ['PT_CACHE'] = '1'
 sys.path.insert(0, sys.argv[1])
-os.environ['PT_CACHE_DIR'] = sys.argv[2]
+os.environ['JAX_COMPILATION_CACHE_DIR'] = sys.argv[2]
 import json
 import numpy as np
 import paddle_tpu as fluid
@@ -164,7 +164,7 @@ def _run_warmstart_proc(cache_dir):
 
 def test_warm_start_across_fresh_processes(tmp_path):
     """The acceptance contract: run the same program twice in FRESH
-    processes over one PT_CACHE_DIR — the second must report disk hits,
+    processes over one JAX_COMPILATION_CACHE_DIR — the second must report disk hits,
     zero actual compiles, and materially lower compile time."""
     cold = _run_warmstart_proc(tmp_path / 'cache')
     warm = _run_warmstart_proc(tmp_path / 'cache')
@@ -184,7 +184,7 @@ def test_warm_start_across_fresh_processes(tmp_path):
 
 def test_corrupt_disk_entries_are_misses(tmp_path, monkeypatch):
     monkeypatch.setenv('PT_CACHE', '1')
-    monkeypatch.setenv('PT_CACHE_DIR', str(tmp_path))
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path))
     disk = cc.DiskCache(str(tmp_path))
     fp = 'ab' + 'cd' * 31
     # truncated garbage
@@ -207,7 +207,7 @@ def test_disk_cache_round_trip_in_process(tmp_path, monkeypatch):
     resolve from disk without tracing."""
     from paddle_tpu.core import executor as em
     monkeypatch.setenv('PT_CACHE', '1')
-    monkeypatch.setenv('PT_CACHE_DIR', str(tmp_path))
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path))
     main, startup, loss = _build()
     feed = {'x': np.ones((2, 4), 'float32'),
             'lbl': np.zeros((2, 1), 'int64')}
@@ -260,7 +260,7 @@ def test_lru_keeps_recently_used():
 
 def test_predictor_warm_starts_from_disk(tmp_path, monkeypatch):
     monkeypatch.setenv('PT_CACHE', '1')
-    monkeypatch.setenv('PT_CACHE_DIR', str(tmp_path / 'cache'))
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path / 'cache'))
     from paddle_tpu import inference
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):

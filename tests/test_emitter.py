@@ -430,7 +430,7 @@ def test_emitted_executable_disk_round_trip(tmp_path, monkeypatch):
     compile its own traced twin — to the same bits."""
     monkeypatch.setenv('PT_EMIT', '1')
     monkeypatch.setenv('PT_CACHE', '1')
-    monkeypatch.setenv('PT_CACHE_DIR', str(tmp_path))
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path))
     main, startup, loss = _train_model(amp=False)
     feed = _feeds(1)[0]
 
@@ -465,7 +465,7 @@ def test_fallback_program_shares_traced_artifacts(tmp_path, monkeypatch):
     posture (fresh L1, PT_EMIT=0) must disk-hit the entry the fallback
     run stored."""
     monkeypatch.setenv('PT_CACHE', '1')
-    monkeypatch.setenv('PT_CACHE_DIR', str(tmp_path))
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path))
     monkeypatch.setattr(emitter, 'DENY_OPS', {'relu'})
     emit.reset_fallbacks()
     main, _, out = _relu_model()

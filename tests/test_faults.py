@@ -144,7 +144,7 @@ def test_retry_never_retries_give_up_exceptions():
 def test_cache_write_fault_recovers_via_retry(tmp_path, monkeypatch):
     """One injected cache_write OSError must NOT lose the disk store:
     the shared retry_with_backoff absorbs it on the second attempt."""
-    monkeypatch.setenv('PT_CACHE_DIR', str(tmp_path))
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path))
     from paddle_tpu.core.compile_cache import DiskCache
     faults.configure('cache_write:at=1')
 

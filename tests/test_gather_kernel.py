@@ -17,41 +17,22 @@ def _force_kernel(monkeypatch):
     monkeypatch.setattr(gather, '_MIN_ROWS', _BLOCK)
 
 
-def test_pallas_gather_kernel_runs_no_fallback_possible():
-    """Drive the pallas kernel DIRECTLY in interpret mode — no try/except
-    between this test and the kernel, so an API drift (BENCH_r04's dtype
-    TypeError, the later pltpu.MemorySpace rename) fails HERE instead of
-    silently rerouting production training to jnp.take."""
+def test_pallas_gather_kernel_direct():
+    """Drive the pallas kernel DIRECTLY (interpret mode is the CPU
+    backend's): an API drift in pallas fails HERE."""
     from paddle_tpu.ops.gather import _pallas_gather
     rng = np.random.RandomState(7)
     w = jnp.asarray(rng.randn(512, 128), jnp.float32)
     idx = jnp.asarray(rng.randint(0, 512, (_BLOCK,)), jnp.int32)
-    out = _pallas_gather(w, idx, interpret=True)
+    out = _pallas_gather(w, idx)
     np.testing.assert_allclose(np.asarray(out), np.asarray(w)[idx],
                                rtol=1e-6)
 
 
-def test_embedding_gather_no_silent_fallback(monkeypatch):
-    """The full embedding_gather path must run WITHOUT emitting the
-    fallback warning (warnings-as-errors): the kernel path either works
-    or this test fails — degradation can't hide."""
-    import warnings
-    rng = np.random.RandomState(3)
-    w = jnp.asarray(rng.randn(640, 128), jnp.float32)
-    idx = jnp.asarray(rng.randint(0, 640, (_BLOCK,)), jnp.int32)
-    with warnings.catch_warnings():
-        warnings.simplefilter('error')
-        out = embedding_gather(w, idx)
-        jax.grad(lambda w: (embedding_gather(w, idx) ** 2).sum())(w)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(w)[idx],
-                               rtol=1e-6)
-
-
-def test_strict_kernels_raises_instead_of_falling_back(monkeypatch):
-    """PT_STRICT_KERNELS=1 turns a kernel failure into a raise with the
-    underlying error; default mode counts kernel.fallbacks."""
+def test_eligible_gather_runs_the_kernel_or_raises(monkeypatch):
+    """No reroute: a failure inside an eligible gather propagates to the
+    caller instead of degrading to jnp.take."""
     from paddle_tpu.ops import gather
-    import paddle_tpu.observability as obs
 
     def _boom(*a, **k):
         raise ValueError('induced kernel failure')
@@ -60,17 +41,16 @@ def test_strict_kernels_raises_instead_of_falling_back(monkeypatch):
     rng = np.random.RandomState(4)
     w = jnp.asarray(rng.randn(640, 128), jnp.float32)
     idx = jnp.asarray(rng.randint(0, 640, (_BLOCK,)), jnp.int32)
-    before = obs.counters().get('kernel.fallbacks') or 0
-    with pytest.warns(UserWarning, match='embedding_gather'):
-        from paddle_tpu.ops import _fallback
-        monkeypatch.setattr(_fallback, '_warned', set())
-        out = embedding_gather(w, idx)   # degrades to jnp.take, loudly
-    np.testing.assert_allclose(np.asarray(out), np.asarray(w)[idx],
-                               rtol=1e-6)
-    assert (obs.counters().get('kernel.fallbacks') or 0) == before + 1
-    monkeypatch.setenv('PT_STRICT_KERNELS', '1')
-    with pytest.raises(RuntimeError, match='PT_STRICT_KERNELS'):
+    with pytest.raises(ValueError, match='induced kernel failure'):
         embedding_gather(w, idx)
+
+
+def test_bf16_table_is_not_eligible():
+    """Mosaic refuses the one-row slice of a packed bf16 table (PERF.md
+    PR 21), so bf16 takes jnp.take by the static rule."""
+    w = jnp.zeros((640, 128), jnp.bfloat16)
+    idx = jnp.zeros((_BLOCK,), jnp.int32)
+    assert not _eligible(w, idx)
 
 
 def test_gather_parity_and_grad(monkeypatch):
@@ -87,9 +67,7 @@ def test_gather_parity_and_grad(monkeypatch):
     np.testing.assert_allclose(np.asarray(out), np.asarray(w)[idx],
                                rtol=1e-6)
     # gradient: scatter-add with duplicate indices.  The kernel must
-    # actually engage under jax.grad (a dtype object in the vjp
-    # residuals used to raise at trace time and silently reroute every
-    # training step to the jnp.take fallback — ADVICE r4).
+    # actually engage under jax.grad.
     n_fwd_calls = len(calls)
     assert n_fwd_calls > 0
     g = jax.grad(lambda w: (embedding_gather(w, idx) ** 2).sum())(w)
@@ -98,7 +76,7 @@ def test_gather_parity_and_grad(monkeypatch):
     np.testing.assert_allclose(np.asarray(g), np.asarray(gr), rtol=1e-5)
 
 
-def test_gather_multi_dim_ids_and_fallback():
+def test_gather_multi_dim_ids_and_ineligible_shapes():
     rng = np.random.RandomState(1)
     w = jnp.asarray(rng.randn(64, 128), jnp.float32)
     idx2d = jnp.asarray(rng.randint(0, 64, (2, _BLOCK)), jnp.int32)
@@ -106,7 +84,7 @@ def test_gather_multi_dim_ids_and_fallback():
     assert out.shape == (2, _BLOCK, 128)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(w)[np.asarray(idx2d)], rtol=1e-6)
-    # ineligible (tiny / misaligned) shapes fall back to jnp.take
+    # ineligible (tiny / misaligned) shapes take jnp.take
     small = jnp.asarray([3, 1], jnp.int32)
     np.testing.assert_allclose(np.asarray(embedding_gather(w, small)),
                                np.asarray(w)[[3, 1]], rtol=1e-6)

@@ -225,7 +225,7 @@ def test_fused_window_bitwise_equals_sequential(params):
 
 def test_decode_parity_through_restored_aot_cache(tmp_path, monkeypatch):
     monkeypatch.setenv('PT_CACHE', '1')
-    monkeypatch.setenv('PT_CACHE_DIR', str(tmp_path))
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path))
     params = SamplingParams(0.7, 5, 9)
     w = random_weights(CFG)
     rt1 = DecodeRuntime(w, CFG, slots=2, prefill_chunk=4)

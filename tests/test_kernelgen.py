@@ -1,6 +1,6 @@
 """Pallas codegen tier (ops/kernelgen): per-rule bitwise parity vs the
-reference replay, the fused-Adam single-kernel contract, loud fallback
-semantics (PT_STRICT_KERNELS), emitter/launch-signature integration, AOT
+reference replay, the fused-Adam single-kernel contract, the no-reroute
+contract and the per-kind TPU default, emitter/launch-signature integration, AOT
 disk-cache round trip, and end-to-end parity through run / run_steps /
 ParallelExecutor under AMP + dropout.
 
@@ -31,6 +31,13 @@ import paddle_tpu.observability as obs                # noqa: E402
 from paddle_tpu.ops import fused as _fused            # noqa: E402
 from paddle_tpu.ops import kernelgen as kg            # noqa: E402
 from paddle_tpu.ops.kernelgen import builder          # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _every_kind_on(monkeypatch):
+    """plan_for builds for the kinds that are ON (kg.pallas_kinds());
+    this module exercises every kind, in the Pallas interpreter."""
+    monkeypatch.setenv('PT_KERNELGEN', '1')
 
 
 # ------------------------------------------------------------- helpers
@@ -391,42 +398,15 @@ class _PlainCtx(object):
         return jax.random.key(0)
 
 
-def test_strict_kernels_raises_naming_sub_op(monkeypatch):
+def test_unsupported_group_raises_naming_sub_op(monkeypatch):
+    """No reroute to the replay: a group the tier cannot lower raises,
+    naming the sub-op."""
     monkeypatch.setenv('PT_KERNELGEN', '1')
-    monkeypatch.setenv('PT_STRICT_KERNELS', '1')
     from paddle_tpu.core.registry import get_op
     x = jnp.ones((2, 3), jnp.float32)
-    with pytest.raises(RuntimeError) as ei:
+    with pytest.raises(kg.KernelgenUnsupported, match='reduce_sum'):
         get_op('fused_elementwise').impl(_PlainCtx(), {'X': [x]},
                                          _unsupported_attrs())
-    msg = str(ei.value)
-    assert 'reduce_sum' in msg and 'PT_STRICT_KERNELS' in msg
-
-
-def test_fallback_counts_warns_once_and_replays(monkeypatch):
-    monkeypatch.setenv('PT_KERNELGEN', '1')
-    monkeypatch.delenv('PT_STRICT_KERNELS', raising=False)
-    from paddle_tpu.core.registry import get_op
-    from paddle_tpu.ops import _fallback
-    _fallback._warned.discard('kernelgen')
-    x = jnp.full((2, 3), 0.5, jnp.float32)
-    before = obs.counters().get('kernelgen.fallbacks') or 0
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter('always')
-        out = get_op('fused_elementwise').impl(
-            _PlainCtx(), {'X': [x]}, _unsupported_attrs())
-        out2 = get_op('fused_elementwise').impl(
-            _PlainCtx(), {'X': [x]}, _unsupported_attrs())
-    relevant = [x for x in w if 'kernelgen' in str(x.message)]
-    assert len(relevant) == 1, 'fallback must warn exactly once'
-    assert 'reduce_sum' in str(relevant[0].message)
-    after = obs.counters().get('kernelgen.fallbacks') or 0
-    assert after == before + 2
-    want = jnp.sum(x * 2.0, axis=-1)
-    np.testing.assert_allclose(np.asarray(out['Out'][0]),
-                               np.asarray(want), rtol=1e-6)
-    np.testing.assert_array_equal(np.asarray(out['Out'][0]),
-                                  np.asarray(out2['Out'][0]))
 
 
 def test_unsupported_sub_ops_lists_gaps_once():
@@ -547,7 +527,7 @@ def test_autotune_searches_once_persists_and_is_deterministic(
         tmp_path, monkeypatch):
     from paddle_tpu.ops.kernelgen import autotune
     monkeypatch.setenv('PT_CACHE', '1')
-    monkeypatch.setenv('PT_CACHE_DIR', str(tmp_path))
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path))
     monkeypatch.setenv('PT_AUTOTUNE', '1')
     kg.clear_plan_cache()
     autotune.clear_memory()
@@ -616,26 +596,47 @@ def test_autotune_off_mode_and_lint_ctx_never_time(monkeypatch):
     autotune.clear_memory()
 
 
-# ------------------------------ default-on + interpret misconfiguration
+# ------------------------------------------- which kinds are on where
 
-def test_enabled_defaults_on_only_for_tpu_backend(monkeypatch):
+def test_kinds_default_per_backend(monkeypatch):
     monkeypatch.delenv('PT_KERNELGEN', raising=False)
-    assert not kg.enabled(), 'CPU session: tier defaults OFF'
+    assert kg.pallas_kinds() == () and not kg.enabled(), \
+        'CPU session: tier defaults OFF'
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
-    assert kg.enabled(), 'TPU session: tier defaults ON'
+    assert kg.pallas_kinds() == kg.TPU_DEFAULT_KINDS and kg.enabled()
+    assert 'ew' not in kg.TPU_DEFAULT_KINDS, \
+        'Mosaic refuses the flat elementwise kernel (PERF.md PR 21)'
     monkeypatch.setenv('PT_KERNELGEN', '0')
     assert not kg.enabled(), 'explicit 0 wins on TPU'
     monkeypatch.setattr(jax, 'default_backend', lambda: 'cpu')
     monkeypatch.setenv('PT_KERNELGEN', '1')
-    assert kg.enabled(), 'explicit 1 wins off TPU'
+    assert kg.pallas_kinds() == kg.ALL_KINDS, 'explicit 1: every kind'
 
 
-def test_interpret_forced_off_without_tpu_raises(monkeypatch):
-    monkeypatch.setenv('PT_KERNELGEN_INTERPRET', '0')
-    with pytest.raises(kg.KernelgenUnsupported) as ei:
-        builder._interpret()
-    msg = str(ei.value)
-    assert 'no TPU' in msg and 'interpret' in msg
+def test_kind_off_runs_as_xla_step_bitwise(monkeypatch):
+    """With 'ew' off (the TPU default) a group's elementwise sub-ops run
+    their registered impl as XLA steps while its layer_norm stays a
+    generated row kernel — bitwise the all-kinds plan and the replay."""
+    monkeypatch.setenv('PT_KERNELGEN', '1')
+    rng = np.random.RandomState(5)
+    x, y = _rand(rng, (6, 16)), _rand(rng, (6, 16))
+    s, b = _rand(rng, (16,)), _rand(rng, (16,))
+    attrs = _attrs(
+        [_sub('elementwise_add', {'X': ['x'], 'Y': ['y']},
+              {'Out': ['h']}, {'axis': -1}),
+         _sub('layer_norm', {'X': ['h'], 'Scale': ['s'], 'Bias': ['b']},
+              {'Y': ['o'], 'Mean': ['m'], 'Variance': ['v']},
+              {'begin_norm_axis': 1, 'epsilon': 1e-5})],
+        ['x', 'y', 's', 'b'], ['o'])
+    avals = kg._in_avals([x, y, s, b])
+    full = builder._build_plan(attrs, avals, False)
+    part = builder._build_plan(attrs, avals, False,
+                               kinds=kg.TPU_DEFAULT_KINDS)
+    assert (full.n_kernels, full.n_dsteps, full.n_xla) == (1, 1, 0)
+    assert (part.n_kernels, part.n_dsteps, part.n_xla) == (0, 1, 1)
+    a, = full.fn((x, y, s, b), ())
+    c, = part.fn((x, y, s, b), ())
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
 
 
 # ------------------------------------- config tokens and fingerprints
@@ -646,9 +647,11 @@ def test_config_token_and_fingerprint_extra(monkeypatch):
     monkeypatch.setenv('PT_KERNELGEN', '0')
     tok_off = kg.config_token()
     assert tok_on != tok_off and tok_on[0] == 'kernelgen'
+    monkeypatch.setenv('PT_KERNELGEN', '1')
     fp = kg.fingerprint_extra()
     assert fp[0] == 'kernelgen' and fp[1] == kg.KERNELGEN_VERSION
-    assert 'adam' in fp[2] and 'dropout' in fp[2]
+    assert fp[2] == kg.pallas_kinds()
+    assert 'adam' in fp[3] and 'dropout' in fp[3]
 
     # executor composition: kernelgen OFF leaves old fingerprints
     # untouched; ON composes on both emit and trace paths
@@ -696,10 +699,6 @@ def _train(monkeypatch, pt_kg, runner, seed):
     emitter.clear_memo()
     monkeypatch.setenv('PT_CACHE', '0')
     monkeypatch.setenv('PT_KERNELGEN', pt_kg)
-    if pt_kg == '1':
-        monkeypatch.setenv('PT_STRICT_KERNELS', '1')
-    else:
-        monkeypatch.delenv('PT_STRICT_KERNELS', raising=False)
     kg.clear_plan_cache()
     main, startup, loss = _train_model(seed)
     losses, scope = runner(main, startup, loss)
@@ -711,7 +710,7 @@ def _assert_parity(monkeypatch, runner, seed):
     """First launch 1e-6, later steps drift-bounded (docstring up top);
     the kernel path must actually engage (kernelgen.ops advances —
     per-test seed keeps the program out of the cross-test lowering
-    cache) with zero fallbacks under PT_STRICT_KERNELS=1."""
+    cache); there is no reroute, so a kernel failure raises."""
     before = obs.counters().get('kernelgen.ops') or 0
     l1, s1 = _train(monkeypatch, '1', runner, seed)
     assert (obs.counters().get('kernelgen.ops') or 0) > before
@@ -775,7 +774,6 @@ def test_launch_signature_names_kernelgen_flip(monkeypatch):
         exe.run(startup)
         exe.run(main, feed=feed, fetch_list=[loss])
         monkeypatch.setenv('PT_KERNELGEN', '1')
-        monkeypatch.setenv('PT_STRICT_KERNELS', '1')
         exe.run(main, feed=feed, fetch_list=[loss])
     hits = [r for r in obs.explainer().reports
             if any('kernelgen' in d for d in r['details'])]
@@ -787,9 +785,8 @@ def test_aot_disk_cache_round_trip(tmp_path, monkeypatch):
     second fresh-L1 executor loads without tracing, bitwise."""
     from paddle_tpu.core import executor as em
     monkeypatch.setenv('PT_CACHE', '1')
-    monkeypatch.setenv('PT_CACHE_DIR', str(tmp_path))
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', str(tmp_path))
     monkeypatch.setenv('PT_KERNELGEN', '1')
-    monkeypatch.setenv('PT_STRICT_KERNELS', '1')
     kg.clear_plan_cache()
     main, startup, loss = _train_model()
     feed, = _feeds(1)

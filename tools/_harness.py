@@ -1,41 +1,23 @@
 """Shared bench/soak process harness: stage tracking, the structured
-{"error", "stage"} JSON tail, the hang watchdog, and the subprocess
-backend probe.
+{"error", "stage"} JSON tail, the hang watchdog, and the one rule for
+which device a measuring tool may run on.
 
 One implementation, one contract, five consumers (bench.py, perflab
 children, fault_soak, serve_soak, pod_soak): whatever kills the process
-— an exception, a hang, a hung PJRT init — the LAST stdout line is
+— an exception, a hang — the LAST stdout line is
 
     {"error": <kind>, "stage": <last stage entered>, "detail": ...}
 
 so a dead round is still a diagnosable artifact instead of a bare
-stack (or nothing).  Stdlib-only on purpose: bench.py must be able to
-import this BEFORE importing jax/paddle_tpu, because the whole point of
-the subprocess probe is to never init the device runtime in-process
-until a child proved it responds.
+stack (or nothing).  Stdlib-only at import: perflab's parent imports
+this and must stay off JAX, because a chip belongs to one process and
+its children need it.
 """
 import json
 import os
-import subprocess
 import sys
 import threading
 import traceback
-
-# BENCH_PROBE_S is the documented knob (default 60s — a healthy PJRT
-# init is seconds, and BENCH_r05 showed a hung one never recovers, so
-# 300s only delayed the CPU fallback); BENCH_PROBE_TIMEOUT kept for
-# back-compat.
-PROBE_TIMEOUT_S = int(os.environ.get('BENCH_PROBE_S')
-                      or os.environ.get('BENCH_PROBE_TIMEOUT') or '60')
-
-_PROBE_CODE = r"""
-import jax, jax.numpy as jnp
-d = jax.devices()
-x = jnp.ones((128, 128), jnp.bfloat16)
-s = float((x @ x).sum())
-assert s == 128 * 128 * 128, s
-print('PROBE_OK', d[0].platform, '|', d[0].device_kind)
-"""
 
 _TOOL = ['BENCH']
 _STAGE = ['startup']
@@ -100,36 +82,24 @@ def install_watchdog(default_s=1800.0, env='BENCH_WATCHDOG_S',
     return t
 
 
-def probe_backend(retries=None, timeout_s=None):
-    """Run a trivial device computation in a subprocess with a timeout.
-    A failed/hung probe is retried once (BENCH_r05 lost a whole round to
-    one transient 300s PJRT init hang).  Returns (platform, device_kind)
-    or (None, reason)."""
-    if retries is None:
-        retries = int(os.environ.get('BENCH_PROBE_RETRIES', '1'))
-    if timeout_s is None:
-        timeout_s = PROBE_TIMEOUT_S
-    reason = 'probe never ran'
-    for attempt in range(retries + 1):
-        try:
-            r = subprocess.run([sys.executable, '-c', _PROBE_CODE],
-                               capture_output=True, text=True,
-                               timeout=timeout_s)
-        except subprocess.TimeoutExpired:
-            reason = 'probe timed out after %ds (PJRT init hang)' % \
-                timeout_s
-        else:
-            for line in r.stdout.splitlines():
-                if line.startswith('PROBE_OK'):
-                    _, platform, _, kind = line.split(None, 3)
-                    return platform, kind
-            tail = (r.stderr or r.stdout).strip().splitlines()[-8:]
-            reason = 'probe rc=%d: %s' % (r.returncode, ' | '.join(tail))
-        if attempt < retries:
-            print('%s: backend probe failed (%s) — retrying (%d/%d)'
-                  % (_TOOL[0], reason, attempt + 1, retries),
-                  file=sys.stderr)
-    return None, reason
+def cpu_requested():
+    """A deliberate ``JAX_PLATFORMS=cpu`` run: CI plumbing, labelled cpu."""
+    return 'cpu' in (os.environ.get('JAX_PLATFORMS') or '')
+
+
+def require_device():
+    """(platform, device_kind) of the device this process runs on.  A
+    tool that wants the chip and finds none FAILS — there is no probe
+    subprocess (a child that opens the chip takes it from its parent)
+    and no fall-back to the CPU; only ``cpu_requested()`` runs on it."""
+    import jax
+    dev0 = jax.devices()[0]
+    if dev0.platform != 'tpu' and not cpu_requested():
+        raise RuntimeError(
+            'this run wants a TPU and JAX found %s (%s); set '
+            'JAX_PLATFORMS=cpu for a plumbing run labelled cpu'
+            % (dev0.platform, dev0.device_kind))
+    return dev0.platform, str(dev0.device_kind)
 
 
 def main_guard(main, watchdog=True, watchdog_default_s=1800.0,

@@ -12,8 +12,8 @@ Exactly one way to produce a perf number in this repo (ROADMAP item 5):
   compare  diff the newest ledger record per scenario against the
            committed PERF_BASELINE.json: deterministic counters are
            zero-tolerance, timings are noise-bounded best-of-K, and a
-           cpu-fallback record vs a TPU baseline is a structured
-           REFUSAL, not a pass.
+           record from another platform than the baseline's is a
+           structured REFUSAL, not a pass.
   check    assert every requested scenario has a schema-valid,
            non-error, provenance-complete ledger record (the ci gate).
   bless    write the newest ledger records out as the new baseline.
@@ -30,28 +30,35 @@ Scenarios (geometry via the BENCH_* shrink knobs, see docs/perflab.md):
   decode_stream      GenerationEngine streaming decode: tokens/s/chip
                      + TTFT/ITL p99 under open-loop load
   pod_parallel       all-reduce bandwidth over the local mesh + 2-host
-                     lockstep scaling (subprocess workers)
+                     lockstep scaling of HOST-side step throughput
+                     (subprocess workers pinned to the CPU)
   fused_adam_micro   the kernelgen tier's headline op: ms/step of the
                      fused-Adam update
 
 Record + comparison semantics live in
 paddle_tpu/observability/perflab.py; the per-scenario metric schemas in
 observability/export.py (SCHEMA['perflab.*']).
+
+One process for each chip: this parent never initialises JAX (importing
+paddle_tpu starts no backend) and runs its children strictly one after
+another, so each child has the chip to itself.  There is no backend
+probe and no fall-back to the CPU: a child that wants the chip and
+finds none fails; a deliberate ``JAX_PLATFORMS=cpu`` round is labelled
+``cpu``.  Every child compiles into the one cache directory
+(core/compile_cache.cache_dir()).
 """
 import argparse
 import json
 import os
-import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-import _harness  # noqa: E402 - shared stage/watchdog/probe machinery
+import _harness  # noqa: E402 - shared stage/watchdog machinery
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT_LEDGER = os.path.join(REPO_ROOT, 'PERF_HISTORY.jsonl')
@@ -464,45 +471,43 @@ def _pod_shard_round():
 def scenario_pod_parallel(best_of):
     """Pod-story plumbing: psum bus bandwidth over the local mesh (null
     single-device), the shard pass's replicated-vs-sharded HBM round,
-    and 2-worker lockstep throughput scaling via subprocess workers —
-    the shape the real pod gate grows into."""
+    and 2-worker lockstep scaling of HOST-side step throughput via
+    subprocess workers — the shape the real pod gate grows into."""
     import jax
     from bench import allreduce_bw_gbps
 
     steps = _env_int('PERFLAB_POD_STEPS', 8)
     _harness.stage('shard_round')
-    try:
-        shard_metrics = _pod_shard_round()
-    except Exception as e:  # noqa: BLE001 - diagnostic-only path
-        print('PERFLAB: shard round failed: %s' % e, file=sys.stderr)
-        shard_metrics = {}
+    shard_metrics = _pod_shard_round()
     _harness.stage('allreduce')
     devices = jax.local_device_count()
-    try:
-        bw = allreduce_bw_gbps(n_iters=5, nbytes=8 * 1024 * 1024)
-    except Exception as e:  # noqa: BLE001 - diagnostic-only path
-        print('PERFLAB: allreduce microbench failed: %s' % e,
-              file=sys.stderr)
-        bw = None
+    bw = allreduce_bw_gbps(n_iters=5, nbytes=8 * 1024 * 1024)
 
     def spawn():
         env = dict(os.environ)
-        # workers measure host-side step throughput; keep their device
-        # view simple regardless of this child's forced multi-device one
+        # this process has run JAX and holds whatever chip there is, so
+        # the workers are pinned to the CPU: steps_per_s_1worker and
+        # scaling_2worker_x are host-side numbers on every platform
+        env['JAX_PLATFORMS'] = 'cpu'
         env.pop('XLA_FLAGS', None)
         return subprocess.Popen(
             [sys.executable, os.path.abspath(__file__), 'podworker',
              '--steps', str(steps)],
-            env=env, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+            env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
             text=True)
 
     def finish(proc, timeout):
         try:
-            out, _ = proc.communicate(timeout=timeout)
+            out, err = proc.communicate(timeout=timeout)
         except subprocess.TimeoutExpired:
             proc.kill()
             proc.communicate()
             return None
+        if proc.returncode != 0:
+            print('PERFLAB: podworker rc=%d: %s'
+                  % (proc.returncode,
+                     ' | '.join((err or '').strip().splitlines()[-4:])),
+                  file=sys.stderr)
         for line in reversed((out or '').strip().splitlines()):
             if line.startswith('{'):
                 try:
@@ -629,51 +634,13 @@ if os.environ.get('PERFLAB_TEST_SCENARIOS') == '1':
 
 
 # ------------------------------------------------------------- plumbing
-def _resolve_backend(allow_cpu):
-    """Decide the backend for a round, bench.py-style: a deliberate
-    JAX_PLATFORMS=cpu run is CPU with NO fallback reason; otherwise the
-    subprocess probe must reach a TPU, and anything else is either a
-    recorded fallback (allow_cpu) or a structured hard failure.
-    Returns (platform, fallback_reason, extra_child_env) or exits."""
-    if 'cpu' in (os.environ.get('JAX_PLATFORMS') or ''):
-        return 'cpu', None, {}
-    platform, kind_or_reason = _harness.probe_backend()
-    if platform == 'tpu':
-        print('PERFLAB: backend ok: tpu (%s)' % kind_or_reason,
-              file=sys.stderr)
-        return 'tpu', None, {}
-    reason = kind_or_reason if platform is None else \
-        "probe reached backend '%s', not tpu" % platform
-    if not allow_cpu:
-        print('PERFLAB: backend is not TPU — %s' % reason, file=sys.stderr)
-        print('PERFLAB: set --allow-cpu (or PERFLAB_ALLOW_CPU=1) to '
-              'record CPU numbers anyway', file=sys.stderr)
-        _harness.emit_error('cpu_fallback', reason)
-        sys.exit(3)
-    print('PERFLAB: falling back to CPU — %s' % reason, file=sys.stderr)
-    return 'cpu', reason if platform is None else None, \
-        {'JAX_PLATFORMS': 'cpu'}
-
-
-def _run_child(name, budget, best_of, fallback, extra_env, platform,
-               cache_root=None):
+def _run_child(name, budget, best_of):
     """One subprocess-isolated scenario.  Returns a ledger record —
     success, or a structured {"error": "timeout"|...} record."""
     from paddle_tpu.observability import perflab as pl
 
     env = dict(os.environ)
-    env.update(extra_env)
-    env.setdefault('PT_KERNELGEN', '1')
-    if cache_root is not None:
-        # every scenario lowers against its OWN fresh compile cache, so
-        # compile/codegen counters (kernelgen_ops, compiles, ...) are
-        # reproducible by construction — independent of whatever an
-        # ambient PT_CACHE_DIR (e.g. ci_smoke's shared cache, warmed by
-        # earlier gates) happens to contain
-        env['PT_CACHE_DIR'] = os.path.join(cache_root, name)
-    if fallback:
-        env['PERFLAB_FALLBACK'] = fallback
-    if name == 'pod_parallel' and platform == 'cpu':
+    if name == 'pod_parallel' and _harness.cpu_requested():
         # give the allreduce microbench a 2-device mesh to measure
         flags = env.get('XLA_FLAGS', '')
         if 'xla_force_host_platform_device_count' not in flags:
@@ -737,38 +704,23 @@ def cmd_run(args):
     if unknown:
         sys.exit('perflab: unknown scenario(s) %s (known: %s)'
                  % (unknown, ', '.join(sorted(SCENARIOS))))
-    allow_cpu = args.allow_cpu or \
-        os.environ.get('PERFLAB_ALLOW_CPU',
-                       os.environ.get('BENCH_ALLOW_CPU', '0')) in ('1',
-                                                                   'true')
-    _harness.stage('probe')
-    platform, fallback, extra_env = _resolve_backend(allow_cpu)
     ledger = args.ledger
-    # children compile against a fresh per-scenario cache so the
-    # deterministic counters in the record never depend on ambient cache
-    # state; PERFLAB_CACHE_DIR pins a persistent root instead (explicit
-    # warm-cache mode, e.g. to amortise TPU compiles across rounds)
-    pinned_cache = os.environ.get('PERFLAB_CACHE_DIR')
-    cache_root = pinned_cache or tempfile.mkdtemp(prefix='perflab_cache_')
     records, failed = [], []
-    try:
-        for name in names:
-            _harness.stage(name)
-            rec = _run_child(name, args.budget_s, args.best_of, fallback,
-                             extra_env, platform, cache_root=cache_root)
-            pl.append_record(ledger, rec)
-            records.append(rec)
-            if 'error' in rec:
-                failed.append(name)
-    finally:
-        if not pinned_cache:
-            shutil.rmtree(cache_root, ignore_errors=True)
+    for name in names:
+        _harness.stage(name)
+        rec = _run_child(name, args.budget_s, args.best_of)
+        pl.append_record(ledger, rec)
+        records.append(rec)
+        if 'error' in rec:
+            failed.append(name)
     summary = {
         'scenarios': len(records),
         'ok': len(records) - len(failed),
         'failed': failed,
-        'platform': platform,
-        'fallback': fallback,
+        # what the children ran on, by their own report: this process
+        # never opens a device
+        'platform': sorted({r['provenance']['platform']
+                            for r in records if 'error' not in r}),
         'ledger': ledger,
     }
     print(json.dumps(summary))
@@ -781,11 +733,11 @@ def cmd_child(args):
     name = args.scenario
     if name not in SCENARIOS:
         sys.exit('perflab child: unknown scenario %r' % name)
-    fallback = os.environ.get('PERFLAB_FALLBACK') or None
+    _harness.stage('device')
+    _harness.require_device()
     metrics, spread, config = SCENARIOS[name](args.best_of)
     _harness.stage('report')
-    rec = pl.build_record(name, metrics, spread=spread, config=config,
-                          fallback=fallback)
+    rec = pl.build_record(name, metrics, spread=spread, config=config)
     print(json.dumps(rec))
     return 0
 
@@ -840,8 +792,8 @@ def cmd_compare(args):
     print(json.dumps(summary))
     if rc == 2:
         print('PERFLAB: comparison REFUSED — see reasons above '
-              '(a cpu-fallback or mismatched-backend record cannot '
-              'gate against this baseline)', file=sys.stderr)
+              '(a mismatched-backend record cannot gate against this '
+              'baseline)', file=sys.stderr)
     elif rc:
         print('PERFLAB: regression(s) detected', file=sys.stderr)
     return rc
@@ -926,9 +878,6 @@ def main():
     p.add_argument('--best-of', type=int,
                    default=int(os.environ.get('PERFLAB_BEST_OF', '3')),
                    help='timing trials per scenario (spread is recorded)')
-    p.add_argument('--allow-cpu', action='store_true',
-                   help='record CPU numbers when no TPU is reachable '
-                        '(provenance carries the fallback reason)')
     p.set_defaults(fn=cmd_run)
 
     p = sub.add_parser('child', help='internal: run ONE scenario '

@@ -14,6 +14,12 @@ trips ``DeviceLossError`` → ``RecoveryPolicy`` rolls back to the last
 good manifest and the worker exits ``RESTART_EXIT_CODE`` (75) so the
 supervisor respawns the roster.
 
+The workers always run on the CPU (``JAX_PLATFORMS=cpu`` is set in
+their environment): a chip belongs to one process, so N trainer
+processes on one host cannot share it, and what this soak checks —
+checkpoint sharding, heartbeats, rollback, bitwise resume — is host-side
+logic.  Nothing it prints is a device number.
+
 Supervisor scenario (the ci_smoke pod gate):
 
   ref     1-host uninterrupted run of the same stream → the reference
@@ -171,7 +177,7 @@ class Wave(object):
 def _spawn(args, host, hosts, health_dir, extra_env=None, step_delay=0.0,
            expect_resume=False):
     env = dict(os.environ)
-    env.setdefault('JAX_PLATFORMS', 'cpu')
+    env['JAX_PLATFORMS'] = 'cpu'   # see the module docstring
     env['PT_CACHE'] = '0'
     env.pop('PT_FAULT', None)
     if extra_env:
