@@ -21,8 +21,10 @@ the repo benchmarks, with seeded random weights:
 
 One process, no subprocess, no probe, and no `except` around a phase: any
 failure propagates and the run exits non-zero without a result line.  With
-no TPU it exits non-zero before running anything.  The last stdout line
-is one JSON object naming the device and each phase's outcome.
+no TPU it exits non-zero before running anything.  Each phase prints its
+outcome on its own line; the last stdout line is the result the driver
+parses, one JSON object with exactly the keys ``ok`` and ``device``:
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``.
 
 The phases are functions of a size (``SIZES``): tests/test_chip_smoke.py
 calls each at the ``tiny`` size on the CPU, Pallas in interpret mode —
@@ -688,6 +690,16 @@ def device_report():
     return device
 
 
+def result_line(device):
+    """The last stdout line: exactly ``ok`` and ``device``, the device
+    exactly ``platform``, ``kind`` (text) and ``count`` (a whole number).
+    The driver refuses any other key, so the phases' outcomes are
+    printed on the lines before it, never in it."""
+    return json.dumps({'ok': True, 'device': {
+        'platform': str(device['platform']), 'kind': str(device['kind']),
+        'count': int(device['count'])}})
+
+
 def main():
     t0 = time.perf_counter()
     if not __debug__:
@@ -696,21 +708,18 @@ def main():
     assert obs.enabled(), 'the smoke reads its counters: PT_OBS must be on'
     device = device_report()
     size = SIZES['full']
-    phases = {
-        'train_transformer': train_transformer(size['transformer']),
-        'train_resnet50': train_resnet50(size['resnet']),
-        'kernels': kernels(size['kernels']),
-        'serve': serve(size['serve']),
-    }
+    train_transformer(size['transformer'])
+    train_resnet50(size['resnet'])
+    kernels(size['kernels'])
+    serve(size['serve'])
     if device['count'] >= size['multichip']['devices']:
-        phases['multichip'] = multichip(size['multichip'])
+        multichip(size['multichip'])
     else:
         print('multichip: not run (%d device)' % device['count'])
-        phases['multichip'] = 'not run (%d device)' % device['count']
     print('chip_smoke: every phase passed in %.0f s'
           % (time.perf_counter() - t0))
-    print(json.dumps({'ok': True, 'device': device, 'phases': phases,
-                      'claim': None}))
+    sys.stderr.flush()
+    print(result_line(device), flush=True)
 
 
 if __name__ == '__main__':

@@ -179,3 +179,25 @@ def test_chip_smoke_without_a_tpu_runs_nothing_and_fails():
     assert r.returncode != 0
     assert 'no TPU' in r.stderr
     assert r.stdout.strip() == '', 'printed a result without a chip'
+
+
+def test_last_line_is_the_result_the_driver_parses(monkeypatch, capsys):
+    """main() with the phases stubbed out: whatever the phases print, the
+    last stdout line is one JSON object with exactly `ok` and `device`,
+    the device exactly `platform`, `kind` (text), `count` (an int)."""
+    import json
+    device = {'platform': 'tpu', 'kind': 'TPU v5 lite', 'count': 1}
+    monkeypatch.setattr(chip_smoke, 'device_report', lambda: device)
+    ran = []
+    for phase in ('train_transformer', 'train_resnet50', 'kernels', 'serve',
+                  'multichip'):
+        monkeypatch.setattr(chip_smoke, phase,
+                            lambda size, phase=phase: ran.append(phase))
+    chip_smoke.main()
+    assert ran == ['train_transformer', 'train_resnet50', 'kernels', 'serve']
+    lines = capsys.readouterr().out.splitlines()
+    assert 'multichip: not run (1 device)' in lines
+    last = json.loads(lines[-1])
+    assert set(last) == {'ok', 'device'} and last['ok'] is True
+    assert set(last['device']) == {'platform', 'kind', 'count'}
+    assert last['device'] == device and type(last['device']['count']) is int
