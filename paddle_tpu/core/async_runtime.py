@@ -73,11 +73,10 @@ def default_nan_poll():
 
 
 @contextlib.contextmanager
-def host_block(reason, extra_counter=None, **args):
+def host_block(reason, **args):
     """Meter a forced host<->device sync.
 
-    Every second spent inside lands in ``executor.host_blocked_s`` (plus
-    ``extra_counter`` when a site keeps a legacy per-site counter) and a
+    Every second spent inside lands in ``executor.host_blocked_s`` and a
     ``host_block`` span tagged with the reason — verdict polls, future
     reads, checkpoint snapshots all become visible, attributable time."""
     if not _obs.enabled():
@@ -89,8 +88,6 @@ def host_block(reason, extra_counter=None, **args):
     finally:
         t1 = time.perf_counter()
         _obs.metrics.counter('executor.host_blocked_s').inc(t1 - t0)
-        if extra_counter:
-            _obs.metrics.counter(extra_counter).inc(t1 - t0)
         _obs.tracing.add_span('host_block', t0, t1, cat='launch',
                               args=dict(args, reason=reason))
 

@@ -459,6 +459,7 @@ class EmitEngine(object):
     def run_op(self, op, op_index, env, ectx):
         """Emit one op into `env` under the outer trace (called from
         executor._exec_ops_plain in place of kernel tracing)."""
+        import jax
         import jax.numpy as jnp
         import jax.lax as lax
         dmask = self._dmasks.get(id(op))
@@ -480,7 +481,10 @@ class EmitEngine(object):
         t0 = time.perf_counter()
         fn = _memo_fn(op, ins, getattr(ectx, 'amp', False), dmask,
                       ectx.mesh)
-        outs = fn(ins, ectx.base_key, streams)
+        # one scope per Fluid op type: an operation's op_name in a
+        # device trace says which op of the Program it came from
+        with jax.named_scope(op.type):
+            outs = fn(ins, ectx.base_key, streams)
         self._build_s += time.perf_counter() - t0
         for slot, names in op.outputs.items():
             if slot not in outs:

@@ -1100,9 +1100,7 @@ class Executor(object):
         if as_futures:
             return [_async.FetchFuture(s) for s in stacked]
         if return_numpy:
-            with _async.host_block('tail_split_sync',
-                                   extra_counter='executor.fetch_sync_s',
-                                   steps=steps):
+            with _async.host_block('tail_split_sync', steps=steps):
                 return [np.asarray(s) for s in stacked]
         return stacked
 
@@ -1405,10 +1403,12 @@ class Executor(object):
         fetch_names = tuple(self._resolve_fetch(fetch_list))
 
         # telemetry: ONE flag check per launch; when off, the hot path
-        # below does no telemetry work (no spans, no counters, no dicts)
+        # below does no telemetry work (its span() handles do nothing:
+        # no annotation, no event, no counters, no dicts)
         obs_on = _obs.enabled()
         if obs_on:
-            _obs.on_launch_start(self, time.perf_counter())
+            t_run0 = time.perf_counter()
+            _obs.on_launch_start(self, t_run0)
 
         # rng/shard-layout bookkeeping stays scope-local (unlike the
         # executable): parallel scopes keep independent RNG streams.
@@ -1445,40 +1445,41 @@ class Executor(object):
 
         if obs_on:
             tc0 = _TRACE_COUNT[0]
-            t_d0 = time.perf_counter()
         feeds = {n: feed_vals[n] for n in feed_names}
         ctr = np.uint32(counter & 0xffffffff)
-        try:
-            result = entry.call(params, feeds, ctr)
-        except TypeError:
-            # an input spec drifted under an AOT executable (scope param
-            # swapped to a new dtype/sharding): the artifact cannot
-            # re-specialize, so drop this entry to the lazily-retracing
-            # jit fallback — the explainer names the retrace below
-            if entry.call is entry.jit_fn:
-                raise
-            entry.call = entry.jit_fn
-            result = entry.call(params, feeds, ctr)
-        if obs_on:
-            t_d1 = time.perf_counter()
-            _obs.metrics.counter('executor.launches').inc()
-            if _TRACE_COUNT[0] > tc0:
+        with _obs.span('executor.dispatch', cat='launch') as dispatch:
+            try:
+                result = entry.call(params, feeds, ctr)
+            except TypeError:
+                # an input spec drifted under an AOT executable (scope
+                # param swapped to a new dtype/sharding): the artifact
+                # cannot re-specialize, so drop this entry to the lazily-
+                # retracing jit fallback — the explainer names the
+                # retrace below
+                if entry.call is entry.jit_fn:
+                    raise
+                entry.call = entry.jit_fn
+                result = entry.call(params, feeds, ctr)
+            if obs_on:
+                dispatch.args.update(self._obs_tags, steps=steps)
+            if obs_on and _TRACE_COUNT[0] > tc0:
                 # only the jit-fallback / cache-bypass paths trace at call
-                # time; cached-path traces happen inside _resolve_entry
+                # time; cached-path traces happen inside _resolve_entry.
+                # The span is recorded under the name of what it turned
+                # out to be (its profiler annotation stays `dispatch`)
                 sig = _launch_signature(program, feed_vals, feed_names,
                                         fetch_names, steps, self.check_nan,
                                         scope)
-                report = _obs.explainer().observe(sig, compile_s=t_d1 - t_d0)
-                _obs.tracing.add_span(
-                    'executor.trace_compile', t_d0, t_d1, cat='compile',
-                    args=dict(self._obs_tags, steps=steps,
-                              kind=report['kind'],
-                              cause='; '.join(report['details'])[:512]
-                              or None))
-            else:
-                _obs.tracing.add_span(
-                    'executor.dispatch', t_d0, t_d1, cat='launch',
-                    args=dict(self._obs_tags, steps=steps) or None)
+                report = _obs.explainer().observe(
+                    sig, compile_s=time.perf_counter() - dispatch.t0)
+                dispatch.name, dispatch.cat = ('executor.trace_compile',
+                                               'compile')
+                dispatch.args.update(
+                    kind=report['kind'],
+                    cause='; '.join(report['details'])[:512] or None)
+        if obs_on:
+            _obs.metrics.counter('executor.launches').inc()
+            _obs.metrics.counter('executor.steps').inc(steps or 1)
         fetches, updates = result[0], result[1]
         # write back BEFORE the nan check: params were donated, so the old
         # scope arrays are dead — raising first would leave the scope
@@ -1519,18 +1520,13 @@ class Executor(object):
             # the host-sync point of the launch: converting fetches blocks
             # on the device — its duration is how long the async pipeline
             # made the host wait (near-zero in steady state)
-            t_f0 = time.perf_counter() if obs_on else None
-            fetches = [np.asarray(f) for f in fetches]
+            with _obs.span('executor.fetch_sync', cat='launch') as sync:
+                fetches = [np.asarray(f) for f in fetches]
             if obs_on:
-                t_f1 = time.perf_counter()
-                _obs.metrics.counter('executor.fetch_sync_s').inc(
-                    t_f1 - t_f0)
                 _obs.metrics.counter('executor.host_blocked_s').inc(
-                    t_f1 - t_f0)
+                    sync.seconds)
                 _obs.metrics.histogram('executor.fetch_sync_ms').observe(
-                    (t_f1 - t_f0) * 1000.0)
-                _obs.tracing.add_span('executor.fetch_sync', t_f0, t_f1,
-                                      cat='launch')
+                    sync.seconds * 1000.0)
         if obs_on:
             # drop the donated input refs NOW, inside the launch window: on
             # the CPU backend freeing a donated buffer blocks until its
@@ -1546,6 +1542,7 @@ class Executor(object):
                                       cat='launch')
             _obs.memory.on_launch()
             _obs.on_launch_end(self, t_w1)
+            _obs.metrics.counter('executor.run_s').inc(t_w1 - t_run0)
         return fetches
 
     def _raise_non_finite(self, fetch_names, fetches, updates, window):

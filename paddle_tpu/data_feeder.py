@@ -282,16 +282,16 @@ class FeedPrefetcher(object):
                 raise ValueError('per-step feeds disagree on keys: %s vs %s'
                                  % (sorted(names), sorted(f)))
         obs_on = _obs.enabled()
-        t0 = time.perf_counter() if obs_on else None
         overlapped = obs_on and not self._consumer_waiting
-        stacked = {k: np.stack([np.asarray(f[k]) for f in buf])
-                   for k in buf[0]}
-        if self._to_device:
-            import jax
-            stacked = jax.device_put(stacked)
+        with _obs.span('prefetch.pack', cat='prefetch', steps=len(buf),
+                       overlapped=overlapped) as pack:
+            stacked = {k: np.stack([np.asarray(f[k]) for f in buf])
+                       for k in buf[0]}
+            if self._to_device:
+                import jax
+                stacked = jax.device_put(stacked)
         if obs_on:
-            t1 = time.perf_counter()
-            dt = t1 - t0
+            dt = pack.seconds
             _obs.metrics.counter('prefetch.superbatches').inc()
             _obs.metrics.counter('prefetch.upload_s').inc(dt)
             self._upload_s += dt
@@ -304,11 +304,7 @@ class FeedPrefetcher(object):
                 self._overlap_s += dt
             _obs.metrics.gauge('prefetch.upload_overlap_ratio').set(
                 self._overlap_s / self._upload_s if self._upload_s else 0.0)
-            _obs.tracing.add_span('prefetch.pack', t0, t1,
-                                  cat='prefetch',
-                                  args={'steps': len(buf),
-                                        'overlapped': overlapped})
-            return (stacked, len(buf)), (t0, t1)
+            return (stacked, len(buf)), (pack.t0, pack.t1)
         return (stacked, len(buf)), None
 
     def _put(self, item):

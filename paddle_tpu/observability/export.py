@@ -108,7 +108,6 @@ SCHEMA = {
         ('opt_ops_fused', ('int', 'opt.ops_fused')),
         ('stall_count', ('delta_int', 'executor.stall_count')),
         ('prefetch_starvation_s', ('sec', 'prefetch.starvation_s')),
-        ('fetch_sync_s', ('sec', 'executor.fetch_sync_s')),
         ('kernel_fallbacks', ('int', 'kernel.fallbacks')),
         ('emitter_fallbacks', ('int', 'emitter.fallbacks')),
         ('kernelgen_ops', ('int', 'kernelgen.ops')),

@@ -10,8 +10,14 @@ Timestamps are microseconds relative to a per-process perf_counter
 epoch, so `ts` is monotonic and durations are wall-accurate; events are
 sorted by `ts` at export time (completion order != start order for
 nested spans).
+
+`span(name)` is on two timelines at once: it records into the recorder
+AND enters a `jax.profiler.TraceAnnotation('pt:' + name)`, so an xplane
+taken by anyone (`paddle_tpu.profiler`, a benchmark's own
+`jax.profiler.start_trace`) holds the program's spans on the host
+plane, on the profiler's clock, beside the device's `XLA Ops`
+(observability/timeline.py reads them back).
 """
-import contextlib
 import json
 import os
 import threading
@@ -168,18 +174,57 @@ def recorder():
     return _RECORDER
 
 
-@contextlib.contextmanager
-def span(name, cat='runtime', **args):
-    """Record a complete event around the with-block (no-op when disabled)."""
-    if not enabled():
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        _RECORDER.add_complete(name, t0, time.perf_counter(), cat,
-                               args or None)
+ANNOTATION_PREFIX = 'pt:'
+_ANNOTATION = [None]     # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _annotation(name):
+    cls = _ANNOTATION[0]
+    if cls is None:
+        from jax.profiler import TraceAnnotation as cls
+        _ANNOTATION[0] = cls
+    return cls(ANNOTATION_PREFIX + name)
+
+
+class span(object):
+    """Record a complete event around the with-block AND annotate the
+    profiler's host timeline with ``pt:<name>`` for its duration (a
+    no-op when telemetry is disabled).
+
+    ``with span(...) as sp`` hands the block its own handle: ``sp.args``
+    may gain keys and ``sp.name`` may change before the block ends (the
+    recorder takes both at exit; the annotation keeps the name it was
+    entered with), and after the block ``sp.seconds`` is the duration on
+    the span's clock, so a counter fed from it agrees with the span."""
+    __slots__ = ('name', 'cat', 'args', 't0', 't1', '_ann')
+
+    def __init__(self, name, cat='runtime', **args):
+        self.name = name
+        self.cat = cat
+        self.args = args
+        self.t0 = self.t1 = None
+        self._ann = None
+
+    def __enter__(self):
+        if enabled():
+            self._ann = _annotation(self.name)
+            self._ann.__enter__()
+            self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self.t0 is None:
+            return False
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
+        _RECORDER.add_complete(self.name, self.t0, self.t1, self.cat,
+                               self.args or None)
+        return False
+
+    @property
+    def seconds(self):
+        """Duration of the finished block; 0.0 when telemetry was off."""
+        return 0.0 if self.t1 is None else self.t1 - self.t0
 
 
 def add_span(name, start_pc, end_pc, cat='runtime', args=None):

@@ -480,10 +480,18 @@ class ServingEngine(object):
                                 'oldest queued one (shed_oldest policy)')
         return req.future
 
-    def _emit_root_span(self, trace, t_pc, status, reason=None, rows=None):
+    def _emit_request_spans(self, req, status, reason):
+        """What one request leaves in the trace at its terminal reply: the
+        root span (a subclass adds the phases that tile it)."""
+        self._emit_root_span(req.trace, req.t_pc, status, reason=reason,
+                             rows=req.rows)
+
+    def _emit_root_span(self, trace, t_pc, status, reason=None, rows=None,
+                        t_end=None):
         """The request's single root span, `serving.request` — emitted
         exactly once, at terminal resolution, so its status IS the
-        terminal reply's status."""
+        terminal reply's status.  `t_end` (perf_counter) lets the caller
+        end it on the reading its last child ended on."""
         if trace is None or t_pc is None:
             return
         args = trace.span_args(status=status)
@@ -492,7 +500,8 @@ class ServingEngine(object):
         if rows is not None:
             args['rows'] = int(rows)
         _obs.tracing.recorder().add_complete(
-            'serving.request', t_pc, time.perf_counter(), cat='serving',
+            'serving.request', t_pc,
+            time.perf_counter() if t_end is None else t_end, cat='serving',
             args=args)
 
     def _rejected(self, t_submit, reason, message, trace=None, t_pc=None):
@@ -740,8 +749,7 @@ class ServingEngine(object):
         with self._out_lock:
             self._outstanding.discard(req)
         # exactly one root span per request, status = the terminal reply
-        self._emit_root_span(req.trace, req.t_pc, status, reason=reason,
-                             rows=req.rows)
+        self._emit_request_spans(req, status, reason)
         if status == OK:
             _obs.metrics.counter('serving.completed').inc()
             _obs.metrics.histogram('serving.latency_ms').observe(

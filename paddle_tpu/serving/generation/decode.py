@@ -193,6 +193,18 @@ def _logical_rows(st, bt, i, cache):
     return assemble(st['k'], None), assemble(st['v'], None)
 
 
+def _gathered_rows(cache, st, bt):
+    """Rows of K (or of V) per layer that one step gathers for a window
+    executable built over the structs ``st`` and ``bt``: batch x
+    positions of what `_logical_rows` returns for them (shapes only,
+    nothing runs).  A step that gathers live pages only, or takes a
+    narrower table, changes this count with its executable."""
+    import jax
+    k, _v = jax.eval_shape(lambda s, b: _logical_rows(s, b, 0, cache),
+                           st, bt)
+    return int(k.shape[0]) * int(k.shape[2])
+
+
 def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
     """Build the one-chunk (or one-shot ring) prefill function.
 
@@ -216,38 +228,51 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
 
     def prefill(w, st, bt_row, tokens, slot, offset, true_count,
                 seed, temperature, top_k):
+        import jax
+        scope = jax.named_scope
         pos = (offset + jnp.arange(chunk))[None]          # [1, C]
         p_abs = offset + jnp.arange(chunk)                # [C]
         valid = jnp.arange(chunk) < true_count
         pg = jnp.where(valid,
                        bt_row[jnp.clip(p_abs // PL, 0, M - 1)], 0)
         rw = p_abs % PL
-        x = w['tok_emb'][tokens][None]                    # [1, C, D]
+        with scope('embed'):
+            x = w['tok_emb'][tokens][None]                # [1, C, D]
         for i in range(L):
-            h = _rms(x, w['layer_%d_att_norm' % i])
-            q, k, v = _qkv(w, cfg, h, i)
-            q = _rope_at(q, pos, theta)
-            k = _rope_at(k, pos, theta)
-            st = _write_rows(st, i, pg, rw, k[0].transpose(1, 0, 2),
-                             v[0].transpose(1, 0, 2), quant)
-            if ring_mesh is not None:
-                # one-shot long-context prefill (offset == 0): the exact
-                # ppermute ring over the whole prompt
-                att = ring_attention(q, k, v, ring_mesh, causal=True)
-            else:
-                kl, vl = _logical_rows(st, bt_row[None], i, cache)
-                att = cached_attention(q, kl, vl, pos)
-            B, H, T = att.shape[0], att.shape[1], att.shape[2]
-            att = att.transpose(0, 2, 1, 3).reshape(B, T, H * dh)
-            x = x + att @ w['layer_%d_att_o_w' % i]
-            x = _ffn(w, x, i)
-        import jax
-        x = _rms(x, w['final_norm'])
-        last = jax.lax.dynamic_slice_in_dim(x[0], true_count - 1, 1)[0]
-        logits = last @ w['lm_proj_w']                    # [V] f32
+            # ONE scope name for every layer: an operation's op_name
+            # says which part of the block it is, whatever its index
+            with scope('layer'):
+                with scope('attn.qkv'):
+                    h = _rms(x, w['layer_%d_att_norm' % i])
+                    q, k, v = _qkv(w, cfg, h, i)
+                    q = _rope_at(q, pos, theta)
+                    k = _rope_at(k, pos, theta)
+                with scope('kv.write'):
+                    st = _write_rows(st, i, pg, rw, k[0].transpose(1, 0, 2),
+                                     v[0].transpose(1, 0, 2), quant)
+                if ring_mesh is None:
+                    with scope('kv.gather'):
+                        kl, vl = _logical_rows(st, bt_row[None], i, cache)
+                with scope('attn.scores'):
+                    if ring_mesh is not None:
+                        # one-shot long-context prefill (offset == 0):
+                        # the exact ppermute ring over the whole prompt
+                        att = ring_attention(q, k, v, ring_mesh, causal=True)
+                    else:
+                        att = cached_attention(q, kl, vl, pos)
+                    B, H, T = att.shape[0], att.shape[1], att.shape[2]
+                    att = att.transpose(0, 2, 1, 3).reshape(B, T, H * dh)
+                    x = x + att @ w['layer_%d_att_o_w' % i]
+                with scope('ffn'):
+                    x = _ffn(w, x, i)
+        with scope('lm_head'):
+            x = _rms(x, w['final_norm'])
+            last = jax.lax.dynamic_slice_in_dim(x[0], true_count - 1, 1)[0]
+            logits = last @ w['lm_proj_w']                # [V] f32
         new_len = offset + true_count
-        nxt = sample_logits(logits, token_key(seed, new_len),
-                            temperature, top_k)
+        with scope('sample'):
+            nxt = sample_logits(logits, token_key(seed, new_len),
+                                temperature, top_k)
         st = dict(st)
         st['lengths'] = st['lengths'].at[slot].set(new_len)
         st['tok'] = st['tok'].at[slot].set(nxt)
@@ -270,28 +295,39 @@ def _step_fn(cfg, cache):
     quant = cache.quant == 'int8'
 
     def step(w, st, bt, fed, active, seeds, temps, topks):
+        import jax
+        scope = jax.named_scope
         S = bt.shape[0]
         pos = st['lengths']                               # [S] write pos
         pg = bt[jnp.arange(S), jnp.clip(pos // PL, 0, M - 1)]
         pg = jnp.where(active, pg, 0)
         rw = pos % PL
-        x = w['tok_emb'][fed][:, None, :]                 # [S, 1, D]
+        with scope('embed'):
+            x = w['tok_emb'][fed][:, None, :]             # [S, 1, D]
         for i in range(L):
-            h = _rms(x, w['layer_%d_att_norm' % i])
-            q, k, v = _qkv(w, cfg, h, i)
-            q = _rope_at(q, pos[:, None], theta)
-            k = _rope_at(k, pos[:, None], theta)
-            st = _write_rows(st, i, pg, rw, k[:, :, 0, :], v[:, :, 0, :],
-                             quant)
-            kl, vl = _logical_rows(st, bt, i, cache)
-            att = cached_attention(q, kl, vl, pos[:, None])
-            H = att.shape[1]
-            att = att.transpose(0, 2, 1, 3).reshape(S, 1, H * dh)
-            x = x + att @ w['layer_%d_att_o_w' % i]
-            x = _ffn(w, x, i)
-        x = _rms(x, w['final_norm'])
-        logits = x[:, 0] @ w['lm_proj_w']                 # [S, V]
-        nxt = sample_tokens_at(logits, seeds, pos + 1, temps, topks)
+            with scope('layer'):     # one name for every layer (prefill)
+                with scope('attn.qkv'):
+                    h = _rms(x, w['layer_%d_att_norm' % i])
+                    q, k, v = _qkv(w, cfg, h, i)
+                    q = _rope_at(q, pos[:, None], theta)
+                    k = _rope_at(k, pos[:, None], theta)
+                with scope('kv.write'):
+                    st = _write_rows(st, i, pg, rw, k[:, :, 0, :],
+                                     v[:, :, 0, :], quant)
+                with scope('kv.gather'):
+                    kl, vl = _logical_rows(st, bt, i, cache)
+                with scope('attn.scores'):
+                    att = cached_attention(q, kl, vl, pos[:, None])
+                    H = att.shape[1]
+                    att = att.transpose(0, 2, 1, 3).reshape(S, 1, H * dh)
+                    x = x + att @ w['layer_%d_att_o_w' % i]
+                with scope('ffn'):
+                    x = _ffn(w, x, i)
+        with scope('lm_head'):
+            x = _rms(x, w['final_norm'])
+            logits = x[:, 0] @ w['lm_proj_w']             # [S, V]
+        with scope('sample'):
+            nxt = sample_tokens_at(logits, seeds, pos + 1, temps, topks)
         st = dict(st)
         st['tok'] = jnp.where(active, nxt, st['tok'])
         st['lengths'] = jnp.where(active, pos + 1, pos)
@@ -447,6 +483,7 @@ class DecodeRuntime(object):
         self.ring_min_len = (int(ring_min_len) if ring_min_len is not None
                              else 2 * self.prefill_chunk)
         self._execs = {}
+        self._window_rows = {}   # (kind, steps) -> KV rows a launch gathers
         self._lock = threading.Lock()
         _obs.metrics.gauge('generation.kv_cache_bytes').set(
             self.cache.bytes())
@@ -648,6 +685,9 @@ class DecodeRuntime(object):
                                chunk), build)
 
     def _window_exec(self, kind, steps):
+        """(executable, rows of K or V per layer one launch of it
+        gathers): the count is taken from the same argument structs the
+        executable is built for (`generation.kv_rows_read`)."""
         import jax
 
         def build():
@@ -667,7 +707,14 @@ class DecodeRuntime(object):
                      vec(jax.numpy.float32), vec(jax.numpy.int32)]
             return jitted, args
 
-        return self._compiled((kind, steps), build)
+        key = (kind, steps)
+        call = self._compiled(key, build)
+        rows = self._window_rows.get(key)
+        if rows is None:
+            rows = self._window_rows[key] = steps * _gathered_rows(
+                self.cache, self._state_structs(),
+                self._bt_struct(self.cache.slots))
+        return call, rows
 
     def _decode_exec(self, steps):
         return self._window_exec('decode', steps)
@@ -693,7 +740,6 @@ class DecodeRuntime(object):
         (next_token, logits) — meaningful only on the final chunk.
         ``params`` is a SamplingParams.  The slot's block table must
         already cover the chunk (`try_begin`/`ensure_capacity`)."""
-        import jax.numpy as jnp
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         n = tokens.shape[0]
         if not 0 < n <= self.prefill_chunk:
@@ -701,18 +747,44 @@ class DecodeRuntime(object):
                              'prefill executable' % (n, self.prefill_chunk))
         if offset + n > self.cache.max_len:
             raise ValueError('prefill past max_len=%d' % self.cache.max_len)
-        buf = np.zeros(self.prefill_chunk, np.int32)
-        buf[:n] = tokens
-        call = self._prefill_exec(self.prefill_chunk)
-        st, nxt, logits = call(
-            self.w, self.state, jnp.asarray(self.block_tables[slot]),
-            jnp.asarray(buf), jnp.int32(slot), jnp.int32(offset),
-            jnp.int32(n), jnp.int32(params.seed),
-            jnp.float32(params.temperature), jnp.int32(params.top_k))
-        self.state = st
+        return self._launch_prefill(self._prefill_exec(self.prefill_chunk),
+                                    self.prefill_chunk, slot, tokens, offset,
+                                    params, ring=False)
+
+    def _launch_prefill(self, call, width, slot, tokens, offset, params,
+                        ring):
+        """Pad ``tokens`` to the executable's ``width``, upload, launch,
+        fetch the sample: one `decode.prefill` span with `upload` /
+        `dispatch` / `fetch` children, and the prefill counters (time,
+        time blocked in the fetch, real and padding tokens)."""
+        import jax.numpy as jnp
+        n = tokens.shape[0]
+        with _obs.span('decode.prefill', cat='decode', slot=int(slot),
+                       tokens=int(n), ring=ring) as sp:
+            with _obs.span('decode.prefill.upload', cat='decode'):
+                buf = np.zeros(width, np.int32)
+                buf[:n] = tokens
+                args = (jnp.asarray(self.block_tables[slot]),
+                        jnp.asarray(buf), jnp.int32(slot),
+                        jnp.int32(offset), jnp.int32(n),
+                        jnp.int32(params.seed),
+                        jnp.float32(params.temperature),
+                        jnp.int32(params.top_k))
+            with _obs.span('decode.prefill.dispatch', cat='decode'):
+                st, nxt, logits = call(self.w, self.state, *args)
+                self.state = st
+            with _obs.span('decode.prefill.fetch', cat='decode') as fetch:
+                nxt = int(nxt)
+                logits = np.asarray(logits)
         self.host_len[slot] = offset + n
-        self.host_tok[slot] = int(nxt)
-        return int(nxt), np.asarray(logits)
+        self.host_tok[slot] = nxt
+        if _obs.enabled():
+            counter = _obs.metrics.counter
+            counter('generation.prefill_s').inc(sp.seconds)
+            counter('generation.prefill_fetch_s').inc(fetch.seconds)
+            counter('generation.prefill_tokens').inc(n)
+            counter('generation.prefill_pad_tokens').inc(width - n)
+        return nxt, logits
 
     def ring_pad(self, n):
         """Padded one-shot ring prefill width for an n-token prompt:
@@ -724,7 +796,6 @@ class DecodeRuntime(object):
     def prefill_ring(self, slot, prompt, params):
         """One-shot long-context prefill through ring attention: the
         whole (padded) prompt in a single launch.  Requires ``mesh``."""
-        import jax.numpy as jnp
         if self.mesh is None:
             raise ValueError('ring prefill needs a mesh with a seq axis')
         prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -733,18 +804,9 @@ class DecodeRuntime(object):
         if n > width:
             raise ValueError('prompt of %d exceeds max_len=%d'
                              % (n, self.cache.max_len))
-        buf = np.zeros(width, np.int32)
-        buf[:n] = prompt
-        call = self._prefill_exec(width, ring=True)
-        st, nxt, logits = call(
-            self.w, self.state, jnp.asarray(self.block_tables[slot]),
-            jnp.asarray(buf), jnp.int32(slot), jnp.int32(0),
-            jnp.int32(n), jnp.int32(params.seed),
-            jnp.float32(params.temperature), jnp.int32(params.top_k))
-        self.state = st
-        self.host_len[slot] = n
-        self.host_tok[slot] = int(nxt)
-        return int(nxt), np.asarray(logits)
+        return self._launch_prefill(self._prefill_exec(width, ring=True),
+                                    width, slot, prompt, 0, params,
+                                    ring=True)
 
     # --------------------------------------------------------- decode
     def _vecs(self, active, seeds, temps, topks):
@@ -755,19 +817,51 @@ class DecodeRuntime(object):
                 jnp.asarray(np.asarray(temps, np.float32).reshape(S)),
                 jnp.asarray(np.asarray(topks, np.int32).reshape(S)))
 
+    def _launch_window(self, kind, steps, fed, active, seeds, temps, topks):
+        """Upload the per-slot vectors, launch one K-step window
+        executable, fetch its [slots, steps] samples: one `decode.window`
+        span with `upload` / `dispatch` / `fetch` children, and the
+        window counters (time, time blocked in the fetch, slot-steps run
+        and live, KV positions live streams attended and rows read)."""
+        import jax.numpy as jnp
+        steps = int(steps)
+        call, rows_read = self._window_exec(kind, steps)
+        act = np.asarray(active, bool).reshape(self.cache.slots)
+        with _obs.span('decode.window', cat='decode', kind=kind,
+                       steps=steps) as sp:
+            with _obs.span('decode.window.upload', cat='decode'):
+                args = [jnp.asarray(self.block_tables)]
+                if fed is not None:
+                    args.append(jnp.asarray(fed.T))
+                args.extend(self._vecs(act, seeds, temps, topks))
+            with _obs.span('decode.window.dispatch', cat='decode'):
+                st, toks = call(self.w, self.state, *args)
+                self.state = st
+            with _obs.span('decode.window.fetch', cat='decode') as fetch:
+                out = np.asarray(toks)
+        if _obs.enabled():
+            live = int(act.sum())
+            counter = _obs.metrics.counter
+            counter('generation.window_s').inc(sp.seconds)
+            counter('generation.window_fetch_s').inc(fetch.seconds)
+            counter('generation.decode_slot_steps').inc(
+                self.cache.slots * steps)
+            counter('generation.decode_live_slot_steps').inc(live * steps)
+            # step j of a live stream attends its len + j + 1 positions
+            counter('generation.kv_tokens_live').inc(
+                steps * int(self.host_len[act].sum(dtype=np.int64))
+                + live * steps * (steps + 1) // 2)
+            counter('generation.kv_rows_read').inc(rows_read)
+        return act, out
+
     def decode_window(self, steps, active, seeds, temps, topks):
         """Advance every ACTIVE slot ``steps`` tokens in one fused
         launch.  active/seeds/temps/topks are per-slot vectors (plain
         data — they never retrace); so is the block table.  Returns the
         [slots, steps] token matrix; inactive rows are garbage by
         contract."""
-        import jax.numpy as jnp
-        call = self._decode_exec(int(steps))
-        act = np.asarray(active, bool).reshape(self.cache.slots)
-        st, toks = call(self.w, self.state, jnp.asarray(self.block_tables),
-                        *self._vecs(act, seeds, temps, topks))
-        self.state = st
-        out = np.asarray(toks)
+        act, out = self._launch_window('decode', steps, None, active, seeds,
+                                       temps, topks)
         self.host_len[act] = np.minimum(
             self.host_len[act] + int(steps), np.iinfo(np.int32).max)
         self.host_tok[act] = out[act, -1]
@@ -780,15 +874,10 @@ class DecodeRuntime(object):
         g_0..g_{K-1}.  Device lengths advance K for active slots — the
         caller MUST follow with `commit_speculation` (the host-side
         rollback) before any other launch."""
-        import jax.numpy as jnp
-        call = self._verify_exec(int(steps))
         fed = np.asarray(fed, np.int32).reshape(self.cache.slots,
                                                 int(steps))
-        st, toks = call(self.w, self.state, jnp.asarray(self.block_tables),
-                        jnp.asarray(fed.T),
-                        *self._vecs(active, seeds, temps, topks))
-        self.state = st
-        return np.asarray(toks)
+        return self._launch_window('verify', steps, fed, active, seeds,
+                                   temps, topks)[1]
 
     def commit_speculation(self, accepted):
         """Roll the post-verify state back to the accepted prefix.

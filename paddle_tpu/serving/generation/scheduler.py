@@ -46,6 +46,16 @@ wait, overall deadline, TTFT or ITL budget), ``shed`` (cancel, drain),
 Token-level SLOs: ``serving.ttft_ms`` observes submit→first-token per
 request, ``serving.itl_ms`` the amortized inter-token gap; both export
 through telemetry_snapshot('serving') (docs/generation.md).
+
+Measured from inside (docs/observability.md): every request's life is
+three spans that tile its ``serving.request`` and share its trace id —
+``serving.queue`` (generate() → slot granted), ``serving.prefill_phase``
+(→ first token) and ``serving.decode_phase`` (→ terminal reply) — and
+every scheduler round is a ``serving.round`` span with ``serving.admit``
+/ ``serving.prefill`` / ``serving.decode_step`` / ``serving.emit``
+children; waiting with nothing to do is ``serving.idle_wait``.  Each
+boundary also feeds a ``generation.*`` time or work counter, taken on
+the span's own clock.
 """
 import time
 
@@ -61,7 +71,6 @@ from .sampling import SamplingParams
 from .streaming import TokenStream
 
 __all__ = ['GenerationConfig', 'GenerationEngine']
-
 
 class GenerationConfig(object):
     """Generation-side knobs (the queue/rate/breaker knobs stay on
@@ -95,7 +104,8 @@ class GenerationConfig(object):
 class _GenRequest(_Request):
     __slots__ = ('prompt', 'max_new', 'params', 'ttft_timeout',
                  'itl_timeout', 'slot', 'offset', 'produced',
-                 't_last_token')
+                 't_last_token', 't_grant', 't_first', 'round_grant',
+                 'rounds_first', 'chunks', 'skipped', 'windows')
 
     def __init__(self, prompt, max_new, params, deadline, t_submit,
                  ttft_timeout=None, itl_timeout=None, trace=None,
@@ -115,6 +125,16 @@ class _GenRequest(_Request):
         self.offset = 0          # prompt tokens prefilled so far
         self.produced = 0        # tokens streamed so far
         self.t_last_token = None
+        # phase marks on the recorder's clock (perf_counter; None until
+        # reached, and always None with PT_OBS=0) and what the phase
+        # spans carry as args
+        self.t_grant = None      # slot granted: queue -> prefill phase
+        self.t_first = None      # first token: prefill -> decode phase
+        self.round_grant = 0     # scheduler round of the grant
+        self.rounds_first = 0    # rounds from the grant to the first token
+        self.chunks = 0          # prefill chunks (or ring shots) run
+        self.skipped = 0         # prompt tokens the prefix cache skipped
+        self.windows = 0         # decode windows this stream rode
 
 
 class GenerationEngine(ServingEngine):
@@ -139,6 +159,7 @@ class GenerationEngine(ServingEngine):
         self.runtime = runtime
         self._gen = gen_config or GenerationConfig()
         self._active = []        # slot-holding requests, admission order
+        self._round_no = 0       # rounds with work so far
 
     @staticmethod
     def _no_backend(feed):
@@ -260,10 +281,42 @@ class GenerationEngine(ServingEngine):
     def _round(self):
         """One scheduler round; False means the loop should exit."""
         with self._cond:
-            while not self._queue and not self._active:
-                if self._stopping or self._state == DRAINING:
+            if not self._queue and not self._active:
+                # nobody asked: the chip is idle for want of requests
+                alive = True
+                idle = _obs.span('serving.idle_wait', cat='serving')
+                with idle:
+                    while not self._queue and not self._active:
+                        if self._stopping or self._state == DRAINING:
+                            alive = False
+                            break
+                        self._cond.wait(0.05)
+                if _obs.enabled():
+                    _obs.metrics.counter('generation.idle_wait_s').inc(
+                        idle.seconds)
+                if not alive:
                     return False
-                self._cond.wait(0.05)
+            if self._stopping:
+                return False
+        self._round_no += 1
+        work = _obs.span('serving.round', cat='serving')
+        with work:
+            with _obs.span('serving.admit', cat='serving'):
+                if not self._admit_round():
+                    return False
+                self._sweep_active()
+            did_prefill = self._prefill_step()
+            did_decode = self._decode_step()
+        if _obs.enabled():
+            _obs.metrics.counter('generation.round_s').inc(work.seconds)
+        if did_prefill and did_decode:
+            _obs.metrics.counter('generation.mixed_dispatches').inc()
+        return True
+
+    def _admit_round(self):
+        """Drop expired/cancelled queued requests and claim queued ones
+        into free slots WITH pages; False when the engine is stopping."""
+        with self._cond:
             if self._stopping:
                 return False
             now = self._clock()
@@ -296,6 +349,14 @@ class GenerationEngine(ServingEngine):
                 r.slot = slot
                 r.offset = int(start)   # prefix-cache hits skip ahead
                 self._active.append(r)
+                if r.t_pc is not None:
+                    # queue -> prefill phase, where the slot is granted
+                    r.t_grant = time.perf_counter()
+                    r.round_grant = self._round_no
+                    r.skipped = int(start)
+                    _obs.metrics.counter('generation.admitted').inc()
+                    _obs.metrics.counter('generation.queue_wait_s').inc(
+                        r.t_grant - r.t_pc)
             _obs.metrics.gauge('serving.queue_depth').set(len(self._queue))
             self._cond.notify_all()
         for r in expired:
@@ -306,11 +367,6 @@ class GenerationEngine(ServingEngine):
             _obs.metrics.counter('generation.cancelled').inc()
             self._resolve(r, SHED, reason='cancelled',
                           error='cancelled while queued')
-        self._sweep_active()
-        did_prefill = self._prefill_step()
-        did_decode = self._decode_step()
-        if did_prefill and did_decode:
-            _obs.metrics.counter('generation.mixed_dispatches').inc()
         return True
 
     def _sweep_active(self):
@@ -344,39 +400,43 @@ class GenerationEngine(ServingEngine):
         if not pre:
             return False
         r = min(pre, key=lambda x: x.t_submit)
-        t0 = time.perf_counter()
         use_ring = (rt.mesh is not None and r.offset == 0
                     and r.prompt.size >= rt.ring_min_len)
-        try:
-            if use_ring:
-                first, _logits = rt.prefill_ring(r.slot, r.prompt, r.params)
-                r.offset = int(r.prompt.size)
-            else:
-                chunk = r.prompt[r.offset:r.offset + rt.prefill_chunk]
-                first, _logits = rt.prefill(r.slot, chunk, r.offset,
-                                            r.params)
-                r.offset += int(chunk.size)
-        except BaseException as e:  # noqa: BLE001 - replied per request
-            self.breaker.record_failure()
-            _obs.metrics.counter('serving.batch_failures').inc()
-            _flight.record('serving.prefill_failure', error=repr(e)[:300])
-            self._retire(r, ERROR, error=e, reason='prefill')
-            _flight.maybe_dump('serving_prefill_failure')
-            return True
+        with _obs.span('serving.prefill', cat='serving') as sp:
+            if _obs.enabled():
+                sp.args.update(slot=int(r.slot), ring=bool(use_ring))
+                if r.trace is not None:
+                    sp.args.update(trace_id=r.trace.trace_id,
+                                   parent_span_id=r.trace.span_id)
+            try:
+                if use_ring:
+                    first, _logits = rt.prefill_ring(r.slot, r.prompt,
+                                                     r.params)
+                    r.offset = int(r.prompt.size)
+                else:
+                    chunk = r.prompt[r.offset:r.offset + rt.prefill_chunk]
+                    first, _logits = rt.prefill(r.slot, chunk, r.offset,
+                                                r.params)
+                    r.offset += int(chunk.size)
+            except BaseException as e:  # noqa: BLE001 - replied per request
+                self.breaker.record_failure()
+                _obs.metrics.counter('serving.batch_failures').inc()
+                _flight.record('serving.prefill_failure',
+                               error=repr(e)[:300])
+                self._retire(r, ERROR, error=e, reason='prefill')
+                _flight.maybe_dump('serving_prefill_failure')
+                return True
+            if _obs.enabled():
+                sp.args['offset'] = int(r.offset)
+        r.chunks += 1
         _obs.metrics.counter('generation.prefill_chunks').inc()
-        if r.trace is not None:
-            _obs.tracing.recorder().add_complete(
-                'serving.prefill', t0, time.perf_counter(), cat='serving',
-                args={'trace_id': r.trace.trace_id,
-                      'parent_span_id': r.trace.span_id,
-                      'slot': int(r.slot), 'offset': int(r.offset),
-                      'ring': bool(use_ring)})
         if r.offset >= r.prompt.size:
             # prompt complete: publish its full pages for later
             # prefix-sharing requests, then emit the final chunk's
             # sample — the first token (TTFT)
             self.runtime.promote_prefix(r.slot, r.prompt)
-            self._emit_tokens(r, [int(first)])
+            with _obs.span('serving.emit', cat='serving'):
+                self._emit_tokens(r, [int(first)])
         return True
 
     def _decode_step(self):
@@ -417,37 +477,39 @@ class GenerationEngine(ServingEngine):
             temps[r.slot] = r.params.temperature
             topks[r.slot] = r.params.top_k
         speculative = self._gen.speculative and K > 1
-        t0 = time.perf_counter()
-        try:
-            if _faults.any_active():
-                _faults.maybe_fail('decode_step')
-            if speculative:
-                emitted = self._verify_step(dec, K, active, seeds, temps,
-                                            topks)
-            else:
-                toks = rt.decode_window(K, active, seeds, temps, topks)
-                emitted = {id(r): [int(t) for t in toks[r.slot]]
-                           for r in dec}
-        except BaseException as e:  # noqa: BLE001 - replied per request
-            self.breaker.record_failure()
-            _obs.metrics.counter('serving.batch_failures').inc()
-            _flight.record('serving.decode_failure', error=repr(e)[:300],
-                           requests=len(dec), steps=int(K))
-            for r in dec:
-                self._retire(r, ERROR, error=e, reason='decode_step')
-            _flight.maybe_dump('serving_decode_failure')
-            return False
+        with _obs.span('serving.decode_step', cat='serving') as sp:
+            if _obs.enabled():
+                sp.args.update(
+                    steps=int(K), requests=len(dec),
+                    speculative=bool(speculative),
+                    links=[r.trace.trace_id for r in dec
+                           if r.trace is not None])
+            try:
+                if _faults.any_active():
+                    _faults.maybe_fail('decode_step')
+                if speculative:
+                    emitted = self._verify_step(dec, K, active, seeds,
+                                                temps, topks)
+                else:
+                    toks = rt.decode_window(K, active, seeds, temps, topks)
+                    emitted = {id(r): [int(t) for t in toks[r.slot]]
+                               for r in dec}
+            except BaseException as e:  # noqa: BLE001 - replied per request
+                self.breaker.record_failure()
+                _obs.metrics.counter('serving.batch_failures').inc()
+                _flight.record('serving.decode_failure',
+                               error=repr(e)[:300], requests=len(dec),
+                               steps=int(K))
+                for r in dec:
+                    self._retire(r, ERROR, error=e, reason='decode_step')
+                _flight.maybe_dump('serving_decode_failure')
+                return False
         self.breaker.record_success(cold=False)
         _obs.metrics.counter('generation.decode_windows').inc()
-        if _obs.enabled():
-            links = [r.trace.trace_id for r in dec if r.trace is not None]
-            _obs.tracing.recorder().add_complete(
-                'serving.decode_step', t0, time.perf_counter(),
-                cat='serving', args={'steps': int(K), 'requests': len(dec),
-                                     'speculative': bool(speculative),
-                                     'links': links})
-        for r in list(dec):
-            self._emit_tokens(r, emitted[id(r)])
+        with _obs.span('serving.emit', cat='serving'):
+            for r in list(dec):
+                r.windows += 1
+                self._emit_tokens(r, emitted[id(r)])
         return True
 
     def _verify_step(self, dec, K, active, seeds, temps, topks):
@@ -490,6 +552,15 @@ class GenerationEngine(ServingEngine):
         if first:
             _obs.metrics.histogram('serving.ttft_ms').observe(
                 max(0.0, (now - r.t_submit) * 1e3))
+            if r.t_grant is not None:
+                # prefill -> decode phase, where the first token leaves
+                r.t_first = time.perf_counter()
+                r.rounds_first = self._round_no - r.round_grant + 1
+                _obs.metrics.counter('generation.first_tokens').inc()
+                _obs.metrics.counter('generation.prefill_phase_s').inc(
+                    r.t_first - r.t_grant)
+                _obs.metrics.counter(
+                    'generation.rounds_to_first_token').inc(r.rounds_first)
         elif toks:
             # the fused window delivers K tokens at once: observe the
             # amortized per-token gap K times so the ITL histogram
@@ -503,11 +574,6 @@ class GenerationEngine(ServingEngine):
             r.future._push(tok)
             r.produced += 1
             _obs.metrics.counter('generation.tokens').inc()
-            if r.trace is not None:
-                _obs.tracing.instant(
-                    'serving.token', cat='serving',
-                    args={'trace_id': r.trace.trace_id,
-                          'index': int(r.produced)})
             if self._gen.eos_id is not None and tok == self._gen.eos_id:
                 finish = 'eos'
                 break
@@ -518,6 +584,36 @@ class GenerationEngine(ServingEngine):
         if finish is not None:
             ids = np.asarray(r.future.tokens_so_far(), np.int64)
             self._retire(r, OK, outputs=[ids], reason=finish)
+
+    def _emit_request_spans(self, req, status, reason):
+        """The phases a request reached, then its root: `serving.queue`,
+        `serving.prefill_phase` and `serving.decode_phase` share the
+        root's trace id, name it as their parent and tile it exactly
+        (the last phase and the root end on one clock reading)."""
+        if req.trace is None or req.t_pc is None:
+            return
+        t_end = time.perf_counter()
+        rec = _obs.tracing.recorder()
+        ids = {'trace_id': req.trace.trace_id,
+               'parent_span_id': req.trace.span_id}
+        t_grant = req.t_grant
+        rec.add_complete('serving.queue', req.t_pc,
+                         t_end if t_grant is None else t_grant,
+                         cat='serving', args=dict(ids))
+        if t_grant is not None:
+            t_first = req.t_first
+            rec.add_complete(
+                'serving.prefill_phase', t_grant,
+                t_end if t_first is None else t_first, cat='serving',
+                args=dict(ids, chunks=req.chunks, rounds=req.rounds_first,
+                          prefix_tokens_skipped=req.skipped))
+            if t_first is not None:
+                rec.add_complete(
+                    'serving.decode_phase', t_first, t_end, cat='serving',
+                    args=dict(ids, tokens=req.produced,
+                              windows=req.windows))
+        self._emit_root_span(req.trace, req.t_pc, status, reason=reason,
+                             rows=req.rows, t_end=t_end)
 
     def _retire(self, r, status, outputs=None, error=None, reason=None):
         """Terminal resolution for a slot-holding request: drop it from

@@ -12,10 +12,21 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+import pytest                                          # noqa: E402
+
 import chip_smoke                                      # noqa: E402
+import paddle_tpu.observability as obs                 # noqa: E402
 from paddle_tpu.core import compile_cache as cc        # noqa: E402
 
 TINY = chip_smoke.SIZES['tiny']
+
+
+@pytest.fixture(autouse=True)
+def _own_counters():
+    """The phases assert ABSOLUTE fallback counts, as on the chip where
+    the process is theirs alone: fallbacks another file's tests left in
+    this xdist worker's process are not theirs."""
+    obs.metrics.reset()
 
 
 def test_sizes_name_the_same_phases():

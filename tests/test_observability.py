@@ -80,6 +80,7 @@ def test_retrace_explainer_fused_steps_change():
 
 
 def test_chrome_trace_json_valid(tmp_path):
+    obs.tracing.reset()    # serving tests earlier in this process left flows
     main, startup, y, _ = _build()
     exe = fluid.Executor()
     scope = fluid.Scope()
@@ -147,17 +148,75 @@ def test_prefetch_starvation_gauge_fires_under_slow_reader():
     assert (c.get('prefetch.upload_s') or 0) > 0
 
 
-def test_disabled_mode_does_no_telemetry_work(monkeypatch):
-    """With telemetry disabled the executor hot path must not touch the
-    subsystem at all: every entry point is patched to raise, and the
-    recorder/registry must not grow — i.e. no per-launch telemetry
-    allocations beyond the constant `enabled()` branch."""
+def _disabled_run(exe, main, y):
+    return lambda: _run(exe, main, {'x': np.ones((2, 4), 'float32')}, [y])
+
+
+def _disabled_run_steps(exe, main, y):
+    feeds = [{'x': np.ones((2, 4), 'float32')} for _ in range(2)]
+    return lambda: exe.run_steps(main, feed_list=feeds, fetch_list=[y])
+
+
+def _disabled_prefetch(exe, main, y):
+    def once():
+        pf = fluid.FeedPrefetcher(
+            iter([{'x': np.ones((2, 4), 'float32')}] * 2), steps=2,
+            capacity=1, to_device=False)
+        for feed, k in pf:
+            exe.run_steps(main, feed_list=feed, steps=k, fetch_list=[y])
+        pf.close()
+    return once
+
+
+def _disabled_generation(exe, main, y):
+    from paddle_tpu.serving.engine import ServingConfig
+    from paddle_tpu.serving.generation import (DecodeRuntime,
+                                               GenerationConfig,
+                                               GenerationEngine)
+    from paddle_tpu.serving.generation.decode import random_weights
+    cfg = dict(vocab=64, d_model=32, n_layer=1, n_head=4, n_kv_head=2,
+               d_ffn=64, theta=10000.0, max_len=32)
+    rt = DecodeRuntime(random_weights(cfg), cfg, slots=2, prefill_chunk=4)
+
+    def once():
+        eng = GenerationEngine(
+            rt, config=ServingConfig(),
+            gen_config=GenerationConfig(decode_window=2)).start()
+        try:
+            stream = eng.generate([1, 2, 3, 4, 5], max_new=4)
+            assert stream.result(60).ok
+            assert stream.traceparent is None or obs.enabled()
+        finally:
+            eng.stop(timeout=10)
+    return once
+
+
+# the serving engine's older metrics are looked up by name on every call
+# and drop the update themselves; no other lookup may happen when disabled
+_UNGUARDED_LOOKUPS = ('serving.', 'generation.tokens',
+                      'generation.prefill_chunks',
+                      'generation.decode_windows',
+                      'generation.mixed_dispatches')
+
+
+@pytest.mark.parametrize('path', [
+    _disabled_run, _disabled_run_steps, _disabled_prefetch,
+    _disabled_generation],
+    ids=['run', 'run_steps', 'prefetch', 'generation'])
+def test_disabled_mode_does_no_telemetry_work(monkeypatch, path):
+    """With telemetry disabled the hot paths (a launch, a K-step launch, a
+    prefetched launch, a serving round) must not touch the subsystem at
+    all: every entry point, the span's profiler annotation among them,
+    is patched to raise, and the recorder/registry must not grow — i.e.
+    no per-launch telemetry allocations beyond the constant `enabled()`
+    branch."""
     main, startup, y, _ = _build()
     exe = fluid.Executor()
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         exe.run(startup)
-        _run(exe, main, {'x': np.ones((2, 4), 'float32')}, [y])  # warm
+        once = path(exe, main, y)
+        once()                                                   # warm
         events_before = obs.recorder().event_count()
         counters_before = dict(obs.counters())
         obs.disable()
@@ -167,10 +226,17 @@ def test_disabled_mode_does_no_telemetry_work(monkeypatch):
             monkeypatch.setattr(obs.stall, 'on_launch_start', boom)
             monkeypatch.setattr(obs.stall, 'on_launch_end', boom)
             monkeypatch.setattr(obs.tracing, 'add_span', boom)
-            monkeypatch.setattr(obs.metrics, 'counter', boom)
-            monkeypatch.setattr(obs.metrics, 'histogram', boom)
-            for _ in range(5):
-                _run(exe, main, {'x': np.ones((2, 4), 'float32')}, [y])
+            monkeypatch.setattr(obs.tracing, '_annotation', boom)
+            monkeypatch.setattr(obs.tracing.TraceRecorder, 'add_complete',
+                                boom)
+            for kind in ('counter', 'histogram'):
+                real = getattr(obs.metrics, kind)
+                monkeypatch.setattr(
+                    obs.metrics, kind,
+                    lambda name, real=real: real(name) if name.startswith(
+                        _UNGUARDED_LOOKUPS) else boom())
+            for _ in range(3):
+                once()
         finally:
             obs.enable()
     assert obs.recorder().event_count() == events_before

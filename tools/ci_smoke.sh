@@ -776,7 +776,7 @@ tel_expected = ['platform', 'device_kind', 'retraces', 'retraces_total',
                 'emit_s', 'trace_s', 'backend_compile_s',
                 'program_op_count_raw', 'program_op_count_opt',
                 'opt_pass_ms', 'opt_ops_fused', 'stall_count',
-                'prefetch_starvation_s', 'fetch_sync_s',
+                'prefetch_starvation_s',
                 'kernel_fallbacks', 'emitter_fallbacks',
                 'kernelgen_ops', 'kernelgen_fallbacks',
                 'autotune_searches', 'autotune_cache_hits', 'fused_adam_ms',
