@@ -568,17 +568,194 @@ def cached_attention(q, kcache, vcache, qpos, scale=None):
     return out.reshape(B, H, Tq, D).astype(q.dtype)
 
 
-def write_cache(kcache, vcache, k, v, slot, layer, offset):
-    """Write one layer's new K/V for one slot at a position offset.
+# ------------------------------------------- paged decode-step attention
 
-    kcache/vcache: [S, L, Hkv, Tmax, D] slot-major pages; k/v:
-    [Hkv, C, D] for the C new positions of layer ``layer``; slot/offset
-    are traced scalars.  The write is a pure dynamic_update_slice so the
-    whole prefill/decode step stays one fused XLA program with the cache
-    as donated carry (no host round-trip per layer or per token).
+_PAGED_BLOCK_TOKENS = 128   # key positions one double-buffered block holds
+
+
+def paged_attention_eligible(pool_shape, dtype, mesh=None):
+    """Static rule for `paged_attention` over a ``[pages, layers, page_len,
+    kv_heads, head_dim]`` pool: a floating pool (an int8 pool dequantizes
+    in the composed gather) on a single device; on an accelerator one
+    page of one layer, ``[page_len * kv_heads, head_dim]``, must be whole
+    tiles of the pool's dtype where it lies."""
+    dtype = jnp.dtype(dtype)
+    if not jnp.issubdtype(dtype, jnp.floating) \
+            or not _pallas.single_device(mesh):
+        return False
+    if _pallas.interpret():
+        return True
+    _pages, _layers, page_len, hkv, dh = pool_shape
+    sublanes = 8 * (4 // dtype.itemsize)
+    return dh % 128 == 0 and (hkv * page_len) % sublanes == 0
+
+
+def paged_attention_rows(n_attend, page_len):
+    """Key positions `paged_attention` fetches for slots attending
+    ``n_attend`` positions each (numpy, host side): whole pages up to
+    the one holding the last position, nothing for a slot that attends
+    nothing.  `generation.kv_rows_read` counts with it."""
+    n = np.asarray(n_attend, np.int64)
+    return int((-(-n // int(page_len)) * int(page_len)).sum())
+
+
+def _paged_kernel(bt_ref, n_ref, layer_ref, q_ref, koff_ref, hbias_ref,
+                  k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *, pages_per_block,
+                  page_rows, page_len, max_pages, scale):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s = pl.program_id(0)
+    n = n_ref[s]                          # positions this slot attends
+    layer = layer_ref[0]
+    P, PR = pages_per_block, page_rows
+    block_tokens = P * page_len
+    n_blocks = jax.lax.div(n + block_tokens - 1, block_tokens)
+    n_pages = jax.lax.div(n + page_len - 1, page_len)
+
+    def page_copies(blk, buf):
+        """(covered, K copy, V copy) per page of block ``blk``: one
+        page of one layer is ``[page_len * kv_heads, head_dim]``,
+        contiguous in the pool, and lands at its rows of the buffer."""
+        out = []
+        for p in range(P):
+            idx = blk * P + p
+            page = bt_ref[s * max_pages + jnp.minimum(idx, max_pages - 1)]
+            rows = pl.ds(p * PR, PR)
+            out.append((idx < n_pages,
+                        pltpu.make_async_copy(k_hbm.at[page, layer],
+                                              kbuf.at[buf, rows],
+                                              sem.at[0, buf]),
+                        pltpu.make_async_copy(v_hbm.at[page, layer],
+                                              vbuf.at[buf, rows],
+                                              sem.at[1, buf])))
+        return out
+
+    def start(blk, buf):
+        for covered, ck, cv in page_copies(blk, buf):
+            @pl.when(covered)
+            def _():
+                ck.start()
+                cv.start()
+
+    def wait(blk, buf):
+        for covered, ck, cv in page_copies(blk, buf):
+            @pl.when(covered)
+            def _():
+                ck.wait()
+                cv.wait()
+
+    @pl.when(s == 0)
+    def _():
+        # rows no copy ever fills meet a probability of exactly 0 in the
+        # value product; whatever VMEM held there must not be a NaN
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    @pl.when(n > 0)
+    def _():
+        start(0, 0)
+
+    H, D = q_ref.shape[1], q_ref.shape[2]
+    q = q_ref[0]
+    ctype = jnp.promote_types(q.dtype, kbuf.dtype)
+
+    def body(blk, carry):
+        m, l, acc = carry
+        buf = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            start(blk + 1, 1 - buf)
+
+        wait(blk, buf)
+        k, v = kbuf[buf], vbuf[buf]                     # [R, D]
+        # every query head against every row of the block; the rows of
+        # other kv heads are masked out with the positions past n
+        sc = jax.lax.dot_general(
+            q.astype(ctype), k.astype(ctype), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [H, R]
+        kpos = blk * block_tokens + koff_ref[...]        # [1, R]
+        sc = jnp.where(kpos < n, sc + hbias_ref[...], _NEG_INF)
+        m_new = jnp.maximum(m, sc.max(axis=1, keepdims=True))
+        # a block the loop reaches holds a visible position of every
+        # head, so m_new is a real score and a masked row's exp is 0
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = alpha * l + p.sum(axis=1, keepdims=True)
+        acc_new = acc * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_new
+
+    m, l, acc = jax.lax.fori_loop(
+        0, n_blocks, body,
+        (jnp.full((H, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((H, 1), jnp.float32), jnp.zeros((H, D), jnp.float32)))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def paged_attention(q, kpool, vpool, block_tables, n_attend, layer,
+                    scale=None, pages_per_block=None):
+    """One decode step's attention over the page pool IN PLACE.
+
+    q: [S, H, D], one query per slot at position ``n_attend - 1``;
+    kpool/vpool: [pages, layers, page_len, Hkv, D] with the step's own
+    K/V already written; block_tables: [S, max_pages] int32; n_attend:
+    [S] int32 positions each slot attends (its length after the write; 0
+    for a slot that rides along: it reads nothing and returns zeros);
+    layer: int32 scalar.  Returns [S, H, D] in q's dtype.
+
+    The pools stay in HBM and XLA never slices them: per slot the
+    kernel copies the pages its length covers, ``pages_per_block`` at a
+    time and double-buffered, through the block table (scalar prefetch)
+    and runs an online softmax with f32 statistics over them.  Key
+    position kpos is visible iff ``kpos < n_attend`` (`cached_attention`'s
+    ``kpos <= qpos``); probabilities are cast to the pool's dtype for the
+    value product, as there.  No dense ``[S, Hkv, max_len, D]`` exists.
     """
-    k = k[None, None].astype(kcache.dtype)
-    v = v[None, None].astype(vcache.dtype)
-    idx = (slot, layer, 0, offset, 0)
-    return (jax.lax.dynamic_update_slice(kcache, k, idx),
-            jax.lax.dynamic_update_slice(vcache, v, idx))
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    S, H, D = q.shape
+    pages, layers, PL, Hkv, _ = kpool.shape
+    M = block_tables.shape[1]
+    if scale is None:
+        scale = D ** -0.5
+    P = pages_per_block or max(1, _PAGED_BLOCK_TOKENS // PL)
+    P = min(int(P), M)
+    PR = Hkv * PL
+    R = P * PR
+    # row r of a block is (page r // PR, token (r % PR) // Hkv, kv head
+    # r % Hkv): its position within the block, and which query heads it
+    # belongs to (GQA: head h reads kv head h // g)
+    r = np.arange(R)
+    koff = ((r // PR) * PL + (r % PR) // Hkv).astype(np.int32)[None]
+    hbias = np.where(np.arange(H)[:, None] // (H // Hkv)
+                     == (r % Hkv)[None], 0.0, _NEG_INF).astype(np.float32)
+    kernel = functools.partial(
+        _paged_kernel, pages_per_block=P, page_rows=PR, page_len=PL,
+        max_pages=M, scale=scale)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[
+            pl.BlockSpec((1, H, D), lambda s, *_: (s, 0, 0)),
+            pl.BlockSpec((1, R), lambda s, *_: (0, 0)),
+            pl.BlockSpec((H, R), lambda s, *_: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, H, D), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, R, D), kpool.dtype),
+                        pltpu.VMEM((2, R, D), vpool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2))],
+    )
+    n = jnp.clip(n_attend.astype(jnp.int32), 0, M * PL)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
+        name='paged_attention',
+        interpret=_pallas.interpret(),
+    )(block_tables.reshape(-1).astype(jnp.int32), n,
+      jnp.asarray(layer, jnp.int32).reshape(1), q, jnp.asarray(koff),
+      jnp.asarray(hbias), kpool.reshape(pages, layers, PR, D),
+      vpool.reshape(pages, layers, PR, D))

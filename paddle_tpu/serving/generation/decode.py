@@ -26,6 +26,16 @@ With ``quant='int8'`` rows are stored as int8 with one float32 scale
 per (token, kv head), quantized on write and dequantized inside the
 gather — attention math stays float32.
 
+The decode step does NOT gather: it attends over the pool in place
+(`ops.attention.paged_attention`), each active slot reading the pages
+its length covers and an inactive slot nothing, so the KV bytes a step
+moves follow the live tokens, not ``slots * max_len``.  An int8 pool
+and a runtime over a mesh of several devices keep the composed path
+(`_logical_rows` + `cached_attention`); which one runs is read off the
+pool and the mesh (`DecodeRuntime.paged`), and
+``generation.kv_rows_read`` counts what that path reads
+(`DecodeRuntime._window_rows_read`).
+
 `_verify_fn` is the speculative-decode twin of the decode window: the
 same step body, but each scan step feeds a HOST-PROVIDED token (last
 emitted token + draft proposals) instead of the carry token, and the
@@ -47,7 +57,9 @@ import numpy as np
 
 from ... import observability as _obs
 from ...core import compile_cache as _cc
-from ...ops.attention import cached_attention
+from ...ops.attention import (cached_attention, paged_attention,
+                              paged_attention_eligible,
+                              paged_attention_rows)
 from ...ops.sampling import sample_logits, sample_tokens_at, token_key
 from .kv_cache import (CacheConfig, PagePool, PrefixCache, SlotAllocator,
                        init_state)
@@ -156,13 +168,13 @@ def _write_rows(st, i, pg, rw, k_new, v_new, quant):
     if quant:
         qk, sk = _quantize_rows(k_new)
         qv, sv = _quantize_rows(v_new)
-        st['k'] = st['k'].at[pg, i, :, rw, :].set(qk)
-        st['v'] = st['v'].at[pg, i, :, rw, :].set(qv)
-        st['k_scale'] = st['k_scale'].at[pg, i, :, rw].set(sk)
-        st['v_scale'] = st['v_scale'].at[pg, i, :, rw].set(sv)
+        st['k'] = st['k'].at[pg, i, rw].set(qk)
+        st['v'] = st['v'].at[pg, i, rw].set(qv)
+        st['k_scale'] = st['k_scale'].at[pg, i, rw].set(sk)
+        st['v_scale'] = st['v_scale'].at[pg, i, rw].set(sv)
     else:
-        st['k'] = st['k'].at[pg, i, :, rw, :].set(k_new.astype(st['k'].dtype))
-        st['v'] = st['v'].at[pg, i, :, rw, :].set(v_new.astype(st['v'].dtype))
+        st['k'] = st['k'].at[pg, i, rw].set(k_new.astype(st['k'].dtype))
+        st['v'] = st['v'].at[pg, i, rw].set(v_new.astype(st['v'].dtype))
     return st
 
 
@@ -179,12 +191,12 @@ def _logical_rows(st, bt, i, cache):
     Hkv, PL, dh = cache.kv_heads, cache.page_len, cache.head_dim
 
     def assemble(pool, scale):
-        rows = pool[bt, i]                     # [B, M, Hkv, PL, dh]
-        rows = rows.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, M * PL, dh)
+        rows = pool[bt, i]                     # [B, M, PL, Hkv, dh]
+        rows = rows.transpose(0, 3, 1, 2, 4).reshape(B, Hkv, M * PL, dh)
         if scale is None:
             return rows
-        sc = scale[bt, i]                      # [B, M, Hkv, PL]
-        sc = sc.transpose(0, 2, 1, 3).reshape(B, Hkv, M * PL)
+        sc = scale[bt, i]                      # [B, M, PL, Hkv]
+        sc = sc.transpose(0, 3, 1, 2).reshape(B, Hkv, M * PL)
         return rows.astype(jnp.float32) * sc[..., None]
 
     if cache.quant == 'int8':
@@ -194,11 +206,12 @@ def _logical_rows(st, bt, i, cache):
 
 
 def _gathered_rows(cache, st, bt):
-    """Rows of K (or of V) per layer that one step gathers for a window
-    executable built over the structs ``st`` and ``bt``: batch x
-    positions of what `_logical_rows` returns for them (shapes only,
-    nothing runs).  A step that gathers live pages only, or takes a
-    narrower table, changes this count with its executable."""
+    """Rows of K (or of V) per layer that one COMPOSED step gathers for
+    a window executable built over the structs ``st`` and ``bt``: batch
+    x positions of what `_logical_rows` returns for them (shapes only,
+    nothing runs); a narrower table changes this count with its
+    executable.  The paged step gathers nothing and counts what its
+    kernel fetches (`DecodeRuntime._window_rows_read`)."""
     import jax
     k, _v = jax.eval_shape(lambda s, b: _logical_rows(s, b, 0, cache),
                            st, bt)
@@ -281,16 +294,20 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
     return prefill
 
 
-def _step_fn(cfg, cache):
+def _step_fn(cfg, cache, paged):
     """One fused decode/verify step over ALL slots: write the fed token's
-    K/V through the block table, attend against the gathered logical
-    rows, sample each slot's next token with the position-keyed stream,
-    advance ACTIVE slots only.  Inactive slots compute masked garbage
-    routed to page 0."""
+    K/V through the block table, attend, sample each slot's next token
+    with the position-keyed stream, advance ACTIVE slots only.  Inactive
+    slots compute masked garbage routed to page 0.
+
+    ``paged`` (`DecodeRuntime.paged`) attends over the pool in place
+    (`ops.attention.paged_attention`): an active slot reads the pages
+    its length covers, an inactive one nothing.  Otherwise the composed
+    path gathers every slot's logical row first (`_logical_rows` +
+    `cached_attention`)."""
     import jax.numpy as jnp
     L = int(cfg['n_layer'])
     theta = float(cfg['theta'])
-    dh = int(cfg['d_model']) // int(cfg['n_head'])
     M, PL = cache.max_pages, cache.page_len
     quant = cache.quant == 'int8'
 
@@ -302,6 +319,7 @@ def _step_fn(cfg, cache):
         pg = bt[jnp.arange(S), jnp.clip(pos // PL, 0, M - 1)]
         pg = jnp.where(active, pg, 0)
         rw = pos % PL
+        n_attend = jnp.where(active, pos + 1, 0)          # [S]
         with scope('embed'):
             x = w['tok_emb'][fed][:, None, :]             # [S, 1, D]
         for i in range(L):
@@ -314,13 +332,17 @@ def _step_fn(cfg, cache):
                 with scope('kv.write'):
                     st = _write_rows(st, i, pg, rw, k[:, :, 0, :],
                                      v[:, :, 0, :], quant)
-                with scope('kv.gather'):
-                    kl, vl = _logical_rows(st, bt, i, cache)
+                if not paged:
+                    with scope('kv.gather'):
+                        kl, vl = _logical_rows(st, bt, i, cache)
                 with scope('attn.scores'):
-                    att = cached_attention(q, kl, vl, pos[:, None])
-                    H = att.shape[1]
-                    att = att.transpose(0, 2, 1, 3).reshape(S, 1, H * dh)
-                    x = x + att @ w['layer_%d_att_o_w' % i]
+                    if paged:
+                        att = paged_attention(q[:, :, 0, :], st['k'],
+                                              st['v'], bt, n_attend, i)
+                    else:
+                        att = cached_attention(q, kl, vl, pos[:, None])
+                        att = att.transpose(0, 2, 1, 3)
+                    x = x + att.reshape(S, 1, -1) @ w['layer_%d_att_o_w' % i]
                 with scope('ffn'):
                     x = _ffn(w, x, i)
         with scope('lm_head'):
@@ -336,13 +358,13 @@ def _step_fn(cfg, cache):
     return step
 
 
-def _decode_fn(cfg, cache, steps):
+def _decode_fn(cfg, cache, steps, paged):
     """K-step fused decode window: each step feeds every slot's own
     carry token.  One `lax.scan`; the state dict is donated carry; the
     block table is closed-over DATA (an ordinary traced argument)."""
     import jax
 
-    step = _step_fn(cfg, cache)
+    step = _step_fn(cfg, cache, paged)
 
     def window(w, st, bt, active, seeds, temps, topks):
         def body(carry, _):
@@ -355,7 +377,7 @@ def _decode_fn(cfg, cache, steps):
     return window
 
 
-def _verify_fn(cfg, cache, steps):
+def _verify_fn(cfg, cache, steps, paged):
     """K-step speculative VERIFY window: identical step body, but step j
     feeds ``fed[j]`` (host-built: last emitted token, then the draft's
     proposals) and the returned samples are the target model's verdicts
@@ -363,7 +385,7 @@ def _verify_fn(cfg, cache, steps):
     an accepted prefix is bitwise the sequential stream."""
     import jax
 
-    step = _step_fn(cfg, cache)
+    step = _step_fn(cfg, cache, paged)
 
     def window(w, st, bt, fed, active, seeds, temps, topks):
         def body(carry, fed_t):
@@ -482,8 +504,16 @@ class DecodeRuntime(object):
         self.mesh = mesh
         self.ring_min_len = (int(ring_min_len) if ring_min_len is not None
                              else 2 * self.prefill_chunk)
+        # the decode step attends over the pool in place where the
+        # kernel can run (a floating pool, one device); an int8 pool and
+        # a mesh of several devices keep the composed gather
+        self.paged = paged_attention_eligible(
+            self.cache.pool_shape, self.cache.store_dtype, mesh)
         self._execs = {}
-        self._window_rows = {}   # (kind, steps) -> KV rows a launch gathers
+        # rows of K (or V) per layer one COMPOSED step gathers
+        self._gathered = None if self.paged else _gathered_rows(
+            self.cache, self._state_structs(),
+            self._bt_struct(self.cache.slots))
         self._lock = threading.Lock()
         _obs.metrics.gauge('generation.kv_cache_bytes').set(
             self.cache.bytes())
@@ -685,16 +715,11 @@ class DecodeRuntime(object):
                                chunk), build)
 
     def _window_exec(self, kind, steps):
-        """(executable, rows of K or V per layer one launch of it
-        gathers): the count is taken from the same argument structs the
-        executable is built for (`generation.kv_rows_read`)."""
         import jax
 
         def build():
-            if kind == 'verify':
-                fn = _verify_fn(self.cfg, self.cache, steps)
-            else:
-                fn = _decode_fn(self.cfg, self.cache, steps)
+            make = _verify_fn if kind == 'verify' else _decode_fn
+            fn = make(self.cfg, self.cache, steps, self.paged)
             jitted = jax.jit(fn, donate_argnums=(1,))
             S = self.cache.slots
             vec = lambda dt: self._sds((S,), dt)  # noqa: E731
@@ -707,14 +732,21 @@ class DecodeRuntime(object):
                      vec(jax.numpy.float32), vec(jax.numpy.int32)]
             return jitted, args
 
-        key = (kind, steps)
-        call = self._compiled(key, build)
-        rows = self._window_rows.get(key)
-        if rows is None:
-            rows = self._window_rows[key] = steps * _gathered_rows(
-                self.cache, self._state_structs(),
-                self._bt_struct(self.cache.slots))
-        return call, rows
+        return self._compiled((kind, steps), build)
+
+    def _window_rows_read(self, steps, act):
+        """Rows of K (or of V) per layer that one ``steps``-step window
+        over the active slots ``act`` reads (`generation.kv_rows_read`).
+        Paged: what the kernel fetches, step j of an active slot the
+        whole pages its len + j + 1 positions cover, an inactive slot
+        nothing (`paged_attention_rows`).  Composed: every slot's
+        ``max_len`` rows a step whoever is live, taken from the shapes
+        the executable was built over (`_gathered_rows`)."""
+        if self.paged:
+            lens = self.host_len[act].astype(np.int64)[:, None]
+            return paged_attention_rows(lens + np.arange(1, steps + 1),
+                                        self.cache.page_len)
+        return steps * self._gathered
 
     def _decode_exec(self, steps):
         return self._window_exec('decode', steps)
@@ -825,7 +857,7 @@ class DecodeRuntime(object):
         and live, KV positions live streams attended and rows read)."""
         import jax.numpy as jnp
         steps = int(steps)
-        call, rows_read = self._window_exec(kind, steps)
+        call = self._window_exec(kind, steps)
         act = np.asarray(active, bool).reshape(self.cache.slots)
         with _obs.span('decode.window', cat='decode', kind=kind,
                        steps=steps) as sp:
@@ -851,7 +883,8 @@ class DecodeRuntime(object):
             counter('generation.kv_tokens_live').inc(
                 steps * int(self.host_len[act].sum(dtype=np.int64))
                 + live * steps * (steps + 1) // 2)
-            counter('generation.kv_rows_read').inc(rows_read)
+            counter('generation.kv_rows_read').inc(
+                self._window_rows_read(steps, act))
         return act, out
 
     def decode_window(self, steps, active, seeds, temps, topks):
@@ -911,12 +944,12 @@ class DecodeRuntime(object):
         Tmax, dh = self.cache.max_len, self.cache.head_dim
 
         def assemble(pool, scale):
-            rows = np.asarray(pool)[bt]        # [M, L, Hkv, PL, dh]
-            rows = rows.transpose(1, 2, 0, 3, 4).reshape(L, Hkv, Tmax, dh)
+            rows = np.asarray(pool)[bt]        # [M, L, PL, Hkv, dh]
+            rows = rows.transpose(1, 3, 0, 2, 4).reshape(L, Hkv, Tmax, dh)
             if scale is None:
                 return rows
-            sc = np.asarray(scale)[bt]         # [M, L, Hkv, PL]
-            sc = sc.transpose(1, 2, 0, 3).reshape(L, Hkv, Tmax)
+            sc = np.asarray(scale)[bt]         # [M, L, PL, Hkv]
+            sc = sc.transpose(1, 3, 0, 2).reshape(L, Hkv, Tmax)
             return rows.astype(np.float32) * sc[..., None]
 
         if self.cache.quant == 'int8':

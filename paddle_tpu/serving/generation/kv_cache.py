@@ -1,7 +1,11 @@
 """Paged KV cache: a shared page pool + per-slot block tables.
 
-One preallocated pair of ``[pages, layers, kv_heads, page_len,
-head_dim]`` pools holds every in-flight request's keys/values; a
+One preallocated pair of ``[pages, layers, page_len, kv_heads,
+head_dim]`` pools holds every in-flight request's keys/values (token
+major inside a page: one token's K for all kv heads is one contiguous
+``[kv_heads, head_dim]`` slab, which is what a step writes, and one
+page of one layer is a contiguous ``[page_len * kv_heads, head_dim]``
+matrix, which is what the paged decode kernel copies); a
 request owns a *slot* (its row in the fixed-width decode batch) and a
 list of *pages* its block table maps, so its memory footprint is
 ``ceil(len / page_len)`` pages instead of a dense ``max_len`` strip.
@@ -102,14 +106,14 @@ class CacheConfig(object):
 
     @property
     def pool_shape(self):
-        return (self.pages, self.layers, self.kv_heads, self.page_len,
+        return (self.pages, self.layers, self.page_len, self.kv_heads,
                 self.head_dim)
 
     @property
     def scale_shape(self):
         """Per-row dequant scales (int8 mode): one f32 per written
-        (page, layer, kv head, row)."""
-        return (self.pages, self.layers, self.kv_heads, self.page_len)
+        (page, layer, row, kv head)."""
+        return (self.pages, self.layers, self.page_len, self.kv_heads)
 
     @property
     def page_shape(self):

@@ -80,7 +80,7 @@ def test_cache_config_geometry():
     c = CacheConfig(slots=4, layers=2, kv_heads=2, max_len=32, head_dim=8)
     assert c.page_len == 8 and c.max_pages == 4
     assert c.pages == 4 * 4 + 1              # dense-equivalent + garbage
-    assert c.pool_shape == (c.pages, 2, 2, 8, 8)
+    assert c.pool_shape == (c.pages, 2, 8, 2, 8)   # token-major pages
     assert c.bytes() == c.pages * c.page_bytes()
     assert c.page_bytes() == 2 * 4 * 2 * 2 * 8 * 8
     assert (c.pages_for(0), c.pages_for(1), c.pages_for(8),

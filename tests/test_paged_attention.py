@@ -1,0 +1,220 @@
+"""The decode step's attention over the page pool in place
+(`ops.attention.paged_attention`, Pallas in interpret mode on the CPU)
+against the composed path it replaces on one pool: `_logical_rows` +
+`cached_attention`.  Ragged lengths, page and block boundaries, a full
+slot, inactive slots with stale tables, shared prefix pages, an idle
+batch, GQA and MHA, f32 and bf16; and that pages no live length covers
+are not READ, not merely masked.  Toy geometry: counts and values, never
+a time."""
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from paddle_tpu.ops.attention import (cached_attention, paged_attention,
+                                      paged_attention_eligible,
+                                      paged_attention_rows)
+from paddle_tpu.serving.generation import CacheConfig
+from paddle_tpu.serving.generation.decode import _logical_rows
+
+PL, M, LAYERS, DH = 4, 6, 2, 8           # max_len 24
+MAX_LEN = PL * M
+
+
+def _pool(slots, hkv, dtype, seed=0):
+    cache = CacheConfig(slots=slots, layers=LAYERS, kv_heads=hkv,
+                        max_len=MAX_LEN, head_dim=DH, dtype=dtype,
+                        page_len=PL)
+    rng = np.random.RandomState(seed)
+    st = {n: jnp.asarray(rng.randn(*cache.pool_shape), jnp.dtype(dtype))
+          for n in ('k', 'v')}
+    return cache, st
+
+
+def _tables(slots, pages, seed=1):
+    """Every slot maps distinct random pages (never the garbage page)."""
+    rng = np.random.RandomState(seed)
+    return rng.permutation(np.arange(1, pages))[:slots * M].reshape(
+        slots, M).astype(np.int32)
+
+
+def _composed(st, bt, cache, layer, q, n):
+    """What the step computed before: gather every slot's logical row,
+    attend with the positional mask kpos <= qpos = n - 1."""
+    kl, vl = _logical_rows(st, jnp.asarray(bt), layer, cache)
+    qpos = jnp.asarray(n, jnp.int32)[:, None] - 1
+    return cached_attention(q[:, :, None, :], kl, vl, qpos)[:, :, 0, :]
+
+
+def _both(lengths, heads, hkv, dtype, pages_per_block, bt=None, st=None,
+          layer=1):
+    slots = len(lengths)
+    cache, fresh = _pool(slots, hkv, dtype)
+    st = st or fresh
+    if bt is None:
+        bt = _tables(slots, cache.pages)
+    q = jnp.asarray(np.random.RandomState(2).randn(slots, heads, DH),
+                    jnp.dtype(dtype))
+    n = np.asarray(lengths, np.int32)
+    got = paged_attention(q, st['k'], st['v'], jnp.asarray(bt),
+                          jnp.asarray(n), layer,
+                          pages_per_block=pages_per_block)
+    want = _composed(st, bt, cache, layer, q, n)
+    return np.asarray(got, np.float32), np.asarray(want, np.float32), n
+
+
+def _assert_close(got, want, n, dtype):
+    live = n > 0
+    assert np.isfinite(got).all()
+    # bf16: both sides round probabilities and the output to 8 bits of
+    # mantissa, in another order of summation
+    tol = 1e-5 if dtype == 'float32' else 2e-2
+    np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+    assert not got[~live].any()          # a slot that rides along: zeros
+
+
+RAGGED = {
+    'ragged_incl_1': [1, 7, 13, 24],
+    'page_boundary': [PL, PL - 1, PL + 1, 3 * PL],
+    'block_boundary': [2 * PL, 2 * PL + 1, 4 * PL - 1, 4 * PL + 1],
+    'slot_at_max_len': [MAX_LEN, 2, MAX_LEN - 1, MAX_LEN],
+    'inactive_among_live': [0, 9, 0, 17],
+}
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('heads,hkv', [(4, 1), (2, 2)],
+                         ids=['gqa4to1', 'mha'])
+@pytest.mark.parametrize('case', sorted(RAGGED))
+def test_paged_equals_gather_then_attend(case, heads, hkv, dtype):
+    """Blocks of two pages, so lengths cross block boundaries and the
+    double buffer turns over; an inactive slot's table maps live pages
+    of others (stale) and must contribute nothing."""
+    got, want, n = _both(RAGGED[case], heads, hkv, dtype, 2)
+    _assert_close(got, want, n, dtype)
+
+
+@pytest.mark.parametrize('pages_per_block', [1, 3, M, None])
+def test_block_size_does_not_change_the_result(pages_per_block):
+    got, want, n = _both([5, 24, 0, 12], 4, 2, 'float32', pages_per_block)
+    _assert_close(got, want, n, 'float32')
+
+
+def test_two_slots_sharing_prefix_pages():
+    """Slots 0 and 1 map the same first two pages (a prefix-cache hit)
+    and their own tails; slot 2 is inactive with slot 0's table."""
+    cache, _ = _pool(3, 2, 'float32')
+    bt = _tables(3, cache.pages)
+    bt[1, :2] = bt[0, :2]
+    bt[2] = bt[0]
+    got, want, n = _both([11, 14, 0], 4, 2, 'float32', 2, bt=bt)
+    _assert_close(got, want, n, 'float32')
+    # the shared pages really carried weight in both results
+    assert np.abs(got[0]).max() > 0 and np.abs(got[1]).max() > 0
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+def test_all_inactive_batch_is_zero_and_reads_nothing(dtype):
+    cache, st = _pool(3, 2, dtype)
+    poisoned = {k: jnp.full_like(v, jnp.nan) for k, v in st.items()}
+    got, _want, n = _both([0, 0, 0], 4, 2, dtype, 2, st=poisoned)
+    assert np.isfinite(got).all() and not got.any()
+    assert paged_attention_rows(n, PL) == 0
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+def test_pages_no_live_length_covers_are_not_read(dtype):
+    """NaN in every page that no ACTIVE slot's length covers: the
+    garbage page, unmapped pool pages, the pages past each live length
+    and every page of the inactive slot's stale table.  Masking alone
+    would not do: 0 * NaN in the value product is NaN."""
+    lengths = [1, 9, 0, 2 * PL]
+    cache, st = _pool(len(lengths), 2, dtype)
+    bt = _tables(len(lengths), cache.pages)
+    covered = np.zeros(cache.pages, bool)
+    for s, n in enumerate(lengths):
+        covered[bt[s, :cache.pages_for(n)]] = True
+    assert not covered[0] and covered.sum() == 1 + 3 + 0 + 2
+    dead = jnp.asarray(~covered)[:, None, None, None, None]
+    poisoned = {k: jnp.where(dead, jnp.nan, v) for k, v in st.items()}
+    got, _, n = _both(lengths, 4, 2, dtype, 2, bt=bt, st=poisoned)
+    clean, want, _ = _both(lengths, 4, 2, dtype, 2, bt=bt, st=st)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
+    _assert_close(got, want, n, dtype)
+    assert paged_attention_rows(n, PL) == (1 + 3 + 0 + 2) * PL
+
+
+def test_rows_fetched_are_whole_pages_of_active_slots():
+    assert paged_attention_rows([0, 1, 8, 9], 8) == 0 + 8 + 8 + 16
+    assert paged_attention_rows(np.zeros(4, int), 8) == 0
+    # a window: step j of a slot of length n attends n + j + 1
+    lens = np.array([7, 20])[:, None] + np.arange(1, 3)
+    assert paged_attention_rows(lens, 8) == (8 + 16) + (24 + 24)
+
+
+def test_eligibility_is_read_off_the_pool():
+    """A floating pool on one device; the int8 pool and a mesh of
+    several devices keep the composed gather."""
+    import jax
+    from jax.sharding import Mesh
+    shape = (9, 2, 8, 2, 8)
+    assert paged_attention_eligible(shape, 'float32')
+    assert paged_attention_eligible(shape, 'bfloat16')
+    assert not paged_attention_eligible(shape, 'int8')
+    one = Mesh(np.array(jax.devices()[:1]), ('seq',))
+    assert paged_attention_eligible(shape, 'float32', one)
+    if len(jax.devices()) > 1:
+        two = Mesh(np.array(jax.devices()[:2]), ('seq',))
+        assert not paged_attention_eligible(shape, 'float32', two)
+
+
+# ------------------------------------ the chip's compiler, without the chip
+
+@pytest.fixture(scope='module')
+def one_v5e_chip():
+    """A described, not attached, v5e chip: XLA:TPU and Mosaic compile for
+    it here and raise what the chip's compiler would."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:  # noqa: BLE001 - no libtpu here: nothing to test
+        pytest.skip('no v5e:2x2 topology can be described here: %s' % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize('dtype,heads,slots,max_len,pages', [
+    ('bfloat16', 32, 32, 1280, 5121),     # mistral7b.chat_steady's pool
+    ('float32', 16, 8, 2048, None),       # chip_smoke's llama_1b widths
+])
+def test_mosaic_compiles_the_kernel_at_real_widths(
+        one_v5e_chip, monkeypatch, dtype, heads, slots, max_len, pages):
+    """Interpret mode cannot see a refused tiling or DMA; the compiler
+    can, and the pool must reach the kernel as a bitcast, not a copy."""
+    import jax
+    from paddle_tpu.ops import _pallas
+    monkeypatch.setattr(_pallas, 'interpret', lambda: False)
+    cache = CacheConfig(slots=slots, layers=16, kv_heads=8, max_len=max_len,
+                        head_dim=128, dtype=dtype, page_len=8, pages=pages)
+    assert paged_attention_eligible(cache.pool_shape, dtype)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                    sharding=one_v5e_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, bt, n: paged_attention(q, k, v, bt, n, 3)).lower(
+            sds((slots, heads, 128), 'float32'), sds(cache.pool_shape, dtype),
+            sds(cache.pool_shape, dtype),
+            sds((slots, cache.max_pages), 'int32'),
+            sds((slots,), 'int32')).compile()
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 1
+    pool = '[%d,' % cache.pages
+    assert not [ln for ln in text.splitlines() if pool in ln.split('(')[0]
+                and (' copy(' in ln or 'copy-start(' in ln
+                     or ' fusion(' in ln)]
+    # nothing of the pool's size is made: the output is the queries' size
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

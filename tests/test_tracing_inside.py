@@ -71,7 +71,7 @@ def test_scripted_serving_run_counts_exactly():
     c = _delta(obs.counters(), before)
 
     skipped = [0, PAGE, 0]
-    chunks = windows = pad = live_tokens = 0
+    chunks = windows = pad = live_tokens = rows_read = 0
     for (prompt, max_new), skip in zip(script, skipped):
         todo = len(prompt) - skip
         n_chunks = -(-todo // CHUNK)
@@ -81,6 +81,9 @@ def test_scripted_serving_run_counts_exactly():
         for w in range(n_windows):
             length = len(prompt) + w * WINDOW     # the stream's rows so far
             live_tokens += WINDOW * length + WINDOW * (WINDOW + 1) // 2
+            # the paged step fetches the whole pages its positions cover
+            rows_read += sum(-(-(length + j + 1) // PAGE) * PAGE
+                             for j in range(WINDOW))
         windows += n_windows
     prompt_tokens = sum(len(p) for p, _ in script)
 
@@ -93,8 +96,9 @@ def test_scripted_serving_run_counts_exactly():
     assert c['generation.decode_live_slot_steps'] == windows * WINDOW
     assert c['generation.decode_slot_steps'] == windows * WINDOW * SLOTS
     assert c['generation.kv_tokens_live'] == live_tokens
-    assert c['generation.kv_rows_read'] == \
-        windows * WINDOW * SLOTS * CFG['max_len']
+    # the live stream's pages alone: the two idle slots read nothing
+    assert c['generation.kv_rows_read'] == rows_read
+    assert rows_read < windows * WINDOW * CFG['max_len']
     # alone in the engine a request's chunks run in consecutive rounds,
     # the first in the round of its grant; never fewer rounds than chunks
     assert c['generation.rounds_to_first_token'] == chunks
@@ -109,22 +113,35 @@ def test_scripted_serving_run_counts_exactly():
 
 
 def test_kv_rows_read_follows_the_executables_own_shapes():
-    """The count rides on the compiled entry and is read off what
-    `_logical_rows` returns for the structs the executable was built
-    over: a narrower table or a shorter gather moves it, no constant."""
+    """Paged (a floating pool): the count is what the kernel fetches,
+    whole pages up to each ACTIVE slot's last position, step by step.
+    Composed (an int8 pool): every slot's ``max_len`` rows a step, read
+    off what `_logical_rows` returns for the structs the executable was
+    built over: a narrower table or a shorter gather moves it."""
     from paddle_tpu.serving.generation import decode
-    rt = DecodeRuntime(random_weights(CFG, seed=0), CFG, slots=SLOTS,
-                       prefill_chunk=CHUNK, page_len=PAGE)
-    _call, rows = rt._window_exec('decode', 2)
-    assert rows == 2 * SLOTS * CFG['max_len']
-    assert rt._window_exec('verify', 3)[1] == 3 * SLOTS * CFG['max_len']
-    state = rt._state_structs()
-    assert decode._gathered_rows(rt.cache, state, rt._bt_struct(1)) \
-        == CFG['max_len']
-    half = jax.ShapeDtypeStruct((SLOTS, rt.cache.max_pages // 2), 'int32')
-    assert decode._gathered_rows(rt.cache, state, half) \
-        == SLOTS * CFG['max_len'] // 2
+    weights = random_weights(CFG, seed=0)
+    rt = DecodeRuntime(weights, CFG, slots=SLOTS, prefill_chunk=CHUNK,
+                       page_len=PAGE)
+    assert rt.paged
+    rt.host_len[:] = [7, 20, 8]
+    act = np.array([True, False, True])
+    # slot 0 attends 8 then 9 positions (1 then 2 pages), slot 2 9 then 10
+    assert rt._window_rows_read(2, act) == (8 + 16) + (16 + 16)
+    assert rt._window_rows_read(3, ~act) == 3 * 24
+    assert rt._window_rows_read(2, np.zeros(SLOTS, bool)) == 0
 
+    q8 = DecodeRuntime(weights, CFG, slots=SLOTS, prefill_chunk=CHUNK,
+                       page_len=PAGE, kv_quant='int8')
+    assert not q8.paged
+    q8.host_len[:] = rt.host_len
+    assert q8._window_rows_read(2, act) == 2 * SLOTS * CFG['max_len']
+    assert q8._window_rows_read(3, ~act) == 3 * SLOTS * CFG['max_len']
+    state = q8._state_structs()
+    assert decode._gathered_rows(q8.cache, state, q8._bt_struct(1)) \
+        == CFG['max_len']
+    half = jax.ShapeDtypeStruct((SLOTS, q8.cache.max_pages // 2), 'int32')
+    assert decode._gathered_rows(q8.cache, state, half) \
+        == SLOTS * CFG['max_len'] // 2
 
 
 # --------------------------------------------- (b) the phases tile the root
