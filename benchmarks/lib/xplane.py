@@ -16,6 +16,14 @@ call; the recorded trace under benchmarks/tests/data pins it):
   start to last end, and a gap shorter than a few milliseconds may be
   named after the neighbouring host span.
 
+`summarize` returns, beside the ten costliest operations (`device_ops`,
+what the result line's `breakdown` shows), EVERY operation of the window
+under `ops`: label -> {'seconds', 'count'}, so that a metric file can
+read its own kernel's time (`op_seconds`).  The `op_name` an instruction
+was lowered from (its `jax.named_scope`) is a stat of the event's
+METADATA, which `ProfileData` does not hand out; a reader of it comes
+with the first metric that sums by scope (PERF.md, Open questions).
+
 Busy time is the UNION of the op intervals (so nesting counts once); an
 operation's own time is its duration less what it encloses.  An idle gap
 is a stretch of the traced window with no operation on the chip; it is
@@ -178,7 +186,7 @@ def summarize(pd, top=10, top_gaps=10):
     hi = max(max(e[1] for e in ops) for _, ops, _ in per_chip)
     n = float(len(per_chip))
     busy = 0.0
-    op_ns, module_ns, module_count = {}, {}, {}
+    op_ns, op_count, module_ns, module_count = {}, {}, {}, {}
     collective_ns = custom_ns = 0.0
     for _, ops, modules in per_chip:
         inside = [e for e in ops if e[1] > lo and e[0] < hi]
@@ -188,6 +196,8 @@ def summarize(pd, top=10, top_gaps=10):
             if e[2] not in parsed:
                 parsed[e[2]] = parse_op(e[2])
         labelled = [(e[0], e[1], parsed[e[2]]) for e in inside]
+        for _, _, (label, _) in labelled:
+            op_count[label] = op_count.get(label, 0) + 1
         for (label, opcode), ns in self_times(labelled).items():
             op_ns[label] = op_ns.get(label, 0.0) + ns
             if opcode and opcode.startswith(COLLECTIVES):
@@ -216,12 +226,16 @@ def summarize(pd, top=10, top_gaps=10):
             if overlap > best_overlap:
                 best, best_overlap = hname[len(SPAN_PREFIX):], overlap
         named.append([best, (e - s) / 1e9])
-    top_ops = sorted(op_ns.items(), key=lambda kv: -kv[1])[:top]
+    ranked = sorted(op_ns.items(), key=lambda kv: -kv[1])
+    ops = {label: {'seconds': ns / n / 1e9, 'count': op_count[label] / n}
+           for label, ns in ranked}
+    top_ops = ranked[:top]
     return {
         'chips': int(n),
         'window_s': (hi - lo) / 1e9,
         'busy_s': busy / n / 1e9,
         'device_ops': [[k, v / n / 1e9] for k, v in top_ops],
+        'ops': ops,
         'idle_gaps': named,
         'collective_s': collective_ns / n / 1e9,
         'custom_call_s': custom_ns / n / 1e9,
@@ -229,6 +243,15 @@ def summarize(pd, top=10, top_gaps=10):
                         'count': module_count[k] / n}
                     for k, v in module_ns.items()},
     }
+
+
+def op_seconds(summary, prefix):
+    """Own seconds of the operations whose label starts with `prefix`
+    (a kernel's `custom-call <name>`), or None where the trace has none."""
+    hits = [op['seconds'] for label, op in (summary or {}).get('ops',
+                                                               {}).items()
+            if label.startswith(prefix)]
+    return sum(hits) if hits else None
 
 
 def module_time(summary, word):

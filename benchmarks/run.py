@@ -62,13 +62,21 @@ def say(what, **fields):
 
 def wanted_metrics(manifest, cell_name, trace):
     """The metric entries this run reports: the cell's end-to-end metrics
-    without a trace, its per-layer metrics with one."""
-    out = []
-    for m in manifest['per_layer' if trace else 'end_to_end']:
-        if 'workloads' in m and cell_name not in m['workloads']:
-            continue
-        out.append(m)
-    return out
+    without a trace, its per-layer metrics with one.  Which those are is
+    BENCHMARK.json's own rule, so that a cell a later PR appends is judged
+    with no entry edited but its end-to-end metric's list: an entry that
+    lists `workloads` belongs to the cells it lists (arithmetic tied to
+    one architecture or to a mesh); an end-to-end entry without the key
+    belongs to every cell; a per-layer entry without it to every cell
+    that reports the end-to-end metric it `moves`."""
+    end_to_end = [m for m in manifest['end_to_end']
+                  if cell_name in m.get('workloads', (cell_name,))]
+    if not trace:
+        return end_to_end
+    reported = {m['name'] for m in end_to_end}
+    return [m for m in manifest['per_layer']
+            if (cell_name in m['workloads'] if 'workloads' in m
+                else m['moves'] in reported)]
 
 
 def prepare_environment():

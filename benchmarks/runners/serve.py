@@ -9,6 +9,14 @@ millisecond or two).  Latency is counted from the time a request was DUE,
 not from when it was sent.  Requests that arrive inside the window and
 finish after it are drained and counted in the latency metrics;
 `serve_tokens_per_s` counts only tokens seen inside the window.
+
+The runtime's model dict and its weights' shapes come from
+`model_dict(config, traffic)` and `weight_shapes(model)` of the
+configuration's own builds/<config>.py where it has one (README, "Adding a
+cell"); `model_dict` and `dense_weight_shapes` below are the
+fall-back for a dense decoder that brings none.  Either way the PROGRAM
+decides the weights' names: `make_weights` refuses shapes whose names are
+not `weight_names(model)`.
 """
 import shutil
 import tempfile
@@ -20,15 +28,16 @@ from lib import memory as _memory
 from lib import spans as _spans
 from lib import traffic as _traffic
 from lib import xplane as _xplane
-from runners.train import _counters, _delta, load_reference
+from runners.train import _counters, _delta, load_build, load_reference
 
 POLL_S = 0.002
 MEMORY_EVERY = 64          # polls between two readings of device memory
 
 
 def model_dict(config, traffic):
-    """The program's model dict from the configuration's published keys;
-    `max_len` is one slot's share of the pool, from the traffic file."""
+    """The fall-back: the program's model dict of a dense decoder from
+    the configuration's published keys; `max_len` is one slot's share of
+    the pool, from the traffic file."""
     return {'vocab': int(config['vocab_size']),
             'd_model': int(config['hidden_size']),
             'n_layer': int(config['num_hidden_layers']),
@@ -39,12 +48,8 @@ def model_dict(config, traffic):
             'max_len': int(traffic['slot_tokens'])}
 
 
-def make_weights(model, seed, std, dtype):
-    """Every weight on the device, from the seed, in ONE jitted call, in
-    the type it is served in."""
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.serving.generation import weight_names
+def dense_weight_shapes(model):
+    """The fall-back: {weight name: shape} of a dense decoder."""
     d, v = model['d_model'], model['vocab']
     h, hkv, f = model['n_head'], model['n_kv_head'], model['d_ffn']
     dh = d // h
@@ -56,8 +61,24 @@ def make_weights(model, seed, std, dtype):
     for i in range(model['n_layer']):
         for k, s in per_layer.items():
             shapes['layer_%d_%s' % (i, k)] = s
+    return shapes
+
+
+def make_weights(model, shapes, seed, std, dtype):
+    """Every weight of `shapes` on the device, from the seed, in ONE
+    jitted call, in the type it is served in: a norm's scale is ones,
+    anything else normal with deviation `std`."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving.generation import weight_names
     names = weight_names(model)
-    assert sorted(names) == sorted(shapes), 'weight layout drifted'
+    if sorted(names) != sorted(shapes):
+        raise ValueError(
+            'weight layout drifted: the program names %s which the '
+            'configuration gives no shape, and the configuration shapes %s '
+            'which the program does not name'
+            % (sorted(set(names) - set(shapes)) or 'nothing',
+               sorted(set(shapes) - set(names)) or 'nothing'))
     dt = jnp.dtype(dtype)
 
     def init(key):
@@ -80,8 +101,11 @@ def build_runtime(config, traffic, seed, spans, record):
     """The runtime, its weights and warm executables, with the
     benchmark's spans wrapped around its two launch calls."""
     from paddle_tpu.serving.generation import DecodeRuntime
-    model = model_dict(config, traffic)
-    weights = make_weights(model, seed, float(config['initializer_range']),
+    own = record['build'] = load_build(config)
+    model = getattr(own, 'model_dict', model_dict)(config, traffic)
+    shapes = getattr(own, 'weight_shapes', dense_weight_shapes)(model)
+    weights = make_weights(model, shapes, seed,
+                           float(config['initializer_range']),
                            config['torch_dtype'])
     rt = DecodeRuntime(weights, model, slots=int(traffic['slots']),
                        prefill_chunk=int(traffic['prefill_chunk']),
@@ -349,6 +373,7 @@ def run(cell, config, traffic, seed, seconds, trace, t_start, device, say):
         'counters': window,
         'setup_counters': _delta(c_warm, c_start),
         'trace': summary,
+        'build': record['build'],
         'config': config,
         'traffic': traffic,
         'device': device,

@@ -68,6 +68,11 @@ def test_every_metric_has_a_reader_that_agrees(manifest):
             for key in ('unit', 'better', 'source'):
                 assert reader.META[key] == m[key], (m['name'], key)
             assert set(m.get('workloads', cells)) <= set(cells)
+            # the contract's keys and no other (a `group`, a `why`)
+            assert set(m) - {'workloads'} == (
+                {'name', 'unit', 'better', 'source', 'bound'}
+                if kind == 'end_to_end' else
+                {'name', 'unit', 'better', 'source', 'layer', 'moves'})
             if kind == 'end_to_end':
                 assert 0.01 <= m['bound'] <= 0.1
                 assert m['source'] in ('host_clock', 'device_trace')
@@ -75,8 +80,9 @@ def test_every_metric_has_a_reader_that_agrees(manifest):
             assert reader.META['layer'] == m['layer']
             assert reader.META['moves'] == m['moves']
             moved = e2e[m['moves']]
-            # the moved metric is reported wherever this one is
-            assert set(m.get('workloads', cells)) <= set(
+            # the moved metric is reported wherever this one is listed
+            # (an entry that lists nothing follows it by construction)
+            assert set(m.get('workloads', ())) <= set(
                 moved.get('workloads', cells)), m['name']
 
 
@@ -86,6 +92,92 @@ def test_every_cell_reports_setup_another_metric_and_a_layer(manifest):
         e2e = [m['name'] for m in run.wanted_metrics(manifest, w['name'], 0)]
         assert 'setup_s' in e2e and len(e2e) >= 2
         assert run.wanted_metrics(manifest, w['name'], 1)
+
+
+# what each cell reported at the parent of PR 26 (558fc82), by kind: the
+# three cells that existed must report exactly these names still
+TRAIN_LAYERS = {
+    'input.wait_share', 'executor.host_ms_per_step', 'executor.stall_share',
+    'executor.segment_median_rate', 'setup.compile_s', 'setup.cache_load_s',
+    'step.mfu', 'kernels.pallas_share', 'device.idle_share',
+    'executor.inside_host_ms_per_step', 'input.starved_share'}
+SERVE_LAYERS = {
+    'setup.compile_s', 'setup.cache_load_s', 'scheduler.batch_occupancy',
+    'decode.step_ms', 'decode_step_roofline', 'prefill.chunk_ms',
+    'ttft_p50_ms', 'ttft_p90_ms', 'serve_tokens_per_s',
+    'generator.late_ms_p90', 'serve.device_idle_share',
+    'scheduler.queue_wait_ms', 'scheduler.prefill_phase_ms',
+    'scheduler.rounds_to_first_token', 'scheduler.host_gap_share',
+    'scheduler.idle_wait_share', 'scheduler.live_slot_share',
+    'prefill.host_ms_per_chunk', 'prefill.useful_token_share',
+    'decode.host_ms_per_window', 'decode.kv_read_useful_share'}
+WANTED = {
+    'tbase.train_1chip': ({'train_rate', 'setup_s'}, TRAIN_LAYERS),
+    'resnet50.train_1chip': ({'train_rate', 'setup_s'}, TRAIN_LAYERS),
+    'mistral7b.chat_steady': ({'tpot_p50_ms', 'setup_s'},
+                              SERVE_LAYERS | {'decode.paged_attention_share'}),
+    'tbase.train_dp4': ({'train_rate', 'setup_s'},
+                        TRAIN_LAYERS | {'collective.exposed_share'}),
+}
+
+
+@pytest.mark.parametrize('cell', sorted(WANTED))
+def test_a_cell_reports_the_names_it_reported_before(manifest, cell):
+    import run
+    end_to_end, layers = WANTED[cell]
+    assert {m['name'] for m in run.wanted_metrics(manifest, cell, 0)} \
+        == end_to_end
+    assert {m['name'] for m in run.wanted_metrics(manifest, cell, 1)} \
+        == layers
+
+
+def test_only_arithmetic_tied_to_an_architecture_lists_cells(manifest):
+    """The rule that lets a later PR append a cell: a per-layer entry
+    names cells only where its arithmetic belongs to one architecture or
+    to a mesh, and an end-to-end entry only as the contract makes it
+    ("An end-to-end metric that exists only in some cells lists them")."""
+    listed = {m['name'] for m in manifest['per_layer'] if 'workloads' in m}
+    assert listed == {'decode_step_roofline', 'decode.paged_attention_share',
+                      'collective.exposed_share'}
+    assert {m['name'] for m in manifest['end_to_end'] if 'workloads' in m} \
+        == {'train_rate', 'tpot_p50_ms'}
+
+
+def test_an_appended_cell_takes_its_kinds_readers_and_declines_the_rest(
+        manifest):
+    """wanted_metrics is a pure function of the manifest: a cell appended
+    with nothing but its name in its end-to-end metric's list gets every
+    reader that follows that metric, and none that lists cells."""
+    import copy
+    import run
+    later = copy.deepcopy(manifest)
+    later['workloads'].append({'name': 'moe.chat', 'config': 'moe',
+                               'traffic': 'chat', 'chips': 1, 'why': 'x'})
+    for m in later['end_to_end']:
+        if m['name'] == 'tpot_p50_ms':
+            m['workloads'].append('moe.chat')
+    assert {m['name'] for m in run.wanted_metrics(later, 'moe.chat', 0)} \
+        == {'tpot_p50_ms', 'setup_s'}
+    assert {m['name'] for m in run.wanted_metrics(later, 'moe.chat', 1)} \
+        == SERVE_LAYERS - {'decode_step_roofline'}
+    # and the cells that were there report what they did
+    for cell, (end_to_end, layers) in WANTED.items():
+        assert {m['name'] for m in run.wanted_metrics(later, cell, 1)} \
+            == layers
+
+
+def test_the_four_chip_cell_keeps_the_one_chip_cells_batch_a_chip(manifest):
+    cells = {w['name']: w for w in manifest['workloads']}
+    import run
+    one = run.load_json(BENCH, 'traffic',
+                        cells['tbase.train_1chip']['traffic'] + '.json')
+    four = run.load_json(BENCH, 'traffic',
+                         cells['tbase.train_dp4']['traffic'] + '.json')
+    assert cells['tbase.train_dp4']['chips'] == 4 == four['mesh']['data']
+    assert four['batch'] == 4 * one['batch']
+    for key in ('seq', 'steps_per_launch', 'launches_per_segment', 'feeds',
+                'generator', 'pool_batches', 'warm_launches'):
+        assert four[key] == one[key], key
 
 
 def test_a_configuration_may_set_only_the_named_switches(manifest,

@@ -94,6 +94,19 @@ def test_summarize_by_hand():
     assert sum(t for _, t in gaps) == 3000 - 2200
 
 
+def test_every_operation_is_kept_with_its_count():
+    s = xplane.summarize(_fake())
+    assert [k for k, _ in s['device_ops']] == list(s['ops'])[:10]
+    assert s['ops']['fusion:Loop f32[8]'] == {
+        'seconds': pytest.approx(700e-9), 'count': 2.0}
+    assert sum(op['seconds'] for op in s['ops'].values()) \
+        == pytest.approx(s['busy_s'])
+    assert xplane.op_seconds(s, 'custom-call layer_norm_rows') \
+        == pytest.approx(1000e-9)
+    assert xplane.op_seconds(s, 'custom-call paged_attention') is None
+    assert xplane.op_seconds(None, 'fusion') is None
+
+
 def test_several_chips_average():
     s = xplane.summarize(_fake(two_chips=True))
     assert s['chips'] == 2
@@ -154,3 +167,16 @@ def test_recorded_trace_reduces_to_sane_numbers(recorded):
     assert idle <= s['window_s'] - s['busy_s'] + 1e-9
     assert {n for n, _ in s['idle_gaps']} <= {'launch', 'fetch', 'feed',
                                               'unattributed'}
+
+
+def test_recorded_trace_keeps_every_operation(recorded):
+    """`ops` holds ALL the window's operations: it sums to the busy time
+    (nothing overlaps on one TensorCore) and `device_ops` is its head."""
+    s = xplane.summarize(recorded)
+    assert sum(op['seconds'] for op in s['ops'].values()) \
+        == pytest.approx(s['busy_s'], rel=1e-9)
+    assert [[k, op['seconds']] for k, op in list(s['ops'].items())[:10]] \
+        == s['device_ops']
+    assert len(s['ops']) == 6
+    assert s['ops']['fusion:Output convolution_tanh_fusion bf16[256,256]'][
+        'count'] == 9                # three launches of a 3-step scan
