@@ -5,6 +5,7 @@ group_norm_op, softmax_op, dropout_op, lrn_op, interpolate_op, etc.
 Convs/pools use lax.conv_general_dilated / lax.reduce_window in NCHW — XLA
 lays them out for the MXU; no cuDNN-style algo selection needed.
 """
+import functools
 import os
 
 import numpy as np
@@ -182,6 +183,75 @@ def adaptive_pool3d(ctx, ins, attrs):
                                   attrs.get('pooling_type', 'max'), 3)}
 
 
+def _bn_shapes(x, ch_axis):
+    axes = tuple(i for i in range(x.ndim) if i != ch_axis)
+    bshape = [1] * x.ndim
+    bshape[ch_axis] = x.shape[ch_axis]
+    return axes, bshape
+
+
+def _bn_train_fwd(x, scale, bias, ch_axis, eps):
+    axes, bshape = _bn_shapes(x, ch_axis)
+    xf = x.astype(jnp.float32)
+    # the pilot is cut from x BEFORE the upcast (the same values): cut
+    # from xf, XLA:TPU writes all of xf to HBM in the forward so that a
+    # later fusion can read these few elements of it
+    c = lax.stop_gradient(x[tuple(
+        slice(None) if i == ch_axis else slice(0, 1)
+        for i in range(x.ndim))].astype(jnp.float32))
+    d = xf - c
+    md = jnp.mean(d, axis=axes, keepdims=True)
+    v_raw = jnp.mean(jnp.square(d), axis=axes, keepdims=True) \
+        - jnp.square(md)
+    v = jnp.maximum(v_raw, 0.0)
+    m = (md + c).reshape(x.shape[ch_axis])
+    v = v.reshape(x.shape[ch_axis])
+    y = (d - md) * (
+        scale.reshape(bshape) * lax.rsqrt(v.reshape(bshape) + eps)) + \
+        bias.reshape(bshape)
+    # residuals: x as it came (the convolution's bf16 output under AMP,
+    # which exists anyway) and per-channel f32 vectors; no array of x's
+    # shape in f32
+    return (y.astype(x.dtype), m, v), (x, c, md, v_raw, scale)
+
+
+def _bn_train_bwd(ch_axis, eps, res, cts):
+    """The gradient AD derives from _bn_train_fwd's formula, from x in
+    its own dtype: d - md is recomputed in f32 inside the fusions that
+    read it, every sum is f32, dx returns in x's dtype.  gm and gv are
+    the cotangents of the saved mean and variance (zero in a training
+    step, where only stop_gradient'd moving statistics read them)."""
+    x, c, md, v_raw, scale = res
+    dy, gm, gv = cts
+    axes, bshape = _bn_shapes(x, ch_axis)
+    n = x.size // x.shape[ch_axis]
+    scale, gm, gv = (a.reshape(bshape) for a in (scale, gm, gv))
+    r = lax.rsqrt(jnp.maximum(v_raw, 0.0) + eps)
+    dyf = dy.astype(jnp.float32)
+    dm = x.astype(jnp.float32) - c - md
+    dbias = jnp.sum(dyf, axis=axes, keepdims=True)
+    dyd = jnp.sum(dyf * dm, axis=axes, keepdims=True)
+    # through the variance: r = (v + eps) ** -0.5, and the clamp passes
+    # nothing where it binds (half at a tie: AD's rule for maximum)
+    g_raw = (gv - 0.5 * scale * dyd * r * r * r) * jnp.where(
+        v_raw > 0.0, 1.0, jnp.where(v_raw == 0.0, 0.5, 0.0))
+    sr = scale * r
+    dx = sr * dyf + (2.0 / n) * g_raw * dm + (gm - sr * dbias) / n
+    return (dx.astype(x.dtype), (dyd * r).reshape(x.shape[ch_axis]),
+            dbias.reshape(x.shape[ch_axis]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _bn_train(x, scale, bias, ch_axis, eps):
+    """Training-mode batch norm over every axis but ch_axis: shifted
+    one-pass f32 statistics (see batch_norm), y in x's dtype, the batch
+    mean and variance per channel."""
+    return _bn_train_fwd(x, scale, bias, ch_axis, eps)[0]
+
+
+_bn_train.defvjp(_bn_train_fwd, _bn_train_bwd)
+
+
 @register('batch_norm')
 def batch_norm(ctx, ins, attrs):
     x = ins['X']
@@ -192,9 +262,7 @@ def batch_norm(ctx, ins, attrs):
     is_test = attrs.get('is_test', False)
     layout = attrs.get('data_layout', 'NCHW')
     ch_axis = 1 if layout == 'NCHW' else x.ndim - 1
-    axes = tuple(i for i in range(x.ndim) if i != ch_axis)
-    bshape = [1] * x.ndim
-    bshape[ch_axis] = x.shape[ch_axis]
+    axes, bshape = _bn_shapes(x, ch_axis)
     # statistics always accumulate in f32 (bf16 mean/var over B*H*W
     # elements would lose ~5 bits); y returns in the input dtype so AMP
     # activations stay half-width in HBM
@@ -228,22 +296,17 @@ def batch_norm(ctx, ins, attrs):
         return {'Y': y.astype(x.dtype), 'MeanOut': new_mean,
                 'VarianceOut': new_var, 'SavedMean': m,
                 'SavedVariance': v}
-    c = lax.stop_gradient(xf[tuple(
-        slice(None) if i == ch_axis else slice(0, 1)
-        for i in range(x.ndim))])
-    d = xf - c
-    md = jnp.mean(d, axis=axes, keepdims=True)
-    v = jnp.maximum(
-        jnp.mean(jnp.square(d), axis=axes, keepdims=True)
-        - jnp.square(md), 0.0)
-    m = (md + c).reshape(x.shape[ch_axis])
-    v = v.reshape(x.shape[ch_axis])
-    y = (d - md) * (
-        scale.reshape(bshape) * lax.rsqrt(v.reshape(bshape) + eps)) + \
-        bias.reshape(bshape)
+    # the backward is written out (_bn_train): its residuals are x in
+    # its own dtype and per-channel vectors, where AD of this formula
+    # saves two f32 arrays of x's shape (2d and d - md) and leaves it
+    # to the compiler to recompute them from x
+    from ..observability import metrics
+    metrics.counter('batch_norm.recompute_vjp').inc()
+    y, m, v = _bn_train(x, scale.astype(jnp.float32),
+                        bias.astype(jnp.float32), ch_axis, eps)
     new_mean = lax.stop_gradient(momentum * mean + (1 - momentum) * m)
     new_var = lax.stop_gradient(momentum * var + (1 - momentum) * v)
-    return {'Y': y.astype(x.dtype), 'MeanOut': new_mean,
+    return {'Y': y, 'MeanOut': new_mean,
             'VarianceOut': new_var, 'SavedMean': m, 'SavedVariance': v}
 
 
