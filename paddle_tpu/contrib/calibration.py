@@ -33,6 +33,11 @@ def kl_scale(samples, bins=2048, dst_bins=255):
     # robust histogram range: far outliers must not stretch the binning
     # (everything beyond the range saturates into the edge bin below)
     amax = min(amax, 4.0 * float(np.percentile(x, 99.0)) + 1e-12)
+    # with a sample or two a bin the divergence is counting noise that
+    # grows with the bins kept, and the search lands on its 70% floor
+    # whatever the data.  Few samples get few bins, down to dst_bins + 1,
+    # where the only candidate left is (nearly) the observed range.
+    bins = max(dst_bins + 1, min(bins, x.size // 16))
     hist, edges = np.histogram(np.minimum(x, amax), bins=bins,
                                range=(0.0, amax))
     hist = hist.astype(np.float64)

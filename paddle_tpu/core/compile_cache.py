@@ -120,7 +120,6 @@ def _environment_blob():
         'jaxlib': jaxlib.__version__,
         'backend': backend,
         'x64': bool(jax.config.jax_enable_x64),
-        'amp_flow': os.environ.get('PT_AMP_FLOW', 'conv'),
         'source': source_digest(),
     }
 

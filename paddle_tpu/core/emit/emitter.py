@@ -287,7 +287,7 @@ def _memo_fn(op, ins, amp, dmask, mesh):
         lambda x: (np.shape(x), str(jnp.result_type(x))), ins)
     dkey = tuple(sorted(dmask.items()))
     key = (op.type, _canon_attrs(op.type, op.attrs), _canonv(avals),
-           use_amp, amp, op.type in _ex._REMAT_OPS, dkey, _mesh_key(mesh),
+           use_amp, amp, dkey, _mesh_key(mesh),
            _kg_token() if op.type == 'fused_elementwise' else None)
     fn = _MEMO.get(key)
     if fn is None:
@@ -337,8 +337,6 @@ def _memo_fn(op, ins, amp, dmask, mesh):
                     pruned[s] = vs if mm[0] else None
             return pruned
 
-        if otype in _ex._REMAT_OPS:
-            pure_op = jax.checkpoint(pure_op)
         fn = jax.jit(pure_op)
         _MEMO[key] = fn
     return fn

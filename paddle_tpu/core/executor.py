@@ -130,29 +130,20 @@ def _zero_cotangent(v):
 
 # MXU-bound ops worth running in bfloat16 under AMP (matmul/conv class):
 # their f32 inputs cast down to bf16.  What happens to the OUTPUT is
-# per-class, decided by measurement on TPU v5 lite (see PERF.md):
+# per-class:
 #   - conv class: outputs STAY bf16 ("flow-through") — activations keep
 #     half-width through the BN/relu/residual chains, halving HBM traffic.
-#     ResNet-50 measured +25% img/s from this alone.
 #   - matmul/attention class: outputs cast back to f32 (the cast fuses
-#     into the GEMM epilogue).  Flow-through measured 4% SLOWER on the
-#     transformer: its hot f32 consumers (layer_norm stats, the CE
-#     logsumexp) upcast anyway, so bf16 outputs only add VPU cast work.
+#     into the GEMM epilogue): its hot f32 consumers (layer_norm stats,
+#     the CE logsumexp) upcast anyway, so bf16 outputs only add VPU cast
+#     work.
 # Numerics-sensitive ops (norm statistics, softmax, cross-entropy)
 # upcast internally to f32 in their impls, so precision-critical
 # reductions never run in bf16 either way.
-# PT_AMP_FLOW=0 / PT_AMP_FLOW=all override the split for A/B runs.
 _AMP_CAST_OPS = {'mul', 'matmul', 'flash_attention', 'ring_attention',
                  'bilinear_tensor_product'}
 _AMP_FLOW_OPS = {'conv2d', 'conv3d', 'conv2d_transpose',
                  'conv3d_transpose', 'sequence_conv'}
-_flow_env = os.environ.get('PT_AMP_FLOW', 'conv')
-if _flow_env == '0':
-    _AMP_CAST_OPS = _AMP_CAST_OPS | _AMP_FLOW_OPS
-    _AMP_FLOW_OPS = set()
-elif _flow_env == 'all':
-    _AMP_FLOW_OPS = _AMP_FLOW_OPS | _AMP_CAST_OPS
-    _AMP_CAST_OPS = set()
 _AMP_OPS = _AMP_CAST_OPS | _AMP_FLOW_OPS
 
 # Elementwise glue: under AMP, if any float input is already bf16, cast
@@ -162,15 +153,6 @@ _AMP_OPS = _AMP_CAST_OPS | _AMP_FLOW_OPS
 # untouched.
 _AMP_MATCH = {'elementwise_add', 'elementwise_sub', 'elementwise_mul',
               'elementwise_div', 'elementwise_max', 'elementwise_min'}
-
-# Rematerializing softmax_with_cross_entropy (jax.checkpoint so the f32
-# [B, T, V] log-prob residual never persists to backward) was measured
-# 19% SLOWER end-to-end on TPU v5 lite (PERF.md): the recomputed
-# logsumexp pass costs more than the saved HBM round-trip at bench
-# shapes.  Kept behind PT_CE_REMAT=1 for re-testing on other parts.
-_REMAT_OPS = ({'softmax_with_cross_entropy'}
-              if os.environ.get('PT_CE_REMAT', '0') == '1' else set())
-
 
 def _amp_cast(x, to):
     import jax.numpy as jnp
@@ -378,12 +360,7 @@ def _exec_ops_plain(ops, op_offset, env, ectx, program):
         if amp:
             ins = _amp_match_ins(op.type, ins)
         ctx = ectx.for_op(op_offset + i, op)
-        if op.type in _REMAT_OPS:
-            outs = jax.checkpoint(
-                lambda kw, _impl=impl, _ctx=ctx, _a=op.attrs:
-                _impl(_ctx, kw, _a))(ins)
-        else:
-            outs = impl(ctx, ins, op.attrs)
+        outs = impl(ctx, ins, op.attrs)
         # amp_keep_bf16: per-op opt-out of the cast-back policy for a
         # GEMM whose consumers are bf16-tolerant (e.g. the logit
         # projection feeding softmax_with_cross_entropy, which upcasts

@@ -211,147 +211,6 @@ def generation_program(config='tiny', mode='decode', temperature=0.0,
     return out
 
 
-# ----------------------------------------------------------- decoding
-
-def make_decoder(scope, config='tiny', temperature=0.0, **overrides):
-    """Build a jitted KV-cache autoregressive decoder over the weights a
-    trained llama program left in `scope` (same parameter names).
-
-    The graph program is the training/scoring path; decode is a separate
-    pure-JAX path because its structure differs (per-step KV cache, not
-    teacher forcing) — the analogue of the reference's beam_search decode
-    programs (machine_translation infer program).  Static shapes: the
-    cache is [n_layer, B, Hkv, Tmax, Dh], current length carried as a
-    scalar; attention masks by position, so every step compiles once.
-
-    Returns generate(prompt_ids [B, Tp] int32, max_new) -> [B, Tp+max_new].
-    """
-    import jax
-    import jax.numpy as jnp
-
-    cfg = dict(CONFIGS[config] if isinstance(config, str) else config)
-    cfg.update(overrides)
-    L, H, Hkv = cfg['n_layer'], cfg['n_head'], cfg['n_kv_head']
-    D, V, theta = cfg['d_model'], cfg['vocab'], cfg['theta']
-    Tmax = cfg['max_len']
-    dh = D // H
-
-    def g(name):
-        return jnp.asarray(scope.vars[name])
-
-    w = {'emb': g('tok_emb'), 'final': g('final_norm'),
-         'proj': g('lm_proj_w')}
-    for i in range(L):
-        p = 'layer_%d' % i
-        for s in ('att_q_w', 'att_k_w', 'att_v_w', 'att_o_w', 'att_norm',
-                  'ffn_norm', 'ffn_fc1_w', 'ffn_fc2_w', 'ffn_fc3_w'):
-            w['%d_%s' % (i, s)] = g('%s_%s' % (p, s))
-
-    def rms(x, scale):
-        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(var + 1e-6) * scale
-
-    def rope_at(x, pos):
-        # x: [B, h, T, dh]; pos: [T] absolute positions
-        freqs = theta ** (-jnp.arange(0, dh // 2) * 2.0 / dh)
-        ang = pos[None, None, :, None] * freqs            # [1,1,T,dh/2]
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
-        x1, x2 = x[..., 0::2], x[..., 1::2]
-        return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                         axis=-1).reshape(x.shape)
-
-    def attn(x, i, kcache, vcache, pos, cur_len):
-        """x: [B, T, D] new positions starting at `pos[0]`; returns output
-        plus updated caches."""
-        B, T = x.shape[0], x.shape[1]
-        q = (x @ w['%d_att_q_w' % i]).reshape(B, T, H, dh)
-        k = (x @ w['%d_att_k_w' % i]).reshape(B, T, Hkv, dh)
-        v = (x @ w['%d_att_v_w' % i]).reshape(B, T, Hkv, dh)
-        q = rope_at(q.transpose(0, 2, 1, 3), pos)
-        k = rope_at(k.transpose(0, 2, 1, 3), pos)
-        v = v.transpose(0, 2, 1, 3)
-        kcache = jax.lax.dynamic_update_slice(
-            kcache, k.astype(kcache.dtype), (0, 0, pos[0], 0))
-        vcache = jax.lax.dynamic_update_slice(
-            vcache, v.astype(vcache.dtype), (0, 0, pos[0], 0))
-        # GQA attention of q [B,H,T,dh] against cache [B,Hkv,Tmax,dh]
-        qg = q.reshape(B, Hkv, H // Hkv, T, dh)
-        s = jnp.einsum('bhgqd,bhkd->bhgqk', qg, kcache) * (dh ** -0.5)
-        kpos = jnp.arange(Tmax)
-        qpos = pos  # [T]
-        mask = (kpos[None, :] <= qpos[:, None]) & \
-            (kpos[None, :] < cur_len + T)
-        s = jnp.where(mask[None, None, None], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum('bhgqk,bhkd->bhgqd', p, vcache)
-        o = o.reshape(B, H, T, dh).transpose(0, 2, 1, 3).reshape(B, T, D)
-        return o @ w['%d_att_o_w' % i], kcache, vcache
-
-    def block(x, i, kc, vc, pos, cur_len):
-        h, kc, vc = attn(rms(x, w['%d_att_norm' % i]), i, kc, vc, pos,
-                         cur_len)
-        x = x + h
-        hh = rms(x, w['%d_ffn_norm' % i])
-        gate = jax.nn.silu(hh @ w['%d_ffn_fc1_w' % i])
-        up = hh @ w['%d_ffn_fc3_w' % i]
-        x = x + (gate * up) @ w['%d_ffn_fc2_w' % i]
-        return x, kc, vc
-
-    def forward(tokens, kcaches, vcaches, pos, cur_len):
-        x = w['emb'][tokens]                               # [B, T, D]
-        new_k, new_v = [], []
-        for i in range(L):
-            x, kc, vc = block(x, i, kcaches[i], vcaches[i], pos, cur_len)
-            new_k.append(kc)
-            new_v.append(vc)
-        x = rms(x, w['final'])
-        return x @ w['proj'], jnp.stack(new_k), jnp.stack(new_v)
-
-    def pick(logits, key):
-        if temperature and temperature > 0:
-            return jax.random.categorical(key, logits / temperature, -1)
-        return jnp.argmax(logits, axis=-1)
-
-    import functools
-
-    @functools.partial(jax.jit, static_argnums=(1,))
-    def generate(prompt, max_new, seed=0):
-        B, Tp = prompt.shape
-        kc = jnp.zeros((L, B, Hkv, Tmax, dh), jnp.float32)
-        vc = jnp.zeros_like(kc)
-        logits, kc, vc = forward(prompt, kc, vc, jnp.arange(Tp),
-                                 jnp.int32(0))
-        key = jax.random.key(seed)
-        nxt = pick(logits[:, -1], key)
-
-        def step(carry, t):
-            kc, vc, tok, key = carry
-            key, sub = jax.random.split(key)
-            logits, kc, vc = forward(tok[:, None], kc, vc,
-                                     jnp.array([0]) + Tp + t,
-                                     Tp + t)
-            nxt = pick(logits[:, 0], sub)
-            return (kc, vc, nxt, key), tok
-
-        # prefill already produced one token; scan emits the rest
-        (_, _, last, _), toks = jax.lax.scan(
-            step, (kc, vc, nxt, key), jnp.arange(max_new - 1))
-        out = jnp.concatenate([toks.T, last[:, None]], axis=1)
-        return jnp.concatenate([prompt, out], axis=1)
-
-    def run(prompt_ids, max_new, seed=0):
-        import numpy as np
-        if max_new <= 0:
-            # prefill would still emit one token; zero requested -> no-op
-            return np.asarray(prompt_ids)
-        prompt = jnp.asarray(np.asarray(prompt_ids), jnp.int32)
-        if prompt.shape[1] + max_new > Tmax:
-            raise ValueError('prompt+max_new exceeds max_len=%d' % Tmax)
-        return np.asarray(generate(prompt, int(max_new), seed))
-
-    return run
-
-
 # ------------------------------------------------- streaming generation
 
 def generation_weights(scope, config='tiny', **overrides):
@@ -367,9 +226,9 @@ def generation_weights(scope, config='tiny', **overrides):
 def make_streaming_runtime(scope, config='tiny', slots=4, prefill_chunk=8,
                            mesh=None, **overrides):
     """Build a serving.generation.DecodeRuntime over a trained scope:
-    the streaming-decode counterpart of `make_decoder` (same weights,
-    but a slotted multi-request KV cache, fused K-token decode windows,
-    and chunked/ring prefill — the device half of GenerationEngine).
+    the decode path beside the Program (same weights; a slotted
+    multi-request KV cache, fused K-token decode windows, and
+    chunked/ring prefill — the device half of GenerationEngine).
 
         rt = llama.make_streaming_runtime(scope, 'tiny', slots=8)
         engine = GenerationEngine(rt).start()

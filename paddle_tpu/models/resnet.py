@@ -2,10 +2,8 @@
 
 Built NCHW with conv+BN blocks; XLA lays out for MXU.  `dtype='bfloat16'`
 runs the conv stack in bf16 with f32 batch-norm statistics — the TPU fast
-path used by bench.py.
+path.
 """
-import numpy as np
-
 import paddle_tpu as fluid
 
 
@@ -103,20 +101,3 @@ def build(data_shape=(3, 224, 224), class_dim=1000, depth=50, lr=0.1,
     return {'loss': avg_cost, 'accuracy': batch_acc,
             'feeds': [images, label], 'predict': predict, 'optimizer': opt}
 
-
-def bench_program(B=128, side=224, classes=1000, depth=50, lr=0.1,
-                  seed=0):
-    """The canonical ResNet-50 bench step + synthetic feed, shared by
-    bench.py / tools/tune_tpu.py / tools/measure.py so every harness
-    profiles the SAME program (r5 review).  Returns
-    (main, startup, out, feed)."""
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        with fluid.unique_name.guard():
-            out = build(data_shape=(3, side, side), class_dim=classes,
-                        depth=depth, lr=lr)
-    main.set_amp(True)
-    rng = np.random.RandomState(seed)
-    feed = {'data': rng.rand(B, 3, side, side).astype('float32'),
-            'label': rng.randint(0, classes, (B, 1)).astype('int64')}
-    return main, startup, out, feed

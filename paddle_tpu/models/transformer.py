@@ -244,9 +244,8 @@ def build(src_vocab=10000, trg_vocab=10000, max_len=64, n_layer=6, n_head=8,
 
 
 def synthetic_batch(rng, batch_size, max_len, vocab=32000):
-    """Full-length synthetic (src, trg_in, trg_out) feeds for benchmarks
-    (bench.py / tools/) — ONE definition so every harness measures the
-    same feed contract."""
+    """Full-length synthetic (src, trg_in, trg_out) feeds (chip_smoke.py;
+    benchmarks/lib/traffic.py vectorises the same contract)."""
     rows = []
     for _ in range(batch_size):
         s = rng.randint(3, vocab, (max_len - 1,))

@@ -10,8 +10,8 @@ Three consumers, one source of truth:
     Histograms render as real cumulative-``le`` histograms straight
     from the bounded log buckets.
   * ``telemetry_snapshot(section, ...)`` is the ONE JSON emitter behind
-    ``bench.py``'s telemetry block, ``tools/serve_soak.py`` and
-    ``tools/fault_soak.py`` — each section's keys live in ``SCHEMA``,
+    ``tools/serve_soak.py``, ``tools/fault_soak.py`` and
+    ``tools/pod_soak.py`` — each section's keys live in ``SCHEMA``,
     so a renamed counter breaks one declarative table (which ci_smoke
     validates once) instead of silently drifting three tools apart.
   * ``MetricsServer`` serves ``/metrics`` (Prometheus text),
@@ -79,49 +79,11 @@ def render_prometheus():
 
 
 # ------------------------------------------------- shared JSON schema
-# Spec kinds: ('int'|'sec', counter) read one counter (sec rounds to ms
-# precision); ('delta_int', counter) subtracts the baseline snapshot;
-# ('sum_int', names) / ('ratio', num, den) derive; ('quantile', hist, q)
-# reads a bounded-histogram quantile; ('extra',) must be supplied by the
-# caller (values the registry can't know — platform, program op counts);
-# ('block_prefix', prefixes, names) / ('block_names', names) build the
-# nested counters dict soak tools print.
+# Spec kinds: ('int', counter) reads one counter; ('sum_int', names) /
+# ('ratio', num, den) derive; ('quantile', hist, q) reads a
+# bounded-histogram quantile; ('block_prefix', prefixes, names) /
+# ('block_names', names) build the nested counters dict soak tools print.
 SCHEMA = {
-    'bench': (
-        ('platform', ('extra',)),
-        ('device_kind', ('extra',)),
-        ('retraces', ('delta_int', 'executor.retraces')),
-        ('retraces_total', ('int', 'executor.retraces')),
-        ('compiles', ('int', 'executor.compiles')),
-        ('compile_s', ('sec', 'executor.compile_s')),
-        ('compile_s_cold', ('sec', 'executor.compile_s')),
-        ('compile_s_warm', ('sec', 'compile_cache.load_s')),
-        ('compile_cache_hits', ('int', 'compile_cache.disk_hits')),
-        ('compile_cache_misses', ('int', 'compile_cache.disk_misses')),
-        ('tail_splits', ('int', 'executor.tail_splits')),
-        ('emit_s', ('sec', 'executor.emit_s')),
-        ('trace_s', ('sec', 'executor.trace_s')),
-        ('backend_compile_s', ('sec', 'executor.backend_compile_s')),
-        ('program_op_count_raw', ('extra',)),
-        ('program_op_count_opt', ('extra',)),
-        ('opt_pass_ms', ('sec', 'opt.pass_ms')),
-        ('opt_ops_fused', ('int', 'opt.ops_fused')),
-        ('stall_count', ('delta_int', 'executor.stall_count')),
-        ('prefetch_starvation_s', ('sec', 'prefetch.starvation_s')),
-        ('kernel_fallbacks', ('int', 'kernel.fallbacks')),
-        ('emitter_fallbacks', ('int', 'emitter.fallbacks')),
-        ('kernelgen_ops', ('int', 'kernelgen.ops')),
-        ('kernelgen_fallbacks', ('int', 'kernelgen.fallbacks')),
-        ('autotune_searches', ('int', 'kernelgen.autotune_searches')),
-        ('autotune_cache_hits', ('int',
-                                 'kernelgen.autotune_cache_hits')),
-        ('fused_adam_ms', ('extra',)),
-        ('host_blocked_s', ('sec', 'executor.host_blocked_s')),
-        ('nan_poll_lag_steps', ('int', 'nan_poll.lag_steps')),
-        ('prefetch_upload_overlap_s', ('sec', 'prefetch.upload_overlap_s')),
-        ('forensics_replays', ('int', 'recovery.forensics_replay_steps')),
-        ('quarantined_samples', ('int', 'feed.quarantined')),
-    ),
     'serving': (
         ('admitted', ('int', 'serving.admitted')),
         ('terminal_replies', ('sum_int', ('serving.completed',
@@ -165,156 +127,16 @@ SCHEMA = {
     ),
 }
 
-# ------------------------------------------- perf-lab record sections
-# One ``perflab.<scenario>`` section per performance-lab scenario
-# (observability/perflab.py validates every ledger record against its
-# section).  These use a different spec vocabulary from the telemetry
-# sections above — they describe RECORD metrics and how `perflab
-# compare` treats them, not how to read the live registry:
-#
-#   ('counter', 'lower'|'higher')      deterministic integer.  Exact,
-#       zero tolerance: any move in the worse direction (away from the
-#       declared better direction) is a regression.  CI-enforceable on
-#       CPU — op counts, fallbacks and retraces don't depend on clock
-#       noise.
-#   ('timing', 'lower'|'higher', unit) noise-bounded float (or null
-#       when unmeasurable, e.g. MFU off-TPU).  Best-of-K with the raw
-#       samples recorded in the record's ``spread`` block; compared
-#       only when baseline and candidate share a backend, within a
-#       per-metric relative threshold widened by the observed spread.
-#   ('info', )                         descriptive context (shapes,
-#       request counts).  Never compared.
-SCHEMA.update({
-    'perflab.train_transformer': (
-        ('program_op_count_opt', ('counter', 'lower')),
-        ('compiles_after_warmup', ('counter', 'lower')),
-        ('retraces', ('counter', 'lower')),
-        ('kernel_fallbacks', ('counter', 'lower')),
-        ('kernelgen_fallbacks', ('counter', 'lower')),
-        ('emitter_fallbacks', ('counter', 'lower')),
-        ('tokens_per_s', ('timing', 'higher', 'tokens/s')),
-        ('mfu', ('timing', 'higher', 'ratio')),
-        ('host_blocked_s', ('timing', 'lower', 's')),
-        ('params_m', ('info',)),
-        ('batch', ('info',)),
-        ('seq', ('info',)),
-        ('steps_per_launch', ('info',)),
-    ),
-    'perflab.train_resnet': (
-        ('compiles_after_warmup', ('counter', 'lower')),
-        ('retraces', ('counter', 'lower')),
-        ('kernel_fallbacks', ('counter', 'lower')),
-        ('emitter_fallbacks', ('counter', 'lower')),
-        ('images_per_s', ('timing', 'higher', 'img/s')),
-        ('mfu', ('timing', 'higher', 'ratio')),
-        ('batch', ('info',)),
-        ('depth', ('info',)),
-    ),
-    'perflab.decode_stream': (
-        ('compiles_after_warmup', ('counter', 'lower')),
-        ('deadlocks', ('counter', 'lower')),
-        ('kv_slots_leaked', ('counter', 'lower')),
-        ('kv_pages_leaked', ('counter', 'lower')),
-        ('streams_failed', ('counter', 'lower')),
-        ('streams_at_slo', ('counter', 'higher')),
-        ('density_x_vs_dense', ('counter', 'higher')),
-        ('tokens_per_s_per_chip', ('timing', 'higher', 'tokens/s')),
-        ('ttft_p99_ms', ('timing', 'lower', 'ms')),
-        ('itl_p99_ms', ('timing', 'lower', 'ms')),
-        ('requests', ('info',)),
-        ('streams_ok', ('info',)),
-    ),
-    'perflab.pod_parallel': (
-        ('workers_completed', ('counter', 'higher')),
-        ('worker_failures', ('counter', 'lower')),
-        ('allreduce_gbps', ('timing', 'higher', 'GB/s')),
-        ('steps_per_s_1worker', ('timing', 'higher', 'steps/s')),
-        ('scaling_2worker_x', ('timing', 'higher', 'x')),
-        # shard-pass round: explicit-collective accounting + per-device
-        # persistable HBM, replicated vs ZeRO-sharded in one record
-        ('reshards_inserted', ('counter', 'lower')),
-        ('collective_bytes', ('counter', 'lower')),
-        ('hbm_sharded_ratio', ('timing', 'lower', 'x')),
-        ('hbm_params_bytes_replicated', ('info',)),
-        ('hbm_params_bytes_sharded', ('info',)),
-        ('devices', ('info',)),
-    ),
-    'perflab.fused_adam_micro': (
-        ('kernelgen_ops', ('counter', 'higher')),
-        ('kernelgen_fallbacks', ('counter', 'lower')),
-        ('retraces', ('counter', 'lower')),
-        ('fused_adam_ms', ('timing', 'lower', 'ms')),
-        ('params', ('info',)),
-    ),
-    # ledger bridges: bench.py / serve_soak.py / pod_soak.py emit their
-    # existing telemetry through the shared scenario-record writer
-    # (PT_PERF_LEDGER=<path>) so all three feed the same PERF_HISTORY
-    'perflab.bench': (
-        ('program_op_count_opt', ('counter', 'lower')),
-        ('retraces', ('counter', 'lower')),
-        ('kernel_fallbacks', ('counter', 'lower')),
-        ('kernelgen_fallbacks', ('counter', 'lower')),
-        ('emitter_fallbacks', ('counter', 'lower')),
-        ('tokens_per_s', ('timing', 'higher', 'tokens/s')),
-        ('mfu', ('timing', 'higher', 'ratio')),
-        ('host_blocked_s', ('timing', 'lower', 's')),
-        ('fused_adam_ms', ('timing', 'lower', 'ms')),
-        ('resnet50_images_per_s', ('timing', 'higher', 'img/s')),
-        ('batch', ('info',)),
-        ('seq', ('info',)),
-    ),
-    'perflab.serve_soak': (
-        ('deadlocks', ('counter', 'lower')),
-        ('no_reply', ('counter', 'lower')),
-        ('p99_ms', ('timing', 'lower', 'ms')),
-        ('ttft_p99_ms', ('timing', 'lower', 'ms')),
-        ('itl_p99_ms', ('timing', 'lower', 'ms')),
-        ('scenario', ('info',)),
-        ('admitted', ('info',)),
-    ),
-    'perflab.decode_capacity': (
-        ('streams_at_slo', ('counter', 'higher')),
-        ('kv_pages_leaked', ('counter', 'lower')),
-        ('density_x_vs_dense', ('counter', 'higher')),
-        ('capacity_floor', ('info',)),
-        ('kv_budget_bytes', ('info',)),
-        ('page_len', ('info',)),
-        ('kv_quant', ('info',)),
-    ),
-    'perflab.pod_soak': (
-        ('failures', ('counter', 'lower')),
-        ('segments', ('info',)),
-        ('rollbacks', ('info',)),
-        ('manifests', ('info',)),
-    ),
-})
-
 
 def schema_keys(section):
     return [k for k, _ in SCHEMA[section]]
 
 
-def telemetry_snapshot(section, baseline=None, extra=None, snapshot=None):
-    """Build the section's telemetry dict from the live registry.
-
-    ``baseline`` is an earlier ``obs.counters()`` for delta keys;
-    ``extra`` supplies exactly the keys declared ``('extra',)`` —
-    missing or unknown extra keys raise, which is the anti-drift
-    contract the three emitters share.
-    """
+def telemetry_snapshot(section, snapshot=None):
+    """Build the section's telemetry dict from the live registry (or
+    from ``snapshot``, an earlier ``obs.counters()``)."""
     spec = SCHEMA[section]
     c = metrics.counters() if snapshot is None else snapshot
-    baseline = baseline or {}
-    extra = dict(extra or {})
-    declared_extra = {k for k, s in spec if s[0] == 'extra'}
-    unknown = set(extra) - declared_extra
-    if unknown:
-        raise ValueError('telemetry_snapshot(%r): unexpected extra keys %s'
-                         % (section, sorted(unknown)))
-    missing = declared_extra - set(extra)
-    if missing:
-        raise ValueError('telemetry_snapshot(%r): missing extra keys %s'
-                         % (section, sorted(missing)))
 
     def val(name):
         return c.get(name) or 0
@@ -322,14 +144,8 @@ def telemetry_snapshot(section, baseline=None, extra=None, snapshot=None):
     out = {}
     for key, s in spec:
         kind = s[0]
-        if kind == 'extra':
-            out[key] = extra[key]
-        elif kind == 'int':
+        if kind == 'int':
             out[key] = int(val(s[1]))
-        elif kind == 'sec':
-            out[key] = round(float(val(s[1])), 3)
-        elif kind == 'delta_int':
-            out[key] = int(val(s[1])) - int(baseline.get(s[1]) or 0)
         elif kind == 'sum_int':
             out[key] = sum(int(val(n)) for n in s[1])
         elif kind == 'ratio':

@@ -15,7 +15,7 @@ snapshot, retrace reports, and the PT_* environment to
 `PT_FLIGHT_DIR` is set and telemetry is enabled, so unit tests and
 library users never get surprise files.  Trigger sites: serving batch
 failure, circuit-breaker trip, recovery give-up re-raise, SIGTERM
-drain, bench watchdog fire, and the `install()` excepthook for
+drain, soak watchdog fire, and the `install()` excepthook for
 uncaught crashes in soak tools.
 """
 import json

@@ -237,8 +237,8 @@ class MetricsRegistry(object):
             return sorted(self._metrics.items())
 
     def counters(self):
-        """Flat {name: value} over counters AND gauges (the shape bench.py
-        and tests diff against)."""
+        """Flat {name: value} over counters AND gauges (the shape the soak
+        tools and tests diff against)."""
         with self._lock:
             items = list(self._metrics.items())
         return {name: m.snapshot() for name, m in items

@@ -6,7 +6,7 @@ XLA's SPMD partitioner emits the matching collective — an all-gather
 when the constraint removes sharded axes, a dynamic-slice/all-to-all
 when it moves them, and (for a constrained vjp cotangent) a
 reduce-scatter.  The three TYPES are semantically distinct IR nodes so
-the analyzer, pt_lint, perflab, and a human reading the optimized
+the analyzer, pt_lint and a human reading the optimized
 program can see WHAT moves where:
 
   reshard        layout change of a live value (the materialized D018)

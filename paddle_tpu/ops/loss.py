@@ -97,8 +97,7 @@ def _make_hard_ce(V, eps, ignore):
 def softmax_with_cross_entropy(ctx, ins, attrs):
     # logsumexp in f32 (bf16 logits under AMP are fine — the reduction is
     # not); Loss is always f32.  Hard labels over the last axis take the
-    # custom-vjp fast path (_make_hard_ce); jax.checkpoint remat of the
-    # whole op measured 19% slower (PERF.md), kept behind PT_CE_REMAT=1.
+    # custom-vjp fast path (_make_hard_ce).
     logits, label = ins['Logits'], ins['Label']
     axis = attrs.get('axis', -1)
     ndim = logits.ndim

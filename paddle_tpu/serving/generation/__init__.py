@@ -6,8 +6,8 @@ decode server (docs/generation.md):
   * `kv_cache` — PAGED KV storage: one shared page pool
     ``[pages, layers, kv_heads, page_len, head_dim]`` plus per-slot
     block tables, refcounted free-list page allocation (`PagePool`),
-    optional int8 quantization (``PT_KV_QUANT``) and a fingerprinted
-    shared-prefix page cache (`PrefixCache`, ``PT_PREFIX_CACHE``) — a
+    optional int8 quantization (``kv_quant='int8'``) and a fingerprinted
+    shared-prefix page cache (`PrefixCache`, ``prefix_cache=``) — a
     stream's footprint is ceil(len/page_len) pages, not max_len rows.
   * `decode` — the fused prefill/decode/verify executables: K decode
     tokens launch as ONE `lax.scan` with the page pools as donated

@@ -50,7 +50,6 @@ cache spec now carries page_len/pages/quant, so geometry changes get
 fresh fingerprints.  `dense_reference` is the independent, page-free
 parity oracle.
 """
-import os
 import threading
 
 import numpy as np
@@ -437,22 +436,6 @@ def dense_reference(weights, cfg, prompt):
             np.asarray(logits))
 
 
-def _env_quant(kv_quant):
-    if kv_quant is not None:
-        q = str(kv_quant)
-    else:
-        q = os.environ.get('PT_KV_QUANT', 'none')
-    return 'none' if q.strip().lower() in ('', '0', 'none', 'off',
-                                           'false') else q.strip().lower()
-
-
-def _env_prefix(prefix_cache):
-    if prefix_cache is not None:
-        return bool(prefix_cache)
-    return os.environ.get('PT_PREFIX_CACHE', '1').strip().lower() not in (
-        '0', 'off', 'false', '')
-
-
 class DecodeRuntime(object):
     """The device half of the streaming decode server: the paged KV
     pool + block tables + AOT prefill/decode/verify executables over one
@@ -466,9 +449,8 @@ class DecodeRuntime(object):
 
     Paging knobs: ``page_len`` (default: largest divisor of max_len
     <= 8), ``pages`` (pool depth incl. the garbage page; default =
-    dense-equivalent capacity), ``kv_quant`` ('none'/'int8', default
-    env PT_KV_QUANT), ``prefix_cache`` (default env PT_PREFIX_CACHE,
-    on).  A slot is a batch row; PAGES are the memory: admission goes
+    dense-equivalent capacity), ``kv_quant`` ('none'/'int8'),
+    ``prefix_cache`` (default on).  A slot is a batch row; PAGES are the memory: admission goes
     through `try_begin` (prefix-cache match + all-or-nothing page
     claim) and per-window `ensure_capacity`, both of which report
     shortage as a clean False/None the scheduler turns into
@@ -477,8 +459,8 @@ class DecodeRuntime(object):
 
     def __init__(self, weights, cfg, slots=4, prefill_chunk=8,
                  cache_dtype='float32', mesh=None, ring_min_len=None,
-                 page_len=None, pages=None, kv_quant=None,
-                 prefix_cache=None):
+                 page_len=None, pages=None, kv_quant='none',
+                 prefix_cache=True):
         import jax.numpy as jnp
         self.cfg = dict(cfg)
         self.w = {n: jnp.asarray(weights[n]) for n in weight_names(cfg)}
@@ -487,11 +469,11 @@ class DecodeRuntime(object):
             slots=slots, layers=int(cfg['n_layer']),
             kv_heads=int(cfg['n_kv_head']), max_len=int(cfg['max_len']),
             head_dim=int(cfg['d_model']) // H, dtype=cache_dtype,
-            page_len=page_len, pages=pages, quant=_env_quant(kv_quant))
+            page_len=page_len, pages=pages, quant=kv_quant)
         self.allocator = SlotAllocator(self.cache.slots)
         self.pool = PagePool(self.cache)
         self.prefix = (PrefixCache(self.pool, self.cache.page_len)
-                       if _env_prefix(prefix_cache) else None)
+                       if prefix_cache else None)
         S = self.cache.slots
         self.block_tables = np.zeros((S, self.cache.max_pages), np.int32)
         self.owned = [[] for _ in range(S)]

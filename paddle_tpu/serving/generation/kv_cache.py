@@ -24,14 +24,14 @@ zeroed — reuse is metadata-only, O(1), zero device work.
 ``quant='int8'`` stores the pools as int8 with one float32 scale per
 written row (per token, per kv head): ``scale = amax/127`` on write,
 dequantized inside the attention window (decode.py) with float32
-accumulation.  Kill switch: ``PT_KV_QUANT=0``.
+accumulation.  Off by default (``DecodeRuntime(kv_quant='int8')``).
 
 `PrefixCache` maps chain-hashed FULL prompt pages to refcounted page
 ids so requests sharing a prompt prefix map the same read-only pages
 instead of re-prefilling them.  Shared pages are full by construction,
 so a request's own writes (its prompt tail and generated tokens)
 always land in freshly allocated pages — copy-on-extend needs no copy.
-Kill switch: ``PT_PREFIX_CACHE=0``.
+On by default (``DecodeRuntime(prefix_cache=False)`` turns it off).
 """
 import hashlib
 import threading

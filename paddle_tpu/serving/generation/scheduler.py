@@ -23,9 +23,8 @@ flight dump carrying the pool gauge snapshot.  Prefix-cache hits skip
 straight to their first unshared chunk (`DecodeRuntime.try_begin`) and
 completed prompts are published for later requests (`promote_prefix`).
 
-With ``GenerationConfig.speculative`` (default off; env
-``PT_SPEC_DECODE``) the decode window becomes draft-propose + fused
-VERIFY: a host-side n-gram draft proposes K-1 tokens per stream, one
+With ``GenerationConfig.speculative`` (default off) the decode window
+becomes draft-propose + fused VERIFY: a host-side n-gram draft proposes K-1 tokens per stream, one
 batched verify pass samples the target model at every position, and
 each stream keeps the longest accepted prefix
 (``generation.spec_proposed`` / ``spec_accepted``) — greedy streams
@@ -78,13 +77,11 @@ class GenerationConfig(object):
     the fused decode scan; ``ttft_timeout_s`` / ``itl_timeout_s`` are
     the default per-token SLO budgets (overridable per request);
     ``speculative`` swaps the decode window for draft + fused verify
-    (default off; env ``PT_SPEC_DECODE=1`` turns it on, ``=0`` is a
-    hard kill switch over an explicit True)."""
+    (default off)."""
 
     def __init__(self, decode_window=4, eos_id=None, max_new_default=16,
                  ttft_timeout_s=None, itl_timeout_s=None,
-                 speculative=None):
-        import os
+                 speculative=False):
         if int(decode_window) < 1:
             raise ValueError('decode_window must be >= 1')
         self.decode_window = int(decode_window)
@@ -92,13 +89,7 @@ class GenerationConfig(object):
         self.max_new_default = int(max_new_default)
         self.ttft_timeout_s = ttft_timeout_s
         self.itl_timeout_s = itl_timeout_s
-        env = os.environ.get('PT_SPEC_DECODE', '').strip().lower()
-        if env in ('0', 'off', 'false'):
-            self.speculative = False
-        elif speculative is None:
-            self.speculative = env in ('1', 'on', 'true')
-        else:
-            self.speculative = bool(speculative)
+        self.speculative = bool(speculative)
 
 
 class _GenRequest(_Request):

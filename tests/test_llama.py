@@ -6,6 +6,7 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu import layers
 from paddle_tpu.models import llama
+from paddle_tpu.serving.generation.sampling import SamplingParams
 
 
 def _run_single(x_fn, feed, fetch):
@@ -211,19 +212,19 @@ def test_kv_cache_decoder_continues_pattern():
             rows = [(2 + (s + 3 * np.arange(25)) % 250) for s in starts]
             exe.run(main, feed=llama.make_batch(rows, 32),
                     fetch_list=[out['loss']])
-        dec = llama.make_decoder(scope, 'tiny')
-        prompt = (2 + (7 + 3 * np.arange(6)) % 250).reshape(1, 6)
-        gen = dec(prompt, 10)
+        rt = llama.make_streaming_runtime(scope, 'tiny')
+        prompt = 2 + (7 + 3 * np.arange(6)) % 250
+        gen = np.asarray(rt.generate(prompt, 10))
         expect = 2 + (7 + 3 * np.arange(16)) % 250
-        assert gen.shape == (1, 16)
-        assert (gen[0][6:] == expect[6:]).mean() > 0.8, gen
+        assert gen.shape == (10,)
+        assert (gen == expect[6:]).mean() > 0.8, gen
 
         # decoder prefill logits == program logits on the same prefix
         feed = llama.make_batch([2 + (7 + 3 * np.arange(17)) % 250], 32)
         prog_logits, = exe.run(main, feed=feed,
                                fetch_list=[out['logits']])
         prog_next = np.asarray(prog_logits)[0, 5].argmax()
-        assert prog_next == gen[0][6]
+        assert prog_next == gen[0]
 
 
 def test_decoder_sampling_temperature():
@@ -234,9 +235,9 @@ def test_decoder_sampling_temperature():
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         exe.run(startup)
-        dec = llama.make_decoder(scope, 'tiny', temperature=1.0)
-        prompt = np.arange(2, 8).reshape(1, 6)
-        a = dec(prompt, 6, seed=1)
-        b = dec(prompt, 6, seed=2)
+        rt = llama.make_streaming_runtime(scope, 'tiny')
+        prompt = np.arange(2, 8)
+        a = rt.generate(prompt, 6, SamplingParams(temperature=1.0, seed=1))
+        b = rt.generate(prompt, 6, SamplingParams(temperature=1.0, seed=2))
     # untrained model at T=1: different seeds give different samples
-    assert not np.array_equal(a, b)
+    assert a != b

@@ -2,6 +2,8 @@
 reference tests/unittests per-op OpTest forward checks) for ops that
 previously had build-and-run coverage only (test_layers.py) but no
 value assertions."""
+import warnings
+
 import numpy as np
 import pytest
 
@@ -421,3 +423,27 @@ def test_sequence_erase_compacts_and_relengths():
         [[7, 0, 0, 0, 0, 0],   # row0 [3,5,3,7]: erase 3s and 5s -> [7]
          [1, 2, 9, 0, 0, 0]])  # row1: erase 5s -> [1, 2, 9]
     np.testing.assert_array_equal(np.asarray(outs['OutLength']), [1, 3])
+
+
+def test_fill_constant_int64_overflow_is_silent():
+    """The documented warn-and-truncate contract: an overflowing int64
+    fill wraps like the reference C++ cast with NO numpy RuntimeWarning
+    (which would be fatal under warnings-as-errors CI)."""
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+    main_prog, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main_prog, startup):
+        with fluid.unique_name.guard():
+            c = layers.fill_constant(shape=[2], dtype='int64',
+                                     value=2 ** 40)
+    exe = fluid.Executor()
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        with warnings.catch_warnings():
+            warnings.simplefilter('error')
+            out, = exe.run(main_prog, fetch_list=[c])
+    # int64 stores as int32 (the TPU warn-and-truncate policy); the
+    # out-of-range value truncates (wrap or saturate is backend-defined)
+    # — the contract under test is that NO warning escaped above
+    assert out.dtype == np.int32
+    assert int(out[0]) != 2 ** 40

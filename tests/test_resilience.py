@@ -432,6 +432,11 @@ with fluid.scope_guard(scope):
             l, = exe.run(main, feed=feed_at(i), fetch_list=[loss])
             losses.append(float(np.asarray(l).ravel()[0]))
             ck.save(0, i)                        # async, every step
+            if i == kill_at - 1:
+                # one checkpoint is durable however slowly the writer
+                # thread runs (on a loaded host none of five had landed
+                # at the kill); step kill_at's write still races the kill
+                ck.wait()
             if i == kill_at:
                 os.kill(os.getpid(), signal.SIGKILL)   # preemption, hard
     else:
@@ -441,6 +446,8 @@ with fluid.scope_guard(scope):
                                 fetch_list=[loss])
             losses.extend(float(v) for v in np.asarray(ls).ravel())
             ck.save(0, s + K - 1)
+            if s <= kill_at - K < s + K:
+                ck.wait()        # as above: the window before the kill
             if s <= kill_at < s + K:
                 os.kill(os.getpid(), signal.SIGKILL)
 print(json.dumps({'start': start, 'losses': losses}))

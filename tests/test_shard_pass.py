@@ -335,15 +335,3 @@ def test_accumulators_inherit_param_spec():
         assert b.vars[n].sharding == (None, 'model')
     pows = [n for n in b.vars if 'aw_beta' in n]
     assert pows and all(b.vars[n].sharding is None for n in pows)
-
-
-# ------------------------------------------------------- observability
-
-def test_perflab_schema_has_shard_keys():
-    from paddle_tpu.observability.export import SCHEMA
-    keys = dict(SCHEMA['perflab.pod_parallel'])
-    assert keys['reshards_inserted'] == ('counter', 'lower')
-    assert keys['collective_bytes'] == ('counter', 'lower')
-    assert 'hbm_params_bytes_replicated' in keys
-    assert 'hbm_params_bytes_sharded' in keys
-    assert 'hbm_sharded_ratio' in keys

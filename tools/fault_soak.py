@@ -368,5 +368,4 @@ def main():
 
 if __name__ == '__main__':
     _harness.set_tool('FAULT_SOAK')
-    _harness.main_guard(main, watchdog_env='PT_SOAK_WATCHDOG_S',
-                        flight_tag='fault_soak.watchdog')
+    _harness.main_guard(main, flight_tag='fault_soak.watchdog')

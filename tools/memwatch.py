@@ -18,7 +18,7 @@ keeps asking:
     ``kv_pages_in_use`` sampled while streams run, the serving-density
     counterpart of the HBM gauges (docs/generation.md).
 
-Runs a small fused training loop (the same shape bench.py uses) with
+Runs a small fused training loop with
 periodic checkpoints, sampling after every launch, and prints one JSON
 report.  ``--steps``/``--steps-per-launch``/``--hidden`` scale the
 workload; on CPU the HBM gauges are absent by design (memory_stats()
@@ -99,9 +99,8 @@ def main():
     ap.add_argument('--decode', action='store_true',
                     help='also run a small paged decode workload and '
                          'report the KV pool gauges')
-    ap.add_argument('--kv-quant', default=None, choices=['none', 'int8'],
-                    help='KV quantization for the --decode workload '
-                         '(default: env PT_KV_QUANT)')
+    ap.add_argument('--kv-quant', default='none', choices=['none', 'int8'],
+                    help='KV quantization for the --decode workload')
     args = ap.parse_args()
 
     import numpy as np

@@ -401,13 +401,6 @@ def supervisor_main(args):
         'failures': fails,
     }
     print(json.dumps(verdict))
-    from paddle_tpu.observability import perflab
-    perflab.maybe_ledger(
-        'pod_soak',
-        {'failures': len(fails),
-         'segments': verdict['segments'],
-         'rollbacks': rollbacks,
-         'manifests': verdict['manifests']})
     return 0 if not fails else 1
 
 
@@ -450,5 +443,4 @@ def main():
 
 if __name__ == '__main__':
     _harness.set_tool('POD_SOAK')
-    _harness.main_guard(main, watchdog_env='PT_SOAK_WATCHDOG_S',
-                        flight_tag='pod_soak.watchdog')
+    _harness.main_guard(main, flight_tag='pod_soak.watchdog')

@@ -59,12 +59,11 @@ acceptance when --speculative) — see docs/generation.md.
 ``--capacity-floor N`` appends the fixed-budget density gate
 (`run_capacity_gate`): a hard KV byte budget, an oversubscribed slot
 table, and a stream ramp that must queue at admission backpressure —
-never die mid-stream — while sustaining >= N concurrent streams at SLO
-(ledgered as the ``decode_capacity`` scenario).
+never die mid-stream — while sustaining >= N concurrent streams at SLO.
 
 Prints one JSON line with the verdict and the metrics that prove it
 (the serving block comes from observability.telemetry_snapshot, the
-same schema bench.py and fault_soak.py print).
+same schema fault_soak.py prints).
 """
 import argparse
 import json
@@ -154,7 +153,7 @@ def run_decode_scenario(args):
                                  drain_timeout_s=30.0),
         gen_config=GenerationConfig(
             decode_window=K,
-            speculative=True if args.speculative else None)).start()
+            speculative=args.speculative)).start()
 
     # parity gate first (its executables land before the warmup
     # snapshot): fused engine stream == sequential K=1 reference over
@@ -260,14 +259,6 @@ def run_decode_scenario(args):
     }
     rec.update(tel)
     print(json.dumps(rec))
-    from paddle_tpu.observability import perflab
-    perflab.maybe_ledger(
-        'serve_soak',
-        {'deadlocks': int(rec['deadlocks']), 'no_reply': no_reply,
-         'p99_ms': rec.get('p99_ms'),
-         'ttft_p99_ms': rec.get('ttft_p99_ms'),
-         'itl_p99_ms': rec.get('itl_p99_ms'),
-         'scenario': 'decode', 'admitted': rec.get('admitted')})
 
     if args.assert_slo:
         if no_reply:
@@ -417,14 +408,6 @@ def run_capacity_gate(args, w, cfg):
            'density_x_vs_dense': streams_at_slo // dense_streams,
            'capacity_floor': floor}
     print(json.dumps(rec))
-    from paddle_tpu.observability import perflab
-    perflab.maybe_ledger(
-        'decode_capacity',
-        {'streams_at_slo': streams_at_slo,
-         'kv_pages_leaked': pages_leaked,
-         'density_x_vs_dense': rec['density_x_vs_dense'],
-         'capacity_floor': floor, 'kv_budget_bytes': budget,
-         'page_len': page_len, 'kv_quant': quant})
     if ok != requests:
         sys.exit('serve_soak[capacity]: %d/%d streams failed under the '
                  'page budget — backpressure must queue, never kill'
@@ -489,8 +472,8 @@ def main():
                     help='[decode] cancel every Nth stream after its '
                          'first token (0 = never)')
     ap.add_argument('--kv-quant', default=None, choices=('none', 'int8'),
-                    help='[decode] KV page quantization (default: env '
-                         'PT_KV_QUANT)')
+                    help='[decode] KV page quantization (default: none; '
+                         'the --capacity-floor rerun defaults to int8)')
     ap.add_argument('--page-len', type=int, default=None,
                     help='[decode] tokens per KV page (default: largest '
                          'divisor of max_len that is <= 8)')
@@ -654,14 +637,6 @@ def main():
     }
     rec.update(tel)
     print(json.dumps(rec))
-    from paddle_tpu.observability import perflab
-    perflab.maybe_ledger(
-        'serve_soak',
-        {'deadlocks': int(rec['deadlocks']), 'no_reply': no_reply,
-         'p99_ms': p99,
-         'ttft_p99_ms': rec.get('ttft_p99_ms'),
-         'itl_p99_ms': rec.get('itl_p99_ms'),
-         'scenario': 'oneshot', 'admitted': admitted})
 
     if args.assert_slo:
         if no_reply:
@@ -793,5 +768,4 @@ def main():
 
 if __name__ == '__main__':
     _harness.set_tool('SERVE_SOAK')
-    _harness.main_guard(main, watchdog_env='PT_SOAK_WATCHDOG_S',
-                        flight_tag='serve_soak.watchdog')
+    _harness.main_guard(main, flight_tag='serve_soak.watchdog')
