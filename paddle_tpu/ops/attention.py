@@ -275,13 +275,89 @@ def _flash_dkv_kernel(klen_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 _BWD_PALLAS_SCORE_BYTES = 256 << 20
 
 # Below this key length the FORWARD also routes to the composed einsum
-# path: measured end-to-end on TPU v5 lite transformer-base training
-# (B*T = 8k tokens), composed reaches 211.8k tok/s at T=256 vs 182.1k
-# through the pallas forward (+16%) — XLA's fused batched matmuls win
-# while the T^2 scores are small — with the crossover at T=512 (146.2k
-# flash vs 145.6k composed).  `flash_attention` is fused-attention
-# SEMANTICS; the op picks the fastest lowering per shape.
+# path, with the crossover put at T=512.  The numbers that set it
+# (transformer-base training at B*T = 8k tokens: composed 211.8k tok/s
+# at T=256 against 182.1k through the pallas forward; 146.2k flash
+# against 145.6k composed at T=512) are from an installation that no
+# longer exists (PERF.md section 6: "PRs 1-20: not verified"); no cell
+# has re-read them.  `flash_attention` is fused-attention SEMANTICS;
+# the op picks the fastest lowering per shape.
 _FWD_PALLAS_MIN_T = 512
+
+# Above this many bytes of f32 scores (B*H*Tq*Tk*4) the composed path
+# runs over tiles of the batch (_composed_attention): XLA:TPU keeps the
+# score matrices of a tile in on-chip memory across the fusions of the
+# block, and writes a whole batch's to HBM and reads them back.  Set on
+# the v5e in tbase.train_1chip (96 x 8 heads x 256 x 256: 2 MiB a
+# sequence, 192 MiB a block; PERF.md section 6, my chip runs, PR 31),
+# train_rate in items/s/chip by tile: whole batch 191,451; 2 sequences
+# 224,915; 4: 229,080; 8: 226,675; 12: 231,195; 16: 230,618; 24:
+# 229,417; 32: 225,713.  Every tile is 17 to 21 % ahead of the whole
+# batch and the best are 24 to 48 MiB, so the constant sits inside:
+# 32 MiB, 16 sequences there.  (Compiled ALONE the block keeps tiles of
+# 48 MiB resident and not of 96; inside the step the on-chip memory is
+# shared.)
+_COMPOSED_TILE_BYTES = 32 << 20
+
+
+def _composed_tile(B, H, Tq, Tk):
+    """Sequences a tile of the composed path holds: the largest divisor
+    of B whose f32 scores fit _COMPOSED_TILE_BYTES.  B itself (no loop)
+    when the whole batch fits, and when the best divisor's scores are
+    under a quarter of that budget (a quarter measured within 1 % of
+    the best tile, an eighth 2.5 % behind it, less is unmeasured)."""
+    per_seq = H * Tq * Tk * 4
+    fit = _COMPOSED_TILE_BYTES // per_seq
+    if fit >= B:
+        return B
+    c = max((d for d in range(1, fit + 1) if B % d == 0), default=0)
+    return c if 4 * c * per_seq >= _COMPOSED_TILE_BYTES else B
+
+
+def _composed_attention(q, k, v, causal, scale, k_len, mesh=None):
+    """`_ref_attention`, run over tiles of the batch where its scores
+    would not stay on chip.  Attention is independent per sequence, so a
+    tile is the same mathematics; the loop body is rematerialised, so the
+    backward pass keeps q, k, v and recomputes a tile's scores where
+    plain AD would stack every tile's softmax into a full-size residual.
+    Under a mesh that shards the batch over 'data' (and nothing else)
+    each device tiles its LOCAL batch inside a shard_map: no collective,
+    and the loop never runs over a sharded dimension.  Any other mesh
+    of several devices keeps the whole-batch path."""
+    from ..observability import metrics
+    B, H, Tq, _ = q.shape
+    Tk = k.shape[2]
+    shards = 1 if mesh is None else mesh.size
+    local = B // shards
+    if shards == 1 or (
+            mesh.shape.get('data') == shards and B % shards == 0):
+        c = _composed_tile(local, H, Tq, Tk)
+    else:
+        c = local
+    if c == local:
+        metrics.counter('attention.composed_whole').inc()
+        return _ref_attention(q, k, v, causal, scale, k_len)
+    metrics.counter('attention.composed_tiled').inc()
+
+    @jax.checkpoint
+    def tile(qkvl):
+        q, k, v, k_len = qkvl
+        with jax.named_scope('attn.tile'):
+            return _ref_attention(q, k, v, causal, scale, k_len)
+
+    def tiled(*qkvl):
+        out = jax.lax.map(tile, tuple(
+            x.reshape((local // c, c) + x.shape[1:]) for x in qkvl))
+        return out.reshape(qkvl[0].shape)
+
+    if shards > 1:
+        from jax.sharding import PartitionSpec as P
+        from ..parallel.mesh import shard_map
+        # every axis named (the others have one device): an axis left
+        # out would be summed over in the backward pass
+        spec = P(mesh.axis_names)
+        tiled = shard_map(tiled, mesh=mesh, in_specs=spec, out_specs=spec)
+    return tiled(q, k, v, k_len)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, k_len=None,
@@ -308,8 +384,9 @@ def flash_attention(q, k, v, causal=False, scale=None, k_len=None,
             or not _pallas.single_device(mesh):
         # shapes the kernel can't tile, short-context sizes where the
         # composed path measures faster, or a launch over several
-        # devices — composed (jax AD backward)
-        return _ref_attention(q, k, v, causal, scale, k_len)
+        # devices — composed (jax AD backward), tiled over the batch
+        # where the scores would not stay on chip
+        return _composed_attention(q, k, v, causal, scale, k_len, mesh)
     pallas_bwd = B * H * Tq * Tk * 2 > _BWD_PALLAS_SCORE_BYTES
 
     @jax.custom_vjp

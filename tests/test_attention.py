@@ -157,3 +157,188 @@ def test_pallas_backward_kernels_gradient_parity(cfg, dkv_variant,
         'pallas backward never engaged — a routing gate vacated this test'
     for a, b, n in zip(gf, gr, 'dq dk dv'.split()):
         np.testing.assert_allclose(a, b, atol=5e-4, err_msg=n)
+
+
+# --------------------------------- the composed path over tiles of the batch
+
+def _composed_case(monkeypatch, cfg, tile_seqs=5):
+    """Inputs of one case, with the real routing (T under the Pallas
+    crossover) and the byte constant patched down so that `tile_seqs`
+    sequences of CPU-sized scores are a tile's budget."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention as att
+    B, H, Hkv, Tq, Tk, D = (cfg[k] for k in 'B H Hkv Tq Tk D'.split())
+    monkeypatch.setattr(att, '_FWD_PALLAS_MIN_T', 512)
+    monkeypatch.setattr(att, '_COMPOSED_TILE_BYTES',
+                        tile_seqs * H * Tq * Tk * 4)
+    rng = np.random.RandomState(31)
+    dt = jnp.dtype(cfg['dtype'])
+    q = jnp.asarray(rng.randn(B, H, Tq, D), dt)
+    k = jnp.asarray(rng.randn(B, Hkv, Tk, D), dt)
+    v = jnp.asarray(rng.randn(B, Hkv, Tk, D), dt)
+    kl = (jnp.asarray(rng.randint(Tk // 2, Tk + 1, B), jnp.int32)
+          if cfg['klen'] else None)
+    return att, q, k, v, kl
+
+
+def _counters():
+    from paddle_tpu.observability import metrics
+    return (metrics.counter('attention.composed_tiled').value,
+            metrics.counter('attention.composed_whole').value)
+
+
+_BASE = dict(B=8, H=4, Hkv=4, Tq=32, Tk=32, D=16, causal=False, klen=False,
+             dtype='float32')
+
+
+@pytest.mark.parametrize('cfg', [
+    dict(_BASE),
+    dict(_BASE, causal=True),
+    dict(_BASE, klen=True),
+    dict(_BASE, causal=True, klen=True),
+    dict(_BASE, H=8, Hkv=2, causal=True),                 # GQA
+    dict(_BASE, Tq=16, Tk=48, causal=True, klen=True),    # Tq != Tk
+    dict(_BASE, dtype='bfloat16', causal=True),
+    dict(_BASE, dtype='bfloat16', H=8, Hkv=2, klen=True),
+    dict(_BASE, B=12, causal=True, klen=True),            # tiles of 4
+    dict(_BASE, B=7, causal=True, klen=True),             # prime: whole
+    dict(_BASE, B=4, causal=True),                        # fits: whole
+], ids=lambda c: '-'.join('%s%s' % kv for kv in sorted(c.items())
+                          if _BASE[kv[0]] != kv[1]) or 'base')
+def test_composed_tiles_equal_the_whole_batch(cfg, monkeypatch):
+    """The composed route over tiles of the batch is `_ref_attention` in
+    output and in dq, dk, dv; a batch that is one tile (it fits, or its
+    only useful divisor is itself) lowers to the jaxpr it lowered to
+    before there were tiles; and the two counters say which ran."""
+    import jax.numpy as jnp
+    att, q, k, v, kl = _composed_case(monkeypatch, cfg)
+    B, D, causal = cfg['B'], cfg['D'], cfg['causal']
+    tiles = att._composed_tile(B, cfg['H'], cfg['Tq'], cfg['Tk']) != B
+    assert tiles == (B in (8, 12))
+
+    def loss(fn):
+        def f(q, k, v):
+            out = fn(q, k, v)
+            return (out.astype(jnp.float32) ** 2).sum(), out
+        return f
+
+    def ours(q, k, v):
+        return att.flash_attention(q, k, v, causal=causal, k_len=kl)
+
+    def ref(q, k, v):
+        return att._ref_attention(q, k, v, causal, D ** -0.5, kl)
+
+    before = _counters()
+    (_, out), grads = jax.value_and_grad(
+        loss(ours), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    after = _counters()
+    assert (after[0] - before[0], after[1] - before[1]) == \
+        ((1, 0) if tiles else (0, 1))
+    (_, want), want_grads = jax.value_and_grad(
+        loss(ref), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    assert out.dtype == q.dtype
+    # f32: the same operations on the same rows; bf16: one rounding of
+    # the output and of each gradient, whatever the order of the sums
+    tol = dict(atol=1e-5, rtol=1e-5) if cfg['dtype'] == 'float32' \
+        else dict(atol=5e-2, rtol=2e-2)
+    for a, b, n in zip((out,) + grads, (want,) + want_grads,
+                       'out dq dk dv'.split()):
+        assert a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   err_msg=n, **tol)
+
+    text = str(jax.make_jaxpr(jax.grad(lambda *a: loss(ours)(*a)[0]))(
+        q, k, v))
+    if tiles:
+        assert 'while' in text or 'scan' in text
+        assert 'checkpoint' in text or 'remat' in text
+    else:
+        def before_tiles(q, k, v):
+            # what flash_attention's composed branch was: the lengths
+            # filled in, then `_ref_attention` on the whole batch
+            full = jnp.full((B,), cfg['Tk'], jnp.int32) if kl is None \
+                else kl
+            return att._ref_attention(q, k, v, causal, D ** -0.5,
+                                      full.astype(jnp.int32))
+
+        old = str(jax.make_jaxpr(jax.grad(
+            lambda *a: loss(before_tiles)(*a)[0]))(q, k, v))
+        assert 'while' not in text and 'scan' not in text
+        assert text == old
+
+
+def test_composed_tile_follows_the_shapes(monkeypatch):
+    """The largest divisor of the batch whose f32 scores fit the byte
+    constant; the whole batch when it fits, when no divisor holds a
+    quarter of the budget, and when one sequence is over it."""
+    from paddle_tpu.ops import attention as att
+    monkeypatch.setattr(att, '_COMPOSED_TILE_BYTES',
+                        24 * 8 * 256 * 256 * 4)
+    tile = att._composed_tile
+    assert tile(96, 8, 256, 256) == 24          # tbase.train_1chip
+    assert tile(24, 8, 256, 256) == 24          # fits: no loop
+    assert tile(100, 8, 256, 256) == 20
+    assert tile(94, 8, 256, 256) == 94          # 2 x 47: 2 is no tile
+    assert tile(97, 8, 256, 256) == 97          # prime
+    assert tile(96, 8, 128, 128) == 96          # a quarter the scores: fits
+    assert tile(8, 8, 2048, 2048) == 8          # one sequence is over it
+    assert tile(96, 16, 256, 256) == 12         # twice the heads
+
+
+def test_composed_tiles_the_local_batch_under_a_data_mesh(monkeypatch):
+    """Under a mesh that shards the batch over 'data' alone each device
+    tiles its own share inside a shard_map (no loop over the sharded
+    dimension); a mesh with another axis in use keeps the whole batch."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.parallel import make_mesh
+    cfg = dict(_BASE, B=16, causal=True, klen=True)
+    att, q, k, v, kl = _composed_case(monkeypatch, cfg, tile_seqs=2)
+    want = att._ref_attention(q, k, v, True, 16 ** -0.5, kl)
+
+    def grad_of(mesh):
+        def f(q, k, v, kl):
+            out = att.flash_attention(q, k, v, causal=True, k_len=kl,
+                                      mesh=mesh)
+            return (out ** 2).sum(), out
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    want_g = jax.grad(lambda q, k, v: (att._ref_attention(
+        q, k, v, True, 16 ** -0.5, kl) ** 2).sum(), argnums=(0, 1, 2))(
+            q, k, v)
+    data4 = make_mesh(data=4, devices=jax.devices()[:4])
+    sh = NamedSharding(data4, P('data'))
+    before = _counters()
+    (_, out), grads = grad_of(data4)(*(jax.device_put(x, sh)
+                                       for x in (q, k, v, kl)))
+    assert _counters()[0] - before[0] == 1
+    assert out.sharding.is_equivalent_to(sh, out.ndim)
+    for a, b in zip((out,) + grads, (want,) + want_g):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+    lowered = grad_of(data4).lower(*(jax.device_put(x, sh)
+                                     for x in (q, k, v, kl)))
+    text = lowered.as_text()
+    # scores [c, Hkv, g, Tq, Tk]: 4 sequences a device in 2 tiles of 2
+    assert 'tensor<2x4x1x32x32xf32>' in text
+    assert 'tensor<4x4x1x32x32xf32>' not in text
+    assert 'tensor<16x4x1x32x32xf32>' not in text
+
+    def pulled_back(q, k, v, kl, g):
+        # no loss to sum over the devices: out and its pullback alone
+        out, pull = jax.vjp(lambda q, k, v: att.flash_attention(
+            q, k, v, causal=True, k_len=kl, mesh=data4), q, k, v)
+        return out, pull(g)
+
+    compiled = jax.jit(pulled_back).lower(*(
+        jax.device_put(x, sh) for x in (q, k, v, kl, q))).compile().as_text()
+    assert ' while(' in compiled
+    assert not [w for w in ('all-reduce', 'all-gather', 'all-to-all',
+                            'collective-permute') if w in compiled]
+
+    mixed = make_mesh(data=2, model=2, devices=jax.devices()[:4])
+    before = _counters()
+    (_, out), _ = grad_of(mixed)(q, k, v, kl)
+    assert _counters()[1] - before[1] == 1
+    np.testing.assert_allclose(out, want, atol=1e-5, rtol=1e-5)

@@ -218,3 +218,36 @@ def test_mosaic_compiles_the_kernel_at_real_widths(
                      or ' fusion(' in ln)]
     # nothing of the pool's size is made: the output is the queries' size
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize('causal,lengths', [(True, False), (False, True)],
+                         ids=['self', 'cross'])
+def test_short_attention_keeps_its_scores_on_chip_at_tbase_widths(
+        one_v5e_chip, causal, lengths):
+    """`jax.grad` through `flash_attention` at tbase.train_1chip's shape,
+    as XLA:TPU compiles it: the composed route runs over tiles of the
+    batch, so no score matrix of the whole batch is defined and the
+    block needs next to no HBM temporaries (302.5 MB before the tiles:
+    a 201 MB f32 array and its bf16 twin)."""
+    import jax
+    from paddle_tpu.ops.attention import _composed_tile, flash_attention
+    B, H, T, D = 96, 8, 256, 64
+    assert _composed_tile(B, H, T, T) < B
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                    sharding=one_v5e_chip)
+
+    def loss(q, k, v, kl):
+        return flash_attention(q, k, v, causal=causal, k_len=kl).astype(
+            jnp.float32).sum()
+
+    qkv = sds((B, H, T, D), 'bfloat16')
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv, sds((B,), 'int32') if lengths else None).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    text = compiled.as_text()
+    assert ' while(' in text
+    for whole in ('[%d,%d,%d,%d]' % (B, H, T, T),
+                  '[%d,%d,1,%d,%d]' % (B, H, T, T)):
+        assert whole not in text
