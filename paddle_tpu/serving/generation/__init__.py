@@ -15,6 +15,11 @@ decode server (docs/generation.md):
     per-launch DATA, so one warm executable serves every page
     assignment; chunked/ring prefill; speculative verify windows;
     AOT-compiled and persisted through the compile-cache disk tier.
+  * `ssm` — the Mamba-2 mixer of the `falcon_h1` block kind (a model
+    dict with ``block: 'falcon_h1'``): the chunked scan a prefill chunk
+    runs from and into a slot's recurrent state, and the single step of
+    a decode window; that state lives beside the page pool in the same
+    donated state dict (`CacheConfig.recurrent`).
   * `sampling` — greedy / temperature / top-k draws keyed by
     ``(request seed, absolute position)`` only, so fused and sequential
     decode sample bitwise-identical streams (ops/sampling.py).

@@ -49,6 +49,23 @@ compile-cache disk tier (core/compile_cache.callable_fingerprint) — the
 cache spec now carries page_len/pages/quant, so geometry changes get
 fresh fingerprints.  `dense_reference` is the independent, page-free
 parity oracle.
+
+The model dict chooses the BLOCK.  Without a ``block`` key it is the
+dense decoder above (RMSNorm, GQA, RoPE, SwiGLU), whose ``head_dim``
+and ``rms_eps`` default to ``d_model // n_head`` and 1e-6.  ``block:
+'falcon_h1'`` is the Falcon-H1 block: the same attention and
+feed-forward under the model's ``multipliers``, and BESIDE the
+attention, reading the same normalised input, a Mamba-2 mixer (ssm.py)
+whose outputs are both added to the residual stream.  Such a model has
+a second kind of state: per slot and layer a float32 scan state and the
+convolution's last inputs (``ssm`` / ``conv`` in the state dict,
+kv_cache.py), donated and carried like the pools.  A prefill chunk
+starts from the slot's state (from zeros at offset 0) and leaves it at
+``true_count``; a decode step advances every slot's and keeps an
+inactive slot's as it was, bit for bit.  That state can neither be
+shared between prompts nor rolled back, so such a runtime takes no
+prefix-cache hit (`generation.prefix_refused_recurrent` counts the
+begins), no speculative window and no ring prefill.
 """
 import threading
 
@@ -60,6 +77,7 @@ from ...ops.attention import (cached_attention, paged_attention,
                               paged_attention_eligible,
                               paged_attention_rows)
 from ...ops.sampling import sample_logits, sample_tokens_at, token_key
+from . import ssm as _ssm
 from .kv_cache import (CacheConfig, PagePool, PrefixCache, SlotAllocator,
                        init_state)
 
@@ -70,30 +88,49 @@ _WEIGHT_SLOTS = ('att_q_w', 'att_k_w', 'att_v_w', 'att_o_w', 'att_norm',
                  'ffn_norm', 'ffn_fc1_w', 'ffn_fc2_w', 'ffn_fc3_w')
 
 
+def _recurrent(cfg):
+    """Whether the model's block carries recurrent state (ssm.py)."""
+    block = cfg.get('block', 'dense')
+    if block not in ('dense', 'falcon_h1'):
+        raise ValueError("block must be 'dense' or 'falcon_h1', got %r"
+                         % (block,))
+    return block == 'falcon_h1'
+
+
+def _head_dim(cfg):
+    return int(cfg.get('head_dim', int(cfg['d_model']) // int(cfg['n_head'])))
+
+
 def weight_names(cfg):
     """The decode-side parameter names — the same names a trained llama
-    program leaves in its scope (models/llama.py layout)."""
+    program leaves in its scope (models/llama.py layout); a
+    ``falcon_h1`` block adds its mixer's (`ssm.SLOTS`)."""
+    slots = _WEIGHT_SLOTS + (_ssm.SLOTS if _recurrent(cfg) else ())
     names = ['tok_emb', 'final_norm', 'lm_proj_w']
     for i in range(int(cfg['n_layer'])):
-        names.extend('layer_%d_%s' % (i, s) for s in _WEIGHT_SLOTS)
+        names.extend('layer_%d_%s' % (i, s) for s in slots)
     return names
 
 
 def random_weights(cfg, seed=0, scale=0.08):
-    """Random-init weight dict with the llama layout (tests/soaks that
-    exercise the runtime without training a model first)."""
+    """Random-init weight dict under `weight_names(cfg)` (tests/soaks
+    that exercise the runtime without training a model first): a name
+    ending in ``norm`` is ones, the rest normal at ``scale``."""
     rng = np.random.RandomState(seed)
     d, v, h = int(cfg['d_model']), int(cfg['vocab']), int(cfg['n_head'])
     hkv, f = int(cfg['n_kv_head']), int(cfg['d_ffn'])
-    dh = d // h
+    dh = _head_dim(cfg)
+    mixer = _ssm.weight_shapes(d, cfg['ssm']) if _recurrent(cfg) else {}
     shapes = {'tok_emb': (v, d), 'final_norm': (d,), 'lm_proj_w': (d, v)}
     for i in range(int(cfg['n_layer'])):
         p = 'layer_%d_' % i
         shapes.update({p + 'att_q_w': (d, h * dh), p + 'att_k_w': (d, hkv * dh),
-                       p + 'att_v_w': (d, hkv * dh), p + 'att_o_w': (d, d),
+                       p + 'att_v_w': (d, hkv * dh),
+                       p + 'att_o_w': (h * dh, d),
                        p + 'att_norm': (d,), p + 'ffn_norm': (d,),
                        p + 'ffn_fc1_w': (d, f), p + 'ffn_fc3_w': (d, f),
                        p + 'ffn_fc2_w': (f, d)})
+        shapes.update((p + k, s) for k, s in mixer.items())
     out = {}
     for n, s in shapes.items():
         if n.endswith('norm'):
@@ -105,11 +142,22 @@ def random_weights(cfg, seed=0, scale=0.08):
 
 # ------------------------------------------------------- forward pieces
 
-def _rms(x, scale):
+def _rms(x, scale, eps=1e-6):
     import jax
     import jax.numpy as jnp
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + 1e-6) * scale
+    return x * jax.lax.rsqrt(var + eps) * scale
+
+
+def _eps(cfg):
+    return float(cfg.get('rms_eps', 1e-6))
+
+
+def _scaled(cfg, x, name):
+    """x times the model's multiplier ``name``; x itself for a model
+    without ``multipliers`` (the dense decoder)."""
+    mu = cfg.get('multipliers')
+    return x * mu[name] if mu else x
 
 
 def _rope_at(x, pos, theta):
@@ -129,20 +177,35 @@ def _qkv(w, cfg, h, i):
     """h: [B, T, D] -> q [B, H, T, dh], k/v [B, Hkv, T, dh] (pre-rope)."""
     B, T = h.shape[0], h.shape[1]
     H, Hkv = int(cfg['n_head']), int(cfg['n_kv_head'])
-    dh = int(cfg['d_model']) // H
+    dh = _head_dim(cfg)
     p = 'layer_%d_' % i
+    h = _scaled(cfg, h, 'attention_in')
     q = (h @ w[p + 'att_q_w']).reshape(B, T, H, dh).transpose(0, 2, 1, 3)
     k = (h @ w[p + 'att_k_w']).reshape(B, T, Hkv, dh).transpose(0, 2, 1, 3)
     v = (h @ w[p + 'att_v_w']).reshape(B, T, Hkv, dh).transpose(0, 2, 1, 3)
-    return q, k, v
+    return q, _scaled(cfg, k, 'key'), v
 
 
-def _ffn(w, x, i):
+def _ffn(w, cfg, x, i):
     import jax
     p = 'layer_%d_' % i
-    hh = _rms(x, w[p + 'ffn_norm'])
-    gate = jax.nn.silu(hh @ w[p + 'ffn_fc1_w'])
-    return x + (gate * (hh @ w[p + 'ffn_fc3_w'])) @ w[p + 'ffn_fc2_w']
+    hh = _rms(x, w[p + 'ffn_norm'], _eps(cfg))
+    gate = jax.nn.silu(_scaled(cfg, hh @ w[p + 'ffn_fc1_w'], 'mlp_gate'))
+    out = (gate * (hh @ w[p + 'ffn_fc3_w'])) @ w[p + 'ffn_fc2_w']
+    return x + _scaled(cfg, out, 'mlp_down')
+
+
+def _embed(w, cfg, tokens):
+    return _scaled(cfg, w['tok_emb'][tokens], 'embedding')
+
+
+def _head(w, cfg, x):
+    """x [..., D], normalised -> logits [..., V]."""
+    return _scaled(cfg, x @ w['lm_proj_w'], 'lm_head')
+
+
+def _attn_out(w, cfg, att, i):
+    return _scaled(cfg, att @ w['layer_%d_att_o_w' % i], 'attention_out')
 
 
 # -------------------------------------------------- paged read / write
@@ -231,11 +294,14 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
     import jax.numpy as jnp
     L = int(cfg['n_layer'])
     theta = float(cfg['theta'])
-    dh = int(cfg['d_model']) // int(cfg['n_head'])
+    dh = _head_dim(cfg)
     M, PL = cache.max_pages, cache.page_len
     quant = cache.quant == 'int8'
+    recurrent = _recurrent(cfg)
 
     if ring_mesh is not None:
+        if recurrent:
+            raise ValueError('ring prefill cannot carry recurrent state')
         from ...parallel.ring_attention import ring_attention
 
     def prefill(w, st, bt_row, tokens, slot, offset, true_count,
@@ -249,13 +315,13 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
                        bt_row[jnp.clip(p_abs // PL, 0, M - 1)], 0)
         rw = p_abs % PL
         with scope('embed'):
-            x = w['tok_emb'][tokens][None]                # [1, C, D]
+            x = _embed(w, cfg, tokens)[None]              # [1, C, D]
         for i in range(L):
             # ONE scope name for every layer: an operation's op_name
             # says which part of the block it is, whatever its index
             with scope('layer'):
                 with scope('attn.qkv'):
-                    h = _rms(x, w['layer_%d_att_norm' % i])
+                    h = _rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
                     q, k, v = _qkv(w, cfg, h, i)
                     q = _rope_at(q, pos, theta)
                     k = _rope_at(k, pos, theta)
@@ -274,13 +340,26 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
                         att = cached_attention(q, kl, vl, pos)
                     B, H, T = att.shape[0], att.shape[1], att.shape[2]
                     att = att.transpose(0, 2, 1, 3).reshape(B, T, H * dh)
-                    x = x + att @ w['layer_%d_att_o_w' % i]
+                    x = x + _attn_out(w, cfg, att, i)
+                if recurrent:
+                    # the slot's state as the last chunk left it; a
+                    # prompt's first chunk starts from zeros, whoever
+                    # held the slot before
+                    carried = offset > 0
+                    mix, S, tail = _ssm.prefill_mixer(
+                        w, 'layer_%d_' % i, cfg, h[0],
+                        jnp.where(carried, st['ssm'][slot, i], 0.0),
+                        jnp.where(carried, st['conv'][slot, i], 0.0),
+                        true_count)
+                    st = dict(st, ssm=st['ssm'].at[slot, i].set(S),
+                              conv=st['conv'].at[slot, i].set(tail))
+                    x = x + _scaled(cfg, mix[None], 'ssm_out')
                 with scope('ffn'):
-                    x = _ffn(w, x, i)
+                    x = _ffn(w, cfg, x, i)
         with scope('lm_head'):
-            x = _rms(x, w['final_norm'])
+            x = _rms(x, w['final_norm'], _eps(cfg))
             last = jax.lax.dynamic_slice_in_dim(x[0], true_count - 1, 1)[0]
-            logits = last @ w['lm_proj_w']                # [V] f32
+            logits = _head(w, cfg, last)                  # [V]
         new_len = offset + true_count
         with scope('sample'):
             nxt = sample_logits(logits, token_key(seed, new_len),
@@ -309,6 +388,7 @@ def _step_fn(cfg, cache, paged):
     theta = float(cfg['theta'])
     M, PL = cache.max_pages, cache.page_len
     quant = cache.quant == 'int8'
+    recurrent = _recurrent(cfg)
 
     def step(w, st, bt, fed, active, seeds, temps, topks):
         import jax
@@ -320,11 +400,11 @@ def _step_fn(cfg, cache, paged):
         rw = pos % PL
         n_attend = jnp.where(active, pos + 1, 0)          # [S]
         with scope('embed'):
-            x = w['tok_emb'][fed][:, None, :]             # [S, 1, D]
+            x = _embed(w, cfg, fed)[:, None, :]           # [S, 1, D]
         for i in range(L):
             with scope('layer'):     # one name for every layer (prefill)
                 with scope('attn.qkv'):
-                    h = _rms(x, w['layer_%d_att_norm' % i])
+                    h = _rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
                     q, k, v = _qkv(w, cfg, h, i)
                     q = _rope_at(q, pos[:, None], theta)
                     k = _rope_at(k, pos[:, None], theta)
@@ -341,12 +421,24 @@ def _step_fn(cfg, cache, paged):
                     else:
                         att = cached_attention(q, kl, vl, pos[:, None])
                         att = att.transpose(0, 2, 1, 3)
-                    x = x + att.reshape(S, 1, -1) @ w['layer_%d_att_o_w' % i]
+                    x = x + _attn_out(w, cfg, att.reshape(S, 1, -1), i)
+                if recurrent:
+                    # every slot steps; an inactive one keeps its state
+                    mix, S_new, tail = _ssm.step_mixer(
+                        w, 'layer_%d_' % i, cfg, h[:, 0], st['ssm'][:, i],
+                        st['conv'][:, i])
+                    st = dict(
+                        st, ssm=st['ssm'].at[:, i].set(jnp.where(
+                            active[:, None, None, None], S_new,
+                            st['ssm'][:, i])),
+                        conv=st['conv'].at[:, i].set(jnp.where(
+                            active[:, None, None], tail, st['conv'][:, i])))
+                    x = x + _scaled(cfg, mix[:, None], 'ssm_out')
                 with scope('ffn'):
-                    x = _ffn(w, x, i)
+                    x = _ffn(w, cfg, x, i)
         with scope('lm_head'):
-            x = _rms(x, w['final_norm'])
-            logits = x[:, 0] @ w['lm_proj_w']             # [S, V]
+            x = _rms(x, w['final_norm'], _eps(cfg))
+            logits = _head(w, cfg, x[:, 0])               # [S, V]
         with scope('sample'):
             nxt = sample_tokens_at(logits, seeds, pos + 1, temps, topks)
         st = dict(st)
@@ -413,7 +505,7 @@ def dense_reference(weights, cfg, prompt):
     x = w['tok_emb'][jnp.asarray(prompt, jnp.int32).reshape(1, P)]
     ks, vs = [], []
     for i in range(L):
-        h = _rms(x, w['layer_%d_att_norm' % i])
+        h = _rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
         q, k, v = _qkv(w, cfg, h, i)
         q = _rope_at(q, pos, theta)
         k = _rope_at(k, pos, theta)
@@ -429,8 +521,8 @@ def dense_reference(weights, cfg, prompt):
                          preferred_element_type=jnp.float32)
         att = att.reshape(1, H, P, dh).transpose(0, 2, 1, 3)
         x = x + att.reshape(1, P, H * dh) @ w['layer_%d_att_o_w' % i]
-        x = _ffn(w, x, i)
-    x = _rms(x, w['final_norm'])
+        x = _ffn(w, cfg, x, i)
+    x = _rms(x, w['final_norm'], _eps(cfg))
     logits = x[0, P - 1] @ w['lm_proj_w']
     return (np.asarray(jnp.stack(ks)), np.asarray(jnp.stack(vs)),
             np.asarray(logits))
@@ -455,6 +547,11 @@ class DecodeRuntime(object):
     claim) and per-window `ensure_capacity`, both of which report
     shortage as a clean False/None the scheduler turns into
     backpressure or a terminal ``kv_oom``.
+
+    A model whose block carries recurrent state (``block:
+    'falcon_h1'``; `recurrent`) runs WITHOUT the prefix cache whatever
+    ``prefix_cache`` says (a hit would skip tokens the scan state never
+    saw), and refuses speculative windows and ring prefill.
     """
 
     def __init__(self, weights, cfg, slots=4, prefill_chunk=8,
@@ -464,16 +561,21 @@ class DecodeRuntime(object):
         import jax.numpy as jnp
         self.cfg = dict(cfg)
         self.w = {n: jnp.asarray(weights[n]) for n in weight_names(cfg)}
-        H = int(cfg['n_head'])
+        self.recurrent = _recurrent(cfg)
         self.cache = CacheConfig(
             slots=slots, layers=int(cfg['n_layer']),
             kv_heads=int(cfg['n_kv_head']), max_len=int(cfg['max_len']),
-            head_dim=int(cfg['d_model']) // H, dtype=cache_dtype,
-            page_len=page_len, pages=pages, quant=kv_quant)
+            head_dim=_head_dim(cfg), dtype=cache_dtype,
+            page_len=page_len, pages=pages, quant=kv_quant,
+            recurrent=(_ssm.state_shapes(cfg['ssm']) if self.recurrent
+                       else None))
         self.allocator = SlotAllocator(self.cache.slots)
         self.pool = PagePool(self.cache)
+        # recurrent state cannot be shared between prompts: no prefix
+        # cache, and every begin that forgoes one is counted
+        self._prefix_refused = bool(prefix_cache) and self.recurrent
         self.prefix = (PrefixCache(self.pool, self.cache.page_len)
-                       if prefix_cache else None)
+                       if prefix_cache and not self.recurrent else None)
         S = self.cache.slots
         self.block_tables = np.zeros((S, self.cache.max_pages), np.int32)
         self.owned = [[] for _ in range(S)]
@@ -499,6 +601,8 @@ class DecodeRuntime(object):
         self._lock = threading.Lock()
         _obs.metrics.gauge('generation.kv_cache_bytes').set(
             self.cache.bytes())
+        _obs.metrics.gauge('generation.recurrent_state_bytes').set(
+            self.cache.recurrent_bytes())
 
     # ------------------------------------------------------- geometry
     @property
@@ -519,7 +623,8 @@ class DecodeRuntime(object):
         """Retire a slot: release every page its block table maps (a
         shared prefix page survives in the cache / other streams) and
         unmap the row.  Pages are never zeroed — positional masking
-        keeps stale rows unreachable."""
+        keeps stale rows unreachable — and neither is recurrent state:
+        the next prompt's first chunk starts from zeros."""
         slot = int(slot)
         pages, self.owned[slot] = self.owned[slot], []
         if pages:
@@ -561,6 +666,8 @@ class DecodeRuntime(object):
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         span = min(prompt.size + max(1, int(window)), self.cache.max_len)
         need = self.cache.pages_for(span)
+        if self._prefix_refused:
+            _obs.metrics.counter('generation.prefix_refused_recurrent').inc()
         matched = self.prefix.match(prompt) if self.prefix is not None else []
         evict = self.prefix.evict_one if self.prefix is not None else None
         fresh = self.pool.alloc(max(0, need - len(matched)), evict=evict)
@@ -611,6 +718,7 @@ class DecodeRuntime(object):
                 'pages_in_use': self.pool.in_use(),
                 'page_bytes': self.pool.page_bytes,
                 'bytes_reserved': self.cache.bytes(),
+                'recurrent_state_bytes': self.cache.recurrent_bytes(),
                 'bytes_live': self.pool.in_use() * self.pool.page_bytes,
                 'prefix_entries': (len(self.prefix)
                                    if self.prefix is not None else 0),
@@ -698,6 +806,10 @@ class DecodeRuntime(object):
 
     def _window_exec(self, kind, steps):
         import jax
+        if kind == 'verify' and self.recurrent:
+            raise ValueError(
+                'speculative decode rolls lengths back; recurrent state '
+                'cannot be rolled back')
 
         def build():
             make = _verify_fn if kind == 'verify' else _decode_fn
@@ -798,6 +910,8 @@ class DecodeRuntime(object):
             counter('generation.prefill_fetch_s').inc(fetch.seconds)
             counter('generation.prefill_tokens').inc(n)
             counter('generation.prefill_pad_tokens').inc(width - n)
+            if self.recurrent and offset == 0:
+                counter('generation.state_resets').inc()
         return nxt, logits
 
     def ring_pad(self, n):
@@ -867,6 +981,11 @@ class DecodeRuntime(object):
                 + live * steps * (steps + 1) // 2)
             counter('generation.kv_rows_read').inc(
                 self._window_rows_read(steps, act))
+            if self.recurrent:
+                # the step reads and writes every slot's state
+                counter('generation.state_slot_steps').inc(
+                    self.cache.slots * steps)
+                counter('generation.state_live_slot_steps').inc(live * steps)
         return act, out
 
     def decode_window(self, steps, active, seeds, temps, topks):

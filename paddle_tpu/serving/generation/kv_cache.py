@@ -32,6 +32,15 @@ instead of re-prefilling them.  Shared pages are full by construction,
 so a request's own writes (its prompt tail and generated tokens)
 always land in freshly allocated pages — copy-on-extend needs no copy.
 On by default (``DecodeRuntime(prefix_cache=False)`` turns it off).
+
+A model with RECURRENT layers (decode.py's ``falcon_h1`` block, ssm.py)
+keeps a second kind of state beside the pool, ``CacheConfig.recurrent``:
+per slot and layer a float32 scan state and the convolution's last
+inputs, ``ssm`` ``[slots, layers, heads, head_dim, d_state]`` and
+``conv`` ``[slots, layers, d_conv - 1, channels]`` in the same donated
+state dict.  A slot IS its row there: nothing is allocated or freed, the
+chunk at offset 0 starts from zeros, and such rows cannot be shared, so
+a runtime over them runs without the prefix cache.
 """
 import hashlib
 import threading
@@ -62,13 +71,16 @@ class CacheConfig(object):
     ``slots`` is the decode-batch width (rows of ``lengths``/``tok``
     and of the block table); ``pages`` the pool depth INCLUDING the
     reserved garbage page 0; ``page_len`` tokens per page (must divide
-    ``max_len``); ``quant`` is ``'none'`` or ``'int8'``.
+    ``max_len``); ``quant`` is ``'none'`` or ``'int8'``; ``recurrent``
+    is None or the (scan state, convolution tail) shapes of one slot in
+    one layer (`ssm.state_shapes`), both float32.
     """
     __slots__ = ('slots', 'layers', 'kv_heads', 'max_len', 'head_dim',
-                 'dtype', 'page_len', 'pages', 'quant')
+                 'dtype', 'page_len', 'pages', 'quant', 'recurrent')
 
     def __init__(self, slots, layers, kv_heads, max_len, head_dim,
-                 dtype='float32', page_len=None, pages=None, quant='none'):
+                 dtype='float32', page_len=None, pages=None, quant='none',
+                 recurrent=None):
         if int(slots) < 1:
             raise ValueError('kv cache needs >= 1 slot, got %r' % (slots,))
         self.slots = int(slots)
@@ -94,6 +106,8 @@ class CacheConfig(object):
         if self.quant not in ('none', 'int8'):
             raise ValueError("quant must be 'none' or 'int8', got %r"
                              % (quant,))
+        self.recurrent = None if recurrent is None else tuple(
+            tuple(int(n) for n in shape) for shape in recurrent)
 
     @property
     def max_pages(self):
@@ -134,9 +148,24 @@ class CacheConfig(object):
             b += 2 * 4 * self.layers * self.kv_heads * self.page_len
         return b
 
+    def recurrent_shapes(self):
+        """{state name: shape} of the recurrent arrays over every slot
+        and layer; empty for a model without recurrent layers."""
+        if self.recurrent is None:
+            return {}
+        lead = (self.slots, self.layers)
+        return {'ssm': lead + self.recurrent[0],
+                'conv': lead + self.recurrent[1]}
+
+    def recurrent_bytes(self):
+        """Bytes of the recurrent state (float32), every slot's."""
+        return 4 * sum(int(np.prod(s))
+                       for s in self.recurrent_shapes().values())
+
     def bytes(self):
-        """Total K+V pool bytes (capacity-planning helper)."""
-        return self.pages * self.page_bytes()
+        """Total bytes reserved for streams' state: the K+V pools and
+        the recurrent state (capacity-planning helper)."""
+        return self.pages * self.page_bytes() + self.recurrent_bytes()
 
     def dense_slot_bytes(self):
         """What ONE slot would reserve under the dense PR-11 layout (a
@@ -148,18 +177,22 @@ class CacheConfig(object):
 
     def spec(self):
         """Declarative blob for the AOT cache fingerprint."""
-        return {'slots': self.slots, 'layers': self.layers,
+        spec = {'slots': self.slots, 'layers': self.layers,
                 'kv_heads': self.kv_heads, 'max_len': self.max_len,
                 'head_dim': self.head_dim, 'dtype': self.dtype,
                 'page_len': self.page_len, 'pages': self.pages,
                 'quant': self.quant}
+        if self.recurrent is not None:
+            spec['recurrent'] = self.recurrent
+        return spec
 
 
 def init_state(cache_cfg):
     """Fresh device-side decode state: the K/V page pools plus per-slot
     ``lengths`` (tokens written so far) and ``tok`` (the next token to
     feed — set by prefill, advanced by every decode step).  int8 mode
-    adds the per-row dequant scale pools."""
+    adds the per-row dequant scale pools, recurrent layers their zeroed
+    ``ssm`` and ``conv`` rows."""
     import jax.numpy as jnp
     k = jnp.zeros(cache_cfg.pool_shape, jnp.dtype(cache_cfg.store_dtype))
     st = {'k': k, 'v': jnp.zeros_like(k),
@@ -169,6 +202,8 @@ def init_state(cache_cfg):
         ks = jnp.zeros(cache_cfg.scale_shape, jnp.float32)
         st['k_scale'] = ks
         st['v_scale'] = jnp.zeros_like(ks)
+    for name, shape in cache_cfg.recurrent_shapes().items():
+        st[name] = jnp.zeros(shape, jnp.float32)
     return st
 
 

@@ -149,6 +149,10 @@ class GenerationEngine(ServingEngine):
                                config=config, clock=clock)
         self.runtime = runtime
         self._gen = gen_config or GenerationConfig()
+        if self._gen.speculative and runtime.recurrent:
+            raise ValueError(
+                'speculative decode rolls lengths back; the recurrent '
+                'state of this runtime\'s model cannot be rolled back')
         self._active = []        # slot-holding requests, admission order
         self._round_no = 0       # rounds with work so far
 

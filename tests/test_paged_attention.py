@@ -185,19 +185,24 @@ def one_v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize('dtype,heads,slots,max_len,pages', [
-    ('bfloat16', 32, 32, 1280, 5121),     # mistral7b.chat_steady's pool
-    ('float32', 16, 8, 2048, None),       # chip_smoke's llama_1b widths
+@pytest.mark.parametrize('dtype,heads,kv_heads,layers,slots,max_len,pages', [
+    ('bfloat16', 32, 8, 16, 32, 1280, 5121),   # mistral7b.chat_steady's pool
+    ('float32', 16, 8, 16, 8, 2048, None),     # chip_smoke's llama_1b widths
+    # falconh1_34b.chat_long_answers: 20 query heads, five a kv head, are
+    # no multiple of the 8 sublanes but the array's own extent
+    ('bfloat16', 20, 4, 6, 32, 1544, 6177),
 ])
 def test_mosaic_compiles_the_kernel_at_real_widths(
-        one_v5e_chip, monkeypatch, dtype, heads, slots, max_len, pages):
+        one_v5e_chip, monkeypatch, dtype, heads, kv_heads, layers, slots,
+        max_len, pages):
     """Interpret mode cannot see a refused tiling or DMA; the compiler
     can, and the pool must reach the kernel as a bitcast, not a copy."""
     import jax
     from paddle_tpu.ops import _pallas
     monkeypatch.setattr(_pallas, 'interpret', lambda: False)
-    cache = CacheConfig(slots=slots, layers=16, kv_heads=8, max_len=max_len,
-                        head_dim=128, dtype=dtype, page_len=8, pages=pages)
+    cache = CacheConfig(slots=slots, layers=layers, kv_heads=kv_heads,
+                        max_len=max_len, head_dim=128, dtype=dtype,
+                        page_len=8, pages=pages)
     assert paged_attention_eligible(cache.pool_shape, dtype)
 
     def sds(shape, dt):
