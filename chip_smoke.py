@@ -52,6 +52,8 @@ SIZES = {
                        seq_resident=4096, seq_streamed=8192),
             # the B*T = 8192 lookups of the transformer-base step
             gather=dict(rows=8192, vocab=32000, width=512),
+            # the falconh1_34b cell's scan state, 15 of 32 slots live
+            ssm_step=dict(state=(32, 6, 32, 128, 256), groups=2, live=15),
             softmax=(32, 8, 256, 256),
             # transformer-base widths, one layer: layers share their
             # fused-group signatures, so one layer builds every plan
@@ -73,6 +75,7 @@ SIZES = {
             flash=dict(heads=2, kv_heads=1, head_dim=64,
                        seq_resident=128, seq_streamed=256),
             gather=dict(rows=256, vocab=512, width=128),
+            ssm_step=dict(state=(4, 2, 8, 16, 128), groups=2, live=2),
             softmax=(2, 2, 16, 16),
             groups=dict(n_layer=1, d_model=32, n_head=2, d_inner=64,
                         vocab=128, batch=2, seq=16)),
@@ -377,6 +380,61 @@ def _gather_check(cfg):
     return {'rows': cfg['rows'], 'bitwise': True}
 
 
+def _ssm_step_check(cfg):
+    """`ssm_step` (one decode step of the Mamba-2 recurrence, in place
+    over the live slots) against `scan_step` over every slot, on one
+    layer of a whole state array: no slot live (the kernel visits one
+    block and must hand it back), one, and ``live`` of them."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving.generation import ssm
+    shape, G = tuple(cfg['state']), cfg['groups']
+    S, L, H, P, N = shape
+    assert ssm.ssm_step_eligible(shape, jnp.float32), \
+        'smoke shape is not eligible'
+    layer = L // 2
+    rng = np.random.RandomState(SEED)
+    f32 = jnp.float32
+    x = jnp.asarray(rng.randn(S, H, P), f32)
+    dt = jnp.asarray(np.log1p(np.exp(rng.randn(S, H))), f32)
+    A = jnp.asarray(-np.exp(rng.randn(H)), f32)
+    B, C = (jnp.asarray(rng.randn(S, G, N), f32) for _ in range(2))
+    D = jnp.asarray(rng.randn(H), f32)
+
+    def fresh():
+        return jax.random.normal(jax.random.key(SEED), shape, f32)
+
+    def kernel(state, active):
+        return ssm.ssm_step(x, dt, A, B, C, D, state, layer, active)
+
+    compiled = jax.jit(kernel, donate_argnums=(0,)).lower(
+        fresh(), jnp.zeros(S, bool)).compile()
+    _assert_mosaic('ssm_step', compiled.as_text().count('tpu_custom_call'),
+                   1)
+    before = np.asarray(fresh())
+    want_y, want_S = (np.asarray(a) for a in jax.jit(ssm.scan_step)(
+        x, dt, A, B, C, D, before[:, layer]))
+    out = {}
+    for n in (0, 1, cfg['live']):
+        active = np.zeros(S, bool)
+        active[rng.permutation(S)[:n]] = True
+        y, state = (np.asarray(a) for a in compiled(fresh(),
+                                                    jnp.asarray(active)))
+        # what the kernel did not visit is what it was, bit for bit
+        untouched = np.ones((S, L), bool)
+        untouched[active, layer] = False
+        np.testing.assert_array_equal(state[untouched], before[untouched])
+        np.testing.assert_array_equal(y[~active], 0.0)
+        if n:
+            # the same f32 expressions; y sums d_state products in
+            # another order than XLA's reduction
+            _close('ssm_step state', state[active, layer], want_S[active],
+                   1e-6)
+            out['y_err_live_%d' % n] = float('%.2e' % _close(
+                'ssm_step y', y[active], want_y[active], 1e-5))
+    return out
+
+
 def _run_softmax_group(fluid, shape):
     """A program whose fused group holds a softmax, so the `row` kind's
     other kernel has a plan to check."""
@@ -466,6 +524,7 @@ def kernels(cfg):
         'flash_resident': _flash_check(flash, flash['seq_resident']),
         'flash_streamed': _flash_check(flash, flash['seq_streamed']),
         'gather': _gather_check(cfg['gather']),
+        'ssm_step': _ssm_step_check(cfg['ssm_step']),
     }
     # build the plans of the transformer's fused groups (LayerNorm rows,
     # attention, elementwise chains, the LR schedule, fused Adam) and of a

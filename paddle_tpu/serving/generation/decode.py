@@ -61,8 +61,11 @@ a second kind of state: per slot and layer a float32 scan state and the
 convolution's last inputs (``ssm`` / ``conv`` in the state dict,
 kv_cache.py), donated and carried like the pools.  A prefill chunk
 starts from the slot's state (from zeros at offset 0) and leaves it at
-``true_count``; a decode step advances every slot's and keeps an
-inactive slot's as it was, bit for bit.  That state can neither be
+``true_count``; a decode step advances the live slots' scan state in
+place (`ssm.ssm_step`, a Pallas kernel that touches no other slot's;
+under a mesh `ssm.scan_step` steps every slot and masks:
+`DecodeRuntime.state_kernel`) and keeps an inactive slot's state as it
+was, bit for bit.  That state can neither be
 shared between prompts nor rolled back, so such a runtime takes no
 prefix-cache hit (`generation.prefix_refused_recurrent` counts the
 begins), no speculative window and no ring prefill.
@@ -372,7 +375,7 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
     return prefill
 
 
-def _step_fn(cfg, cache, paged):
+def _step_fn(cfg, cache, paged, state_kernel):
     """One fused decode/verify step over ALL slots: write the fed token's
     K/V through the block table, attend, sample each slot's next token
     with the position-keyed stream, advance ACTIVE slots only.  Inactive
@@ -382,7 +385,11 @@ def _step_fn(cfg, cache, paged):
     (`ops.attention.paged_attention`): an active slot reads the pages
     its length covers, an inactive one nothing.  Otherwise the composed
     path gathers every slot's logical row first (`_logical_rows` +
-    `cached_attention`)."""
+    `cached_attention`).
+
+    ``state_kernel`` (`DecodeRuntime.state_kernel`) advances a recurrent
+    model's scan state in place over the live slots (`ssm.ssm_step`);
+    otherwise every slot's steps and the dead ones' is masked."""
     import jax.numpy as jnp
     L = int(cfg['n_layer'])
     theta = float(cfg['theta'])
@@ -423,14 +430,12 @@ def _step_fn(cfg, cache, paged):
                         att = att.transpose(0, 2, 1, 3)
                     x = x + _attn_out(w, cfg, att.reshape(S, 1, -1), i)
                 if recurrent:
-                    # every slot steps; an inactive one keeps its state
-                    mix, S_new, tail = _ssm.step_mixer(
-                        w, 'layer_%d_' % i, cfg, h[:, 0], st['ssm'][:, i],
-                        st['conv'][:, i])
+                    # an inactive slot keeps both kinds of state
+                    mix, scan_state, tail = _ssm.step_mixer(
+                        w, 'layer_%d_' % i, cfg, h[:, 0], st['ssm'], i,
+                        st['conv'][:, i], active, state_kernel)
                     st = dict(
-                        st, ssm=st['ssm'].at[:, i].set(jnp.where(
-                            active[:, None, None, None], S_new,
-                            st['ssm'][:, i])),
+                        st, ssm=scan_state,
                         conv=st['conv'].at[:, i].set(jnp.where(
                             active[:, None, None], tail, st['conv'][:, i])))
                     x = x + _scaled(cfg, mix[:, None], 'ssm_out')
@@ -449,13 +454,13 @@ def _step_fn(cfg, cache, paged):
     return step
 
 
-def _decode_fn(cfg, cache, steps, paged):
+def _decode_fn(cfg, cache, steps, paged, state_kernel):
     """K-step fused decode window: each step feeds every slot's own
     carry token.  One `lax.scan`; the state dict is donated carry; the
     block table is closed-over DATA (an ordinary traced argument)."""
     import jax
 
-    step = _step_fn(cfg, cache, paged)
+    step = _step_fn(cfg, cache, paged, state_kernel)
 
     def window(w, st, bt, active, seeds, temps, topks):
         def body(carry, _):
@@ -468,7 +473,7 @@ def _decode_fn(cfg, cache, steps, paged):
     return window
 
 
-def _verify_fn(cfg, cache, steps, paged):
+def _verify_fn(cfg, cache, steps, paged, state_kernel):
     """K-step speculative VERIFY window: identical step body, but step j
     feeds ``fed[j]`` (host-built: last emitted token, then the draft's
     proposals) and the returned samples are the target model's verdicts
@@ -476,7 +481,7 @@ def _verify_fn(cfg, cache, steps, paged):
     an accepted prefix is bitwise the sequential stream."""
     import jax
 
-    step = _step_fn(cfg, cache, paged)
+    step = _step_fn(cfg, cache, paged, state_kernel)
 
     def window(w, st, bt, fed, active, seeds, temps, topks):
         def body(carry, fed_t):
@@ -593,6 +598,10 @@ class DecodeRuntime(object):
         # a mesh of several devices keep the composed gather
         self.paged = paged_attention_eligible(
             self.cache.pool_shape, self.cache.store_dtype, mesh)
+        # likewise the scan state of a recurrent model: in place over
+        # the live slots where that kernel can run (float32, one device)
+        self.state_kernel = self.recurrent and _ssm.ssm_step_eligible(
+            self.state['ssm'].shape, self.state['ssm'].dtype, mesh)
         self._execs = {}
         # rows of K (or V) per layer one COMPOSED step gathers
         self._gathered = None if self.paged else _gathered_rows(
@@ -813,7 +822,8 @@ class DecodeRuntime(object):
 
         def build():
             make = _verify_fn if kind == 'verify' else _decode_fn
-            fn = make(self.cfg, self.cache, steps, self.paged)
+            fn = make(self.cfg, self.cache, steps, self.paged,
+                      self.state_kernel)
             jitted = jax.jit(fn, donate_argnums=(1,))
             S = self.cache.slots
             vec = lambda dt: self._sds((S,), dt)  # noqa: E731
@@ -982,9 +992,12 @@ class DecodeRuntime(object):
             counter('generation.kv_rows_read').inc(
                 self._window_rows_read(steps, act))
             if self.recurrent:
-                # the step reads and writes every slot's state
+                # slot-steps whose scan state the window touched: the
+                # kernel's live slots; the composed step reads and
+                # writes every slot's state
                 counter('generation.state_slot_steps').inc(
-                    self.cache.slots * steps)
+                    (live if self.state_kernel else self.cache.slots)
+                    * steps)
                 counter('generation.state_live_slot_steps').inc(live * steps)
         return act, out
 

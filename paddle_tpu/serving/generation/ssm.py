@@ -25,18 +25,33 @@ between blocks the state is carried), starting FROM the slot's state
 and leaving it at ``true_count``: a pad position gets dt = 0, so it
 neither decays the state nor feeds it, and the convolution's tail is
 cut at ``true_count``.  `step_mixer` is the single recurrence step of a
-decode window, every slot at once; which slots keep the result is the
-caller's mask.  Everything is plain jax.numpy; the state's products run
-at `highest` precision (they are a few per cent of a chunk's matrix
-work) so that the chunked and the stepwise form agree to float32.
+decode window over the WHOLE state array ``[slots, layers, ...]``: a
+live slot's state of that layer advances, a dead slot's stays bit for
+bit what it was.
+
+The step's recurrence has two routes.  `ssm_step` is a Pallas kernel
+that updates the state array IN PLACE: it walks the live slots only
+(their indices are scalar prefetch), reads each block of a live slot's
+state once, writes it once, and takes ``y = S C`` from the registers
+that hold the new state.  `scan_step` is the same arithmetic in plain
+jax.numpy over every slot, masked afterwards: the route under a mesh or
+for a state the kernel cannot tile (`ssm_step_eligible`, a static rule
+on the state's shape, dtype and the mesh), and the reference the kernel
+is tested against.  The chunk scan and everything around the recurrence
+are plain jax.numpy; the state's products run at `highest` precision
+(they are a few per cent of a chunk's matrix work) so that the chunked
+and the stepwise form agree to float32.
 """
+import functools
 import math
 
 import numpy as np
 
+from ...ops import _pallas
+
 __all__ = ['SLOTS', 'part_sizes', 'conv_channels', 'weight_shapes',
            'state_shapes', 'prefill_mixer', 'step_mixer', 'scan_chunk',
-           'scan_step']
+           'scan_step', 'ssm_step', 'ssm_step_eligible']
 
 # the mixer's weights of one layer, after `layer_<i>_`.  The gated
 # norm's scale ends in `norm`: whoever draws weights makes such a name
@@ -179,6 +194,170 @@ def scan_step(x, dt, A, B, C, D, S):
     return jnp.sum(S * Ch, axis=-1) + D[:, None] * x, S
 
 
+# ------------------------------------------------ the step, in place
+
+# bytes of one [head block, head_dim, d_state] tile of the state.  The
+# kernel holds four (in and out, double buffered)
+_STEP_BLOCK_BYTES = 1 << 20
+
+
+def _head_block(H, P, N):
+    """Heads one tile of `ssm_step` holds: the largest divisor of H
+    whose tile fits _STEP_BLOCK_BYTES and whose rows of x tile ([hb, P]
+    with hb whole sublanes, or every head).  None where nothing does."""
+    fits = [hb for hb in range(1, H + 1)
+            if H % hb == 0 and (hb % 8 == 0 or hb == H)
+            and hb * P * N * 4 <= _STEP_BLOCK_BYTES]
+    return max(fits, default=None)
+
+
+def ssm_step_eligible(state_shape, dtype, mesh=None):
+    """Static rule for `ssm_step` over a ``[slots, layers, heads,
+    head_dim, d_state]`` state: float32 on a single device; on an
+    accelerator a head's ``[head_dim, d_state]`` must be whole tiles
+    and some block of heads must fit the kernel's buffers."""
+    import jax.numpy as jnp
+    if jnp.dtype(dtype) != jnp.float32 or not _pallas.single_device(mesh):
+        return False
+    if _pallas.interpret():
+        return True
+    _slots, _layers, H, P, N = state_shape
+    return N % 128 == 0 and P % 8 == 0 and _head_block(H, P, N) is not None
+
+
+def _live_slots(active):
+    """active [S] bool -> (order [S] int32, count [1] int32): the live
+    slots' indices compacted to the front in slot order (zeros behind
+    them) and how many there are.  Plain data for `ssm_step`'s scalar
+    prefetch; a comparison and a sum, no sort.  Every layer of a step
+    asks with the same mask: XLA keeps one copy."""
+    import jax.numpy as jnp
+    S = active.shape[0]
+    ids = jnp.arange(S, dtype=jnp.int32)
+    rank = jnp.cumsum(active.astype(jnp.int32)) - 1
+    here = active[None, :] & (rank[None, :] == ids[:, None])
+    order = jnp.sum(jnp.where(here, ids[None, :], 0), axis=1)
+    return order, jnp.sum(active.astype(jnp.int32)).reshape(1)
+
+
+def _grid_block(pos, hblk, order_ref, count_ref, nh):
+    """(slot, head block) that grid position (pos, hblk) of `ssm_step`
+    works on.  A position past the live count names the LAST live
+    block again (with no live slot at all: one block of slot
+    ``order[0]``), so the pipeline neither fetches nor writes anything
+    new for it."""
+    import jax.numpy as jnp
+    count = count_ref[0]
+    return (order_ref[jnp.clip(pos, 0, jnp.maximum(count - 1, 0))],
+            jnp.where(pos < count, hblk, nh - 1))
+
+
+def _ssm_step_kernel(order_ref, count_ref, layer_ref, decay_ref, dtx_ref,
+                     dx_ref, bc_ref, s_ref, o_ref, y_ref, *, heads, groups):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    del layer_ref                         # the index maps read it
+    pos, hblk = pl.program_id(0), pl.program_id(1)
+    count = count_ref[0]
+    hb, P, _ = s_ref.shape
+    rep = heads // groups
+
+    @pl.when(pos < count)
+    def _():
+        slot = order_ref[pos]
+        # [P, P] identity: turns a row of P lanes into a column of P
+        # sublanes and back with one select and one sum, exactly
+        eye = jax.lax.broadcasted_iota(jnp.int32, (P, P), 0) \
+            == jax.lax.broadcasted_iota(jnp.int32, (P, P), 1)
+        for j in range(hb):
+            head = hblk * hb + j
+            g = head // rep
+            b = bc_ref[pl.ds(g, 1), :]                       # [1, N]
+            c = bc_ref[pl.ds(groups + g, 1), :]
+            dtx = jnp.sum(jnp.where(eye, dtx_ref[j:j + 1, :], 0.0),
+                          axis=1, keepdims=True)             # [P, 1]
+            S = decay_ref[slot * heads + head] * s_ref[j] + dtx * b
+            o_ref[j] = S
+            y = jnp.sum(S * c, axis=1, keepdims=True)        # [P, 1]
+            y_ref[j:j + 1, :] = dx_ref[j:j + 1, :] + jnp.sum(
+                jnp.where(eye, y, 0.0), axis=0, keepdims=True)
+
+    # no live slot: every grid position maps to ONE block, which goes
+    # back as it came (an output block the body left alone is written
+    # back as whatever its buffer held)
+    @pl.when((count == 0) & (pos == 0) & (hblk == 0))
+    def _():
+        o_ref[...] = s_ref[...]
+
+
+def ssm_step(x, dt, A, B, C, D, state, layer, active):
+    """One step of the recurrence for the LIVE slots, on the state array
+    in place.
+
+    x [S, H, P], dt [S, H] (after softplus), A, D [H], B, C [S, G, N],
+    float32; state [S, layers, H, P, N] float32, WHOLE: it is aliased
+    to the first result, so under donation XLA neither slices nor
+    copies it; layer an int32 scalar; active [S] bool, the live slots.
+    Returns (y [S, H, P], state): `scan_step`'s arithmetic (only the
+    order of the sum over N may differ) for the live slots; every other
+    slot's state, and every other layer's, is not touched, and a slot
+    that is not live gets zeros for y.
+
+    The grid walks the live slots' indices, compacted to the front
+    (scalar prefetch), x blocks of heads.  A position past the live
+    count repeats the last live block's index, so nothing is fetched or
+    written for it.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    S, _layers, H, P, N = state.shape
+    G = B.shape[1]
+    hb = _head_block(H, P, N) or H
+    nh = H // hb
+
+    def rows(pos, hblk, order_ref, count_ref, layer_ref):
+        return _grid_block(pos, hblk, order_ref, count_ref, nh) + (0,)
+
+    def slot_only(pos, hblk, order_ref, count_ref, layer_ref):
+        return (_grid_block(pos, hblk, order_ref, count_ref, nh)[0], 0, 0)
+
+    def tile(pos, hblk, order_ref, count_ref, layer_ref):
+        slot, hblk = _grid_block(pos, hblk, order_ref, count_ref, nh)
+        return (slot, layer_ref[0], hblk, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S, nh),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),            # decay
+            pl.BlockSpec((None, hb, P), rows),                # dt x
+            pl.BlockSpec((None, hb, P), rows),                # D x
+            pl.BlockSpec((None, 2 * G, N), slot_only),        # B over C
+            pl.BlockSpec((None, None, hb, P, N), tile),
+        ],
+        out_specs=[pl.BlockSpec((None, None, hb, P, N), tile),
+                   pl.BlockSpec((None, hb, P), rows)],
+    )
+    order, count = _live_slots(active)
+    state, y = pl.pallas_call(
+        functools.partial(_ssm_step_kernel, heads=H, groups=G),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((S, H, P), jnp.float32)],
+        # operand 7 (after the three prefetched scalars): the state
+        input_output_aliases={7: 0},
+        name='ssm_step',
+        interpret=_pallas.interpret(),
+    )(order, count, jnp.asarray(layer, jnp.int32).reshape(1),
+      jnp.exp(dt * A).reshape(-1), dt[..., None] * x, D[:, None] * x,
+      jnp.concatenate([B, C], axis=1), state)
+    # a dead slot's rows of y were never written
+    return jnp.where(active[:, None, None], y, 0.0), state
+
+
 def prefill_mixer(w, p, cfg, h, S0, tail, true_count):
     """One slot, one prefill chunk: h [C, D] normalised, S0 and tail the
     slot's state of this layer (zeros where the prompt begins).  Returns
@@ -206,12 +385,20 @@ def prefill_mixer(w, p, cfg, h, S0, tail, true_count):
     return out, S, tail
 
 
-def step_mixer(w, p, cfg, h, S, tail):
-    """Every slot, one decode step: h [slots, D] normalised, S [slots,
-    H, P, N], tail [slots, K-1, ch].  Returns (out [slots, D], S, tail)
-    for ALL slots; the caller keeps an inactive slot's old state."""
+def step_mixer(w, p, cfg, h, state, layer, tail, active, kernel):
+    """Every slot, one decode step of layer ``layer``: h [slots, D]
+    normalised, state [slots, layers, H, P, N] (the WHOLE scan state),
+    tail [slots, K-1, ch] (this layer's), active [slots] bool.  Returns
+    (out [slots, D], state, tail): the scan state of the live slots
+    advanced in this layer and nothing else of it changed; the tail for
+    ALL slots (the caller keeps an inactive slot's old one).
+
+    ``kernel`` (`ssm_step_eligible`, static) runs the recurrence in
+    place over the live slots (`ssm_step`); otherwise every slot steps
+    (`scan_step`) and the dead ones' result is masked away."""
     import jax
     import jax.numpy as jnp
+    from ... import observability as _obs
     ssm = cfg['ssm']
     z, xbc, dt = _in_proj(h, w, p, ssm, cfg['multipliers'])
     with jax.named_scope('ssm.conv'):
@@ -222,7 +409,14 @@ def step_mixer(w, p, cfg, h, S, tail):
         xbc = jax.nn.silu(conv)
     with jax.named_scope('ssm.step'):
         x, B, Cm, dt, A, D = _heads(xbc, dt, w, p, ssm)
-        y, S = scan_step(x, dt, A, B, Cm, D, S)
+        if kernel:
+            _obs.metrics.counter('ssm.step_kernel').inc()
+            y, state = ssm_step(x, dt, A, B, Cm, D, state, layer, active)
+        else:
+            _obs.metrics.counter('ssm.step_composed').inc()
+            y, S = scan_step(x, dt, A, B, Cm, D, state[:, layer])
+            state = state.at[:, layer].set(jnp.where(
+                active[:, None, None, None], S, state[:, layer]))
     out = _gate_out(y.reshape(h.shape[0], -1), z, w, p, ssm,
                     float(cfg['rms_eps']))
-    return out, S, tail
+    return out, state, tail

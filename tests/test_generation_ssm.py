@@ -206,12 +206,24 @@ def test_an_inactive_slots_state_is_bit_identical_after_a_window(rt):
     for k, was in kept.items():
         np.testing.assert_array_equal(np.asarray(rt.state[k][idle]), was)
     assert np.abs(np.asarray(rt.state['ssm'][live]) - moved).max() > 0
-    # every slot's state went through the step; one slot's was live
+    # the kernel touched the live slot's state alone
+    assert rt.state_kernel
     after = obs.counters()
     assert after['generation.state_slot_steps'] \
-        - before.get('generation.state_slot_steps', 0) == rt.slots * WINDOW
+        - before.get('generation.state_slot_steps', 0) == WINDOW
     assert after['generation.state_live_slot_steps'] \
         - before.get('generation.state_live_slot_steps', 0) == WINDOW
+
+
+def test_the_window_lowers_the_kernel_route_and_not_the_composed_one(weights):
+    before = obs.counters()
+    fresh = _runtime(weights, slots=2)
+    assert fresh.state_kernel
+    fresh.warmup(steps=WINDOW)
+    after = obs.counters()
+    assert after['ssm.step_kernel'] > before.get('ssm.step_kernel', 0)
+    assert after.get('ssm.step_composed', 0) \
+        == before.get('ssm.step_composed', 0)
 
 
 def test_the_engine_batches_streams_over_recurrent_state(rt):
