@@ -533,6 +533,50 @@ def dense_reference(weights, cfg, prompt):
             np.asarray(logits))
 
 
+class _Pending(np.lib.mixins.NDArrayOperatorsMixin):
+    """What a launch returned, still on the device.  It stands in for
+    the array: ``[i]``, ``int()``, ``len()``, iteration, `np.asarray`,
+    arithmetic, comparisons and any ndarray attribute read it; the
+    first of them waits for the launch, and the runtime times that wait
+    as the launch's fetch (`DecodeRuntime._pending`).  Never read, it
+    costs no transfer."""
+    __slots__ = ('_land', '_host')
+
+    def __init__(self, land):
+        self._land, self._host = land, None
+
+    def read(self):
+        if self._land is not None:
+            self._host, self._land = self._land(), None
+        return self._host
+
+    def __array__(self, dtype=None, copy=None):
+        out = self.read()
+        return out if dtype is None else out.astype(dtype)
+
+    def __getitem__(self, i):
+        return self.read()[i]
+
+    def __int__(self):
+        return int(self.read())
+
+    __index__ = __int__
+
+    def __len__(self):
+        return len(self.read())
+
+    def __iter__(self):
+        return iter(self.read())
+
+    def __array_ufunc__(self, ufunc, method, *inputs, **kwargs):
+        # arithmetic and comparisons (NDArrayOperatorsMixin), on the host
+        inputs = [x.read() if isinstance(x, _Pending) else x for x in inputs]
+        return getattr(ufunc, method)(*inputs, **kwargs)
+
+    def __getattr__(self, name):
+        return getattr(self.read(), name)
+
+
 class DecodeRuntime(object):
     """The device half of the streaming decode server: the paged KV
     pool + block tables + AOT prefill/decode/verify executables over one
@@ -603,6 +647,9 @@ class DecodeRuntime(object):
         self.state_kernel = self.recurrent and _ssm.ssm_step_eligible(
             self.state['ssm'].shape, self.state['ssm'].dtype, mesh)
         self._execs = {}
+        # arguments uploaded ahead of their launch: {'prefill' | 'window':
+        # [(host copy, device array), ...]} (`stage_prefill`, `stage_window`)
+        self._staged = {}
         # rows of K (or V) per layer one COMPOSED step gathers
         self._gathered = None if self.paged else _gathered_rows(
             self.cache, self._state_structs(),
@@ -655,6 +702,7 @@ class DecodeRuntime(object):
         self.block_tables[:] = 0
         self.host_len[:] = 0
         self.host_tok[:] = 0
+        self._staged.clear()
         self.state = init_state(self.cache)
 
     # ------------------------------------------------ page accounting
@@ -868,14 +916,65 @@ class DecodeRuntime(object):
             if speculative:
                 self._verify_exec(int(steps))
 
+    # ------------------------------------------------------ launching
+    # A launch is upload -> dispatch, and returns what the executable
+    # returned, still on the device (`_Pending`): whoever reads it pays
+    # the wait, which is timed here as that launch's fetch.  `stage_*`
+    # makes the upload ahead of the launch, while another one runs.
+    def _put(self, values):
+        """[(host copy, device array)] of numpy ``values``.  The device
+        array is made from a copy nobody else holds: the caller's array
+        (a row of `block_tables`, say) changes while the launch is in
+        flight, and on the CPU `jax.device_put` may alias its memory."""
+        import jax
+        hosts = [np.array(v, order='C') for v in values]
+        return list(zip(hosts, jax.device_put(hosts)))
+
+    def _uploaded(self, kind, values):
+        """The device copies of one launch's arguments: what `stage_*`
+        left for this ``kind`` of launch wherever its bytes are these
+        ``values``' (the block table among them), a fresh upload of the
+        others.  Whatever was staged is spent either way."""
+        staged = self._staged.pop(kind, None)
+        if staged is None or len(staged) != len(values):
+            staged = [None] * len(values)
+        args = [held[1] if held is not None and np.array_equal(held[0], v)
+                else None for held, v in zip(staged, values)]
+        fresh = [i for i, a in enumerate(args) if a is None]
+        for i, (_, dev) in zip(fresh, self._put([values[i] for i in fresh])):
+            args[i] = dev
+        if _obs.enabled():
+            _obs.metrics.counter('generation.launches').inc()
+            if not fresh:
+                _obs.metrics.counter('generation.launches_staged').inc()
+        return args
+
+    def _stage(self, kind, values):
+        with _obs.span('decode.%s.upload' % kind, cat='decode') as sp:
+            self._staged[kind] = self._put(values)
+        if _obs.enabled():
+            # the upload's time belongs to the launch it is made for
+            _obs.metrics.counter('generation.%s_s' % kind).inc(sp.seconds)
+
+    def _pending(self, kind, dev, then=None):
+        """``dev`` as a `_Pending` whose read is its launch's fetch: a
+        `decode.<kind>.fetch` span whose seconds go to
+        `generation.<kind>_s` and `generation.<kind>_fetch_s` wherever
+        the read happens; ``then(host array)`` follows it."""
+        def land():
+            with _obs.span('decode.%s.fetch' % kind, cat='decode') as fetch:
+                out = np.asarray(dev)
+            if _obs.enabled():
+                counter = _obs.metrics.counter
+                counter('generation.%s_s' % kind).inc(fetch.seconds)
+                counter('generation.%s_fetch_s' % kind).inc(fetch.seconds)
+            if then is not None:
+                then(out)
+            return out
+        return _Pending(land)
+
     # -------------------------------------------------------- prefill
-    def prefill(self, slot, tokens, offset, params):
-        """Run ONE prefill chunk for ``slot``: tokens[offset:offset+C]
-        of the prompt (the final chunk may be short — it is padded to
-        the chunk width and masked by ``true_count``).  Returns
-        (next_token, logits) — meaningful only on the final chunk.
-        ``params`` is a SamplingParams.  The slot's block table must
-        already cover the chunk (`try_begin`/`ensure_capacity`)."""
+    def _chunk(self, tokens, offset):
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         n = tokens.shape[0]
         if not 0 < n <= self.prefill_chunk:
@@ -883,46 +982,72 @@ class DecodeRuntime(object):
                              'prefill executable' % (n, self.prefill_chunk))
         if offset + n > self.cache.max_len:
             raise ValueError('prefill past max_len=%d' % self.cache.max_len)
+        return tokens
+
+    def _prefill_values(self, width, slot, tokens, offset, params):
+        """What a prefill launch uploads, in the executable's order:
+        the slot's block-table row, the chunk padded to ``width``, and
+        six scalars."""
+        buf = np.zeros(width, np.int32)
+        buf[:tokens.shape[0]] = tokens
+        return [self.block_tables[slot], buf, np.int32(slot),
+                np.int32(offset), np.int32(tokens.shape[0]),
+                np.int32(params.seed), np.float32(params.temperature),
+                np.int32(params.top_k)]
+
+    def stage_prefill(self, slot, tokens, offset, params):
+        """Upload now what `prefill` with these arguments will launch
+        with, so that the launch itself is the dispatch alone.  The
+        staged copies are used only if the arguments and the slot's
+        block-table row still read the same at the launch."""
+        self._stage('prefill', self._prefill_values(
+            self.prefill_chunk, slot, self._chunk(tokens, offset), offset,
+            params))
+
+    def prefill(self, slot, tokens, offset, params):
+        """Launch ONE prefill chunk for ``slot``: tokens[offset:offset+C]
+        of the prompt (the final chunk may be short — it is padded to
+        the chunk width and masked by ``true_count``).  Returns
+        (next_token, logits) — meaningful only on the final chunk, and
+        still on the device (`_Pending`): reading one waits for the
+        chunk.  ``params`` is a SamplingParams.  The slot's block table
+        must already cover the chunk (`try_begin`/`ensure_capacity`)."""
         return self._launch_prefill(self._prefill_exec(self.prefill_chunk),
-                                    self.prefill_chunk, slot, tokens, offset,
+                                    self.prefill_chunk, slot,
+                                    self._chunk(tokens, offset), offset,
                                     params, ring=False)
 
     def _launch_prefill(self, call, width, slot, tokens, offset, params,
                         ring):
-        """Pad ``tokens`` to the executable's ``width``, upload, launch,
-        fetch the sample: one `decode.prefill` span with `upload` /
-        `dispatch` / `fetch` children, and the prefill counters (time,
-        time blocked in the fetch, real and padding tokens)."""
-        import jax.numpy as jnp
+        """Pad ``tokens`` to the executable's ``width``, upload (or take
+        what was staged), launch: one `decode.prefill` span with
+        `upload` / `dispatch` children, and the prefill counters (time,
+        real and padding tokens).  `host_len` moves here, `host_tok`
+        when the sample is read."""
         n = tokens.shape[0]
         with _obs.span('decode.prefill', cat='decode', slot=int(slot),
                        tokens=int(n), ring=ring) as sp:
             with _obs.span('decode.prefill.upload', cat='decode'):
-                buf = np.zeros(width, np.int32)
-                buf[:n] = tokens
-                args = (jnp.asarray(self.block_tables[slot]),
-                        jnp.asarray(buf), jnp.int32(slot),
-                        jnp.int32(offset), jnp.int32(n),
-                        jnp.int32(params.seed),
-                        jnp.float32(params.temperature),
-                        jnp.int32(params.top_k))
+                args = self._uploaded('prefill', self._prefill_values(
+                    width, slot, tokens, offset, params))
             with _obs.span('decode.prefill.dispatch', cat='decode'):
                 st, nxt, logits = call(self.w, self.state, *args)
                 self.state = st
-            with _obs.span('decode.prefill.fetch', cat='decode') as fetch:
-                nxt = int(nxt)
-                logits = np.asarray(logits)
+                nxt.copy_to_host_async()
         self.host_len[slot] = offset + n
-        self.host_tok[slot] = nxt
         if _obs.enabled():
             counter = _obs.metrics.counter
             counter('generation.prefill_s').inc(sp.seconds)
-            counter('generation.prefill_fetch_s').inc(fetch.seconds)
             counter('generation.prefill_tokens').inc(n)
             counter('generation.prefill_pad_tokens').inc(width - n)
             if self.recurrent and offset == 0:
                 counter('generation.state_resets').inc()
-        return nxt, logits
+
+        def sampled(out):
+            self.host_tok[slot] = out
+
+        return (self._pending('prefill', nxt, sampled),
+                self._pending('prefill', logits))
 
     def ring_pad(self, n):
         """Padded one-shot ring prefill width for an n-token prompt:
@@ -933,7 +1058,8 @@ class DecodeRuntime(object):
 
     def prefill_ring(self, slot, prompt, params):
         """One-shot long-context prefill through ring attention: the
-        whole (padded) prompt in a single launch.  Requires ``mesh``."""
+        whole (padded) prompt in a single launch, read at once.
+        Requires ``mesh``."""
         if self.mesh is None:
             raise ValueError('ring prefill needs a mesh with a seq axis')
         prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -942,46 +1068,53 @@ class DecodeRuntime(object):
         if n > width:
             raise ValueError('prompt of %d exceeds max_len=%d'
                              % (n, self.cache.max_len))
-        return self._launch_prefill(self._prefill_exec(width, ring=True),
-                                    width, slot, prompt, 0, params,
-                                    ring=True)
+        nxt, logits = self._launch_prefill(
+            self._prefill_exec(width, ring=True), width, slot, prompt, 0,
+            params, ring=True)
+        return int(nxt), logits.read()
 
     # --------------------------------------------------------- decode
-    def _vecs(self, active, seeds, temps, topks):
-        import jax.numpy as jnp
+    def _window_values(self, fed, active, seeds, temps, topks):
+        """What a window launch uploads, in the executable's order: the
+        block table, a verify window's fed rows, the per-slot vectors."""
         S = self.cache.slots
-        return (jnp.asarray(np.asarray(active, bool).reshape(S)),
-                jnp.asarray(np.asarray(seeds, np.int32).reshape(S)),
-                jnp.asarray(np.asarray(temps, np.float32).reshape(S)),
-                jnp.asarray(np.asarray(topks, np.int32).reshape(S)))
+        return ([self.block_tables] + ([] if fed is None else [fed.T])
+                + [np.asarray(active, bool).reshape(S),
+                   np.asarray(seeds, np.int32).reshape(S),
+                   np.asarray(temps, np.float32).reshape(S),
+                   np.asarray(topks, np.int32).reshape(S)])
+
+    def stage_window(self, active, seeds, temps, topks):
+        """Upload now what `decode_window` with these vectors will
+        launch with.  Each staged copy is used only if it still reads
+        the same at the launch: a changed ``active`` (a stream ended
+        that nobody foresaw) is uploaded again, alone."""
+        self._stage('window', self._window_values(None, active, seeds,
+                                                  temps, topks))
 
     def _launch_window(self, kind, steps, fed, active, seeds, temps, topks):
-        """Upload the per-slot vectors, launch one K-step window
-        executable, fetch its [slots, steps] samples: one `decode.window`
-        span with `upload` / `dispatch` / `fetch` children, and the
-        window counters (time, time blocked in the fetch, slot-steps run
-        and live, KV positions live streams attended and rows read)."""
-        import jax.numpy as jnp
+        """Upload the per-slot vectors (or take what was staged), launch
+        one K-step window executable: one `decode.window` span with
+        `upload` / `dispatch` children, and the window counters (time,
+        slot-steps run and live, KV positions live streams attended and
+        rows read).  Returns (active slots, the [slots, steps] samples
+        on the device)."""
         steps = int(steps)
         call = self._window_exec(kind, steps)
-        act = np.asarray(active, bool).reshape(self.cache.slots)
+        values = self._window_values(fed, active, seeds, temps, topks)
+        act = values[-4].copy()
         with _obs.span('decode.window', cat='decode', kind=kind,
                        steps=steps) as sp:
             with _obs.span('decode.window.upload', cat='decode'):
-                args = [jnp.asarray(self.block_tables)]
-                if fed is not None:
-                    args.append(jnp.asarray(fed.T))
-                args.extend(self._vecs(act, seeds, temps, topks))
+                args = self._uploaded('window', values)
             with _obs.span('decode.window.dispatch', cat='decode'):
                 st, toks = call(self.w, self.state, *args)
                 self.state = st
-            with _obs.span('decode.window.fetch', cat='decode') as fetch:
-                out = np.asarray(toks)
+                toks.copy_to_host_async()
         if _obs.enabled():
             live = int(act.sum())
             counter = _obs.metrics.counter
             counter('generation.window_s').inc(sp.seconds)
-            counter('generation.window_fetch_s').inc(fetch.seconds)
             counter('generation.decode_slot_steps').inc(
                 self.cache.slots * steps)
             counter('generation.decode_live_slot_steps').inc(live * steps)
@@ -999,32 +1132,39 @@ class DecodeRuntime(object):
                     (live if self.state_kernel else self.cache.slots)
                     * steps)
                 counter('generation.state_live_slot_steps').inc(live * steps)
-        return act, out
+        return act, toks
 
     def decode_window(self, steps, active, seeds, temps, topks):
-        """Advance every ACTIVE slot ``steps`` tokens in one fused
-        launch.  active/seeds/temps/topks are per-slot vectors (plain
-        data — they never retrace); so is the block table.  Returns the
-        [slots, steps] token matrix; inactive rows are garbage by
-        contract."""
-        act, out = self._launch_window('decode', steps, None, active, seeds,
-                                       temps, topks)
+        """Launch one fused window that advances every ACTIVE slot
+        ``steps`` tokens.  active/seeds/temps/topks are per-slot vectors
+        (plain data — they never retrace); so is the block table.
+        Returns the [slots, steps] token matrix, still on the device
+        (`_Pending`: reading it waits for the window); inactive rows are
+        garbage by contract.  `host_len` advances here, where the new
+        lengths are already known; `host_tok` when the tokens are read."""
+        act, toks = self._launch_window('decode', steps, None, active, seeds,
+                                        temps, topks)
         self.host_len[act] = np.minimum(
             self.host_len[act] + int(steps), np.iinfo(np.int32).max)
-        self.host_tok[act] = out[act, -1]
-        return out
+
+        def last(out):
+            self.host_tok[act] = out[act, -1]
+
+        return self._pending('window', toks, last)
 
     def verify_window(self, steps, fed, active, seeds, temps, topks):
         """Speculative verify: feed ``fed`` [slots, steps] (host-built
         per-slot rows: last emitted token then draft proposals) through
         the fused window; returns the [slots, steps] TARGET samples
-        g_0..g_{K-1}.  Device lengths advance K for active slots — the
-        caller MUST follow with `commit_speculation` (the host-side
-        rollback) before any other launch."""
+        g_0..g_{K-1}, read at once (the draft needs them).  Device
+        lengths advance K for active slots — the caller MUST follow
+        with `commit_speculation` (the host-side rollback) before any
+        other launch."""
         fed = np.asarray(fed, np.int32).reshape(self.cache.slots,
                                                 int(steps))
-        return self._launch_window('verify', steps, fed, active, seeds,
+        toks = self._launch_window('verify', steps, fed, active, seeds,
                                    temps, topks)[1]
+        return self._pending('window', toks).read()
 
     def commit_speculation(self, accepted):
         """Roll the post-verify state back to the accepted prefix.

@@ -6,11 +6,35 @@ superbatches; generation requests are long-lived instead, so this
 subclass replaces the dispatch loop with a round-based scheduler over
 the DecodeRuntime's KV slots:
 
-  round := sweep (cancel / deadline / TTFT / ITL)
-         → claim queued requests into free slots WITH pages
-         → ONE prefill chunk for the oldest still-prefilling request
-         → ONE fused decode (or speculative verify) window for ALL
-           decoding slots
+  round := while the chip runs window N:
+           claim queued requests into free slots WITH pages
+         → sweep (cancel / deadline / TTFT / ITL)
+         → plan ONE prefill chunk for the oldest still-prefilling
+           request and ONE fused decode window N+1 for ALL the slots
+           decoding then; grow their block tables; upload both
+           launches' arguments (`DecodeRuntime.stage_*`)
+         at the boundary:
+           read N's tokens → ends nobody foresaw (EOS, cancel,
+           deadline) → launch the chunk and window N+1 back to back
+         while they run:
+           emit N's tokens → read the chunk's sample, emit it
+
+A launch does not block (decode.py): the runtime's calls return their
+results still on the device, so the host work of a round lies UNDER a
+running launch and only read → end check → dispatch stands between two.
+The host knows who rides N+1 before N ends: a rider whose
+``produced + K`` reaches ``max_new`` leaves, the stream whose last
+chunk is picked joins (its first token is fed on the device).  Never
+two windows queued: a new arrival would wait behind a committed window,
+one more window on every TTFT; a request that arrives before N lands
+still gets its first chunk directly behind N.  Two boundaries keep the
+serial order — emit and retire, admit, grow or ``kv_oom``, upload,
+launch — because they turn on pages and slots that N's leavers are
+about to give back: a block table that could not grow under N, and a
+queued request with no chunk to run while a rider of N leaves.  A
+speculative window's draft needs the last tokens on the host, so with
+``GenerationConfig.speculative`` every launch is read at once: the same
+round with nothing ever in flight.
 
 Memory admission is PAGED (kv_cache.PagePool): a queued request is
 claimed only when a slot AND the pages for its prompt plus one decode
@@ -54,7 +78,10 @@ every scheduler round is a ``serving.round`` span with ``serving.admit``
 / ``serving.prefill`` / ``serving.decode_step`` / ``serving.emit``
 children; waiting with nothing to do is ``serving.idle_wait``.  Each
 boundary also feeds a ``generation.*`` time or work counter, taken on
-the span's own clock.
+the span's own clock.  ``generation.restaged`` counts the boundaries
+at which an unforeseen end changed the staged window, and
+``generation.overrun_slot_steps`` the slot-steps run for a stream that
+had ended on its first token.
 """
 import time
 
@@ -128,6 +155,63 @@ class _GenRequest(_Request):
         self.windows = 0         # decode windows this stream rode
 
 
+def _alive(r):
+    """A slot-holding request that has no terminal reply yet (`_retire`
+    takes the slot away with the reply)."""
+    return r.slot is not None
+
+
+class _Plan(object):
+    """What one boundary launches: at most one prefill chunk (``chunk``
+    the request, ``tokens`` its slice, ``ring`` a one-shot ring prefill)
+    and one window over ``dec`` with its per-slot vectors.  ``short``:
+    a block table could not grow while another window was running."""
+    __slots__ = ('chunk', 'tokens', 'ring', 'short', 'dec', 'active',
+                 'seeds', 'temps', 'topks')
+
+    def __init__(self, slots):
+        self.chunk = self.tokens = None
+        self.ring = self.short = False
+        self.dec = []
+        self.active = np.zeros(slots, bool)
+        self.seeds = np.zeros(slots, np.int32)
+        self.temps = np.zeros(slots, np.float32)
+        self.topks = np.zeros(slots, np.int32)
+
+    @property
+    def vectors(self):
+        return self.active, self.seeds, self.temps, self.topks
+
+    def join(self, r):
+        self.dec.append(r)
+        self.active[r.slot] = True
+        self.seeds[r.slot] = r.params.seed
+        self.temps[r.slot] = r.params.temperature
+        self.topks[r.slot] = r.params.top_k
+
+    def keep(self, alive):
+        """Drop the streams ``alive`` refuses; True when one went.  Only
+        ``active`` changes (a NEW array: the old one may be staged)."""
+        dec = [r for r in self.dec if alive(r)]
+        if len(dec) == len(self.dec):
+            return False
+        self.dec = dec
+        self.active = np.zeros_like(self.active)
+        for r in dec:
+            self.active[r.slot] = True
+        return True
+
+
+class _Flight(object):
+    """A launched window: the streams riding it and its tokens (on the
+    device until read; a speculative window's, {slot: accepted}), or
+    the ``error`` its launch raised."""
+    __slots__ = ('dec', 'toks', 'error')
+
+    def __init__(self, dec, toks, error=None):
+        self.dec, self.toks, self.error = dec, toks, error
+
+
 class GenerationEngine(ServingEngine):
     """Streaming decode server over one :class:`DecodeRuntime`.
 
@@ -155,6 +239,10 @@ class GenerationEngine(ServingEngine):
                 'state of this runtime\'s model cannot be rolled back')
         self._active = []        # slot-holding requests, admission order
         self._round_no = 0       # rounds with work so far
+        self._flight = None      # the window the chip is running, if any
+        # a speculative window's draft needs the last tokens on the host
+        self._speculative = (self._gen.speculative
+                             and self._gen.decode_window > 1)
 
     @staticmethod
     def _no_backend(feed):
@@ -296,16 +384,80 @@ class GenerationEngine(ServingEngine):
         self._round_no += 1
         work = _obs.span('serving.round', cat='serving')
         with work:
-            with _obs.span('serving.admit', cat='serving'):
-                if not self._admit_round():
-                    return False
-                self._sweep_active()
-            did_prefill = self._prefill_step()
-            did_decode = self._decode_step()
+            alive = self._work()
         if _obs.enabled():
             _obs.metrics.counter('generation.round_s').inc(work.seconds)
-        if did_prefill and did_decode:
-            _obs.metrics.counter('generation.mixed_dispatches').inc()
+        return alive
+
+    def _work(self):
+        """One round with work (the order is the module docstring's);
+        False when the engine is stopping."""
+        flight, self._flight = self._flight, None
+        # under the running window: all that the next launches need
+        if not self._admit_and_sweep():
+            return False
+        plan = self._plan(flight)
+        if flight is not None and not plan.short:
+            self._stage(plan)
+        # the boundary: from here to the dispatch the chip has nothing
+        toks = None
+        if flight is not None:
+            toks = self._land(flight)
+            ended = self._eos_rows(flight, toks)
+            self._sweep_active()
+            if plan.keep(lambda r: _alive(r) and id(r) not in ended):
+                _obs.metrics.counter('generation.restaged').inc()
+            if plan.chunk is not None and not _alive(plan.chunk):
+                plan.chunk = None
+            waiting = plan.chunk is None and bool(self._queue)
+            if plan.short or (waiting and self._leavers(flight, plan)):
+                # a table that could not grow, or a queued request and
+                # nothing to run for it, while streams of the landed
+                # window are about to give slots and pages back: this
+                # boundary keeps the serial order (emit and retire,
+                # admit, grow or `kv_oom`, upload, launch)
+                self._emit_window(flight, toks)
+                flight = None
+                self._admit_and_sweep()
+                plan = self._plan(None)
+            elif waiting:
+                # it arrived after the staging: its first chunk goes
+                # directly behind the landed window all the same
+                self._admit_and_sweep()
+                if self._pick_chunk(plan):
+                    plan.join(plan.chunk)
+        first = self._launch_chunk(plan)
+        if self._speculative:
+            # the draft starts from the last tokens, on the host: the
+            # chunk is read before its window goes, and the window (read
+            # inside its launch) lands in the round that launched it
+            self._finish_chunk(plan, first, None)
+            plan.keep(_alive)
+            flight = flown = self._launch_window(plan)
+            toks = flown.toks if flown is not None else None
+        else:
+            flown = self._launch_window(plan)
+        # under the launched window: what the landed one and the chunk
+        # gave; a launch that failed is replied to after them, as in the
+        # serial order (partial output stays readable)
+        if flight is not None:
+            self._emit_window(flight, toks)
+        if not self._speculative:
+            self._finish_chunk(plan, first, flown)
+        if flown is not None and flown.error is not None:
+            self._fail_window(flown.error, flown.dec)
+        elif flown is not None:
+            if first is not None:
+                _obs.metrics.counter('generation.mixed_dispatches').inc()
+            if not self._speculative:
+                self._flight = flown
+        return True
+
+    def _admit_and_sweep(self):
+        with _obs.span('serving.admit', cat='serving'):
+            if not self._admit_round():
+                return False
+            self._sweep_active()
         return True
 
     def _admit_round(self):
@@ -386,158 +538,255 @@ class GenerationEngine(ServingEngine):
                              error='inter-token gap exceeded the ITL '
                                    'budget (%gs)' % r.itl_timeout)
 
-    def _prefill_step(self):
-        """Advance the OLDEST still-prefilling request by one chunk (or
-        one ring shot).  Bounded work per round: long prompts cannot
-        starve the decode batch."""
+    # ------------------------------------------------------- planning
+    def _pick_chunk(self, plan):
+        """Put the OLDEST still-prefilling request's next chunk (or its
+        one ring shot) into ``plan``: bounded work per round, so long
+        prompts cannot starve the decode batch.  True when that chunk
+        completes a prompt whose stream then rides the window behind
+        it: its first token is fed on the device, so one that is EOS is
+        found a window late (``generation.overrun_slot_steps``)."""
         rt = self.runtime
         pre = [r for r in self._active if r.offset < r.prompt.size]
         if not pre:
             return False
-        r = min(pre, key=lambda x: x.t_submit)
-        use_ring = (rt.mesh is not None and r.offset == 0
-                    and r.prompt.size >= rt.ring_min_len)
-        with _obs.span('serving.prefill', cat='serving') as sp:
-            if _obs.enabled():
-                sp.args.update(slot=int(r.slot), ring=bool(use_ring))
-                if r.trace is not None:
-                    sp.args.update(trace_id=r.trace.trace_id,
-                                   parent_span_id=r.trace.span_id)
-            try:
-                if use_ring:
-                    first, _logits = rt.prefill_ring(r.slot, r.prompt,
-                                                     r.params)
-                    r.offset = int(r.prompt.size)
-                else:
-                    chunk = r.prompt[r.offset:r.offset + rt.prefill_chunk]
-                    first, _logits = rt.prefill(r.slot, chunk, r.offset,
-                                                r.params)
-                    r.offset += int(chunk.size)
-            except BaseException as e:  # noqa: BLE001 - replied per request
-                self.breaker.record_failure()
-                _obs.metrics.counter('serving.batch_failures').inc()
-                _flight.record('serving.prefill_failure',
-                               error=repr(e)[:300])
-                self._retire(r, ERROR, error=e, reason='prefill')
-                _flight.maybe_dump('serving_prefill_failure')
-                return True
-            if _obs.enabled():
-                sp.args['offset'] = int(r.offset)
-        r.chunks += 1
-        _obs.metrics.counter('generation.prefill_chunks').inc()
-        if r.offset >= r.prompt.size:
-            # prompt complete: publish its full pages for later
-            # prefix-sharing requests, then emit the final chunk's
-            # sample — the first token (TTFT)
-            self.runtime.promote_prefix(r.slot, r.prompt)
-            with _obs.span('serving.emit', cat='serving'):
-                self._emit_tokens(r, [int(first)])
-        return True
+        r = plan.chunk = min(pre, key=lambda x: x.t_submit)
+        plan.ring = (rt.mesh is not None and r.offset == 0
+                     and r.prompt.size >= rt.ring_min_len)
+        plan.tokens = (r.prompt if plan.ring else
+                       r.prompt[r.offset:r.offset + rt.prefill_chunk])
+        return (r.offset + plan.tokens.size >= r.prompt.size
+                and r.max_new > 1)
 
-    def _decode_step(self):
-        """One fused K-token window (plain decode or speculative
-        verify) over every decoding slot."""
-        rt = self.runtime
-        dec = [r for r in self._active if r.offset >= r.prompt.size]
-        if not dec:
-            return False
-        S, K = rt.slots, self._gen.decode_window
-        # grow every stream's block table to cover this window FIRST: a
-        # stream the pool cannot grow gets a terminal kv_oom reply (it
-        # is never truncated and never silently stalled) and its freed
-        # pages may rescue the streams after it
-        for r in list(dec):
-            if rt.ensure_capacity(r.slot, int(rt.host_len[r.slot]) + K):
+    def _plan(self, flight):
+        """What the next boundary launches, from what the host knows
+        while ``flight`` (a window, or None) still runs: one chunk, and
+        one window over every stream that will be decoding then — the
+        lengths are known (`host_len` moved at the launch), and a rider
+        of ``flight`` whose ``produced + K`` reaches ``max_new`` is
+        known to leave.  Every block table is grown to cover that
+        window FIRST.  With nothing in flight a stream the pool cannot
+        grow gets a terminal kv_oom reply (it is never truncated and
+        never silently stalled) and its freed pages may rescue the
+        streams after it; under a running window the verdict waits for
+        the boundary (``plan.short``), where the leavers' pages are
+        back."""
+        rt, K = self.runtime, self._gen.decode_window
+        plan = _Plan(rt.slots)
+        joins = self._pick_chunk(plan)
+        riding = set(map(id, flight.dec)) if flight is not None else ()
+        for r in self._active:
+            if joins if r is plan.chunk else (
+                    r.offset >= r.prompt.size
+                    and r.produced + (K if id(r) in riding else 0)
+                    < r.max_new):
+                plan.join(r)
+        for r in list(plan.dec):
+            start = (r.prompt.size if r is plan.chunk
+                     else int(rt.host_len[r.slot]))
+            if rt.ensure_capacity(r.slot, start + K):
                 continue
+            if flight is not None:
+                plan.short = True
+                break
             _obs.metrics.counter('generation.kv_oom').inc()
             snap = rt.pool_snapshot()
             _flight.record('serving.kv_oom', slot=int(r.slot),
                            produced=int(r.produced), **snap)
-            dec.remove(r)
             self._retire(
                 r, ERROR, reason='kv_oom',
                 error='KV page pool exhausted mid-stream (%d/%d pages '
                       'live); partial output is in tokens_so_far()'
                       % (snap['pages_in_use'], snap['pages_capacity']))
             _flight.maybe_dump('kv_oom', extra={'kv_pool': snap})
-        if not dec:
-            return False
-        active = np.zeros(S, bool)
-        seeds = np.zeros(S, np.int32)
-        temps = np.zeros(S, np.float32)
-        topks = np.zeros(S, np.int32)
-        for r in dec:
-            active[r.slot] = True
-            seeds[r.slot] = r.params.seed
-            temps[r.slot] = r.params.temperature
-            topks[r.slot] = r.params.top_k
-        speculative = self._gen.speculative and K > 1
+        plan.keep(_alive)
+        return plan
+
+    def _stage(self, plan):
+        """Upload the next launches' arguments while the chip is busy;
+        the launches find them by value (`DecodeRuntime.stage_*`)."""
+        rt, r = self.runtime, plan.chunk
+        try:
+            if r is not None and not plan.ring:
+                rt.stage_prefill(r.slot, plan.tokens, r.offset, r.params)
+            if plan.dec:
+                rt.stage_window(*plan.vectors)
+        except Exception:  # noqa: BLE001 - the launch raises it again,
+            pass           # and there it is replied to
+
+    # ------------------------------------------------------- launching
+    def _launch_chunk(self, plan):
+        """Launch ``plan.chunk``; returns its sample, still on the
+        device, or None (no chunk, or a fault: the request has its
+        ERROR reply)."""
+        r, rt = plan.chunk, self.runtime
+        if r is None:
+            return None
+        with _obs.span('serving.prefill', cat='serving') as sp:
+            if _obs.enabled():
+                sp.args.update(slot=int(r.slot), ring=bool(plan.ring))
+                if r.trace is not None:
+                    sp.args.update(trace_id=r.trace.trace_id,
+                                   parent_span_id=r.trace.span_id)
+            try:
+                if plan.ring:
+                    first, _logits = rt.prefill_ring(r.slot, r.prompt,
+                                                     r.params)
+                else:
+                    first, _logits = rt.prefill(r.slot, plan.tokens,
+                                                r.offset, r.params)
+            except BaseException as e:  # noqa: BLE001 - replied per request
+                self._fail_chunk(r, e)
+                plan.chunk = None
+                plan.keep(_alive)
+                return None
+            r.offset += int(plan.tokens.size)
+            if _obs.enabled():
+                sp.args['offset'] = int(r.offset)
+        r.chunks += 1
+        _obs.metrics.counter('generation.prefill_chunks').inc()
+        return first
+
+    def _fail_chunk(self, r, e):
+        self.breaker.record_failure()
+        _obs.metrics.counter('serving.batch_failures').inc()
+        _flight.record('serving.prefill_failure', error=repr(e)[:300])
+        self._retire(r, ERROR, error=e, reason='prefill')
+        _flight.maybe_dump('serving_prefill_failure')
+
+    def _finish_chunk(self, plan, first, flown):
+        """Read the launched chunk's sample if it completed the prompt
+        (the wait is for the chunk alone, whatever is queued behind it):
+        publish the prompt's full pages for later prefix-sharing
+        requests, then emit the first token (TTFT).  ``flown`` is the
+        window launched behind the chunk, if any."""
+        r = plan.chunk
+        if first is None or r.offset < r.prompt.size:
+            return
+        try:
+            tok = int(first)
+        except BaseException as e:  # noqa: BLE001 - replied per request
+            self._fail_chunk(r, e)
+        else:
+            self.runtime.promote_prefix(r.slot, r.prompt)
+            with _obs.span('serving.emit', cat='serving'):
+                self._emit_tokens(r, [tok])
+        if not _alive(r) and flown is not None and flown.error is None \
+                and r in flown.dec:
+            # it ended on its first token with the window already gone
+            _obs.metrics.counter('generation.overrun_slot_steps').inc(
+                self._gen.decode_window)
+
+    def _launch_window(self, plan):
+        """Launch one fused K-token window (plain decode or speculative
+        verify) over ``plan.dec``; returns it as a `_Flight` (holding
+        the fault, had the launch one), or None: nobody decodes."""
+        if not plan.dec:
+            return None
+        rt, K = self.runtime, self._gen.decode_window
         with _obs.span('serving.decode_step', cat='serving') as sp:
             if _obs.enabled():
                 sp.args.update(
-                    steps=int(K), requests=len(dec),
-                    speculative=bool(speculative),
-                    links=[r.trace.trace_id for r in dec
+                    steps=int(K), requests=len(plan.dec),
+                    speculative=self._speculative,
+                    links=[r.trace.trace_id for r in plan.dec
                            if r.trace is not None])
             try:
                 if _faults.any_active():
                     _faults.maybe_fail('decode_step')
-                if speculative:
-                    emitted = self._verify_step(dec, K, active, seeds,
-                                                temps, topks)
+                if self._speculative:
+                    toks = self._verify_step(plan, K)
                 else:
-                    toks = rt.decode_window(K, active, seeds, temps, topks)
-                    emitted = {id(r): [int(t) for t in toks[r.slot]]
-                               for r in dec}
+                    toks = rt.decode_window(K, *plan.vectors)
             except BaseException as e:  # noqa: BLE001 - replied per request
-                self.breaker.record_failure()
-                _obs.metrics.counter('serving.batch_failures').inc()
-                _flight.record('serving.decode_failure',
-                               error=repr(e)[:300], requests=len(dec),
-                               steps=int(K))
-                for r in dec:
-                    self._retire(r, ERROR, error=e, reason='decode_step')
-                _flight.maybe_dump('serving_decode_failure')
-                return False
-        self.breaker.record_success(cold=False)
+                return _Flight(plan.dec, None, e)
         _obs.metrics.counter('generation.decode_windows').inc()
-        with _obs.span('serving.emit', cat='serving'):
-            for r in list(dec):
-                r.windows += 1
-                self._emit_tokens(r, emitted[id(r)])
-        return True
+        return _Flight(plan.dec, toks)
 
-    def _verify_step(self, dec, K, active, seeds, temps, topks):
+    def _fail_window(self, e, dec):
+        self.breaker.record_failure()
+        _obs.metrics.counter('serving.batch_failures').inc()
+        _flight.record('serving.decode_failure', error=repr(e)[:300],
+                       requests=len(dec),
+                       steps=int(self._gen.decode_window))
+        for r in dec:
+            if _alive(r):
+                self._retire(r, ERROR, error=e, reason='decode_step')
+        _flight.maybe_dump('serving_decode_failure')
+
+    def _verify_step(self, plan, K):
         """One speculative window: build each stream's fed row (last
         emitted token + n-gram draft), run the fused verify, keep the
         longest accepted prefix per stream, and roll the runtime back
-        to the committed lengths.  Returns {id(request): tokens}."""
+        to the committed lengths.  Returns {slot: tokens}."""
         from .sampling import draft_ngram
         rt = self.runtime
         S = rt.slots
         fed = np.zeros((S, K), np.int32)
-        for r in dec:
+        for r in plan.dec:
             fed[r.slot, 0] = rt.host_tok[r.slot]
             ctx = np.concatenate([
                 r.prompt, np.asarray(r.future.tokens_so_far(), np.int32)])
             fed[r.slot, 1:] = draft_ngram(ctx, K - 1)
-        g = rt.verify_window(K, fed, active, seeds, temps, topks)
+        g = rt.verify_window(K, fed, *plan.vectors)
         emitted, accepted, kept = {}, {}, 0
-        for r in dec:
+        for r in plan.dec:
             row = g[r.slot]
             m = 1
             while m < K and fed[r.slot, m] == row[m - 1]:
                 m += 1
             accepted[r.slot] = (m, int(row[m - 1]))
-            emitted[id(r)] = [int(t) for t in row[:m]]
+            emitted[r.slot] = row[:m]
             kept += m - 1
         _obs.metrics.counter('generation.spec_proposed').inc(
-            (K - 1) * len(dec))
+            (K - 1) * len(plan.dec))
         _obs.metrics.counter('generation.spec_accepted').inc(kept)
         # commit BEFORE emitting: finishing streams retire (and free
         # their pages) with the runtime already consistent
         rt.commit_speculation(accepted)
         return emitted
+
+    # -------------------------------------------------------- landing
+    def _land(self, flight):
+        """The landed window's [slots, K] tokens: the one wait for the
+        chip in a round.  None when nobody is left to read them for, or
+        on a fault (every rider has its ERROR reply)."""
+        if not any(map(_alive, flight.dec)):
+            return None
+        try:
+            return np.asarray(flight.toks)
+        except BaseException as e:  # noqa: BLE001 - replied per request
+            self._fail_window(e, flight.dec)
+            return None
+
+    def _eos_rows(self, flight, toks):
+        """ids of the riders whose landed window holds EOS: the one end
+        the host could not foresee from the counts."""
+        eos = self._gen.eos_id
+        riders = list(filter(_alive, flight.dec))
+        if eos is None or toks is None or not riders:
+            return ()
+        hit = (toks[[r.slot for r in riders]] == eos).any(axis=1)
+        return {id(r) for r, h in zip(riders, hit) if h}
+
+    @staticmethod
+    def _leavers(flight, plan):
+        """Whether a rider of the landed window is about to retire."""
+        staying = set(map(id, plan.dec))
+        return any(_alive(r) and id(r) not in staying
+                   for r in flight.dec)
+
+    def _emit_window(self, flight, toks):
+        """Stream a landed window's tokens to its riders; one that was
+        retired while the window ran (a cancel, a deadline) gets none."""
+        if toks is None:
+            return
+        self.breaker.record_success(cold=False)
+        with _obs.span('serving.emit', cat='serving'):
+            for r in flight.dec:
+                if _alive(r):
+                    r.windows += 1
+                    self._emit_tokens(r, [int(t) for t in toks[r.slot]])
 
     # ----------------------------------------------------- token path
     def _emit_tokens(self, r, toks):

@@ -297,8 +297,12 @@ def test_midstream_kv_oom_terminal_error_with_flight_dump(tmp_path,
     try:
         oom0 = _cnt('generation.kv_oom')
         # alloc #1 claims the admission span; alloc #2 is the
-        # mid-stream growth before the second window — inject there
-        faults.configure('kv_oom:at=2:times=1')
+        # mid-stream growth for the second window, tried while the first
+        # still runs, and alloc #3 the same growth tried again at the
+        # boundary, where the verdict falls (a stream leaving with the
+        # first window would have given its pages back by then) —
+        # inject at both
+        faults.configure('kv_oom:at=2:times=2')
         s = eng.generate([2, 7], max_new=8, timeout_s=60.0)
         res = s.result(60)
         assert res.status == 'error' and res.reason == 'kv_oom'

@@ -238,11 +238,17 @@ def test_profiler_trace_holds_the_program_spans_nested(tmp_path):
     sched = {t for _, _, t in by_name['serving.round']}
     assert len(sched) == 1
     assert sched.isdisjoint(t for _, _, t in by_name['executor.dispatch'])
+    # a launch is its upload and dispatch; its fetch is whoever reads the
+    # result, a round later for a window (and the upload of a launch that
+    # was staged under the running window sits outside it, in the round)
     for child, parent in [('decode.window.dispatch', 'decode.window'),
-                          ('decode.window.fetch', 'decode.window'),
+                          ('decode.window.fetch', 'serving.round'),
+                          ('decode.window.upload', 'serving.round'),
+                          ('decode.prefill.fetch', 'serving.round'),
                           ('decode.window', 'serving.decode_step'),
                           ('serving.decode_step', 'serving.round'),
-                          ('decode.prefill.upload', 'decode.prefill'),
+                          ('decode.prefill.dispatch', 'decode.prefill'),
+                          ('decode.prefill.upload', 'serving.round'),
                           ('decode.prefill', 'serving.prefill'),
                           ('serving.prefill', 'serving.round'),
                           ('serving.emit', 'serving.round')]:
