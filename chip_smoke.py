@@ -88,8 +88,7 @@ SIZES = {
     },
 }
 
-_FALLBACK_COUNTERS = ('kernel.fallbacks', 'kernelgen.fallbacks',
-                      'emitter.fallbacks')
+_FALLBACK_COUNTERS = ('emitter.fallbacks',)
 _COMPILE_SECONDS = ('executor.emit_s', 'executor.trace_s',
                     'executor.backend_compile_s')
 

@@ -13,7 +13,10 @@ Use `import paddle_tpu as fluid` for fluid-style code, or
 `import paddle_tpu.paddle_compat as paddle` for `paddle.*` dataset/batch
 helpers.
 """
-from .core import framework
+import time as _time
+_IMPORT_T0 = _time.perf_counter()
+
+from .core import framework  # noqa: E402
 from .core.framework import (  # noqa
     Program, Block, Operator, Variable, Parameter, program_guard,
     default_main_program, default_startup_program, switch_main_program,
@@ -97,3 +100,20 @@ def memory_optimize_hint(*a, **k):
 
 
 __version__ = '0.1.0'
+
+
+def _record_import(t0):
+    """Set-up from inside (docs/observability.md): what this import took,
+    and what the process had already spent when it began (the
+    interpreter, the caller's imports, `import jax`, a TPU runtime coming
+    up)."""
+    from . import observability as obs
+    t1 = _time.perf_counter()
+    obs.counter('process.import_s').inc(t1 - t0)
+    obs.add_span('process.import', t0, t1, cat='setup')
+    age = obs.metrics.process_age_s()
+    if age is not None:
+        obs.gauge('process.before_import_s').set(max(0.0, age - (t1 - t0)))
+
+
+_record_import(_IMPORT_T0)

@@ -1149,11 +1149,8 @@ class Executor(object):
     def _resolve_entry(self, program, feed_vals, feed_names, fetch_names,
                        scope, steps, base_key, counter, use_cache, obs_on):
         """Two-tier executable resolution (see core/compile_cache.py):
-        L1 in-process LRU by hot key; on miss, the canonical fingerprint
-        is tried against the disk store (a hit skips trace AND compile);
-        on a disk miss the program is traced and AOT-compiled eagerly
-        (`jit(fn).lower(...).compile()`) and the executable serialized
-        back to disk for the next process."""
+        L1 in-process LRU by hot key; on miss, the cold path
+        (`_prepare_entry`), which is the phase ``executor.prepare``."""
         hot_key = (self._hot_key(program, feed_vals, fetch_names, steps)
                    if use_cache else None)
         if use_cache:
@@ -1162,25 +1159,49 @@ class Executor(object):
                 return entry, self._gather_params(
                     program, entry.params_in, scope, base_key,
                     targets=entry.shard_targets)
+        with _obs.span('executor.prepare', cat='compile',
+                       counter='executor.prepare_s') as prep:
+            if obs_on:
+                prep.args.update(self._obs_tags, steps=steps)
+            return self._prepare_entry(
+                program, feed_vals, feed_names, fetch_names, scope, steps,
+                base_key, counter, hot_key, obs_on, prep)
+
+    def _prepare_entry(self, program, feed_vals, feed_names, fetch_names,
+                       scope, steps, base_key, counter, hot_key, obs_on,
+                       prep):
+        """The cold path, from the hot-key miss to `_cache.put`: the
+        canonical fingerprint is tried against the disk store (a hit
+        skips trace AND compile); on a disk miss the program is traced
+        and AOT-compiled eagerly (`jit(fn).lower(...).compile()`) and the
+        executable serialized back to disk for the next process.  Every
+        step is a child span of ``executor.prepare`` with its own seconds
+        counter (docs/observability.md, "Set-up from inside"); a warm
+        start runs all of them but trace/compile/store.  ``hot_key`` is
+        None on the cache-bypass path."""
+        use_cache = hot_key is not None
+        tags = dict(self._obs_tags, steps=steps) if obs_on else {}
         # PT_LINT gate on the RAW program, BEFORE the rewriter: a user's
         # def-use/shape bug must be named here, not DCE'd out of sight
-        from ..analysis import apply_lint_policy, lint_mode
-        apply_lint_policy(program, feed_names=feed_names,
-                          fetch_names=fetch_names, mode=lint_mode(),
-                          header='program lint failed before lowering')
+        with _obs.span('executor.lint', cat='compile',
+                       counter='executor.lint_s'):
+            from ..analysis import apply_lint_policy, lint_mode
+            apply_lint_policy(program, feed_names=feed_names,
+                              fetch_names=fetch_names, mode=lint_mode(),
+                              header='program lint failed before lowering')
         # Program->Program rewriter (core/passes): the tracer sees the
         # optimized twin; every cache key/RNG stream stays keyed on the
         # RAW program (PT_OPT toggling is part of the hot key + launch
         # signature via config_token, so it reads as a named change)
-        t_o0 = time.perf_counter() if obs_on else None
-        opt_program, opt_stats = _passes.maybe_optimize(program, fetch_names)
-        if obs_on and opt_stats is not None:
-            _obs.tracing.add_span(
-                'executor.optimize', t_o0, time.perf_counter(),
-                cat='compile',
-                args=dict(self._obs_tags,
-                          raw=opt_stats['op_count_raw'],
-                          opt=opt_stats['op_count_opt']) or None)
+        with _obs.span('executor.optimize', cat='compile',
+                       counter='executor.optimize_s') as sp:
+            opt_program, opt_stats = _passes.maybe_optimize(program,
+                                                            fetch_names)
+            if obs_on and opt_stats is not None:
+                sp.args.update(self._obs_tags,
+                               raw=opt_stats['op_count_raw'],
+                               opt=opt_stats['op_count_opt'],
+                               pass_ms=opt_stats['pass_ms'])
         # Direct Program->jaxpr emitter (core/emit): built on the
         # optimized twin so emission sees the fused/rng_stream-stamped
         # shape.  A static coverage gap falls back PER PROGRAM to the
@@ -1189,37 +1210,40 @@ class Executor(object):
         # (use_cache=False) keeps seed semantics and never emits.
         engine, emit_verdict = None, 'trace'
         if use_cache and _emit.enabled():
-            try:
-                engine = _emit.build_engine(opt_program, feed_names,
-                                            fetch_names)
-                emit_verdict = 'emit'
-            except _emit.EmitFallback as e:
-                if _emit.strict():
-                    raise
-                _emit.note_fallback(e.op, e.why)
-                emit_verdict = 'emit_fallback:%s' % e.op
-        t_l0 = time.perf_counter() if obs_on else None
-        jit_fn, params_in, writeback = _lower(
-            opt_program, feed_names, fetch_names, donate=True,
-            mesh=self.mesh, check_nan=self.check_nan, steps=steps,
-            emit_engine=engine)
-        if obs_on:
-            _obs.metrics.counter('executor.lowerings').inc()
-            _obs.tracing.add_span(
-                'executor.lower', t_l0, time.perf_counter(), cat='compile',
-                args=dict(self._obs_tags, steps=steps) or None)
-        shard_targets = self._shard_targets_for(opt_program, params_in)
-        params = self._gather_params(program, params_in, scope, base_key,
-                                     targets=shard_targets)
+            with _obs.span('executor.emit_build', cat='compile',
+                           counter='executor.emit_build_s'):
+                try:
+                    engine = _emit.build_engine(opt_program, feed_names,
+                                                fetch_names)
+                    emit_verdict = 'emit'
+                except _emit.EmitFallback as e:
+                    if _emit.strict():
+                        raise
+                    _emit.note_fallback(e.op, e.why)
+                    emit_verdict = 'emit_fallback:%s' % e.op
+        with _obs.span('executor.lower', cat='compile',
+                       counter='executor.lower_s', **tags):
+            jit_fn, params_in, writeback = _lower(
+                opt_program, feed_names, fetch_names, donate=True,
+                mesh=self.mesh, check_nan=self.check_nan, steps=steps,
+                emit_engine=engine)
+            if obs_on:
+                _obs.metrics.counter('executor.lowerings').inc()
+        # with a mesh this is where ParallelExecutor's shards are placed
+        with _obs.span('executor.gather_params', cat='compile',
+                       counter='executor.gather_params_s'):
+            shard_targets = self._shard_targets_for(opt_program, params_in)
+            params = self._gather_params(program, params_in, scope,
+                                         base_key, targets=shard_targets)
         if not use_cache:
             # cache bypass keeps the seed semantics: a lazily-retracing
             # jit call per run, observed by the explainer at call time
+            if obs_on:
+                prep.args['verdict'] = 'uncached'
             return (_ExecEntry(jit_fn, jit_fn, params_in, writeback,
                                program, None, shard_targets), params)
 
-        call, fp, disk_tier = None, None, None
-        if _cc.disk_enabled():
-            _cc.ensure_xla_cache_backstop()
+        def fingerprint(engine):
             # fingerprint the OPTIMIZED desc: it is what actually lowers,
             # and it folds the PT_OPT config in for free (PT_OPT=0 hashes
             # the raw desc, a skipped pass changes the rewrite output)
@@ -1229,7 +1253,7 @@ class Executor(object):
             # kernelgen (when on) composes its version + rule coverage
             # into the extra on BOTH modes — generated kernels change
             # what lowers on the traced path too
-            fp = _cc.launch_fingerprint(
+            return _cc.launch_fingerprint(
                 opt_program,
                 {n: _feed_spec(feed_vals[n]) for n in feed_names},
                 fetch_names, steps, self.check_nan, mesh=self.mesh,
@@ -1237,98 +1261,98 @@ class Executor(object):
                 extra=_compose_fp_extra(
                     engine.fingerprint_extra() if engine is not None
                     else None))
-            t_a0 = time.perf_counter()
-            call, disk_tier = _cc.disk_cache().load(fp)
+
+        call, fp, disk_tier = None, None, None
+        if _cc.disk_enabled():
+            with _obs.span('compile_cache.fingerprint', cat='compile',
+                           counter='compile_cache.fingerprint_s'):
+                _cc.ensure_xla_cache_backstop()
+                fp = fingerprint(engine)
+            with _obs.span('executor.aot_load', cat='compile',
+                           **tags) as load:
+                call, disk_tier = _cc.disk_cache().load(fp)
             if obs_on:
-                t_a1 = time.perf_counter()
+                load.args['hit'] = call is not None
                 if call is not None:
                     _obs.metrics.counter('compile_cache.disk_hits').inc()
                     _obs.metrics.counter('compile_cache.load_s').inc(
-                        t_a1 - t_a0)
-                    _obs.tracing.add_span(
-                        'executor.aot_load', t_a0, t_a1, cat='compile',
-                        args=dict(self._obs_tags, steps=steps) or None)
+                        load.seconds)
                     sig = _launch_signature(program, feed_vals, feed_names,
                                             fetch_names, steps,
                                             self.check_nan, scope)
                     _obs.explainer().observe_disk_load(
-                        sig, load_s=t_a1 - t_a0)
+                        sig, load_s=load.seconds)
                 else:
                     _obs.metrics.counter('compile_cache.disk_misses').inc()
         if call is None:
-            tc0 = _TRACE_COUNT[0]
-            args = (params, {n: feed_vals[n] for n in feed_names},
-                    np.uint32(counter & 0xffffffff))
-            t_c0 = time.perf_counter()
-            try:
-                traced = jit_fn.trace(*args)
-            except _emit.EmitError as e:
-                # runtime emission gap (e.g. an op outside the known RNG
-                # set drew ctx.rng): rebuild this program on the traced
-                # path.  The fingerprint is recomputed with extra=None so
-                # the stored artifact is the shared traced one.
-                if engine is None or _emit.strict():
-                    raise
-                _emit.note_fallback(e.op, e.why)
-                emit_verdict = 'emit_fallback:%s' % e.op
-                engine = None
-                jit_fn, params_in, writeback = _lower(
-                    opt_program, feed_names, fetch_names, donate=True,
-                    mesh=self.mesh, check_nan=self.check_nan,
-                    steps=steps)
-                if fp is not None:
-                    fp = _cc.launch_fingerprint(
-                        opt_program,
-                        {n: _feed_spec(feed_vals[n]) for n in feed_names},
-                        fetch_names, steps, self.check_nan,
-                        mesh=self.mesh,
-                        param_specs={n: _feed_spec(v)
-                                     for n, v in params.items()},
-                        extra=_compose_fp_extra(None))
-                traced = jit_fn.trace(*args)
-            t_cmid = time.perf_counter()
-            lowered = traced.lower()
-            call = lowered.compile()
-            t_c1 = time.perf_counter()
-            # emit_s: wall time inside the emitter (memo build +
-            # dispatch); trace_s: the residual jaxpr-staging time.  With
-            # the staged AOT API the StableHLO lowering now lands in
-            # backend_compile_s for BOTH modes (accounting change vs
-            # PR-5, documented in PERF.md).
-            emit_s = engine.take_build_seconds() if engine is not None \
-                else 0.0
-            if obs_on:
-                _obs.metrics.counter('executor.emit_s').inc(emit_s)
-                _obs.metrics.counter('executor.trace_s').inc(
-                    max(0.0, (t_cmid - t_c0) - emit_s))
-                _obs.metrics.counter('executor.backend_compile_s').inc(
-                    t_c1 - t_cmid)
-            if obs_on and _TRACE_COUNT[0] > tc0:
-                sig = _launch_signature(program, feed_vals, feed_names,
-                                        fetch_names, steps, self.check_nan,
-                                        scope)
-                cache_status = ('disabled' if fp is None else
-                                'stablehlo_hit' if disk_tier == 'stablehlo'
-                                else 'miss')
-                report = _obs.explainer().observe(
-                    sig, compile_s=t_c1 - t_c0, cache=cache_status,
-                    lowering=emit_verdict)
-                _obs.tracing.add_span(
-                    'executor.trace_compile', t_c0, t_c1, cat='compile',
-                    args=dict(self._obs_tags, steps=steps,
-                              kind=report['kind'],
-                              lowering=emit_verdict,
-                              cause='; '.join(report['details'])[:512]
-                              or None))
+            with _obs.span('executor.trace_compile', cat='compile',
+                           **tags) as sp:
+                tc0 = _TRACE_COUNT[0]
+                args = (params, {n: feed_vals[n] for n in feed_names},
+                        np.uint32(counter & 0xffffffff))
+                t_c0 = time.perf_counter() if obs_on else None
+                try:
+                    traced = jit_fn.trace(*args)
+                except _emit.EmitError as e:
+                    # runtime emission gap (e.g. an op outside the known
+                    # RNG set drew ctx.rng): rebuild this program on the
+                    # traced path.  The fingerprint is recomputed with
+                    # extra=None so the stored artifact is the shared
+                    # traced one.
+                    if engine is None or _emit.strict():
+                        raise
+                    _emit.note_fallback(e.op, e.why)
+                    emit_verdict = 'emit_fallback:%s' % e.op
+                    engine = None
+                    jit_fn, params_in, writeback = _lower(
+                        opt_program, feed_names, fetch_names, donate=True,
+                        mesh=self.mesh, check_nan=self.check_nan,
+                        steps=steps)
+                    if fp is not None:
+                        fp = fingerprint(None)
+                    traced = jit_fn.trace(*args)
+                t_cmid = time.perf_counter() if obs_on else None
+                lowered = traced.lower()
+                call = lowered.compile()
+                t_c1 = time.perf_counter() if obs_on else None
+                # emit_s: wall time inside the emitter (memo build +
+                # dispatch); trace_s: the residual jaxpr-staging time.
+                # With the staged AOT API the StableHLO lowering now
+                # lands in backend_compile_s for BOTH modes (accounting
+                # change vs PR-5, documented in PERF.md).
+                emit_s = engine.take_build_seconds() \
+                    if engine is not None else 0.0
+                if obs_on:
+                    sp.args['lowering'] = emit_verdict
+                    _obs.metrics.counter('executor.emit_s').inc(emit_s)
+                    _obs.metrics.counter('executor.trace_s').inc(
+                        max(0.0, (t_cmid - t_c0) - emit_s))
+                    _obs.metrics.counter('executor.backend_compile_s').inc(
+                        t_c1 - t_cmid)
+                if obs_on and _TRACE_COUNT[0] > tc0:
+                    sig = _launch_signature(program, feed_vals, feed_names,
+                                            fetch_names, steps,
+                                            self.check_nan, scope)
+                    cache_status = ('disabled' if fp is None else
+                                    'stablehlo_hit'
+                                    if disk_tier == 'stablehlo' else 'miss')
+                    report = _obs.explainer().observe(
+                        sig, compile_s=t_c1 - t_c0, cache=cache_status,
+                        lowering=emit_verdict)
+                    sp.args.update(
+                        kind=report['kind'],
+                        cause='; '.join(report['details'])[:512] or None)
             if fp is not None:
-                t_s0 = time.perf_counter()
-                tier = _cc.disk_cache().store(
-                    fp, compiled=call, lowered=lowered,
-                    meta={'steps': steps, 'fetch': list(fetch_names),
-                          'program': _cc.program_fingerprint(opt_program)})
-                if tier and obs_on:
-                    _obs.metrics.counter('compile_cache.store_s').inc(
-                        time.perf_counter() - t_s0)
+                with _obs.span('compile_cache.store', cat='compile',
+                               counter='compile_cache.store_s'):
+                    _cc.disk_cache().store(
+                        fp, compiled=call, lowered=lowered,
+                        meta={'steps': steps, 'fetch': list(fetch_names),
+                              'program': _cc.program_fingerprint(
+                                  opt_program)})
+        if obs_on:
+            prep.args['verdict'] = 'disk_hit' if disk_tier == 'exec' \
+                else 'compiled'
         entry = _ExecEntry(call, jit_fn, params_in, writeback, program, fp,
                            shard_targets)
         self._cache.put(hot_key, entry)

@@ -16,6 +16,7 @@ import numpy as np
 from . import unique_name
 from .dtypes import convert_dtype, dtype_str
 from . import registry
+from .. import observability as _obs
 
 __all__ = [
     'Program', 'Block', 'Operator', 'Variable', 'Parameter', 'program_guard',
@@ -1064,15 +1065,29 @@ def switch_startup_program(program):
     return old
 
 
+_guard_depth = [0]
+
+
 @contextlib.contextmanager
 def program_guard(main_program, startup_program=None):
+    # the OUTERMOST guard is the phase `program.build`: layers,
+    # append_backward and minimize run inside it; a nested guard adds
+    # nothing to it
+    outermost = not _guard_depth[0]
+    _guard_depth[0] += 1
     old_main = switch_main_program(main_program)
     old_start = None
     if startup_program is not None:
         old_start = switch_startup_program(startup_program)
-    try:
-        yield
-    finally:
-        switch_main_program(old_main)
-        if old_start is not None:
-            switch_startup_program(old_start)
+    with (_obs.span('program.build', cat='build', counter='program.build_s')
+          if outermost else contextlib.nullcontext()) as build:
+        try:
+            yield
+        finally:
+            _guard_depth[0] -= 1
+            switch_main_program(old_main)
+            if old_start is not None:
+                switch_startup_program(old_start)
+            if outermost and _obs.enabled():
+                block = main_program.global_block()
+                build.args.update(ops=len(block.ops), vars=len(block.vars))

@@ -195,10 +195,6 @@ def maybe_optimize(program, fetch_names=()):
         _obs.metrics.counter('opt.ops_fused').inc(stats['ops_fused'])
         _obs.metrics.counter('opt.pass_ms').inc(stats['pass_ms'])
         _obs.metrics.counter('opt.runs').inc()
-        _obs.instant('executor.optimize', cat='compile',
-                     args={'raw': stats['op_count_raw'],
-                           'opt': stats['op_count_opt'],
-                           'pass_ms': stats['pass_ms']})
     while len(memo) >= _MEMO_MAX:
         memo.pop(next(iter(memo)))
     memo[key] = (opt, stats)

@@ -11,7 +11,8 @@ Three consumers, one source of truth:
     from the bounded log buckets.
   * ``telemetry_snapshot(section, ...)`` is the ONE JSON emitter behind
     ``tools/serve_soak.py``, ``tools/fault_soak.py`` and
-    ``tools/pod_soak.py`` — each section's keys live in ``SCHEMA``,
+    ``tools/pod_soak.py``, and the ``setup`` block of ``/varz`` — each
+    section's keys live in ``SCHEMA``,
     so a renamed counter breaks one declarative table (which ci_smoke
     validates once) instead of silently drifting three tools apart.
   * ``MetricsServer`` serves ``/metrics`` (Prometheus text),
@@ -118,12 +119,35 @@ SCHEMA = {
             'ckpt.desync_dropped', 'health.beats', 'health.trips',
             'health.lost_hosts', 'health.desyncs', 'retry.attempts',
             'executor.retraces', 'executor.stall_count',
-            'prefetch.starvation_count', 'kernel.fallbacks',
+            'prefetch.starvation_count',
             'nan_poll.polls', 'nan_poll.trips',
             'executor.host_blocked_s', 'recovery.forensics_runs',
             'recovery.forensics_replay_steps',
             'recovery.escalation.quarantine', 'recovery.escalation.skip',
             'feed.quarantined', 'retry.attempts.feed_read'))),
+    ),
+    # "why did this restart take 40 s": every phase between process start
+    # and the first warm launch, in seconds, nested as the spans are
+    # (docs/observability.md, "Set-up from inside")
+    'setup': (
+        ('process_s', ('block_names', (
+            'process.before_import_s', 'process.import_s'))),
+        ('training_s', ('block_names', (
+            'program.build_s', 'executor.prepare_s', 'executor.lint_s',
+            'executor.optimize_s', 'executor.emit_build_s',
+            'executor.lower_s', 'executor.gather_params_s',
+            'executor.emit_s', 'executor.trace_s',
+            'executor.backend_compile_s', 'executor.run_s'))),
+        ('serving_s', ('block_names', (
+            'generation.init_s', 'generation.warmup_s',
+            'generation.compile_s'))),
+        ('compile_cache_s', ('block_names', (
+            'compile_cache.fingerprint_s', 'compile_cache.load_s',
+            'compile_cache.store_s'))),
+        ('executables', ('block_names', (
+            'executor.lowerings', 'generation.compiles',
+            'compile_cache.disk_hits', 'compile_cache.disk_misses',
+            'compile_cache.disk_stores'))),
     ),
 }
 
@@ -170,6 +194,7 @@ def _varz():
     snap['spans'] = tracing.span_summary()
     snap['retrace_reports'] = list(retrace.explainer().reports)
     snap['flight_events'] = len(_flight.flight().events())
+    snap['setup'] = telemetry_snapshot('setup')
     snap['env'] = {k: v for k, v in os.environ.items()
                    if k.startswith('PT_') or k == 'JAX_PLATFORMS'}
     return snap

@@ -20,10 +20,11 @@ steer an SLO gate.
 import math
 import os
 import threading
+import time
 
 __all__ = ['enabled', 'enable', 'disable', 'Counter', 'Gauge', 'Histogram',
            'MetricsRegistry', 'registry', 'counter', 'gauge', 'histogram',
-           'metrics_snapshot', 'counters', 'reset']
+           'metrics_snapshot', 'counters', 'reset', 'process_age_s']
 
 _ENABLED = [os.environ.get('PT_OBS', '1') not in ('0', 'false', 'False')]
 
@@ -278,3 +279,17 @@ def counters():
 
 def reset():
     _REGISTRY.reset()
+
+
+def process_age_s():
+    """Seconds since the kernel started this process: the boot clock now
+    less field 22 (`starttime`, in clock ticks) of ``/proc/self/stat``;
+    None where that cannot be read (no Linux /proc)."""
+    try:
+        with open('/proc/self/stat') as f:
+            # the command (field 2) may hold spaces: count from its ')'
+            ticks = int(f.read().rsplit(')', 1)[1].split()[19])
+        return max(0.0, time.clock_gettime(time.CLOCK_BOOTTIME)
+                   - ticks / os.sysconf('SC_CLK_TCK'))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None

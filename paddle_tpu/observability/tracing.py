@@ -24,7 +24,7 @@ import threading
 import time
 from collections import deque
 
-from .metrics import enabled
+from .metrics import counter as _counter, enabled
 from . import trace_context as _tc
 
 __all__ = ['TraceRecorder', 'recorder', 'span', 'instant', 'add_span',
@@ -195,13 +195,16 @@ class span(object):
     may gain keys and ``sp.name`` may change before the block ends (the
     recorder takes both at exit; the annotation keeps the name it was
     entered with), and after the block ``sp.seconds`` is the duration on
-    the span's clock, so a counter fed from it agrees with the span."""
-    __slots__ = ('name', 'cat', 'args', 't0', 't1', '_ann')
+    the span's clock, so a counter fed from it agrees with the span.
+    ``counter='x.phase_s'`` names the seconds counter of a PHASE: it is
+    looked up at exit and moved by exactly ``sp.seconds``."""
+    __slots__ = ('name', 'cat', 'args', 'counter', 't0', 't1', '_ann')
 
-    def __init__(self, name, cat='runtime', **args):
+    def __init__(self, name, cat='runtime', counter=None, **args):
         self.name = name
         self.cat = cat
         self.args = args
+        self.counter = counter
         self.t0 = self.t1 = None
         self._ann = None
 
@@ -219,6 +222,8 @@ class span(object):
         self._ann.__exit__(exc_type, exc, tb)
         _RECORDER.add_complete(self.name, self.t0, self.t1, self.cat,
                                self.args or None)
+        if self.counter is not None:
+            _counter(self.counter).inc(self.t1 - self.t0)
         return False
 
     @property

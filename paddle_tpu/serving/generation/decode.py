@@ -607,58 +607,62 @@ class DecodeRuntime(object):
                  cache_dtype='float32', mesh=None, ring_min_len=None,
                  page_len=None, pages=None, kv_quant='none',
                  prefix_cache=True):
-        import jax.numpy as jnp
-        self.cfg = dict(cfg)
-        self.w = {n: jnp.asarray(weights[n]) for n in weight_names(cfg)}
-        self.recurrent = _recurrent(cfg)
-        self.cache = CacheConfig(
-            slots=slots, layers=int(cfg['n_layer']),
-            kv_heads=int(cfg['n_kv_head']), max_len=int(cfg['max_len']),
-            head_dim=_head_dim(cfg), dtype=cache_dtype,
-            page_len=page_len, pages=pages, quant=kv_quant,
-            recurrent=(_ssm.state_shapes(cfg['ssm']) if self.recurrent
-                       else None))
-        self.allocator = SlotAllocator(self.cache.slots)
-        self.pool = PagePool(self.cache)
-        # recurrent state cannot be shared between prompts: no prefix
-        # cache, and every begin that forgoes one is counted
-        self._prefix_refused = bool(prefix_cache) and self.recurrent
-        self.prefix = (PrefixCache(self.pool, self.cache.page_len)
-                       if prefix_cache and not self.recurrent else None)
-        S = self.cache.slots
-        self.block_tables = np.zeros((S, self.cache.max_pages), np.int32)
-        self.owned = [[] for _ in range(S)]
-        self.host_len = np.zeros(S, np.int32)
-        self.host_tok = np.zeros(S, np.int32)
-        self.state = init_state(self.cache)
-        self.prefill_chunk = int(prefill_chunk)
-        if not 0 < self.prefill_chunk <= self.cache.max_len:
-            raise ValueError('prefill_chunk must be in (0, max_len]')
-        self.mesh = mesh
-        self.ring_min_len = (int(ring_min_len) if ring_min_len is not None
-                             else 2 * self.prefill_chunk)
-        # the decode step attends over the pool in place where the
-        # kernel can run (a floating pool, one device); an int8 pool and
-        # a mesh of several devices keep the composed gather
-        self.paged = paged_attention_eligible(
-            self.cache.pool_shape, self.cache.store_dtype, mesh)
-        # likewise the scan state of a recurrent model: in place over
-        # the live slots where that kernel can run (float32, one device)
-        self.state_kernel = self.recurrent and _ssm.ssm_step_eligible(
-            self.state['ssm'].shape, self.state['ssm'].dtype, mesh)
-        self._execs = {}
-        # arguments uploaded ahead of their launch: {'prefill' | 'window':
-        # [(host copy, device array), ...]} (`stage_prefill`, `stage_window`)
-        self._staged = {}
-        # rows of K (or V) per layer one COMPOSED step gathers
-        self._gathered = None if self.paged else _gathered_rows(
-            self.cache, self._state_structs(),
-            self._bt_struct(self.cache.slots))
-        self._lock = threading.Lock()
-        _obs.metrics.gauge('generation.kv_cache_bytes').set(
-            self.cache.bytes())
-        _obs.metrics.gauge('generation.recurrent_state_bytes').set(
-            self.cache.recurrent_bytes())
+        # weights adopted, pool and recurrent state allocated, the
+        # composed path's rows read off the shapes: one phase of set-up
+        with _obs.span('decode.init', cat='build',
+                       counter='generation.init_s', slots=int(slots)):
+            import jax.numpy as jnp
+            self.cfg = dict(cfg)
+            self.w = {n: jnp.asarray(weights[n]) for n in weight_names(cfg)}
+            self.recurrent = _recurrent(cfg)
+            self.cache = CacheConfig(
+                slots=slots, layers=int(cfg['n_layer']),
+                kv_heads=int(cfg['n_kv_head']), max_len=int(cfg['max_len']),
+                head_dim=_head_dim(cfg), dtype=cache_dtype,
+                page_len=page_len, pages=pages, quant=kv_quant,
+                recurrent=(_ssm.state_shapes(cfg['ssm']) if self.recurrent
+                           else None))
+            self.allocator = SlotAllocator(self.cache.slots)
+            self.pool = PagePool(self.cache)
+            # recurrent state cannot be shared between prompts: no prefix
+            # cache, and every begin that forgoes one is counted
+            self._prefix_refused = bool(prefix_cache) and self.recurrent
+            self.prefix = (PrefixCache(self.pool, self.cache.page_len)
+                           if prefix_cache and not self.recurrent else None)
+            S = self.cache.slots
+            self.block_tables = np.zeros((S, self.cache.max_pages), np.int32)
+            self.owned = [[] for _ in range(S)]
+            self.host_len = np.zeros(S, np.int32)
+            self.host_tok = np.zeros(S, np.int32)
+            self.state = init_state(self.cache)
+            self.prefill_chunk = int(prefill_chunk)
+            if not 0 < self.prefill_chunk <= self.cache.max_len:
+                raise ValueError('prefill_chunk must be in (0, max_len]')
+            self.mesh = mesh
+            self.ring_min_len = (int(ring_min_len) if ring_min_len is not None
+                                 else 2 * self.prefill_chunk)
+            # the decode step attends over the pool in place where the
+            # kernel can run (a floating pool, one device); an int8 pool and
+            # a mesh of several devices keep the composed gather
+            self.paged = paged_attention_eligible(
+                self.cache.pool_shape, self.cache.store_dtype, mesh)
+            # likewise the scan state of a recurrent model: in place over
+            # the live slots where that kernel can run (float32, one device)
+            self.state_kernel = self.recurrent and _ssm.ssm_step_eligible(
+                self.state['ssm'].shape, self.state['ssm'].dtype, mesh)
+            self._execs = {}
+            # arguments uploaded ahead of their launch: {'prefill' | 'window':
+            # [(host copy, device array), ...]} (`stage_prefill`, `stage_window`)
+            self._staged = {}
+            # rows of K (or V) per layer one COMPOSED step gathers
+            self._gathered = None if self.paged else _gathered_rows(
+                self.cache, self._state_structs(),
+                self._bt_struct(self.cache.slots))
+            self._lock = threading.Lock()
+            _obs.metrics.gauge('generation.kv_cache_bytes').set(
+                self.cache.bytes())
+            _obs.metrics.gauge('generation.recurrent_state_bytes').set(
+                self.cache.recurrent_bytes())
 
     # ------------------------------------------------------- geometry
     @property
@@ -789,35 +793,56 @@ class DecodeRuntime(object):
     def _compiled(self, key, build):
         """One executable per (kind, shape) key: AOT-lowered, donated
         state, persisted through the compile-cache disk tier so a fresh
-        process warm-starts the decode loop without compiling."""
+        process warm-starts the decode loop without compiling.  A key
+        not yet in `_execs` is the phase ``decode.compile``, whose
+        children (fingerprint, disk load, trace + compile, store) each
+        move a seconds counter."""
         with self._lock:
             call = self._execs.get(key)
         if call is not None:
             return call
-        _cc.ensure_xla_cache_backstop()
-        spec = {'fn': key[0], 'shape': list(key[1:]), 'cfg': self.cfg,
-                'cache': self.cache.spec(),
-                'mesh': _cc._mesh_blob(self.mesh) if key[0].endswith(
-                    'ring') else None}
-        fp = _cc.callable_fingerprint('generation', spec,
-                                      param_specs=self._param_specs())
-        call = None
-        if _cc.disk_enabled():
-            call, _tier = _cc.disk_cache().load(fp)
-            _obs.metrics.counter(
-                'compile_cache.disk_hits' if call is not None
-                else 'compile_cache.disk_misses').inc()
-        if call is None:
-            jitted, args = build()
-            lowered = jitted.lower(*args)
-            call = lowered.compile()
-            _obs.metrics.counter('generation.compiles').inc()
+        obs_on = _obs.enabled()
+        with _obs.span('decode.compile', cat='compile', fn=key[0],
+                       shape=list(key[1:])) as comp:
+            with _obs.span('compile_cache.fingerprint', cat='compile',
+                           counter='compile_cache.fingerprint_s'):
+                _cc.ensure_xla_cache_backstop()
+                spec = {'fn': key[0], 'shape': list(key[1:]),
+                        'cfg': self.cfg, 'cache': self.cache.spec(),
+                        'mesh': _cc._mesh_blob(self.mesh)
+                        if key[0].endswith('ring') else None}
+                fp = _cc.callable_fingerprint(
+                    'generation', spec, param_specs=self._param_specs())
+            call = None
             if _cc.disk_enabled():
-                _cc.disk_cache().store(fp, compiled=call, lowered=lowered,
-                                       meta={'kind': 'generation',
-                                             'fn': key[0]})
-        with self._lock:
-            self._execs[key] = call
+                with _obs.span('decode.aot_load', cat='compile') as load:
+                    call, _tier = _cc.disk_cache().load(fp)
+            if obs_on:
+                hit = call is not None
+                comp.args['verdict'] = 'disk_hit' if hit else 'compiled'
+                if _cc.disk_enabled():
+                    _obs.metrics.counter(
+                        'compile_cache.disk_hits' if hit
+                        else 'compile_cache.disk_misses').inc()
+                if hit:
+                    _obs.metrics.counter('compile_cache.load_s').inc(
+                        load.seconds)
+            if call is None:
+                with _obs.span('decode.trace_compile', cat='compile',
+                               counter='generation.compile_s'):
+                    jitted, args = build()
+                    lowered = jitted.lower(*args)
+                    call = lowered.compile()
+                if obs_on:
+                    _obs.metrics.counter('generation.compiles').inc()
+                if _cc.disk_enabled():
+                    with _obs.span('compile_cache.store', cat='compile',
+                                   counter='compile_cache.store_s'):
+                        _cc.disk_cache().store(
+                            fp, compiled=call, lowered=lowered,
+                            meta={'kind': 'generation', 'fn': key[0]})
+            with self._lock:
+                self._execs[key] = call
         return call
 
     def _sds(self, shape, dtype):
@@ -910,11 +935,13 @@ class DecodeRuntime(object):
         """Compile (or disk-load) the steady-state executables up front
         so the first request pays no compile latency.  With
         ``speculative`` the verify window is warmed too."""
-        self._prefill_exec(self.prefill_chunk)
-        if steps:
-            self._decode_exec(int(steps))
-            if speculative:
-                self._verify_exec(int(steps))
+        with _obs.span('decode.warmup', cat='compile',
+                       counter='generation.warmup_s'):
+            self._prefill_exec(self.prefill_chunk)
+            if steps:
+                self._decode_exec(int(steps))
+                if speculative:
+                    self._verify_exec(int(steps))
 
     # ------------------------------------------------------ launching
     # A launch is upload -> dispatch, and returns what the executable

@@ -303,11 +303,8 @@ def check_d016(main, fetch_names, label):
                  % (label, d16[0].render()))
 
 
-def counters():
-    c = obs.counters()
-    return (c.get('kernelgen.ops') or 0,
-            c.get('kernelgen.fallbacks') or 0,
-            c.get('kernel.fallbacks') or 0)
+def kernelgen_ops():
+    return obs.counters().get('kernelgen.ops') or 0
 
 
 # 1. bench transformer (smoke shapes), AMP + dropout, 2 steps
@@ -328,7 +325,7 @@ with fluid.scope_guard(scope):
         loss, = exe.run(main, feed=feed, fetch_list=[out['loss']])
         if not np.isfinite(np.asarray(loss)).all():
             sys.exit('ci_smoke: non-finite loss under PT_KERNELGEN=1')
-ops, kg_fb, k_fb = counters()
+ops = kernelgen_ops()
 if ops < 1:
     sys.exit('ci_smoke: kernelgen.ops=%r — PT_KERNELGEN=1 but no fused '
              'group lowered through a generated kernel' % ops)
@@ -351,16 +348,12 @@ with fluid.scope_guard(scope):
     exe.run(startup)
     for _ in range(2):
         exe.run(main, feed=feed, fetch_list=[loss])
-ops2, kg_fb, k_fb = counters()
+ops2 = kernelgen_ops()
 if ops2 <= ops:
     sys.exit('ci_smoke: fused-Adam program lowered no generated kernels '
              '(kernelgen.ops %r -> %r)' % (ops, ops2))
-if kg_fb or k_fb:
-    sys.exit('ci_smoke: %d kernelgen / %d kernel fallback(s) counted — '
-             'no reroute exists, so nothing may count one'
-             % (kg_fb, k_fb))
 print('ci_smoke: fused-Adam trained strict-kernelgen '
-      '(%d groups total, zero fallbacks)' % ops2)
+      '(%d groups total)' % ops2)
 EOF
 kg_zoo_rc=$?
 if [ "$kg_zoo_rc" -ne 0 ]; then
@@ -406,13 +399,8 @@ with fluid.scope_guard(scope):
 c = obs.counters()
 searches = c.get('kernelgen.autotune_searches') or 0
 hits = c.get('kernelgen.autotune_cache_hits') or 0
-fallbacks = ((c.get('kernelgen.fallbacks') or 0) +
-             (c.get('kernel.fallbacks') or 0))
-print('ci_smoke: autotune %s run: searches=%d cache_hits=%d fallbacks=%d'
-      % (phase, searches, hits, fallbacks))
-if fallbacks:
-    sys.exit('ci_smoke: %d fallback(s) counted with the autotuner on'
-             % fallbacks)
+print('ci_smoke: autotune %s run: searches=%d cache_hits=%d'
+      % (phase, searches, hits))
 if phase == 'cold':
     if searches < 1:
         sys.exit('ci_smoke: cold run paid no autotune searches — '
