@@ -50,6 +50,36 @@ cache spec now carries page_len/pages/quant, so geometry changes get
 fresh fingerprints.  `dense_reference` is the independent, page-free
 parity oracle.
 
+Weights as the launches read them.  `DecodeRuntime` prepares each
+layer's q, k and v projection ONCE, where it adopts the weights
+(`_params_from`), instead of every launch and every layer re-deriving
+that form on the chip.  LAYOUT: the public ``[D, N]`` matrix is stored
+``[N, D]`` and `_qkv` contracts it on its second axis, the operand
+layout XLA:TPU asks of these three products in both executables (fed
+``[D, N]``, the window copied all 3 x layers of them before its loop
+on every launch, and so did every prefill chunk).  ROTARY PAIRS AS
+HALVES: within every head the output columns of q and of k are
+reordered even-then-odd (`_head_rows`), and `_rope_at` turns
+``x[..., :dh/2]`` against ``x[..., dh/2:]`` by the same angles
+``pos * theta^(-2i/dh)`` the interleaved rotation gives pair ``(2i,
+2i + 1)``: two contiguous halves where the pairs were a stride of two
+along the lanes.  q and k are then the public q and k under ONE fixed
+permutation of the head dimension, so every score ``q . k``, softmax
+and output is the same number up to the order of a sum; v,
+``att_o_w``, the ``key`` multiplier and the int8 row scale (a maximum
+over the head dimension) never see it.  A K PAGE THEREFORE HOLDS ITS
+ROWS IN ROTATED-HALF ORDER, whoever wrote it (prefill, decode, verify,
+ring prefill: all through `_qkv` and `_rope_at`) and whoever shares it
+(the prefix cache).  The public face is unchanged: the constructor
+takes `weight_names(cfg)` in the public shapes, ``rt.w[name]`` answers
+with the public names, shapes and values bit for bit (`_PublicWeights`
+undoes the data movement on read; the runtime itself holds q, k and v
+once, prepared: ``rt.params``), and `cache_row` returns K in the
+public order.  `dense_reference` keeps the interleaved rotation on the
+raw weights and so checks the preparation.
+tests/test_generation_layout.py holds the compiled text (no copy of a
+weight's extent in either launch) and the exactness.
+
 The model dict chooses the BLOCK.  Without a ``block`` key it is the
 dense decoder above (RMSNorm, GQA, RoPE, SwiGLU), whose ``head_dim``
 and ``rms_eps`` default to ``d_model // n_head`` and 1e-6.  ``block:
@@ -71,6 +101,7 @@ prefix-cache hit (`generation.prefix_refused_recurrent` counts the
 begins), no speculative window and no ring prefill.
 """
 import threading
+from collections.abc import Mapping
 
 import numpy as np
 
@@ -85,7 +116,7 @@ from .kv_cache import (CacheConfig, PagePool, PrefixCache, SlotAllocator,
                        init_state)
 
 __all__ = ['DecodeRuntime', 'dense_reference', 'weight_names',
-           'random_weights']
+           'weight_shapes', 'random_weights']
 
 _WEIGHT_SLOTS = ('att_q_w', 'att_k_w', 'att_v_w', 'att_o_w', 'att_norm',
                  'ffn_norm', 'ffn_fc1_w', 'ffn_fc2_w', 'ffn_fc3_w')
@@ -115,11 +146,9 @@ def weight_names(cfg):
     return names
 
 
-def random_weights(cfg, seed=0, scale=0.08):
-    """Random-init weight dict under `weight_names(cfg)` (tests/soaks
-    that exercise the runtime without training a model first): a name
-    ending in ``norm`` is ones, the rest normal at ``scale``."""
-    rng = np.random.RandomState(seed)
+def weight_shapes(cfg):
+    """{name: shape} of every weight under `weight_names(cfg)`, in the
+    public layout (a projection is ``[in, out]``)."""
     d, v, h = int(cfg['d_model']), int(cfg['vocab']), int(cfg['n_head'])
     hkv, f = int(cfg['n_kv_head']), int(cfg['d_ffn'])
     dh = _head_dim(cfg)
@@ -127,20 +156,123 @@ def random_weights(cfg, seed=0, scale=0.08):
     shapes = {'tok_emb': (v, d), 'final_norm': (d,), 'lm_proj_w': (d, v)}
     for i in range(int(cfg['n_layer'])):
         p = 'layer_%d_' % i
-        shapes.update({p + 'att_q_w': (d, h * dh), p + 'att_k_w': (d, hkv * dh),
+        shapes.update({p + 'att_q_w': (d, h * dh),
+                       p + 'att_k_w': (d, hkv * dh),
                        p + 'att_v_w': (d, hkv * dh),
                        p + 'att_o_w': (h * dh, d),
                        p + 'att_norm': (d,), p + 'ffn_norm': (d,),
                        p + 'ffn_fc1_w': (d, f), p + 'ffn_fc3_w': (d, f),
                        p + 'ffn_fc2_w': (f, d)})
         shapes.update((p + k, s) for k, s in mixer.items())
+    return shapes
+
+
+def random_weights(cfg, seed=0, scale=0.08):
+    """Random-init weight dict under `weight_names(cfg)` (tests/soaks
+    that exercise the runtime without training a model first): a name
+    ending in ``norm`` is ones, the rest normal at ``scale``."""
+    rng = np.random.RandomState(seed)
     out = {}
-    for n, s in shapes.items():
+    for n, s in weight_shapes(cfg).items():
         if n.endswith('norm'):
             out[n] = np.ones(s, np.float32)
         else:
             out[n] = (scale * rng.randn(*s)).astype(np.float32)
     return out
+
+
+# ---------------------------------------- weights as the launches read them
+
+# public slot -> the name its prepared form goes by among the executables'
+# parameters: another name, so that a raw weight handed to `_qkv` is a
+# KeyError and never a silently wrong product (q's matrix is square)
+_PREPARED = {'att_q_w': 'att_q_wt', 'att_k_w': 'att_k_wt',
+             'att_v_w': 'att_v_wt'}
+
+
+def _head_rows(wt, dh, halves):
+    """wt [heads * dh, D]: every head's rows from interleaved pairs
+    (0, 1, 2, ...) to rotated halves (0, 2, ..., 1, 3, ...) with
+    ``halves``, back without.  Data movement: each is the other's exact
+    inverse."""
+    n, d = wt.shape
+    inner = (dh // 2, 2) if halves else (2, dh // 2)
+    return wt.reshape((n // dh,) + inner + (d,)).transpose(
+        0, 2, 1, 3).reshape(n, d)
+
+
+def _prepare_qkv(q, k, v, dh):
+    """The public ``[D, N]`` projections of one layer -> what `_qkv`
+    contracts: ``[N, D]`` each, q's and k's heads in rotated-half order
+    (v's columns keep theirs: nothing rotates v)."""
+    return (_head_rows(q.T, dh, True), _head_rows(k.T, dh, True), v.T)
+
+
+def _public_weight(slot, wt, dh):
+    """`_prepare_qkv` undone for ONE prepared array: bitwise the public
+    weight it was made from."""
+    return (wt if slot == 'att_v_w' else _head_rows(wt, dh, False)).T
+
+
+def _public_rows(k, dh):
+    """K rows [..., dh] as the pool holds them (rotated halves) -> the
+    public interleaved order (numpy; `DecodeRuntime.cache_row`)."""
+    return k.reshape(k.shape[:-1] + (2, dh // 2)).swapaxes(-1, -2).reshape(
+        k.shape)
+
+
+def _prepared_names(cfg):
+    """{public name: (slot, the executables' name for its prepared
+    form)} of the weights the runtime keeps prepared."""
+    return {'layer_%d_%s' % (i, slot): (slot, 'layer_%d_%s' % (i, stored))
+            for i in range(int(cfg['n_layer']))
+            for slot, stored in _PREPARED.items()}
+
+
+def _params_from(weights, cfg):
+    """``weights`` under `weight_names(cfg)` -> the parameters the
+    executables take: q, k and v of every layer prepared (one jitted
+    call a layer, one compilation per layer geometry), under their
+    `_PREPARED` names; every other weight as it is.  A layer's raw q, k
+    and v are held only while that layer is prepared."""
+    import jax
+    import jax.numpy as jnp
+    prepared, dh = _prepared_names(cfg), _head_dim(cfg)
+    prepare = jax.jit(_prepare_qkv, static_argnums=3)
+    params = {n: jnp.asarray(weights[n]) for n in weight_names(cfg)
+              if n not in prepared}
+    for i in range(int(cfg['n_layer'])):
+        p = 'layer_%d_' % i
+        made = prepare(*(jnp.asarray(weights[p + s]) for s in _PREPARED), dh)
+        params.update(zip((p + t for t in _PREPARED.values()), made))
+    return params
+
+
+class _PublicWeights(Mapping):
+    """`DecodeRuntime.w`: the weights under `weight_names(cfg)`, in the
+    public shapes and with the values that were passed in, bit for bit.
+    The runtime keeps q, k and v ONCE, in the prepared form
+    (`DecodeRuntime.params`); reading one of them here undoes the
+    preparation into a fresh array, every other name is the array the
+    executables read."""
+
+    def __init__(self, params, cfg):
+        import jax
+        self._params, self._names = params, weight_names(cfg)
+        self._prepared, self._dh = _prepared_names(cfg), _head_dim(cfg)
+        self._undo = jax.jit(_public_weight, static_argnums=(0, 2))
+
+    def __getitem__(self, name):
+        if name not in self._prepared:
+            return self._params[name]
+        slot, stored = self._prepared[name]
+        return self._undo(slot, self._params[stored], self._dh)
+
+    def __iter__(self):
+        return iter(self._names)
+
+    def __len__(self):
+        return len(self._names)
 
 
 # ------------------------------------------------------- forward pieces
@@ -163,29 +295,48 @@ def _scaled(cfg, x, name):
     return x * mu[name] if mu else x
 
 
-def _rope_at(x, pos, theta):
-    """x: [B, h, T, dh]; pos: [B, T] absolute positions (per-row — decode
-    slots all sit at different lengths)."""
+def _rope_angles(pos, dh, theta):
+    """(cos, sin) [B, 1, T, dh/2] of ``pos * theta^(-2i/dh)``: pair i's
+    angle at each of pos [B, T]."""
     import jax.numpy as jnp
-    dh = x.shape[-1]
     freqs = theta ** (-jnp.arange(0, dh // 2) * 2.0 / dh)
     ang = pos[:, None, :, None].astype(jnp.float32) * freqs
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                     axis=-1).reshape(x.shape)
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def _rope_at(x, pos, theta):
+    """x: [B, h, T, dh] in ROTATED-HALF order (`_prepare_qkv`: a head's
+    even columns, then its odd ones); pos: [B, T] absolute positions
+    (per-row — decode slots all sit at different lengths).  Pair i of a
+    head is ``(x[i], x[dh/2 + i])`` and turns by ``pos * theta^(-2i/dh)``:
+    the interleaved rotation of the public order, with no stride along
+    the lanes."""
+    import jax.numpy as jnp
+    dh = x.shape[-1]
+    cos, sin = _rope_angles(pos, dh, theta)
+    x1, x2 = x[..., :dh // 2], x[..., dh // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1)
 
 
 def _qkv(w, cfg, h, i):
-    """h: [B, T, D] -> q [B, H, T, dh], k/v [B, Hkv, T, dh] (pre-rope)."""
+    """h: [B, T, D] -> q [B, H, T, dh], k/v [B, Hkv, T, dh] (pre-rope),
+    from the PREPARED projections (`_prepare_qkv`): each stored
+    ``[N, D]`` and contracted on its second axis, q's and k's heads in
+    rotated-half order."""
+    import jax.numpy as jnp
     B, T = h.shape[0], h.shape[1]
     H, Hkv = int(cfg['n_head']), int(cfg['n_kv_head'])
     dh = _head_dim(cfg)
     p = 'layer_%d_' % i
     h = _scaled(cfg, h, 'attention_in')
-    q = (h @ w[p + 'att_q_w']).reshape(B, T, H, dh).transpose(0, 2, 1, 3)
-    k = (h @ w[p + 'att_k_w']).reshape(B, T, Hkv, dh).transpose(0, 2, 1, 3)
-    v = (h @ w[p + 'att_v_w']).reshape(B, T, Hkv, dh).transpose(0, 2, 1, 3)
+
+    def heads(slot, n):
+        out = jnp.einsum('btd,nd->btn', h, w[p + _PREPARED[slot]])
+        return out.reshape(B, T, n, dh).transpose(0, 2, 1, 3)
+
+    q, k, v = heads('att_q_w', H), heads('att_k_w', Hkv), heads('att_v_w',
+                                                                Hkv)
     return q, _scaled(cfg, k, 'key'), v
 
 
@@ -494,29 +645,48 @@ def _verify_fn(cfg, cache, steps, paged, state_kernel):
     return window
 
 
+def _interleaved_rope(x, pos, theta):
+    """The rotation in the PUBLIC order, as the model defines it: pairs
+    ``(x[2i], x[2i + 1])`` of x [B, h, T, dh] turn by
+    ``pos * theta^(-2i/dh)`` (`dense_reference` only; the executables
+    rotate halves, `_rope_at`)."""
+    import jax.numpy as jnp
+    cos, sin = _rope_angles(pos, x.shape[-1], theta)
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                     axis=-1).reshape(x.shape)
+
+
 def dense_reference(weights, cfg, prompt):
     """Independent prefill reference: ordinary dense causal attention
     over the whole prompt — no cache pages, no positional masking, no
     chunking (an intentionally different code path from
-    `cached_attention`).  Returns (k [L, Hkv, P, dh], v, last-position
+    `cached_attention`), and the RAW weights in the public order under
+    the interleaved rotation: nothing of `_prepare_qkv`, which it
+    thereby checks.  Returns (k [L, Hkv, P, dh], v, last-position
     logits [V]) for the parity tests."""
     import jax
     import jax.numpy as jnp
     w = {n: jnp.asarray(weights[n]) for n in weight_names(cfg)}
     L = int(cfg['n_layer'])
     theta = float(cfg['theta'])
+    H, Hkv, dh = int(cfg['n_head']), int(cfg['n_kv_head']), _head_dim(cfg)
     P = int(np.asarray(prompt).shape[-1])
     pos = jnp.arange(P)[None]
     x = w['tok_emb'][jnp.asarray(prompt, jnp.int32).reshape(1, P)]
     ks, vs = [], []
     for i in range(L):
         h = _rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
-        q, k, v = _qkv(w, cfg, h, i)
-        q = _rope_at(q, pos, theta)
-        k = _rope_at(k, pos, theta)
+
+        def heads(slot, n):
+            out = h @ w['layer_%d_%s' % (i, slot)]
+            return out.reshape(1, P, n, dh).transpose(0, 2, 1, 3)
+
+        q = _interleaved_rope(heads('att_q_w', H), pos, theta)
+        k = _interleaved_rope(heads('att_k_w', Hkv), pos, theta)
+        v = heads('att_v_w', Hkv)
         ks.append(k[0])
         vs.append(v[0])
-        H, Hkv, dh = q.shape[1], k.shape[1], q.shape[-1]
         qg = q.reshape(1, Hkv, H // Hkv, P, dh)
         s = jnp.einsum('bhgqd,bhkd->bhgqk', qg, k,
                        preferred_element_type=jnp.float32) * (dh ** -0.5)
@@ -609,11 +779,23 @@ class DecodeRuntime(object):
                  prefix_cache=True):
         # weights adopted, pool and recurrent state allocated, the
         # composed path's rows read off the shapes: one phase of set-up
-        with _obs.span('decode.init', cat='build',
-                       counter='generation.init_s', slots=int(slots)):
-            import jax.numpy as jnp
+        with _obs.span('decode.init', cat='build', slots=int(slots),
+                       counter='generation.init_s') as init:
+            import jax
             self.cfg = dict(cfg)
-            self.w = {n: jnp.asarray(weights[n]) for n in weight_names(cfg)}
+            # what the executables read (q, k and v prepared, once: a
+            # jitted call a layer, from JAX's cache on a warm start), and
+            # the public face over the same arrays
+            _cc.ensure_xla_cache_backstop()
+            self.params = _params_from(weights, cfg)
+            self.w = _PublicWeights(self.params, cfg)
+            made = jax.block_until_ready(
+                [self.params[stored]
+                 for _slot, stored in _prepared_names(cfg).values()])
+            made_bytes = sum(int(a.nbytes) for a in made)
+            init.args.update(prepared=len(made), prepared_bytes=made_bytes)
+            _obs.metrics.gauge('generation.prepared_weight_bytes').set(
+                made_bytes)
             self.recurrent = _recurrent(cfg)
             self.cache = CacheConfig(
                 slots=slots, layers=int(cfg['n_layer']),
@@ -788,7 +970,12 @@ class DecodeRuntime(object):
     # ---------------------------------------------------------- AOT
     def _param_specs(self):
         return {n: (tuple(a.shape), str(a.dtype))
-                for n, a in self.w.items()}
+                for n, a in self.params.items()}
+
+    def _param_structs(self):
+        """Arg structs of the executables' first argument."""
+        return {n: self._sds(a.shape, a.dtype)
+                for n, a in self.params.items()}
 
     def _compiled(self, key, build):
         """One executable per (kind, shape) key: AOT-lowered, donated
@@ -875,12 +1062,10 @@ class DecodeRuntime(object):
             jitted = jax.jit(fn, donate_argnums=(1,))
             i32 = self._sds((), jax.numpy.int32)
             f32 = self._sds((), jax.numpy.float32)
-            params = {n: self._sds(a.shape, a.dtype)
-                      for n, a in self.w.items()}
             toks = self._sds((chunk,), jax.numpy.int32)
             bt_row = self._sds((self.cache.max_pages,), jax.numpy.int32)
-            args = [params, self._state_structs(), bt_row, toks,
-                    i32, i32, i32, i32, f32, i32]
+            args = [self._param_structs(), self._state_structs(), bt_row,
+                    toks, i32, i32, i32, i32, f32, i32]
             return jitted, args
 
         return self._compiled(('prefill_ring' if ring else 'prefill',
@@ -900,9 +1085,8 @@ class DecodeRuntime(object):
             jitted = jax.jit(fn, donate_argnums=(1,))
             S = self.cache.slots
             vec = lambda dt: self._sds((S,), dt)  # noqa: E731
-            params = {n: self._sds(a.shape, a.dtype)
-                      for n, a in self.w.items()}
-            args = [params, self._state_structs(), self._bt_struct(S)]
+            args = [self._param_structs(), self._state_structs(),
+                    self._bt_struct(S)]
             if kind == 'verify':
                 args.append(self._sds((steps, S), jax.numpy.int32))
             args += [vec(jax.numpy.bool_), vec(jax.numpy.int32),
@@ -1058,7 +1242,7 @@ class DecodeRuntime(object):
                 args = self._uploaded('prefill', self._prefill_values(
                     width, slot, tokens, offset, params))
             with _obs.span('decode.prefill.dispatch', cat='decode'):
-                st, nxt, logits = call(self.w, self.state, *args)
+                st, nxt, logits = call(self.params, self.state, *args)
                 self.state = st
                 nxt.copy_to_host_async()
         self.host_len[slot] = offset + n
@@ -1135,7 +1319,7 @@ class DecodeRuntime(object):
             with _obs.span('decode.window.upload', cat='decode'):
                 args = self._uploaded('window', values)
             with _obs.span('decode.window.dispatch', cat='decode'):
-                st, toks = call(self.w, self.state, *args)
+                st, toks = call(self.params, self.state, *args)
                 self.state = st
                 toks.copy_to_host_async()
         if _obs.enabled():
@@ -1218,7 +1402,8 @@ class DecodeRuntime(object):
     def cache_row(self, slot):
         """Host copies (k [L, Hkv, Tmax, dh], v, length) of one slot's
         LOGICAL row, reassembled (and dequantized) through its block
-        table."""
+        table, K in the public (interleaved) order of the head
+        dimension: the pages hold it in rotated halves."""
         st = self.state
         bt = self.block_tables[int(slot)]
         L, Hkv = self.cache.layers, self.cache.kv_heads
@@ -1238,7 +1423,8 @@ class DecodeRuntime(object):
             v = assemble(st['v'], st['v_scale'])
         else:
             k, v = assemble(st['k'], None), assemble(st['v'], None)
-        return k, v, int(np.asarray(st['lengths'][int(slot)]))
+        return (_public_rows(k, dh), v,
+                int(np.asarray(st['lengths'][int(slot)])))
 
     def generate(self, prompt, max_new, params=None, steps_per_window=4,
                  use_ring=False, speculative=False):

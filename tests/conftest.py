@@ -44,3 +44,20 @@ def fresh_programs():
     framework.switch_startup_program(old_startup)
     unique_name.switch(old_gen)
     executor_mod._global_scope = old_scope
+
+
+@pytest.fixture(scope='session')
+def one_v5e_chip():
+    """A described, not attached, v5e chip: XLA:TPU and Mosaic compile for
+    it here and raise what the chip's compiler would.  Asked for by name,
+    by the few tests that read the compiler's text
+    (test_paged_attention.py, test_generation_layout.py): the topology is
+    described only once one of them runs, never at import."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:  # noqa: BLE001 - no libtpu here: nothing to test
+        pytest.skip('no v5e:2x2 topology can be described here: %s' % e)
+    return SingleDeviceSharding(topo.devices[0])

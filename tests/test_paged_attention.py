@@ -171,20 +171,6 @@ def test_eligibility_is_read_off_the_pool():
 
 # ------------------------------------ the chip's compiler, without the chip
 
-@pytest.fixture(scope='module')
-def one_v5e_chip():
-    """A described, not attached, v5e chip: XLA:TPU and Mosaic compile for
-    it here and raise what the chip's compiler would."""
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    try:
-        topo = topologies.get_topology_desc(platform='tpu',
-                                            topology_name='v5e:2x2')
-    except Exception as e:  # noqa: BLE001 - no libtpu here: nothing to test
-        pytest.skip('no v5e:2x2 topology can be described here: %s' % e)
-    return SingleDeviceSharding(topo.devices[0])
-
-
 @pytest.mark.parametrize('dtype,heads,kv_heads,layers,slots,max_len,pages', [
     ('bfloat16', 32, 8, 16, 32, 1280, 5121),   # mistral7b.chat_steady's pool
     ('float32', 16, 8, 16, 8, 2048, None),     # chip_smoke's llama_1b widths
