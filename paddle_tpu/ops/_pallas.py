@@ -16,6 +16,7 @@ def single_device(mesh):
     """A Mosaic kernel is a one-device program.  Under a mesh of several
     devices GSPMD refuses it when the step lowers ("Mosaic kernels cannot
     be automatically partitioned. Please wrap the call in a shard_map."),
-    so such launches take the composed XLA path by this static rule —
-    until a kernel carries its own shard_map (ROADMAP D4 / R7)."""
+    so such launches take the composed XLA path by this static rule: no
+    kernel carries its own shard_map (ROADMAP R5: the serving kernels
+    under a mesh)."""
     return mesh is None or mesh.size == 1

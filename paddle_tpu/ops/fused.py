@@ -59,10 +59,12 @@ def fused_elementwise(ctx, ins, attrs):
     fx = getattr(ctx, 'forensic', None)
     if _kg.enabled() and fx is None and \
             _pallas.single_device(getattr(ctx, 'mesh', None)):
-        # a forensic lowering never hands the group to kernelgen: the
+        # PT_KERNELGEN=1 only (off by default on every backend: every
+        # launch, on one chip as under a mesh, takes the replay below
+        # with plain AD and XLA fuses it with its neighbours).  A
+        # forensic lowering never hands the group to kernelgen: the
         # whole point is probing INSIDE the fused sub-program, which a
-        # single generated kernel hides.  Production launches keep the
-        # kernel tier — only the replay runner pays the granularity tax.
+        # single generated kernel hides.
         return _kg.run_fused(ctx, ins, attrs)
     xs = ins.get('X', [])
     xs = xs if isinstance(xs, (list, tuple)) else [xs]

@@ -1,6 +1,16 @@
 """Pallas codegen tier: lower fused_elementwise sub-programs to
 generated kernels (docs/kernels.md).
 
+The tier is OFF unless ``PT_KERNELGEN=1`` asks for it, on every backend:
+a fused group then takes the inline replay with plain AD, and XLA fuses
+it with its neighbours.  On a TPU the one generated kernel Mosaic
+compiled, the ``row`` LayerNorm, cost ``tbase.train_1chip`` 3 % of its
+``train_rate`` (an opaque custom call between the residual add and the
+next matmul; PERF.md §6, PR 41), and ``1`` compiles on no chip (Mosaic
+refuses the ``ew`` kind).  What is left is a CPU test vehicle in
+interpret mode, ~9x slower than XLA fusion (ops/_pallas.py), until
+ROADMAP D4 takes it out.
+
 Entry points:
 
 * ``run_fused(ctx, ins, attrs)`` — kernel path (ops/fused.py tries this
@@ -15,15 +25,14 @@ There is no reroute: a group the tier takes on lowers or the launch
 raises (``KernelgenUnsupported`` names the sub-op; a Mosaic refusal
 surfaces when the whole step compiles).  Which *kinds* of generated
 kernel the tier takes on is ``pallas_kinds()``; sub-ops of the other
-kinds run their registered impl as plain XLA steps inside the plan.
+kinds run their registered impl as plain XLA steps inside the plan
+(``builder._build_plan(..., kinds=...)``: chip_smoke.py builds the
+``row`` plans that way to hold them to their replay through Mosaic).
 
-Env vars (docs/kernels.md has the full table): ``PT_KERNELGEN`` (unset:
-on TPU the kinds Mosaic compiles, nothing elsewhere; ``1``: every kind;
-``0``: off — the interpret-mode tier is a CPU test vehicle, ~9x slower
-than XLA fusion), ``PT_KERNELGEN_BLOCK`` (static base block size,
-default 1024), ``PT_AUTOTUNE`` (0/1/cached — kernelgen/autotune.py
-block-size search + persistence).  Interpret mode is the CPU backend's
-and only its (ops/_pallas.py).
+Env vars (docs/kernels.md has the full table): ``PT_KERNELGEN`` (``1``:
+every kind; unset or ``0``: off), ``PT_KERNELGEN_BLOCK`` (static base
+block size, default 1024), ``PT_AUTOTUNE`` (0/1/cached —
+kernelgen/autotune.py block-size search + persistence).
 """
 import os
 
@@ -34,7 +43,7 @@ from ...core.registry import register_emit
 from .._pallas import single_device
 
 __all__ = ['KERNEL_RULES', 'KernelgenUnsupported', 'KERNELGEN_VERSION',
-           'ALL_KINDS', 'TPU_DEFAULT_KINDS', 'pallas_kinds', 'enabled',
+           'ALL_KINDS', 'pallas_kinds', 'enabled',
            'config_token', 'fingerprint_extra', 'rule_names',
            'run_fused', 'run_fused_emit', 'plan_for', 'plans',
            'clear_plan_cache', 'unsupported_sub_ops']
@@ -43,24 +52,12 @@ __all__ = ['KERNEL_RULES', 'KernelgenUnsupported', 'KERNELGEN_VERSION',
 # feeds the compile-cache fingerprint and the emitter memo key
 KERNELGEN_VERSION = 3
 
-# What an unset PT_KERNELGEN turns on for a TPU backend: the kinds that
-# compile through Mosaic (chip_smoke.py's `kernels` phase compiles each).
-# 'ew' is off: Mosaic on jax 0.9.0 / libtpu 0.0.34 refuses its (1,)
-# VMEM scalars, its scalar-core pow and its in-kernel 1-D tile — the
-# messages are in PERF.md, PR 21, for ROADMAP D4.
-TPU_DEFAULT_KINDS = ('attention', 'row')
-
 
 def pallas_kinds():
     """The kinds that lower to generated Pallas kernels right now
-    (sorted tuple).  ``PT_KERNELGEN=1``: all of them; ``=0``: none;
-    unset: ``TPU_DEFAULT_KINDS`` on a TPU backend and none elsewhere,
-    where kernels would run under the Pallas interpreter — a bitwise
-    test vehicle, not a fast path."""
+    (sorted tuple): all of them under ``PT_KERNELGEN=1``, none otherwise,
+    whatever the backend."""
     v = os.environ.get('PT_KERNELGEN')
-    if v is None:
-        import jax
-        return TPU_DEFAULT_KINDS if jax.default_backend() == 'tpu' else ()
     return ALL_KINDS if v in ('1', 'true', 'True') else ()
 
 

@@ -47,13 +47,14 @@ def test_phase_train_resnet50():
 
 
 def test_phase_kernels(monkeypatch):
-    """Every kind of generated kernel on (PT_KERNELGEN=1, as on no chip
-    yet), and the shape gates lowered so that the tiny shapes take the
-    routes the full shapes take: Pallas forward, both dK/dV kernels, the
-    DMA gather."""
+    """The shape gates lowered so that the tiny shapes take the routes
+    the full shapes take: Pallas forward, both dK/dV kernels, the DMA
+    gather.  The tier's plans are built for the kinds Mosaic compiles
+    (chip_smoke.MOSAIC_KINDS) whatever the default, which the phase
+    reports: off."""
     from paddle_tpu.ops import attention, gather
     flash = TINY['kernels']['flash']
-    monkeypatch.setenv('PT_KERNELGEN', '1')
+    monkeypatch.delenv('PT_KERNELGEN', raising=False)
     monkeypatch.setattr(attention, '_FWD_PALLAS_MIN_T',
                         flash['seq_resident'])
     monkeypatch.setattr(attention, '_BWD_PALLAS_SCORE_BYTES', 0)
@@ -61,10 +62,13 @@ def test_phase_kernels(monkeypatch):
                         flash['seq_resident'])
     monkeypatch.setattr(gather, '_MIN_ROWS', TINY['kernels']['gather']['rows'])
     out = chip_smoke.kernels(TINY['kernels'])
-    assert out['kinds_off'] == []
-    # the LayerNorm rows, the softmax, the attention group, the fused Adam
-    assert out['plans']['plans'] >= 4
-    assert out['plans']['pallas_kernels'] >= 4
+    assert out['kinds_on'] == []
+    assert out['kinds_off'] == ['attention', 'ew', 'row']
+    # the LayerNorm rows, the softmax, the attention group; the fused Adam
+    # and the elementwise chains are plans of XLA steps
+    assert out['plans']['plans'] >= 3
+    assert out['plans']['pallas_kernels'] >= 3
+    assert out['plans']['xla_only_plans'] >= 1
     assert out['gather']['bitwise']
 
 

@@ -1,8 +1,8 @@
 """Pallas codegen tier (ops/kernelgen): per-rule bitwise parity vs the
 reference replay, the fused-Adam single-kernel contract, the no-reroute
-contract and the per-kind TPU default, emitter/launch-signature integration, AOT
-disk-cache round trip, and end-to-end parity through run / run_steps /
-ParallelExecutor under AMP + dropout.
+contract and the default (off on every backend), emitter/launch-signature
+integration, AOT disk-cache round trip, and end-to-end parity through run /
+run_steps / ParallelExecutor under AMP + dropout.
 
 Parity contract (docs/kernels.md): a generated kernel is BITWISE equal
 to the jitted replay of the same fused group — both lower through XLA,
@@ -599,24 +599,100 @@ def test_autotune_off_mode_and_lint_ctx_never_time(monkeypatch):
 # ------------------------------------------- which kinds are on where
 
 def test_kinds_default_per_backend(monkeypatch):
+    """Unset, the tier is off on every backend (PR 41): the one-chip step
+    takes the inline replay the mesh step has taken since PR 26."""
     monkeypatch.delenv('PT_KERNELGEN', raising=False)
     assert kg.pallas_kinds() == () and not kg.enabled(), \
         'CPU session: tier defaults OFF'
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
-    assert kg.pallas_kinds() == kg.TPU_DEFAULT_KINDS and kg.enabled()
-    assert 'ew' not in kg.TPU_DEFAULT_KINDS, \
-        'Mosaic refuses the flat elementwise kernel (PERF.md PR 21)'
+    assert kg.pallas_kinds() == () and not kg.enabled(), \
+        'TPU: the row kernel cost tbase.train_1chip 3 % (PERF.md PR 41)'
     monkeypatch.setenv('PT_KERNELGEN', '0')
-    assert not kg.enabled(), 'explicit 0 wins on TPU'
+    assert not kg.enabled(), 'explicit 0 is the default, spelled out'
     monkeypatch.setattr(jax, 'default_backend', lambda: 'cpu')
     monkeypatch.setenv('PT_KERNELGEN', '1')
     assert kg.pallas_kinds() == kg.ALL_KINDS, 'explicit 1: every kind'
 
 
+def _default_route_model():
+    """One LayerNorm group, one [elementwise_add, relu] group and one
+    fused Adam group: the three shapes of fused group the training cells
+    hold (tbase's LayerNorms, ResNet-50's block tails, both optimizers)."""
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 41
+    with fluid.program_guard(main, startup):
+        with fluid.unique_name.guard():
+            x = fluid.layers.data('x', shape=[8], dtype='float32')
+            lbl = fluid.layers.data('lbl', shape=[1], dtype='int64')
+            h = fluid.layers.fc(x, 16)
+            h = fluid.layers.layer_norm(fluid.layers.elementwise_add(h, h))
+            h = fluid.layers.fc(h, 16, act='relu')
+            loss = fluid.layers.mean(
+                fluid.layers.softmax_with_cross_entropy(
+                    fluid.layers.fc(h, 4), lbl))
+            fluid.optimizer.Adam(0.01).minimize(loss)
+    main.set_amp(True)
+    return main, startup, loss
+
+
+def _step_jaxpr(main, scope, feed, fetch_names, emit):
+    """The step as `Executor._prepare_entry` lowers it (the rewriter, the
+    emitter's engine or the traced path, `_lower`), traced to its jaxpr
+    and never compiled: a mocked backend has no device to run on."""
+    from paddle_tpu.core import emit as _emit
+    from paddle_tpu.core import executor as em
+    from paddle_tpu.core import passes
+    from paddle_tpu.core.emit import emitter
+    emitter.clear_memo()            # or the second build is the first's
+    feed_names = tuple(sorted(feed))
+    opt, _ = passes.maybe_optimize(main, fetch_names)
+    engine = _emit.build_engine(opt, feed_names, fetch_names) if emit \
+        else None
+    jit_fn, params_in, _ = em._lower(opt, feed_names, fetch_names,
+                                     emit_engine=engine)
+    args = ({n: scope.vars[n] for n in params_in}, feed, np.uint32(0))
+    return opt, str(jit_fn.trace(*args).jaxpr)
+
+
+@pytest.mark.parametrize('emit', [True, False], ids=['emit', 'trace'])
+def test_default_step_is_the_replay_on_a_tpu_backend(monkeypatch, emit):
+    """Through both entries (`_emit_fused`, ops/fused.py) the step an unset
+    PT_KERNELGEN lowers for a TPU backend is, text for text, the one
+    under PT_KERNELGEN=0: no plan, no `custom_vjp` around a group, no
+    `pallas_call`; and the tier's counters do not move."""
+    main, startup, loss = _default_route_model()
+    scope = fluid.Scope()
+    fluid.Executor().run(startup, scope=scope)
+    feed = {k: jnp.asarray(v) for k, v in _feeds(1)[0].items()}
+    monkeypatch.setenv('PT_CACHE', '0')
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    kg.clear_plan_cache()
+    before = obs.counters()
+
+    monkeypatch.delenv('PT_KERNELGEN')
+    opt, default = _step_jaxpr(main, scope, feed, (loss.name,), emit)
+    monkeypatch.setenv('PT_KERNELGEN', '0')
+    _, off = _step_jaxpr(main, scope, feed, (loss.name,), emit)
+
+    groups = {tuple(sub['type'] for sub in op.attrs['sub_ops'])
+              for op in opt.global_block().ops
+              if op.type == 'fused_elementwise'}
+    assert ('elementwise_add', 'relu') in groups, groups
+    assert any('layer_norm' in g for g in groups), groups
+    assert any(set(g) == {'adam'} and len(g) > 1 for g in groups), groups
+    assert default == off
+    assert 'pallas_call' not in default and 'custom_vjp' not in default
+    after = obs.counters()
+    for name in ('kernelgen.ops', 'kernelgen.kernels'):
+        assert after.get(name, 0) == before.get(name, 0), name
+    assert kg.plans() == []
+
+
 def test_kind_off_runs_as_xla_step_bitwise(monkeypatch):
-    """With 'ew' off (the TPU default) a group's elementwise sub-ops run
-    their registered impl as XLA steps while its layer_norm stays a
-    generated row kernel — bitwise the all-kinds plan and the replay."""
+    """With 'ew' off (chip_smoke.py's MOSAIC_KINDS: what Mosaic
+    compiles) a group's elementwise sub-ops run their registered impl as
+    XLA steps while its layer_norm stays a generated row kernel — bitwise
+    the all-kinds plan and the replay."""
     monkeypatch.setenv('PT_KERNELGEN', '1')
     rng = np.random.RandomState(5)
     x, y = _rand(rng, (6, 16)), _rand(rng, (6, 16))
@@ -631,7 +707,7 @@ def test_kind_off_runs_as_xla_step_bitwise(monkeypatch):
     avals = kg._in_avals([x, y, s, b])
     full = builder._build_plan(attrs, avals, False)
     part = builder._build_plan(attrs, avals, False,
-                               kinds=kg.TPU_DEFAULT_KINDS)
+                               kinds=('attention', 'row'))
     assert (full.n_kernels, full.n_dsteps, full.n_xla) == (1, 1, 0)
     assert (part.n_kernels, part.n_dsteps, part.n_xla) == (0, 1, 1)
     a, = full.fn((x, y, s, b), ())

@@ -242,3 +242,63 @@ def test_short_attention_keeps_its_scores_on_chip_at_tbase_widths(
     for whole in ('[%d,%d,%d,%d]' % (B, H, T, T),
                   '[%d,%d,1,%d,%d]' % (B, H, T, T)):
         assert whole not in text
+
+
+# temp_size_in_bytes of the same step at the parent of PR 41, where an
+# unset PT_KERNELGEN put 32 generated `row` LayerNorm kernels into it; the
+# replay reads 8,173,700,096 (+0.70 %, 56 MB of 8.1 GB: offline compiles,
+# PR 41; on the chip the cell's `memory_peak_bytes` is in PERF.md section 6)
+_TBASE_STEP_TEMP_BYTES_WITH_ROW_KERNELS = 8117223424
+
+
+def test_the_one_chip_tbase_step_holds_no_kernel_of_the_tier(
+        one_v5e_chip, monkeypatch):
+    """tbase.train_1chip's step (96 x 256 tokens, AMP, Adam; one step of
+    the K=8 scan) as XLA:TPU compiles it for one v5e chip with
+    PT_KERNELGEN unset: every fused group took the inline replay, so the
+    only Mosaic calls left are the embedding tables' two DMA gathers
+    (`ops/gather.py`, outside the tier), and the step needs the scratch
+    it needed around the opaque LayerNorm calls, to within 1 %."""
+    import jax
+    import paddle_tpu as fluid
+    from paddle_tpu.core import emit, passes
+    from paddle_tpu.core import executor as em
+    from paddle_tpu.models import transformer as tr
+    monkeypatch.delenv('PT_KERNELGEN', raising=False)
+    monkeypatch.setenv('PT_CACHE', '0')
+    batch, seq, vocab = 96, 256, 32000
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        with fluid.unique_name.guard():
+            out = tr.build(src_vocab=vocab, trg_vocab=vocab, max_len=seq,
+                           n_layer=6, n_head=8, d_model=512, d_inner=2048,
+                           dropout=0.0, lr=2.0, warmup_steps=4000,
+                           use_flash=True)
+    main.set_amp(True)
+    scope = fluid.Scope()
+    fluid.Executor().run(startup, scope=scope)      # the state's shapes
+    feed = tr.synthetic_batch(np.random.RandomState(0), batch, seq, vocab)
+
+    # repo code asks the backend whether to interpret its Pallas calls
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    feed_names, fetch_names = tuple(sorted(feed)), (out['loss'].name,)
+    opt, _ = passes.maybe_optimize(main, fetch_names)
+    jit_fn, params_in, _ = em._lower(
+        opt, feed_names, fetch_names,
+        emit_engine=emit.build_engine(opt, feed_names, fetch_names))
+
+    def sds(v):
+        return jax.ShapeDtypeStruct(np.shape(v), v.dtype,
+                                    sharding=one_v5e_chip)
+
+    compiled = jit_fn.lower(
+        {n: sds(scope.vars[n]) for n in params_in},
+        {n: sds(feed[n]) for n in feed_names},
+        jax.ShapeDtypeStruct((), jnp.uint32,
+                             sharding=one_v5e_chip)).compile()
+    mosaic = [ln for ln in compiled.as_text().splitlines()
+              if 'tpu_custom_call' in ln]
+    assert len(mosaic) == 2 and all('lookup_table' in ln for ln in mosaic), \
+        [ln.split('metadata=')[-1][:120] for ln in mosaic]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 1.01 * _TBASE_STEP_TEMP_BYTES_WITH_ROW_KERNELS, temp
