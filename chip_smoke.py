@@ -12,7 +12,8 @@ the repo benchmarks, with seeded random weights:
   train_resnet50     ResNet-50, 224x224, 1000 classes, B=128, AMP: three
                      steps (the conv / bf16 flow-through side)
   kernels            every Pallas kernel a launch can select (flash,
-                     DMA gather, ssm_step) and the kernel tier's `row`
+                     DMA gather, ssm_step, latent_attention) and the kernel
+                     tier's `row`
                      plans, which no launch selects since PR 41, compiled
                      by Mosaic and compared with their references
   serve              GenerationEngine over DecodeRuntime at the llama_1b
@@ -57,6 +58,11 @@ SIZES = {
             gather=dict(rows=8192, vocab=32000, width=512),
             # the falconh1_34b cell's scan state, 15 of 32 slots live
             ssm_step=dict(state=(32, 6, 32, 128, 256), groups=2, live=15),
+            # the axk1 cell's latent pool: 64 heads over one 640-wide row
+            latent=dict(slots=8, heads=64, v_dim=512, width=640, page_len=16,
+                        pages=2049, layers=2, max_pages=449,
+                        lengths=(0, 1, 16, 300, 4097, 5000, 7184, 777),
+                        dtype='bfloat16', tol=2e-2),
             softmax=(32, 8, 256, 256),
             # transformer-base widths, one layer: layers share their
             # fused-group signatures, so one layer builds every plan
@@ -79,6 +85,9 @@ SIZES = {
                        seq_resident=128, seq_streamed=256),
             gather=dict(rows=256, vocab=512, width=128),
             ssm_step=dict(state=(4, 2, 8, 16, 128), groups=2, live=2),
+            latent=dict(slots=4, heads=4, v_dim=128, width=256, page_len=4,
+                        pages=41, layers=2, max_pages=6,
+                        lengths=(0, 1, 13, 24), dtype='float32', tol=2e-5),
             softmax=(2, 2, 16, 16),
             groups=dict(n_layer=1, d_model=32, n_head=2, d_inner=64,
                         vocab=128, batch=2, seq=16)),
@@ -443,6 +452,47 @@ def _ssm_step_check(cfg):
     return out
 
 
+def _latent_attention_check(cfg):
+    """`latent_attention` (one decode step's attention over a latent
+    pool in place: every head reads the one row a token has) against
+    `latent_attention_composed` on gathered rows: a dead slot (it reads
+    nothing and gets zeros), one position, lengths that end on and
+    inside a page and a block, a full slot."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention as att
+    S, H, V, W = cfg['slots'], cfg['heads'], cfg['v_dim'], cfg['width']
+    PL, M, dt = cfg['page_len'], cfg['max_pages'], jnp.dtype(cfg['dtype'])
+    shape = (cfg['pages'], cfg['layers'], PL, W)
+    assert att.latent_attention_eligible(shape, dt, V), \
+        'smoke shape is not eligible'
+    rng = np.random.RandomState(SEED)
+    pool = jax.random.normal(jax.random.key(SEED), shape, jnp.float32) \
+        .astype(dt)
+    # every slot its own pages, in no order (page 0 is never mapped)
+    bt = np.stack([rng.permutation(np.arange(1, cfg['pages']))[:M]
+                   for _ in range(S)]).astype(np.int32)
+    n = jnp.asarray(cfg['lengths'], jnp.int32)
+    q_lat = jnp.asarray(rng.randn(S, H, V), jnp.float32)
+    q_r = jnp.asarray(rng.randn(S, H, W - V), jnp.float32)
+    layer, scale = cfg['layers'] - 1, 0.05
+
+    def kernel(pool, bt, n):
+        return att.latent_attention(q_lat, q_r, pool, bt, n, layer, scale)
+
+    compiled, n_calls = _mosaic_calls(kernel, pool, jnp.asarray(bt), n)
+    _assert_mosaic('latent_attention', n_calls, 1)
+    got = np.asarray(compiled(pool, jnp.asarray(bt), n))
+    rows = pool[jnp.asarray(bt), layer].reshape(S, M * PL, W)
+    want = np.asarray(att.latent_attention_composed(
+        q_lat[:, :, None], q_r[:, :, None], rows, (n - 1)[:, None],
+        scale)[:, :, 0])
+    live = np.asarray(n) > 0
+    np.testing.assert_array_equal(got[~live], 0.0)
+    return {'slots': int(live.sum()), 'err': float('%.2e' % _close(
+        'latent_attention', got[live], want[live], cfg['tol']))}
+
+
 def _run_softmax_group(fluid, shape):
     """A program whose fused group holds a softmax, so the `row` kind's
     other kernel has a plan to check."""
@@ -531,6 +581,7 @@ def kernels(cfg):
         'flash_streamed': _flash_check(flash, flash['seq_streamed']),
         'gather': _gather_check(cfg['gather']),
         'ssm_step': _ssm_step_check(cfg['ssm_step']),
+        'latent_attention': _latent_attention_check(cfg['latent']),
     }
     # The kernel tier is off by default on every backend (PR 41: its one
     # Mosaic kernel cost tbase.train_1chip 3 % of its rate), and

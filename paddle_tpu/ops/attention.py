@@ -836,3 +836,197 @@ def paged_attention(q, kpool, vpool, block_tables, n_attend, layer,
       jnp.asarray(layer, jnp.int32).reshape(1), q, jnp.asarray(koff),
       jnp.asarray(hbias), kpool.reshape(pages, layers, PR, D),
       vpool.reshape(pages, layers, PR, D))
+
+
+# -------------------------------- latent decode-step attention, in place
+
+_LATENT_BLOCK_TOKENS = 256  # key positions one double-buffered block holds
+
+
+def latent_attention_eligible(pool_shape, dtype, v_dim, mesh=None):
+    """Static rule for `latent_attention` over a ``[pages, layers,
+    page_len, width]`` pool whose rows serve as keys (all ``width``
+    columns) and as values (the first ``v_dim``): a floating pool on a
+    single device; on an accelerator the value columns and the rest of
+    the row must each start on a lane tile, and one page of one layer,
+    ``[page_len, width]``, must be whole sublane tiles of the pool's
+    dtype."""
+    dtype = jnp.dtype(dtype)
+    if not jnp.issubdtype(dtype, jnp.floating) \
+            or not _pallas.single_device(mesh):
+        return False
+    if _pallas.interpret():
+        return True
+    _pages, _layers, page_len, width = pool_shape
+    sublanes = 8 * (4 // dtype.itemsize)
+    return int(v_dim) % 128 == 0 and width % 128 == 0 \
+        and page_len % sublanes == 0
+
+
+def latent_attention_composed(q_lat, q_rope, rows, qpos, scale):
+    """The absorbed form on GATHERED rows: q_lat [B, H, Tq, v_dim] and
+    q_rope [B, H, Tq, r] against rows [B, Tk, v_dim + r] that every
+    head shares; key position kpos is visible iff ``kpos <= qpos`` (qpos
+    [B, Tq]).  Returns [B, H, Tq, v_dim] float32: the probabilities'
+    sum over the rows' first ``v_dim`` columns.  What `latent_attention`
+    is tested against, and the step's route under a mesh."""
+    v_dim = q_lat.shape[-1]
+    ckv, kr = rows[..., :v_dim], rows[..., v_dim:]
+    s = (jnp.einsum('bhqc,bkc->bhqk', q_lat.astype(rows.dtype), ckv,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum('bhqr,bkr->bhqk', q_rope.astype(rows.dtype), kr,
+                      preferred_element_type=jnp.float32)) * scale
+    mask = jnp.arange(rows.shape[1])[None, None, :] <= qpos[:, :, None]
+    s = jnp.where(mask[:, None], s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum('bhqk,bkc->bhqc', p.astype(rows.dtype), ckv,
+                      preferred_element_type=jnp.float32)
+
+
+def _latent_kernel(bt_ref, n_ref, layer_ref, ql_ref, qr_ref, pool_hbm, o_ref,
+                   buf, sem, *, pages_per_block, page_len, max_pages, v_dim,
+                   scale):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s = pl.program_id(0)
+    n = n_ref[s]                          # positions this slot attends
+    layer = layer_ref[0]
+    P, PL = pages_per_block, page_len
+    block_tokens = P * PL
+    n_blocks = jax.lax.div(n + block_tokens - 1, block_tokens)
+    n_pages = jax.lax.div(n + PL - 1, PL)
+
+    def page_copies(blk, slot):
+        """(covered, copy) per page of block ``blk``: one page of one
+        layer is ``[page_len, width]``, contiguous in the pool, and
+        lands at its rows of the buffer."""
+        out = []
+        for p in range(P):
+            idx = blk * P + p
+            page = bt_ref[s * max_pages + jnp.minimum(idx, max_pages - 1)]
+            out.append((idx < n_pages, pltpu.make_async_copy(
+                pool_hbm.at[page, layer], buf.at[slot, pl.ds(p * PL, PL)],
+                sem.at[slot])))
+        return out
+
+    def start(blk, slot):
+        for covered, copy in page_copies(blk, slot):
+            @pl.when(covered)
+            def _():
+                copy.start()
+
+    def wait(blk, slot):
+        for covered, copy in page_copies(blk, slot):
+            @pl.when(covered)
+            def _():
+                copy.wait()
+
+    @pl.when(s == 0)
+    def _():
+        # rows no copy ever fills meet a probability of exactly 0 in the
+        # value product; whatever VMEM held there must not be a NaN
+        buf[...] = jnp.zeros_like(buf)
+
+    @pl.when(n > 0)
+    def _():
+        start(0, 0)
+
+    H = ql_ref.shape[1]
+    ql, qr = ql_ref[0], qr_ref[0]                       # [H, v_dim], [H, r]
+
+    def body(blk, carry):
+        m, l, acc = carry
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _():
+            start(blk + 1, 1 - slot)
+
+        wait(blk, slot)
+        ckv = buf[slot, :, :v_dim]                      # [R, v_dim]
+        kr = buf[slot, :, v_dim:]                       # [R, r]
+        # every head against the one row a token has: the compressed
+        # part and the rotated key are two products over one score
+        sc = (jax.lax.dot_general(
+            ql, ckv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) + jax.lax.dot_general(
+            qr, kr, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)) * scale  # [H, R]
+        kpos = blk * block_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, sc.shape, 1)
+        sc = jnp.where(kpos < n, sc, _NEG_INF)
+        m_new = jnp.maximum(m, sc.max(axis=1, keepdims=True))
+        # a block the loop reaches holds a visible position, so m_new
+        # is a real score and a masked row's exp is 0
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = alpha * l + p.sum(axis=1, keepdims=True)
+        acc_new = acc * alpha + jnp.dot(
+            p.astype(ckv.dtype), ckv, preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_new
+
+    m, l, acc = jax.lax.fori_loop(
+        0, n_blocks, body,
+        (jnp.full((H, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((H, 1), jnp.float32),
+         jnp.zeros((H, v_dim), jnp.float32)))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
+def latent_attention(q_lat, q_rope, pool, block_tables, n_attend, layer,
+                     scale, pages_per_block=None):
+    """One decode step's latent attention over the page pool IN PLACE.
+
+    q_lat: [S, H, v_dim], each head's query carried into the compressed
+    space (absorbed through the key half of the up-projection); q_rope:
+    [S, H, r], its rotated part; pool: [pages, layers, page_len, v_dim +
+    r], ONE row a token a layer that every head shares, the step's own
+    row already written: a row's ``v_dim + r`` columns are the key, its
+    first ``v_dim`` the value; block_tables: [S, max_pages] int32;
+    n_attend: [S] int32 positions each slot attends (0 for a slot that
+    rides along: it reads nothing and returns zeros); layer: int32
+    scalar.  Returns [S, H, v_dim] float32.
+
+    The pool stays in HBM and XLA never slices it: per slot the kernel
+    copies the pages its length covers ONCE, ``pages_per_block`` at a
+    time and double-buffered, through the block table (scalar
+    prefetch), and all H heads read that one copy: scores over the
+    whole row, an online softmax with f32 statistics, probabilities
+    cast to the pool's dtype for the value product (as
+    `latent_attention_composed`).  There is no V pool and no per-head
+    key anywhere.
+    """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    S, H, v_dim = q_lat.shape
+    pages, layers, PL, width = pool.shape
+    M = block_tables.shape[1]
+    P = pages_per_block or max(1, _LATENT_BLOCK_TOKENS // PL)
+    P = min(int(P), M)
+    R = P * PL
+    kernel = functools.partial(
+        _latent_kernel, pages_per_block=P, page_len=PL, max_pages=M,
+        v_dim=v_dim, scale=float(scale))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[
+            pl.BlockSpec((1, H, v_dim), lambda s, *_: (s, 0, 0)),
+            pl.BlockSpec((1, H, width - v_dim), lambda s, *_: (s, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, H, v_dim), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, R, width), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
+    )
+    n = jnp.clip(n_attend.astype(jnp.int32), 0, M * PL)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, v_dim), jnp.float32),
+        name='latent_attention',
+        interpret=_pallas.interpret(),
+    )(block_tables.reshape(-1).astype(jnp.int32), n,
+      jnp.asarray(layer, jnp.int32).reshape(1), q_lat.astype(pool.dtype),
+      q_rope.astype(pool.dtype), pool)

@@ -20,6 +20,13 @@ decode server (docs/generation.md):
     runs from and into a slot's recurrent state, and the single step of
     a decode window; that state lives beside the page pool in the same
     donated state dict (`CacheConfig.recurrent`).
+  * `latent`, `experts` — the `latent_moe` block kind (a model dict
+    with ``block: 'latent_moe'``): multi-head latent attention over the
+    second pool geometry, ONE row ``[c_kv ; k_r]`` a token a layer
+    (`CacheConfig.latent`), expanded in a prefill chunk and absorbed in
+    a decode step; and per layer (``cfg['ffn']``) a dense SwiGLU or
+    routed experts beside a shared one, as one expert-parallel rank
+    holds them.
   * `sampling` — greedy / temperature / top-k draws keyed by
     ``(request seed, absolute position)`` only, so fused and sequential
     decode sample bitwise-identical streams (ops/sampling.py).
@@ -43,7 +50,8 @@ decode server (docs/generation.md):
 from .kv_cache import (CacheConfig, PagePool, PrefixCache,  # noqa
                        SlotAllocator, default_page_len, init_state)
 from .decode import (DecodeRuntime, dense_reference,  # noqa
-                     random_weights, weight_names)
+                     random_weights, weight_names, weight_shapes)
+from . import experts, latent, ssm  # noqa
 from .sampling import SamplingParams, draft_ngram  # noqa
 from .streaming import TokenStream  # noqa
 from .scheduler import GenerationConfig, GenerationEngine  # noqa
@@ -51,5 +59,6 @@ from .scheduler import GenerationConfig, GenerationEngine  # noqa
 __all__ = ['CacheConfig', 'PagePool', 'PrefixCache', 'SlotAllocator',
            'default_page_len', 'init_state', 'DecodeRuntime',
            'dense_reference', 'random_weights', 'weight_names',
+           'weight_shapes', 'experts', 'latent', 'ssm',
            'SamplingParams', 'draft_ngram', 'TokenStream',
            'GenerationConfig', 'GenerationEngine']

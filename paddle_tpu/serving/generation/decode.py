@@ -99,6 +99,28 @@ was, bit for bit.  That state can neither be
 shared between prompts nor rolled back, so such a runtime takes no
 prefix-cache hit (`generation.prefix_refused_recurrent` counts the
 begins), no speculative window and no ring prefill.
+
+``block: 'latent_moe'`` is the third kind: multi-head LATENT attention
+(latent.py) and, per layer as ``cfg['ffn']`` says, a dense SwiGLU or
+routed experts beside a shared one, held as ONE expert-parallel rank
+(experts.py; ``cfg['moe']`` says which of ``ranks`` this is).  Its
+weights have other names and shapes (`weight_shapes`), its prepared
+forms are not per-head q/k/v (`latent.PREPARED`: the up-projection
+split into its key and value halves for absorption, rope columns as
+rotated halves), and its pool has the second geometry: ONE row
+``[c_kv ; k_r]`` a token a layer and no V pool (`CacheConfig.latent`),
+under the same pages, block tables and prefix cache.  A prefill chunk
+expands keys and values from the gathered rows; a decode step attends
+in the absorbed form over the pool in place
+(`ops.attention.latent_attention`; gathered rows under a mesh:
+`DecodeRuntime.paged`).  The residual stream is float32.  Its two
+launches hand back, beside their tokens, a few counts
+(`_LAUNCH_STATS`: routing's and the latent rows read) that move into
+``generation.moe_*`` / ``generation.latent_rows_read`` behind the next
+read of a result (`DecodeRuntime._count_stats`): no launch and no wait
+of their own.  No ring prefill and no int8 rows.  The other two kinds'
+programs are untouched by it, bit for bit (tests/
+test_generation_pipeline.py pins their lowered text).
 """
 import threading
 from collections.abc import Mapping
@@ -107,10 +129,12 @@ import numpy as np
 
 from ... import observability as _obs
 from ...core import compile_cache as _cc
-from ...ops.attention import (cached_attention, paged_attention,
-                              paged_attention_eligible,
+from ...ops.attention import (cached_attention, latent_attention_eligible,
+                              paged_attention, paged_attention_eligible,
                               paged_attention_rows)
 from ...ops.sampling import sample_logits, sample_tokens_at, token_key
+from . import experts as _experts
+from . import latent as _latent
 from . import ssm as _ssm
 from .kv_cache import (CacheConfig, PagePool, PrefixCache, SlotAllocator,
                        init_state)
@@ -122,13 +146,42 @@ _WEIGHT_SLOTS = ('att_q_w', 'att_k_w', 'att_v_w', 'att_o_w', 'att_norm',
                  'ffn_norm', 'ffn_fc1_w', 'ffn_fc2_w', 'ffn_fc3_w')
 
 
-def _recurrent(cfg):
-    """Whether the model's block carries recurrent state (ssm.py)."""
+_BLOCKS = ('dense', 'falcon_h1', 'latent_moe')
+
+
+def _block(cfg):
+    """The model's block kind: ``'dense'`` (the default: GQA and one
+    SwiGLU), ``'falcon_h1'`` (the same beside a Mamba-2 mixer, ssm.py) or
+    ``'latent_moe'`` (latent attention, latent.py, and per layer the
+    feed-forward ``cfg['ffn']`` names, experts.py)."""
     block = cfg.get('block', 'dense')
-    if block not in ('dense', 'falcon_h1'):
-        raise ValueError("block must be 'dense' or 'falcon_h1', got %r"
-                         % (block,))
-    return block == 'falcon_h1'
+    if block not in _BLOCKS:
+        raise ValueError('block must be one of %s, got %r'
+                         % (', '.join(map(repr, _BLOCKS)), block))
+    return block
+
+
+def _recurrent(cfg):
+    """Whether the model's block carries recurrent state: of the three
+    kinds (`_block`) only ``'falcon_h1'`` does (ssm.py)."""
+    return _block(cfg) == 'falcon_h1'
+
+
+def _latent_moe(cfg):
+    """Whether the model's block is the ``'latent_moe'`` kind."""
+    return _block(cfg) == 'latent_moe'
+
+
+def _ffn_kinds(cfg):
+    """A ``latent_moe`` model's feed-forward kind per layer: ``cfg['ffn']``,
+    ``'dense'`` or ``'experts'`` for each of ``n_layer``."""
+    kinds = tuple(cfg['ffn'])
+    if len(kinds) != int(cfg['n_layer']) \
+            or any(k not in ('dense', 'experts') for k in kinds):
+        raise ValueError("ffn must name 'dense' or 'experts' for each of "
+                         'the %d layers, got %r' % (int(cfg['n_layer']),
+                                                    kinds))
+    return kinds
 
 
 def _head_dim(cfg):
@@ -138,7 +191,12 @@ def _head_dim(cfg):
 def weight_names(cfg):
     """The decode-side parameter names — the same names a trained llama
     program leaves in its scope (models/llama.py layout); a
-    ``falcon_h1`` block adds its mixer's (`ssm.SLOTS`)."""
+    ``falcon_h1`` block adds its mixer's (`ssm.SLOTS`).  A ``latent_moe``
+    block has its own: per layer the two norms, latent attention's
+    (`latent.SLOTS`) and, by the layer's feed-forward kind, the dense
+    SwiGLU's or the expert layer's (`experts.SLOTS`)."""
+    if _latent_moe(cfg):
+        return list(_latent_moe_shapes(cfg))
     slots = _WEIGHT_SLOTS + (_ssm.SLOTS if _recurrent(cfg) else ())
     names = ['tok_emb', 'final_norm', 'lm_proj_w']
     for i in range(int(cfg['n_layer'])):
@@ -149,6 +207,8 @@ def weight_names(cfg):
 def weight_shapes(cfg):
     """{name: shape} of every weight under `weight_names(cfg)`, in the
     public layout (a projection is ``[in, out]``)."""
+    if _latent_moe(cfg):
+        return _latent_moe_shapes(cfg)
     d, v, h = int(cfg['d_model']), int(cfg['vocab']), int(cfg['n_head'])
     hkv, f = int(cfg['n_kv_head']), int(cfg['d_ffn'])
     dh = _head_dim(cfg)
@@ -164,6 +224,26 @@ def weight_shapes(cfg):
                        p + 'ffn_fc1_w': (d, f), p + 'ffn_fc3_w': (d, f),
                        p + 'ffn_fc2_w': (f, d)})
         shapes.update((p + k, s) for k, s in mixer.items())
+    return shapes
+
+
+def _latent_moe_shapes(cfg):
+    """`weight_shapes` of a ``latent_moe`` model, in `weight_names`'
+    order."""
+    d, v = int(cfg['d_model']), int(cfg['vocab'])
+    att = _latent.weight_shapes(d, int(cfg['n_head']), cfg['latent'])
+    shapes = {'tok_emb': (v, d), 'final_norm': (d,), 'lm_proj_w': (d, v)}
+    for i, kind in enumerate(_ffn_kinds(cfg)):
+        p = 'layer_%d_' % i
+        shapes.update({p + 'att_norm': (d,), p + 'ffn_norm': (d,)})
+        shapes.update((p + k, s) for k, s in att.items())
+        if kind == 'dense':
+            f = int(cfg['d_ffn'])
+            shapes.update({p + 'ffn_fc1_w': (d, f), p + 'ffn_fc3_w': (d, f),
+                           p + 'ffn_fc2_w': (f, d)})
+        else:
+            shapes.update((p + k, s) for k, s in
+                          _experts.weight_shapes(d, cfg['moe']).items())
     return shapes
 
 
@@ -223,10 +303,33 @@ def _public_rows(k, dh):
 
 def _prepared_names(cfg):
     """{public name: (slot, the executables' name for its prepared
-    form)} of the weights the runtime keeps prepared."""
+    form)} of the weights the runtime keeps prepared.  A ``latent_moe``
+    model's are latent attention's (`latent.PREPARED`): a public weight
+    there has one or two prepared parts, and the second entry is the
+    tuple of their names."""
+    if _latent_moe(cfg):
+        return {'layer_%d_%s' % (i, slot):
+                (slot, tuple('layer_%d_%s' % (i, t) for t in stored))
+                for i in range(int(cfg['n_layer']))
+                for slot, stored in _latent.PREPARED.items()}
     return {'layer_%d_%s' % (i, slot): (slot, 'layer_%d_%s' % (i, stored))
             for i in range(int(cfg['n_layer']))
             for slot, stored in _PREPARED.items()}
+
+
+def _prepared_arrays(params, cfg):
+    """Every prepared array of ``params``, a flat list."""
+    out = []
+    for _slot, stored in _prepared_names(cfg).values():
+        out.extend(params[n] for n in
+                   (stored if isinstance(stored, tuple) else (stored,)))
+    return out
+
+
+def _latent_dims(cfg):
+    lat = cfg['latent']
+    return (int(cfg['n_head']), int(lat['nope']), int(lat['rope']),
+            int(lat['v']))
 
 
 def _params_from(weights, cfg):
@@ -237,10 +340,21 @@ def _params_from(weights, cfg):
     and v are held only while that layer is prepared."""
     import jax
     import jax.numpy as jnp
-    prepared, dh = _prepared_names(cfg), _head_dim(cfg)
-    prepare = jax.jit(_prepare_qkv, static_argnums=3)
+    prepared = _prepared_names(cfg)
     params = {n: jnp.asarray(weights[n]) for n in weight_names(cfg)
               if n not in prepared}
+    if _latent_moe(cfg):
+        # latent attention's: W_qb, W_kva, W_kvb -> `latent.PREPARED`
+        prepare = jax.jit(_latent.prepare, static_argnums=(3, 4, 5, 6))
+        stored = [t for parts in _latent.PREPARED.values() for t in parts]
+        for i in range(int(cfg['n_layer'])):
+            p = 'layer_%d_' % i
+            made = prepare(*(jnp.asarray(weights[p + s])
+                             for s in _latent.PREPARED), *_latent_dims(cfg))
+            params.update(zip((p + t for t in stored), made))
+        return params
+    dh = _head_dim(cfg)
+    prepare = jax.jit(_prepare_qkv, static_argnums=3)
     for i in range(int(cfg['n_layer'])):
         p = 'layer_%d_' % i
         made = prepare(*(jnp.asarray(weights[p + s]) for s in _PREPARED), dh)
@@ -259,13 +373,22 @@ class _PublicWeights(Mapping):
     def __init__(self, params, cfg):
         import jax
         self._params, self._names = params, weight_names(cfg)
-        self._prepared, self._dh = _prepared_names(cfg), _head_dim(cfg)
-        self._undo = jax.jit(_public_weight, static_argnums=(0, 2))
+        self._prepared = _prepared_names(cfg)
+        if _latent_moe(cfg):
+            self._dims = _latent_dims(cfg)
+            self._undo = jax.jit(_latent.public,
+                                 static_argnums=(0, 2, 3, 4, 5))
+        else:
+            self._dh = _head_dim(cfg)
+            self._undo = jax.jit(_public_weight, static_argnums=(0, 2))
 
     def __getitem__(self, name):
         if name not in self._prepared:
             return self._params[name]
         slot, stored = self._prepared[name]
+        if isinstance(stored, tuple):
+            return self._undo(slot, tuple(self._params[t] for t in stored),
+                              *self._dims)
         return self._undo(slot, self._params[stored], self._dh)
 
     def __iter__(self):
@@ -434,6 +557,25 @@ def _gathered_rows(cache, st, bt):
     return int(k.shape[0]) * int(k.shape[2])
 
 
+# what a `latent_moe` launch counts on the device and hands back beside
+# its tokens: `experts.STATS` summed over its expert layers (and a
+# window's steps), then the latent rows it read
+_LAUNCH_STATS = _experts.STATS + ('latent_rows_read',)
+
+
+def _latent_moe_ffn(w, cfg, x, i, valid):
+    """The feed-forward half of layer ``i`` of a ``latent_moe`` block: x
+    [T, D] float32 -> (x + the layer's feed-forward, `experts.STATS`);
+    ``valid`` [T] marks the tokens that route (experts.py)."""
+    p = 'layer_%d_' % i
+    h = _latent.rms(x, w[p + 'ffn_norm'], _eps(cfg))
+    if _ffn_kinds(cfg)[i] == 'dense':
+        y, stats = _experts.dense_layer(w, p, h)
+    else:
+        y, stats = _experts.expert_layer(w, p, cfg, h, valid)
+    return x + y, stats
+
+
 def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
     """Build the one-chunk (or one-shot ring) prefill function.
 
@@ -444,18 +586,25 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
     samples the would-be next token at its absolute position, and
     stores it in tok[slot].  Only the final chunk's draw (the request's
     FIRST token, the TTFT token) survives.
+
+    A ``latent_moe`` model's layers take their own branch (`latent.prefill`
+    over the latent pool, then the layer's feed-forward kind), carry the
+    residual stream in float32, and the function returns a fourth value,
+    the chunk's `_LAUNCH_STATS`.
     """
     import jax.numpy as jnp
     L = int(cfg['n_layer'])
     theta = float(cfg['theta'])
-    dh = _head_dim(cfg)
     M, PL = cache.max_pages, cache.page_len
     quant = cache.quant == 'int8'
     recurrent = _recurrent(cfg)
+    latent_moe = _latent_moe(cfg)
+    dh = _head_dim(cfg)
 
     if ring_mesh is not None:
-        if recurrent:
-            raise ValueError('ring prefill cannot carry recurrent state')
+        if recurrent or latent_moe:
+            raise ValueError('ring prefill carries neither recurrent state '
+                             'nor a latent pool')
         from ...parallel.ring_attention import ring_attention
 
     def prefill(w, st, bt_row, tokens, slot, offset, true_count,
@@ -470,10 +619,24 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
         rw = p_abs % PL
         with scope('embed'):
             x = _embed(w, cfg, tokens)[None]              # [1, C, D]
+        if latent_moe:
+            x = x[0].astype(jnp.float32)                  # [C, D]
+            stats = jnp.zeros((len(_experts.STATS),), jnp.int32)
         for i in range(L):
             # ONE scope name for every layer: an operation's op_name
             # says which part of the block it is, whatever its index
             with scope('layer'):
+                if latent_moe:
+                    h = _latent.rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
+                    att, pool = _latent.prefill(
+                        w, 'layer_%d_' % i, cfg, h, p_abs,
+                        offset + true_count, st['k'], i, pg, rw, bt_row)
+                    st = dict(st, k=pool)
+                    with scope('ffn'):
+                        x, counted = _latent_moe_ffn(
+                            w, cfg, x + att, i, valid)
+                    stats = stats + counted
+                    continue
                 with scope('attn.qkv'):
                     h = _rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
                     q, k, v = _qkv(w, cfg, h, i)
@@ -511,9 +674,16 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
                 with scope('ffn'):
                     x = _ffn(w, cfg, x, i)
         with scope('lm_head'):
-            x = _rms(x, w['final_norm'], _eps(cfg))
-            last = jax.lax.dynamic_slice_in_dim(x[0], true_count - 1, 1)[0]
-            logits = _head(w, cfg, last)                  # [V]
+            if latent_moe:
+                last = jax.lax.dynamic_slice_in_dim(x, true_count - 1, 1)[0]
+                logits = _latent.dot(
+                    _latent.rms(last, w['final_norm'], _eps(cfg)),
+                    w['lm_proj_w'])                       # [V] float32
+            else:
+                x = _rms(x, w['final_norm'], _eps(cfg))
+                last = jax.lax.dynamic_slice_in_dim(x[0], true_count - 1,
+                                                    1)[0]
+                logits = _head(w, cfg, last)              # [V]
         new_len = offset + true_count
         with scope('sample'):
             nxt = sample_logits(logits, token_key(seed, new_len),
@@ -521,6 +691,11 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
         st = dict(st)
         st['lengths'] = st['lengths'].at[slot].set(new_len)
         st['tok'] = st['tok'].at[slot].set(nxt)
+        if latent_moe:
+            # the blocks of cached rows the chunk visited, in every layer
+            rows = L * _latent.prefill_rows(new_len, M * PL)
+            return st, nxt, logits, jnp.concatenate(
+                [stats, rows.astype(jnp.int32).reshape(1)])
         return st, nxt, logits
 
     return prefill
@@ -540,13 +715,20 @@ def _step_fn(cfg, cache, paged, state_kernel):
 
     ``state_kernel`` (`DecodeRuntime.state_kernel`) advances a recurrent
     model's scan state in place over the live slots (`ssm.ssm_step`);
-    otherwise every slot's steps and the dead ones' is masked."""
+    otherwise every slot's steps and the dead ones' is masked.
+
+    A ``latent_moe`` model's layers take their own branch: `latent.step`
+    (absorbed; with ``paged`` over the latent pool in place through
+    `ops.attention.latent_attention`), then the layer's feed-forward
+    kind, where a slot that rides along routes nowhere.  Its step
+    returns a third value, the step's `_LAUNCH_STATS`."""
     import jax.numpy as jnp
     L = int(cfg['n_layer'])
     theta = float(cfg['theta'])
     M, PL = cache.max_pages, cache.page_len
     quant = cache.quant == 'int8'
     recurrent = _recurrent(cfg)
+    latent_moe = _latent_moe(cfg)
 
     def step(w, st, bt, fed, active, seeds, temps, topks):
         import jax
@@ -559,8 +741,22 @@ def _step_fn(cfg, cache, paged, state_kernel):
         n_attend = jnp.where(active, pos + 1, 0)          # [S]
         with scope('embed'):
             x = _embed(w, cfg, fed)[:, None, :]           # [S, 1, D]
+        if latent_moe:
+            x = x[:, 0].astype(jnp.float32)               # [S, D]
+            stats = jnp.zeros((len(_experts.STATS),), jnp.int32)
         for i in range(L):
             with scope('layer'):     # one name for every layer (prefill)
+                if latent_moe:
+                    h = _latent.rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
+                    att, pool = _latent.step(
+                        w, 'layer_%d_' % i, cfg, h, pos, st['k'], i, pg, rw,
+                        bt, n_attend, paged)
+                    st = dict(st, k=pool)
+                    with scope('ffn'):
+                        x, counted = _latent_moe_ffn(
+                            w, cfg, x + att, i, active)
+                    stats = stats + counted
+                    continue
                 with scope('attn.qkv'):
                     h = _rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
                     q, k, v = _qkv(w, cfg, h, i)
@@ -593,16 +789,42 @@ def _step_fn(cfg, cache, paged, state_kernel):
                 with scope('ffn'):
                     x = _ffn(w, cfg, x, i)
         with scope('lm_head'):
-            x = _rms(x, w['final_norm'], _eps(cfg))
-            logits = _head(w, cfg, x[:, 0])               # [S, V]
+            if latent_moe:
+                logits = _latent.dot(
+                    _latent.rms(x, w['final_norm'], _eps(cfg)),
+                    w['lm_proj_w'])                       # [S, V] float32
+            else:
+                x = _rms(x, w['final_norm'], _eps(cfg))
+                logits = _head(w, cfg, x[:, 0])           # [S, V]
         with scope('sample'):
             nxt = sample_tokens_at(logits, seeds, pos + 1, temps, topks)
         st = dict(st)
         st['tok'] = jnp.where(active, nxt, st['tok'])
         st['lengths'] = jnp.where(active, pos + 1, pos)
+        if latent_moe:
+            # rows a layer reads: in place, the whole pages a live slot's
+            # positions cover (`paged_attention_rows`); gathered, every
+            # slot's ``max_len``
+            rows = jnp.sum(-(-n_attend // PL) * PL) if paged else S * M * PL
+            return st, nxt, jnp.concatenate(
+                [stats, jnp.asarray(L * rows, jnp.int32).reshape(1)])
         return st, nxt
 
     return step
+
+
+def _counted_window(step_body, st, xs, steps):
+    """`lax.scan` of a window whose step counts: ``step_body(carry, x)``
+    returns (state, tokens [S], `_LAUNCH_STATS`).  Returns (state, tokens
+    [S, K], the counts summed over the window)."""
+    import jax
+
+    def body(carry, x):
+        carry, nxt, stats = step_body(carry, x)
+        return carry, (nxt, stats)
+
+    st, (toks, stats) = jax.lax.scan(body, st, xs, length=steps)
+    return st, toks.T, stats.sum(axis=0)
 
 
 def _decode_fn(cfg, cache, steps, paged, state_kernel):
@@ -612,6 +834,13 @@ def _decode_fn(cfg, cache, steps, paged, state_kernel):
     import jax
 
     step = _step_fn(cfg, cache, paged, state_kernel)
+
+    if _latent_moe(cfg):
+        def window(w, st, bt, active, seeds, temps, topks):
+            return _counted_window(
+                lambda carry, _: step(w, carry, bt, carry['tok'], active,
+                                      seeds, temps, topks), st, None, steps)
+        return window
 
     def window(w, st, bt, active, seeds, temps, topks):
         def body(carry, _):
@@ -633,6 +862,13 @@ def _verify_fn(cfg, cache, steps, paged, state_kernel):
     import jax
 
     step = _step_fn(cfg, cache, paged, state_kernel)
+
+    if _latent_moe(cfg):
+        def window(w, st, bt, fed, active, seeds, temps, topks):
+            return _counted_window(
+                lambda carry, fed_t: step(w, carry, bt, fed_t, active, seeds,
+                                          temps, topks), st, fed, steps)
+        return window
 
     def window(w, st, bt, fed, active, seeds, temps, topks):
         def body(carry, fed_t):
@@ -770,7 +1006,10 @@ class DecodeRuntime(object):
     A model whose block carries recurrent state (``block:
     'falcon_h1'``; `recurrent`) runs WITHOUT the prefix cache whatever
     ``prefix_cache`` says (a hit would skip tokens the scan state never
-    saw), and refuses speculative windows and ring prefill.
+    saw), and refuses speculative windows and ring prefill.  A
+    ``latent_moe`` model (`latent_moe`) keeps the prefix cache (its
+    pages hold latent rows, shared like any other) and speculative
+    windows, and refuses ring prefill and ``kv_quant='int8'``.
     """
 
     def __init__(self, weights, cfg, slots=4, prefill_chunk=8,
@@ -789,21 +1028,29 @@ class DecodeRuntime(object):
             _cc.ensure_xla_cache_backstop()
             self.params = _params_from(weights, cfg)
             self.w = _PublicWeights(self.params, cfg)
-            made = jax.block_until_ready(
-                [self.params[stored]
-                 for _slot, stored in _prepared_names(cfg).values()])
+            made = jax.block_until_ready(_prepared_arrays(self.params, cfg))
             made_bytes = sum(int(a.nbytes) for a in made)
             init.args.update(prepared=len(made), prepared_bytes=made_bytes)
             _obs.metrics.gauge('generation.prepared_weight_bytes').set(
                 made_bytes)
             self.recurrent = _recurrent(cfg)
+            self.latent_moe = _latent_moe(cfg)
+            if self.latent_moe:
+                # the second pool geometry: one row a token a layer
+                _ffn_kinds(cfg)
+                lat = cfg['latent']
+                geometry = dict(kv_heads=1,
+                                head_dim=_latent.stored_width(lat),
+                                latent=int(lat['kv_rank']))
+            else:
+                geometry = dict(kv_heads=int(cfg['n_kv_head']),
+                                head_dim=_head_dim(cfg))
             self.cache = CacheConfig(
                 slots=slots, layers=int(cfg['n_layer']),
-                kv_heads=int(cfg['n_kv_head']), max_len=int(cfg['max_len']),
-                head_dim=_head_dim(cfg), dtype=cache_dtype,
+                max_len=int(cfg['max_len']), dtype=cache_dtype,
                 page_len=page_len, pages=pages, quant=kv_quant,
                 recurrent=(_ssm.state_shapes(cfg['ssm']) if self.recurrent
-                           else None))
+                           else None), **geometry)
             self.allocator = SlotAllocator(self.cache.slots)
             self.pool = PagePool(self.cache)
             # recurrent state cannot be shared between prompts: no prefix
@@ -826,20 +1073,32 @@ class DecodeRuntime(object):
             # the decode step attends over the pool in place where the
             # kernel can run (a floating pool, one device); an int8 pool and
             # a mesh of several devices keep the composed gather
-            self.paged = paged_attention_eligible(
-                self.cache.pool_shape, self.cache.store_dtype, mesh)
+            if self.latent_moe:
+                self.paged = latent_attention_eligible(
+                    self.cache.pool_shape, self.cache.store_dtype,
+                    self.cache.latent, mesh)
+            else:
+                self.paged = paged_attention_eligible(
+                    self.cache.pool_shape, self.cache.store_dtype, mesh)
             # likewise the scan state of a recurrent model: in place over
             # the live slots where that kernel can run (float32, one device)
             self.state_kernel = self.recurrent and _ssm.ssm_step_eligible(
                 self.state['ssm'].shape, self.state['ssm'].dtype, mesh)
             self._execs = {}
+            # `latent_moe` launches' `_LAUNCH_STATS`, still on the device,
+            # oldest first, and how many launches' were already moved into
+            # the counters: that happens behind the next read of a launch
+            # that came after them (`_count_stats`)
+            self._stats, self._counted = [], 0
             # arguments uploaded ahead of their launch: {'prefill' | 'window':
             # [(host copy, device array), ...]} (`stage_prefill`, `stage_window`)
             self._staged = {}
             # rows of K (or V) per layer one COMPOSED step gathers
-            self._gathered = None if self.paged else _gathered_rows(
-                self.cache, self._state_structs(),
-                self._bt_struct(self.cache.slots))
+            self._gathered = (
+                None if self.paged
+                else self.cache.slots * self.cache.max_len if self.latent_moe
+                else _gathered_rows(self.cache, self._state_structs(),
+                                    self._bt_struct(self.cache.slots)))
             self._lock = threading.Lock()
             _obs.metrics.gauge('generation.kv_cache_bytes').set(
                 self.cache.bytes())
@@ -889,6 +1148,7 @@ class DecodeRuntime(object):
         self.host_len[:] = 0
         self.host_tok[:] = 0
         self._staged.clear()
+        self._count_stats(self._counted + len(self._stats))
         self.state = init_state(self.cache)
 
     # ------------------------------------------------ page accounting
@@ -1172,6 +1432,9 @@ class DecodeRuntime(object):
         `decode.<kind>.fetch` span whose seconds go to
         `generation.<kind>_s` and `generation.<kind>_fetch_s` wherever
         the read happens; ``then(host array)`` follows it."""
+        # the launches made so far: theirs have landed when this one has
+        upto = self._counted + len(self._stats)
+
         def land():
             with _obs.span('decode.%s.fetch' % kind, cat='decode') as fetch:
                 out = np.asarray(dev)
@@ -1179,10 +1442,38 @@ class DecodeRuntime(object):
                 counter = _obs.metrics.counter
                 counter('generation.%s_s' % kind).inc(fetch.seconds)
                 counter('generation.%s_fetch_s' % kind).inc(fetch.seconds)
+            if upto > self._counted:
+                self._count_stats(upto)
             if then is not None:
                 then(out)
             return out
         return _Pending(land)
+
+    def _count_stats(self, upto):
+        """Move the `_LAUNCH_STATS` of the first ``upto`` launches of this
+        runtime (those made up to the one whose result was just read: the
+        device runs launches in order, so theirs have landed with it)
+        into ``generation.<stat>``, and a decode window's also into
+        ``generation.window_<stat>`` (what a step's roofline needs apart
+        from the chunks').  No wait and no launch: the few bytes came
+        over beside the tokens (`copy_to_host_async`)."""
+        n = upto - self._counted
+        mine, self._stats = self._stats[:n], self._stats[n:]
+        self._counted = upto
+        if not _obs.enabled():
+            return
+        for kind, stats in mine:
+            for name, n in zip(_LAUNCH_STATS, np.asarray(stats)):
+                _obs.metrics.counter('generation.' + name).inc(int(n))
+                if kind == 'window':
+                    _obs.metrics.counter(
+                        'generation.window_' + name).inc(int(n))
+
+    def _launched_stats(self, kind, stats):
+        """Keep a launch's `_LAUNCH_STATS` (on the device) until a read
+        behind it moves them into the counters."""
+        stats.copy_to_host_async()
+        self._stats.append((kind, stats))
 
     # -------------------------------------------------------- prefill
     def _chunk(self, tokens, offset):
@@ -1242,9 +1533,12 @@ class DecodeRuntime(object):
                 args = self._uploaded('prefill', self._prefill_values(
                     width, slot, tokens, offset, params))
             with _obs.span('decode.prefill.dispatch', cat='decode'):
-                st, nxt, logits = call(self.params, self.state, *args)
+                st, nxt, logits, *stats = call(self.params, self.state,
+                                               *args)
                 self.state = st
                 nxt.copy_to_host_async()
+                if stats:
+                    self._launched_stats('prefill', stats[0])
         self.host_len[slot] = offset + n
         if _obs.enabled():
             counter = _obs.metrics.counter
@@ -1319,9 +1613,11 @@ class DecodeRuntime(object):
             with _obs.span('decode.window.upload', cat='decode'):
                 args = self._uploaded('window', values)
             with _obs.span('decode.window.dispatch', cat='decode'):
-                st, toks = call(self.params, self.state, *args)
+                st, toks, *stats = call(self.params, self.state, *args)
                 self.state = st
                 toks.copy_to_host_async()
+                if stats:
+                    self._launched_stats('window', stats[0])
         if _obs.enabled():
             live = int(act.sum())
             counter = _obs.metrics.counter
@@ -1408,6 +1704,13 @@ class DecodeRuntime(object):
         bt = self.block_tables[int(slot)]
         L, Hkv = self.cache.layers, self.cache.kv_heads
         Tmax, dh = self.cache.max_len, self.cache.head_dim
+        if self.latent_moe:
+            # the one row a token has: k [L, 1, Tmax, kv_rank + rope] in
+            # the public order (`latent.public_rows`), no v
+            rows = np.asarray(st['k'])[bt]         # [M, L, PL, W]
+            rows = rows.transpose(1, 0, 2, 3).reshape(L, 1, Tmax, dh)
+            return (_latent.public_rows(rows, self.cfg['latent']), None,
+                    int(np.asarray(st['lengths'][int(slot)])))
 
         def assemble(pool, scale):
             rows = np.asarray(pool)[bt]        # [M, L, PL, Hkv, dh]

@@ -41,6 +41,17 @@ inputs, ``ssm`` ``[slots, layers, heads, head_dim, d_state]`` and
 state dict.  A slot IS its row there: nothing is allocated or freed, the
 chunk at offset 0 starts from zeros, and such rows cannot be shared, so
 a runtime over them runs without the prefix cache.
+
+A model with LATENT attention (decode.py's ``latent_moe`` block,
+latent.py) keeps the second pool geometry, ``CacheConfig.latent``: ONE
+pool ``[pages, layers, page_len, width]`` whose row is everything a
+token leaves in a layer, the normalised compressed key/value vector
+and behind it the one rotated key all heads share (``width`` =
+``kv_lora_rank + qk_rope_head_dim``).  There is no V pool and there are
+no kv heads: the values are the row's first ``latent`` columns.  The
+page geometry ``[pages, layers, page_len, ...]`` is the same, so
+`PagePool`, block tables, refcounts and `PrefixCache` count pages as
+before and the kv-bytes gauges count what such a page holds.
 """
 import hashlib
 import threading
@@ -74,13 +85,21 @@ class CacheConfig(object):
     ``max_len``); ``quant`` is ``'none'`` or ``'int8'``; ``recurrent``
     is None or the (scan state, convolution tail) shapes of one slot in
     one layer (`ssm.state_shapes`), both float32.
+
+    Two pool geometries.  Without ``latent``: a K and a V pool, each
+    ``[pages, layers, page_len, kv_heads, head_dim]``.  With ``latent``
+    (an int, the leading columns of a row that serve as values): ONE
+    pool ``[pages, layers, page_len, head_dim]``, a row a token a layer
+    (``kv_heads`` is 1, ``head_dim`` the row's width), no V pool and no
+    int8 form.
     """
     __slots__ = ('slots', 'layers', 'kv_heads', 'max_len', 'head_dim',
-                 'dtype', 'page_len', 'pages', 'quant', 'recurrent')
+                 'dtype', 'page_len', 'pages', 'quant', 'recurrent',
+                 'latent')
 
     def __init__(self, slots, layers, kv_heads, max_len, head_dim,
                  dtype='float32', page_len=None, pages=None, quant='none',
-                 recurrent=None):
+                 recurrent=None, latent=None):
         if int(slots) < 1:
             raise ValueError('kv cache needs >= 1 slot, got %r' % (slots,))
         self.slots = int(slots)
@@ -108,6 +127,16 @@ class CacheConfig(object):
                              % (quant,))
         self.recurrent = None if recurrent is None else tuple(
             tuple(int(n) for n in shape) for shape in recurrent)
+        self.latent = None if latent is None else int(latent)
+        if self.latent is not None:
+            if self.kv_heads != 1 or not 0 < self.latent <= self.head_dim:
+                raise ValueError(
+                    'a latent pool holds one row a token: kv_heads=1 and '
+                    '0 < latent <= head_dim, got kv_heads=%d latent=%d '
+                    'head_dim=%d' % (self.kv_heads, self.latent,
+                                     self.head_dim))
+            if self.quant != 'none':
+                raise ValueError('a latent pool has no int8 form')
 
     @property
     def max_pages(self):
@@ -120,6 +149,8 @@ class CacheConfig(object):
 
     @property
     def pool_shape(self):
+        if self.latent is not None:
+            return (self.pages, self.layers, self.page_len, self.head_dim)
         return (self.pages, self.layers, self.page_len, self.kv_heads,
                 self.head_dim)
 
@@ -140,9 +171,12 @@ class CacheConfig(object):
 
     def page_bytes(self):
         """Bytes ONE page costs across both pools (K+V, plus the scale
-        rows when quantized) — the unit of the kv_bytes gauges."""
+        rows when quantized; the one pool of a latent cache) — the unit
+        of the kv_bytes gauges."""
         per = int(np.dtype(self.store_dtype).itemsize)
         elems = self.layers * self.kv_heads * self.page_len * self.head_dim
+        if self.latent is not None:
+            return per * elems
         b = 2 * per * elems
         if self.quant == 'int8':
             b += 2 * 4 * self.layers * self.kv_heads * self.page_len
@@ -184,6 +218,8 @@ class CacheConfig(object):
                 'quant': self.quant}
         if self.recurrent is not None:
             spec['recurrent'] = self.recurrent
+        if self.latent is not None:
+            spec['latent'] = self.latent
         return spec
 
 
@@ -192,12 +228,15 @@ def init_state(cache_cfg):
     ``lengths`` (tokens written so far) and ``tok`` (the next token to
     feed — set by prefill, advanced by every decode step).  int8 mode
     adds the per-row dequant scale pools, recurrent layers their zeroed
-    ``ssm`` and ``conv`` rows."""
+    ``ssm`` and ``conv`` rows.  A latent cache has the one pool ``k``
+    and no ``v``."""
     import jax.numpy as jnp
     k = jnp.zeros(cache_cfg.pool_shape, jnp.dtype(cache_cfg.store_dtype))
-    st = {'k': k, 'v': jnp.zeros_like(k),
+    st = {'k': k,
           'lengths': jnp.zeros((cache_cfg.slots,), jnp.int32),
           'tok': jnp.zeros((cache_cfg.slots,), jnp.int32)}
+    if cache_cfg.latent is None:
+        st['v'] = jnp.zeros_like(k)
     if cache_cfg.quant == 'int8':
         ks = jnp.zeros(cache_cfg.scale_shape, jnp.float32)
         st['k_scale'] = ks

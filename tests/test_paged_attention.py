@@ -211,6 +211,48 @@ def test_mosaic_compiles_the_kernel_at_real_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+def test_mosaic_compiles_the_latent_kernel_at_real_widths(one_v5e_chip,
+                                                          monkeypatch):
+    """`latent_attention` at axk1.shared_context_answers' extents: 64
+    heads over one 576-value row a token, stored 640 wide because a DMA
+    moves whole lane tiles (Mosaic refuses the 576-wide slice, and the
+    eligibility rule says so first); the pool reaches the kernel as it
+    lies, and nothing of its size is made."""
+    import jax
+    from paddle_tpu.ops import _pallas
+    from paddle_tpu.ops.attention import (latent_attention,
+                                          latent_attention_eligible)
+    monkeypatch.setattr(_pallas, 'interpret', lambda: False)
+    slots, heads, layers, pages = 64, 64, 7, 16385
+    cache = CacheConfig(slots=slots, layers=layers, kv_heads=1, max_len=7184,
+                        head_dim=640, dtype='bfloat16', page_len=16,
+                        pages=pages, latent=512)
+    assert cache.pool_shape == (pages, layers, 16, 640)
+    assert latent_attention_eligible(cache.pool_shape, 'bfloat16', 512)
+    assert not latent_attention_eligible((pages, layers, 16, 576),
+                                         'bfloat16', 512)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                    sharding=one_v5e_chip)
+
+    compiled = jax.jit(
+        lambda ql, qr, pool, bt, n: latent_attention(
+            ql, qr, pool, bt, n, 3, 0.13)).lower(
+                sds((slots, heads, 512), 'float32'),
+                sds((slots, heads, 128), 'float32'),
+                sds(cache.pool_shape, 'bfloat16'),
+                sds((slots, cache.max_pages), 'int32'),
+                sds((slots,), 'int32')).compile()
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 1
+    pool = '[%d,' % pages
+    assert not [ln for ln in text.splitlines() if pool in ln.split('(')[0]
+                and (' copy(' in ln or 'copy-start(' in ln
+                     or ' fusion(' in ln)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+
+
 @pytest.mark.parametrize('causal,lengths', [(True, False), (False, True)],
                          ids=['self', 'cross'])
 def test_short_attention_keeps_its_scores_on_chip_at_tbase_widths(
