@@ -12,7 +12,8 @@ the repo benchmarks, with seeded random weights:
   train_resnet50     ResNet-50, 224x224, 1000 classes, B=128, AMP: three
                      steps (the conv / bf16 flow-through side)
   kernels            every Pallas kernel a launch can select (flash,
-                     DMA gather, ssm_step, latent_attention) and the kernel
+                     DMA gather, ssm_step, latent_attention,
+                     latent_prefill) and the kernel
                      tier's `row`
                      plans, which no launch selects since PR 41, compiled
                      by Mosaic and compared with their references
@@ -63,6 +64,14 @@ SIZES = {
                         pages=2049, layers=2, max_pages=449,
                         lengths=(0, 1, 16, 300, 4097, 5000, 7184, 777),
                         dtype='bfloat16', tol=2e-2),
+            # the axk1 cell's chunk: 512 queries of 64 heads over a table
+            # of 7,184 rows; (offset, real tokens) at offset 0, on and
+            # inside a block of 1,024, with a padded tail
+            latent_prefill=dict(
+                heads=64, chunk=512, rows=7184, key_block=1024, kv_rank=512,
+                nope=128, rope=64, v=128, width=640,
+                cases=((0, 512), (4608, 512), (5000, 512), (6144, 37)),
+                dtype='bfloat16', tol=1e-4),
             softmax=(32, 8, 256, 256),
             # transformer-base widths, one layer: layers share their
             # fused-group signatures, so one layer builds every plan
@@ -88,6 +97,11 @@ SIZES = {
             latent=dict(slots=4, heads=4, v_dim=128, width=256, page_len=4,
                         pages=41, layers=2, max_pages=6,
                         lengths=(0, 1, 13, 24), dtype='float32', tol=2e-5),
+            latent_prefill=dict(
+                heads=4, chunk=8, rows=60, key_block=16, kv_rank=16, nope=8,
+                rope=4, v=8, width=128,
+                cases=((0, 8), (24, 8), (40, 3)),
+                dtype='float32', tol=2e-5),
             softmax=(2, 2, 16, 16),
             groups=dict(n_layer=1, d_model=32, n_head=2, d_inner=64,
                         vocab=128, batch=2, seq=16)),
@@ -493,6 +507,58 @@ def _latent_attention_check(cfg):
         'latent_attention', got[live], want[live], cfg['tol']))}
 
 
+def _latent_prefill_check(cfg):
+    """`latent_prefill` (a `latent_moe` chunk's attention, its scores on
+    chip) against the composed block loop it replaces
+    (`latent._block_loop`) on the same queries, rows and up-projection:
+    per case (offset, real tokens) the chunk's output on both routes.  A
+    block the chunk visits holds rows past its context (masked), and the
+    padded tail's queries see them on both routes."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention as att
+    from paddle_tpu.serving.generation import latent
+    H, C, Tk, BK = cfg['heads'], cfg['chunk'], cfg['rows'], cfg['key_block']
+    kr, nope, rope, v = cfg['kv_rank'], cfg['nope'], cfg['rope'], cfg['v']
+    W, dt = cfg['width'], jnp.dtype(cfg['dtype'])
+    assert att.latent_prefill_eligible((1, 1, 16, W), dt, C, BK, kr, nope,
+                                       v), 'smoke shape is not eligible'
+    ks = jax.random.split(jax.random.key(SEED), 5)
+    q_nope = jax.random.normal(ks[0], (H, C, nope), jnp.float32)
+    q_r = jax.random.normal(ks[1], (H, C, rope), jnp.float32)
+    rows = jax.random.normal(ks[2], (-(-Tk // BK) * BK, W), jnp.float32)
+    rows = rows.at[:, kr + rope:].set(0.0).astype(dt)      # the pad columns
+    wk = (0.05 * jax.random.normal(ks[3], (H, nope, kr))).astype(dt)
+    wv = (0.05 * jax.random.normal(ks[4], (H, kr, v))).astype(dt)
+    scale = 0.1
+
+    def kernel(q_nope, q_r, rows, wk, wv, offset, count):
+        q = jnp.concatenate(
+            [q_nope, jnp.pad(q_r, ((0, 0), (0, 0), (0, W - kr - rope)))],
+            axis=-1).astype(dt)
+        return att.latent_prefill(q, rows, wk, wv, offset + jnp.arange(C),
+                                  offset + count, scale, BK)
+
+    def composed(q_nope, q_r, rows, wk, wv, offset, count):
+        q = jnp.concatenate([q_nope, q_r], axis=-1).astype(dt)
+        return latent._block_loop(q, rows, wk, wv, offset + jnp.arange(C),
+                                  offset + count, scale, BK, kr, rope)
+
+    zero = jnp.int32(0)
+    ops = (q_nope, q_r, rows, wk, wv)
+    kernel, n_calls = _mosaic_calls(kernel, *ops, zero, zero)
+    _assert_mosaic('latent_prefill', n_calls, 1)
+    composed, n_calls = _mosaic_calls(composed, *ops, zero, zero)
+    assert n_calls == 0, 'the composed route holds a Mosaic kernel'
+    out = {}
+    for offset, count in cfg['cases']:
+        args = ops + (jnp.int32(offset), jnp.int32(count))
+        out['err_%d_%d' % (offset, count)] = float('%.2e' % _close(
+            'latent_prefill at %d + %d' % (offset, count),
+            kernel(*args), composed(*args), cfg['tol']))
+    return out
+
+
 def _run_softmax_group(fluid, shape):
     """A program whose fused group holds a softmax, so the `row` kind's
     other kernel has a plan to check."""
@@ -582,6 +648,7 @@ def kernels(cfg):
         'gather': _gather_check(cfg['gather']),
         'ssm_step': _ssm_step_check(cfg['ssm_step']),
         'latent_attention': _latent_attention_check(cfg['latent']),
+        'latent_prefill': _latent_prefill_check(cfg['latent_prefill']),
     }
     # The kernel tier is off by default on every backend (PR 41: its one
     # Mosaic kernel cost tbase.train_1chip 3 % of its rate), and

@@ -1030,3 +1030,148 @@ def latent_attention(q_lat, q_rope, pool, block_tables, n_attend, layer,
     )(block_tables.reshape(-1).astype(jnp.int32), n,
       jnp.asarray(layer, jnp.int32).reshape(1), q_lat.astype(pool.dtype),
       q_rope.astype(pool.dtype), pool)
+
+
+# ---------------------- latent prefill-chunk attention, scores on chip
+
+# one block's scores and probabilities in float32 and their copy in the
+# rows' dtype are 5 MB at 512 x 1024, beside the double-buffered blocks:
+# more than the 16 MiB a Mosaic kernel gets unasked, of a v5e's 128
+_LATENT_PREFILL_VMEM = 64 << 20
+
+
+def latent_prefill_eligible(pool_shape, dtype, chunk, key_block, kv_rank,
+                            nope, v_dim, mesh=None):
+    """Static rule for `latent_prefill`: a chunk of ``chunk`` queries
+    (a head's ``nope`` un-rotated columns, its values ``v_dim`` wide)
+    over rows gathered from a ``[pages, layers, page_len, width]`` pool
+    whose first ``kv_rank`` columns are the compressed part, ``key_block``
+    rows a grid step.  A floating pool on a single device; on an
+    accelerator every slice the kernel takes must be whole tiles: the
+    compressed part, the rest of a row, a head's un-rotated columns and
+    its values each whole lane tiles, the key block too (it is the
+    scores' lane axis), and the chunk whole sublane tiles of the pool's
+    dtype."""
+    dtype = jnp.dtype(dtype)
+    if not jnp.issubdtype(dtype, jnp.floating) \
+            or not _pallas.single_device(mesh):
+        return False
+    if _pallas.interpret():
+        return True
+    width = pool_shape[-1]
+    sublanes = 8 * (4 // dtype.itemsize)
+    return all(int(n) % 128 == 0
+               for n in (kv_rank, width, nope, v_dim, key_block)) \
+        and width > kv_rank and int(chunk) % sublanes == 0
+
+
+def _latent_prefill_kernel(q_ref, rows_ref, wk_ref, wv_ref, pos_ref, o_ref,
+                           m_sc, l_sc, acc_sc, *, block_k, kv_rank, nope,
+                           scale):
+    from jax.experimental import pallas as pl
+
+    j = pl.program_id(1)
+    dt = rows_ref.dtype
+
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    ckv = rows_ref[:, :kv_rank]                           # [BK, kv_rank]
+    # this head's keys and values of the block, expanded from the rows
+    # every head shares and rounded to the pool's dtype
+    k_nope = jax.lax.dot_general(
+        ckv, wk_ref[0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(dt)
+    vals = jnp.dot(ckv, wv_ref[0],
+                   preferred_element_type=jnp.float32).astype(dt)
+    q = q_ref[0]
+    s = (jax.lax.dot_general(
+        q[:, :nope], k_nope, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) + jax.lax.dot_general(
+        q[:, nope:], rows_ref[:, kv_rank:], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)) * scale      # [C, BK]
+    kpos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(kpos <= pos_ref[...], s, _NEG_INF)
+    # key 0 is visible to every query, so from the first block on m is a
+    # real score and a masked key's exp is 0
+    m = m_sc[...]
+    m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m - m_new)
+    l_sc[...] = alpha * l_sc[...] + p.sum(axis=1, keepdims=True)
+    acc_sc[...] = acc_sc[...] * alpha + jnp.dot(
+        p.astype(dt), vals, preferred_element_type=jnp.float32)
+    m_sc[...] = m_new
+
+    # a head's last step: there is always one, and an output block that
+    # is visited and not written goes back as whatever VMEM held
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        o_ref[0] = acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
+
+
+def latent_prefill(q, rows, wk, wv, pos, n_keys, scale, block_k):
+    """One prefill chunk's latent attention in the EXPANDED form with the
+    scores kept on chip.
+
+    q: [H, C, nope + r], the chunk's queries: a head's un-rotated columns,
+    then its rotated part padded with zeros to the columns a row has
+    behind its compressed part; rows: [Tk, kv_rank + r], the slot's
+    gathered logical rows, the chunk's own already among them, Tk whole
+    blocks of ``block_k`` (the caller's: what it counts as visited); wk: [H, nope, kv_rank] and wv: [H, kv_rank, v], the two
+    halves of the up-projection; pos: [C] int32, the queries' absolute
+    positions (key ``kpos`` is visible iff ``kpos <= pos``); n_keys:
+    int32 scalar, the positions written (the last query's + 1).  Returns
+    [H, C, v] float32.
+
+    The grid is (heads, the key blocks ``n_keys`` covers): its second
+    extent is a traced value, so blocks past the context are not steps
+    at all, neither copied nor computed.  A step expands the block's
+    ``k_nope`` and values for its head on chip (rounded to the rows'
+    dtype, as the composed block loop of `latent.prefill` does), takes
+    the scores over both parts of the key, and folds them into an online
+    softmax whose float32 statistics and accumulator live in VMEM: no
+    array of scores or probabilities exists in HBM.
+    """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    H, C, d = q.shape
+    Tk, width = rows.shape
+    nope, kv_rank = wk.shape[1], wk.shape[2]
+    v_dim = wv.shape[2]
+    BK = int(block_k)
+    if Tk % BK or d != nope + width - kv_rank:
+        raise ValueError('latent_prefill: rows %r are not whole blocks of '
+                         '%d, or q %r does not match them'
+                         % (rows.shape, BK, q.shape))
+    kernel = functools.partial(
+        _latent_prefill_kernel, block_k=BK, kv_rank=kv_rank, nope=nope,
+        scale=float(scale))
+    n_blocks = jnp.clip((jnp.asarray(n_keys, jnp.int32) + BK - 1) // BK,
+                        1, Tk // BK)
+    dt = rows.dtype
+    return pl.pallas_call(
+        kernel,
+        grid=(H, n_blocks),
+        in_specs=[
+            pl.BlockSpec((1, C, d), lambda h, j: (h, 0, 0)),
+            pl.BlockSpec((BK, width), lambda h, j: (j, 0)),
+            pl.BlockSpec((1, nope, kv_rank), lambda h, j: (h, 0, 0)),
+            pl.BlockSpec((1, kv_rank, v_dim), lambda h, j: (h, 0, 0)),
+            pl.BlockSpec((C, 1), lambda h, j: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, C, v_dim), lambda h, j: (h, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((C, 1), jnp.float32),
+                        pltpu.VMEM((C, 1), jnp.float32),
+                        pltpu.VMEM((C, v_dim), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((H, C, v_dim), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary'),
+            vmem_limit_bytes=_LATENT_PREFILL_VMEM),
+        name='latent_prefill',
+        interpret=_pallas.interpret(),
+    )(q.astype(dt), rows, wk.astype(dt), wv.astype(dt),
+      pos.astype(jnp.int32).reshape(C, 1))

@@ -110,7 +110,9 @@ split into its key and value halves for absorption, rope columns as
 rotated halves), and its pool has the second geometry: ONE row
 ``[c_kv ; k_r]`` a token a layer and no V pool (`CacheConfig.latent`),
 under the same pages, block tables and prefix cache.  A prefill chunk
-expands keys and values from the gathered rows; a decode step attends
+expands keys and values from the gathered rows, its scores kept on chip
+(`ops.attention.latent_prefill`; a loop of XLA operations under a mesh:
+`DecodeRuntime.prefill_kernel`); a decode step attends
 in the absorbed form over the pool in place
 (`ops.attention.latent_attention`; gathered rows under a mesh:
 `DecodeRuntime.paged`).  The residual stream is float32.  Its two
@@ -576,7 +578,7 @@ def _latent_moe_ffn(w, cfg, x, i, valid):
     return x + y, stats
 
 
-def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
+def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False):
     """Build the one-chunk (or one-shot ring) prefill function.
 
     Scatters the chunk's K/V rows into the pages ``bt_row`` maps at the
@@ -590,7 +592,10 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
     A ``latent_moe`` model's layers take their own branch (`latent.prefill`
     over the latent pool, then the layer's feed-forward kind), carry the
     residual stream in float32, and the function returns a fourth value,
-    the chunk's `_LAUNCH_STATS`.
+    the chunk's `_LAUNCH_STATS`.  ``latent_kernel``
+    (`DecodeRuntime.prefill_kernel`) keeps that attention's scores on
+    chip (`ops.attention.latent_prefill`); otherwise its block loop is
+    composed of XLA operations.
     """
     import jax.numpy as jnp
     L = int(cfg['n_layer'])
@@ -630,7 +635,8 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None):
                     h = _latent.rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
                     att, pool = _latent.prefill(
                         w, 'layer_%d_' % i, cfg, h, p_abs,
-                        offset + true_count, st['k'], i, pg, rw, bt_row)
+                        offset + true_count, st['k'], i, pg, rw, bt_row,
+                        latent_kernel)
                     st = dict(st, k=pool)
                     with scope('ffn'):
                         x, counted = _latent_moe_ffn(
@@ -1084,6 +1090,10 @@ class DecodeRuntime(object):
             # the live slots where that kernel can run (float32, one device)
             self.state_kernel = self.recurrent and _ssm.ssm_step_eligible(
                 self.state['ssm'].shape, self.state['ssm'].dtype, mesh)
+            # and a `latent_moe` chunk's scores: on chip where that kernel
+            # can run, else through HBM a block at a time
+            self.prefill_kernel = self.latent_moe and _latent.prefill_kernel(
+                cfg, self.cache, self.prefill_chunk, mesh)
             self._execs = {}
             # `latent_moe` launches' `_LAUNCH_STATS`, still on the device,
             # oldest first, and how many launches' were already moved into
@@ -1318,7 +1328,8 @@ class DecodeRuntime(object):
 
         def build():
             fn = _prefill_fn(self.cfg, self.cache, chunk,
-                             ring_mesh=self.mesh if ring else None)
+                             ring_mesh=self.mesh if ring else None,
+                             latent_kernel=self.prefill_kernel)
             jitted = jax.jit(fn, donate_argnums=(1,))
             i32 = self._sds((), jax.numpy.int32)
             f32 = self._sds((), jax.numpy.float32)

@@ -53,11 +53,13 @@ import math
 
 import numpy as np
 
-from ...ops.attention import latent_attention, latent_attention_composed
+from ...ops.attention import (latent_attention, latent_attention_composed,
+                              latent_prefill, latent_prefill_eligible)
 
 __all__ = ['SLOTS', 'PREPARED', 'weight_shapes', 'row_width', 'stored_width',
            'yarn_inv_freq', 'score_scale', 'prepare', 'public', 'prefill',
-           'prefill_rows', 'step', 'public_rows', 'rms', 'dot']
+           'prefill_kernel', 'prefill_rows', 'step', 'public_rows', 'rms',
+           'dot']
 
 # the attention weights of one layer, after `layer_<i>_`.  The two inner
 # norms' scales end in `norm`: whoever draws weights makes such a name
@@ -259,15 +261,75 @@ def _padded(q_r, lat):
     return jnp.pad(q_r, [(0, 0)] * (q_r.ndim - 1) + [(0, pad)])
 
 
+def _key_block(table_rows):
+    """Cached positions one pass of a chunk takes, on either route."""
+    return min(_PREFILL_KEY_BLOCK, table_rows)
+
+
+def prefill_kernel(cfg, cache, chunk, mesh=None):
+    """Whether `prefill` attends through `ops.attention.latent_prefill`
+    for chunks of ``chunk`` tokens over ``cache`` (a `CacheConfig` with a
+    latent pool): the kernel's static rule of shapes, dtype and mesh."""
+    lat = cfg['latent']
+    return latent_prefill_eligible(
+        cache.pool_shape, cache.store_dtype, chunk,
+        _key_block(cache.max_pages * cache.page_len), int(lat['kv_rank']),
+        int(lat['nope']), int(lat['v']), mesh)
+
+
 def prefill_rows(n_keys, table_rows):
     """Cached rows a layer of `prefill` visits for a chunk that leaves
     ``n_keys`` positions written, of a slot whose block table maps
-    ``table_rows``: whole blocks of ``_PREFILL_KEY_BLOCK``."""
-    BK = min(_PREFILL_KEY_BLOCK, table_rows)
+    ``table_rows``: whole blocks of ``_PREFILL_KEY_BLOCK``, on either
+    route."""
+    BK = _key_block(table_rows)
     return (n_keys + BK - 1) // BK * BK
 
 
-def prefill(w, p, cfg, h, pos, n_keys, pool, layer, pg, rw, bt_row):
+def _block_loop(q, rows, wk, wv, pos, n_keys, scale, BK, kr, rope):
+    """The chunk's attention composed of XLA operations: q [H, C, nope +
+    rope] against rows [Tk, W] (whole blocks of ``BK``), a block at a
+    time under an online softmax.  Returns [H, C, v] float32."""
+    import jax
+    import jax.numpy as jnp
+    H, C, _ = q.shape
+    dt = rows.dtype
+
+    def block(b, carry):
+        m, l, acc = carry
+        blk = jax.lax.dynamic_slice_in_dim(rows, b * BK, BK)
+        ckv = blk[:, :kr]
+        k_nope = jnp.einsum('tc,hnc->htn', ckv, wk,
+                            preferred_element_type=jnp.float32)
+        vals = jnp.einsum('tc,hcv->htv', ckv, wv,
+                          preferred_element_type=jnp.float32)
+        k = jnp.concatenate(
+            [k_nope.astype(dt), jnp.broadcast_to(
+                blk[None, :, kr:kr + rope], (H, BK, rope))], axis=-1)
+        s = jnp.einsum('hqd,hkd->hqk', q, k,
+                       preferred_element_type=jnp.float32) * scale
+        kpos = b * BK + jnp.arange(BK)
+        s = jnp.where(kpos[None, :] <= pos[:, None], s, -1e30)
+        # key 0 is visible to every query, so from the first block on
+        # m is a real score and a masked key's exp is 0
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        prob = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + jnp.sum(prob, axis=-1, keepdims=True)
+        acc = acc * alpha + jnp.einsum(
+            'hqk,hkv->hqv', prob.astype(dt), vals.astype(dt),
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m, l, acc = jax.lax.fori_loop(
+        0, (n_keys + BK - 1) // BK, block,
+        (jnp.full((H, C, 1), -1e30, jnp.float32),
+         jnp.zeros((H, C, 1), jnp.float32),
+         jnp.zeros((H, C, wv.shape[2]), jnp.float32)))
+    return acc / jnp.maximum(l, 1e-30)
+
+
+def prefill(w, p, cfg, h, pos, n_keys, pool, layer, pg, rw, bt_row, kernel):
     """One slot, one prefill chunk of layer ``layer``: h [C, D]
     normalised, pos [C] absolute positions, n_keys the positions written
     once the chunk is (offset + true_count), pg / rw [C] the page and
@@ -277,10 +339,18 @@ def prefill(w, p, cfg, h, pos, n_keys, pool, layer, pg, rw, bt_row):
     at a time: each block's ``k_nope`` and ``v`` are expanded for every
     head from its cached rows and enter an online softmax (float32
     statistics), and blocks past ``n_keys`` are not visited, so the
-    work follows the context and not ``max_len``.  Returns (the
-    attention's output [C, D] float32, the pool)."""
+    work follows the context and not ``max_len``.
+
+    ``kernel`` (`prefill_kernel`, static) runs those passes as ONE
+    Pallas kernel whose scores never leave the chip
+    (`ops.attention.latent_prefill`); otherwise they are a loop of XLA
+    operations (`_block_loop`), a block's float32 scores an array in
+    HBM: the composed form the kernel is tested against, and the route
+    under a mesh.
+    Returns (the attention's output [C, D] float32, the pool)."""
     import jax
     import jax.numpy as jnp
+    from ... import observability as _obs
     lat, H = cfg['latent'], int(cfg['n_head'])
     kr, rope = int(lat['kv_rank']), int(lat['rope'])
     v = int(lat['v'])
@@ -291,46 +361,23 @@ def prefill(w, p, cfg, h, pos, n_keys, pool, layer, pg, rw, bt_row):
         dt = pool.dtype
         q_r = _rotate(q_rope.transpose(1, 0, 2), pos,
                       yarn_inv_freq(lat, cfg['theta']))       # [H, C, rope]
-        q = jnp.concatenate([q_nope.transpose(1, 0, 2), q_r],
-                            axis=-1).astype(dt)               # [H, C, n + r]
+        q_nope = q_nope.transpose(1, 0, 2)                    # [H, C, nope]
         rows = pool[bt_row, layer].reshape(-1, pool.shape[-1])   # [Tk, W]
-        BK = min(_PREFILL_KEY_BLOCK, rows.shape[0])
+        BK = _key_block(rows.shape[0])
         rows = jnp.pad(rows, ((0, -rows.shape[0] % BK), (0, 0)))
         wk, wv = w[p + 'att_kvb_k'].astype(dt), w[p + 'att_kvb_v'].astype(dt)
         scale = score_scale(lat)
-
-        def block(b, carry):
-            m, l, acc = carry
-            blk = jax.lax.dynamic_slice_in_dim(rows, b * BK, BK)
-            ckv = blk[:, :kr]
-            k_nope = jnp.einsum('tc,hnc->htn', ckv, wk,
-                                preferred_element_type=jnp.float32)
-            vals = jnp.einsum('tc,hcv->htv', ckv, wv,
-                              preferred_element_type=jnp.float32)
-            k = jnp.concatenate(
-                [k_nope.astype(dt), jnp.broadcast_to(
-                    blk[None, :, kr:kr + rope], (H, BK, rope))], axis=-1)
-            s = jnp.einsum('hqd,hkd->hqk', q, k,
-                           preferred_element_type=jnp.float32) * scale
-            kpos = b * BK + jnp.arange(BK)
-            s = jnp.where(kpos[None, :] <= pos[:, None], s, -1e30)
-            # key 0 is visible to every query, so from the first block on
-            # m is a real score and a masked key's exp is 0
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            prob = jnp.exp(s - m_new)
-            alpha = jnp.exp(m - m_new)
-            l = alpha * l + jnp.sum(prob, axis=-1, keepdims=True)
-            acc = acc * alpha + jnp.einsum(
-                'hqk,hkv->hqv', prob.astype(dt), vals.astype(dt),
-                preferred_element_type=jnp.float32)
-            return m_new, l, acc
-
-        m, l, acc = jax.lax.fori_loop(
-            0, (n_keys + BK - 1) // BK, block,
-            (jnp.full((H, C, 1), -1e30, jnp.float32),
-             jnp.zeros((H, C, 1), jnp.float32),
-             jnp.zeros((H, C, v), jnp.float32)))
-        att = (acc / jnp.maximum(l, 1e-30)).transpose(1, 0, 2)
+        if kernel:
+            _obs.metrics.counter('latent.prefill_kernel').inc()
+            q = jnp.concatenate([q_nope, _padded(q_r, lat)],
+                                axis=-1).astype(dt)
+            out = latent_prefill(q, rows, wk, wv, pos, n_keys, scale, BK)
+        else:
+            _obs.metrics.counter('latent.prefill_composed').inc()
+            q = jnp.concatenate([q_nope, q_r], axis=-1).astype(dt)
+            out = _block_loop(q, rows, wk, wv, pos, n_keys, scale, BK,
+                              kr, rope)
+        att = out.transpose(1, 0, 2)
         return dot(att.reshape(C, H * v), w[p + 'att_o_w']), pool
 
 

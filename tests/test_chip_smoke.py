@@ -70,6 +70,8 @@ def test_phase_kernels(monkeypatch):
     assert out['plans']['pallas_kernels'] >= 3
     assert out['plans']['xla_only_plans'] >= 1
     assert out['gather']['bitwise']
+    assert sorted(out['latent_prefill']) == ['err_0_8', 'err_24_8',
+                                             'err_40_3']
 
 
 def test_phase_serve():

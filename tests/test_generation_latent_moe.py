@@ -113,15 +113,19 @@ def test_chunks_a_window_and_a_chunk_match_the_reference(rt, reference,
     assert _rel(got, want) < 2e-4
 
 
+@pytest.mark.parametrize('kernel', [True, False],
+                         ids=['kernel', 'composed'])
 def test_a_chunk_visits_its_context_block_by_block(weights, reference,
-                                                   monkeypatch):
+                                                   monkeypatch, kernel):
     """Blocks of 16 cached positions where the module's fixture has one
     of 64: the online softmax over several blocks, a context that ends
     mid-block, and blocks past it not visited (their rows are not
-    counted)."""
+    counted), on either route of the chunk."""
     monkeypatch.setattr(latent, '_PREFILL_KEY_BLOCK', 16)
     small = DecodeRuntime(weights, CFG, slots=2, prefill_chunk=CHUNK,
                           page_len=PAGE)
+    assert small.prefill_kernel
+    small.prefill_kernel = kernel
     before = dict(obs.counters())
     context, got = _through_the_pool(small, _prompt(37, 12))
     assert _rel(got, reference.last_logits(small.w, CFG, context)) < 2e-4
@@ -130,6 +134,9 @@ def test_a_chunk_visits_its_context_block_by_block(weights, reference,
     # 48 and 48 rows a layer
     assert c['generation.latent_rows_read'] \
         - c['generation.window_latent_rows_read'] == 3 * 192
+    # one lowering of the chunk, one count a layer, of the route taken
+    assert c.get('latent.prefill_kernel', 0) == (3 if kernel else 0)
+    assert c.get('latent.prefill_composed', 0) == (0 if kernel else 3)
 
 
 def test_the_reference_controls_are_seen(rt, reference):
@@ -582,3 +589,124 @@ def test_the_composed_route_gives_the_same_tokens(weights, rt):
     assert c['generation.kv_rows_read'] == 2 * WINDOW * 3 * CFG['max_len']
     assert c['generation.window_latent_rows_read'] \
         == 3 * c['generation.kv_rows_read']
+
+
+# ---------------------------------------------- the chunk's two routes
+
+def _one_chunk(rt, kernel, offset, count, poison, layer=1):
+    """Layer ``layer``'s attention of one chunk at ``offset`` with
+    ``count`` real tokens (the rest is the padded tail), over a slot
+    whose cached rows are random; positions from ``poison`` on hold NaN
+    before the chunk's own rows are written."""
+    rng = np.random.RandomState(3)
+    M, PL = rt.cache.max_pages, rt.cache.page_len
+    lat = CFG['latent']
+    bt_row = jnp.asarray(rng.permutation(np.arange(1, rt.cache.pages))[:M],
+                         jnp.int32)
+    pool = rng.randn(*rt.cache.pool_shape).astype(np.float32)
+    pool[..., latent.row_width(lat):] = 0.0            # the pad columns
+    flat = pool[np.asarray(bt_row), layer].reshape(M * PL, -1)
+    flat[poison:] = np.nan
+    pool[np.asarray(bt_row), layer] = flat.reshape(M, PL, -1)
+    h = jnp.asarray(rng.randn(CHUNK, CFG['d_model']), jnp.float32)
+    pos = offset + jnp.arange(CHUNK)
+    pg = jnp.where(jnp.arange(CHUNK) < count, bt_row[pos // PL], 0)
+    att, _ = jax.jit(
+        lambda pool: latent.prefill(
+            rt.params, 'layer_%d_' % layer, CFG, h, pos, offset + count,
+            pool, layer, pg, pos % PL, bt_row, kernel))(jnp.asarray(pool))
+    return np.asarray(att)
+
+
+@pytest.mark.parametrize('offset,count', [
+    (0, CHUNK),         # offset 0: one block, the chunk its own context
+    (24, CHUNK),        # mid-context, ends on a block's last row
+    (32, CHUNK),        # ends inside the third block
+    (40, 3),            # a short final chunk: five rows of padded tail
+], ids=['offset_0', 'mid_context', 'ends_mid_block', 'padded_tail'])
+def test_the_kernel_is_the_block_loop_on_the_same_cache(rt, monkeypatch,
+                                                        offset, count):
+    """`latent_prefill` (interpret mode) against the composed block loop
+    it replaces, blocks of 16: the same rows written, the same rows
+    attended.  Every block past the context holds NaN and neither route
+    visits it; the padded tail's queries see the same rows on both."""
+    monkeypatch.setattr(latent, '_PREFILL_KEY_BLOCK', 16)
+    visited = latent.prefill_rows(offset + count, CFG['max_len'])
+    assert visited == -(-(offset + count) // 16) * 16
+    got = _one_chunk(rt, True, offset, count, poison=visited)
+    want = _one_chunk(rt, False, offset, count, poison=visited)
+    assert np.isfinite(got).all() and np.isfinite(want).all()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    if visited > offset + CHUNK:
+        # one block sooner and the chunk would have met the NaN
+        assert not np.isfinite(_one_chunk(rt, True, offset, count,
+                                          poison=visited - 16)).all()
+
+
+@pytest.mark.parametrize('kernel', [True, False],
+                         ids=['kernel', 'composed'])
+def test_the_chunk_lowers_the_route_the_rule_chose(rt, kernel):
+    """The prefill program holds the kernel where the rule allows and
+    the block loop where it does not; either lowering counts once a
+    layer."""
+    sds = rt._sds
+    i32, f32 = sds((), jnp.int32), sds((), jnp.float32)
+    before = dict(obs.counters())
+    text = jax.jit(
+        decode._prefill_fn(rt.cfg, rt.cache, CHUNK, latent_kernel=kernel),
+        donate_argnums=(1,)).lower(
+            rt._param_structs(), rt._state_structs(),
+            sds((rt.cache.max_pages,), jnp.int32), sds((CHUNK,), jnp.int32),
+            i32, i32, i32, i32, f32, i32).as_text(debug_info=True)
+    c = {k: v - before.get(k, 0) for k, v in obs.counters().items()}
+    L = CFG['n_layer']
+    assert c.get('latent.prefill_kernel', 0) == (L if kernel else 0)
+    assert c.get('latent.prefill_composed', 0) == (0 if kernel else L)
+    assert 'attn.latent.scores' in text
+    # the block loop's scores are an array of every head's; the kernel
+    # (interpret mode here: plain operations, a head and a block at a
+    # time) makes none of that extent
+    scores = 'tensor<%dx%dx%dxf32>' % (CFG['n_head'], CHUNK, CFG['max_len'])
+    assert (scores in text) is (not kernel)
+
+
+def test_the_composed_chunk_gives_the_same_tokens(weights, rt):
+    """Under a mesh the chunk's scores go through the block loop
+    (`rt.prefill_kernel` False): the same stream."""
+    assert rt.prefill_kernel
+    want = rt.generate(_prompt(21, 4), 6, steps_per_window=WINDOW)
+    plain = DecodeRuntime(weights, CFG, slots=3, prefill_chunk=CHUNK,
+                          page_len=PAGE)
+    plain.prefill_kernel = False
+    before = dict(obs.counters())
+    assert plain.generate(_prompt(21, 4), 6, steps_per_window=WINDOW) == want
+    c = {k: v - before.get(k, 0) for k, v in obs.counters().items()}
+    assert c['latent.prefill_composed'] == CFG['n_layer']
+    assert 'latent.prefill_kernel' not in c or not c['latent.prefill_kernel']
+
+
+@pytest.mark.parametrize('shape,dtype,chunk,block,dims,devices,want', [
+    ((9, 7, 16, 640), 'bfloat16', 512, 1024, (512, 128, 128), 1, True),
+    ((9, 7, 16, 640), 'float32', 512, 1024, (512, 128, 128), 1, True),
+    ((9, 7, 16, 576), 'bfloat16', 512, 1024, (512, 128, 128), 1, False),
+    ((9, 7, 16, 640), 'bfloat16', 512, 1024, (512, 96, 128), 1, False),
+    ((9, 7, 16, 640), 'bfloat16', 512, 1024, (512, 128, 64), 1, False),
+    ((9, 7, 16, 640), 'bfloat16', 512, 1024, (448, 128, 128), 1, False),
+    ((9, 7, 16, 640), 'bfloat16', 512, 720, (512, 128, 128), 1, False),
+    ((9, 7, 16, 640), 'bfloat16', 8, 1024, (512, 128, 128), 1, False),
+    ((9, 7, 16, 640), 'float32', 8, 1024, (512, 128, 128), 1, True),
+    ((9, 7, 16, 640), 'int8', 512, 1024, (512, 128, 128), 1, False),
+    ((9, 7, 16, 640), 'bfloat16', 512, 1024, (512, 128, 128), 4, False)],
+    ids=['axk1', 'axk1_f32', 'row_not_lane_tiles', 'nope_96', 'v_64',
+         'rank_448', 'key_block_720', 'chunk_half_a_tile', 'chunk_8_f32',
+         'int8', 'mesh_of_4'])
+def test_on_an_accelerator_the_chunk_rule_asks_for_whole_tiles(
+        monkeypatch, shape, dtype, chunk, block, dims, devices, want):
+    from paddle_tpu.ops import _pallas
+    monkeypatch.setattr(_pallas, 'interpret', lambda: False)
+
+    class Mesh(object):
+        size = devices
+    assert ops_attention.latent_prefill_eligible(
+        shape, dtype, chunk, block, *dims,
+        mesh=None if devices == 1 else Mesh()) is want

@@ -253,6 +253,59 @@ def test_mosaic_compiles_the_latent_kernel_at_real_widths(one_v5e_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
 
 
+@pytest.mark.parametrize('kernel', [True, False],
+                         ids=['kernel', 'composed'])
+def test_a_latent_chunk_keeps_its_scores_on_chip_at_real_widths(
+        one_v5e_chip, monkeypatch, kernel):
+    """One layer of `latent.prefill` at axk1.shared_context_answers'
+    extents (64 heads, a chunk of 512 over a table of 7,184 rows, blocks
+    of 1,024), compiled for the chip: through `latent_prefill` the
+    program holds ONE Mosaic call and no array of the scores' extents in
+    any dtype; the composed block loop, the route under a mesh, is what
+    holds them."""
+    import jax
+    from paddle_tpu.ops import _pallas
+    from paddle_tpu.serving.generation import latent
+    monkeypatch.setattr(_pallas, 'interpret', lambda: False)
+    cfg = {'n_head': 64, 'theta': 1e4, 'rms_eps': 1e-6,
+           'latent': {'q_rank': 1536, 'kv_rank': 512, 'nope': 128,
+                      'rope': 64, 'v': 128,
+                      'yarn': {'factor': 32.0, 'beta_fast': 32.0,
+                               'beta_slow': 1.0, 'original_max_len': 4096,
+                               'mscale': 1.0, 'mscale_all_dim': 1.0}}}
+    C, layers = 512, 7
+    cache = CacheConfig(slots=64, layers=layers, kv_heads=1, max_len=7184,
+                        head_dim=640, dtype='bfloat16', page_len=16,
+                        pages=16385, latent=512)
+    assert latent.prefill_kernel(cfg, cache, C)
+    assert not latent.prefill_kernel(cfg, cache, C + 8)   # half a tile
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                    sharding=one_v5e_chip)
+
+    public = {n: sds(shape, 'bfloat16') for n, shape in
+              latent.weight_shapes(7168, 64, cfg['latent']).items()}
+    parts = jax.eval_shape(
+        lambda qb, kva, kvb: latent.prepare(qb, kva, kvb, 64, 128, 64, 128),
+        public['att_qb_w'], public['att_kva_w'], public['att_kvb_w'])
+    w = {'l_' + n: a for n, a in public.items()}
+    names = [t for slot in latent.PREPARED for t in latent.PREPARED[slot]]
+    w.update({'l_' + n: sds(a.shape, a.dtype)
+              for n, a in zip(names, parts)})
+    i32 = 'int32'
+    text = jax.jit(
+        lambda w, h, pos, n, pool, pg, rw, bt: latent.prefill(
+            w, 'l_', cfg, h, pos, n, pool, 3, pg, rw, bt, kernel),
+        donate_argnums=(4,)).lower(
+            w, sds((C, 7168), 'float32'), sds((C,), i32), sds((), i32),
+            sds(cache.pool_shape, 'bfloat16'), sds((C,), i32),
+            sds((C,), i32), sds((cache.max_pages,), i32)).compile().as_text()
+    assert text.count('tpu_custom_call') == (1 if kernel else 0)
+    scores = [ln for ln in text.splitlines() if '[64,512,1024]' in ln]
+    assert bool(scores) is (not kernel)
+
+
 @pytest.mark.parametrize('causal,lengths', [(True, False), (False, True)],
                          ids=['self', 'cross'])
 def test_short_attention_keeps_its_scores_on_chip_at_tbase_widths(
