@@ -141,7 +141,7 @@ def _zero_cotangent(v):
 # upcast internally to f32 in their impls, so precision-critical
 # reductions never run in bf16 either way.
 _AMP_CAST_OPS = {'mul', 'matmul', 'flash_attention', 'ring_attention',
-                 'bilinear_tensor_product'}
+                 'attn_out_proj', 'bilinear_tensor_product'}
 _AMP_FLOW_OPS = {'conv2d', 'conv3d', 'conv2d_transpose',
                  'conv3d_transpose', 'sequence_conv'}
 _AMP_OPS = _AMP_CAST_OPS | _AMP_FLOW_OPS

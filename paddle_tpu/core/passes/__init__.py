@@ -19,6 +19,11 @@ clone in place):
                     only): completes sharding specs, materializes D018
                     edges as explicit reshard/grad_allreduce/all_gather
                     collectives, ZeRO-shards optimizer state
+  attn_layout       split-heads chains around a `flash_attention`: the op
+                    and the projection behind it (its `mul` retyped
+                    `attn_out_proj`) learn where their operands come
+                    from (input slots; nothing is removed), so the tiled
+                    route can write and read the tile loop's own layout
   fuse_elementwise  consecutive elementwise/glue runs -> one
                     fused_elementwise op replaying the sub-program
   canon             64-bit attr narrowing + cross-block initializer dedup
@@ -40,7 +45,7 @@ import os
 import time
 
 from . import walker  # noqa: F401  (re-exported for analysis/)
-from . import dce, const_fold, cse, fuse, canon, shard
+from . import dce, const_fold, cse, fuse, canon, shard, attn_layout
 
 __all__ = ['enabled', 'skip_set', 'config_token', 'optimize_program',
            'maybe_optimize', 'pass_names', 'PASSES', 'walker']
@@ -50,6 +55,7 @@ PASSES = (
     ('const_fold', const_fold.run),
     ('cse', cse.run),
     ('shard', shard.run),
+    ('attn_layout', attn_layout.run),
     ('fuse_elementwise', fuse.run),
     ('canon', canon.run),
 )

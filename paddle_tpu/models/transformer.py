@@ -31,18 +31,24 @@ def multi_head_attention(q_in, kv_in, mask, d_model, n_head, dropout,
     """mask: [B, 1, Tq, Tk] additive (-1e9 on invalid); kv_lengths int [B]
     (used by the flash path, where pad is a suffix)."""
     d_head = d_model // n_head
-    # fused projections: self-attention projects q,k,v as ONE d x 3d
-    # GEMM (cross-attention fuses k,v as d x 2d) and splits the result.
-    # Measured ~parity end-to-end at B=32/T=256 (+0.2%, PERF.md r5) —
-    # XLA was already handling the three small GEMMs well — kept because
-    # it reads the activations once and is never slower.
-    # amp_keep_bf16 flow-through was ALSO measured for the block
-    # interior (q/k/v + scores + weights + context, and separately the
-    # ffn hidden): both lose ~0.5% — the f32 [B,H,T,T] residual copies
-    # the ledger flagged are cheaper than the extra converts the bf16
-    # interior induces around the f32 softmax statistics.  Cast-back
-    # stays the block-interior policy; only the logits projection flows
-    # (PERF.md r5).
+    # fused projections: self-attention projects q, k, v as ONE d x 3d
+    # product (cross-attention k, v as d x 2d) and splits the result, the
+    # reference's layout of the weights.  What lowers depends on the
+    # attention's route (ops/attention.py): on the whole-batch and Pallas
+    # routes the one GEMM and the split, as written; where the attention
+    # tiles the batch (tbase.train_1chip: 96 x 256 tokens) the rewriter
+    # (core/passes/attn_layout.py) lets the op slice the WEIGHT and write
+    # each operand in the tile loop's layout, since the split and the
+    # copies into that layout cost 5.7 ms of a 103.4 ms step while the
+    # [512,512] products cost what the fused ones did (0.166 s against
+    # 0.160 s over 32 traced steps; PERF.md section 6, PR 50, chip
+    # runs).  Separate q/k/v fc's would lower the same there and read
+    # the activations three times elsewhere.
+    # AMP: the block interior casts back to f32 after each product
+    # (core/executor.py _AMP_CAST_OPS); only the logits projection keeps
+    # bf16 (amp_keep_bf16).  The numbers that chose this (bf16 interior
+    # -0.5 %) are from an installation that no longer exists (PERF.md
+    # section 6, "PRs 1-20: not verified"); no cell has re-read them.
     # the fused [d, 3d] weight pins Xavier fans to the SEPARATE
     # projections' (d, d) so each q/k/v slice keeps the exact init
     # distribution of three unfused fc's (fan_out would otherwise

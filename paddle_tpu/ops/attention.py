@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from ..core.registry import register
 from . import _pallas
+from .math import mul
 
 _NEG_INF = -1e30
 _LSE_LANES = 8   # trailing broadcast dim that makes (1, bq) rows tileable
@@ -314,6 +315,34 @@ def _composed_tile(B, H, Tq, Tk):
     return c if 4 * c * per_seq >= _COMPOSED_TILE_BYTES else B
 
 
+def _composed_plan(B, H, Tq, Tk, mesh):
+    """(sequences a device holds, sequences a tile holds) on the composed
+    route; equal where there is no loop."""
+    shards = 1 if mesh is None else mesh.size
+    local = B // shards
+    if shards == 1 or (
+            mesh.shape.get('data') == shards and B % shards == 0):
+        return local, _composed_tile(local, H, Tq, Tk)
+    return local, local
+
+
+def _takes_pallas(Tq, Tk, D, block_q, block_k, mesh):
+    bq, bk = min(block_q, Tq), min(block_k, Tk)
+    return not (Tq % bq or Tk % bk or D % 8 or Tk < _FWD_PALLAS_MIN_T
+                or not _pallas.single_device(mesh))
+
+
+def takes_tile_loop(B, H, Tq, Tk, D, mesh=None, block_q=128, block_k=128):
+    """Whether `flash_attention` at these extents lowers to the loop over
+    tiles of the batch: the ONE rule the op, and the projections beside
+    it that the rewriter marked (core/passes/attn_layout.py), decide
+    their lowering by."""
+    if _takes_pallas(Tq, Tk, D, block_q, block_k, mesh):
+        return False
+    local, c = _composed_plan(B, H, Tq, Tk, mesh)
+    return c != local
+
+
 def _composed_attention(q, k, v, causal, scale, k_len, mesh=None):
     """`_ref_attention`, run over tiles of the batch where its scores
     would not stay on chip.  Attention is independent per sequence, so a
@@ -328,12 +357,7 @@ def _composed_attention(q, k, v, causal, scale, k_len, mesh=None):
     B, H, Tq, _ = q.shape
     Tk = k.shape[2]
     shards = 1 if mesh is None else mesh.size
-    local = B // shards
-    if shards == 1 or (
-            mesh.shape.get('data') == shards and B % shards == 0):
-        c = _composed_tile(local, H, Tq, Tk)
-    else:
-        c = local
+    local, c = _composed_plan(B, H, Tq, Tk, mesh)
     if c == local:
         metrics.counter('attention.composed_whole').inc()
         return _ref_attention(q, k, v, causal, scale, k_len)
@@ -380,8 +404,7 @@ def flash_attention(q, k, v, causal=False, scale=None, k_len=None,
         k_len = jnp.full((q.shape[0],), Tk, jnp.int32)
     k_len = k_len.astype(jnp.int32)
     bq, bk = min(block_q, Tq), min(block_k, Tk)
-    if Tq % bq or Tk % bk or D % 8 or Tk < _FWD_PALLAS_MIN_T \
-            or not _pallas.single_device(mesh):
+    if not _takes_pallas(Tq, Tk, D, block_q, block_k, mesh):
         # shapes the kernel can't tile, short-context sizes where the
         # composed path measures faster, or a launch over several
         # devices — composed (jax AD backward), tiled over the batch
@@ -577,16 +600,124 @@ def _flash_backward(q, k, v, k_len, out, lse, g_out, causal, scale,
 
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _column_slices(w, pieces):
+    """`w [M, pieces * N]` as `pieces` arrays `[M, N]`.  The rule below
+    joins their cotangents in ONE concatenation; plain AD pads each to
+    the weight's extent and adds them, three passes over a fused q/k/v
+    weight's gradient where there was none."""
+    n = w.shape[1] // pieces
+    return tuple(w[:, i * n:(i + 1) * n] for i in range(pieces))
+
+
+def _column_slices_fwd(w, pieces):
+    return _column_slices(w, pieces), None
+
+
+def _column_slices_bwd(pieces, _, cts):
+    return (jnp.concatenate(cts, axis=1),)
+
+
+_column_slices.defvjp(_column_slices_fwd, _column_slices_bwd)
+
+
+def heads_in_loop_layout(x, w, heads):
+    """`x [B, T, M]` times `w [M, heads * D]`, as heads `[B, heads, T,
+    D]`, the way the tile loop reads them: XLA keeps T on the lanes there
+    (a 64-wide head would fill half a lane tile), and the `while` lets no
+    neighbour fuse a relayout, so the dot itself is written `[B, heads *
+    D, T]` and the head split and the last two axes' swap are bitcasts.
+    Same contraction as `mul` followed by the program's reshape and
+    transpose."""
+    y = jnp.einsum('btm,mn->bnt', x, w)
+    B, N, T = y.shape
+    return jnp.swapaxes(y.reshape(B, heads, N // heads, T), 2, 3)
+
+
+def _merged(o):
+    B, H, T, D = o.shape
+    return jnp.swapaxes(o, 2, 3).reshape(B, H * D, T)
+
+
+@jax.custom_vjp
+def project_from_loop_layout(o, w):
+    """The output projection `[B, T, H*D] x [H*D, M]` read from the tile
+    loop's own result `o [B, H, T, D]`, which lies `[B, H*D, T]` in
+    memory.  Plain AD would write this product's cotangent `[B, T, H*D]`
+    (the cotangent's free axes first), a copy away from the backward
+    loop; the rule below puts the weight first, so the dot writes
+    `[H*D, B, T]` with T on the lanes, as `heads_in_loop_layout` does."""
+    return jnp.einsum('bnt,nm->btm', _merged(o), w)
+
+
+def _project_fwd(o, w):
+    return project_from_loop_layout(o, w), (o, w)
+
+
+def _project_bwd(res, g):
+    o, w = res
+    B, H, T, D = o.shape
+    do = jax.lax.dot_general(w.astype(g.dtype), g,
+                             (((1,), (2,)), ((), ())))        # [N, B, T]
+    do = jnp.swapaxes(do.transpose(1, 0, 2).reshape(B, H, D, T), 2, 3)
+    dw = jnp.einsum('bnt,btm->nm', _merged(o), g)
+    return do.astype(o.dtype), dw.astype(w.dtype)
+
+
+project_from_loop_layout.defvjp(_project_fwd, _project_bwd)
+
+
+def _op_takes_tile_loop(ctx, out, k):
+    """`takes_tile_loop` for an attention op whose result is `out [B, H,
+    Tq, D]` over keys `k`, as the executor lowers it: asked by the op and
+    by the projection behind it, so both take one route."""
+    B, H, Tq, D = out.shape
+    return takes_tile_loop(B, H, Tq, k.shape[2], D,
+                           getattr(ctx, 'mesh', None))
+
+
 @register('flash_attention')
 def flash_attention_op(ctx, ins, attrs):
+    """`ProjX`, `ProjW` and attr `proj` (core/passes/attn_layout.py) name
+    the projections Q, K and V come from; the tiled route computes them
+    itself, in the loop's layout, and leaves `Q`, `K`, `V` unread."""
     q, k, v = ins['Q'], ins['K'], ins['V']
     k_len = ins.get('KLength')
     if k_len is not None and k_len.ndim > 1:
         k_len = k_len.reshape(-1)
+    proj = attrs.get('proj')
+    if proj and _op_takes_tile_loop(ctx, q, k):
+        from ..observability import metrics
+        metrics.counter('attention.operands_in_loop_layout').inc()
+        # a fused weight is sliced (free), not its product's activations
+        # (a pass through HBM)
+        sliced, operands = {}, []
+        for t, i in zip((q, k, v), (0, 4, 8)):
+            xi, wi, piece, pieces = proj[i:i + 4]
+            if xi < 0:
+                operands.append(t)
+                continue
+            if wi not in sliced:
+                sliced[wi] = _column_slices(ins['ProjW'][wi], pieces)
+            operands.append(heads_in_loop_layout(
+                ins['ProjX'][xi], sliced[wi][piece], t.shape[1]))
+        q, k, v = operands
     return {'Out': flash_attention(
         q, k, v, causal=attrs.get('causal', False),
         scale=attrs.get('scale', None), k_len=k_len,
         mesh=getattr(ctx, 'mesh', None))}
+
+
+@register('attn_out_proj')
+def attn_out_proj_op(ctx, ins, attrs):
+    """The `mul` behind a `flash_attention` (core/passes/attn_layout.py
+    retypes it): `X [B, T, H*D]` is the program's transpose and reshape
+    of `AttnOut [B, H, T, D]`, the attention's result over keys `AttnK`.
+    Where that attention ran its tile loop the product reads `AttnOut` in
+    the loop's layout and leaves `X` unread; elsewhere it is `mul`."""
+    if _op_takes_tile_loop(ctx, ins['AttnOut'], ins['AttnK']):
+        return {'Out': project_from_loop_layout(ins['AttnOut'], ins['Y'])}
+    return mul(ctx, {'X': ins['X'], 'Y': ins['Y']}, attrs)
 
 
 @register('ring_attention')
