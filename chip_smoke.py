@@ -13,10 +13,8 @@ the repo benchmarks, with seeded random weights:
                      steps (the conv / bf16 flow-through side)
   kernels            every Pallas kernel a launch can select (flash,
                      DMA gather, ssm_step, latent_attention,
-                     latent_prefill) and the kernel
-                     tier's `row`
-                     plans, which no launch selects since PR 41, compiled
-                     by Mosaic and compared with their references
+                     latent_prefill), compiled by Mosaic and compared
+                     with its reference
   serve              GenerationEngine over DecodeRuntime at the llama_1b
                      widths: four concurrent streams, twice, same tokens
   multichip          (>= 4 devices) transformer-base through
@@ -38,7 +36,6 @@ import json
 import math
 import sys
 import time
-from unittest import mock
 
 import numpy as np
 
@@ -71,12 +68,7 @@ SIZES = {
                 heads=64, chunk=512, rows=7184, key_block=1024, kv_rank=512,
                 nope=128, rope=64, v=128, width=640,
                 cases=((0, 512), (4608, 512), (5000, 512), (6144, 37)),
-                dtype='bfloat16', tol=1e-4),
-            softmax=(32, 8, 256, 256),
-            # transformer-base widths, one layer: layers share their
-            # fused-group signatures, so one layer builds every plan
-            groups=dict(n_layer=1, d_model=512, n_head=8, d_inner=2048,
-                        vocab=32000, batch=32, seq=256)),
+                dtype='bfloat16', tol=1e-4)),
         'serve': dict(config='llama_1b', n_layer=16, slots=8,
                       prompt_lens=(64, 192, 320, 512), max_new=32,
                       prefill_chunk=128, decode_window=8),
@@ -101,10 +93,7 @@ SIZES = {
                 heads=4, chunk=8, rows=60, key_block=16, kv_rank=16, nope=8,
                 rope=4, v=8, width=128,
                 cases=((0, 8), (24, 8), (40, 3)),
-                dtype='float32', tol=2e-5),
-            softmax=(2, 2, 16, 16),
-            groups=dict(n_layer=1, d_model=32, n_head=2, d_inner=64,
-                        vocab=128, batch=2, seq=16)),
+                dtype='float32', tol=2e-5)),
         'serve': dict(config='tiny', n_layer=2, slots=8,
                       prompt_lens=(4, 8, 12, 16), max_new=8,
                       prefill_chunk=4, decode_window=4),
@@ -301,12 +290,6 @@ def train_resnet50(cfg):
 
 
 # ------------------------------------------------------------- kernels
-
-# The kernel tier's kinds that Mosaic compiles.  'ew' is not among them:
-# Mosaic on jax 0.9.0 / libtpu 0.0.34 refuses its (1,) VMEM scalars, its
-# scalar-core pow and its in-kernel 1-D tile (PERF.md section 6, PR 21).
-MOSAIC_KINDS = ('attention', 'row')
-
 
 def _mosaic_calls(fn, *args):
     """Compile `fn` ahead of time and count the Mosaic kernels in its
@@ -559,84 +542,8 @@ def _latent_prefill_check(cfg):
     return out
 
 
-def _run_softmax_group(fluid, shape):
-    """A program whose fused group holds a softmax, so the `row` kind's
-    other kernel has a plan to check."""
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        with fluid.unique_name.guard():
-            x = fluid.layers.data('sm_x', shape=list(shape[1:]),
-                                  dtype='float32')
-            y = fluid.layers.softmax(fluid.layers.scale(x, scale=2.0))
-    feed = {'sm_x': np.random.RandomState(SEED).randn(*shape)
-            .astype('float32')}
-    out, = fluid.Executor().run(main, feed=feed, fetch_list=[y],
-                                scope=fluid.Scope(),
-                                use_program_cache=False)
-    np.testing.assert_allclose(out.sum(-1), 1.0, rtol=1e-5)
-
-
-def _plan_inputs(plan):
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.ops import kernelgen as kg
-    rng = np.random.RandomState(SEED)
-    xs = []
-    for shape, dtype in plan.in_avals:
-        if np.dtype(dtype).kind in 'iub':
-            # lengths, masks, step counters: ones are valid for all
-            xs.append(jnp.ones(shape, dtype))
-        else:
-            # (0.25, 0.75): positive, so sqrt / log / pow stay finite
-            xs.append(jnp.asarray(rng.uniform(0.25, 0.75, shape), dtype))
-    base = jax.random.key(SEED)
-    keys = kg._keys_for(plan.attrs,
-                        lambda si, sub: jax.random.fold_in(base, si))
-    return tuple(xs), keys
-
-
-def _plans_check():
-    """Re-run every kernelgen plan this process built (for MOSAIC_KINDS)
-    that holds a generated kernel, standalone, against the replay of its
-    sub-ops (the plan's own backward reference).  A plan of XLA steps
-    only IS its replay; it is counted, not run."""
-    import jax
-    from paddle_tpu.ops import kernelgen as kg
-    checked = {'plans': 0, 'pallas_kernels': 0, 'xla_steps': 0,
-               'xla_only_plans': 0}
-    for plan in kg.plans():
-        if not plan.n_kernels + plan.n_dsteps:
-            checked['xla_only_plans'] += 1
-            continue
-        xs, keys = _plan_inputs(plan)
-        compiled, n_calls = _mosaic_calls(plan.fn, xs, keys)
-        types = {s['type'] for s in plan.attrs['sub_ops']}
-        if types & {'softmax', 'layer_norm'}:
-            _assert_mosaic('row plan %s' % sorted(types), n_calls, 1)
-        got = compiled(xs, keys)
-        with jax.default_matmul_precision('highest'):
-            ref = jax.jit(plan.ref)(xs, keys)
-        for name, a, b in zip(plan.attrs['out_names'], got, ref):
-            assert a.dtype == b.dtype and a.shape == b.shape, name
-            if np.dtype(a.dtype).kind in 'iub':
-                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-            else:
-                # same f32 expressions on both sides; a row kernel sums
-                # its row in another order than XLA's fusion (1e-6
-                # relative), and a bf16 result may round the other way
-                # (2^-8).  On the CPU both sides are bitwise equal
-                tol = 1e-2 if a.dtype == 'bfloat16' else 1e-5
-                _close('plan output %s' % name, a, b, tol)
-        checked['plans'] += 1
-        checked['pallas_kernels'] += plan.n_kernels + plan.n_dsteps
-        checked['xla_steps'] += plan.n_xla
-    return checked
-
-
 def kernels(cfg):
-    import paddle_tpu as fluid
     from paddle_tpu.ops import attention as att
-    from paddle_tpu.ops import kernelgen as kg
     t0 = time.perf_counter()
     flash = cfg['flash']
     assert att._FWD_PALLAS_MIN_T <= flash['seq_resident'] \
@@ -650,32 +557,6 @@ def kernels(cfg):
         'latent_attention': _latent_attention_check(cfg['latent']),
         'latent_prefill': _latent_prefill_check(cfg['latent_prefill']),
     }
-    # The kernel tier is off by default on every backend (PR 41: its one
-    # Mosaic kernel cost tbase.train_1chip 3 % of its rate), and
-    # PT_KERNELGEN=1 asks for the `ew` kind Mosaic refuses: no launch
-    # builds a plan on a chip.  While the tier's code is in the tree
-    # (ROADMAP D4) this phase turns on, for its own two programs, the
-    # kinds Mosaic compiles, and holds each plan to its replay: the plans
-    # of the transformer's fused groups (LayerNorm rows, attention; the
-    # elementwise chains, the LR schedule and the fused Adam as XLA steps)
-    # and of a softmax group.  use_program_cache=False: a step served
-    # from the disk cache is never traced, and an untraced step builds no
-    # plan
-    with mock.patch.object(kg, 'pallas_kinds', lambda: MOSAIC_KINDS):
-        main, startup, loss, feed = _transformer_program(fluid,
-                                                         cfg['groups'])
-        exe, scope = fluid.Executor(), fluid.Scope()
-        with fluid.scope_guard(scope):
-            exe.run(startup)
-            value, = exe.run(main, feed=feed, fetch_list=[loss],
-                             use_program_cache=False)
-        assert math.isfinite(float(np.asarray(value).ravel()[0]))
-        _run_softmax_group(fluid, cfg['softmax'])
-        out['plans'] = _plans_check()
-    assert out['plans']['plans'] > 0, 'the tier was on and built no plan'
-    kinds = kg.pallas_kinds()
-    out['kinds_on'] = list(kinds)
-    out['kinds_off'] = [k for k in kg.ALL_KINDS if k not in kinds]
     _assert_no_fallbacks()
     out['wall_s'] = round(time.perf_counter() - t0, 1)
     _say('kernels', **out)

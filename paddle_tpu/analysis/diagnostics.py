@@ -22,7 +22,6 @@ Code table (docs/analysis.md has the full semantics):
   D013 warning  numerical hazard: softmax built without max-subtraction
   D014 warning  degenerate learning-rate decay constant
   D015 info     op not emit-capable (direct emitter would fall back)
-  D016 info     fused sub-op not kernelgen-capable (replay fallback)
   D017 error    sharding conflict (producers force incompatible specs)
   D018 warning  implicit reshard (consumed spec differs from delivered)
   D019 error    mesh-axis mismatch (spec names an undeclared mesh axis)
@@ -53,7 +52,6 @@ CODES = {
     'D013': 'softmax without max-subtraction',
     'D014': 'degenerate lr decay',
     'D015': 'op not emit-capable',
-    'D016': 'fused sub-op not kernelgen-capable',
     'D017': 'sharding conflict',
     'D018': 'implicit reshard',
     'D019': 'mesh-axis mismatch',

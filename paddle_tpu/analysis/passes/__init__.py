@@ -12,7 +12,6 @@ from . import aliasing  # noqa: F401
 from . import retrace  # noqa: F401
 from . import numeric  # noqa: F401
 from . import emit_coverage  # noqa: F401
-from . import kernelgen_coverage  # noqa: F401
 from . import sharding  # noqa: F401
 from . import memplan  # noqa: F401
 from . import donation  # noqa: F401

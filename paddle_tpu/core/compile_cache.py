@@ -22,10 +22,9 @@ version, backend platform + chip kind) — and stored in two tiers:
 ``cache_dir()`` is the ONE place the directory is decided:
 ``$JAX_COMPILATION_CACHE_DIR`` when set (JAX reads that variable itself,
 so no code here touches ``jax_compilation_cache_dir`` then), else
-``<checkout>/.jax_cache``.  JAX's persistent cache, the L2 store
-(``v<FMT>/``) and the kernelgen autotune choices (``autotune/``) all live
-under it, so whoever runs the program can place — and keep — every
-compile artifact by setting one variable.
+``<checkout>/.jax_cache``.  JAX's persistent cache and the L2 store
+(``v<FMT>/``) both live under it, so whoever runs the program can place
+— and keep — every compile artifact by setting one variable.
 
 Corrupt, truncated, or version-mismatched disk entries are MISSES, never
 errors: the entry is deleted and the caller recompiles.  Disable the disk

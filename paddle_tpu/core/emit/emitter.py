@@ -195,30 +195,6 @@ def _op_streams(op, op_index):
     return tuple(out)
 
 
-class _FusedEmitCtx(object):
-    """Ctx handed to a fused_elementwise emit rule (the kernelgen
-    tier): the traced base key, this op's pinned per-sub stream bases,
-    and the policy flags the replay would have applied."""
-
-    __slots__ = ('key', 'streams', 'amp', 'mesh')
-
-    def __init__(self, key, streams, amp, mesh):
-        self.key = key
-        self.streams = streams
-        self.amp = amp
-        self.mesh = mesh
-
-
-def _kg_token():
-    """Kernelgen on/off + version: part of the memo key so flipping
-    PT_KERNELGEN mid-process can't serve stale memoized functions."""
-    try:
-        from ...ops import kernelgen as _kg
-        return _kg.config_token()
-    except Exception:
-        return None
-
-
 def _replay_fused(ins, attrs, amp, mesh, key, streams):
     """Inline replay of a fused_elementwise sub-program (ops/fused.py
     semantics), dispatching each sub-op to its emit rule when one
@@ -287,8 +263,7 @@ def _memo_fn(op, ins, amp, dmask, mesh):
         lambda x: (np.shape(x), str(jnp.result_type(x))), ins)
     dkey = tuple(sorted(dmask.items()))
     key = (op.type, _canon_attrs(op.type, op.attrs), _canonv(avals),
-           use_amp, amp, dkey, _mesh_key(mesh),
-           _kg_token() if op.type == 'fused_elementwise' else None)
+           use_amp, amp, dkey, _mesh_key(mesh))
     fn = _MEMO.get(key)
     if fn is None:
         attrs = op.attrs
@@ -309,12 +284,7 @@ def _memo_fn(op, ins, amp, dmask, mesh):
             if amp:
                 kw2 = _ex._amp_match_ins(otype, kw2)
             if fused:
-                if od.emit is not None:
-                    outs = od.emit(_FusedEmitCtx(bkey, streams, amp,
-                                                 mesh), kw2, attrs)
-                else:
-                    outs = _replay_fused(kw2, attrs, amp, mesh, bkey,
-                                         streams)
+                outs = _replay_fused(kw2, attrs, amp, mesh, bkey, streams)
             else:
                 ctx = EmitCtx(bkey, streams[0] if streams else None,
                               amp, mesh, otype)

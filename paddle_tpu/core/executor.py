@@ -180,11 +180,11 @@ def _amp_match_ins(op_type, ins):
 
 def _amp_sub_ins(op_type, ins, amp):
     """The FULL per-op AMP input policy the trace loop below applies,
-    for replayed sub-ops (ops/fused.py, the emitter's _replay_fused, the
-    kernelgen dedicated steps): _AMP_OPS get every input cast to bf16
-    before dispatch, then the elementwise-match glue runs.  A fused
-    group containing e.g. flash_attention must see the same activations
-    it would have unfused."""
+    for replayed sub-ops (ops/fused.py, the emitter's _replay_fused):
+    _AMP_OPS get every input cast to bf16 before dispatch, then the
+    elementwise-match glue runs.  A fused group containing e.g.
+    flash_attention must see the same activations it would have
+    unfused."""
     import jax.numpy as jnp
     if not amp:
         return ins
@@ -462,24 +462,7 @@ def _launch_signature(program, feed_vals, feed_names, fetch_names, steps,
                      for n in feed_names},
         fetch_set=fetch_names, steps=steps, check_nan=check_nan,
         scope=scope._serial, opt=_passes.config_token(),
-        emit=_emit.config_token(), kernelgen=_kg_token())
-
-
-def _kg_token():
-    from ..ops import kernelgen as _kg
-    return _kg.config_token()
-
-
-def _compose_fp_extra(engine_extra):
-    """Compose the emitter's fingerprint extra with kernelgen's.  When
-    kernelgen is off the engine extra passes through UNCHANGED (same
-    fingerprints as before the tier existed — disk artifacts stay
-    shared); when on, both paths gain the kernelgen component."""
-    from ..ops import kernelgen as _kg
-    if not _kg.enabled():
-        return engine_extra
-    kx = _kg.fingerprint_extra()
-    return (engine_extra, kx) if engine_extra is not None else kx
+        emit=_emit.config_token())
 
 
 def _lower(program, feed_names, fetch_names, donate=True, mesh=None,
@@ -1091,8 +1074,7 @@ class Executor(object):
                 tuple((n,) + _feed_spec(feed_vals[n])
                       for n in sorted(feed_vals)),
                 fetch_names, self.check_nan, steps,
-                _passes.config_token(), _emit.config_token(),
-                _kg_token())
+                _passes.config_token(), _emit.config_token())
 
     def _shard_targets_for(self, program, params_in):
         """Param -> NamedSharding targets from `program._sharding`.
@@ -1250,17 +1232,13 @@ class Executor(object):
             # emit-mode entries carry the emitter version + coverage set
             # in the key; fallback (and PT_EMIT=0) entries use extra=None
             # so traced artifacts are SHARED across modes on disk.
-            # kernelgen (when on) composes its version + rule coverage
-            # into the extra on BOTH modes — generated kernels change
-            # what lowers on the traced path too
             return _cc.launch_fingerprint(
                 opt_program,
                 {n: _feed_spec(feed_vals[n]) for n in feed_names},
                 fetch_names, steps, self.check_nan, mesh=self.mesh,
                 param_specs={n: _feed_spec(v) for n, v in params.items()},
-                extra=_compose_fp_extra(
-                    engine.fingerprint_extra() if engine is not None
-                    else None))
+                extra=engine.fingerprint_extra() if engine is not None
+                else None)
 
         call, fp, disk_tier = None, None, None
         if _cc.disk_enabled():

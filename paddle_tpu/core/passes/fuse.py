@@ -28,21 +28,20 @@ Bitwise parity with the unfused program is preserved by construction:
 """
 import numpy as np
 
-__all__ = ['run', 'FUSABLE_OPS', 'FUSED_OP', 'KERNEL_TIER_OPS']
+__all__ = ['run', 'FUSABLE_OPS', 'FUSED_OP']
 
 FUSED_OP = 'fused_elementwise'
 
-# reduction/attention ops the kernelgen tier lowers through DEDICATED
-# generated kernels (row reductions, flash attention — KERNEL_RULES
-# kinds 'row'/'attention').  They fuse like any elementwise op, and
-# unlike pure glue they justify a fused group even as a SINGLETON run:
-# a lone softmax between two matmuls must still reach the kernel tier.
-KERNEL_TIER_OPS = {'softmax', 'layer_norm', 'flash_attention'}
+# the row reductions and the attention: they fuse like any elementwise
+# op, and a lone one is still wrapped as a group of ONE.  Nothing needs
+# that wrap any more; it stays because the step compiles to another
+# module without it (tbase's two final LayerNorms; ROADMAP D12).
+ROW_AND_ATTENTION_OPS = {'softmax', 'layer_norm', 'flash_attention'}
 
 # unary/binary elementwise math + zero-flop glue + per-param optimizer
 # updates (elementwise over the param): anything whose kernel is pure,
-# rng-stable (via rng_stream), and — KERNEL_TIER_OPS excepted — free of
-# cross-element reductions
+# rng-stable (via rng_stream), and — ROW_AND_ATTENTION_OPS excepted —
+# free of cross-element reductions
 FUSABLE_OPS = {
     # elementwise binary
     'elementwise_add', 'elementwise_sub', 'elementwise_mul',
@@ -69,7 +68,7 @@ FUSABLE_OPS = {
     # per-param optimizer updates
     'sgd', 'momentum', 'adam', 'adamax', 'adagrad', 'decayed_adagrad',
     'adadelta', 'rmsprop', 'ftrl',
-} | KERNEL_TIER_OPS
+} | ROW_AND_ATTENTION_OPS
 
 # never nest: keeps the pipeline idempotent and the impl non-recursive
 assert FUSED_OP not in FUSABLE_OPS
@@ -218,7 +217,7 @@ def run(program, ctx):
                 run_ops.append((nxt, ndesc))
                 j += 1
             if len(run_ops) < 2 and not any(
-                    o.type in KERNEL_TIER_OPS for o, _ in run_ops):
+                    o.type in ROW_AND_ATTENTION_OPS for o, _ in run_ops):
                 i = j
                 continue
             lo, hi = i, j  # [lo, hi) is the run

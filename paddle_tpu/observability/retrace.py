@@ -26,7 +26,7 @@ from . import tracing
 __all__ = ['LaunchSignature', 'RetraceExplainer', 'explainer', 'reset']
 
 _COMPONENTS = ('program', 'feed_shapes', 'feed_dtypes', 'fetch_set',
-               'steps', 'check_nan', 'scope', 'opt', 'emit', 'kernelgen')
+               'steps', 'check_nan', 'scope', 'opt', 'emit')
 
 
 class LaunchSignature(object):
@@ -36,13 +36,11 @@ class LaunchSignature(object):
     PT_OPT / PT_OPT_SKIP mid-process changes what the tracer sees for the
     same raw program, and must be named, not a mystery retrace.  `emit`
     is the direct-emitter token (core/emit.config_token()) — flipping
-    PT_EMIT is likewise a named signature change, as is `kernelgen`
-    (ops/kernelgen.config_token()) for PT_KERNELGEN."""
+    PT_EMIT is likewise a named signature change."""
     __slots__ = _COMPONENTS
 
     def __init__(self, program, feed_shapes, feed_dtypes, fetch_set,
-                 steps, check_nan, scope, opt=None, emit=None,
-                 kernelgen=None):
+                 steps, check_nan, scope, opt=None, emit=None):
         self.program = program            # (serial, version)
         self.feed_shapes = dict(feed_shapes)   # name -> tuple
         self.feed_dtypes = dict(feed_dtypes)   # name -> str
@@ -52,7 +50,6 @@ class LaunchSignature(object):
         self.scope = scope
         self.opt = opt
         self.emit = emit
-        self.kernelgen = kernelgen
 
     def changed_components(self, other):
         return [c for c in _COMPONENTS
@@ -98,10 +95,6 @@ class LaunchSignature(object):
             details.append('emit: PT_EMIT config %r -> %r (direct '
                            'emitter toggled or versioned)'
                            % (other.emit, self.emit))
-        if self.kernelgen != other.kernelgen:
-            details.append('kernelgen: PT_KERNELGEN config %r -> %r '
-                           '(Pallas codegen tier toggled or versioned)'
-                           % (other.kernelgen, self.kernelgen))
         return details
 
 

@@ -19,4 +19,3 @@ from . import ctc_crf  # noqa
 from . import int8  # noqa
 from . import fused  # noqa  (fused_elementwise from core/passes/fuse.py)
 from . import collective  # noqa  (explicit collectives from core/passes/shard.py)
-from . import kernelgen  # noqa  (Pallas codegen tier + its emit rule)

@@ -54,18 +54,7 @@ def _run_sub_op(ctx, sub, env, amp):
 
 @register('fused_elementwise')
 def fused_elementwise(ctx, ins, attrs):
-    from . import _pallas
-    from . import kernelgen as _kg
     fx = getattr(ctx, 'forensic', None)
-    if _kg.enabled() and fx is None and \
-            _pallas.single_device(getattr(ctx, 'mesh', None)):
-        # PT_KERNELGEN=1 only (off by default on every backend: every
-        # launch, on one chip as under a mesh, takes the replay below
-        # with plain AD and XLA fuses it with its neighbours).  A
-        # forensic lowering never hands the group to kernelgen: the
-        # whole point is probing INSIDE the fused sub-program, which a
-        # single generated kernel hides.
-        return _kg.run_fused(ctx, ins, attrs)
     xs = ins.get('X', [])
     xs = xs if isinstance(xs, (list, tuple)) else [xs]
     env = dict(zip(attrs['arg_names'], xs))

@@ -17,7 +17,7 @@ verdict, in three bisection phases:
      fused [all_finite, nonfinite_count, max_abs] probe, fetched as one
      stacked array per step.  The first false probe names the op, its
      output var and the D-style ``source_loc`` the analyzer stamped.
-     The RAW program is lowered (no passes / emit / kernelgen), so
+     The RAW program is lowered (no passes, no emit engine), so
      fused groups are seen at sub-program granularity while the
      production path keeps its kernels — RNG parity is by construction,
      since optimized twins pin each op's raw position in

@@ -8,10 +8,6 @@ os.environ['JAX_PLATFORMS'] = 'cpu'  # tests never take the chip
 # in with PT_CACHE=1 and JAX_COMPILATION_CACHE_DIR=<tmp_path> (see
 # tests/test_compile_cache.py)
 os.environ.setdefault('PT_CACHE', '0')
-# no timed autotune searches inside tests: plan builds use cached/default
-# block choices so kernel-execution counts stay deterministic (the
-# autotuner's own tests opt back in with PT_AUTOTUNE=1)
-os.environ.setdefault('PT_AUTOTUNE', 'cached')
 flags = os.environ.get('XLA_FLAGS', '')
 if 'xla_force_host_platform_device_count' not in flags:
     os.environ['XLA_FLAGS'] = (

@@ -40,21 +40,6 @@ def _adam_case(rng, grad_dtype='float32'):
             {'beta1': 0.9, 'beta2': 0.999, 'epsilon': 1e-8})
 
 
-def _fused_case(rng):
-    # deterministic fused group (no rng sub-ops: EmitCtx here carries no
-    # base key); impl replays, emit dispatches through the kernelgen
-    # rule's fallback replay — both must agree bitwise
-    def sub(type_, inputs, outputs, attrs=None):
-        return {'type': type_, 'inputs': inputs, 'outputs': outputs,
-                'input_is_list': {}, 'output_is_list': {},
-                'attrs': dict(attrs or {}), 'stop_grad': []}
-    subs = [sub('scale', {'X': ['x']}, {'Out': ['t']},
-                {'scale': 2.0, 'bias': 0.5, 'bias_after_scale': True}),
-            sub('relu', {'X': ['t']}, {'Out': ['y']})]
-    return ({'X': [rng.randn(4, 5).astype('float32')]},
-            {'sub_ops': subs, 'arg_names': ['x'], 'out_names': ['y']})
-
-
 def _ew_cases(rng):
     x = rng.randn(4, 5).astype('float32')
     return [
@@ -88,7 +73,6 @@ _RULE_CASES = {
         ({'X': rng.randn(4, 5).astype('float32'),
           'Y': np.abs(rng.randn(5)).astype('float32') + 0.5}, {}),
     ],
-    'fused_elementwise': lambda rng: [_fused_case(rng)],
 }
 
 

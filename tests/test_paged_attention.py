@@ -339,12 +339,13 @@ def test_short_attention_keeps_its_scores_on_chip_at_tbase_widths(
         assert whole not in text
 
 
-# temp_size_in_bytes of the same step at the parent of PR 41, where an
-# unset PT_KERNELGEN put 32 generated `row` LayerNorm kernels into it:
-# 8,117,223,424; the replay read 8,173,700,096 (+0.70 %, 56 MB of 8.1 GB:
-# offline compiles, PR 41).  Since PR 50 the projections beside the tile
-# loops are written in the loops' layout and the step reads 8,312,873,472
-# (+139 MB).  The buffers, from XLA's buffer assignment of both compiles
+# temp_size_in_bytes of the same step at the parent of PR 41, where the
+# generated-kernel tier (deleted in PR 51) put 32 `row` LayerNorm kernels
+# into it: 8,117,223,424; the replay read 8,173,700,096 (+0.70 %, 56 MB
+# of 8.1 GB: offline compiles, PR 41).  Since PR 50 the projections
+# beside the tile loops are written in the loops' layout and the step
+# reads 8,312,873,472 (+139 MB; PR 51's compile reads the same to the
+# byte).  The buffers, from XLA's buffer assignment of both compiles
 # (PERF.md section 6, PR 50): the q, k and v products are three dots a
 # self-attention where they were one, so the bf16 cast of the LayerNorm's
 # output has three readers forward and three backward and XLA writes it
@@ -361,17 +362,15 @@ _TBASE_STEP_TEMP_BYTES = 8312873472
 def test_the_one_chip_tbase_step_holds_no_kernel_of_the_tier(
         one_v5e_chip, monkeypatch):
     """tbase.train_1chip's step (96 x 256 tokens, AMP, Adam; one step of
-    the K=8 scan) as XLA:TPU compiles it for one v5e chip with
-    PT_KERNELGEN unset: every fused group took the inline replay, so the
-    only Mosaic calls left are the embedding tables' two DMA gathers
-    (`ops/gather.py`, outside the tier), and the step needs the scratch
-    accounted for above, to within 1 %."""
+    the K=8 scan) as XLA:TPU compiles it for one v5e chip: every fused
+    group is the inline replay, so the only Mosaic calls are the
+    embedding tables' two DMA gathers (`ops/gather.py`), and the step
+    needs the scratch accounted for above, to within 1 %."""
     import jax
     import paddle_tpu as fluid
     from paddle_tpu.core import emit, passes
     from paddle_tpu.core import executor as em
     from paddle_tpu.models import transformer as tr
-    monkeypatch.delenv('PT_KERNELGEN', raising=False)
     monkeypatch.setenv('PT_CACHE', '0')
     batch, seq, vocab = 96, 256, 32000
     main, startup = fluid.Program(), fluid.Program()
