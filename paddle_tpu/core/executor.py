@@ -599,6 +599,25 @@ def _lower(program, feed_names, fetch_names, donate=True, mesh=None,
                       else _zero_cotangent(v))
                   for k, v in env_out.items()}
             grads, = pullback(ct)
+            if mesh is None:
+                # On one device XLA fuses a weight's optimizer update
+                # (parameter and both moments, f32) into the EPILOGUE of
+                # the product that computes its gradient, and the product
+                # then runs at 59-73 % of the MXU's peak where alone it
+                # runs at 86-91 % (PERF.md section 6, PR 54).  So the
+                # gradient of a rank-2 parameter (the weight of a mul /
+                # matmul) is fenced from the ops behind the backward, each
+                # behind its own barrier: one tuple over all of them would
+                # hold every gradient alive until the last is computed.
+                # A filter (rank 4) and a vector keep their fusion, and
+                # under a mesh the gradient already leaves its product
+                # for a collective.
+                fenced = [p for p in pnames if jnp.ndim(grads[p]) == 2]
+                for p in fenced:
+                    grads[p] = jax.lax.optimization_barrier(grads[p])
+                if _obs.enabled():
+                    _obs.metrics.counter('executor.grad_fences').inc(
+                        len(fenced))
             if emit_engine is not None and \
                     emit_engine.slim_fw_keep is not None:
                 # the slim keep-set drops pass-through names (params the

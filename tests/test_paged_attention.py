@@ -6,6 +6,8 @@ slot, inactive slots with stale tables, shared prefix pages, an idle
 batch, GQA and MHA, f32 and bf16; and that pages no live length covers
 are not READ, not merely masked.  Toy geometry: counts and values, never
 a time."""
+import re
+
 import numpy as np
 import pytest
 
@@ -356,7 +358,22 @@ def test_short_attention_keeps_its_scores_on_chip_at_tbase_widths(
 # encoder's output), less four `f32[96,256,512]` the parent still held
 # there.  The price of the layout; a second pass over the stream in the
 # backward would buy it back for 75 MB of traffic an attention.
+# Since PR 54 each rank-2 weight's gradient is fenced from its Adam
+# update (`core/executor.py` `_lower`), so a gradient the product's
+# epilogue used to consume in place is written out in f32 before the
+# update's loop fusion reads it: 8,327,549,952 (+14.7 MB, 0.18 %: inside
+# the 1 %, so the pin stays; offline compile, PR 54).
 _TBASE_STEP_TEMP_BYTES = 8312873472
+
+# A `kind=kOutput` fusion (a convolution with an epilogue) whose result is
+# three f32 arrays of one weight's extents: the weight-gradient product
+# carrying Adam's update of the parameter and both moments.  49 in the
+# parent of PR 54 (24 of `[512,512]`, 12 + 12 of the FFN, the output
+# head's `[512,32000]`), where the products ran at 59-73 % of the MXU's
+# peak against 86-91 % alone (PERF.md section 6, PR 54).
+_UPDATE_IN_A_PRODUCTS_EPILOGUE = re.compile(
+    r'= \(f32\[(\d+,\d+)\]\{[^}]*\}, f32\[\1\]\{[^}]*\}, '
+    r'f32\[\1\]\{[^}]*\}\) fusion\(')
 
 
 def test_the_one_chip_tbase_step_holds_no_kernel_of_the_tier(
@@ -402,9 +419,13 @@ def test_the_one_chip_tbase_step_holds_no_kernel_of_the_tier(
         {n: sds(feed[n]) for n in feed_names},
         jax.ShapeDtypeStruct((), jnp.uint32,
                              sharding=one_v5e_chip)).compile()
-    mosaic = [ln for ln in compiled.as_text().splitlines()
-              if 'tpu_custom_call' in ln]
+    lines = compiled.as_text().splitlines()
+    mosaic = [ln for ln in lines if 'tpu_custom_call' in ln]
     assert len(mosaic) == 2 and all('lookup_table' in ln for ln in mosaic), \
         [ln.split('metadata=')[-1][:120] for ln in mosaic]
+    fused_updates = [ln.split(' fusion(')[0].strip() for ln in lines
+                     if 'kind=kOutput' in ln
+                     and _UPDATE_IN_A_PRODUCTS_EPILOGUE.search(ln)]
+    assert not fused_updates, fused_updates[:3]
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= 1.01 * _TBASE_STEP_TEMP_BYTES, temp
