@@ -12,9 +12,8 @@ the repo benchmarks, with seeded random weights:
   train_resnet50     ResNet-50, 224x224, 1000 classes, B=128, AMP: three
                      steps (the conv / bf16 flow-through side)
   kernels            every Pallas kernel a launch can select (flash,
-                     DMA gather, ssm_step, latent_attention,
-                     latent_prefill), compiled by Mosaic and compared
-                     with its reference
+                     ssm_step, latent_attention, latent_prefill),
+                     compiled by Mosaic and compared with its reference
   serve              GenerationEngine over DecodeRuntime at the llama_1b
                      widths: four concurrent streams, twice, same tokens
   multichip          (>= 4 devices) transformer-base through
@@ -52,8 +51,6 @@ SIZES = {
             # q rows VMEM-resident, T=8192 streams them
             flash=dict(heads=16, kv_heads=8, head_dim=128,
                        seq_resident=4096, seq_streamed=8192),
-            # the B*T = 8192 lookups of the transformer-base step
-            gather=dict(rows=8192, vocab=32000, width=512),
             # the falconh1_34b cell's scan state, 15 of 32 slots live
             ssm_step=dict(state=(32, 6, 32, 128, 256), groups=2, live=15),
             # the axk1 cell's latent pool: 64 heads over one 640-wide row
@@ -84,7 +81,6 @@ SIZES = {
         'kernels': dict(
             flash=dict(heads=2, kv_heads=1, head_dim=64,
                        seq_resident=128, seq_streamed=256),
-            gather=dict(rows=256, vocab=512, width=128),
             ssm_step=dict(state=(4, 2, 8, 16, 128), groups=2, live=2),
             latent=dict(slots=4, heads=4, v_dim=128, width=256, page_len=4,
                         pages=41, layers=2, max_pages=6,
@@ -377,23 +373,6 @@ def _flash_check(cfg, seq):
             for name, a, b in zip(('out', 'dq', 'dk', 'dv'), got, ref)}
 
 
-def _gather_check(cfg):
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.ops import gather
-    rng = np.random.RandomState(SEED)
-    table = jnp.asarray(rng.randn(cfg['vocab'], cfg['width']), jnp.float32)
-    ids = jnp.asarray(rng.randint(0, cfg['vocab'], (cfg['rows'],)),
-                      jnp.int32)
-    assert gather._eligible(table, ids), 'smoke shape is not eligible'
-    compiled, n_calls = _mosaic_calls(gather.embedding_gather, table, ids)
-    _assert_mosaic('embedding_gather', n_calls, 1)
-    got = np.asarray(compiled(table, ids))
-    # a gather copies rows: bitwise, no tolerance
-    np.testing.assert_array_equal(got, np.asarray(table)[np.asarray(ids)])
-    return {'rows': cfg['rows'], 'bitwise': True}
-
-
 def _ssm_step_check(cfg):
     """`ssm_step` (one decode step of the Mamba-2 recurrence, in place
     over the live slots) against `scan_step` over every slot, on one
@@ -552,7 +531,6 @@ def kernels(cfg):
     out = {
         'flash_resident': _flash_check(flash, flash['seq_resident']),
         'flash_streamed': _flash_check(flash, flash['seq_streamed']),
-        'gather': _gather_check(cfg['gather']),
         'ssm_step': _ssm_step_check(cfg['ssm_step']),
         'latent_attention': _latent_attention_check(cfg['latent']),
         'latent_prefill': _latent_prefill_check(cfg['latent_prefill']),

@@ -193,20 +193,14 @@ def one_hot(ctx, ins, attrs):
 @register('lookup_table')
 def lookup_table(ctx, ins, attrs):
     # reference lookup_table_op.cc: ids [..., 1] int64, W [V, D].
-    # Large lookups route through the pallas DMA gather (ops/gather.py,
-    # measured 1.7x over XLA's row gather); backward stays scatter-add.
-    # Over a mesh of several devices the kernel is not eligible
-    # (_pallas.single_device) and the lookup stays on jnp.take.
-    from . import _pallas
-    from .gather import embedding_gather
+    # jnp.take wraps negative ids and fills out-of-range rows with NaN
+    # (corruption SURFACES via executor check_nan); backward is XLA's
+    # scatter-add, duplicate-id-correct.
     w, ids = ins['W'], ins['Ids']
     padding_idx = attrs.get('padding_idx', -1)
     squeeze_last = ids.ndim >= 2 and ids.shape[-1] == 1
     idx = ids[..., 0] if squeeze_last else ids
-    if _pallas.single_device(getattr(ctx, 'mesh', None)):
-        out = embedding_gather(w, idx)
-    else:
-        out = jnp.take(w, idx, axis=0)
+    out = jnp.take(w, idx, axis=0)
     if padding_idx is not None and padding_idx >= 0:
         mask = (idx != padding_idx)[..., None]
         out = out * mask.astype(out.dtype)

@@ -48,21 +48,18 @@ def test_phase_train_resnet50():
 
 def test_phase_kernels(monkeypatch):
     """The shape gates lowered so that the tiny shapes take the routes
-    the full shapes take: Pallas forward, both dK/dV kernels, the DMA
-    gather."""
-    from paddle_tpu.ops import attention, gather
+    the full shapes take: Pallas forward, both dK/dV kernels."""
+    from paddle_tpu.ops import attention
     flash = TINY['kernels']['flash']
     monkeypatch.setattr(attention, '_FWD_PALLAS_MIN_T',
                         flash['seq_resident'])
     monkeypatch.setattr(attention, '_BWD_PALLAS_SCORE_BYTES', 0)
     monkeypatch.setattr(attention, '_DKV_RESIDENT_MAX_T',
                         flash['seq_resident'])
-    monkeypatch.setattr(gather, '_MIN_ROWS', TINY['kernels']['gather']['rows'])
     out = chip_smoke.kernels(TINY['kernels'])
-    assert sorted(out) == ['flash_resident', 'flash_streamed', 'gather',
+    assert sorted(out) == ['flash_resident', 'flash_streamed',
                            'latent_attention', 'latent_prefill', 'ssm_step',
                            'wall_s']
-    assert out['gather']['bitwise']
     assert sorted(out['latent_prefill']) == ['err_0_8', 'err_24_8',
                                              'err_40_3']
 
