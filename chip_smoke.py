@@ -13,7 +13,9 @@ the repo benchmarks, with seeded random weights:
                      steps (the conv / bf16 flow-through side)
   kernels            every Pallas kernel a launch can select (flash,
                      ssm_step, latent_attention, latent_prefill),
-                     compiled by Mosaic and compared with its reference
+                     compiled by Mosaic and compared with its reference;
+                     and the composed attention's loop over tiles of the
+                     batch, whose result buffers start uninitialised
   serve              GenerationEngine over DecodeRuntime at the llama_1b
                      widths: four concurrent streams, twice, same tokens
   multichip          (>= 4 devices) transformer-base through
@@ -51,6 +53,8 @@ SIZES = {
             # q rows VMEM-resident, T=8192 streams them
             flash=dict(heads=16, kv_heads=8, head_dim=128,
                        seq_resident=4096, seq_streamed=8192),
+            # the tbase cells' attention: 96 sequences a chip, 6 tiles
+            tile_loop=dict(batch=96, heads=8, seq=256, head_dim=64, tiles=6),
             # the falconh1_34b cell's scan state, 15 of 32 slots live
             ssm_step=dict(state=(32, 6, 32, 128, 256), groups=2, live=15),
             # the axk1 cell's latent pool: 64 heads over one 640-wide row
@@ -81,6 +85,7 @@ SIZES = {
         'kernels': dict(
             flash=dict(heads=2, kv_heads=1, head_dim=64,
                        seq_resident=128, seq_streamed=256),
+            tile_loop=dict(batch=8, heads=2, seq=16, head_dim=8, tiles=4),
             ssm_step=dict(state=(4, 2, 8, 16, 128), groups=2, live=2),
             latent=dict(slots=4, heads=4, v_dim=128, width=256, page_len=4,
                         pages=41, layers=2, max_pages=6,
@@ -373,6 +378,63 @@ def _flash_check(cfg, seq):
             for name, a, b in zip(('out', 'dq', 'dk', 'dv'), got, ref)}
 
 
+def _tile_loop_check(cfg):
+    """The composed attention's loop over tiles of the batch at
+    [B, H, T, D] bf16, causal, ragged lengths: output, dq, dk and dv
+    against `_ref_attention` on the whole batch, TWICE in one process on
+    other inputs.  Both loops start from buffers nothing fills
+    (`ops.attention._unfilled`): a tile no iteration wrote would hold
+    what the memory held, at best the first call's results, so the
+    second call is the one that tells.  The CPU's `lax.empty` is a zero
+    fill and cannot show it."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention as att
+    B, H, T, D = (cfg[n] for n in ('batch', 'heads', 'seq', 'head_dim'))
+    tiles = cfg['tiles']
+    assert att.takes_tile_loop(B, H, T, T, D) \
+        and att._composed_tile(B, H, T, T) * tiles == B, \
+        'the smoke shape no longer takes %d tiles' % tiles
+
+    def inputs(run):
+        keys = jax.random.split(jax.random.key(SEED + run), 5)
+        q, k, v, ct = (jax.random.normal(key, (B, H, T, D), jnp.bfloat16)
+                       for key in keys[:4])
+        return q, k, v, ct, jax.random.randint(keys[4], (B,), T // 2, T + 1)
+
+    def pulled_back(attention):
+        def fn(q, k, v, ct, k_len):
+            out, pull = jax.vjp(
+                lambda q, k, v: attention(q, k, v, k_len), q, k, v)
+            return (out,) + pull(ct)
+        return fn
+
+    before = _counters()
+    compiled = jax.jit(pulled_back(lambda q, k, v, k_len: att.flash_attention(
+        q, k, v, causal=True, k_len=k_len))).lower(*inputs(0)).compile()
+    assert _since(before, 'attention.composed_tiled') == 1
+    assert _since(before, 'attention.tile_buffers_unfilled') == 4
+    if jax.default_backend() != 'cpu':
+        text = compiled.as_text()
+        filled = 'bf16[%d,%d,%d,%d,%d]' % (tiles, B // tiles, H, T, D)
+        assert text.count('"AllocateBuffer"') == 4 and not [
+            line for line in text.splitlines()
+            if ' broadcast(' in line and '= ' + filled in line], \
+            'the tile loops\' result buffers are filled before the loops'
+    reference = jax.jit(pulled_back(lambda q, k, v, k_len: att._ref_attention(
+        q, k, v, True, D ** -0.5, k_len)))
+    out = {}
+    for run in range(2):
+        args = inputs(run)
+        # one bf16 rounding of each result, as `_flash_check`; a tile
+        # left unwritten differs by the size of the values themselves
+        for name, a, b in zip(('out', 'dq', 'dk', 'dv'), compiled(*args),
+                              reference(*args)):
+            out['%s_%d' % (name, run)] = round(_close(
+                'tile loop, call %d, %s' % (run, name), a, b, 2e-2), 5)
+    return out
+
+
 def _ssm_step_check(cfg):
     """`ssm_step` (one decode step of the Mamba-2 recurrence, in place
     over the live slots) against `scan_step` over every slot, on one
@@ -531,6 +593,7 @@ def kernels(cfg):
     out = {
         'flash_resident': _flash_check(flash, flash['seq_resident']),
         'flash_streamed': _flash_check(flash, flash['seq_streamed']),
+        'tile_loop': _tile_loop_check(cfg['tile_loop']),
         'ssm_step': _ssm_step_check(cfg['ssm_step']),
         'latent_attention': _latent_attention_check(cfg['latent']),
         'latent_prefill': _latent_prefill_check(cfg['latent_prefill']),

@@ -343,12 +343,73 @@ def takes_tile_loop(B, H, Tq, Tk, D, mesh=None, block_q=128, block_k=128):
     return c != local
 
 
+def _unfilled(like):
+    """A result buffer of `like`'s shape and dtype that nothing fills
+    before the tile loop writes it: `AllocateBuffer` on the TPU, an
+    allocation and no pass, where `jnp.zeros` (and the stacked output
+    of a `scan`) is a pass over HBM the loop then overwrites.  The value
+    is whatever the memory held: every caller writes each index of the
+    leading axis before anyone reads it.  (An allocation has no operand,
+    so XLA may schedule it long before its loop: docs/kernels.md.)"""
+    from ..observability import metrics
+    metrics.counter('attention.tile_buffers_unfilled').inc()
+    return jax.lax.empty(like.shape, like.dtype)
+
+
+def _tile_loop(causal, scale):
+    """`_ref_attention` over operands `[tiles, c, ...]`, one tile of `c`
+    sequences an iteration, with the loops of both directions written by
+    hand: the forward keeps q, k, v and the lengths (no score matrix, no
+    softmax), the backward recomputes tile `i` and pulls its cotangent
+    back through it.  Each loop carries its results and writes tile `i`
+    in place; iteration `i` of `range(tiles)` writes index `i` of every
+    carried buffer and no other, so all of a buffer is written exactly
+    once before the loop hands it on, and it may start uninitialised."""
+
+    def tile(q, k, v, k_len):
+        with jax.named_scope('attn.tile'):
+            return _ref_attention(q, k, v, causal, scale, k_len)
+
+    def at(i, *xs):
+        return (jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False)
+                for x in xs)
+
+    def put(buf, i, x):
+        # not `.at[i].set`: a scatter drops an index out of range, which
+        # XLA lowers to a select that READS the buffer
+        return jax.lax.dynamic_update_index_in_dim(buf, x, i, 0)
+
+    def forward(q, k, v, k_len):
+        def body(i, out):
+            return put(out, i, tile(*at(i, q, k, v, k_len)))
+        return jax.lax.fori_loop(0, q.shape[0], body, _unfilled(q))
+
+    def fwd(q, k, v, k_len):
+        return forward(q, k, v, k_len), (q, k, v, k_len)
+
+    def bwd(res, g):
+        q, k, v, k_len = res
+
+        def body(i, grads):
+            *qkv, kl, gi = at(i, q, k, v, k_len, g)
+            _, pullback = jax.vjp(lambda *qkv: tile(*qkv, kl), *qkv)
+            return tuple(put(buf, i, d)
+                         for buf, d in zip(grads, pullback(gi)))
+        return jax.lax.fori_loop(
+            0, q.shape[0], body,
+            (_unfilled(q), _unfilled(k), _unfilled(v))) + (None,)
+
+    loop = jax.custom_vjp(forward)
+    loop.defvjp(fwd, bwd)
+    return loop
+
+
 def _composed_attention(q, k, v, causal, scale, k_len, mesh=None):
     """`_ref_attention`, run over tiles of the batch where its scores
     would not stay on chip.  Attention is independent per sequence, so a
-    tile is the same mathematics; the loop body is rematerialised, so the
-    backward pass keeps q, k, v and recomputes a tile's scores where
-    plain AD would stack every tile's softmax into a full-size residual.
+    tile is the same mathematics; the backward pass keeps q, k, v and
+    recomputes a tile's scores (`_tile_loop`) where plain AD would stack
+    every tile's softmax into a full-size residual.
     Under a mesh that shards the batch over 'data' (and nothing else)
     each device tiles its LOCAL batch inside a shard_map: no collective,
     and the loop never runs over a sharded dimension.  Any other mesh
@@ -362,16 +423,11 @@ def _composed_attention(q, k, v, causal, scale, k_len, mesh=None):
         metrics.counter('attention.composed_whole').inc()
         return _ref_attention(q, k, v, causal, scale, k_len)
     metrics.counter('attention.composed_tiled').inc()
-
-    @jax.checkpoint
-    def tile(qkvl):
-        q, k, v, k_len = qkvl
-        with jax.named_scope('attn.tile'):
-            return _ref_attention(q, k, v, causal, scale, k_len)
+    loop = _tile_loop(causal, scale)
 
     def tiled(*qkvl):
-        out = jax.lax.map(tile, tuple(
-            x.reshape((local // c, c) + x.shape[1:]) for x in qkvl))
+        out = loop(*(x.reshape((local // c, c) + x.shape[1:])
+                     for x in qkvl))
         return out.reshape(qkvl[0].shape)
 
     if shards > 1:

@@ -183,8 +183,32 @@ def _composed_case(monkeypatch, cfg, tile_seqs=5):
 
 def _counters():
     from paddle_tpu.observability import metrics
-    return (metrics.counter('attention.composed_tiled').value,
-            metrics.counter('attention.composed_whole').value)
+    return tuple(metrics.counter('attention.' + n).value for n in (
+        'composed_tiled', 'composed_whole', 'tile_buffers_unfilled'))
+
+
+def _moved(before):
+    return tuple(a - b for a, b in zip(_counters(), before))
+
+
+def _mapped_tiles(att, c, causal, scale):
+    """The tiled route as it was before its loops were written by hand:
+    `lax.map` over a checkpointed tile, differentiated by plain AD (its
+    stacked results start as zeros).  The oracle of the route's loops."""
+    import jax.numpy as jnp
+
+    @jax.checkpoint
+    def tile(qkvl):
+        return att._ref_attention(*qkvl[:3], causal, scale, qkvl[3])
+
+    def mapped(q, k, v, kl):
+        if kl is None:
+            kl = jnp.full((q.shape[0],), k.shape[2], jnp.int32)
+        out = jax.lax.map(tile, tuple(
+            x.reshape((x.shape[0] // c, c) + x.shape[1:])
+            for x in (q, k, v, kl)))
+        return out.reshape(q.shape)
+    return mapped
 
 
 _BASE = dict(B=8, H=4, Hkv=4, Tq=32, Tk=32, D=16, causal=False, klen=False,
@@ -207,13 +231,17 @@ _BASE = dict(B=8, H=4, Hkv=4, Tq=32, Tk=32, D=16, causal=False, klen=False,
                           if _BASE[kv[0]] != kv[1]) or 'base')
 def test_composed_tiles_equal_the_whole_batch(cfg, monkeypatch):
     """The composed route over tiles of the batch is `_ref_attention` in
-    output and in dq, dk, dv; a batch that is one tile (it fits, or its
-    only useful divisor is itself) lowers to the jaxpr it lowered to
-    before there were tiles; and the two counters say which ran."""
+    output and in dq, dk, dv, and the `lax.map` over checkpointed tiles
+    it was before its two loops were written by hand; a batch that is
+    one tile (it fits, or its only useful divisor is itself) lowers to
+    the jaxpr it lowered to before there were tiles; and the counters
+    say which ran, and that the tiled one allocated its four result
+    buffers (the forward's, dq, dk, dv) without a fill."""
     import jax.numpy as jnp
     att, q, k, v, kl = _composed_case(monkeypatch, cfg)
     B, D, causal = cfg['B'], cfg['D'], cfg['causal']
-    tiles = att._composed_tile(B, cfg['H'], cfg['Tq'], cfg['Tk']) != B
+    c = att._composed_tile(B, cfg['H'], cfg['Tq'], cfg['Tk'])
+    tiles = c != B
     assert tiles == (B in (8, 12))
 
     def loss(fn):
@@ -231,9 +259,7 @@ def test_composed_tiles_equal_the_whole_batch(cfg, monkeypatch):
     before = _counters()
     (_, out), grads = jax.value_and_grad(
         loss(ours), argnums=(0, 1, 2), has_aux=True)(q, k, v)
-    after = _counters()
-    assert (after[0] - before[0], after[1] - before[1]) == \
-        ((1, 0) if tiles else (0, 1))
+    assert _moved(before) == ((1, 0, 4) if tiles else (0, 1, 0))
     (_, want), want_grads = jax.value_and_grad(
         loss(ref), argnums=(0, 1, 2), has_aux=True)(q, k, v)
     assert out.dtype == q.dtype
@@ -241,18 +267,33 @@ def test_composed_tiles_equal_the_whole_batch(cfg, monkeypatch):
     # the output and of each gradient, whatever the order of the sums
     tol = dict(atol=1e-5, rtol=1e-5) if cfg['dtype'] == 'float32' \
         else dict(atol=5e-2, rtol=2e-2)
-    for a, b, n in zip((out,) + grads, (want,) + want_grads,
-                       'out dq dk dv'.split()):
-        assert a.dtype == b.dtype
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32),
-                                   err_msg=n, **tol)
+
+    def same(got, want, exact=False):
+        for a, b, n in zip(got, want, 'out dq dk dv'.split()):
+            assert a.dtype == b.dtype
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            if exact:
+                np.testing.assert_array_equal(a, b, err_msg=n)
+            else:
+                np.testing.assert_allclose(a, b, err_msg=n, **tol)
+
+    same((out,) + grads, (want,) + want_grads)
 
     text = str(jax.make_jaxpr(jax.grad(lambda *a: loss(ours)(*a)[0]))(
         q, k, v))
     if tiles:
+        # tile by tile the same operations on the same rows as the
+        # mapped form's, in either direction: bit for bit
+        mapped = _mapped_tiles(att, c, causal, D ** -0.5)
+        (_, old), old_grads = jax.value_and_grad(
+            loss(lambda q, k, v: mapped(q, k, v, kl)), argnums=(0, 1, 2),
+            has_aux=True)(q, k, v)
+        same((out,) + grads, (old,) + old_grads, exact=True)
+        # two loops, four buffers nothing fills, nothing stacked by a
+        # scan and no residual but q, k, v and the lengths
         assert 'while' in text or 'scan' in text
-        assert 'checkpoint' in text or 'remat' in text
+        assert text.count(' empty[') == 4
+        assert 'checkpoint' not in text and 'remat' not in text
     else:
         def before_tiles(q, k, v):
             # what flash_attention's composed branch was: the lengths
@@ -286,7 +327,33 @@ def test_composed_tile_follows_the_shapes(monkeypatch):
     assert tile(96, 16, 256, 256) == 12         # twice the heads
 
 
-def test_composed_tiles_the_local_batch_under_a_data_mesh(monkeypatch):
+def test_the_lengths_of_the_tiled_route_take_no_cotangent(monkeypatch):
+    """`k_len` is an integer operand of the route's `custom_vjp`:
+    differentiating with respect to q, k, v while the lengths are traced
+    raises nothing, and asked for, their cotangent is `float0`."""
+    att, q, k, v, kl = _composed_case(
+        monkeypatch, dict(_BASE, causal=True, klen=True))
+
+    def f(q, k, v, kl):
+        return (att.flash_attention(q, k, v, causal=True, k_len=kl)
+                ** 2).sum()
+
+    want = jax.grad(lambda q, k, v: (att._ref_attention(
+        q, k, v, True, 16 ** -0.5, kl) ** 2).sum(), argnums=(0, 1, 2))(
+            q, k, v)
+    before = _counters()
+    got = jax.jit(jax.grad(f, argnums=(0, 1, 2)))(q, k, v, kl)
+    assert _moved(before) == (1, 0, 4)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+    dq, dkl = jax.grad(f, argnums=(0, 3), allow_int=True)(q, k, v, kl)
+    assert dkl.dtype == jax.dtypes.float0 and dkl.shape == kl.shape
+    np.testing.assert_allclose(dq, want[0], atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize('shards', [2, 4])
+def test_composed_tiles_the_local_batch_under_a_data_mesh(shards,
+                                                          monkeypatch):
     """Under a mesh that shards the batch over 'data' alone each device
     tiles its own share inside a shard_map (no loop over the sharded
     dimension); a mesh with another axis in use keeps the whole batch."""
@@ -308,21 +375,22 @@ def test_composed_tiles_the_local_batch_under_a_data_mesh(monkeypatch):
     want_g = jax.grad(lambda q, k, v: (att._ref_attention(
         q, k, v, True, 16 ** -0.5, kl) ** 2).sum(), argnums=(0, 1, 2))(
             q, k, v)
-    data4 = make_mesh(data=4, devices=jax.devices()[:4])
+    data4 = make_mesh(data=shards, devices=jax.devices()[:shards])
     sh = NamedSharding(data4, P('data'))
     before = _counters()
     (_, out), grads = grad_of(data4)(*(jax.device_put(x, sh)
                                        for x in (q, k, v, kl)))
-    assert _counters()[0] - before[0] == 1
+    assert _moved(before) == (1, 0, 4)
     assert out.sharding.is_equivalent_to(sh, out.ndim)
     for a, b in zip((out,) + grads, (want,) + want_g):
         np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
     lowered = grad_of(data4).lower(*(jax.device_put(x, sh)
                                      for x in (q, k, v, kl)))
     text = lowered.as_text()
-    # scores [c, Hkv, g, Tq, Tk]: 4 sequences a device in 2 tiles of 2
+    # scores [c, Hkv, g, Tq, Tk]: 16 / shards sequences a device in
+    # tiles of 2
     assert 'tensor<2x4x1x32x32xf32>' in text
-    assert 'tensor<4x4x1x32x32xf32>' not in text
+    assert 'tensor<%dx4x1x32x32xf32>' % (16 // shards) not in text
     assert 'tensor<16x4x1x32x32xf32>' not in text
 
     def pulled_back(q, k, v, kl, g):
@@ -340,5 +408,5 @@ def test_composed_tiles_the_local_batch_under_a_data_mesh(monkeypatch):
     mixed = make_mesh(data=2, model=2, devices=jax.devices()[:4])
     before = _counters()
     (_, out), _ = grad_of(mixed)(q, k, v, kl)
-    assert _counters()[1] - before[1] == 1
+    assert _moved(before) == (0, 1, 0)
     np.testing.assert_allclose(out, want, atol=1e-5, rtol=1e-5)

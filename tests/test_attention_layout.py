@@ -30,6 +30,10 @@ def _counters():
         'composed_tiled', 'composed_whole', 'operands_in_loop_layout'))
 
 
+def _unfilled():
+    return metrics.counter('attention.tile_buffers_unfilled').value
+
+
 def _moved(before):
     return tuple(a - b for a, b in zip(_counters(), before))
 
@@ -417,6 +421,20 @@ def _relayouts(text, batch):
     return len(copies), len(splits)
 
 
+ATTENTIONS = 6     # two layers: 2 encoder, 2 decoder self, 2 cross
+
+
+def _tile_buffers(text):
+    """(`broadcast`s that fill a tile loop's result buffer `bf16[6,16,8,
+    256,64]`, `copy`s of one, `AllocateBuffer` calls: buffers a loop
+    takes unfilled, `while`s) in a compiled step at 96 sequences."""
+    shape = r'= bf16\[6,16,8,256,64\]\{[^}]*\} '
+    return (len(re.findall(shape + r'broadcast\(', text)),
+            len(re.findall(shape + r'copy\(', text)),
+            text.count('custom_call_target="AllocateBuffer"'),
+            text.count(' while('))
+
+
 def _skeleton(text):
     """A compiled module less what names its instructions and where they
     came from: two lowerings of one computation share it."""
@@ -435,10 +453,35 @@ def test_no_relayout_is_left_around_the_tile_loops(
     every attention costs a `split` pass and seven copies of
     `bf16[96,256,512]` (q, k, v in; the result's cotangent in; dq, dk, dv
     out); with it the dots write and read that layout themselves."""
+    unfilled = _unfilled()
     text, (tiled, _, in_layout) = _compiled_step(
         cell_program, v5e_2x2, monkeypatch, batch=96)
     assert ' while(' in text and 'bf16[6,16,8,256,64]{3,4,2,1,0' in text
     assert tiled == in_layout > 0
+    assert _relayouts(text, 96) == (0, 0)
+    # each attention's two loops carry four results (the forward's; dq,
+    # dk, dv), and no pass over HBM fills one before its loop writes it
+    assert _tile_buffers(text) == (0, 0, 4 * ATTENTIONS, 2 * ATTENTIONS)
+    # a signature's traces: the op's own (differentiation drops it), its
+    # forward rule, its backward rule's three
+    assert _unfilled() - unfilled == 5 * tiled
+
+
+@pytest.mark.parametrize('chips', [1, 4])
+def test_a_filled_buffer_would_show(chips, cell_program, v5e_2x2,
+                                    monkeypatch):
+    """The counts above are of something: the same step with the loops'
+    buffers allocated as zeros holds one `broadcast` of `bf16[6,16,8,
+    256,64]` a buffer, in front of the same loops."""
+    monkeypatch.setattr(att, '_unfilled',
+                        lambda like: jnp.zeros(like.shape, like.dtype))
+    emit.clear_memo()
+    text, (tiled, _, in_layout) = _compiled_step(
+        cell_program, v5e_2x2, monkeypatch, batch=96, chips=chips)
+    emit.clear_memo()      # no later case meets these lowerings
+    assert tiled == in_layout > 0
+    assert _tile_buffers(text) == (4 * ATTENTIONS, 0, 0, 2 * ATTENTIONS)
+    assert 'bf16[6,16,8,256,64]{3,4,2,1,0' in text
     assert _relayouts(text, 96) == (0, 0)
 
 
@@ -479,3 +522,4 @@ def test_no_relayout_is_left_under_a_data_mesh(
     assert ' while(' in text and 'bf16[6,16,8,256,64]{3,4,2,1,0' in text
     assert tiled == in_layout > 0
     assert _relayouts(text, 96) == (0, 0)
+    assert _tile_buffers(text) == (0, 0, 4 * ATTENTIONS, 2 * ATTENTIONS)

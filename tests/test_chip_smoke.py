@@ -48,9 +48,14 @@ def test_phase_train_resnet50():
 
 def test_phase_kernels(monkeypatch):
     """The shape gates lowered so that the tiny shapes take the routes
-    the full shapes take: Pallas forward, both dK/dV kernels."""
+    the full shapes take: Pallas forward, both dK/dV kernels, and the
+    composed route's loop over four tiles of the batch."""
     from paddle_tpu.ops import attention
     flash = TINY['kernels']['flash']
+    loop = TINY['kernels']['tile_loop']
+    monkeypatch.setattr(
+        attention, '_COMPOSED_TILE_BYTES', loop['batch'] // loop['tiles']
+        * loop['heads'] * loop['seq'] ** 2 * 4)
     monkeypatch.setattr(attention, '_FWD_PALLAS_MIN_T',
                         flash['seq_resident'])
     monkeypatch.setattr(attention, '_BWD_PALLAS_SCORE_BYTES', 0)
@@ -59,7 +64,10 @@ def test_phase_kernels(monkeypatch):
     out = chip_smoke.kernels(TINY['kernels'])
     assert sorted(out) == ['flash_resident', 'flash_streamed',
                            'latent_attention', 'latent_prefill', 'ssm_step',
-                           'wall_s']
+                           'tile_loop', 'wall_s']
+    assert sorted(out['tile_loop']) == [
+        '%s_%d' % (n, run) for n in ('dk', 'dq', 'dv', 'out')
+        for run in range(2)]
     assert sorted(out['latent_prefill']) == ['err_0_8', 'err_24_8',
                                              'err_40_3']
 

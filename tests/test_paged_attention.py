@@ -369,8 +369,17 @@ def test_short_attention_keeps_its_scores_on_chip_at_tbase_widths(
 # `f32[32000,1,512]{T(1,128)}` (each row a leading-dimension slice for
 # the DMA), a copy of the result `f32[24576,1,512]` back to the tiling
 # its readers want, and a select in the backward for the NaN fill:
-# 8,326,679,040 (-0.9 MB), re-pinned to that reading.
-_TBASE_STEP_TEMP_BYTES = 8326679040
+# 8,326,679,040 (-0.9 MB), re-pinned to that reading.  Since PR 58 the
+# tile loops' 72 result buffers are `AllocateBuffer` custom calls where
+# they were zero `broadcast`s (`ops/attention._unfilled`): the same
+# buffers, but an allocation has no operand, so XLA's scheduler hoists
+# it far ahead of its loop where a fill sat on the line before its
+# `while`: the backward's 54 stand in two bunches of 15 and 18 (the
+# decoder's, then the encoder's) and live from there:
+# 8,870,397,952 (+544 MB, 6.5 %; offline compile, PR 58), re-pinned to
+# that reading.  What the chip's allocator made of it: PERF.md
+# section 6, PR 58.
+_TBASE_STEP_TEMP_BYTES = 8870397952
 
 # A `kind=kOutput` fusion (a convolution with an epilogue) whose result is
 # three f32 arrays of one weight's extents: the weight-gradient product
