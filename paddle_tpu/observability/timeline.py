@@ -19,6 +19,12 @@ launches work doing while the chip had none?
   thread's ``pt:`` spans alive then; the gap is named, whole, after the
   span that holds most of it so, ``unattributed`` when most of it lies
   under no span.
+* ``idle_under`` rolls the same idle time UP: the idle nanoseconds that
+  lie under each span name, its descendants' included and no gap
+  rounded to a whole, so a parent (`serving.boundary`) reads what its
+  children (fetch, check, upload, dispatch) read together, beside
+  ``unattributed``, the idle time under no span at all.  The names
+  overlap (a round holds its boundary): the column does not sum.
 * The host's and the chip's clocks agree only to about a millisecond.
   A ``pt:*.dispatch`` span is paired with the executable on ``XLA
   Modules`` that it launched BY POSITION, never by rank: the first
@@ -39,13 +45,15 @@ launches work doing while the chip had none?
 The reduction works on plain ``(start_ns, end_ns[, name])`` tuples, so
 it is tested on hand-built intervals; only `load_trace` touches a file.
 """
+import bisect
 import glob
 import os
 import sys
 
 from .tracing import ANNOTATION_PREFIX as SPAN_PREFIX
 
-__all__ = ['idle_gaps', 'name_gap', 'idle_by_span', 'pair_launches',
+__all__ = ['idle_gaps', 'name_gap', 'idle_by_span', 'idle_under',
+           'pair_launches',
            'clock_skew_ns', 'analyse', 'load_trace', 'report', 'main']
 
 DEVICE_PREFIX = '/device:TPU:'
@@ -116,6 +124,33 @@ def idle_by_span(gaps, spans):
     return out
 
 
+def idle_under(gaps, spans):
+    """{span name: idle nanoseconds of `gaps` under the (start, end,
+    name) spans of that name, descendants included}, and under
+    `unattributed` what lies under no span.  A span inside one of its
+    own name (or, for `unattributed`, inside any span) adds nothing: its
+    ancestor has counted it."""
+    gaps = sorted(gaps)
+    starts = [g[0] for g in gaps]
+    before = [0.0]                      # idle time before each gap
+    for s, e in gaps:
+        before.append(before[-1] + e - s)
+
+    def idle_until(t):
+        i = bisect.bisect_right(starts, t)
+        return before[i] - (max(0.0, gaps[i - 1][1] - t) if i else 0.0)
+
+    out, covered = {}, {}               # covered: name -> end of the last
+    for s, e, name in sorted(spans, key=lambda sp: (sp[0], -sp[1])):
+        under = idle_until(e) - idle_until(s)
+        for key in (name, None):        # None: any span at all
+            if s >= covered.get(key, s):
+                covered[key] = e
+                out[key] = out.get(key, 0.0) + under
+    out[UNATTRIBUTED] = before[-1] - out.pop(None, 0.0)
+    return out
+
+
 def pair_launches(modules, dispatches, tol_ns=MAX_SKEW_NS):
     """[(module, dispatch)]: each (start, end[, name]) dispatch span, in
     order, with the first (start, end) module not yet paired that starts
@@ -171,6 +206,7 @@ def analyse(ops, modules, thread_spans):
     idle = sum(e - s for s, e in gaps)
     by_span = idle_by_span(gaps, spans)
     named = idle - by_span.get(UNATTRIBUTED, 0.0)
+    under = idle_under(gaps, spans)
     return {
         'window_s': (hi - lo) / 1e9,
         'idle_s': idle / 1e9,
@@ -179,6 +215,8 @@ def analyse(ops, modules, thread_spans):
         'idle_s_by_span': {k: v / 1e9 for k, v in sorted(
             by_span.items(), key=lambda kv: -kv[1])},
         'named_share': named / idle if idle else 1.0,
+        'idle_under': {k: v / 1e9 for k, v in sorted(
+            under.items(), key=lambda kv: -kv[1]) if v > 0.0},
         'launching_thread': launcher,
         'launches': len(dispatches),
         'paired': len(pairs),
@@ -224,7 +262,8 @@ def load_trace(path):
 
 
 def report(result):
-    """The text an operator reads: idle seconds by span name, then the
+    """The text an operator reads: idle seconds by innermost span name,
+    the same rolled up under each span and its descendants, then the
     clock skew."""
     if result is None:
         return 'no operation ran on a chip in this trace'
@@ -233,13 +272,18 @@ def report(result):
                result['gaps']),
             'launching thread %s: %d dispatch spans, %d modules on the chip'
             % (result['launching_thread'], result['launches'],
-               result['modules']),
-            '%-36s %12s %8s' % ('idle under span', 'seconds', 'share')]
-    for name, seconds in result['idle_s_by_span'].items():
-        rows.append('%-36s %12.6f %7.2f%%' % (
-            name, seconds,
-            100.0 * seconds / result['idle_s'] if result['idle_s'] else 0.0))
+               result['modules'])]
+
+    def table(title, seconds_by_name):
+        rows.append('%-36s %12s %8s' % (title, 'seconds', 'share'))
+        for name, seconds in seconds_by_name.items():
+            rows.append('%-36s %12.6f %7.2f%%' % (
+                name, seconds, 100.0 * seconds / result['idle_s']
+                if result['idle_s'] else 0.0))
+
+    table('idle under span', result['idle_s_by_span'])
     rows.append('named_share %.4f' % result['named_share'])
+    table('idle under span and descendants', result['idle_under'])
     skew = result['clock_skew_ms']
     rows.append('clock_skew_ms %s' % (
         'unpaired (no dispatch span has its module within %g ms: host '

@@ -951,16 +951,25 @@ class _Pending(np.lib.mixins.NDArrayOperatorsMixin):
     arithmetic, comparisons and any ndarray attribute read it; the
     first of them waits for the launch, and the runtime times that wait
     as the launch's fetch (`DecodeRuntime._pending`).  Never read, it
-    costs no transfer."""
-    __slots__ = ('_land', '_host')
+    costs no transfer.  `landed` says, without waiting, whether the
+    launch had ended before anyone came to read it."""
+    __slots__ = ('_land', '_host', '_dev', '_landed')
 
-    def __init__(self, land):
-        self._land, self._host = land, None
+    def __init__(self, land, dev=None):
+        self._land, self._host, self._dev, self._landed = land, None, dev, None
 
     def read(self):
         if self._land is not None:
-            self._host, self._land = self._land(), None
+            self._host, self._land, self._dev = self._land(), None, None
         return self._host
+
+    def landed(self):
+        """Whether the result was on hand already when this was first
+        asked: one `is_ready()` of the device array, before the read,
+        and the answer kept (asked after the read: yes)."""
+        if self._landed is None:
+            self._landed = self._dev is None or bool(self._dev.is_ready())
+        return self._landed
 
     def __array__(self, dtype=None, copy=None):
         out = self.read()
@@ -1103,6 +1112,10 @@ class DecodeRuntime(object):
             # arguments uploaded ahead of their launch: {'prefill' | 'window':
             # [(host copy, device array), ...]} (`stage_prefill`, `stage_window`)
             self._staged = {}
+            # where the latest launch's `dispatch` span ended, on that
+            # span's clock (None with telemetry off): the scheduler ends
+            # its boundary there without reading the clock again
+            self.dispatched_at = None
             # rows of K (or V) per layer one COMPOSED step gathers
             self._gathered = (
                 None if self.paged
@@ -1458,7 +1471,7 @@ class DecodeRuntime(object):
             if then is not None:
                 then(out)
             return out
-        return _Pending(land)
+        return _Pending(land, dev)
 
     def _count_stats(self, upto):
         """Move the `_LAUNCH_STATS` of the first ``upto`` launches of this
@@ -1543,13 +1556,14 @@ class DecodeRuntime(object):
             with _obs.span('decode.prefill.upload', cat='decode'):
                 args = self._uploaded('prefill', self._prefill_values(
                     width, slot, tokens, offset, params))
-            with _obs.span('decode.prefill.dispatch', cat='decode'):
+            with _obs.span('decode.prefill.dispatch', cat='decode') as sent:
                 st, nxt, logits, *stats = call(self.params, self.state,
                                                *args)
                 self.state = st
                 nxt.copy_to_host_async()
                 if stats:
                     self._launched_stats('prefill', stats[0])
+            self.dispatched_at = sent.t1
         self.host_len[slot] = offset + n
         if _obs.enabled():
             counter = _obs.metrics.counter
@@ -1623,12 +1637,13 @@ class DecodeRuntime(object):
                        steps=steps) as sp:
             with _obs.span('decode.window.upload', cat='decode'):
                 args = self._uploaded('window', values)
-            with _obs.span('decode.window.dispatch', cat='decode'):
+            with _obs.span('decode.window.dispatch', cat='decode') as sent:
                 st, toks, *stats = call(self.params, self.state, *args)
                 self.state = st
                 toks.copy_to_host_async()
                 if stats:
                     self._launched_stats('window', stats[0])
+            self.dispatched_at = sent.t1
         if _obs.enabled():
             live = int(act.sum())
             counter = _obs.metrics.counter

@@ -78,8 +78,11 @@ every scheduler round is a ``serving.round`` span with ``serving.admit``
 / ``serving.prefill`` / ``serving.decode_step`` / ``serving.emit``
 children; waiting with nothing to do is ``serving.idle_wait``.  Each
 boundary also feeds a ``generation.*`` time or work counter, taken on
-the span's own clock.  ``generation.restaged`` counts the boundaries
-at which an unforeseen end changed the staged window, and
+the span's own clock.  Where a round lands a window, the stretch in
+which the chip has nothing is a ``serving.boundary`` span (`_boundary`:
+the read, ``serving.boundary.check``, the first launch) with the
+``generation.boundary_*`` counters.  ``generation.restaged`` counts the
+boundaries at which an unforeseen end changed the staged window, and
 ``generation.overrun_slot_steps`` the slot-steps run for a stream that
 had ended on its first token.
 """
@@ -159,6 +162,14 @@ def _alive(r):
     """A slot-holding request that has no terminal reply yet (`_retire`
     takes the slot away with the reply)."""
     return r.slot is not None
+
+
+def _landed(toks):
+    """Whether a flown window's tokens had landed before anyone read
+    them (`decode._Pending.landed`: one `is_ready()`, no wait).  A
+    stand-in that cannot say (a test's wrapper) says no."""
+    landed = getattr(toks, 'landed', None)
+    return landed is not None and bool(landed())
 
 
 class _Plan(object):
@@ -397,45 +408,22 @@ class GenerationEngine(ServingEngine):
         if not self._admit_and_sweep():
             return False
         plan = self._plan(flight)
-        if flight is not None and not plan.short:
-            self._stage(plan)
-        # the boundary: from here to the dispatch the chip has nothing
         toks = None
         if flight is not None:
-            toks = self._land(flight)
-            ended = self._eos_rows(flight, toks)
-            self._sweep_active()
-            if plan.keep(lambda r: _alive(r) and id(r) not in ended):
-                _obs.metrics.counter('generation.restaged').inc()
-            if plan.chunk is not None and not _alive(plan.chunk):
-                plan.chunk = None
-            waiting = plan.chunk is None and bool(self._queue)
-            if plan.short or (waiting and self._leavers(flight, plan)):
-                # a table that could not grow, or a queued request and
-                # nothing to run for it, while streams of the landed
-                # window are about to give slots and pages back: this
-                # boundary keeps the serial order (emit and retire,
-                # admit, grow or `kv_oom`, upload, launch)
-                self._emit_window(flight, toks)
-                flight = None
-                self._admit_and_sweep()
-                plan = self._plan(None)
-            elif waiting:
-                # it arrived after the staging: its first chunk goes
-                # directly behind the landed window all the same
-                self._admit_and_sweep()
-                if self._pick_chunk(plan):
-                    plan.join(plan.chunk)
-        first = self._launch_chunk(plan)
-        if self._speculative:
+            if not plan.short:
+                self._stage(plan)
+            flight, toks, plan, first, flown = self._boundary(flight, plan)
+        elif self._speculative:
             # the draft starts from the last tokens, on the host: the
             # chunk is read before its window goes, and the window (read
             # inside its launch) lands in the round that launched it
+            first = self._launch_chunk(plan)
             self._finish_chunk(plan, first, None)
             plan.keep(_alive)
             flight = flown = self._launch_window(plan)
             toks = flown.toks if flown is not None else None
         else:
+            first = self._launch_chunk(plan)
             flown = self._launch_window(plan)
         # under the launched window: what the landed one and the chunk
         # gave; a launch that failed is replied to after them, as in the
@@ -452,6 +440,75 @@ class GenerationEngine(ServingEngine):
             if not self._speculative:
                 self._flight = flown
         return True
+
+    def _boundary(self, flight, plan):
+        """Read the landed window and launch what goes behind it: from
+        the read's return to the first dispatch the chip has nothing.
+        `serving.boundary` holds the read (`decode.window.fetch`), the
+        check between the read and the first launch call
+        (`serving.boundary.check`) and that launch, the chunk's when
+        there is one, else the window's; the ``generation.boundary_*``
+        counters stop where the runtime says that launch's dispatch span
+        ended (`DecodeRuntime.dispatched_at`), so the counting behind a
+        dispatch, under the launched work, is outside.  Returns (the
+        landed flight or None when it is already emitted, its tokens,
+        the plan as launched, the chunk's sample, the window flown)."""
+        rt = self.runtime
+        with _obs.span('serving.boundary', cat='serving') as edge:
+            # had the host's work under the window outlasted it, the chip
+            # went dry BEFORE this boundary began
+            late = _obs.enabled() and _landed(flight.toks)
+            toks = self._land(flight)
+            with _obs.span('serving.boundary.check', cat='serving') as check:
+                ended = self._eos_rows(flight, toks)
+                self._sweep_active()
+                if plan.keep(lambda r: _alive(r) and id(r) not in ended):
+                    _obs.metrics.counter('generation.restaged').inc()
+                if plan.chunk is not None and not _alive(plan.chunk):
+                    plan.chunk = None
+                waiting = plan.chunk is None and bool(self._queue)
+                serial = plan.short or (waiting
+                                        and self._leavers(flight, plan))
+                if serial:
+                    # a table that could not grow, or a queued request
+                    # and nothing to run for it, while streams of the
+                    # landed window are about to give slots and pages
+                    # back: this boundary keeps the serial order (emit
+                    # and retire, admit, grow or `kv_oom`, upload, launch)
+                    self._emit_window(flight, toks)
+                    flight = None
+                    self._admit_and_sweep()
+                    plan = self._plan(None)
+                elif waiting:
+                    # it arrived after the staging: its first chunk goes
+                    # directly behind the landed window all the same
+                    self._admit_and_sweep()
+                    if self._pick_chunk(plan):
+                        plan.join(plan.chunk)
+            rt.dispatched_at = None
+            first = self._launch_chunk(plan)
+            dry_to = rt.dispatched_at
+            window_first = dry_to is None       # no chunk went
+            if window_first:
+                flown = self._launch_window(plan)
+                dry_to = rt.dispatched_at
+            if _obs.enabled():
+                edge.args.update(
+                    serial=bool(serial), late=late,
+                    first='none' if dry_to is None
+                    else 'window' if window_first else 'chunk')
+        if not window_first:
+            flown = self._launch_window(plan)
+        if dry_to is not None:
+            counter = _obs.metrics.counter
+            counter('generation.boundaries').inc()
+            counter('generation.boundary_dry_s').inc(dry_to - check.t0)
+            counter('generation.boundary_check_s').inc(check.seconds)
+            if late:
+                counter('generation.boundary_late').inc()
+            if serial:
+                counter('generation.boundary_serial').inc()
+        return flight, toks, plan, first, flown
 
     def _admit_and_sweep(self):
         with _obs.span('serving.admit', cat='serving'):
@@ -622,27 +679,31 @@ class GenerationEngine(ServingEngine):
         r, rt = plan.chunk, self.runtime
         if r is None:
             return None
+        slot = r.slot
         with _obs.span('serving.prefill', cat='serving') as sp:
-            if _obs.enabled():
-                sp.args.update(slot=int(r.slot), ring=bool(plan.ring))
-                if r.trace is not None:
-                    sp.args.update(trace_id=r.trace.trace_id,
-                                   parent_span_id=r.trace.span_id)
             try:
                 if plan.ring:
-                    first, _logits = rt.prefill_ring(r.slot, r.prompt,
+                    first, _logits = rt.prefill_ring(slot, r.prompt,
                                                      r.params)
                 else:
-                    first, _logits = rt.prefill(r.slot, plan.tokens,
+                    first, _logits = rt.prefill(slot, plan.tokens,
                                                 r.offset, r.params)
             except BaseException as e:  # noqa: BLE001 - replied per request
                 self._fail_chunk(r, e)
-                plan.chunk = None
+                plan.chunk = first = None
                 plan.keep(_alive)
-                return None
-            r.offset += int(plan.tokens.size)
+            else:
+                r.offset += int(plan.tokens.size)
+            # the span's args, behind the dispatch: the chip has its work
             if _obs.enabled():
-                sp.args['offset'] = int(r.offset)
+                sp.args.update(slot=int(slot), ring=bool(plan.ring))
+                if r.trace is not None:
+                    sp.args.update(trace_id=r.trace.trace_id,
+                                   parent_span_id=r.trace.span_id)
+                if first is not None:
+                    sp.args['offset'] = int(r.offset)
+        if first is None:
+            return None
         r.chunks += 1
         _obs.metrics.counter('generation.prefill_chunks').inc()
         return first
@@ -684,13 +745,8 @@ class GenerationEngine(ServingEngine):
         if not plan.dec:
             return None
         rt, K = self.runtime, self._gen.decode_window
+        toks = error = None
         with _obs.span('serving.decode_step', cat='serving') as sp:
-            if _obs.enabled():
-                sp.args.update(
-                    steps=int(K), requests=len(plan.dec),
-                    speculative=self._speculative,
-                    links=[r.trace.trace_id for r in plan.dec
-                           if r.trace is not None])
             try:
                 if _faults.any_active():
                     _faults.maybe_fail('decode_step')
@@ -699,7 +755,16 @@ class GenerationEngine(ServingEngine):
                 else:
                     toks = rt.decode_window(K, *plan.vectors)
             except BaseException as e:  # noqa: BLE001 - replied per request
-                return _Flight(plan.dec, None, e)
+                error = e
+            # the span's args, behind the dispatch: the chip has its work
+            if _obs.enabled():
+                sp.args.update(
+                    steps=int(K), requests=len(plan.dec),
+                    speculative=self._speculative,
+                    links=[r.trace.trace_id for r in plan.dec
+                           if r.trace is not None])
+        if error is not None:
+            return _Flight(plan.dec, None, error)
         _obs.metrics.counter('generation.decode_windows').inc()
         return _Flight(plan.dec, toks)
 

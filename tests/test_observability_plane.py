@@ -207,6 +207,66 @@ def test_obs_disabled_new_surfaces_do_zero_work():
         obs.enable()
 
 
+def test_obs_disabled_serving_boundary_does_zero_work(monkeypatch):
+    """The round's boundary with the plane off: `serving.boundary` and
+    its check enter no annotation and write no event, none of the five
+    `generation.boundary*` counters is looked up, the landed window's
+    handle is not asked whether it was ready (`is_ready()`), and the
+    scheduler reads no clock for telemetry, over rounds that land a
+    window and launch a chunk or a window behind it."""
+    from paddle_tpu.serving.engine import READY
+    from paddle_tpu.serving.generation import (DecodeRuntime,
+                                               GenerationConfig,
+                                               GenerationEngine, decode,
+                                               scheduler)
+    cfg = dict(vocab=64, d_model=32, n_layer=1, n_head=4, n_kv_head=2,
+               d_ffn=64, theta=10000.0, max_len=32)
+    rt = DecodeRuntime(decode.random_weights(cfg), cfg, slots=2,
+                       prefill_chunk=4)
+
+    def serve():
+        eng = GenerationEngine(rt, config=ServingConfig(),
+                               gen_config=GenerationConfig(decode_window=2))
+        eng._set_state(READY)
+        streams = [eng.generate([1, 2, 3], max_new=7),
+                   eng.generate(list(range(4, 14)), max_new=3)]
+        while eng._queue or eng._active:
+            assert eng._round()
+        assert all(s.result(0).ok for s in streams)
+        return eng
+
+    boundaries, dry = (_cnt('generation.boundaries'),
+                       _cnt('generation.boundary_dry_s'))
+    serve()                                     # warm, and the plane ON:
+    assert _cnt('generation.boundaries') >= boundaries + 3
+    assert _cnt('generation.boundary_dry_s') > dry
+    events_before = obs.recorder().event_count()
+    counters_before = dict(obs.counters())
+    obs.disable()
+    try:
+        def boom(*a, **k):
+            raise AssertionError('telemetry invoked while disabled')
+        monkeypatch.setattr(decode._Pending, 'landed', boom)
+        monkeypatch.setattr(obs.tracing, '_annotation', boom)
+        monkeypatch.setattr(obs.tracing.TraceRecorder, 'add_complete', boom)
+        real = obs.metrics.counter
+        monkeypatch.setattr(
+            obs.metrics, 'counter',
+            lambda name: boom() if name.startswith('generation.boundar')
+            else real(name))
+
+        class _NoClock(object):
+            perf_counter = staticmethod(boom)
+        monkeypatch.setattr(scheduler, 'time', _NoClock)
+        monkeypatch.setattr(decode, 'time', _NoClock, raising=False)
+        serve()
+    finally:
+        obs.enable()
+    assert obs.recorder().event_count() == events_before
+    assert all((v or 0) == (counters_before.get(k) or 0)
+               for k, v in obs.counters().items())
+
+
 # ----------------------------------------------------- flight recorder
 
 def test_flight_ring_bounded_and_tap_mirrors_trace_events():

@@ -2,11 +2,16 @@
 program (PR 24): exact counter arithmetic of a scripted serving run, the
 three phase spans that tile every request, the program's spans on the
 profiler's own timeline, the timeline reduction on hand-built intervals,
-and the executor's own clock under Executor and ParallelExecutor.
+and the executor's own clock under Executor and ParallelExecutor; the
+round's boundary, where the chip has nothing (PR 59): its span, its five
+counters and the four benchmark metrics that read them.
 
 Everything here runs on the CPU at toy widths: it checks counts, span
 structure and arithmetic, never a time."""
 import glob
+import importlib.util
+import os
+import sys
 
 import numpy as np
 import pytest
@@ -16,7 +21,7 @@ import jax
 import paddle_tpu as fluid
 import paddle_tpu.observability as obs
 from paddle_tpu.observability import timeline, tracing
-from paddle_tpu.serving.engine import ServingConfig
+from paddle_tpu.serving.engine import READY, ServingConfig
 from paddle_tpu.serving.generation import (DecodeRuntime, GenerationConfig,
                                            GenerationEngine)
 from paddle_tpu.serving.generation.decode import random_weights
@@ -49,6 +54,16 @@ def _delta(after, before):
 def _spans_of(trace_id):
     return [e for e in obs.recorder().events() if e['ph'] == 'X'
             and e.get('args', {}).get('trace_id') == trace_id]
+
+
+def _recorded():
+    """{span name: [(start, end)]} of the recorder's complete events."""
+    rec = {}
+    for e in obs.recorder().events():
+        if e['ph'] == 'X':
+            rec.setdefault(e['name'], []).append(
+                (e['ts'], e['ts'] + e['dur']))
+    return rec
 
 
 # ------------------------------------------- (a) exact counter arithmetic
@@ -142,6 +157,120 @@ def test_kv_rows_read_follows_the_executables_own_shapes():
     half = jax.ShapeDtypeStruct((SLOTS, q8.cache.max_pages // 2), 'int32')
     assert decode._gathered_rows(q8.cache, state, half) \
         == SLOTS * CFG['max_len'] // 2
+
+
+# ------------------------------------------ (a') the boundary, by hand
+
+class _Readiness(object):
+    """Stands where a flown window's device array stands in its handle:
+    says what the script says, and counts how often it was asked."""
+
+    def __init__(self, ready):
+        self.ready, self.asked = ready, 0
+
+    def is_ready(self):
+        self.asked += 1
+        return self.ready
+
+
+def _by_hand(script, ready=(), slots=1, speculative=False, trace=None):
+    """Run ``script`` ([(prompt, max_new)], all submitted before the
+    first round) round by round on THIS thread over a runtime of
+    ``slots`` slots.  Window n's handle says ``ready[n]`` (False past
+    the script's end) when asked whether it had landed.  Returns (counter
+    deltas, the windows' `_Readiness`, the streams)."""
+    rt = DecodeRuntime(random_weights(CFG, seed=0), CFG, slots=slots,
+                       prefill_chunk=CHUNK, page_len=PAGE)
+    asked, window = [], rt.decode_window
+
+    def scripted(*args):
+        toks = window(*args)
+        asked.append(_Readiness(len(asked) < len(ready)
+                                and ready[len(asked)]))
+        toks._dev = asked[-1]
+        return toks
+
+    rt.decode_window = scripted
+    eng = GenerationEngine(
+        rt, config=ServingConfig(),
+        gen_config=GenerationConfig(decode_window=WINDOW,
+                                    speculative=speculative))
+    eng._set_state(READY)
+    before = dict(obs.counters())
+    streams = [eng.generate(p, max_new=n) for p, n in script]
+    if trace is not None:
+        jax.profiler.start_trace(trace)
+    try:
+        rounds = 0
+        while eng._queue or eng._active:
+            assert eng._round()
+            rounds += 1
+            assert rounds < 100
+    finally:
+        if trace is not None:
+            jax.profiler.stop_trace()
+    assert all(s.result(0).ok for s in streams)
+    return _delta(obs.counters(), before), asked, streams
+
+
+def _boundary_spans():
+    return [e['args'] for e in obs.recorder().events()
+            if e['name'] == 'serving.boundary']
+
+
+def test_scripted_boundaries_count_exactly():
+    """One slot, two requests.  Round 2 lands A's only window with B
+    queued and A leaving: the boundary keeps the serial order and
+    launches B's chunk; its tokens had landed before the read (the
+    script says so): late.  Round 3 lands B's first window and launches
+    its second; round 4 lands that and launches nothing: no boundary."""
+    c, asked, _ = _by_hand([([1, 2, 3], 1 + WINDOW),
+                            ([4, 5, 6], 1 + 2 * WINDOW)],
+                           ready=[True, False, True])
+    assert c['generation.decode_windows'] == 3
+    assert c['generation.boundaries'] == 2
+    assert c['generation.boundary_serial'] == 1
+    assert c['generation.boundary_late'] == 1
+    assert 0 < c['generation.boundary_check_s'] \
+        <= c['generation.boundary_dry_s'] < c['generation.round_s']
+    # one `is_ready()` a landed window, whatever it launched
+    assert [r.asked for r in asked] == [1, 1, 1]
+    assert [(a['first'], a['serial'], a['late'])
+            for a in _boundary_spans()] == [
+        ('chunk', True, True), ('window', False, False),
+        ('none', False, True)]
+    # the check is a child of the boundary, the serial emit of the check
+    rec = _recorded()
+    assert len(rec['serving.boundary.check']) == 3
+    assert all(_enclosed(s, rec['serving.boundary'])
+               for s in rec['serving.boundary.check'])
+    assert sum(_enclosed(s, rec['serving.boundary.check'])
+               for s in rec['serving.emit']) == 1
+    # the counter stops where the first dispatch span ended: inside the
+    # boundary span, which ends when the launch call has returned
+    dry = c['generation.boundary_dry_s']
+    spans = sum(e - s for s, e in rec['serving.boundary'][:2]) / 1e6
+    assert dry < spans
+
+
+def test_a_steady_stream_has_a_boundary_a_window_but_the_last():
+    c, asked, _ = _by_hand([([1, 2, 3, 4, 5], 1 + 3 * WINDOW)])
+    assert c['generation.decode_windows'] == 3
+    assert c['generation.boundaries'] == 2
+    assert c.get('generation.boundary_serial', 0) == 0
+    assert c.get('generation.boundary_late', 0) == 0
+    assert [a['first'] for a in _boundary_spans()] == [
+        'window', 'window', 'none']
+
+
+def test_a_speculative_engine_counts_no_boundary():
+    """Every launch of a speculative round is read at once: nothing is
+    ever in flight, so no round has a boundary to count."""
+    c, asked, _ = _by_hand([([1, 2, 3], 1 + 2 * WINDOW)], speculative=True)
+    assert c['generation.spec_proposed'] > 0
+    assert not any(v for k, v in c.items()
+                   if k.startswith('generation.boundar'))
+    assert not _boundary_spans() and not asked
 
 
 # --------------------------------------------- (b) the phases tile the root
@@ -256,14 +385,52 @@ def test_profiler_trace_holds_the_program_spans_nested(tmp_path):
             assert span[2] in sched
             assert _enclosed(span, by_name[parent]), (child, parent)
     # ... exactly as the recorder nests them
-    rec = {}
-    for e in obs.recorder().events():
-        if e['ph'] == 'X':
-            rec.setdefault(e['name'], []).append(
-                (e['ts'], e['ts'] + e['dur']))
+    rec = _recorded()
     for child, parent in [('decode.window', 'serving.decode_step'),
                           ('serving.decode_step', 'serving.round')]:
         assert all(_enclosed(s, rec[parent]) for s in rec[child])
+
+
+def test_profiler_trace_holds_the_boundary_around_the_first_dispatch(
+        tmp_path):
+    """Two slots, two requests, the second three chunks long: while it
+    prefills, the first one's boundaries launch a chunk FIRST and the
+    window behind it; then one launches a window alone, and the last
+    nothing.  On the profiler's timeline `pt:serving.boundary` lies in
+    the round, holds the read, the check and the first dispatch alone,
+    and ends before the landed window is emitted."""
+    _by_hand([([1, 2, 3], 1 + 5 * WINDOW),
+              (list(range(10, 10 + 3 * CHUNK)), 2)], slots=2,
+             trace=str(tmp_path))
+    path, = glob.glob(str(tmp_path / '**' / '*.xplane.pb'), recursive=True)
+    _, _, thread_spans = timeline.load_trace(path)
+    (_, spans), = [(t, sp) for t, sp in thread_spans.items()
+                   if any(n == 'serving.boundary' for _, _, n in sp)]
+    named = lambda *names: sorted(  # noqa: E731
+        sp for sp in spans if sp[2] in names)
+    boundaries, rounds = named('serving.boundary'), named('serving.round')
+    dispatches = named('decode.prefill.dispatch', 'decode.window.dispatch')
+    firsts = [a['first'] for a in _boundary_spans()]
+    assert firsts == ['chunk', 'chunk', 'chunk', 'window', 'none']
+    assert len(boundaries) == len(firsts)
+    for b, first in zip(boundaries, firsts):
+        rnd, = [r for r in rounds if _enclosed(b, [r])]
+        inside = lambda sp: _enclosed(sp, [b])  # noqa: E731
+        fetch, = filter(inside, named('decode.window.fetch'))
+        check, = filter(inside, named('serving.boundary.check'))
+        assert b[0] <= fetch[0] and fetch[1] <= check[0]
+        mine = [d for d in dispatches if _enclosed(d, [rnd])]
+        held = list(filter(inside, mine))
+        if first == 'none':
+            assert not mine
+            continue
+        # the first dispatch of the round and no other
+        assert held == mine[:1] and check[1] <= held[0][0]
+        assert held[0][2] == 'decode.%s.dispatch' % (
+            'prefill' if first == 'chunk' else 'window')
+        assert len(mine) == (2 if first == 'chunk' else 1)
+        emits = [e for e in named('serving.emit') if _enclosed(e, [rnd])]
+        assert emits and all(e[0] >= b[1] for e in emits)
 
 
 # --------------------------------- (d) the timeline on hand-built intervals
@@ -365,6 +532,117 @@ def test_timeline_unattributed_and_unpaired():
     assert out['named_share'] == 0.0
     assert timeline.analyse([], [], threads) is None
     assert timeline.idle_gaps([(0, 10), (10.0 + 50, 20)]) == []   # < 100 ns
+
+
+def _boundary_built():
+    """One round whose boundary the chip waits through: ops 10-20 ms and
+    24-30 ms.  The gap (20-24 ms) lies 0.5 ms under the fetch's tail,
+    1.5 ms under the check, 0.5 ms under the staged upload's look-up and
+    1.5 ms under the dispatch: no child holds most of it."""
+    ops = [(10 * MS, 20 * MS), (24 * MS, 30 * MS)]
+    host = [
+        (5.0 * MS, 40.0 * MS, 'serving.round'),
+        (9.7 * MS, 10.0 * MS, 'decode.window.dispatch'),
+        (12.0 * MS, 24.05 * MS, 'serving.boundary'),
+        (12.1 * MS, 20.5 * MS, 'decode.window.fetch'),
+        (20.5 * MS, 22.0 * MS, 'serving.boundary.check'),
+        (22.0 * MS, 24.1 * MS, 'serving.decode_step'),
+        (22.0 * MS, 24.05 * MS, 'decode.window'),
+        (22.0 * MS, 22.5 * MS, 'decode.window.upload'),
+        (22.5 * MS, 24.0 * MS, 'decode.window.dispatch'),
+        (24.2 * MS, 26.0 * MS, 'serving.emit'),
+    ]
+    return ops, list(ops), {'scheduler': host}
+
+
+def test_idle_under_rolls_a_gap_up_to_the_boundary():
+    ops, modules, threads = _boundary_built()
+    # the launches start with their calls: no skew to take off
+    modules = [(9.7 * MS, 20 * MS), (22.5 * MS, 30 * MS)]
+    out = timeline.analyse(ops, modules, threads)
+    assert out['clock_skew_ms'] == pytest.approx(0.0)
+    assert out['idle_s'] == pytest.approx(0.004)
+    # innermost naming as it was: the whole gap to the span that, as the
+    # innermost, holds most of it (the check and the dispatch tie at
+    # 1.5 ms; `max` keeps the first it met)
+    (name, seconds), = out['idle_s_by_span'].items()
+    assert name in ('serving.boundary.check', 'decode.window.dispatch')
+    assert seconds == pytest.approx(0.004)
+    under = out['idle_under']
+    assert under['serving.boundary'] == pytest.approx(0.004)
+    assert under['serving.round'] == pytest.approx(0.004)
+    assert under['serving.boundary.check'] == pytest.approx(0.0015)
+    assert under['decode.window.fetch'] == pytest.approx(0.0005)
+    assert under['decode.window.upload'] == pytest.approx(0.0005)
+    assert under['decode.window.dispatch'] == pytest.approx(0.0015)
+    assert under['decode.window'] == pytest.approx(0.002)
+    assert under['serving.decode_step'] == pytest.approx(0.002)
+    assert 'serving.emit' not in under and 'unattributed' not in under
+    text = timeline.report(out)
+    assert 'idle under span and descendants' in text
+    assert text.index('named_share') < text.rindex('serving.boundary.check')
+
+
+def test_idle_under_counts_no_instant_twice_and_names_the_rest():
+    gaps = [(0.0, 10.0), (20.0, 30.0), (50.0, 60.0)]
+    spans = [(5.0, 25.0, 'a'),           # 5 of gap 1, 5 of gap 2
+             (6.0, 8.0, 'a'),            # inside an `a`: counted already
+             (22.0, 24.0, 'b'),          # a child of the first `a`
+             (28.0, 29.0, 'a'),          # a later `a`, top level
+             (100.0, 200.0, 'c')]        # under no gap
+    out = timeline.idle_under(gaps, spans)
+    assert out == {'a': 11.0, 'b': 2.0, 'c': 0.0, 'unattributed': 19.0}
+    assert timeline.idle_under(gaps, []) == {'unattributed': 30.0}
+    assert timeline.idle_under([], spans)['unattributed'] == 0.0
+    # the hand-built rounds of (d): every gap under the one round alive
+    ops, modules, threads = _hand_built(0.0)
+    under = timeline.analyse(ops, modules, threads)['idle_under']
+    assert under['serving.round'] == pytest.approx(0.011)
+    assert under['serving.emit'] == pytest.approx(0.0077)
+
+
+# ----------------------------------- (d') the metrics that read the counters
+
+_BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), 'benchmarks')
+
+
+def _metric(name):
+    if _BENCH not in sys.path:           # the readers import `lib.program`
+        sys.path.insert(0, _BENCH)
+    spec = importlib.util.spec_from_file_location(
+        'metric_' + name.replace('.', '_'),
+        os.path.join(_BENCH, 'metrics', name + '.py'))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize('name,unit,want', [
+    ('scheduler.boundary_ms', 'ms', 2.5),
+    ('scheduler.boundary_check_ms', 'ms', 1.0),
+    ('scheduler.boundary_dry_share', '%', 2.0),
+    ('scheduler.boundary_late_share', '%', 25.0)])
+def test_boundary_metrics_read_the_counters(name, unit, want):
+    mod = _metric(name)
+    assert mod.META == {
+        'name': name, 'unit': unit, 'better': 'lower',
+        'source': 'program_counter',
+        'layer': 'scheduler (continuous batching)', 'moves': 'tpot_p50_ms'}
+    counters = {'generation.boundaries': 400.0,
+                'generation.boundary_dry_s': 1.0,
+                'generation.boundary_check_s': 0.4,
+                'generation.boundary_late': 100.0,
+                'generation.boundary_serial': 7.0,
+                'generation.round_s': 45.0, 'generation.idle_wait_s': 5.0}
+    assert mod.read({'counters': counters}) == pytest.approx(want)
+    # a cell without boundaries (training; the parent of this PR's
+    # program): no reading, and the result line leaves the metric out
+    assert mod.read({'counters': {'generation.round_s': 45.0,
+                                  'generation.idle_wait_s': 5.0}}) is None
+    assert mod.read({'counters': {}}) is None
+    if 'late' in name:
+        assert mod.read({'counters': {'generation.boundaries': 3.0}}) == 0.0
 
 
 # ------------------------------ (e) the executor's own clock, both entries
