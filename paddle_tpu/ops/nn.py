@@ -190,23 +190,60 @@ def _bn_shapes(x, ch_axis):
     return axes, bshape
 
 
-def _bn_train_fwd(x, scale, bias, ch_axis, eps):
-    axes, bshape = _bn_shapes(x, ch_axis)
-    xf = x.astype(jnp.float32)
-    # the pilot is cut from x BEFORE the upcast (the same values): cut
-    # from xf, XLA:TPU writes all of xf to HBM in the forward so that a
-    # later fusion can read these few elements of it
-    c = lax.stop_gradient(x[tuple(
-        slice(None) if i == ch_axis else slice(0, 1)
-        for i in range(x.ndim))].astype(jnp.float32))
-    d = xf - c
+# The guard of _bn_train_fwd.  With the pilot md away from the batch mean
+# the f32 subtraction E[d^2] - E[d]^2 returns the variance with the sums'
+# own rounding times 1 + md^2/v: nothing to speak of while the pilot is
+# near (a moving mean that tracks the batch), tens of per cent at 10^3
+# sigma (a moving mean still at its initial 0 under such an input).  So
+# where md^2 > _BN_RESHIFT * v_raw in ANY channel, the pilot more than 16
+# sigma out, the sums are taken again around the mean the first read
+# found.  Below the threshold at most 8 of f32's 24 bits go: 5e-4 of the
+# variance at 16 sigma in a sequential sum over 1,152 elements, less in
+# a tree (tests/test_batch_norm_vjp.py holds 1e-3 at 10^3 and 10^4
+# sigma, where the unguarded form is off by 2 and 126 times the variance).
+# The test cannot be fooled by a v_raw that has already cancelled: its
+# error is a few ulp of md^2, under 2^-20 of it, so a v_raw of pure
+# rounding, of either sign, still reads md^2 > 256 * v_raw.  And it does
+# not fire on a sound network: activations lie within a few sigma of 0
+# at the first step and within a fraction of a sigma of the moving mean
+# after it; where it does fire, it costs one more pass over x.
+_BN_RESHIFT = 256.0
+
+
+def _bn_shifted_sums(x, c, axes):
+    """(md, v_raw) of x about the pilot c: mean(x - c) and the raw
+    one-pass variance mean((x - c)^2) - md^2, f32, keepdims."""
+    d = x.astype(jnp.float32) - c
     md = jnp.mean(d, axis=axes, keepdims=True)
-    v_raw = jnp.mean(jnp.square(d), axis=axes, keepdims=True) \
+    return md, jnp.mean(jnp.square(d), axis=axes, keepdims=True) \
         - jnp.square(md)
+
+
+def _bn_train_fwd(x, scale, bias, pilot, ch_axis, eps):
+    axes, bshape = _bn_shapes(x, ch_axis)
+    # the pilot does not depend on x (batch_norm hands in the moving
+    # mean), so the two sums can sit in the epilogue of whatever writes
+    # x: a pilot cut from x itself is a value that producer has not
+    # finished, and costs every batch norm a pass of its own over x
+    c = pilot.reshape(bshape)
+    md, v_raw = _bn_shifted_sums(x, c, axes)
+
+    def reshift(x, c, md, v_raw):
+        # the rare branch, and the only second read of x: the same sums
+        # about the mean the first read found
+        c = c + md
+        return (c,) + _bn_shifted_sums(x, c, axes)
+
+    # behind a barrier: XLA:TPU otherwise sinks the broadcasts of these
+    # vectors into both branches and the conditional returns an f32
+    # array of x's shape (53 of them in ResNet-50: 0.8 GB of temporaries)
+    c, md, v_raw = lax.optimization_barrier(lax.cond(
+        jnp.any(jnp.square(md) > _BN_RESHIFT * jnp.maximum(v_raw, 0.0)),
+        reshift, lambda x, c, md, v_raw: (c, md, v_raw), x, c, md, v_raw))
     v = jnp.maximum(v_raw, 0.0)
     m = (md + c).reshape(x.shape[ch_axis])
     v = v.reshape(x.shape[ch_axis])
-    y = (d - md) * (
+    y = (x.astype(jnp.float32) - c - md) * (
         scale.reshape(bshape) * lax.rsqrt(v.reshape(bshape) + eps)) + \
         bias.reshape(bshape)
     # residuals: x as it came (the convolution's bf16 output under AMP,
@@ -220,7 +257,8 @@ def _bn_train_bwd(ch_axis, eps, res, cts):
     its own dtype: d - md is recomputed in f32 inside the fusions that
     read it, every sum is f32, dx returns in x's dtype.  gm and gv are
     the cotangents of the saved mean and variance (zero in a training
-    step, where only stop_gradient'd moving statistics read them)."""
+    step, where only stop_gradient'd moving statistics read them).  The
+    pilot gets none: the shift cancels out of every result."""
     x, c, md, v_raw, scale = res
     dy, gm, gv = cts
     axes, bshape = _bn_shapes(x, ch_axis)
@@ -237,16 +275,18 @@ def _bn_train_bwd(ch_axis, eps, res, cts):
         v_raw > 0.0, 1.0, jnp.where(v_raw == 0.0, 0.5, 0.0))
     sr = scale * r
     dx = sr * dyf + (2.0 / n) * g_raw * dm + (gm - sr * dbias) / n
-    return (dx.astype(x.dtype), (dyd * r).reshape(x.shape[ch_axis]),
-            dbias.reshape(x.shape[ch_axis]))
+    ch = x.shape[ch_axis]
+    return (dx.astype(x.dtype), (dyd * r).reshape(ch), dbias.reshape(ch),
+            jnp.zeros(ch, jnp.float32))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _bn_train(x, scale, bias, ch_axis, eps):
-    """Training-mode batch norm over every axis but ch_axis: shifted
-    one-pass f32 statistics (see batch_norm), y in x's dtype, the batch
-    mean and variance per channel."""
-    return _bn_train_fwd(x, scale, bias, ch_axis, eps)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _bn_train(x, scale, bias, pilot, ch_axis, eps):
+    """Training-mode batch norm over every axis but ch_axis: one-pass f32
+    statistics shifted by `pilot`, a [C] f32 vector that must not depend
+    on x (see batch_norm), y in x's dtype, the batch mean and variance
+    per channel."""
+    return _bn_train_fwd(x, scale, bias, pilot, ch_axis, eps)[0]
 
 
 _bn_train.defvjp(_bn_train_fwd, _bn_train_bwd)
@@ -277,14 +317,21 @@ def batch_norm(ctx, ins, attrs):
                 'SavedMean': m, 'SavedVariance': v}
     # one-pass statistics (f32 accumulation): the two-pass
     # mean(square(x - m)) form reads the conv-sized activation TWICE
-    # per BN — at ResNet bench shapes the BN statistic fusions were
-    # ~20% of the step (per-HLO ledger, PERF.md r5).  The sums are
-    # SHIFTED by a per-channel pilot value c (the first element) so the
-    # E[d^2] - E[d]^2 subtraction never catastrophically cancels when
-    # |mean| >> std; the shift is analytically a no-op (stop_gradient'd)
-    # and fuses into the same single read.  Residual risk: a pilot
-    # element ~4000 sigma away from its group mean can still cancel —
-    # PT_TWO_PASS_NORM=1 restores the exact two-pass form.
+    # per BN.  The sums are SHIFTED by a per-channel pilot c so that the
+    # subtraction E[d^2] - E[d]^2 does not cancel when |mean| >> std;
+    # the shift is analytically a no-op.  The pilot is the MOVING mean:
+    # a vector that exists before x does, so XLA fuses the two sums
+    # into the epilogue of the convolution that writes x (a pilot cut
+    # from x gives each of ResNet-50's 53 batch norms a pass of its own
+    # over x, 2.7 GB a step at batch 128), and in steady state a
+    # closer one than an element of the sample.  Where it is far from
+    # the batch mean (a moving mean still at its initial 0 under an
+    # input with |mean| > 16 std) _bn_train_fwd sees that in the
+    # vectors it already has and only then takes the sums again around
+    # the mean it found (_BN_RESHIFT): the guarantee does not rest on
+    # the moving mean being any good.  PT_TWO_PASS_NORM=1 is the exact
+    # two-pass form under AD's own backward: the oracle to hold the
+    # one-pass form against.
     if os.environ.get('PT_TWO_PASS_NORM', '0') == '1':
         m = jnp.mean(xf, axis=axes)
         v = jnp.mean(jnp.square(xf - m.reshape(bshape)), axis=axes)
@@ -303,7 +350,9 @@ def batch_norm(ctx, ins, attrs):
     from ..observability import metrics
     metrics.counter('batch_norm.recompute_vjp').inc()
     y, m, v = _bn_train(x, scale.astype(jnp.float32),
-                        bias.astype(jnp.float32), ch_axis, eps)
+                        bias.astype(jnp.float32),
+                        lax.stop_gradient(mean.astype(jnp.float32)),
+                        ch_axis, eps)
     new_mean = lax.stop_gradient(momentum * mean + (1 - momentum) * m)
     new_var = lax.stop_gradient(momentum * var + (1 - momentum) * v)
     return {'Y': y, 'MeanOut': new_mean,
