@@ -12,7 +12,7 @@ the repo benchmarks, with seeded random weights:
   train_resnet50     ResNet-50, 224x224, 1000 classes, B=128, AMP: three
                      steps (the conv / bf16 flow-through side)
   kernels            every Pallas kernel a launch can select (flash,
-                     ssm_step, latent_attention, latent_prefill),
+                     ssm_step, kda_step, latent_attention, latent_prefill),
                      compiled by Mosaic and compared with its reference;
                      and the composed attention's loop over tiles of the
                      batch, whose result buffers start uninitialised
@@ -57,6 +57,9 @@ SIZES = {
             tile_loop=dict(batch=96, heads=8, seq=256, head_dim=64, tiles=6),
             # the falconh1_34b cell's scan state, 15 of 32 slots live
             ssm_step=dict(state=(32, 6, 32, 128, 256), groups=2, live=15),
+            # the kimi_linear cell's matrix state a slot (32 slots of its
+            # 128: the check holds four host copies), 20 live
+            kda_step=dict(state=(32, 6, 32, 128, 128), live=20),
             # the axk1 cell's latent pool: 64 heads over one 640-wide row
             latent=dict(slots=8, heads=64, v_dim=512, width=640, page_len=16,
                         pages=2049, layers=2, max_pages=449,
@@ -87,6 +90,7 @@ SIZES = {
                        seq_resident=128, seq_streamed=256),
             tile_loop=dict(batch=8, heads=2, seq=16, head_dim=8, tiles=4),
             ssm_step=dict(state=(4, 2, 8, 16, 128), groups=2, live=2),
+            kda_step=dict(state=(4, 2, 3, 8, 8), live=2),
             latent=dict(slots=4, heads=4, v_dim=128, width=256, page_len=4,
                         pages=41, layers=2, max_pages=6,
                         lengths=(0, 1, 13, 24), dtype='float32', tol=2e-5),
@@ -490,6 +494,70 @@ def _ssm_step_check(cfg):
     return out
 
 
+def _kda_step_check(cfg):
+    """`kda_step` (one decode step of the delta rule, in place over the
+    live slots) against the token form a slot at a time, on one layer of
+    a whole state array: no slot live (the kernel visits one block and
+    must hand it back), one, and ``live`` of them."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving.generation import kda
+    shape = tuple(cfg['state'])
+    S, L, H, d, _ = shape
+    assert kda.kda_step_eligible(shape, jnp.float32), \
+        'smoke shape is not eligible'
+    layer = L // 2
+    rng = np.random.RandomState(SEED)
+    f32 = jnp.float32
+
+    def unit(a):
+        return a / np.linalg.norm(a, axis=-1, keepdims=True)
+
+    a = jnp.asarray(np.exp(-0.01 * np.abs(rng.randn(S, H, d))), f32)
+    k = jnp.asarray(unit(rng.randn(S, H, d)), f32)
+    q = jnp.asarray(unit(rng.randn(S, H, d)) * d ** -0.5, f32)
+    v = jnp.asarray(rng.randn(S, H, d), f32)
+    beta = jnp.asarray(rng.rand(S, H), f32)
+
+    def fresh():
+        return jax.random.normal(jax.random.key(SEED), shape, f32)
+
+    def kernel(state, active):
+        return kda.kda_step(a, k, q, v, beta, state, layer, active)
+
+    compiled = jax.jit(kernel, donate_argnums=(0,)).lower(
+        fresh(), jnp.zeros(S, bool)).compile()
+    _assert_mosaic('kda_step', compiled.as_text().count('tpu_custom_call'),
+                   1)
+    before = np.asarray(fresh())
+
+    def plain(S0):                         # every slot, elementwise in f32
+        Sd = a[..., None] * S0
+        u = beta[..., None] * (v - jnp.sum(Sd * k[..., None], axis=-2))
+        new = Sd + k[..., None] * u[:, :, None, :]
+        return jnp.sum(new * q[..., None], axis=-2), new
+
+    want_o, want_S = (np.asarray(x) for x in jax.jit(plain)(
+        before[:, layer]))
+    out = {}
+    for n in (0, 1, cfg['live']):
+        active = np.zeros(S, bool)
+        active[rng.permutation(S)[:n]] = True
+        o, state = (np.asarray(x) for x in compiled(fresh(),
+                                                    jnp.asarray(active)))
+        # what the kernel did not visit is what it was, bit for bit
+        untouched = np.ones((S, L), bool)
+        untouched[active, layer] = False
+        np.testing.assert_array_equal(state[untouched], before[untouched])
+        np.testing.assert_array_equal(o[~active], 0.0)
+        if n:
+            _close('kda_step state', state[active, layer], want_S[active],
+                   1e-5)
+            out['o_err_live_%d' % n] = float('%.2e' % _close(
+                'kda_step o', o[active], want_o[active], 1e-5))
+    return out
+
+
 def _latent_attention_check(cfg):
     """`latent_attention` (one decode step's attention over a latent
     pool in place: every head reads the one row a token has) against
@@ -595,6 +663,7 @@ def kernels(cfg):
         'flash_streamed': _flash_check(flash, flash['seq_streamed']),
         'tile_loop': _tile_loop_check(cfg['tile_loop']),
         'ssm_step': _ssm_step_check(cfg['ssm_step']),
+        'kda_step': _kda_step_check(cfg['kda_step']),
         'latent_attention': _latent_attention_check(cfg['latent']),
         'latent_prefill': _latent_prefill_check(cfg['latent_prefill']),
     }

@@ -27,6 +27,14 @@ decode server (docs/generation.md):
     a decode step; and per layer (``cfg['ffn']``) a dense SwiGLU or
     routed experts beside a shared one, as one expert-parallel rank
     holds them.
+  * `kda` — Kimi Delta Attention, the mixer of the layers a
+    `latent_moe` model marks ``'kda'`` in ``cfg['mixer']`` (a mixer per
+    layer as data): a gated delta rule over a float32 matrix state a
+    head with a decay a channel, in the chunk form for a prefill chunk
+    and a single step for a decode window; its state lives in the
+    recurrent arrays, whose layer axis counts the layers that hold
+    state while the pool's counts those that attend
+    (`CacheConfig.recurrent_layers`).
   * `sampling` — greedy / temperature / top-k draws keyed by
     ``(request seed, absolute position)`` only, so fused and sequential
     decode sample bitwise-identical streams (ops/sampling.py).
@@ -51,7 +59,7 @@ from .kv_cache import (CacheConfig, PagePool, PrefixCache,  # noqa
                        SlotAllocator, default_page_len, init_state)
 from .decode import (DecodeRuntime, dense_reference,  # noqa
                      random_weights, weight_names, weight_shapes)
-from . import experts, latent, ssm  # noqa
+from . import experts, kda, latent, ssm  # noqa
 from .sampling import SamplingParams, draft_ngram  # noqa
 from .streaming import TokenStream  # noqa
 from .scheduler import GenerationConfig, GenerationEngine  # noqa
@@ -59,6 +67,6 @@ from .scheduler import GenerationConfig, GenerationEngine  # noqa
 __all__ = ['CacheConfig', 'PagePool', 'PrefixCache', 'SlotAllocator',
            'default_page_len', 'init_state', 'DecodeRuntime',
            'dense_reference', 'random_weights', 'weight_names',
-           'weight_shapes', 'experts', 'latent', 'ssm',
+           'weight_shapes', 'experts', 'kda', 'latent', 'ssm',
            'SamplingParams', 'draft_ngram', 'TokenStream',
            'GenerationConfig', 'GenerationEngine']

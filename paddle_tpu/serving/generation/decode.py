@@ -123,6 +123,23 @@ read of a result (`DecodeRuntime._count_stats`): no launch and no wait
 of their own.  No ring prefill and no int8 rows.  The other two kinds'
 programs are untouched by it, bit for bit (tests/
 test_generation_pipeline.py pins their lowered text).
+
+A ``latent_moe`` model may give a MIXER PER LAYER as data, as it gives
+its feed-forward: ``cfg['mixer']``, ``'latent'`` (the default, every
+layer) or ``'kda'`` for each layer.  A ``'kda'`` layer is Kimi Delta
+Attention (kda.py): a float32 matrix state a head and three short
+convolutions' tails, per slot, in the recurrent arrays of the state
+dict, and NO rows in the pool.  The pool's layer axis then counts the
+layers that attend and the state's the layers that hold state
+(`CacheConfig.layers` / ``recurrent_layers``; `_layer_axes` gives each
+layer its index on its own).  Such a model is `recurrent` like
+``falcon_h1``: a chunk starts from the slot's state (zeros at offset
+0), a step advances the live slots' and keeps the dead ones' bit for
+bit, and the runtime takes no prefix-cache hit, no speculative window
+and no ring prefill.  Its launches' stats carry two counts more
+(`_launch_stats`: the state the windows moved, the tokens the chunk
+scan took).  A model without the key lowers to the program it had
+(tests/test_generation_kda.py pins the text).
 """
 import threading
 from collections.abc import Mapping
@@ -136,6 +153,7 @@ from ...ops.attention import (cached_attention, latent_attention_eligible,
                               paged_attention_rows)
 from ...ops.sampling import sample_logits, sample_tokens_at, token_key
 from . import experts as _experts
+from . import kda as _kda
 from . import latent as _latent
 from . import ssm as _ssm
 from .kv_cache import (CacheConfig, PagePool, PrefixCache, SlotAllocator,
@@ -164,9 +182,11 @@ def _block(cfg):
 
 
 def _recurrent(cfg):
-    """Whether the model's block carries recurrent state: of the three
-    kinds (`_block`) only ``'falcon_h1'`` does (ssm.py)."""
-    return _block(cfg) == 'falcon_h1'
+    """Whether the model carries recurrent state: a ``'falcon_h1'``
+    block does in every layer (ssm.py), a ``'latent_moe'`` model in its
+    ``'kda'`` layers, if it has any (`_mixer_kinds`, kda.py)."""
+    return _block(cfg) == 'falcon_h1' or (
+        _latent_moe(cfg) and 'kda' in _mixer_kinds(cfg))
 
 
 def _latent_moe(cfg):
@@ -186,6 +206,33 @@ def _ffn_kinds(cfg):
     return kinds
 
 
+def _mixer_kinds(cfg):
+    """A ``latent_moe`` model's mixer per layer: ``cfg['mixer']``,
+    ``'latent'`` (latent.py) or ``'kda'`` (kda.py) for each of
+    ``n_layer``; every layer ``'latent'`` for a model without the key.
+    `_ffn_kinds`' sibling, read by no other block kind."""
+    L = int(cfg['n_layer'])
+    kinds = tuple(cfg.get('mixer', ('latent',) * L))
+    if len(kinds) != L or any(k not in ('latent', 'kda') for k in kinds) \
+            or 'latent' not in kinds:
+        raise ValueError("mixer must name 'latent' or 'kda' for each of "
+                         'the %d layers, and attend in at least one, got '
+                         '%r' % (L, kinds))
+    return kinds
+
+
+def _layer_axes(cfg):
+    """A ``latent_moe`` model's layers on their own axes: layer i's
+    index among the layers of ITS mixer kind, which is its index on the
+    pool's layer axis (``'latent'``) or the recurrent state's
+    (``'kda'``)."""
+    seen, out = {}, []
+    for kind in _mixer_kinds(cfg):
+        out.append(seen.get(kind, 0))
+        seen[kind] = out[-1] + 1
+    return out
+
+
 def _head_dim(cfg):
     return int(cfg.get('head_dim', int(cfg['d_model']) // int(cfg['n_head'])))
 
@@ -194,9 +241,10 @@ def weight_names(cfg):
     """The decode-side parameter names — the same names a trained llama
     program leaves in its scope (models/llama.py layout); a
     ``falcon_h1`` block adds its mixer's (`ssm.SLOTS`).  A ``latent_moe``
-    block has its own: per layer the two norms, latent attention's
-    (`latent.SLOTS`) and, by the layer's feed-forward kind, the dense
-    SwiGLU's or the expert layer's (`experts.SLOTS`)."""
+    block has its own: per layer the two norms, its mixer's (latent
+    attention's, `latent.slots`, or `kda.SLOTS`) and, by the layer's
+    feed-forward kind, the dense SwiGLU's or the expert layer's
+    (`experts.weight_shapes`)."""
     if _latent_moe(cfg):
         return list(_latent_moe_shapes(cfg))
     slots = _WEIGHT_SLOTS + (_ssm.SLOTS if _recurrent(cfg) else ())
@@ -233,12 +281,16 @@ def _latent_moe_shapes(cfg):
     """`weight_shapes` of a ``latent_moe`` model, in `weight_names`'
     order."""
     d, v = int(cfg['d_model']), int(cfg['vocab'])
-    att = _latent.weight_shapes(d, int(cfg['n_head']), cfg['latent'])
+    mixers = _mixer_kinds(cfg)
+    mixer = {'latent': _latent.weight_shapes(d, int(cfg['n_head']),
+                                             cfg['latent'])}
+    if 'kda' in mixers:
+        mixer['kda'] = _kda.weight_shapes(d, cfg['kda'])
     shapes = {'tok_emb': (v, d), 'final_norm': (d,), 'lm_proj_w': (d, v)}
     for i, kind in enumerate(_ffn_kinds(cfg)):
         p = 'layer_%d_' % i
         shapes.update({p + 'att_norm': (d,), p + 'ffn_norm': (d,)})
-        shapes.update((p + k, s) for k, s in att.items())
+        shapes.update((p + k, s) for k, s in mixer[mixers[i]].items())
         if kind == 'dense':
             f = int(cfg['d_ffn'])
             shapes.update({p + 'ffn_fc1_w': (d, f), p + 'ffn_fc3_w': (d, f),
@@ -306,14 +358,15 @@ def _public_rows(k, dh):
 def _prepared_names(cfg):
     """{public name: (slot, the executables' name for its prepared
     form)} of the weights the runtime keeps prepared.  A ``latent_moe``
-    model's are latent attention's (`latent.PREPARED`): a public weight
-    there has one or two prepared parts, and the second entry is the
-    tuple of their names."""
+    model's are latent attention's (`latent.prepared`), in the layers
+    that have it: a public weight there has one or two prepared parts,
+    and the second entry is the tuple of their names."""
     if _latent_moe(cfg):
         return {'layer_%d_%s' % (i, slot):
                 (slot, tuple('layer_%d_%s' % (i, t) for t in stored))
-                for i in range(int(cfg['n_layer']))
-                for slot, stored in _latent.PREPARED.items()}
+                for i, kind in enumerate(_mixer_kinds(cfg))
+                if kind == 'latent'
+                for slot, stored in _latent.prepared(cfg['latent']).items()}
     return {'layer_%d_%s' % (i, slot): (slot, 'layer_%d_%s' % (i, stored))
             for i in range(int(cfg['n_layer']))
             for slot, stored in _PREPARED.items()}
@@ -348,11 +401,14 @@ def _params_from(weights, cfg):
     if _latent_moe(cfg):
         # latent attention's: W_qb, W_kva, W_kvb -> `latent.PREPARED`
         prepare = jax.jit(_latent.prepare, static_argnums=(3, 4, 5, 6))
-        stored = [t for parts in _latent.PREPARED.values() for t in parts]
-        for i in range(int(cfg['n_layer'])):
+        slots = _latent.prepared(cfg['latent'])
+        stored = [t for parts in slots.values() for t in parts]
+        for i, kind in enumerate(_mixer_kinds(cfg)):
+            if kind != 'latent':
+                continue
             p = 'layer_%d_' % i
-            made = prepare(*(jnp.asarray(weights[p + s])
-                             for s in _latent.PREPARED), *_latent_dims(cfg))
+            made = prepare(*(jnp.asarray(weights[p + s]) for s in slots),
+                           *_latent_dims(cfg))
             params.update(zip((p + t for t in stored), made))
         return params
     dh = _head_dim(cfg)
@@ -563,6 +619,16 @@ def _gathered_rows(cache, st, bt):
 # its tokens: `experts.STATS` summed over its expert layers (and a
 # window's steps), then the latent rows it read
 _LAUNCH_STATS = _experts.STATS + ('latent_rows_read',)
+# and, of a model with `kda` layers, behind them: slot-layers whose matrix
+# state a window's steps read and wrote (`DecodeRuntime._count_stats`
+# turns them into bytes), tokens a chunk's scan took
+_KDA_STATS = ('kda_state_bytes', 'kda_chunk_tokens')
+
+
+def _launch_stats(cfg):
+    """The names of what a ``latent_moe`` launch of this model counts, in
+    the order of the array it hands back."""
+    return _LAUNCH_STATS + (_KDA_STATS if _recurrent(cfg) else ())
 
 
 def _latent_moe_ffn(w, cfg, x, i, valid):
@@ -605,6 +671,9 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False):
     recurrent = _recurrent(cfg)
     latent_moe = _latent_moe(cfg)
     dh = _head_dim(cfg)
+    if latent_moe:
+        mixers, axis = _mixer_kinds(cfg), _layer_axes(cfg)
+        n_latent = mixers.count('latent')
 
     if ring_mesh is not None:
         if recurrent or latent_moe:
@@ -633,11 +702,24 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False):
             with scope('layer'):
                 if latent_moe:
                     h = _latent.rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
-                    att, pool = _latent.prefill(
-                        w, 'layer_%d_' % i, cfg, h, p_abs,
-                        offset + true_count, st['k'], i, pg, rw, bt_row,
-                        latent_kernel)
-                    st = dict(st, k=pool)
+                    j = axis[i]
+                    if mixers[i] == 'kda':
+                        # the slot's state as the last chunk left it; a
+                        # prompt's first chunk starts from zeros
+                        carried = offset > 0
+                        att, S, tail = _kda.prefill_mixer(
+                            w, 'layer_%d_' % i, cfg, h,
+                            jnp.where(carried, st['ssm'][slot, j], 0.0),
+                            jnp.where(carried, st['conv'][slot, j], 0.0),
+                            true_count)
+                        st = dict(st, ssm=st['ssm'].at[slot, j].set(S),
+                                  conv=st['conv'].at[slot, j].set(tail))
+                    else:
+                        att, pool = _latent.prefill(
+                            w, 'layer_%d_' % i, cfg, h, p_abs,
+                            offset + true_count, st['k'], j, pg, rw, bt_row,
+                            latent_kernel)
+                        st = dict(st, k=pool)
                     with scope('ffn'):
                         x, counted = _latent_moe_ffn(
                             w, cfg, x + att, i, valid)
@@ -699,9 +781,13 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False):
         st['tok'] = st['tok'].at[slot].set(nxt)
         if latent_moe:
             # the blocks of cached rows the chunk visited, in every layer
-            rows = L * _latent.prefill_rows(new_len, M * PL)
-            return st, nxt, logits, jnp.concatenate(
-                [stats, rows.astype(jnp.int32).reshape(1)])
+            # that attends
+            rows = n_latent * _latent.prefill_rows(new_len, M * PL)
+            handed = [stats, rows.astype(jnp.int32).reshape(1)]
+            if recurrent:
+                handed.append(jnp.stack([jnp.int32(0),
+                                         true_count.astype(jnp.int32)]))
+            return st, nxt, logits, jnp.concatenate(handed)
         return st, nxt, logits
 
     return prefill
@@ -726,8 +812,11 @@ def _step_fn(cfg, cache, paged, state_kernel):
     A ``latent_moe`` model's layers take their own branch: `latent.step`
     (absorbed; with ``paged`` over the latent pool in place through
     `ops.attention.latent_attention`), then the layer's feed-forward
-    kind, where a slot that rides along routes nowhere.  Its step
-    returns a third value, the step's `_LAUNCH_STATS`."""
+    kind, where a slot that rides along routes nowhere; a ``'kda'``
+    layer advances the live slots' matrix state (`kda.step_mixer`: in
+    place through `kda.kda_step` with ``state_kernel``, else every slot
+    steps and a dead one's state is kept).  Its step returns a third
+    value, the step's `_launch_stats`."""
     import jax.numpy as jnp
     L = int(cfg['n_layer'])
     theta = float(cfg['theta'])
@@ -735,6 +824,9 @@ def _step_fn(cfg, cache, paged, state_kernel):
     quant = cache.quant == 'int8'
     recurrent = _recurrent(cfg)
     latent_moe = _latent_moe(cfg)
+    if latent_moe:
+        mixers, axis = _mixer_kinds(cfg), _layer_axes(cfg)
+        n_latent = mixers.count('latent')
 
     def step(w, st, bt, fed, active, seeds, temps, topks):
         import jax
@@ -754,10 +846,22 @@ def _step_fn(cfg, cache, paged, state_kernel):
             with scope('layer'):     # one name for every layer (prefill)
                 if latent_moe:
                     h = _latent.rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
-                    att, pool = _latent.step(
-                        w, 'layer_%d_' % i, cfg, h, pos, st['k'], i, pg, rw,
-                        bt, n_attend, paged)
-                    st = dict(st, k=pool)
+                    j = axis[i]
+                    if mixers[i] == 'kda':
+                        # an inactive slot keeps both kinds of state
+                        att, matrices, tail = _kda.step_mixer(
+                            w, 'layer_%d_' % i, cfg, h, st['ssm'], j,
+                            st['conv'][:, j], active, state_kernel)
+                        st = dict(
+                            st, ssm=matrices,
+                            conv=st['conv'].at[:, j].set(jnp.where(
+                                active[:, None, None], tail,
+                                st['conv'][:, j])))
+                    else:
+                        att, pool = _latent.step(
+                            w, 'layer_%d_' % i, cfg, h, pos, st['k'], j, pg,
+                            rw, bt, n_attend, paged)
+                        st = dict(st, k=pool)
                     with scope('ffn'):
                         x, counted = _latent_moe_ffn(
                             w, cfg, x + att, i, active)
@@ -812,8 +916,17 @@ def _step_fn(cfg, cache, paged, state_kernel):
             # positions cover (`paged_attention_rows`); gathered, every
             # slot's ``max_len``
             rows = jnp.sum(-(-n_attend // PL) * PL) if paged else S * M * PL
-            return st, nxt, jnp.concatenate(
-                [stats, jnp.asarray(L * rows, jnp.int32).reshape(1)])
+            handed = [stats,
+                      jnp.asarray(n_latent * rows, jnp.int32).reshape(1)]
+            if recurrent:
+                # the kernel moves the live slots' state in every `kda`
+                # layer, the composed step every slot's (kda.py)
+                moved = jnp.sum(active, dtype=jnp.int32) if state_kernel \
+                    else S
+                handed.append(jnp.stack(
+                    [jnp.asarray((L - n_latent) * moved, jnp.int32),
+                     jnp.int32(0)]))
+            return st, nxt, jnp.concatenate(handed)
         return st, nxt
 
     return step
@@ -1018,13 +1131,15 @@ class DecodeRuntime(object):
     shortage as a clean False/None the scheduler turns into
     backpressure or a terminal ``kv_oom``.
 
-    A model whose block carries recurrent state (``block:
-    'falcon_h1'``; `recurrent`) runs WITHOUT the prefix cache whatever
+    A model that carries recurrent state (``block: 'falcon_h1'``, or a
+    ``latent_moe`` model with ``'kda'`` layers; `recurrent`) runs
+    WITHOUT the prefix cache whatever
     ``prefix_cache`` says (a hit would skip tokens the scan state never
     saw), and refuses speculative windows and ring prefill.  A
     ``latent_moe`` model (`latent_moe`) keeps the prefix cache (its
     pages hold latent rows, shared like any other) and speculative
-    windows, and refuses ring prefill and ``kv_quant='int8'``.
+    windows (unless it is recurrent), and refuses ring prefill and
+    ``kv_quant='int8'``.
     """
 
     def __init__(self, weights, cfg, slots=4, prefill_chunk=8,
@@ -1054,18 +1169,25 @@ class DecodeRuntime(object):
                 # the second pool geometry: one row a token a layer
                 _ffn_kinds(cfg)
                 lat = cfg['latent']
+                # the pool's layers are those that attend, the state's
+                # those that hold one (`_mixer_kinds`)
+                attend = _mixer_kinds(cfg).count('latent')
                 geometry = dict(kv_heads=1,
                                 head_dim=_latent.stored_width(lat),
-                                latent=int(lat['kv_rank']))
+                                latent=int(lat['kv_rank']), layers=attend)
+                if self.recurrent:
+                    geometry.update(
+                        recurrent=_kda.state_shapes(cfg['kda']),
+                        recurrent_layers=int(cfg['n_layer']) - attend)
             else:
                 geometry = dict(kv_heads=int(cfg['n_kv_head']),
-                                head_dim=_head_dim(cfg))
+                                head_dim=_head_dim(cfg),
+                                layers=int(cfg['n_layer']),
+                                recurrent=(_ssm.state_shapes(cfg['ssm'])
+                                           if self.recurrent else None))
             self.cache = CacheConfig(
-                slots=slots, layers=int(cfg['n_layer']),
-                max_len=int(cfg['max_len']), dtype=cache_dtype,
-                page_len=page_len, pages=pages, quant=kv_quant,
-                recurrent=(_ssm.state_shapes(cfg['ssm']) if self.recurrent
-                           else None), **geometry)
+                slots=slots, max_len=int(cfg['max_len']), dtype=cache_dtype,
+                page_len=page_len, pages=pages, quant=kv_quant, **geometry)
             self.allocator = SlotAllocator(self.cache.slots)
             self.pool = PagePool(self.cache)
             # recurrent state cannot be shared between prompts: no prefix
@@ -1095,10 +1217,13 @@ class DecodeRuntime(object):
             else:
                 self.paged = paged_attention_eligible(
                     self.cache.pool_shape, self.cache.store_dtype, mesh)
-            # likewise the scan state of a recurrent model: in place over
-            # the live slots where that kernel can run (float32, one device)
-            self.state_kernel = self.recurrent and _ssm.ssm_step_eligible(
-                self.state['ssm'].shape, self.state['ssm'].dtype, mesh)
+            # likewise the scan state (the matrix state) of a recurrent
+            # model: in place over the live slots where its kernel can run
+            # (float32, one device)
+            self.state_kernel = self.recurrent and (
+                _kda.kda_step_eligible if self.latent_moe
+                else _ssm.ssm_step_eligible)(
+                    self.state['ssm'].shape, self.state['ssm'].dtype, mesh)
             # and a `latent_moe` chunk's scores: on chip where that kernel
             # can run, else through HBM a block at a time
             self.prefill_kernel = self.latent_moe and _latent.prefill_kernel(
@@ -1109,6 +1234,7 @@ class DecodeRuntime(object):
             # the counters: that happens behind the next read of a launch
             # that came after them (`_count_stats`)
             self._stats, self._counted = [], 0
+            self._stat_names = _launch_stats(cfg) if self.latent_moe else ()
             # arguments uploaded ahead of their launch: {'prefill' | 'window':
             # [(host copy, device array), ...]} (`stage_prefill`, `stage_window`)
             self._staged = {}
@@ -1474,7 +1600,7 @@ class DecodeRuntime(object):
         return _Pending(land, dev)
 
     def _count_stats(self, upto):
-        """Move the `_LAUNCH_STATS` of the first ``upto`` launches of this
+        """Move the `_launch_stats` of the first ``upto`` launches of this
         runtime (those made up to the one whose result was just read: the
         device runs launches in order, so theirs have landed with it)
         into ``generation.<stat>``, and a decode window's also into
@@ -1487,7 +1613,10 @@ class DecodeRuntime(object):
         if not _obs.enabled():
             return
         for kind, stats in mine:
-            for name, n in zip(_LAUNCH_STATS, np.asarray(stats)):
+            for name, n in zip(self._stat_names, np.asarray(stats)):
+                if name == 'kda_state_bytes':
+                    # counted in slot-layers: each read once, written once
+                    n = int(n) * 2 * _kda.state_bytes(self.cfg['kda'])
                 _obs.metrics.counter('generation.' + name).inc(int(n))
                 if kind == 'window':
                     _obs.metrics.counter(

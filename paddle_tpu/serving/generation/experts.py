@@ -19,9 +19,13 @@ its own experts give; what the absent ranks' experts would have added
 is left out, and nothing stands in for them or for their exchange.  A
 token that is padding, or a slot that rides along, routes nowhere.
 
-`select` is the ONE place that turns scores into picks (plain top-k:
-the reading of ``topk_method: 'none'``; a group-limited or
-bias-corrected selection would change this function alone).
+`select` is the ONE place that turns scores into picks: plain top-k (the
+reading of ``topk_method: 'none'``), or, for a model whose ``moe`` says
+``bias``, the BIAS-CORRECTED choice ``top_k(g + b)`` with ``b`` the
+layer's weight ``moe_router_bias`` ``[n_routed]``: ``b`` decides which
+experts are picked and nothing else, the weights are the picks' own
+scores ``g_e``.  A group-limited selection would change this function
+alone.
 
 `routed` does work proportional to the ASSIGNMENTS: the (token, pick)
 pairs that fall on held experts are sorted by expert and the tokens
@@ -63,34 +67,44 @@ def held(moe):
 
 
 def weight_shapes(d_model, moe):
-    """{slot: shape} of one expert layer's weights."""
+    """{slot: shape} of one expert layer's weights: `SLOTS`, and where
+    ``moe['bias']`` the choice bias ``moe_router_bias``."""
     f, n = int(moe['d_expert']), held(moe)[1]
     fs = f * int(moe.get('n_shared', 1))
-    return {'moe_router_w': (d_model, int(moe['n_routed'])),
-            'moe_fc1_w': (n, d_model, f), 'moe_fc3_w': (n, d_model, f),
-            'moe_fc2_w': (n, f, d_model),
-            'moe_shared_fc1_w': (d_model, fs),
-            'moe_shared_fc3_w': (d_model, fs),
-            'moe_shared_fc2_w': (fs, d_model)}
+    shapes = {'moe_router_w': (d_model, int(moe['n_routed'])),
+              'moe_fc1_w': (n, d_model, f), 'moe_fc3_w': (n, d_model, f),
+              'moe_fc2_w': (n, f, d_model),
+              'moe_shared_fc1_w': (d_model, fs),
+              'moe_shared_fc3_w': (d_model, fs),
+              'moe_shared_fc2_w': (fs, d_model)}
+    if moe.get('bias'):
+        shapes['moe_router_bias'] = (int(moe['n_routed']),)
+    return shapes
 
 
-def select(scores, moe):
+def select(scores, moe, bias=None):
     """scores [T, n_routed] float32 -> the picked experts [T, top_k]:
-    plain top-k over all of them."""
+    top-k over all of them, of the scores themselves or, with ``bias``
+    [n_routed], of ``scores + bias`` (the bias-corrected choice: the
+    bias moves the choice alone, never a weight)."""
     import jax
+    import jax.numpy as jnp
+    if bias is not None:
+        scores = scores + bias.astype(jnp.float32)
     return jax.lax.top_k(scores, int(moe['top_k']))[1]
 
 
-def route(h, router_w, moe):
+def route(h, router_w, moe, bias=None):
     """h [T, D] float32 normalised -> (picks [T, top_k] int32, their
     weights [T, top_k] float32).  Logits, scores and weights in float32
-    at full precision, as the source computes them."""
+    at full precision, as the source computes them; ``bias`` is
+    `select`'s."""
     import jax
     import jax.numpy as jnp
     logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     g = jax.nn.sigmoid(logits)
-    picks = select(g, moe)
+    picks = select(g, moe, bias)
     gp = jnp.take_along_axis(g, picks, axis=1)
     return picks.astype(jnp.int32), \
         gp / jnp.sum(gp, axis=1, keepdims=True) * float(moe['scale'])
@@ -197,7 +211,8 @@ def expert_layer(w, p, cfg, h, valid):
     import jax
     moe = cfg['moe']
     with jax.named_scope('moe.route'):
-        picks, wts = route(h, w[p + 'moe_router_w'], moe)
+        chosen_by = (w[p + 'moe_router_bias'],) if moe.get('bias') else ()
+        picks, wts = route(h, w[p + 'moe_router_w'], moe, *chosen_by)
     with jax.named_scope('moe.experts'):
         y, stats = routed(h, w[p + 'moe_fc1_w'], w[p + 'moe_fc3_w'],
                           w[p + 'moe_fc2_w'], picks, wts, valid, moe)

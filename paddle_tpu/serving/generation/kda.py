@@ -1,0 +1,530 @@
+"""Kimi Delta Attention (KDA) as the serving path runs it: a gated
+delta rule over a MATRIX state a head, with a decay a CHANNEL.
+
+A ``latent_moe`` model (decode.py) may name ``'kda'`` as a layer's mixer
+(``cfg['mixer']``).  For the layer's normalised input ``h`` ``[T, D]``
+and ``kda = cfg['kda']`` (``n_heads`` H, ``head_dim`` d for keys and
+values alike, ``d_conv`` taps, ``gate_rank``), no biases:
+
+    q^, k^, v = silu(conv(h W_q)), silu(conv(h W_k)), silu(conv(h W_v))
+    q, k      = l2norm(q^) * d^(-1/2), l2norm(k^)            a head
+    g         = -exp(A_log[head]) * softplus((h W_fa) W_fb + dt_bias + dt_shift)
+    beta      = sigmoid(h W_beta)                            [T, H]
+    S         = Diag(exp(g_t)) S;  u_t = beta_t (v_t - S^T k_t)
+    S         = S + k_t u_t^T;     o_t = S^T q_t             S [d_k, d_v]
+    y         = concat_heads(rmsnorm_head(o_t) * sigmoid((h W_ga) W_gb)) W_o
+
+``conv`` is a causal depthwise convolution over time, one filter a
+column, ``d_conv`` taps.  ``g <= 0`` is a log-decay a channel of the
+key dimension: what separates this layer from a gated delta rule with
+one gate a head (and from ssm.py, whose recurrence has a scalar decay a
+head and no delta correction).  ``dt_shift`` (``kda['dt_shift']``, 0
+unless the model dict says otherwise) is a constant beside ``dt_bias``:
+the mean of a bias whose values are drawn about zero; a checkpoint's own
+``dt_bias`` carries its mean and leaves it 0.  It is NOT the source's
+and goes when drawn weights can be given a mean where they are drawn
+(PERF.md, section 7).
+
+What a stream KEEPS a layer is recurrent state beside its pages
+(kv_cache.py): ``S`` ``[H, d, d]`` float32 and the last ``d_conv - 1``
+rows of the three convolutions' inputs (q, k and v side by side).
+Nothing grows with the context.  This module only maps (input, state)
+to (output, state).
+
+`prefill_mixer` advances one slot over one prefill chunk in the CHUNK
+FORM (`chunk_scan`).  Within a sub-chunk of ``_SUB`` tokens, with ``G``
+the running sum of ``g`` and ``S_0`` the state it starts from:
+
+    A_ij = beta_i sum_c k_ic k_jc exp(G_ic - G_jc)   i > j
+    W, U = (I + A)^-1 (beta K exp(G)),  (I + A)^-1 (beta V)
+    U'   = U - W S_0
+    P_ij = sum_c q_ic k_jc exp(G_ic - G_jc)          i >= j
+    O    = (Q exp(G)) S_0 + P U'
+    S_C  = Diag(exp(G_C)) S_0 + sum_j (k_j exp(G_C - G_j)) u'_j^T
+
+Everything but the last three lines is the same for every sub-chunk
+whatever state it meets, so it is computed for all of a chunk's
+sub-chunks at once; only those three are a scan over the sub-chunks.
+Only differences ``G_i - G_j`` with ``i >= j`` are exponentiated, so
+nothing overflows however negative ``g``: inside a block of ``_BLOCK``
+positions directly (a ``[block, block, d]`` tensor), between blocks as
+the product of two factors each at most 1, ``exp(G_i - r)`` and
+``exp(r - G_j)`` with ``r`` the running sum where i's block begins.
+``(I + A)^-1`` is forward substitution inside a block and the block
+formula ``[[X, 0], [-Z M X, Z]]`` between them: no power of ``A`` is
+formed.  A pad position (past ``true_count``) gets ``g = 0`` and
+``beta = 0``: it neither decays the state nor writes it, and the
+convolution's tail is cut at ``true_count``.
+
+`step_mixer` is the single step of a decode window over the WHOLE state
+array ``[slots, layers, ...]``: a live slot's state of that layer
+advances, a dead slot's stays bit for bit what it was.  The step has
+two routes, as ssm.py's has.  `kda_step` is a Pallas kernel that
+updates the state array IN PLACE: it walks the live slots only (their
+indices are scalar prefetch, `ssm._live_slots`), reads a live slot's
+``[H, d, d]`` of that layer once and writes it once, and takes ``r =
+S^T k`` and ``o = S^T q`` from the registers that hold the state.  The
+composed step is the same arithmetic in plain jax.numpy over every slot
+(``S^T (a k)`` and ``S^T (a q)`` in one pass, since ``o = (a S)^T q +
+(k . q) u``; then the update): the route under a mesh or for a state
+the kernel cannot tile (`kda_step_eligible`, a static rule on the
+state's shape, dtype and the mesh), and what the kernel is tested
+against.
+
+Everything of the recurrence is float32 and its products run at
+`highest` precision (a few per cent of a chunk's matrix work), so that
+the chunk form and the stepwise form agree to float32; the projections
+take their inputs in the weights' dtype and accumulate in float32.
+"""
+import math
+
+from ...ops import _pallas
+from .latent import dot as _dot, rms as _rms
+from .ssm import _live_slots
+
+__all__ = ['SLOTS', 'weight_shapes', 'state_shapes', 'conv_channels',
+           'prefill_mixer', 'step_mixer', 'chunk_scan', 'token_scan',
+           'state_bytes', 'kda_step', 'kda_step_eligible']
+
+# the mixer's weights of one layer, after `layer_<i>_`.  The head
+# norm's scale ends in `norm`: whoever draws weights makes such a name
+# ones (decode.random_weights, the benchmark's runner).
+SLOTS = ('kda_q_w', 'kda_k_w', 'kda_v_w', 'kda_q_conv', 'kda_k_conv',
+         'kda_v_conv', 'kda_fa_w', 'kda_fb_w', 'kda_A_log', 'kda_dt_bias',
+         'kda_beta_w', 'kda_ga_w', 'kda_gb_w', 'kda_o_norm', 'kda_o_w')
+
+_SUB = 64       # positions of one sub-chunk of the chunk form
+_BLOCK = 16     # positions whose decays are exponentiated pair by pair
+
+
+def _dims(kda):
+    return int(kda['n_heads']), int(kda['head_dim'])
+
+
+def conv_channels(kda):
+    """Channels the three convolutions run over: q, k and v side by
+    side."""
+    H, d = _dims(kda)
+    return 3 * H * d
+
+
+def weight_shapes(d_model, kda):
+    """{slot: shape} of one layer's mixer weights, public layout (a
+    projection is ``[in, out]``, a filter ``[taps, columns]``: tap k
+    multiplies the input ``d_conv - 1 - k`` positions back)."""
+    H, d = _dims(kda)
+    n, taps, r = H * d, int(kda['d_conv']), int(kda['gate_rank'])
+    return {'kda_q_w': (d_model, n), 'kda_k_w': (d_model, n),
+            'kda_v_w': (d_model, n), 'kda_q_conv': (taps, n),
+            'kda_k_conv': (taps, n), 'kda_v_conv': (taps, n),
+            'kda_fa_w': (d_model, r), 'kda_fb_w': (r, n),
+            'kda_A_log': (H,), 'kda_dt_bias': (n,),
+            'kda_beta_w': (d_model, H), 'kda_ga_w': (d_model, r),
+            'kda_gb_w': (r, n), 'kda_o_norm': (d,), 'kda_o_w': (n, d_model)}
+
+
+def state_shapes(kda):
+    """(matrix state, convolution tail) of ONE slot in ONE layer."""
+    H, d = _dims(kda)
+    return ((H, d, d), (int(kda['d_conv']) - 1, conv_channels(kda)))
+
+
+def state_bytes(kda):
+    """Bytes of the matrix state of one slot in one layer (float32): what
+    a step must read once and write once for a live stream."""
+    H, d = _dims(kda)
+    return 4 * H * d * d
+
+
+def _project(w, p, h):
+    """h [T, D] normalised -> the three convolutions' inputs side by
+    side [T, 3 H d], float32."""
+    import jax
+    import jax.numpy as jnp
+    with jax.named_scope('kda.proj'):
+        return jnp.concatenate([_dot(h, w[p + 'kda_q_w']),
+                                _dot(h, w[p + 'kda_k_w']),
+                                _dot(h, w[p + 'kda_v_w'])], axis=-1)
+
+
+def _taps(w, p):
+    """The three filters side by side [taps, 3 H d], float32."""
+    import jax.numpy as jnp
+    return jnp.concatenate([w[p + 'kda_q_conv'], w[p + 'kda_k_conv'],
+                            w[p + 'kda_v_conv']],
+                           axis=-1).astype(jnp.float32)
+
+
+def _gates(w, p, kda, h):
+    """h [T, D] normalised -> (g [T, H, d] log-decay, <= 0; beta [T, H];
+    the output gate [T, H, d]), float32."""
+    import jax
+    import jax.numpy as jnp
+    H, d = _dims(kda)
+    f32 = jnp.float32
+    with jax.named_scope('kda.gate'):
+        raw = _dot(_dot(h, w[p + 'kda_fa_w']), w[p + 'kda_fb_w']) \
+            + w[p + 'kda_dt_bias'].astype(f32) \
+            + float(kda.get('dt_shift', 0.0))
+        g = -jnp.exp(w[p + 'kda_A_log'].astype(f32))[:, None] \
+            * jax.nn.softplus(raw).reshape(raw.shape[:-1] + (H, d))
+        beta = jax.nn.sigmoid(_dot(h, w[p + 'kda_beta_w']))
+        gate = jax.nn.sigmoid(_dot(_dot(h, w[p + 'kda_ga_w']),
+                                   w[p + 'kda_gb_w']))
+        return g, beta, gate.reshape(gate.shape[:-1] + (H, d))
+
+
+def _heads(conv, kda):
+    """The convolutions' outputs [..., 3 H d] -> q, k, v [..., H, d]:
+    silu, then q and k to unit length a head and q by ``d^(-1/2)``."""
+    import jax
+    import jax.numpy as jnp
+    H, d = _dims(kda)
+    x = jax.nn.silu(conv).reshape(conv.shape[:-1] + (3, H, d))
+    q, k, v = x[..., 0, :, :], x[..., 1, :, :], x[..., 2, :, :]
+
+    def unit(a):
+        return a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True)
+                                 + 1e-6)
+
+    return unit(q) * d ** -0.5, unit(k), v
+
+
+def _out(w, p, kda, o, gate, eps):
+    """o, gate [T, H, d] float32 -> the mixer's output [T, D]: an RMS
+    norm a head, the gate, the output projection."""
+    import jax
+    with jax.named_scope('kda.out'):
+        normed = _rms(o, w[p + 'kda_o_norm'], eps)
+        return _dot((normed * gate).reshape(o.shape[0], -1),
+                    w[p + 'kda_o_w'])
+
+
+# ------------------------------------------------------ the recurrence
+
+def token_scan(q, k, v, g, beta, S0):
+    """The recurrence as it is defined, a token at a time: q, k, v, g
+    [T, H, d], beta [T, H], S0 [H, d, d] -> (o [T, H, d], the state
+    after position T - 1).  What `chunk_scan` is tested against."""
+    import jax
+    import jax.numpy as jnp
+    hi = jax.lax.Precision.HIGHEST
+
+    def body(S, x):
+        qt, kt, vt, gt, bt = x
+        S = jnp.exp(gt)[..., None] * S
+        u = bt[:, None] * (vt - jnp.einsum('hkv,hk->hv', S, kt,
+                                           precision=hi))
+        S = S + kt[..., None] * u[:, None, :]
+        return S, jnp.einsum('hkv,hk->hv', S, qt, precision=hi)
+
+    S, o = jax.lax.scan(body, S0, (q, k, v, g, beta))
+    return o, S
+
+
+def _inverse(A, block):
+    """(I + A)^-1 for A [..., c, c] strictly lower triangular, c a power
+    of two times ``block``: forward substitution inside the diagonal
+    blocks, then pairs of blocks merged by ``[[X, 0], [-Z M X, Z]]``
+    until one is left."""
+    import jax
+    import jax.numpy as jnp
+    hi = jax.lax.Precision.HIGHEST
+    c = A.shape[-1]
+    lead = A.shape[:-2]
+    n = c // block
+    diag = jnp.einsum('...nimj,nm->...nij',
+                      A.reshape(lead + (n, block, n, block)),
+                      jnp.eye(n, dtype=A.dtype))          # [..., n, b, b]
+    rows = [jnp.broadcast_to(jnp.eye(block, dtype=A.dtype)[0],
+                             diag.shape[:-2] + (block,))]
+    for i in range(1, block):
+        done = jnp.stack(rows, axis=-2)                   # [..., i, b]
+        rows.append(jnp.eye(block, dtype=A.dtype)[i] - jnp.einsum(
+            '...j,...jc->...c', diag[..., i, :i], done, precision=hi))
+    inv = jnp.stack(rows, axis=-2)                        # [..., n, b, b]
+    size = block
+    while size < c:
+        n = c // (2 * size)
+        pairs = inv.reshape(lead + (n, 2, size, size))
+        X, Z = pairs[..., 0, :, :], pairs[..., 1, :, :]
+        M = jnp.einsum(
+            '...nimj,nm->...nij',
+            A.reshape(lead + (n, 2, size, n, 2, size))[..., 1, :, :, 0, :],
+            jnp.eye(n, dtype=A.dtype))
+        low = -jnp.einsum('...ij,...jk,...kl->...il', Z, M, X, precision=hi)
+        top = jnp.concatenate([X, jnp.zeros_like(X)], axis=-1)
+        inv = jnp.concatenate(
+            [top, jnp.concatenate([low, Z], axis=-1)], axis=-2)
+        size *= 2
+    return inv.reshape(lead + (c, c))
+
+
+def _pair_decays(x, k, G, block):
+    """``sum_c x_ic k_jc exp(G_ic - G_jc)`` for i >= j, 0 above the
+    diagonal: x, k, G [..., c, d] -> [..., c, c].  Pairs inside a block
+    of ``block`` positions are exponentiated one by one; a pair of two
+    blocks is the product of ``exp(G_i - r)`` and ``exp(r - G_j)``, r the
+    running sum before i's block: each at most 1."""
+    import jax
+    import jax.numpy as jnp
+    hi = jax.lax.Precision.HIGHEST
+    c, d = x.shape[-2:]
+    lead = x.shape[:-2]
+    n = c // block
+
+    def blocks(a):
+        return a.reshape(lead + (n, block, d))
+
+    xb, kb, Gb = blocks(x), blocks(k), blocks(G)
+    lower = jnp.tril(jnp.ones((block, block), bool))
+    diff = Gb[..., :, None, :] - Gb[..., None, :, :]      # [.., n, i, j, d]
+    near = jnp.sum(xb[..., :, None, :] * kb[..., None, :, :] * jnp.exp(
+        jnp.where(lower[..., None], diff, -jnp.inf)), axis=-1)
+    eye = jnp.eye(n, dtype=x.dtype)
+    out = jnp.einsum('...nij,nm->...nimj', near, eye)     # [.., n, b, n, b]
+    if n > 1:
+        # r of block I: the running sum at the last position before it
+        r = jnp.concatenate([jnp.zeros_like(Gb[..., :1, 0, :]),
+                             Gb[..., :-1, -1, :]], axis=-2)   # [.., n, d]
+        left = xb * jnp.exp(Gb - r[..., None, :])             # <= 1
+        # [.., n (row block), c (column), d]: 0 from the row block on
+        before = (jnp.arange(c)[None, :] // block
+                  < jnp.arange(n)[:, None])[..., None]
+        right = jnp.where(before, jnp.exp(jnp.where(
+            before, r[..., :, None, :] - G[..., None, :, :], 0.0)), 0.0) \
+            * k[..., None, :, :]
+        far = jnp.einsum('...nid,...ncd->...nic', left, right, precision=hi)
+        out = out + far.reshape(lead + (n, block, n, block))
+    return out.reshape(lead + (c, c))
+
+
+def chunk_scan(q, k, v, g, beta, S0, sub=_SUB, block=_BLOCK):
+    """The recurrence over T positions in the chunk form, sub-chunks of
+    ``sub`` positions (T a multiple of it; ``sub`` a power of two times
+    ``block``): q, k, v, g [T, H, d], beta [T, H] (0, with g = 0, where
+    a position is padding), S0 [H, d, d]; returns (o [T, H, d], the
+    state after position T - 1).  `token_scan`'s result; the module's
+    docstring has the algebra."""
+    import jax
+    import jax.numpy as jnp
+    hi = jax.lax.Precision.HIGHEST
+    T, H, d = q.shape
+    n = T // sub
+
+    def heads_first(a):                                   # [n, H, sub, ..]
+        return jnp.moveaxis(a.reshape((n, sub) + a.shape[1:]), 2, 1)
+
+    q, k, v, g = (heads_first(a) for a in (q, k, v, g))
+    beta = heads_first(beta)[..., None]                   # [n, H, sub, 1]
+    G = jnp.cumsum(g, axis=2)
+    A = beta * jnp.tril(_pair_decays(k, k, G, block), -1)
+    P = _pair_decays(q, k, G, block)
+    inv = _inverse(A, block)
+    W = jnp.einsum('nhij,nhjd->nhid', inv, beta * k * jnp.exp(G),
+                   precision=hi)
+    U = jnp.einsum('nhij,nhjd->nhid', inv, beta * v, precision=hi)
+    last = G[:, :, -1:, :]
+    to_end = k * jnp.exp(last - G)
+    q_in = q * jnp.exp(G)
+
+    def body(S, x):
+        Wn, Un, Pn, Qn, Kn, end = x
+        Un = Un - jnp.einsum('hik,hkv->hiv', Wn, S, precision=hi)
+        o = jnp.einsum('hik,hkv->hiv', Qn, S, precision=hi) \
+            + jnp.einsum('hij,hjv->hiv', Pn, Un, precision=hi)
+        S = jnp.exp(end)[..., None] * S + jnp.einsum(
+            'hjk,hjv->hkv', Kn, Un, precision=hi)
+        return S, o
+
+    S, o = jax.lax.scan(body, S0, (W, U, P, q_in, to_end, last[:, :, 0]))
+    return jnp.moveaxis(o, 1, 2).reshape(T, H, d), S
+
+
+def _sizes(C):
+    """(sub-chunk, block) for a chunk of C positions: `_SUB` and `_BLOCK`
+    where they divide it, else the largest powers of two that do."""
+    sub = math.gcd(C, _SUB)
+    return sub, math.gcd(sub, _BLOCK)
+
+
+def prefill_mixer(w, p, cfg, h, S0, tail, true_count):
+    """One slot, one prefill chunk: h [C, D] normalised, S0 and tail the
+    slot's state of this layer (zeros where the prompt begins).  Returns
+    (out [C, D] float32, S, tail), the state as position ``true_count -
+    1`` leaves it; rows of ``out`` past it are padding's."""
+    import jax
+    import jax.numpy as jnp
+    kda = cfg['kda']
+    C = h.shape[0]
+    x = _project(w, p, h)
+    g, beta, gate = _gates(w, p, kda, h)
+    with jax.named_scope('kda.conv'):
+        taps = _taps(w, p)
+        full = jnp.concatenate([tail, x], axis=0)          # [K-1+C, ch]
+        conv = sum(full[j:j + C] * taps[j] for j in range(taps.shape[0]))
+        tail = jax.lax.dynamic_slice_in_dim(full, true_count,
+                                            taps.shape[0] - 1)
+    with jax.named_scope('kda.scan'):
+        q, k, v = _heads(conv, kda)
+        real = jnp.arange(C) < true_count
+        g = jnp.where(real[:, None, None], g, 0.0)
+        beta = jnp.where(real[:, None], beta, 0.0)
+        o, S = chunk_scan(q, k, v, g, beta, S0, *_sizes(C))
+    return _out(w, p, kda, o, gate, float(cfg.get('rms_eps', 1e-6))), S, tail
+
+
+# ------------------------------------------------ the step, in place
+
+# bytes of one slot's [H, d, d] state of one layer, the kernel's tile: it
+# holds four (in and out, double buffered)
+_STEP_TILE_BYTES = 2 << 20
+
+
+def kda_step_eligible(state_shape, dtype, mesh=None):
+    """Static rule for `kda_step` over a ``[slots, layers, H, d, d]``
+    state: float32 on a single device; on an accelerator a head's ``[d,
+    d]`` must be whole tiles and one slot's heads must fit the kernel's
+    buffers."""
+    import jax.numpy as jnp
+    if jnp.dtype(dtype) != jnp.float32 or not _pallas.single_device(mesh):
+        return False
+    if _pallas.interpret():
+        return True
+    _slots, _layers, H, d, _ = state_shape
+    return d % 128 == 0 and H * d * d * 4 <= _STEP_TILE_BYTES
+
+
+def _kda_step_kernel(order_ref, count_ref, layer_ref, cols_ref, rows_ref,
+                     s_ref, out_ref, o_ref):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    del order_ref, layer_ref              # the index maps read them
+    pos, count = pl.program_id(0), count_ref[0]
+
+    @pl.when(pos < count)
+    def _():
+        for j in range(s_ref.shape[0]):
+            # a head's decay, key and query down the sublanes [d, 1]
+            a, k, q = (cols_ref[c, :, j:j + 1] for c in range(3))
+            S = a * s_ref[j]
+            r = jnp.sum(k * S, axis=0, keepdims=True)        # [1, d]
+            u = rows_ref[0, j:j + 1, :] - rows_ref[1, j:j + 1, :] * r
+            S = S + k * u
+            out_ref[j] = S
+            o_ref[j:j + 1, :] = jnp.sum(q * S, axis=0, keepdims=True)
+
+    # no live slot: every grid position maps to ONE block, which goes
+    # back as it came
+    @pl.when((count == 0) & (pos == 0))
+    def _():
+        out_ref[...] = s_ref[...]
+
+
+def kda_step(a, k, q, v, beta, state, layer, active):
+    """One step of the delta rule for the LIVE slots, on the state array
+    in place.
+
+    a (the decay, ``exp(g)``), k, q, v [S, H, d], beta [S, H], float32;
+    state [S, layers, H, d, d] float32, WHOLE: it is aliased to the
+    second result, so under donation XLA neither slices nor copies it;
+    layer an int32 scalar; active [S] bool, the live slots.  Returns (o
+    [S, H, d], state): the composed step's arithmetic for the live
+    slots; every other slot's state, and every other layer's, is not
+    touched, and a slot that is not live gets zeros for o.
+
+    The grid walks the live slots' indices, compacted to the front
+    (scalar prefetch); a position past the live count repeats the last
+    live slot's index, so nothing is fetched or written for it.  The
+    vectors that scale ROWS of a head's state (a, k, q: one value a key
+    channel) come in transposed, ``[S, 3, d, H]``, so that a head's is a
+    column down the sublanes; v and beta come in as rows."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    S, _layers, H, d, _ = state.shape
+
+    def slot_of(pos, order_ref, count_ref):
+        return order_ref[jnp.clip(pos, 0, jnp.maximum(count_ref[0] - 1, 0))]
+
+    def vectors(pos, order_ref, count_ref, layer_ref):
+        return (slot_of(pos, order_ref, count_ref), 0, 0, 0)
+
+    def tile(pos, order_ref, count_ref, layer_ref):
+        return (slot_of(pos, order_ref, count_ref), layer_ref[0], 0, 0, 0)
+
+    def out_rows(pos, order_ref, count_ref, layer_ref):
+        return (slot_of(pos, order_ref, count_ref), 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[pl.BlockSpec((None, 3, d, H), vectors),
+                  pl.BlockSpec((None, 2, H, d), vectors),
+                  pl.BlockSpec((None, None, H, d, d), tile)],
+        out_specs=[pl.BlockSpec((None, None, H, d, d), tile),
+                   pl.BlockSpec((None, H, d), out_rows)],
+    )
+    order, count = _live_slots(active)
+    cols = jnp.stack([a, k, q], axis=1).transpose(0, 1, 3, 2)
+    rows = jnp.stack([beta[..., None] * v,
+                      jnp.broadcast_to(beta[..., None], v.shape)], axis=1)
+    state, o = pl.pallas_call(
+        _kda_step_kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((S, H, d), jnp.float32)],
+        # operand 5 (after the three prefetched scalars): the state
+        input_output_aliases={5: 0},
+        name='kda_step',
+        interpret=_pallas.interpret(),
+    )(order, count, jnp.asarray(layer, jnp.int32).reshape(1), cols, rows,
+      state)
+    # a dead slot's rows of o were never written
+    return jnp.where(active[:, None, None], o, 0.0), state
+
+
+def step_mixer(w, p, cfg, h, state, layer, tail, active, kernel):
+    """Every slot, one decode step of recurrent layer ``layer``: h
+    [slots, D] normalised, state [slots, layers, H, d, d] (the WHOLE
+    matrix state), tail [slots, K-1, ch] (this layer's), active [slots]
+    bool.  Returns (out [slots, D] float32, state, tail): the state of
+    the live slots advanced in this layer and nothing else of it
+    changed; the tail for ALL slots (the caller keeps an inactive slot's
+    old one).
+
+    ``kernel`` (`kda_step_eligible`, static) runs the recurrence in
+    place over the live slots (`kda_step`); otherwise every slot steps
+    and the dead ones' state is kept by a select."""
+    import jax
+    import jax.numpy as jnp
+    from ... import observability as _obs
+    kda = cfg['kda']
+    x = _project(w, p, h)
+    g, beta, gate = _gates(w, p, kda, h)
+    with jax.named_scope('kda.conv'):
+        full = jnp.concatenate([tail, x[:, None]], axis=1)    # [S, K, ch]
+        conv = jnp.sum(full * _taps(w, p), axis=1)
+        tail = full[:, 1:]
+    with jax.named_scope('kda.step'):
+        q, k, v = _heads(conv, kda)
+        a = jnp.exp(g)
+        if kernel:
+            _obs.metrics.counter('kda.step_kernel').inc()
+            o, state = kda_step(a, k, q, v, beta, state, layer, active)
+        else:
+            _obs.metrics.counter('kda.step_composed').inc()
+            S = state[:, layer]                            # [S, H, d, d]
+            # S^T (a k) and S^T (a q) in ONE pass over the state
+            both = jnp.sum(
+                S[:, :, None] * (a[:, :, None]
+                                 * jnp.stack([k, q], axis=2))[..., None],
+                axis=-2)                                   # [S, H, 2, d]
+            u = beta[..., None] * (v - both[:, :, 0])
+            o = both[:, :, 1] + jnp.sum(k * q, axis=-1, keepdims=True) * u
+            new = a[..., None] * S + k[..., None] * u[:, :, None, :]
+            state = state.at[:, layer].set(
+                jnp.where(active[:, None, None, None], new, S))
+    return _out(w, p, kda, o, gate, float(cfg.get('rms_eps', 1e-6))), \
+        state, tail

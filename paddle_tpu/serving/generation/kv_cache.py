@@ -42,6 +42,16 @@ state dict.  A slot IS its row there: nothing is allocated or freed, the
 chunk at offset 0 starts from zeros, and such rows cannot be shared, so
 a runtime over them runs without the prefix cache.
 
+The pool and the recurrent state need not cover the same layers.  A
+``latent_moe`` model with a mixer per layer (decode.py's
+``cfg['mixer']``, kda.py) ATTENDS in some layers and holds a matrix
+state in the others: ``CacheConfig.layers`` counts the layers that
+attend (the pool's layer axis, `page_bytes`), ``recurrent_layers`` the
+layers that hold state (the layer axis of ``ssm`` and ``conv``), and
+each layer knows its index on its own axis.  Left out,
+``recurrent_layers`` is ``layers``: the ``falcon_h1`` block, every
+layer of which does both.
+
 A model with LATENT attention (decode.py's ``latent_moe`` block,
 latent.py) keeps the second pool geometry, ``CacheConfig.latent``: ONE
 pool ``[pages, layers, page_len, width]`` whose row is everything a
@@ -84,7 +94,12 @@ class CacheConfig(object):
     reserved garbage page 0; ``page_len`` tokens per page (must divide
     ``max_len``); ``quant`` is ``'none'`` or ``'int8'``; ``recurrent``
     is None or the (scan state, convolution tail) shapes of one slot in
-    one layer (`ssm.state_shapes`), both float32.
+    one layer (`ssm.state_shapes`, `kda.state_shapes`), both float32.
+
+    ``layers`` is the pool's layer axis: the layers that ATTEND.
+    ``recurrent_layers`` is the recurrent state's: the layers that hold
+    state, ``layers`` unless given (a model with a mixer per layer has
+    fewer of each than it has layers).
 
     Two pool geometries.  Without ``latent``: a K and a V pool, each
     ``[pages, layers, page_len, kv_heads, head_dim]``.  With ``latent``
@@ -95,11 +110,11 @@ class CacheConfig(object):
     """
     __slots__ = ('slots', 'layers', 'kv_heads', 'max_len', 'head_dim',
                  'dtype', 'page_len', 'pages', 'quant', 'recurrent',
-                 'latent')
+                 'latent', 'recurrent_layers')
 
     def __init__(self, slots, layers, kv_heads, max_len, head_dim,
                  dtype='float32', page_len=None, pages=None, quant='none',
-                 recurrent=None, latent=None):
+                 recurrent=None, latent=None, recurrent_layers=None):
         if int(slots) < 1:
             raise ValueError('kv cache needs >= 1 slot, got %r' % (slots,))
         self.slots = int(slots)
@@ -127,6 +142,8 @@ class CacheConfig(object):
                              % (quant,))
         self.recurrent = None if recurrent is None else tuple(
             tuple(int(n) for n in shape) for shape in recurrent)
+        self.recurrent_layers = (self.layers if recurrent_layers is None
+                                 else int(recurrent_layers))
         self.latent = None if latent is None else int(latent)
         if self.latent is not None:
             if self.kv_heads != 1 or not 0 < self.latent <= self.head_dim:
@@ -171,8 +188,8 @@ class CacheConfig(object):
 
     def page_bytes(self):
         """Bytes ONE page costs across both pools (K+V, plus the scale
-        rows when quantized; the one pool of a latent cache) — the unit
-        of the kv_bytes gauges."""
+        rows when quantized; the one pool of a latent cache) over the
+        layers that attend — the unit of the kv_bytes gauges."""
         per = int(np.dtype(self.store_dtype).itemsize)
         elems = self.layers * self.kv_heads * self.page_len * self.head_dim
         if self.latent is not None:
@@ -184,10 +201,11 @@ class CacheConfig(object):
 
     def recurrent_shapes(self):
         """{state name: shape} of the recurrent arrays over every slot
-        and layer; empty for a model without recurrent layers."""
+        and every layer that holds state; empty for a model without
+        recurrent layers."""
         if self.recurrent is None:
             return {}
-        lead = (self.slots, self.layers)
+        lead = (self.slots, self.recurrent_layers)
         return {'ssm': lead + self.recurrent[0],
                 'conv': lead + self.recurrent[1]}
 
@@ -218,6 +236,8 @@ class CacheConfig(object):
                 'quant': self.quant}
         if self.recurrent is not None:
             spec['recurrent'] = self.recurrent
+            if self.recurrent_layers != self.layers:
+                spec['recurrent_layers'] = self.recurrent_layers
         if self.latent is not None:
             spec['latent'] = self.latent
         return spec

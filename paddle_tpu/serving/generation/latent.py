@@ -45,6 +45,17 @@ cached row's rope part lies in that order too); ``W_kvb`` split into
 its key half ``[H, nope, kv_rank]`` and its value half ``[H, kv_rank,
 v]``, the operands of the two absorptions.
 
+TWO STEPS ARE OPTIONAL, read off ``lat``.  ``q_rank: None`` is a model
+whose queries have no low-rank step: ``q = h W_q``, ONE weight
+``att_q_w`` ``[D, H (nope + rope)]`` in place of ``att_qa_w``,
+``att_qa_norm`` and ``att_qb_w`` (`slots`, `prepared`: it is prepared
+as ``W_qb`` is).  ``rotate: False`` is a model that gives its latent
+layers no positions: ``q_rope`` and ``k_r`` enter the score as they
+come out of their projections.  Without ``lat['yarn']`` the score scale
+is ``(nope + rope)^(-1/2)`` alone (the only form served without a
+rotation).  The cache row, the absorbed step and the expanded chunk
+are the same either way.
+
 The residual stream of this block is float32 and so is every norm and
 every softmax; a product takes its inputs in the weights' dtype and
 accumulates in float32.
@@ -56,7 +67,8 @@ import numpy as np
 from ...ops.attention import (latent_attention, latent_attention_composed,
                               latent_prefill, latent_prefill_eligible)
 
-__all__ = ['SLOTS', 'PREPARED', 'weight_shapes', 'row_width', 'stored_width',
+__all__ = ['SLOTS', 'PREPARED', 'slots', 'prepared', 'weight_shapes',
+           'row_width', 'stored_width',
            'yarn_inv_freq', 'score_scale', 'prepare', 'public', 'prefill',
            'prefill_kernel', 'prefill_rows', 'step', 'public_rows', 'rms',
            'dot']
@@ -76,17 +88,42 @@ PREPARED = {'att_qb_w': ('att_qb_nope', 'att_qb_rope'),
 _PREFILL_KEY_BLOCK = 1024   # cached positions one pass of a chunk expands
 
 
+def _low_rank_q(lat):
+    return lat.get('q_rank') is not None
+
+
+def slots(lat):
+    """`SLOTS` of a model with ``lat``: ``att_q_w`` alone for the queries
+    where ``q_rank`` is None."""
+    return SLOTS if _low_rank_q(lat) else ('att_q_w',) + SLOTS[3:]
+
+
+def prepared(lat):
+    """`PREPARED` of a model with ``lat``: where ``q_rank`` is None the
+    one query weight ``att_q_w`` takes ``att_qb_w``'s place (and its
+    prepared parts' names)."""
+    if _low_rank_q(lat):
+        return PREPARED
+    return {('att_q_w' if slot == 'att_qb_w' else slot): parts
+            for slot, parts in PREPARED.items()}
+
+
 def weight_shapes(d_model, n_head, lat):
     """{slot: shape} of one layer's attention weights, public layout
     (a projection is ``[in, out]``; a head's columns are nope then rope,
     nope then v)."""
-    qr, kr = int(lat['q_rank']), int(lat['kv_rank'])
+    kr = int(lat['kv_rank'])
     nope, rope, v = int(lat['nope']), int(lat['rope']), int(lat['v'])
-    return {'att_qa_w': (d_model, qr), 'att_qa_norm': (qr,),
-            'att_qb_w': (qr, n_head * (nope + rope)),
-            'att_kva_w': (d_model, kr + rope), 'att_kva_norm': (kr,),
-            'att_kvb_w': (kr, n_head * (nope + v)),
-            'att_o_w': (n_head * v, d_model)}
+    if _low_rank_q(lat):
+        qr = int(lat['q_rank'])
+        queries = {'att_qa_w': (d_model, qr), 'att_qa_norm': (qr,),
+                   'att_qb_w': (qr, n_head * (nope + rope))}
+    else:
+        queries = {'att_q_w': (d_model, n_head * (nope + rope))}
+    return dict(queries,
+                **{'att_kva_w': (d_model, kr + rope), 'att_kva_norm': (kr,),
+                   'att_kvb_w': (kr, n_head * (nope + v)),
+                   'att_o_w': (n_head * v, d_model)})
 
 
 def row_width(lat):
@@ -122,7 +159,10 @@ def yarn_inv_freq(lat, theta):
 def score_scale(lat):
     """``(nope + rope)^(-1/2)`` times YaRN's ``mscale(factor,
     mscale_all_dim)`` squared; cos and sin stay unscaled where ``mscale
-    == mscale_all_dim`` (the only case served)."""
+    == mscale_all_dim`` (the only case served).  Without ``lat['yarn']``
+    the first factor alone."""
+    if 'yarn' not in lat:
+        return (int(lat['nope']) + int(lat['rope'])) ** -0.5
     yarn = lat['yarn']
     if float(yarn['mscale']) != float(yarn['mscale_all_dim']):
         raise ValueError('latent attention serves mscale == '
@@ -161,7 +201,7 @@ def public(slot, parts, n_head, nope, rope, v):
     """`prepare` undone for ONE public slot from its prepared parts:
     bitwise the weight they were made from."""
     import jax.numpy as jnp
-    if slot == 'att_qb_w':
+    if slot in ('att_qb_w', 'att_q_w'):
         q_nope, q_rope = parts
         qr = q_nope.shape[0]
         return jnp.concatenate(
@@ -219,14 +259,25 @@ def _rotate(x, pos, inv_freq):
                            axis=-1)
 
 
+def _turned(x, pos, cfg):
+    """`_rotate` by the model's angles; x itself for a model whose
+    latent layers take no positions (``rotate: False``)."""
+    lat = cfg['latent']
+    if not lat.get('rotate', True):
+        return x
+    return _rotate(x, pos, yarn_inv_freq(lat, cfg['theta']))
+
+
 def _queries(w, p, cfg, h):
     """h [T, D] normalised -> q_nope [T, H, nope], q_rope [T, H, rope]
-    (before the rotation), float32."""
+    (before the rotation), float32.  Through the low-rank step where the
+    model has one (``q_rank``), else straight from ``h``."""
     import jax
     lat, H = cfg['latent'], int(cfg['n_head'])
     with jax.named_scope('attn.latent.q'):
-        c_q = rms(dot(h, w[p + 'att_qa_w']), w[p + 'att_qa_norm'],
-                   float(cfg.get('rms_eps', 1e-6)))
+        c_q = h if not _low_rank_q(lat) else rms(
+            dot(h, w[p + 'att_qa_w']), w[p + 'att_qa_norm'],
+            float(cfg.get('rms_eps', 1e-6)))
         T = h.shape[0]
         return (dot(c_q, w[p + 'att_qb_nope']).reshape(T, H, -1),
                 dot(c_q, w[p + 'att_qb_rope']).reshape(T, H,
@@ -245,8 +296,7 @@ def _row(w, p, cfg, h, pos, dtype):
         ckv_kr = dot(h, w[p + 'att_kva_wp'])
         c_kv = rms(ckv_kr[:, :kr], w[p + 'att_kva_norm'],
                     float(cfg.get('rms_eps', 1e-6)))
-        k_r = _rotate(ckv_kr[:, kr:], pos,
-                      yarn_inv_freq(lat, cfg['theta']))
+        k_r = _turned(ckv_kr[:, kr:], pos, cfg)
         pad = stored_width(lat) - row_width(lat)
         return jnp.concatenate(
             [c_kv, k_r, jnp.zeros((h.shape[0], pad), jnp.float32)],
@@ -359,8 +409,7 @@ def prefill(w, p, cfg, h, pos, n_keys, pool, layer, pg, rw, bt_row, kernel):
     pool = pool.at[pg, layer, rw].set(_row(w, p, cfg, h, pos, pool.dtype))
     with jax.named_scope('attn.latent.scores'):
         dt = pool.dtype
-        q_r = _rotate(q_rope.transpose(1, 0, 2), pos,
-                      yarn_inv_freq(lat, cfg['theta']))       # [H, C, rope]
+        q_r = _turned(q_rope.transpose(1, 0, 2), pos, cfg)    # [H, C, rope]
         q_nope = q_nope.transpose(1, 0, 2)                    # [H, C, nope]
         rows = pool[bt_row, layer].reshape(-1, pool.shape[-1])   # [Tk, W]
         BK = _key_block(rows.shape[0])
@@ -396,8 +445,7 @@ def step(w, p, cfg, h, pos, pool, layer, pg, rw, bt, n_attend, paged):
     q_nope, q_rope = _queries(w, p, cfg, h)
     pool = pool.at[pg, layer, rw].set(_row(w, p, cfg, h, pos, pool.dtype))
     with jax.named_scope('attn.latent.scores'):
-        q_r = _rotate(q_rope, pos[:, None],
-                      yarn_inv_freq(lat, cfg['theta']))       # [S, H, rope]
+        q_r = _turned(q_rope, pos[:, None], cfg)              # [S, H, rope]
         wk = w[p + 'att_kvb_k']
         q_lat = jnp.einsum('shn,hnc->shc', q_nope.astype(wk.dtype), wk,
                            preferred_element_type=jnp.float32)

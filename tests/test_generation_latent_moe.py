@@ -184,8 +184,8 @@ def test_the_reference_resolves_a_near_tie_at_the_compared_position_itself(
         assert rt.ensure_capacity(slot, prompt.size + WINDOW + 1)
         plain_select, calls = experts.select, []
 
-        def select(scores, moe):
-            picks = plain_select(scores, moe)
+        def select(scores, moe, bias=None):
+            picks = plain_select(scores, moe, bias)
             calls.append(None)
             if len(calls) - 1 == forced[0]:      # the compared token is row 0
                 picks = picks.at[0].set(jnp.asarray(forced[1], picks.dtype))

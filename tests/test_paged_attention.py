@@ -255,6 +255,42 @@ def test_mosaic_compiles_the_latent_kernel_at_real_widths(one_v5e_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
 
 
+def test_mosaic_compiles_the_delta_rule_step_at_real_widths(one_v5e_chip,
+                                                            monkeypatch):
+    """`kda.kda_step` at kimi_linear.many_streams_long_answers' extents:
+    128 slots of six layers' [32, 128, 128] float32 matrix state, 3.2 GB.
+    The state reaches the kernel as it lies and goes back aliased: nothing
+    of its size, nor of one layer's slice of it, is made."""
+    import jax
+    from paddle_tpu.ops import _pallas
+    from paddle_tpu.serving.generation import kda
+    monkeypatch.setattr(_pallas, 'interpret', lambda: False)
+    slots, layers, H, d = 128, 6, 32, 128
+    shape = (slots, layers, H, d, d)
+    assert kda.kda_step_eligible(shape, 'float32')
+    assert not kda.kda_step_eligible((slots, layers, H, 64, 64), 'float32')
+    assert not kda.kda_step_eligible((slots, layers, 64, d, d), 'float32')
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                    sharding=one_v5e_chip)
+
+    vec = sds((slots, H, d), 'float32')
+    compiled = jax.jit(
+        lambda a, k, q, v, beta, state, active: kda.kda_step(
+            a, k, q, v, beta, state, 3, active),
+        donate_argnums=(5,)).lower(
+            vec, vec, vec, vec, sds((slots, H), 'float32'),
+            sds(shape, 'float32'), sds((slots,), 'bool')).compile()
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 1
+    state = '[%d,%d,%d,%d,%d]' % shape
+    assert not [ln for ln in text.splitlines() if state in ln.split('(')[0]
+                and (' copy(' in ln or 'copy-start(' in ln
+                     or ' fusion(' in ln)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+
+
 @pytest.mark.parametrize('kernel', [True, False],
                          ids=['kernel', 'composed'])
 def test_a_latent_chunk_keeps_its_scores_on_chip_at_real_widths(
