@@ -14,8 +14,11 @@ the repo benchmarks, with seeded random weights:
   kernels            every Pallas kernel a launch can select (flash,
                      ssm_step, kda_step, latent_attention, latent_prefill),
                      compiled by Mosaic and compared with its reference;
-                     and the composed attention's loop over tiles of the
-                     batch, whose result buffers start uninitialised
+                     the composed attention's loop over tiles of the
+                     batch, whose result buffers start uninitialised; and
+                     the three routes of the routed experts' products on
+                     one routing (the grouped one through `experts.gmm`),
+                     with each route's time a layer
   serve              GenerationEngine over DecodeRuntime at the llama_1b
                      widths: four concurrent streams, twice, same tokens
   multichip          (>= 4 devices) transformer-base through
@@ -72,7 +75,14 @@ SIZES = {
                 heads=64, chunk=512, rows=7184, key_block=1024, kv_rank=512,
                 nope=128, rope=64, v=128, width=640,
                 cases=((0, 512), (4608, 512), (5000, 512), (6144, 37)),
-                dtype='bfloat16', tol=1e-4)),
+                dtype='bfloat16', tol=1e-4),
+            # the kimi_linear cell's expert layer in a decode step: 128
+            # slots, 60 live with 2 held picks each, over 25 / 40 / 64 of
+            # the 64 held experts
+            expert_route=dict(tokens=128, live=60, d_model=2304,
+                              d_expert=1024, n_routed=256, top_k=8, ranks=4,
+                              with_rows=(25, 40, 64), dtype='bfloat16',
+                              tol=2e-3, timed=20)),
         'serve': dict(config='llama_1b', n_layer=16, slots=8,
                       prompt_lens=(64, 192, 320, 512), max_new=32,
                       prefill_chunk=128, decode_window=8),
@@ -98,7 +108,11 @@ SIZES = {
                 heads=4, chunk=8, rows=60, key_block=16, kv_rank=16, nope=8,
                 rope=4, v=8, width=128,
                 cases=((0, 8), (24, 8), (40, 3)),
-                dtype='float32', tol=2e-5)),
+                dtype='float32', tol=2e-5),
+            expert_route=dict(tokens=24, live=10, d_model=32, d_expert=24,
+                              n_routed=32, top_k=4, ranks=4,
+                              with_rows=(3, 8), dtype='float32', tol=2e-5,
+                              timed=1)),
         'serve': dict(config='tiny', n_layer=2, slots=8,
                       prompt_lens=(4, 8, 12, 16), max_new=8,
                       prefill_chunk=4, decode_window=4),
@@ -651,6 +665,72 @@ def _latent_prefill_check(cfg):
     return out
 
 
+def _expert_route_check(cfg):
+    """The three routes of `experts.routed` on ONE routing each can take
+    (a decode step of ``tokens`` slots, ``live`` of them with two held
+    picks each over ``with_rows`` of the held experts): grouped and
+    unbatched against the batched route, and each route's time a layer
+    (median of ``timed`` calls, the weights handed in as arguments)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving.generation import experts
+    T, live, k = cfg['tokens'], cfg['live'], cfg['top_k']
+    moe = dict(n_routed=cfg['n_routed'], top_k=k, d_expert=cfg['d_expert'],
+               n_shared=1, scale=2.5, ranks=cfg['ranks'], rank=1)
+    first, G = experts.held(moe)
+    dt = jnp.dtype(cfg['dtype'])
+    ks = jax.random.split(jax.random.key(SEED), 4)
+    shapes = experts.weight_shapes(cfg['d_model'], moe)
+    w = [(0.03 * jax.random.normal(ks[i], shapes[n], jnp.float32)).astype(dt)
+         for i, n in enumerate(('moe_fc1_w', 'moe_fc3_w', 'moe_fc2_w'))]
+    h = jax.random.normal(ks[3], (T, cfg['d_model']), jnp.float32)
+    rng = np.random.RandomState(SEED)
+    wts = jnp.asarray(rng.rand(T, k), jnp.float32)
+    valid = np.zeros(T, bool)
+    valid[rng.permutation(T)[:live]] = True
+    # what is timed: (route, its grouped products through `experts.gmm`)
+    variants = {'grouped': ('grouped', True),
+                'grouped_ragged_dot': ('grouped', False),
+                'batched': ('batched', False),
+                'unbatched': ('unbatched', False)}
+    names = tuple(variants)
+    assert experts.gmm_eligible(w[0].shape), 'smoke shape is not eligible'
+
+    def one(route, kernel):
+        def fn(h, w1, w3, w2, picks, wts, valid):
+            return experts._routes(h, w1, w3, w2, picks, wts, valid, moe,
+                                   kernel)[1][route]()
+        return jax.jit(fn)
+
+    fns = {name: one(*variants[name]) for name in names}
+    out = {}
+    for n_exp in cfg['with_rows']:
+        # every pick elsewhere but two a live token, which cover n_exp
+        # of the held experts
+        picks = rng.randint(0, first, (T, k)).astype(np.int32)
+        some = first + rng.permutation(G)[:n_exp]
+        pairs = np.concatenate([some, rng.choice(some, 2 * live)])[:2 * live]
+        picks[valid, :2] = rng.permutation(pairs).reshape(live, 2)
+        args = (h, *w, jnp.asarray(picks), wts, jnp.asarray(valid))
+        got, ms = {}, {}
+        for name in names:
+            got[name] = np.asarray(fns[name](*args))
+            times = []
+            for _ in range(cfg['timed']):
+                t0 = time.perf_counter()
+                fns[name](*args).block_until_ready()
+                times.append(time.perf_counter() - t0)
+            ms[name] = round(1e3 * float(np.median(times)), 3)
+        np.testing.assert_array_equal(got['batched'][~valid], 0.0)
+        assert np.abs(got['batched'][valid]).max(axis=1).min() > 0, \
+            'a live token got nothing from its held experts'
+        out['with_rows_%d' % n_exp] = dict(
+            ms=ms, **{'err_' + name: float('%.2e' % _close(
+                'experts.routed, %s' % name, got[name], got['batched'],
+                cfg['tol'])) for name in names if name != 'batched'})
+    return out
+
+
 def kernels(cfg):
     from paddle_tpu.ops import attention as att
     t0 = time.perf_counter()
@@ -666,6 +746,7 @@ def kernels(cfg):
         'kda_step': _kda_step_check(cfg['kda_step']),
         'latent_attention': _latent_attention_check(cfg['latent']),
         'latent_prefill': _latent_prefill_check(cfg['latent_prefill']),
+        'expert_route': _expert_route_check(cfg['expert_route']),
     }
     _assert_no_fallbacks()
     out['wall_s'] = round(time.perf_counter() - t0, 1)

@@ -631,20 +631,23 @@ def _launch_stats(cfg):
     return _LAUNCH_STATS + (_KDA_STATS if _recurrent(cfg) else ())
 
 
-def _latent_moe_ffn(w, cfg, x, i, valid):
+def _latent_moe_ffn(w, cfg, x, i, valid, experts_kernel):
     """The feed-forward half of layer ``i`` of a ``latent_moe`` block: x
     [T, D] float32 -> (x + the layer's feed-forward, `experts.STATS`);
-    ``valid`` [T] marks the tokens that route (experts.py)."""
+    ``valid`` [T] marks the tokens that route, ``experts_kernel`` is
+    `DecodeRuntime.experts_kernel` (experts.py)."""
     p = 'layer_%d_' % i
     h = _latent.rms(x, w[p + 'ffn_norm'], _eps(cfg))
     if _ffn_kinds(cfg)[i] == 'dense':
         y, stats = _experts.dense_layer(w, p, h)
     else:
-        y, stats = _experts.expert_layer(w, p, cfg, h, valid)
+        y, stats = _experts.expert_layer(w, p, cfg, h, valid,
+                                         experts_kernel)
     return x + y, stats
 
 
-def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False):
+def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False,
+                experts_kernel=False):
     """Build the one-chunk (or one-shot ring) prefill function.
 
     Scatters the chunk's K/V rows into the pages ``bt_row`` maps at the
@@ -661,7 +664,8 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False):
     the chunk's `_LAUNCH_STATS`.  ``latent_kernel``
     (`DecodeRuntime.prefill_kernel`) keeps that attention's scores on
     chip (`ops.attention.latent_prefill`); otherwise its block loop is
-    composed of XLA operations.
+    composed of XLA operations.  ``experts_kernel``
+    (`DecodeRuntime.experts_kernel`) is `experts.routed`'s.
     """
     import jax.numpy as jnp
     L = int(cfg['n_layer'])
@@ -722,7 +726,7 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False):
                         st = dict(st, k=pool)
                     with scope('ffn'):
                         x, counted = _latent_moe_ffn(
-                            w, cfg, x + att, i, valid)
+                            w, cfg, x + att, i, valid, experts_kernel)
                     stats = stats + counted
                     continue
                 with scope('attn.qkv'):
@@ -793,7 +797,7 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False):
     return prefill
 
 
-def _step_fn(cfg, cache, paged, state_kernel):
+def _step_fn(cfg, cache, paged, state_kernel, experts_kernel=False):
     """One fused decode/verify step over ALL slots: write the fed token's
     K/V through the block table, attend, sample each slot's next token
     with the position-keyed stream, advance ACTIVE slots only.  Inactive
@@ -816,7 +820,8 @@ def _step_fn(cfg, cache, paged, state_kernel):
     layer advances the live slots' matrix state (`kda.step_mixer`: in
     place through `kda.kda_step` with ``state_kernel``, else every slot
     steps and a dead one's state is kept).  Its step returns a third
-    value, the step's `_launch_stats`."""
+    value, the step's `_launch_stats`; ``experts_kernel``
+    (`DecodeRuntime.experts_kernel`) is `experts.routed`'s."""
     import jax.numpy as jnp
     L = int(cfg['n_layer'])
     theta = float(cfg['theta'])
@@ -864,7 +869,7 @@ def _step_fn(cfg, cache, paged, state_kernel):
                         st = dict(st, k=pool)
                     with scope('ffn'):
                         x, counted = _latent_moe_ffn(
-                            w, cfg, x + att, i, active)
+                            w, cfg, x + att, i, active, experts_kernel)
                     stats = stats + counted
                     continue
                 with scope('attn.qkv'):
@@ -946,13 +951,13 @@ def _counted_window(step_body, st, xs, steps):
     return st, toks.T, stats.sum(axis=0)
 
 
-def _decode_fn(cfg, cache, steps, paged, state_kernel):
+def _decode_fn(cfg, cache, steps, paged, state_kernel, experts_kernel=False):
     """K-step fused decode window: each step feeds every slot's own
     carry token.  One `lax.scan`; the state dict is donated carry; the
     block table is closed-over DATA (an ordinary traced argument)."""
     import jax
 
-    step = _step_fn(cfg, cache, paged, state_kernel)
+    step = _step_fn(cfg, cache, paged, state_kernel, experts_kernel)
 
     if _latent_moe(cfg):
         def window(w, st, bt, active, seeds, temps, topks):
@@ -972,7 +977,7 @@ def _decode_fn(cfg, cache, steps, paged, state_kernel):
     return window
 
 
-def _verify_fn(cfg, cache, steps, paged, state_kernel):
+def _verify_fn(cfg, cache, steps, paged, state_kernel, experts_kernel=False):
     """K-step speculative VERIFY window: identical step body, but step j
     feeds ``fed[j]`` (host-built: last emitted token, then the draft's
     proposals) and the returned samples are the target model's verdicts
@@ -980,7 +985,7 @@ def _verify_fn(cfg, cache, steps, paged, state_kernel):
     an accepted prefix is bitwise the sequential stream."""
     import jax
 
-    step = _step_fn(cfg, cache, paged, state_kernel)
+    step = _step_fn(cfg, cache, paged, state_kernel, experts_kernel)
 
     if _latent_moe(cfg):
         def window(w, st, bt, fed, active, seeds, temps, topks):
@@ -1228,6 +1233,12 @@ class DecodeRuntime(object):
             # can run, else through HBM a block at a time
             self.prefill_kernel = self.latent_moe and _latent.prefill_kernel(
                 cfg, self.cache, self.prefill_chunk, mesh)
+            # and the grouped route of its routed experts: only the
+            # matrices of the experts with rows, by a kernel, else by
+            # `ragged_dot`
+            self.experts_kernel = self.latent_moe and 'moe' in cfg \
+                and _experts.gmm_eligible(_experts.weight_shapes(
+                    int(cfg['d_model']), cfg['moe'])['moe_fc1_w'], mesh)
             self._execs = {}
             # `latent_moe` launches' `_LAUNCH_STATS`, still on the device,
             # oldest first, and how many launches' were already moved into
@@ -1468,7 +1479,8 @@ class DecodeRuntime(object):
         def build():
             fn = _prefill_fn(self.cfg, self.cache, chunk,
                              ring_mesh=self.mesh if ring else None,
-                             latent_kernel=self.prefill_kernel)
+                             latent_kernel=self.prefill_kernel,
+                             experts_kernel=self.experts_kernel)
             jitted = jax.jit(fn, donate_argnums=(1,))
             i32 = self._sds((), jax.numpy.int32)
             f32 = self._sds((), jax.numpy.float32)
@@ -1491,7 +1503,7 @@ class DecodeRuntime(object):
         def build():
             make = _verify_fn if kind == 'verify' else _decode_fn
             fn = make(self.cfg, self.cache, steps, self.paged,
-                      self.state_kernel)
+                      self.state_kernel, self.experts_kernel)
             jitted = jax.jit(fn, donate_argnums=(1,))
             S = self.cache.slots
             vec = lambda dt: self._sds((S,), dt)  # noqa: E731

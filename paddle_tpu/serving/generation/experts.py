@@ -29,18 +29,32 @@ alone.
 
 `routed` does work proportional to the ASSIGNMENTS: the (token, pick)
 pairs that fall on held experts are sorted by expert and the tokens
-gathered in that order.  A decode step's handful of pairs runs through
-three `jax.lax.ragged_dot`s, which read only the experts that have rows
-(an expert no token picked is no group: its matrices are not read); a
-chunk's pairs, some twenty an expert, run as one batch entry an expert
-of three batched products (each group padded to 64 rows), every
-expert's matrices read once at full width.  No token is dropped whatever
-the routing: a chunk with a group that does not fit the batch takes
-`ragged_dot` too, and `ragged_dot` runs over ``T`` sorted rows where the
-held pairs fit them and over all ``T * top_k`` where they do not
-(`jax.lax.cond`, every route compiled): its time follows the rows it is
-handed, not the rows that are real (a decode step of 64 slots over 512
-rows instead of 64: 13.4 ms against 9.5, PERF.md, Findings of PR 47).
+gathered in that order.  Which way the sorted rows are multiplied is
+chosen ON THE DEVICE from the routing the call was handed, the held
+pairs' count and the largest group (`jax.lax.switch`, every route
+compiled), never from a model's name or a switch:
+
+* a call of at most ``_GROUP_ROWS`` tokens (a decode step of few
+  slots): three `jax.lax.ragged_dot`s, which read only the experts that
+  have rows (an expert no token picked is no group: its matrices are
+  not read), over ``T`` sorted rows where the held pairs fit them and
+  over all ``T * top_k`` where they do not: `ragged_dot`'s time follows
+  the rows it is handed, not the rows that are real (a decode step of
+  64 slots over 512 rows instead of 64: 13.4 ms against 9.5, PERF.md,
+  Findings of PR 47);
+* a larger call with FEW held pairs, at most ``_GROUPED_ROWS`` a held
+  expert (a decode step of many slots, a short last chunk): the grouped
+  route over that static bucket of sorted rows, which likewise reads the
+  experts with rows and no other, through `gmm` where that kernel can
+  run (`gmm_eligible`) and through `ragged_dot` where not, and gathers
+  and scatters the bucket's rows, not every expert's padded group;
+* a larger call with many pairs whose every group fits ``_GROUP_ROWS``
+  rows (a full chunk: some twenty pairs an expert, every expert
+  touched): one batch entry an expert of three batched products, every
+  expert's matrices read once at full width;
+* a group that does not fit the batch: `ragged_dot` as in a small call.
+
+No pair is dropped on any route.
 
 Every call returns, beside its output, `STATS` int32 counts that the
 launches sum and hand back beside their tokens (decode.py moves them
@@ -55,9 +69,10 @@ SLOTS = ('moe_router_w', 'moe_fc1_w', 'moe_fc3_w', 'moe_fc2_w',
          'moe_shared_fc1_w', 'moe_shared_fc3_w', 'moe_shared_fc2_w')
 # what one call counts: (token, pick) pairs computed here, tokens routed
 # at all, held experts with at least one token, the busiest held
-# expert's tokens
+# expert's tokens, calls that read only the experts with rows (every
+# route of `routed` but the batched one, which reads each held expert)
 STATS = ('moe_assignments', 'moe_tokens', 'moe_experts_touched',
-         'moe_busiest_expert_tokens')
+         'moe_busiest_expert_tokens', 'moe_touched_only_calls')
 
 
 def held(moe):
@@ -123,25 +138,137 @@ def swiglu(h, w1, w3, w2):
 
 
 # rows an expert's group is padded to on the batched route of `routed`
+# (taken by any call of more than this many tokens whose held pairs are
+# many and whose every group fits)
 _GROUP_ROWS = 64
+# the grouped route's bucket of sorted rows, for each held expert: a call
+# of more than `_GROUP_ROWS` tokens with at most this many held pairs an
+# expert held reads the experts that have rows and no other
+_GROUPED_ROWS = 4
 
 
-def routed(h, w1, w3, w2, picks, wts, valid, moe):
-    """The held experts' part of the layer for h [T, D]: picks / wts
-    [T, top_k] (`route`), valid [T] bool (False: the token routes
-    nowhere).  Returns (y [T, D] float32, stats [len(STATS)] int32).
+# `gmm`: rows a grid step multiplies (a whole number of bf16 sublane
+# tiles; a group of a few rows wastes the rest of one tile of the MXU's
+# rows, never a weight byte), and the VMEM asked of Mosaic: an expert's
+# whole matrix is one block (4.7 MB at [2304, 1024] bf16), double
+# buffered, beside the rows' and the result's tiles: more than the 16 MiB
+# a kernel gets unasked, of a v5e's 128
+_GMM_ROWS = 32
+_GMM_VMEM = 48 << 20
+# the largest weight block `gmm` takes whole: above it the result's
+# columns are tiled
+_GMM_BLOCK_BYTES = 6 << 20
 
-    The held (token, pick) pairs are sorted by expert.  A chunk of many
-    tokens whose every group fits ``_GROUP_ROWS`` rows (the usual case: a
-    group averages ``T * top_k / n_routed``) runs each expert's rows as
-    one batch entry of three batched products, every expert's matrices
-    read once at full width; a decode step, and a chunk with a larger
-    group, takes `jax.lax.ragged_dot`, which reads only the experts that
-    have rows: over the first ``T`` sorted rows where the held pairs fit
-    them (a decode step's do, unless its streams' held picks outnumber
-    the slots), over all ``T * top_k`` where they do not.
-    `jax.lax.cond` on the device, every route compiled; none drops a
-    pair."""
+
+def gmm_eligible(w_shape, mesh=None):
+    """Static rule for `gmm` over weights ``[G, K, N]``: one device
+    (`_pallas.single_device`); on an accelerator whole lane tiles both
+    ways (K and N multiples of 128).  Otherwise `jax.lax.ragged_dot`."""
+    from ...ops import _pallas
+    _G, K, N = w_shape
+    if not _pallas.single_device(mesh):
+        return False
+    return _pallas.interpret() or (K % 128 == 0 and N % 128 == 0)
+
+
+def _gmm_visits(sizes, rows, tm):
+    """The (group, row tile) pairs `gmm`'s grid walks, in order, for
+    ``rows`` sorted rows in tiles of ``tm``: every group that has rows
+    with every tile its rows lie in, and no other (a group without rows
+    is no grid step and no DMA).  Returns (offsets [G + 1], group [V],
+    tile [V], count): V = tiles + G - 1 bounds the pairs, ``count`` of
+    them are real (at least 1: with no row at all, the last group on
+    tile 0, which writes zeros)."""
+    import jax.numpy as jnp
+    G = sizes.shape[0]
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    tiles = jnp.where(sizes > 0, (ends - 1) // tm - starts // tm + 1, 0)
+    upto = jnp.cumsum(tiles)                       # visits up to group g's
+    v = jnp.arange(rows // tm + G - 1, dtype=jnp.int32)
+    group = jnp.minimum(jnp.searchsorted(upto, v, side='right'), G - 1) \
+        .astype(jnp.int32)
+    tile = starts[group] // tm + (v - (upto - tiles)[group])
+    tile = jnp.clip(tile, 0, rows // tm - 1).astype(jnp.int32)
+    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                               ends.astype(jnp.int32)])
+    return offsets, group, tile, jnp.maximum(upto[-1], 1).astype(jnp.int32)
+
+
+def gmm(x, w, sizes):
+    """``x[rows of group g] @ w[g]`` for sorted rows: x [R, K] in the
+    weights' dtype, w [G, K, N], sizes [G] int32 (group g owns the
+    ``sizes[g]`` rows behind group g - 1's; their sum at most R).
+    Returns [R, N] float32, zeros behind the last group: what
+    `jax.lax.ragged_dot` returns, by a Pallas kernel that reads the
+    matrices of the groups that HAVE rows and no other.
+
+    The grid is (column tiles, `_gmm_visits`): its second extent is a
+    traced value, and the weight block of a step is chosen by the
+    scalar-prefetched table of the groups with rows, so a group without
+    any is no step and no DMA.  A step multiplies one tile of
+    ``_GMM_ROWS`` rows by its group's block and stores the group's rows of
+    the product; a tile's first step zeroes the rest of it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ...ops import _pallas
+    R, K = x.shape
+    G, _, N = w.shape
+    tm = _GMM_ROWS
+    rows = -(-R // tm) * tm
+    tn = N
+    while K * tn * w.dtype.itemsize > _GMM_BLOCK_BYTES and tn % 256 == 0:
+        tn //= 2
+    offsets, group, tile, count = _gmm_visits(sizes, rows, tm)
+
+    def kernel(offsets_ref, group_ref, tile_ref, x_ref, w_ref, o_ref):
+        v = pl.program_id(1)
+        g, t = group_ref[v], tile_ref[v]
+        y = jnp.dot(x_ref[...], w_ref[...],
+                    preferred_element_type=jnp.float32)
+        row = t * tm + jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
+        mine = (row >= offsets_ref[g]) & (row < offsets_ref[g + 1])
+        first = (v == 0) | (tile_ref[jnp.maximum(v - 1, 0)] != t)
+
+        @pl.when(first)
+        def _():
+            o_ref[...] = jnp.where(mine, y, 0.0)
+
+        @pl.when(jnp.logical_not(first))
+        def _():
+            o_ref[...] = jnp.where(mine, y, o_ref[...])
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(N // tn, count),
+            in_specs=[
+                pl.BlockSpec((tm, K), lambda n, v, o, g, t: (t[v], 0)),
+                pl.BlockSpec((None, K, tn),
+                             lambda n, v, o, g, t: (g[v], 0, n))],
+            out_specs=pl.BlockSpec((tm, tn), lambda n, v, o, g, t: (t[v], n)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, N), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary'),
+            vmem_limit_bytes=_GMM_VMEM),
+        name='experts_gmm',
+        interpret=_pallas.interpret(),
+    )(offsets, group, tile, jnp.pad(x, ((0, rows - R), (0, 0))), w)
+    # a tile behind the last group's was never visited
+    return jnp.where((jnp.arange(R) < offsets[G])[:, None], out[:R], 0.0)
+
+
+def _routes(h, w1, w3, w2, picks, wts, valid, moe, kernel=False):
+    """The routing `routed` was handed, sorted, and every way it has of
+    computing the held pairs: ((n_held, sizes [G], busiest), {'grouped',
+    'batched', 'unbatched': () -> y [T, D] float32}).  Each computes
+    every held pair wherever its precondition holds (`routed` chooses;
+    chip_smoke.py times them one by one).  ``kernel`` (`gmm_eligible`,
+    static): the grouped route's products are `gmm`'s."""
     import jax
     import jax.numpy as jnp
     T, k = picks.shape
@@ -159,16 +286,17 @@ def routed(h, w1, w3, w2, picks, wts, valid, moe):
     def back(tok, y):
         return jnp.zeros((T, h.shape[1]), jnp.float32).at[tok].add(y)
 
-    def ragged(rows):
+    def ragged_dot(x, w):
+        return jax.lax.ragged_dot(x, w, sizes,
+                                  preferred_element_type=jnp.float32)
+
+    def ragged(rows, dot=ragged_dot):
+        # needs n_held <= rows
         sel = order[:rows]
         tok = sel // k
         x = hb[tok]                                            # [rows, D]
-        a = jax.lax.ragged_dot(x, w1, sizes,
-                               preferred_element_type=jnp.float32)
-        b = jax.lax.ragged_dot(x, w3, sizes,
-                               preferred_element_type=jnp.float32)
-        y = jax.lax.ragged_dot((jax.nn.silu(a) * b).astype(w2.dtype), w2,
-                               sizes, preferred_element_type=jnp.float32)
+        a, b = dot(x, w1), dot(x, w3)
+        y = dot((jax.nn.silu(a) * b).astype(w2.dtype), w2)
         # rows behind the last group are zero already; their weight is a
         # pick's that fell elsewhere
         return back(tok, y * jnp.where(jnp.arange(rows) < n_held,
@@ -179,7 +307,8 @@ def routed(h, w1, w3, w2, picks, wts, valid, moe):
                             lambda: ragged(T * k))
 
     def batched():
-        # entry (e, j): the j-th pair of expert e in the sorted order
+        # needs busiest <= _GROUP_ROWS; entry (e, j): the j-th pair of
+        # expert e in the sorted order
         j = jnp.arange(_GROUP_ROWS)[None]
         real = j < sizes[:, None]                              # [G, R]
         sel = order[jnp.where(real, (jnp.cumsum(sizes) - sizes)[:, None] + j,
@@ -195,19 +324,58 @@ def routed(h, w1, w3, w2, picks, wts, valid, moe):
         y = y * jnp.where(real, flat_w[sel], 0.0)[..., None]
         return back(tok.reshape(-1), y.reshape(-1, h.shape[1]))
 
+    return (n_held, sizes, busiest), {
+        'grouped': lambda: ragged(
+            min(_GROUPED_ROWS * G, T * k),
+            (lambda x, w: gmm(x, w, sizes)) if kernel else ragged_dot),
+        'batched': batched, 'unbatched': unbatched}
+
+
+def routed(h, w1, w3, w2, picks, wts, valid, moe, kernel=False):
+    """The held experts' part of the layer for h [T, D]: picks / wts
+    [T, top_k] (`route`), valid [T] bool (False: the token routes
+    nowhere).  Returns (y [T, D] float32, stats [len(STATS)] int32).
+
+    The held (token, pick) pairs are sorted by expert.  A call of at
+    most ``_GROUP_ROWS`` tokens (a decode step of few slots) takes
+    `jax.lax.ragged_dot`, which reads only the experts that have rows:
+    over the first ``T`` sorted rows where the held pairs fit them,
+    over all ``T * top_k`` where they do not (``unbatched``).  A larger
+    call is routed by the ROUTING it was handed, not by its size: few
+    held pairs, at most ``_GROUPED_ROWS`` a held expert (a decode step
+    of many slots, a short last chunk), run grouped over that static
+    bucket of sorted rows and read the experts with rows alone; many
+    pairs whose every group fits ``_GROUP_ROWS`` rows (a full chunk: a
+    group averages ``T * top_k / n_routed``) run each expert's rows as
+    one batch entry of three batched products, every expert's matrices
+    read once at full width; a larger group takes ``unbatched``.
+    `jax.lax.switch` on the device, every route compiled; none drops a
+    pair."""
+    import jax
+    import jax.numpy as jnp
+    T, k = picks.shape
+    (n_held, sizes, busiest), routes = _routes(h, w1, w3, w2, picks, wts,
+                                               valid, moe, kernel)
     if T > _GROUP_ROWS:
-        y = jax.lax.cond(busiest <= _GROUP_ROWS, batched, unbatched)
+        bucket = min(_GROUPED_ROWS * sizes.shape[0], T * k)
+        route = jnp.where(n_held <= bucket, 0,
+                          jnp.where(busiest <= _GROUP_ROWS, 1, 2))
+        y = jax.lax.switch(route, [routes['grouped'], routes['batched'],
+                                   routes['unbatched']])
+        touched_only = (route != 1).astype(jnp.int32)
     else:
-        y = unbatched()
+        y = routes['unbatched']()
+        touched_only = jnp.ones((), jnp.int32)
     stats = jnp.stack([n_held, jnp.sum(valid, dtype=jnp.int32),
-                       jnp.sum(sizes > 0, dtype=jnp.int32), busiest])
+                       jnp.sum(sizes > 0, dtype=jnp.int32), busiest,
+                       touched_only])
     return y, stats
 
 
-def expert_layer(w, p, cfg, h, valid):
+def expert_layer(w, p, cfg, h, valid, kernel=False):
     """h [T, D] float32 normalised -> (the layer's output [T, D]
     float32, stats): the held routed experts' part and the shared
-    expert's."""
+    expert's.  ``kernel`` is `routed`'s."""
     import jax
     moe = cfg['moe']
     with jax.named_scope('moe.route'):
@@ -215,7 +383,8 @@ def expert_layer(w, p, cfg, h, valid):
         picks, wts = route(h, w[p + 'moe_router_w'], moe, *chosen_by)
     with jax.named_scope('moe.experts'):
         y, stats = routed(h, w[p + 'moe_fc1_w'], w[p + 'moe_fc3_w'],
-                          w[p + 'moe_fc2_w'], picks, wts, valid, moe)
+                          w[p + 'moe_fc2_w'], picks, wts, valid, moe,
+                          kernel)
     with jax.named_scope('moe.shared'):
         y = y + swiglu(h, w[p + 'moe_shared_fc1_w'],
                        w[p + 'moe_shared_fc3_w'], w[p + 'moe_shared_fc2_w'])

@@ -62,9 +62,13 @@ def test_phase_kernels(monkeypatch):
     monkeypatch.setattr(attention, '_DKV_RESIDENT_MAX_T',
                         flash['seq_resident'])
     out = chip_smoke.kernels(TINY['kernels'])
-    assert sorted(out) == ['flash_resident', 'flash_streamed', 'kda_step',
-                           'latent_attention', 'latent_prefill', 'ssm_step',
-                           'tile_loop', 'wall_s']
+    assert sorted(out) == ['expert_route', 'flash_resident',
+                           'flash_streamed', 'kda_step', 'latent_attention',
+                           'latent_prefill', 'ssm_step', 'tile_loop',
+                           'wall_s']
+    assert sorted(out['expert_route']) == ['with_rows_3', 'with_rows_8']
+    assert sorted(out['expert_route']['with_rows_8']['ms']) == [
+        'batched', 'grouped', 'grouped_ragged_dot', 'unbatched']
     assert sorted(out['kda_step']) == ['o_err_live_1', 'o_err_live_2']
     assert sorted(out['tile_loop']) == [
         '%s_%d' % (n, run) for n in ('dk', 'dq', 'dv', 'out')

@@ -491,24 +491,42 @@ def _plain_routed(h, w, picks, wts, valid, moe):
     return want
 
 
+# which route of `experts.routed` each case below takes at 90 tokens, four
+# held experts (a bucket of 16 sorted rows): at 19 tokens every one takes
+# `unbatched`, whatever the routing
+_ROUTE_AT_90 = {'one_expert': 'unbatched', 'all_held': 'unbatched',
+                'none_held': 'grouped', 'as_routed': 'batched',
+                'few_held': 'grouped', 'bucket_full': 'grouped',
+                'bucket_and_one': 'batched'}
+_HELD_PAIRS = {'few_held': 11, 'bucket_full': 16, 'bucket_and_one': 17}
+
+
 @pytest.mark.parametrize('T', [19, 90], ids=['step', 'chunk'])
-@pytest.mark.parametrize('uneven', ['one_expert', 'all_held', 'none_held',
-                                    'as_routed'])
+@pytest.mark.parametrize('uneven', sorted(_ROUTE_AT_90))
 def test_no_token_is_dropped_however_uneven_the_routing(uneven, T):
     """Every token to ONE held expert (at 90 tokens its group passes the
     batched route's 64 rows: `ragged_dot` over T rows); every pick of
-    every token held (T * top_k rows); nothing held at all; and the
-    router's own picks (at 90 tokens the batched route: every group fits
-    its 64 rows)."""
+    every token held (T * top_k rows); nothing held at all (at 90 tokens
+    the grouped route over its bucket of 16 rows, every one of them
+    padding); the router's own picks (at 90 tokens the batched route:
+    many pairs, every group within its 64 rows); and 11, 16 and 17 held
+    pairs in all: under the bucket, exactly it, and one over, which at 90
+    tokens is the batched route's.  `moe_touched_only_calls` says which
+    read the experts with rows alone."""
     moe = {'n_routed': 16, 'top_k': 4, 'd_expert': 24, 'n_shared': 1,
            'scale': 2.5, 'ranks': 4, 'rank': 1}
+    assert experts._GROUPED_ROWS * experts.held(moe)[1] == 16
     w = _layer_weights(moe, seed=2)
     h = jnp.asarray(np.random.RandomState(3).randn(T, 32), jnp.float32)
     wts = jnp.asarray(np.random.RandomState(4).rand(T, 4), jnp.float32)
     if uneven == 'as_routed':
         picks, wts = experts.route(h, w['moe_router_w'], moe)
         picks = np.asarray(picks)
-    else:                                              # experts 4..7 are held
+    elif uneven in _HELD_PAIRS:                        # experts 4..7 are held
+        picks = np.tile(np.asarray([0, 1, 2, 3], np.int32), (T, 1))
+        n = _HELD_PAIRS[uneven]
+        picks[:n, 1] = 4 + np.arange(n) % 3            # one of them untouched
+    else:
         picks = np.tile(np.asarray(
             {'one_expert': [5, 0, 1, 2], 'all_held': [4, 5, 6, 7],
              'none_held': [0, 1, 2, 3]}[uneven], np.int32), (T, 1))
@@ -522,10 +540,79 @@ def test_no_token_is_dropped_however_uneven_the_routing(uneven, T):
         rtol=2e-5, atol=2e-5)
     in_held = (picks[:T - 2] >= 4) & (picks[:T - 2] < 8)
     sizes = [int((picks[:T - 2] == e).sum()) for e in range(4, 8)]
+    route = _ROUTE_AT_90[uneven] if T > experts._GROUP_ROWS else 'unbatched'
     assert [int(s) for s in stats] == [
-        int(in_held.sum()), T - 2, sum(n > 0 for n in sizes), max(sizes)]
-    if uneven == 'as_routed' and T == 90:
-        assert 0 < max(sizes) <= experts._GROUP_ROWS < T
+        int(in_held.sum()), T - 2, sum(n > 0 for n in sizes), max(sizes),
+        route != 'batched']
+    if uneven in _HELD_PAIRS:
+        assert int(stats[0]) == _HELD_PAIRS[uneven]
+    if route == 'batched':
+        assert 16 < int(stats[0]) and max(sizes) <= experts._GROUP_ROWS < T
+    if route == 'grouped':
+        assert int(stats[0]) <= 16
+
+
+def test_a_step_of_many_slots_reads_the_touched_experts_alone(weights, rt):
+    """More than `_GROUP_ROWS` slots, three of them live: every expert
+    layer of every step of a window takes a route that reads the experts
+    with rows alone (a dozen held pairs: the grouped route), and each
+    stream is the one it is alone in the three-slot runtime, whose steps
+    take `unbatched`."""
+    prompts = [(_prompt(9, 7), 7), (_prompt(21, 8), 5), (_prompt(4, 9), 9)]
+    alone = []
+    for prompt, new in prompts:
+        alone.append(rt.generate(prompt, new, steps_per_window=WINDOW))
+        rt.reset()
+    slots = experts._GROUP_ROWS + 2
+    many = DecodeRuntime(weights, CFG, slots=slots, prefill_chunk=CHUNK,
+                         page_len=PAGE)
+    before = dict(obs.counters())
+    gen = GenerationEngine(many, gen_config=GenerationConfig(
+        decode_window=WINDOW)).start()
+    try:
+        streams = [gen.generate(prompt, max_new=new)
+                   for prompt, new in prompts]
+        got = [s.result(120) for s in streams]
+    finally:
+        gen.stop()
+    assert all(r.ok for r in got)
+    assert [list(s.tokens_so_far()) for s in streams] == alone
+    c = {k: v - before.get(k, 0) for k, v in obs.counters().items()}
+    steps = c['generation.decode_slot_steps'] // slots
+    assert steps >= 8 and c['generation.decode_slot_steps'] == steps * slots
+    assert c['generation.window_moe_touched_only_calls'] == 2 * steps
+    assert 0 < c['generation.window_moe_assignments'] \
+        <= experts._GROUPED_ROWS * 4 * 2 * steps
+
+
+@pytest.mark.parametrize('rows,sizes', [
+    (64, [0, 3, 0, 40, 1, 0, 5, 0]),      # empty groups between full tiles
+    (64, [0, 0, 0, 0, 0, 0, 0, 0]),       # no row at all: zeros
+    (64, [0, 0, 64, 0, 0, 0, 0, 0]),      # one group with every row
+    (70, [9, 0, 0, 33, 0, 0, 22, 6]),     # rows no multiple of the tile
+    (24, [1, 1, 1, 1, 1, 1, 1, 1]),       # under one tile, padded rows behind
+], ids=['empty_between', 'no_row', 'one_group', 'ragged_tail', 'one_each'])
+def test_the_grouped_product_is_ragged_dot_over_the_groups_with_rows(
+        rows, sizes):
+    """`experts.gmm` in interpret mode against `jax.lax.ragged_dot`, and
+    the grid it walks: a group without rows is no step."""
+    rng = np.random.RandomState(rows)
+    x = jnp.asarray(rng.randn(rows, 40), jnp.float32)
+    w = jnp.asarray(rng.randn(len(sizes), 40, 48), jnp.float32)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    got = jax.jit(experts.gmm)(x, w, sizes)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(jax.lax.ragged_dot(x, w, sizes)),
+        rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got)[int(sizes.sum()):], 0.0)
+    tm = experts._GMM_ROWS
+    padded = -(-rows // tm) * tm
+    offsets, group, tile, count = (np.asarray(a) for a in
+                                   experts._gmm_visits(sizes, padded, tm))
+    want = [(g, t) for g, n in enumerate(np.asarray(sizes)) if n
+            for t in range(offsets[g] // tm, (offsets[g + 1] - 1) // tm + 1)]
+    assert list(zip(group[:count], tile[:count])) == (want or [(7, 0)])
+    assert len(group) == padded // tm + len(sizes) - 1 >= count
 
 
 # ------------------------------------------------------------ the kernel

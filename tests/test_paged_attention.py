@@ -291,6 +291,45 @@ def test_mosaic_compiles_the_delta_rule_step_at_real_widths(one_v5e_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
 
 
+def test_mosaic_compiles_the_grouped_expert_products_at_real_widths(
+        one_v5e_chip, monkeypatch):
+    """`experts.routed` at kimi_linear.many_streams_long_answers' extents,
+    a decode step of 128 slots over 64 held experts of [2304, 1024]: the
+    grouped route's three products are `experts.gmm` (the label holds
+    ``gmm``: what `kimi_linear.moe_share` counts), each over the bucket of
+    256 sorted rows, and no route makes a copy of the 302 MB a weight
+    is."""
+    import jax
+    from paddle_tpu.ops import _pallas
+    from paddle_tpu.serving.generation import experts
+    monkeypatch.setattr(_pallas, 'interpret', lambda: False)
+    moe = dict(n_routed=256, top_k=8, d_expert=1024, n_shared=1,
+               scale=2.446, ranks=4, rank=1)
+    T, D, F, G = 128, 2304, 1024, 64
+    assert experts.gmm_eligible((G, D, F))
+    assert not experts.gmm_eligible((G, D, 1000))
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                    sharding=one_v5e_chip)
+
+    compiled = jax.jit(
+        lambda h, w1, w3, w2, picks, wts, valid: experts.routed(
+            h, w1, w3, w2, picks, wts, valid, moe, True)).lower(
+                sds((T, D), 'float32'), sds((G, D, F), 'bfloat16'),
+                sds((G, D, F), 'bfloat16'), sds((G, F, D), 'bfloat16'),
+                sds((T, 8), 'int32'), sds((T, 8), 'float32'),
+                sds((T,), 'bool')).compile()
+    text = compiled.as_text()
+    calls = [ln.split(' = ')[1].split('{')[0] for ln in text.splitlines()
+             if ' custom-call(' in ln and 'experts_gmm' in ln.split('(')[0]]
+    assert sorted(calls) == ['f32[256,1024]', 'f32[256,1024]',
+                             'f32[256,2304]']
+    assert not [ln for ln in text.splitlines()
+                if re.search(r'= bf16\[64,(2304,1024|1024,2304)\]\S* '
+                             r'(copy|copy-start)\(', ln)]
+
+
 @pytest.mark.parametrize('kernel', [True, False],
                          ids=['kernel', 'composed'])
 def test_a_latent_chunk_keeps_its_scores_on_chip_at_real_widths(
