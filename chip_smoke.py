@@ -82,7 +82,14 @@ SIZES = {
             expert_route=dict(tokens=128, live=60, d_model=2304,
                               d_expert=1024, n_routed=256, top_k=8, ranks=4,
                               with_rows=(25, 40, 64), dtype='bfloat16',
-                              tol=2e-3, timed=20)),
+                              tol=2e-3, timed=20),
+            # the lfm2_8b_a1b cell's K/V pool: 32 query / 8 kv heads of 64,
+            # two kv heads to a 128-lane row
+            paged_narrow=dict(slots=8, heads=32, kv_heads=8, head_dim=64,
+                              page_len=16, pages=1793, layers=4,
+                              max_pages=224,
+                              lengths=(0, 1, 16, 300, 2049, 3000, 3584, 777),
+                              dtype='bfloat16', tol=2e-2)),
         'serve': dict(config='llama_1b', n_layer=16, slots=8,
                       prompt_lens=(64, 192, 320, 512), max_new=32,
                       prefill_chunk=128, decode_window=8),
@@ -112,7 +119,11 @@ SIZES = {
             expert_route=dict(tokens=24, live=10, d_model=32, d_expert=24,
                               n_routed=32, top_k=4, ranks=4,
                               with_rows=(3, 8), dtype='float32', tol=2e-5,
-                              timed=1)),
+                              timed=1),
+            paged_narrow=dict(slots=4, heads=8, kv_heads=4, head_dim=32,
+                              page_len=4, pages=41, layers=2, max_pages=6,
+                              lengths=(0, 1, 13, 24), dtype='float32',
+                              tol=2e-5)),
         'serve': dict(config='tiny', n_layer=2, slots=8,
                       prompt_lens=(4, 8, 12, 16), max_new=8,
                       prefill_chunk=4, decode_window=4),
@@ -613,6 +624,55 @@ def _latent_attention_check(cfg):
         'latent_attention', got[live], want[live], cfg['tol']))}
 
 
+def _paged_narrow_check(cfg):
+    """`paged_attention` over a pool of a head NARROWER than the 128 lanes
+    (64: two kv heads side by side in a row, `paged_pool_heads`) against
+    `cached_attention` on gathered rows with the kv heads apart: a dead
+    slot (it reads nothing and gets zeros), one position, lengths that end
+    on and inside a page and a block, a full slot."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention as att
+    S, H, Hkv, D = (cfg[k] for k in ('slots', 'heads', 'kv_heads',
+                                     'head_dim'))
+    PL, M, dt = cfg['page_len'], cfg['max_pages'], jnp.dtype(cfg['dtype'])
+    hp, wide = att.paged_pool_heads(Hkv, D)
+    assert wide == 128 and hp * wide == Hkv * D, 'smoke shape does not pack'
+    shape = (cfg['pages'], cfg['layers'], PL, hp, wide)
+    assert att.paged_attention_eligible(shape, dt), \
+        'smoke shape is not eligible'
+    rng = np.random.RandomState(SEED)
+    kpool, vpool = (jax.random.normal(jax.random.key(SEED + i), shape,
+                                      jnp.float32).astype(dt)
+                    for i in range(2))
+    bt = np.stack([rng.permutation(np.arange(1, cfg['pages']))[:M]
+                   for _ in range(S)]).astype(np.int32)
+    n = jnp.asarray(cfg['lengths'], jnp.int32)
+    q = jnp.asarray(rng.randn(S, H, D), jnp.float32).astype(dt)
+    layer = cfg['layers'] - 1
+
+    def kernel(kpool, vpool, bt, n):
+        return att.paged_attention(q, kpool, vpool, bt, n, layer)
+
+    compiled, n_calls = _mosaic_calls(kernel, kpool, vpool, jnp.asarray(bt),
+                                      n)
+    _assert_mosaic('paged_attention', n_calls, 1)
+    got = np.asarray(compiled(kpool, vpool, jnp.asarray(bt), n), np.float32)
+
+    def apart(pool):
+        rows = pool[jnp.asarray(bt), layer]          # [S, M, PL, hp, wide]
+        return rows.reshape(S, M * PL, Hkv, D).transpose(0, 2, 1, 3)
+
+    want = np.asarray(att.cached_attention(
+        q[:, :, None], apart(kpool), apart(vpool),
+        (n - 1)[:, None])[:, :, 0], np.float32)
+    live = np.asarray(n) > 0
+    np.testing.assert_array_equal(got[~live], 0.0)
+    return {'slots': int(live.sum()), 'err': float('%.2e' % _close(
+        'paged_attention at a narrow head', got[live], want[live],
+        cfg['tol']))}
+
+
 def _latent_prefill_check(cfg):
     """`latent_prefill` (a `latent_moe` chunk's attention, its scores on
     chip) against the composed block loop it replaces
@@ -747,6 +807,7 @@ def kernels(cfg):
         'latent_attention': _latent_attention_check(cfg['latent']),
         'latent_prefill': _latent_prefill_check(cfg['latent_prefill']),
         'expert_route': _expert_route_check(cfg['expert_route']),
+        'paged_narrow': _paged_narrow_check(cfg['paged_narrow']),
     }
     _assert_no_fallbacks()
     out['wall_s'] = round(time.perf_counter() - t0, 1)

@@ -837,12 +837,30 @@ def cached_attention(q, kcache, vcache, qpos, scale=None):
 _PAGED_BLOCK_TOKENS = 128   # key positions one double-buffered block holds
 
 
+def paged_pool_heads(kv_heads, head_dim):
+    """(kv heads, head width) of the POOL that holds ``kv_heads`` heads of
+    ``head_dim``: themselves for a head of whole lane tiles.  A narrower
+    head that divides 128 (64: two, 32: four) lies as many kv heads side
+    by side as fill a 128-lane row, where the kv heads come in such
+    groups: ``(kv_heads / pack, pack * head_dim)``, the same bytes in the
+    same order, so that a page of one layer is whole tiles
+    (`paged_attention_eligible`) and no lane of a row is padding.  Any
+    other head (96, say) has no such layout and keeps its own."""
+    pack = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+    if kv_heads % pack:
+        pack = 1
+    return kv_heads // pack, head_dim * pack
+
+
 def paged_attention_eligible(pool_shape, dtype, mesh=None):
     """Static rule for `paged_attention` over a ``[pages, layers, page_len,
     kv_heads, head_dim]`` pool: a floating pool (an int8 pool dequantizes
     in the composed gather) on a single device; on an accelerator one
     page of one layer, ``[page_len * kv_heads, head_dim]``, must be whole
-    tiles of the pool's dtype where it lies."""
+    tiles of the pool's dtype where it lies.  A head of 64 meets the rule
+    as the pool `paged_pool_heads` lays out, two kv heads side by side
+    in a 128-lane row (``[.., kv_heads / 2, 128]``); a head with no such
+    layout (96) answers False and the step takes the composed path."""
     dtype = jnp.dtype(dtype)
     if not jnp.issubdtype(dtype, jnp.floating) \
             or not _pallas.single_device(mesh):
@@ -975,14 +993,38 @@ def paged_attention(q, kpool, vpool, block_tables, n_attend, layer,
     position kpos is visible iff ``kpos < n_attend`` (`cached_attention`'s
     ``kpos <= qpos``); probabilities are cast to the pool's dtype for the
     value product, as there.  No dense ``[S, Hkv, max_len, D]`` exists.
+
+    A pool whose rows are WIDER than the queries' head (`paged_pool_heads`:
+    a head of 64 lies two kv heads to a 128-lane row, ``[.., Hkv / pack,
+    pack * D]``) runs the SAME kernel over the same bytes: each query sits
+    in the lanes of its own kv head beside zeros (so a row's score is that
+    head's alone, and the rows of other kv-head groups are masked as other
+    kv heads' always were), and of the ``pack * D`` lanes of a query
+    head's result its own kv head's are kept.  The kernel reads the pages
+    a live slot's length covers and nothing for a dead one, exactly as at
+    128.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    pack = kpool.shape[-1] // q.shape[-1]
+    if pack > 1:
+        S, H, D = q.shape
+        # query head h reads kv head h // g of Hkv = pack * (the pool's):
+        # lanes [lane * D, lane * D + D) of row group h // g // pack
+        lane = np.arange(H) // (H // (kpool.shape[3] * pack)) % pack
+        mine = jnp.asarray(lane[:, None] == np.arange(pack)[None])  # [H, pack]
+        wide = jnp.where(mine[None, :, :, None], q[:, :, None, :],
+                         jnp.zeros((), q.dtype)).reshape(S, H, pack * D)
+        out = paged_attention(wide, kpool, vpool, block_tables, n_attend,
+                              layer, scale, pages_per_block)
+        return jnp.sum(jnp.where(mine[None, :, :, None],
+                                 out.reshape(S, H, pack, D),
+                                 jnp.zeros((), out.dtype)), axis=2)
     S, H, D = q.shape
     pages, layers, PL, Hkv, _ = kpool.shape
     M = block_tables.shape[1]
-    if scale is None:
-        scale = D ** -0.5
     P = pages_per_block or max(1, _PAGED_BLOCK_TOKENS // PL)
     P = min(int(P), M)
     PR = Hkv * PL

@@ -35,6 +35,13 @@ decode server (docs/generation.md):
     recurrent arrays, whose layer axis counts the layers that hold
     state while the pool's counts those that attend
     (`CacheConfig.recurrent_layers`).
+  * `shortconv` — the gated short convolution, the mixer of the layers
+    a `latent_moe` model marks ``'conv'``: a causal 3-tap depthwise
+    filter over ``B * x`` under the gate ``C``, whose whole state is
+    the last two rows of its own input (a tail and no scan state in the
+    recurrent arrays).  Beside it a model may attend through ``'gqa'``
+    layers, the dense block's attention over the K and V pools with a
+    norm on every query and key head (``cfg['qk_norm']``).
   * `sampling` — greedy / temperature / top-k draws keyed by
     ``(request seed, absolute position)`` only, so fused and sequential
     decode sample bitwise-identical streams (ops/sampling.py).
@@ -59,7 +66,7 @@ from .kv_cache import (CacheConfig, PagePool, PrefixCache,  # noqa
                        SlotAllocator, default_page_len, init_state)
 from .decode import (DecodeRuntime, dense_reference,  # noqa
                      random_weights, weight_names, weight_shapes)
-from . import experts, kda, latent, ssm  # noqa
+from . import experts, kda, latent, shortconv, ssm  # noqa
 from .sampling import SamplingParams, draft_ngram  # noqa
 from .streaming import TokenStream  # noqa
 from .scheduler import GenerationConfig, GenerationEngine  # noqa
@@ -67,6 +74,6 @@ from .scheduler import GenerationConfig, GenerationEngine  # noqa
 __all__ = ['CacheConfig', 'PagePool', 'PrefixCache', 'SlotAllocator',
            'default_page_len', 'init_state', 'DecodeRuntime',
            'dense_reference', 'random_weights', 'weight_names',
-           'weight_shapes', 'experts', 'kda', 'latent', 'ssm',
+           'weight_shapes', 'experts', 'kda', 'latent', 'shortconv', 'ssm',
            'SamplingParams', 'draft_ngram', 'TokenStream',
            'GenerationConfig', 'GenerationEngine']

@@ -101,8 +101,9 @@ prefix-cache hit (`generation.prefix_refused_recurrent` counts the
 begins), no speculative window and no ring prefill.
 
 ``block: 'latent_moe'`` is the third kind: multi-head LATENT attention
-(latent.py) and, per layer as ``cfg['ffn']`` says, a dense SwiGLU or
-routed experts beside a shared one, held as ONE expert-parallel rank
+(latent.py; or another mixer a layer, below) and, per layer as
+``cfg['ffn']`` says, a dense SwiGLU or routed experts beside a shared
+one (or none), held as ONE expert-parallel rank
 (experts.py; ``cfg['moe']`` says which of ``ranks`` this is).  Its
 weights have other names and shapes (`weight_shapes`), its prepared
 forms are not per-head q/k/v (`latent.PREPARED`: the up-projection
@@ -125,8 +126,11 @@ programs are untouched by it, bit for bit (tests/
 test_generation_pipeline.py pins their lowered text).
 
 A ``latent_moe`` model may give a MIXER PER LAYER as data, as it gives
-its feed-forward: ``cfg['mixer']``, ``'latent'`` (the default, every
-layer) or ``'kda'`` for each layer.  A ``'kda'`` layer is Kimi Delta
+its feed-forward: ``cfg['mixer']``, for each layer ``'latent'`` (the
+default, every layer), ``'kda'``, ``'gqa'`` or ``'conv'``; it must
+ATTEND in at least one layer, through ``'latent'`` layers or through
+``'gqa'`` ones (it need have no ``'latent'`` layer, and then no
+``cfg['latent']``).  A ``'kda'`` layer is Kimi Delta
 Attention (kda.py): a float32 matrix state a head and three short
 convolutions' tails, per slot, in the recurrent arrays of the state
 dict, and NO rows in the pool.  The pool's layer axis then counts the
@@ -140,6 +144,31 @@ and no ring prefill.  Its launches' stats carry two counts more
 (`_launch_stats`: the state the windows moved, the tokens the chunk
 scan took).  A model without the key lowers to the program it had
 (tests/test_generation_kda.py pins the text).
+
+Two more mixers may stand there.  ``'gqa'`` is the dense block's own
+attention as a layer's mixer: `_qkv`, `_rope_at`, `_write_rows` and
+`ops.attention.paged_attention` over the K and V pools of the FIRST
+geometry, whose layer axis counts the layers that attend; with
+``cfg['qk_norm']`` a learned RMS norm on every query and key head
+between the projection and the rotation (weights ``att_q_norm`` /
+``att_k_norm`` ``[head_dim]``, the pool's K row is the normed, rotated
+key).  A model attends through ``'latent'`` OR ``'gqa'`` layers, never
+both (one pool geometry a runtime), and in at least one.  Such
+a layer's pool is laid out for the paged kernel
+(`ops.attention.paged_pool_heads`): a head of 64 lies two kv heads to a
+128-lane row, ``[pages, layers, page_len, kv_heads / 2, 128]``, the same
+bytes in the same order (`_pool_rows` / `_head_rows_of`), so the step
+attends in place as at 128 and no lane of a row is padding.  ``'conv'``
+is the gated short convolution (shortconv.py): its whole state is the
+last ``taps - 1`` rows of its own input, the ``conv`` array of the
+state dict with NO ``ssm`` beside it
+(`CacheConfig.recurrent` ``(None, tail)``); such a model is `recurrent`
+like one with ``'kda'`` layers (it holds state through ``'kda'`` OR
+``'conv'`` layers, never both: one state geometry a runtime).  Its
+feed-forward may be an expert layer with NO shared expert, and the
+WHOLE layer (``ranks: 1``; experts.py).  The standing programs are
+untouched by all of it (tests/test_generation_lfm2.py pins this kind's
+two launches as the other files pin theirs).
 """
 import threading
 from collections.abc import Mapping
@@ -150,11 +179,12 @@ from ... import observability as _obs
 from ...core import compile_cache as _cc
 from ...ops.attention import (cached_attention, latent_attention_eligible,
                               paged_attention, paged_attention_eligible,
-                              paged_attention_rows)
+                              paged_attention_rows, paged_pool_heads)
 from ...ops.sampling import sample_logits, sample_tokens_at, token_key
 from . import experts as _experts
 from . import kda as _kda
 from . import latent as _latent
+from . import shortconv as _shortconv
 from . import ssm as _ssm
 from .kv_cache import (CacheConfig, PagePool, PrefixCache, SlotAllocator,
                        init_state)
@@ -184,9 +214,10 @@ def _block(cfg):
 def _recurrent(cfg):
     """Whether the model carries recurrent state: a ``'falcon_h1'``
     block does in every layer (ssm.py), a ``'latent_moe'`` model in its
-    ``'kda'`` layers, if it has any (`_mixer_kinds`, kda.py)."""
+    ``'kda'`` or ``'conv'`` layers, if it has any (`_mixer_kinds`,
+    kda.py, shortconv.py)."""
     return _block(cfg) == 'falcon_h1' or (
-        _latent_moe(cfg) and 'kda' in _mixer_kinds(cfg))
+        _latent_moe(cfg) and _state_mixer(cfg) is not None)
 
 
 def _latent_moe(cfg):
@@ -206,26 +237,56 @@ def _ffn_kinds(cfg):
     return kinds
 
 
+# a `latent_moe` layer's mixer: the two that ATTEND (rows in the pool)
+# and the two that hold STATE (the recurrent arrays)
+_ATTENDING = ('latent', 'gqa')
+_STATEFUL = ('kda', 'conv')
+
+
 def _mixer_kinds(cfg):
     """A ``latent_moe`` model's mixer per layer: ``cfg['mixer']``,
-    ``'latent'`` (latent.py) or ``'kda'`` (kda.py) for each of
+    ``'latent'`` (latent.py), ``'gqa'`` (the dense block's attention),
+    ``'kda'`` (kda.py) or ``'conv'`` (shortconv.py) for each of
     ``n_layer``; every layer ``'latent'`` for a model without the key.
-    `_ffn_kinds`' sibling, read by no other block kind."""
+    One pool geometry and one state geometry a runtime: a model attends
+    through ``'latent'`` or ``'gqa'`` layers and holds state through
+    ``'kda'`` or ``'conv'`` layers, never both of a pair.  `_ffn_kinds`'
+    sibling, read by no other block kind."""
     L = int(cfg['n_layer'])
     kinds = tuple(cfg.get('mixer', ('latent',) * L))
-    if len(kinds) != L or any(k not in ('latent', 'kda') for k in kinds) \
-            or 'latent' not in kinds:
-        raise ValueError("mixer must name 'latent' or 'kda' for each of "
-                         'the %d layers, and attend in at least one, got '
-                         '%r' % (L, kinds))
+    if len(kinds) != L or any(k not in _ATTENDING + _STATEFUL
+                              for k in kinds) \
+            or not any(k in _ATTENDING for k in kinds):
+        raise ValueError("mixer must name 'latent', 'gqa', 'kda' or 'conv' "
+                         'for each of the %d layers, and attend in at least '
+                         'one, got %r' % (L, kinds))
+    for pair, what in ((_ATTENDING, 'attends through'),
+                       (_STATEFUL, 'holds state through')):
+        if all(k in kinds for k in pair):
+            raise ValueError('a model %s %r or %r layers, never both, got '
+                             '%r' % ((what,) + pair + (kinds,)))
     return kinds
+
+
+def _attending_mixer(cfg):
+    """The mixer kind a ``latent_moe`` model attends through:
+    ``'latent'`` or ``'gqa'``."""
+    kinds = _mixer_kinds(cfg)
+    return next(k for k in _ATTENDING if k in kinds)
+
+
+def _state_mixer(cfg):
+    """The mixer kind a ``latent_moe`` model holds state through
+    (``'kda'`` or ``'conv'``), or None for one that holds none."""
+    kinds = _mixer_kinds(cfg)
+    return next((k for k in _STATEFUL if k in kinds), None)
 
 
 def _layer_axes(cfg):
     """A ``latent_moe`` model's layers on their own axes: layer i's
     index among the layers of ITS mixer kind, which is its index on the
-    pool's layer axis (``'latent'``) or the recurrent state's
-    (``'kda'``)."""
+    pool's layer axis (``'latent'``, ``'gqa'``) or the recurrent state's
+    (``'kda'``, ``'conv'``)."""
     seen, out = {}, []
     for kind in _mixer_kinds(cfg):
         out.append(seen.get(kind, 0))
@@ -242,7 +303,8 @@ def weight_names(cfg):
     program leaves in its scope (models/llama.py layout); a
     ``falcon_h1`` block adds its mixer's (`ssm.SLOTS`).  A ``latent_moe``
     block has its own: per layer the two norms, its mixer's (latent
-    attention's, `latent.slots`, or `kda.SLOTS`) and, by the layer's
+    attention's, `latent.slots`, `kda.SLOTS`, `shortconv.SLOTS` or the
+    dense block's four projections, `_gqa_shapes`) and, by the layer's
     feed-forward kind, the dense SwiGLU's or the expert layer's
     (`experts.weight_shapes`)."""
     if _latent_moe(cfg):
@@ -277,15 +339,34 @@ def weight_shapes(cfg):
     return shapes
 
 
+def _gqa_shapes(cfg):
+    """{slot: shape} of a ``'gqa'`` mixer's weights: the dense block's
+    four projections, and with ``cfg['qk_norm']`` the two head norms'
+    scales (over a head's ``head_dim`` values, in the public order)."""
+    d, dh = int(cfg['d_model']), _head_dim(cfg)
+    h, hkv = int(cfg['n_head']), int(cfg['n_kv_head'])
+    shapes = {'att_q_w': (d, h * dh), 'att_k_w': (d, hkv * dh),
+              'att_v_w': (d, hkv * dh), 'att_o_w': (h * dh, d)}
+    if cfg.get('qk_norm'):
+        shapes.update({'att_q_norm': (dh,), 'att_k_norm': (dh,)})
+    return shapes
+
+
 def _latent_moe_shapes(cfg):
     """`weight_shapes` of a ``latent_moe`` model, in `weight_names`'
     order."""
     d, v = int(cfg['d_model']), int(cfg['vocab'])
     mixers = _mixer_kinds(cfg)
-    mixer = {'latent': _latent.weight_shapes(d, int(cfg['n_head']),
-                                             cfg['latent'])}
+    mixer = {}
+    if 'latent' in mixers:
+        mixer['latent'] = _latent.weight_shapes(d, int(cfg['n_head']),
+                                                cfg['latent'])
+    if 'gqa' in mixers:
+        mixer['gqa'] = _gqa_shapes(cfg)
     if 'kda' in mixers:
         mixer['kda'] = _kda.weight_shapes(d, cfg['kda'])
+    if 'conv' in mixers:
+        mixer['conv'] = _shortconv.weight_shapes(d, cfg['conv'])
     shapes = {'tok_emb': (v, d), 'final_norm': (d,), 'lm_proj_w': (d, v)}
     for i, kind in enumerate(_ffn_kinds(cfg)):
         p = 'layer_%d_' % i
@@ -355,21 +436,31 @@ def _public_rows(k, dh):
         k.shape)
 
 
+def _qkv_layers(cfg):
+    """The layers whose q, k and v are held as `_prepare_qkv` makes them:
+    every layer of the dense and ``falcon_h1`` kinds, a ``latent_moe``
+    model's ``'gqa'`` layers."""
+    if _latent_moe(cfg):
+        return [i for i, kind in enumerate(_mixer_kinds(cfg))
+                if kind == 'gqa']
+    return list(range(int(cfg['n_layer'])))
+
+
 def _prepared_names(cfg):
     """{public name: (slot, the executables' name for its prepared
     form)} of the weights the runtime keeps prepared.  A ``latent_moe``
     model's are latent attention's (`latent.prepared`), in the layers
     that have it: a public weight there has one or two prepared parts,
-    and the second entry is the tuple of their names."""
-    if _latent_moe(cfg):
+    and the second entry is the tuple of their names; in its ``'gqa'``
+    layers q, k and v as the dense block keeps them."""
+    if _latent_moe(cfg) and _attending_mixer(cfg) == 'latent':
         return {'layer_%d_%s' % (i, slot):
                 (slot, tuple('layer_%d_%s' % (i, t) for t in stored))
                 for i, kind in enumerate(_mixer_kinds(cfg))
                 if kind == 'latent'
                 for slot, stored in _latent.prepared(cfg['latent']).items()}
     return {'layer_%d_%s' % (i, slot): (slot, 'layer_%d_%s' % (i, stored))
-            for i in range(int(cfg['n_layer']))
-            for slot, stored in _PREPARED.items()}
+            for i in _qkv_layers(cfg) for slot, stored in _PREPARED.items()}
 
 
 def _prepared_arrays(params, cfg):
@@ -398,7 +489,7 @@ def _params_from(weights, cfg):
     prepared = _prepared_names(cfg)
     params = {n: jnp.asarray(weights[n]) for n in weight_names(cfg)
               if n not in prepared}
-    if _latent_moe(cfg):
+    if _latent_moe(cfg) and _attending_mixer(cfg) == 'latent':
         # latent attention's: W_qb, W_kva, W_kvb -> `latent.PREPARED`
         prepare = jax.jit(_latent.prepare, static_argnums=(3, 4, 5, 6))
         slots = _latent.prepared(cfg['latent'])
@@ -413,7 +504,7 @@ def _params_from(weights, cfg):
         return params
     dh = _head_dim(cfg)
     prepare = jax.jit(_prepare_qkv, static_argnums=3)
-    for i in range(int(cfg['n_layer'])):
+    for i in _qkv_layers(cfg):
         p = 'layer_%d_' % i
         made = prepare(*(jnp.asarray(weights[p + s]) for s in _PREPARED), dh)
         params.update(zip((p + t for t in _PREPARED.values()), made))
@@ -432,7 +523,7 @@ class _PublicWeights(Mapping):
         import jax
         self._params, self._names = params, weight_names(cfg)
         self._prepared = _prepared_names(cfg)
-        if _latent_moe(cfg):
+        if _latent_moe(cfg) and _attending_mixer(cfg) == 'latent':
             self._dims = _latent_dims(cfg)
             self._undo = jax.jit(_latent.public,
                                  static_argnums=(0, 2, 3, 4, 5))
@@ -500,20 +591,26 @@ def _rope_at(x, pos, theta):
                            axis=-1)
 
 
-def _qkv(w, cfg, h, i):
+def _qkv(w, cfg, h, i, wide=None):
     """h: [B, T, D] -> q [B, H, T, dh], k/v [B, Hkv, T, dh] (pre-rope),
     from the PREPARED projections (`_prepare_qkv`): each stored
     ``[N, D]`` and contracted on its second axis, q's and k's heads in
-    rotated-half order."""
+    rotated-half order.  ``wide`` (a dtype; a ``latent_moe`` layer's
+    float32 stream) takes ``h`` in the weights' dtype and hands the
+    products back in ``wide``; without it they are what the operands'
+    dtypes make them."""
     import jax.numpy as jnp
     B, T = h.shape[0], h.shape[1]
     H, Hkv = int(cfg['n_head']), int(cfg['n_kv_head'])
     dh = _head_dim(cfg)
     p = 'layer_%d_' % i
     h = _scaled(cfg, h, 'attention_in')
+    if wide is not None:
+        h = h.astype(w[p + _PREPARED['att_q_w']].dtype)
 
     def heads(slot, n):
-        out = jnp.einsum('btd,nd->btn', h, w[p + _PREPARED[slot]])
+        out = jnp.einsum('btd,nd->btn', h, w[p + _PREPARED[slot]],
+                         preferred_element_type=wide)
         return out.reshape(B, T, n, dh).transpose(0, 2, 1, 3)
 
     q, k, v = heads('att_q_w', H), heads('att_k_w', Hkv), heads('att_v_w',
@@ -619,6 +716,8 @@ def _gathered_rows(cache, st, bt):
 # its tokens: `experts.STATS` summed over its expert layers (and a
 # window's steps), then the latent rows it read
 _LAUNCH_STATS = _experts.STATS + ('latent_rows_read',)
+# (0 for a model that attends through `gqa` layers: what its kernel reads
+# is counted on the host, `DecodeRuntime._window_rows_read`)
 # and, of a model with `kda` layers, behind them: slot-layers whose matrix
 # state a window's steps read and wrote (`DecodeRuntime._count_stats`
 # turns them into bytes), tokens a chunk's scan took
@@ -628,7 +727,7 @@ _KDA_STATS = ('kda_state_bytes', 'kda_chunk_tokens')
 def _launch_stats(cfg):
     """The names of what a ``latent_moe`` launch of this model counts, in
     the order of the array it hands back."""
-    return _LAUNCH_STATS + (_KDA_STATS if _recurrent(cfg) else ())
+    return _LAUNCH_STATS + (_KDA_STATS if _state_mixer(cfg) == 'kda' else ())
 
 
 def _latent_moe_ffn(w, cfg, x, i, valid, experts_kernel):
@@ -646,6 +745,98 @@ def _latent_moe_ffn(w, cfg, x, i, valid, experts_kernel):
     return x + y, stats
 
 
+def _gqa_qkv(w, cfg, h, i, pos, dtype):
+    """A ``'gqa'`` layer's q [B, H, T, dh], k and v [B, Hkv, T, dh] from
+    its normalised input h [B, T, D] float32 at positions pos [B, T]:
+    the dense block's projections (`_qkv`, products in float32), with
+    ``cfg['qk_norm']`` an RMS norm over every query and key head
+    (``att_q_norm`` / ``att_k_norm``, public order -> the heads'
+    rotated-half order), then the rotation (`_rope_at`).  q comes back in
+    ``dtype``, the pool's: what `paged_attention` and `cached_attention`
+    multiply the rows by; k and v in float32, as `_write_rows` takes
+    them."""
+    import jax
+    import jax.numpy as jnp
+    theta, dh = float(cfg['theta']), _head_dim(cfg)
+    with jax.named_scope('attn.qkv'):
+        q, k, v = _qkv(w, cfg, h, i, wide=jnp.float32)
+        if cfg.get('qk_norm'):
+            with jax.named_scope('attention.qk_norm'):
+                def halves(scale):
+                    return _head_rows(scale[:, None], dh, True)[:, 0]
+                p = 'layer_%d_' % i
+                q = _latent.rms(q, halves(w[p + 'att_q_norm']), _eps(cfg))
+                k = _latent.rms(k, halves(w[p + 'att_k_norm']), _eps(cfg))
+        q, k = _rope_at(q, pos, theta), _rope_at(k, pos, theta)
+    return q.astype(dtype), k, v
+
+
+def _pool_rows(x, cache):
+    """Rows [N, Hkv, dh] of a ``'gqa'`` layer as the pool holds them, [N,
+    cache.kv_heads, cache.head_dim]: `paged_pool_heads`' layout, kv heads
+    side by side; the same values in the same order."""
+    return x.reshape(x.shape[0], cache.kv_heads, cache.head_dim)
+
+
+def _head_rows_of(rows, dh):
+    """`_pool_rows` undone on gathered rows [B, cache.kv_heads, T,
+    cache.head_dim] -> [B, Hkv, T, dh] (jax or numpy; the rows
+    themselves where the pool's head is the model's)."""
+    B, hp, T, wide = rows.shape
+    if wide == dh:
+        return rows
+    return rows.reshape(B, hp, T, wide // dh, dh).transpose(
+        0, 1, 3, 2, 4).reshape(B, hp * (wide // dh), T, dh)
+
+
+def _gqa_prefill(w, cfg, cache, h, i, j, pos, st, pg, rw, bt_row):
+    """A ``'gqa'`` layer of a prefill chunk: h [C, D] float32 normalised
+    -> (the mixer's output [C, D] float32, the state dict with the
+    chunk's rows written into layer ``j`` of the pools): the dense
+    block's write, gather and `cached_attention`."""
+    import jax
+    scope = jax.named_scope
+    q, k, v = _gqa_qkv(w, cfg, h[None], i, pos, st['k'].dtype)
+    with scope('kv.write'):
+        st = _write_rows(st, j, pg, rw,
+                         _pool_rows(k[0].transpose(1, 0, 2), cache),
+                         _pool_rows(v[0].transpose(1, 0, 2), cache), False)
+    with scope('kv.gather'):
+        kl, vl = (_head_rows_of(rows, q.shape[-1]) for rows in
+                  _logical_rows(st, bt_row[None], j, cache))
+    with scope('attn.scores'):
+        att = cached_attention(q, kl, vl, pos)            # [1, H, C, dh]
+        att = att[0].transpose(1, 0, 2).reshape(h.shape[0], -1)
+        return _latent.dot(att, w['layer_%d_att_o_w' % i]), st
+
+
+def _gqa_step(w, cfg, cache, h, i, j, pos, st, pg, rw, bt, n_attend, paged):
+    """A ``'gqa'`` layer of a decode step: h [S, D] float32 normalised ->
+    (the mixer's output [S, D] float32, the state dict with every slot's
+    row written): in place over the pool with ``paged``
+    (`ops.attention.paged_attention`), else the composed gather."""
+    import jax
+    scope = jax.named_scope
+    S = h.shape[0]
+    q, k, v = _gqa_qkv(w, cfg, h[:, None], i, pos[:, None], st['k'].dtype)
+    with scope('kv.write'):
+        st = _write_rows(st, j, pg, rw, _pool_rows(k[:, :, 0, :], cache),
+                         _pool_rows(v[:, :, 0, :], cache), False)
+    if not paged:
+        with scope('kv.gather'):
+            kl, vl = (_head_rows_of(rows, q.shape[-1]) for rows in
+                      _logical_rows(st, bt, j, cache))
+    with scope('attn.scores'):
+        if paged:
+            att = paged_attention(q[:, :, 0, :], st['k'], st['v'], bt,
+                                  n_attend, j)
+        else:
+            att = cached_attention(q, kl, vl, pos[:, None]).transpose(
+                0, 2, 1, 3)
+        return _latent.dot(att.reshape(S, -1),
+                           w['layer_%d_att_o_w' % i]), st
+
+
 def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False,
                 experts_kernel=False):
     """Build the one-chunk (or one-shot ring) prefill function.
@@ -658,8 +849,9 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False,
     stores it in tok[slot].  Only the final chunk's draw (the request's
     FIRST token, the TTFT token) survives.
 
-    A ``latent_moe`` model's layers take their own branch (`latent.prefill`
-    over the latent pool, then the layer's feed-forward kind), carry the
+    A ``latent_moe`` model's layers take their own branch (the layer's
+    mixer, `latent.prefill` over the latent pool unless ``cfg['mixer']``
+    names another, then the layer's feed-forward kind), carry the
     residual stream in float32, and the function returns a fourth value,
     the chunk's `_LAUNCH_STATS`.  ``latent_kernel``
     (`DecodeRuntime.prefill_kernel`) keeps that attention's scores on
@@ -678,6 +870,7 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False,
     if latent_moe:
         mixers, axis = _mixer_kinds(cfg), _layer_axes(cfg)
         n_latent = mixers.count('latent')
+        with_kda = 'kda' in mixers
 
     if ring_mesh is not None:
         if recurrent or latent_moe:
@@ -718,6 +911,16 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False,
                             true_count)
                         st = dict(st, ssm=st['ssm'].at[slot, j].set(S),
                                   conv=st['conv'].at[slot, j].set(tail))
+                    elif mixers[i] == 'conv':
+                        # likewise the slot's tail, all the state it has
+                        att, tail = _shortconv.prefill_mixer(
+                            w, 'layer_%d_' % i, cfg, h,
+                            jnp.where(offset > 0, st['conv'][slot, j], 0.0),
+                            true_count)
+                        st = dict(st, conv=st['conv'].at[slot, j].set(tail))
+                    elif mixers[i] == 'gqa':
+                        att, st = _gqa_prefill(w, cfg, cache, h, i, j, pos,
+                                               st, pg, rw, bt_row)
                     else:
                         att, pool = _latent.prefill(
                             w, 'layer_%d_' % i, cfg, h, p_abs,
@@ -788,7 +991,7 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False,
             # that attends
             rows = n_latent * _latent.prefill_rows(new_len, M * PL)
             handed = [stats, rows.astype(jnp.int32).reshape(1)]
-            if recurrent:
+            if with_kda:
                 handed.append(jnp.stack([jnp.int32(0),
                                          true_count.astype(jnp.int32)]))
             return st, nxt, logits, jnp.concatenate(handed)
@@ -819,7 +1022,9 @@ def _step_fn(cfg, cache, paged, state_kernel, experts_kernel=False):
     kind, where a slot that rides along routes nowhere; a ``'kda'``
     layer advances the live slots' matrix state (`kda.step_mixer`: in
     place through `kda.kda_step` with ``state_kernel``, else every slot
-    steps and a dead one's state is kept).  Its step returns a third
+    steps and a dead one's state is kept), a ``'conv'`` layer their
+    tails (`shortconv.step_mixer`), a ``'gqa'`` layer attends as the
+    dense block does (`_gqa_step`).  Its step returns a third
     value, the step's `_launch_stats`; ``experts_kernel``
     (`DecodeRuntime.experts_kernel`) is `experts.routed`'s."""
     import jax.numpy as jnp
@@ -831,7 +1036,7 @@ def _step_fn(cfg, cache, paged, state_kernel, experts_kernel=False):
     latent_moe = _latent_moe(cfg)
     if latent_moe:
         mixers, axis = _mixer_kinds(cfg), _layer_axes(cfg)
-        n_latent = mixers.count('latent')
+        n_latent, n_kda = mixers.count('latent'), mixers.count('kda')
 
     def step(w, st, bt, fed, active, seeds, temps, topks):
         import jax
@@ -862,6 +1067,14 @@ def _step_fn(cfg, cache, paged, state_kernel, experts_kernel=False):
                             conv=st['conv'].at[:, j].set(jnp.where(
                                 active[:, None, None], tail,
                                 st['conv'][:, j])))
+                    elif mixers[i] == 'conv':
+                        att, tail = _shortconv.step_mixer(
+                            w, 'layer_%d_' % i, cfg, h, st['conv'][:, j],
+                            active)
+                        st = dict(st, conv=st['conv'].at[:, j].set(tail))
+                    elif mixers[i] == 'gqa':
+                        att, st = _gqa_step(w, cfg, cache, h, i, j, pos, st,
+                                            pg, rw, bt, n_attend, paged)
                     else:
                         att, pool = _latent.step(
                             w, 'layer_%d_' % i, cfg, h, pos, st['k'], j, pg,
@@ -923,13 +1136,13 @@ def _step_fn(cfg, cache, paged, state_kernel, experts_kernel=False):
             rows = jnp.sum(-(-n_attend // PL) * PL) if paged else S * M * PL
             handed = [stats,
                       jnp.asarray(n_latent * rows, jnp.int32).reshape(1)]
-            if recurrent:
+            if n_kda:
                 # the kernel moves the live slots' state in every `kda`
                 # layer, the composed step every slot's (kda.py)
                 moved = jnp.sum(active, dtype=jnp.int32) if state_kernel \
                     else S
                 handed.append(jnp.stack(
-                    [jnp.asarray((L - n_latent) * moved, jnp.int32),
+                    [jnp.asarray(n_kda * moved, jnp.int32),
                      jnp.int32(0)]))
             return st, nxt, jnp.concatenate(handed)
         return st, nxt
@@ -1137,7 +1350,8 @@ class DecodeRuntime(object):
     backpressure or a terminal ``kv_oom``.
 
     A model that carries recurrent state (``block: 'falcon_h1'``, or a
-    ``latent_moe`` model with ``'kda'`` layers; `recurrent`) runs
+    ``latent_moe`` model with ``'kda'`` or ``'conv'`` layers;
+    `recurrent`) runs
     WITHOUT the prefix cache whatever
     ``prefix_cache`` says (a hit would skip tokens the scan state never
     saw), and refuses speculative windows and ring prefill.  A
@@ -1171,18 +1385,32 @@ class DecodeRuntime(object):
             self.recurrent = _recurrent(cfg)
             self.latent_moe = _latent_moe(cfg)
             if self.latent_moe:
-                # the second pool geometry: one row a token a layer
                 _ffn_kinds(cfg)
-                lat = cfg['latent']
                 # the pool's layers are those that attend, the state's
                 # those that hold one (`_mixer_kinds`)
-                attend = _mixer_kinds(cfg).count('latent')
-                geometry = dict(kv_heads=1,
-                                head_dim=_latent.stored_width(lat),
-                                latent=int(lat['kv_rank']), layers=attend)
+                mixers = _mixer_kinds(cfg)
+                attend = sum(k in _ATTENDING for k in mixers)
+                if _attending_mixer(cfg) == 'gqa':
+                    # the dense block's K and V pools, a narrow head's kv
+                    # heads side by side in a row (`paged_pool_heads`)
+                    heads, width = paged_pool_heads(int(cfg['n_kv_head']),
+                                                    _head_dim(cfg))
+                    geometry = dict(kv_heads=heads, head_dim=width,
+                                    layers=attend)
+                else:
+                    # the second pool geometry: one row a token a layer
+                    lat = cfg['latent']
+                    geometry = dict(kv_heads=1,
+                                    head_dim=_latent.stored_width(lat),
+                                    latent=int(lat['kv_rank']),
+                                    layers=attend)
                 if self.recurrent:
                     geometry.update(
-                        recurrent=_kda.state_shapes(cfg['kda']),
+                        recurrent=(
+                            _kda.state_shapes(cfg['kda'])
+                            if _state_mixer(cfg) == 'kda' else
+                            _shortconv.state_shapes(int(cfg['d_model']),
+                                                    cfg['conv'])),
                         recurrent_layers=int(cfg['n_layer']) - attend)
             else:
                 geometry = dict(kv_heads=int(cfg['n_kv_head']),
@@ -1215,7 +1443,7 @@ class DecodeRuntime(object):
             # the decode step attends over the pool in place where the
             # kernel can run (a floating pool, one device); an int8 pool and
             # a mesh of several devices keep the composed gather
-            if self.latent_moe:
+            if self.cache.latent is not None:
                 self.paged = latent_attention_eligible(
                     self.cache.pool_shape, self.cache.store_dtype,
                     self.cache.latent, mesh)
@@ -1225,14 +1453,16 @@ class DecodeRuntime(object):
             # likewise the scan state (the matrix state) of a recurrent
             # model: in place over the live slots where its kernel can run
             # (float32, one device)
-            self.state_kernel = self.recurrent and (
+            # (a model whose state is convolution tails alone has none)
+            self.state_kernel = 'ssm' in self.state and (
                 _kda.kda_step_eligible if self.latent_moe
                 else _ssm.ssm_step_eligible)(
                     self.state['ssm'].shape, self.state['ssm'].dtype, mesh)
-            # and a `latent_moe` chunk's scores: on chip where that kernel
+            # and a latent chunk's scores: on chip where that kernel
             # can run, else through HBM a block at a time
-            self.prefill_kernel = self.latent_moe and _latent.prefill_kernel(
-                cfg, self.cache, self.prefill_chunk, mesh)
+            self.prefill_kernel = self.cache.latent is not None \
+                and _latent.prefill_kernel(
+                    cfg, self.cache, self.prefill_chunk, mesh)
             # and the grouped route of its routed experts: only the
             # matrices of the experts with rows, by a kernel, else by
             # `ragged_dot`
@@ -1871,7 +2101,7 @@ class DecodeRuntime(object):
         bt = self.block_tables[int(slot)]
         L, Hkv = self.cache.layers, self.cache.kv_heads
         Tmax, dh = self.cache.max_len, self.cache.head_dim
-        if self.latent_moe:
+        if self.cache.latent is not None:
             # the one row a token has: k [L, 1, Tmax, kv_rank + rope] in
             # the public order (`latent.public_rows`), no v
             rows = np.asarray(st['k'])[bt]         # [M, L, PL, W]
@@ -1893,6 +2123,10 @@ class DecodeRuntime(object):
             v = assemble(st['v'], st['v_scale'])
         else:
             k, v = assemble(st['k'], None), assemble(st['v'], None)
+        if self.latent_moe:
+            # a `gqa` mixer's pool may hold kv heads side by side
+            dh = _head_dim(self.cfg)
+            k, v = _head_rows_of(k, dh), _head_rows_of(v, dh)
         return (_public_rows(k, dh), v,
                 int(np.asarray(st['lengths'][int(slot)])))
 

@@ -3,12 +3,18 @@ in the layers that say ``'dense'``, routed experts beside a shared one
 in those that say ``'experts'``, as ONE expert-parallel rank runs them.
 
 For a layer's normalised input ``h`` ``[T, D]`` and ``moe = cfg['moe']``
-(``n_routed``, ``top_k``, ``d_expert``, ``scale``, ``ranks``, ``rank``):
+(``n_routed``, ``top_k``, ``d_expert``, ``scale``, ``ranks``, ``rank``;
+``n_shared``, 1 unless given; ``norm_eps``, 0 unless given):
 
     g   = sigmoid(h W_g)                    [T, n_routed], float32
     E   = top_k(g)                          over ALL n_routed experts
-    w_e = scale * g_e / sum_{e' in E} g_e'  over all top_k picks
+    w_e = scale * g_e / (sum_{e' in E} g_e' + norm_eps)  over all picks
     y   = sum_{e in E, e held} w_e FFN_e(h) + FFN_shared(h)
+
+``n_shared: 0`` is a layer WITHOUT a shared expert: it has no
+``moe_shared_*`` weights and runs no product for one (not arrays of
+width zero).  ``ranks: 1`` is the whole layer on this chip: every
+routed expert is held and every pair of every token is computed here.
 
 The layer is told WHICH experts it holds: rank ``r`` of ``ranks`` holds
 the contiguous block ``[r * n_routed / ranks, (r + 1) * n_routed /
@@ -81,17 +87,23 @@ def held(moe):
     return int(moe['rank']) * count, count
 
 
+def _n_shared(moe):
+    return int(moe.get('n_shared', 1))
+
+
 def weight_shapes(d_model, moe):
-    """{slot: shape} of one expert layer's weights: `SLOTS`, and where
+    """{slot: shape} of one expert layer's weights: `SLOTS` (without
+    the shared expert's three where ``moe['n_shared']`` is 0), and where
     ``moe['bias']`` the choice bias ``moe_router_bias``."""
     f, n = int(moe['d_expert']), held(moe)[1]
-    fs = f * int(moe.get('n_shared', 1))
+    fs = f * _n_shared(moe)
     shapes = {'moe_router_w': (d_model, int(moe['n_routed'])),
               'moe_fc1_w': (n, d_model, f), 'moe_fc3_w': (n, d_model, f),
-              'moe_fc2_w': (n, f, d_model),
-              'moe_shared_fc1_w': (d_model, fs),
-              'moe_shared_fc3_w': (d_model, fs),
-              'moe_shared_fc2_w': (fs, d_model)}
+              'moe_fc2_w': (n, f, d_model)}
+    if fs:
+        shapes.update({'moe_shared_fc1_w': (d_model, fs),
+                       'moe_shared_fc3_w': (d_model, fs),
+                       'moe_shared_fc2_w': (fs, d_model)})
     if moe.get('bias'):
         shapes['moe_router_bias'] = (int(moe['n_routed']),)
     return shapes
@@ -113,7 +125,9 @@ def route(h, router_w, moe, bias=None):
     """h [T, D] float32 normalised -> (picks [T, top_k] int32, their
     weights [T, top_k] float32).  Logits, scores and weights in float32
     at full precision, as the source computes them; ``bias`` is
-    `select`'s."""
+    `select`'s.  ``moe['norm_eps']`` (a source that renormalises over
+    ``sum + 1e-6``) is added to the picks' sum where the model dict
+    gives one."""
     import jax
     import jax.numpy as jnp
     logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32),
@@ -121,8 +135,10 @@ def route(h, router_w, moe, bias=None):
     g = jax.nn.sigmoid(logits)
     picks = select(g, moe, bias)
     gp = jnp.take_along_axis(g, picks, axis=1)
-    return picks.astype(jnp.int32), \
-        gp / jnp.sum(gp, axis=1, keepdims=True) * float(moe['scale'])
+    total = jnp.sum(gp, axis=1, keepdims=True)
+    if moe.get('norm_eps'):
+        total = total + float(moe['norm_eps'])
+    return picks.astype(jnp.int32), gp / total * float(moe['scale'])
 
 
 def swiglu(h, w1, w3, w2):
@@ -374,8 +390,8 @@ def routed(h, w1, w3, w2, picks, wts, valid, moe, kernel=False):
 
 def expert_layer(w, p, cfg, h, valid, kernel=False):
     """h [T, D] float32 normalised -> (the layer's output [T, D]
-    float32, stats): the held routed experts' part and the shared
-    expert's.  ``kernel`` is `routed`'s."""
+    float32, stats): the held routed experts' part and, where the layer
+    has one, the shared expert's.  ``kernel`` is `routed`'s."""
     import jax
     moe = cfg['moe']
     with jax.named_scope('moe.route'):
@@ -385,6 +401,8 @@ def expert_layer(w, p, cfg, h, valid, kernel=False):
         y, stats = routed(h, w[p + 'moe_fc1_w'], w[p + 'moe_fc3_w'],
                           w[p + 'moe_fc2_w'], picks, wts, valid, moe,
                           kernel)
+    if not _n_shared(moe):
+        return y, stats
     with jax.named_scope('moe.shared'):
         y = y + swiglu(h, w[p + 'moe_shared_fc1_w'],
                        w[p + 'moe_shared_fc3_w'], w[p + 'moe_shared_fc2_w'])

@@ -50,7 +50,11 @@ attend (the pool's layer axis, `page_bytes`), ``recurrent_layers`` the
 layers that hold state (the layer axis of ``ssm`` and ``conv``), and
 each layer knows its index on its own axis.  Left out,
 ``recurrent_layers`` is ``layers``: the ``falcon_h1`` block, every
-layer of which does both.
+layer of which does both.  The layers that attend may as well be GQA
+layers over the K and V pools of the first geometry (``cfg['mixer']``
+``'gqa'``), and a recurrent layer's state may be a convolution tail
+ALONE (shortconv.py: ``recurrent`` ``(None, tail shape)``): then there
+is a ``conv`` array and no ``ssm``.
 
 A model with LATENT attention (decode.py's ``latent_moe`` block,
 latent.py) keeps the second pool geometry, ``CacheConfig.latent``: ONE
@@ -94,7 +98,9 @@ class CacheConfig(object):
     reserved garbage page 0; ``page_len`` tokens per page (must divide
     ``max_len``); ``quant`` is ``'none'`` or ``'int8'``; ``recurrent``
     is None or the (scan state, convolution tail) shapes of one slot in
-    one layer (`ssm.state_shapes`, `kda.state_shapes`), both float32.
+    one layer (`ssm.state_shapes`, `kda.state_shapes`), both float32;
+    the scan state's may be None (`shortconv.state_shapes`: a mixer that
+    keeps its tail and nothing else).
 
     ``layers`` is the pool's layer axis: the layers that ATTEND.
     ``recurrent_layers`` is the recurrent state's: the layers that hold
@@ -141,7 +147,8 @@ class CacheConfig(object):
             raise ValueError("quant must be 'none' or 'int8', got %r"
                              % (quant,))
         self.recurrent = None if recurrent is None else tuple(
-            tuple(int(n) for n in shape) for shape in recurrent)
+            None if shape is None else tuple(int(n) for n in shape)
+            for shape in recurrent)
         self.recurrent_layers = (self.layers if recurrent_layers is None
                                  else int(recurrent_layers))
         self.latent = None if latent is None else int(latent)
@@ -206,8 +213,9 @@ class CacheConfig(object):
         if self.recurrent is None:
             return {}
         lead = (self.slots, self.recurrent_layers)
-        return {'ssm': lead + self.recurrent[0],
-                'conv': lead + self.recurrent[1]}
+        return {name: lead + shape
+                for name, shape in zip(('ssm', 'conv'), self.recurrent)
+                if shape is not None}
 
     def recurrent_bytes(self):
         """Bytes of the recurrent state (float32), every slot's."""

@@ -64,8 +64,9 @@ def test_phase_kernels(monkeypatch):
     out = chip_smoke.kernels(TINY['kernels'])
     assert sorted(out) == ['expert_route', 'flash_resident',
                            'flash_streamed', 'kda_step', 'latent_attention',
-                           'latent_prefill', 'ssm_step', 'tile_loop',
-                           'wall_s']
+                           'latent_prefill', 'paged_narrow', 'ssm_step',
+                           'tile_loop', 'wall_s']
+    assert out['paged_narrow']['slots'] == 3
     assert sorted(out['expert_route']) == ['with_rows_3', 'with_rows_8']
     assert sorted(out['expert_route']['with_rows_8']['ms']) == [
         'batched', 'grouped', 'grouped_ragged_dot', 'unbatched']

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.ops.attention import (cached_attention, paged_attention,
                                       paged_attention_eligible,
-                                      paged_attention_rows)
+                                      paged_attention_rows, paged_pool_heads)
 from paddle_tpu.serving.generation import CacheConfig
 from paddle_tpu.serving.generation.decode import _logical_rows
 
@@ -147,6 +147,113 @@ def test_pages_no_live_length_covers_are_not_read(dtype):
     assert paged_attention_rows(n, PL) == (1 + 3 + 0 + 2) * PL
 
 
+# ----------------------------------- a head narrower than the 128 lanes
+
+def _narrow(lengths, heads, hkv, dh, dtype, poison=False):
+    """(paged over the pool `paged_pool_heads` lays out, gather then
+    attend with the kv heads apart, n) for one layer of a pool whose kv
+    heads lie side by side in a row."""
+    slots = len(lengths)
+    hp, wide = paged_pool_heads(hkv, dh)
+    cache = CacheConfig(slots=slots, layers=LAYERS, kv_heads=hp,
+                        max_len=MAX_LEN, head_dim=wide, dtype=dtype,
+                        page_len=PL)
+    rng = np.random.RandomState(3)
+    st = {n: jnp.asarray(rng.randn(*cache.pool_shape), jnp.dtype(dtype))
+          for n in ('k', 'v')}
+    bt = _tables(slots, cache.pages)
+    n = np.asarray(lengths, np.int32)
+    if poison:
+        covered = np.zeros(cache.pages, bool)
+        for s, length in enumerate(lengths):
+            covered[bt[s, :cache.pages_for(length)]] = True
+        dead = jnp.asarray(~covered)[:, None, None, None, None]
+        st = {k: jnp.where(dead, jnp.nan, v) for k, v in st.items()}
+    q = jnp.asarray(rng.randn(slots, heads, dh), jnp.dtype(dtype))
+    got = paged_attention(q, st['k'], st['v'], jnp.asarray(bt),
+                          jnp.asarray(n), 1, pages_per_block=2)
+
+    def apart(pool):
+        rows = jnp.nan_to_num(pool)[jnp.asarray(bt), 1]  # [S, M, PL, hp, w]
+        return rows.reshape(slots, MAX_LEN, hkv, dh).transpose(0, 2, 1, 3)
+
+    want = cached_attention(q[:, :, None], apart(st['k']), apart(st['v']),
+                            jnp.asarray(n)[:, None] - 1)[:, :, 0]
+    return np.asarray(got, np.float32), np.asarray(want, np.float32), n
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('heads,hkv,dh', [(32, 8, 64), (8, 2, 64),
+                                          (8, 4, 32), (4, 4, 32)],
+                         ids=['lfm2_32q8kv64', 'gqa4to1_64', 'gqa2to1_32',
+                              'mha_32'])
+@pytest.mark.parametrize('case', sorted(RAGGED))
+def test_a_narrow_head_attends_over_kv_heads_side_by_side(case, heads, hkv,
+                                                          dh, dtype):
+    """A head of 64 (of 32) lies two (four) kv heads to a 128-lane row of
+    the pool; the kernel is the same, each query beside zeros in the
+    lanes of the other kv heads of its row."""
+    assert paged_pool_heads(hkv, dh) == (hkv * dh // 128, 128)
+    got, want, n = _narrow(RAGGED[case], heads, hkv, dh, dtype)
+    assert got.shape == (len(n), heads, dh)
+    _assert_close(got, want, n, dtype)
+
+
+def test_a_narrow_head_reads_no_page_a_live_length_does_not_cover():
+    got, want, n = _narrow([1, 9, 0, 2 * PL], 32, 8, 64, 'float32',
+                           poison=True)
+    clean, _, _ = _narrow([1, 9, 0, 2 * PL], 32, 8, 64, 'float32')
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
+    _assert_close(got, want, n, 'float32')
+
+
+def test_the_pool_layout_is_read_off_the_head(monkeypatch):
+    """Whole lane tiles keep their own geometry; 64 and 32 lie side by
+    side where the kv heads come in such groups; a head of 96 has no
+    layout: on an accelerator the rule answers False and the step takes
+    the composed path."""
+    from paddle_tpu.ops import _pallas
+    assert paged_pool_heads(8, 128) == (8, 128)
+    assert paged_pool_heads(4, 256) == (4, 256)
+    assert paged_pool_heads(8, 64) == (4, 128)
+    assert paged_pool_heads(8, 32) == (2, 128)
+    assert paged_pool_heads(3, 64) == (3, 64)        # no pair to lie beside
+    assert paged_pool_heads(8, 96) == (8, 96)
+    monkeypatch.setattr(_pallas, 'interpret', lambda: False)
+    for dtype in ('bfloat16', 'float32'):
+        assert paged_attention_eligible(
+            (9, 2, 16) + paged_pool_heads(8, 64), dtype)
+        assert not paged_attention_eligible((9, 2, 16, 8, 64), dtype)
+        assert not paged_attention_eligible(
+            (9, 2, 16) + paged_pool_heads(8, 96), dtype)
+        assert not paged_attention_eligible(
+            (9, 2, 16) + paged_pool_heads(3, 64), dtype)
+
+
+def test_a_head_of_96_takes_the_composed_path(monkeypatch):
+    """A `gqa` mixer whose head has no layout: the runtime reads the
+    rule, the step gathers, and `generation.kv_rows_read` counts every
+    slot's ``max_len``."""
+    from paddle_tpu.ops import _pallas
+    from paddle_tpu.serving.generation import DecodeRuntime, random_weights
+    cfg = {'block': 'latent_moe', 'vocab': 61, 'd_model': 32, 'n_layer': 2,
+           'n_head': 2, 'n_kv_head': 1, 'head_dim': 96, 'd_ffn': 48,
+           'theta': 1e4, 'max_len': 16, 'mixer': ['conv', 'gqa'],
+           'ffn': ['dense', 'dense'], 'conv': {'taps': 3}}
+    w = random_weights(cfg, seed=1, scale=0.2)
+    paged = DecodeRuntime(w, cfg, slots=2, prefill_chunk=4, page_len=4)
+    assert paged.paged and paged._gathered is None     # interpret mode
+    monkeypatch.setattr(_pallas, 'interpret', lambda: False)
+    composed = DecodeRuntime(w, cfg, slots=2, prefill_chunk=4, page_len=4)
+    monkeypatch.undo()
+    assert not composed.paged and composed._gathered == 2 * 16
+    assert composed.cache.pool_shape == (9, 1, 4, 1, 96)
+    prompt = np.arange(1, 8)
+    assert composed.generate(prompt, 5, steps_per_window=2) \
+        == paged.generate(prompt, 5, steps_per_window=2)
+
+
 def test_rows_fetched_are_whole_pages_of_active_slots():
     assert paged_attention_rows([0, 1, 8, 9], 8) == 0 + 8 + 8 + 16
     assert paged_attention_rows(np.zeros(4, int), 8) == 0
@@ -211,6 +318,45 @@ def test_mosaic_compiles_the_kernel_at_real_widths(
                      or ' fusion(' in ln)]
     # nothing of the pool's size is made: the output is the queries' size
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_mosaic_compiles_the_kernel_over_a_head_of_64(one_v5e_chip,
+                                                      monkeypatch):
+    """lfm2_8b_a1b.many_streams_medium_prompts' pool: 32 query / 8 kv
+    heads of 64, pages of 16, four attention layers, two kv heads to a
+    row.  The pool reaches the kernel as it lies (no copy, nothing of its
+    size made); the queries are widened and the result narrowed outside
+    the kernel, arrays of the queries' size."""
+    import jax
+    from paddle_tpu.ops import _pallas
+    monkeypatch.setattr(_pallas, 'interpret', lambda: False)
+    slots, heads = 96, 32
+    hp, wide = paged_pool_heads(8, 64)
+    cache = CacheConfig(slots=slots, layers=4, kv_heads=hp, max_len=3584,
+                        head_dim=wide, dtype='bfloat16', page_len=16,
+                        pages=21505)
+    assert cache.pool_shape == (21505, 4, 16, 4, 128)
+    assert cache.page_bytes() == 4 * 16 * 2 * 8 * 64 * 2
+    assert paged_attention_eligible(cache.pool_shape, 'bfloat16')
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
+                                    sharding=one_v5e_chip)
+
+    compiled = jax.jit(
+        lambda q, k, v, bt, n: paged_attention(q, k, v, bt, n, 3)).lower(
+            sds((slots, heads, 64), 'bfloat16'),
+            sds(cache.pool_shape, 'bfloat16'),
+            sds(cache.pool_shape, 'bfloat16'),
+            sds((slots, cache.max_pages), 'int32'),
+            sds((slots,), 'int32')).compile()
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 1
+    pool = '[%d,' % cache.pages
+    assert not [ln for ln in text.splitlines() if pool in ln.split('(')[0]
+                and (' copy(' in ln or 'copy-start(' in ln
+                     or ' fusion(' in ln)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
 
 
 def test_mosaic_compiles_the_latent_kernel_at_real_widths(one_v5e_chip,
