@@ -62,7 +62,7 @@ SIZES = {
             ssm_step=dict(state=(32, 6, 32, 128, 256), groups=2, live=15),
             # the kimi_linear cell's matrix state a slot (32 slots of its
             # 128: the check holds four host copies), 20 live
-            kda_step=dict(state=(32, 6, 32, 128, 128), live=20),
+            kda_step=dict(state=(32, 6, 32, 128, 128), taps=4, live=20),
             # the axk1 cell's latent pool: 64 heads over one 640-wide row
             latent=dict(slots=8, heads=64, v_dim=512, width=640, page_len=16,
                         pages=2049, layers=2, max_pages=449,
@@ -107,7 +107,7 @@ SIZES = {
                        seq_resident=128, seq_streamed=256),
             tile_loop=dict(batch=8, heads=2, seq=16, head_dim=8, tiles=4),
             ssm_step=dict(state=(4, 2, 8, 16, 128), groups=2, live=2),
-            kda_step=dict(state=(4, 2, 3, 8, 8), live=2),
+            kda_step=dict(state=(4, 2, 3, 8, 8), taps=4, live=2),
             latent=dict(slots=4, heads=4, v_dim=128, width=256, page_len=4,
                         pages=41, layers=2, max_pages=6,
                         lengths=(0, 1, 13, 24), dtype='float32', tol=2e-5),
@@ -520,64 +520,76 @@ def _ssm_step_check(cfg):
 
 
 def _kda_step_check(cfg):
-    """`kda_step` (one decode step of the delta rule, in place over the
-    live slots) against the token form a slot at a time, on one layer of
-    a whole state array: no slot live (the kernel visits one block and
-    must hand it back), one, and ``live`` of them."""
+    """`kda_step` (one decode step of a `kda` layer from the projections
+    on, in place over the live slots' state and convolution tails)
+    against the convolution, silu, the unit norms and the delta rule
+    written out over every slot, on one layer of the whole arrays: no
+    slot live (the kernel visits one block of each and must hand it
+    back), one, and ``live`` of them."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.serving.generation import kda
     shape = tuple(cfg['state'])
     S, L, H, d, _ = shape
-    assert kda.kda_step_eligible(shape, jnp.float32), \
+    K = cfg['taps']
+    tails_shape = (S, L, K - 1, 3 * H, d)
+    assert kda.kda_step_eligible(shape, tails_shape, jnp.float32), \
         'smoke shape is not eligible'
     layer = L // 2
     rng = np.random.RandomState(SEED)
     f32 = jnp.float32
-
-    def unit(a):
-        return a / np.linalg.norm(a, axis=-1, keepdims=True)
-
+    x = jnp.asarray(rng.randn(S, 3 * H * d), f32)
+    taps = jnp.asarray(rng.randn(K, 3 * H * d) / K ** 0.5, f32)
     a = jnp.asarray(np.exp(-0.01 * np.abs(rng.randn(S, H, d))), f32)
-    k = jnp.asarray(unit(rng.randn(S, H, d)), f32)
-    q = jnp.asarray(unit(rng.randn(S, H, d)) * d ** -0.5, f32)
-    v = jnp.asarray(rng.randn(S, H, d), f32)
     beta = jnp.asarray(rng.rand(S, H), f32)
 
     def fresh():
-        return jax.random.normal(jax.random.key(SEED), shape, f32)
+        k1, k2 = jax.random.split(jax.random.key(SEED))
+        return (jax.random.normal(k1, shape, f32),
+                jax.random.normal(k2, tails_shape, f32))
 
-    def kernel(state, active):
-        return kda.kda_step(a, k, q, v, beta, state, layer, active)
+    def kernel(state, tails, active):
+        return kda.kda_step(x, taps, a, beta, state, tails, layer, active)
 
-    compiled = jax.jit(kernel, donate_argnums=(0,)).lower(
-        fresh(), jnp.zeros(S, bool)).compile()
+    compiled = jax.jit(kernel, donate_argnums=(0, 1)).lower(
+        *fresh(), jnp.zeros(S, bool)).compile()
     _assert_mosaic('kda_step', compiled.as_text().count('tpu_custom_call'),
                    1)
-    before = np.asarray(fresh())
+    before = [np.asarray(b) for b in fresh()]
 
-    def plain(S0):                         # every slot, elementwise in f32
+    def plain(S0, tail):                   # every slot, elementwise in f32
+        full = jnp.concatenate([tail.reshape(S, K - 1, -1), x[:, None]], 1)
+        conv = jnp.sum(full * taps, axis=1)
+        y = (conv * jax.nn.sigmoid(conv)).reshape(S, 3, H, d)
+        q, k = (y[:, c] * jax.lax.rsqrt(
+            jnp.sum(y[:, c] ** 2, -1, keepdims=True) + 1e-6)
+            for c in range(2))
+        q, v = q * d ** -0.5, y[:, 2]
         Sd = a[..., None] * S0
         u = beta[..., None] * (v - jnp.sum(Sd * k[..., None], axis=-2))
         new = Sd + k[..., None] * u[:, :, None, :]
-        return jnp.sum(new * q[..., None], axis=-2), new
+        return jnp.sum(new * q[..., None], axis=-2), new, \
+            full[:, 1:].reshape(tail.shape)
 
-    want_o, want_S = (np.asarray(x) for x in jax.jit(plain)(
-        before[:, layer]))
+    want_o, want_S, want_tail = (np.asarray(r) for r in jax.jit(plain)(
+        before[0][:, layer], before[1][:, layer]))
     out = {}
     for n in (0, 1, cfg['live']):
         active = np.zeros(S, bool)
         active[rng.permutation(S)[:n]] = True
-        o, state = (np.asarray(x) for x in compiled(fresh(),
-                                                    jnp.asarray(active)))
+        o, state, tails = (np.asarray(r) for r in compiled(
+            *fresh(), jnp.asarray(active)))
         # what the kernel did not visit is what it was, bit for bit
         untouched = np.ones((S, L), bool)
         untouched[active, layer] = False
-        np.testing.assert_array_equal(state[untouched], before[untouched])
+        np.testing.assert_array_equal(state[untouched], before[0][untouched])
+        np.testing.assert_array_equal(tails[untouched], before[1][untouched])
         np.testing.assert_array_equal(o[~active], 0.0)
         if n:
             _close('kda_step state', state[active, layer], want_S[active],
                    1e-5)
+            _close('kda_step tails', tails[active, layer],
+                   want_tail[active], 1e-6)
             out['o_err_live_%d' % n] = float('%.2e' % _close(
                 'kda_step o', o[active], want_o[active], 1e-5))
     return out

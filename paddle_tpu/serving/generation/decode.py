@@ -133,16 +133,17 @@ ATTEND in at least one layer, through ``'latent'`` layers or through
 ``cfg['latent']``).  A ``'kda'`` layer is Kimi Delta
 Attention (kda.py): a float32 matrix state a head and three short
 convolutions' tails, per slot, in the recurrent arrays of the state
-dict, and NO rows in the pool.  The pool's layer axis then counts the
+dict (``[slots, layers, H, d, d]`` and ``[slots, layers, d_conv - 1,
+3 H, d]``), and NO rows in the pool.  The pool's layer axis then counts the
 layers that attend and the state's the layers that hold state
 (`CacheConfig.layers` / ``recurrent_layers``; `_layer_axes` gives each
 layer its index on its own).  Such a model is `recurrent` like
 ``falcon_h1``: a chunk starts from the slot's state (zeros at offset
 0), a step advances the live slots' and keeps the dead ones' bit for
 bit, and the runtime takes no prefix-cache hit, no speculative window
-and no ring prefill.  Its launches' stats carry two counts more
-(`_launch_stats`: the state the windows moved, the tokens the chunk
-scan took).  A model without the key lowers to the program it had
+and no ring prefill.  Its launches' stats carry three counts more
+(`_launch_stats`: the state and the tails the windows moved, the tokens
+the chunk scan took).  A model without the key lowers to the program it had
 (tests/test_generation_kda.py pins the text).
 
 Two more mixers may stand there.  ``'gqa'`` is the dense block's own
@@ -720,8 +721,11 @@ _LAUNCH_STATS = _experts.STATS + ('latent_rows_read',)
 # is counted on the host, `DecodeRuntime._window_rows_read`)
 # and, of a model with `kda` layers, behind them: slot-layers whose matrix
 # state a window's steps read and wrote (`DecodeRuntime._count_stats`
-# turns them into bytes), tokens a chunk's scan took
-_KDA_STATS = ('kda_state_bytes', 'kda_chunk_tokens')
+# turns them into bytes), tokens a chunk's scan took, slot-layers whose
+# convolution tails the steps read and wrote (bytes likewise)
+_KDA_STATS = ('kda_state_bytes', 'kda_chunk_tokens', 'kda_tail_bytes')
+_KDA_BYTES = {'kda_state_bytes': _kda.state_bytes,
+              'kda_tail_bytes': _kda.tail_bytes}
 
 
 def _launch_stats(cfg):
@@ -993,7 +997,8 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False,
             handed = [stats, rows.astype(jnp.int32).reshape(1)]
             if with_kda:
                 handed.append(jnp.stack([jnp.int32(0),
-                                         true_count.astype(jnp.int32)]))
+                                         true_count.astype(jnp.int32),
+                                         jnp.int32(0)]))
             return st, nxt, logits, jnp.concatenate(handed)
         return st, nxt, logits
 
@@ -1020,9 +1025,10 @@ def _step_fn(cfg, cache, paged, state_kernel, experts_kernel=False):
     (absorbed; with ``paged`` over the latent pool in place through
     `ops.attention.latent_attention`), then the layer's feed-forward
     kind, where a slot that rides along routes nowhere; a ``'kda'``
-    layer advances the live slots' matrix state (`kda.step_mixer`: in
-    place through `kda.kda_step` with ``state_kernel``, else every slot
-    steps and a dead one's state is kept), a ``'conv'`` layer their
+    layer advances the live slots' matrix state and convolution tails
+    (`kda.step_mixer`: both in place through `kda.kda_step` with
+    ``state_kernel``, else every slot steps and a dead one's are kept), a
+    ``'conv'`` layer their
     tails (`shortconv.step_mixer`), a ``'gqa'`` layer attends as the
     dense block does (`_gqa_step`).  Its step returns a third
     value, the step's `_launch_stats`; ``experts_kernel``
@@ -1059,14 +1065,10 @@ def _step_fn(cfg, cache, paged, state_kernel, experts_kernel=False):
                     j = axis[i]
                     if mixers[i] == 'kda':
                         # an inactive slot keeps both kinds of state
-                        att, matrices, tail = _kda.step_mixer(
+                        att, matrices, tails = _kda.step_mixer(
                             w, 'layer_%d_' % i, cfg, h, st['ssm'], j,
-                            st['conv'][:, j], active, state_kernel)
-                        st = dict(
-                            st, ssm=matrices,
-                            conv=st['conv'].at[:, j].set(jnp.where(
-                                active[:, None, None], tail,
-                                st['conv'][:, j])))
+                            st['conv'], active, state_kernel)
+                        st = dict(st, ssm=matrices, conv=tails)
                     elif mixers[i] == 'conv':
                         att, tail = _shortconv.step_mixer(
                             w, 'layer_%d_' % i, cfg, h, st['conv'][:, j],
@@ -1137,13 +1139,12 @@ def _step_fn(cfg, cache, paged, state_kernel, experts_kernel=False):
             handed = [stats,
                       jnp.asarray(n_latent * rows, jnp.int32).reshape(1)]
             if n_kda:
-                # the kernel moves the live slots' state in every `kda`
-                # layer, the composed step every slot's (kda.py)
-                moved = jnp.sum(active, dtype=jnp.int32) if state_kernel \
-                    else S
-                handed.append(jnp.stack(
-                    [jnp.asarray(n_kda * moved, jnp.int32),
-                     jnp.int32(0)]))
+                # the kernel moves the live slots' state and tail in every
+                # `kda` layer, the composed step every slot's (kda.py)
+                moved = jnp.asarray(n_kda * (
+                    jnp.sum(active, dtype=jnp.int32) if state_kernel
+                    else S), jnp.int32)
+                handed.append(jnp.stack([moved, jnp.int32(0), moved]))
             return st, nxt, jnp.concatenate(handed)
         return st, nxt
 
@@ -1455,9 +1456,11 @@ class DecodeRuntime(object):
             # (float32, one device)
             # (a model whose state is convolution tails alone has none)
             self.state_kernel = 'ssm' in self.state and (
-                _kda.kda_step_eligible if self.latent_moe
-                else _ssm.ssm_step_eligible)(
-                    self.state['ssm'].shape, self.state['ssm'].dtype, mesh)
+                _kda.kda_step_eligible(
+                    self.state['ssm'].shape, self.state['conv'].shape,
+                    self.state['ssm'].dtype, mesh) if self.latent_moe
+                else _ssm.ssm_step_eligible(
+                    self.state['ssm'].shape, self.state['ssm'].dtype, mesh))
             # and a latent chunk's scores: on chip where that kernel
             # can run, else through HBM a block at a time
             self.prefill_kernel = self.cache.latent is not None \
@@ -1856,9 +1859,9 @@ class DecodeRuntime(object):
             return
         for kind, stats in mine:
             for name, n in zip(self._stat_names, np.asarray(stats)):
-                if name == 'kda_state_bytes':
+                if name in _KDA_BYTES:
                     # counted in slot-layers: each read once, written once
-                    n = int(n) * 2 * _kda.state_bytes(self.cfg['kda'])
+                    n = int(n) * 2 * _KDA_BYTES[name](self.cfg['kda'])
                 _obs.metrics.counter('generation.' + name).inc(int(n))
                 if kind == 'window':
                     _obs.metrics.counter(

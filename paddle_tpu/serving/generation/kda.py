@@ -27,7 +27,10 @@ and goes when drawn weights can be given a mean where they are drawn
 
 What a stream KEEPS a layer is recurrent state beside its pages
 (kv_cache.py): ``S`` ``[H, d, d]`` float32 and the last ``d_conv - 1``
-rows of the three convolutions' inputs (q, k and v side by side).
+rows of the three convolutions' inputs, the TAIL, ``[d_conv - 1, 3 H,
+d]`` float32: a kept row is q's heads, k's, v's as ``3 H`` rows of
+``d`` (`state_shapes`), so that one slot's tail of one layer is whole
+``(8, 128)`` tiles, one DMA, and a head's part of it a row of lanes.
 Nothing grows with the context.  This module only maps (input, state)
 to (output, state).
 
@@ -57,19 +60,28 @@ formed.  A pad position (past ``true_count``) gets ``g = 0`` and
 convolution's tail is cut at ``true_count``.
 
 `step_mixer` is the single step of a decode window over the WHOLE state
-array ``[slots, layers, ...]``: a live slot's state of that layer
-advances, a dead slot's stays bit for bit what it was.  The step has
-two routes, as ssm.py's has.  `kda_step` is a Pallas kernel that
-updates the state array IN PLACE: it walks the live slots only (their
-indices are scalar prefetch, `ssm._live_slots`), reads a live slot's
-``[H, d, d]`` of that layer once and writes it once, and takes ``r =
-S^T k`` and ``o = S^T q`` from the registers that hold the state.  The
+and tail arrays ``[slots, layers, ...]``: a live slot's state and tail
+of that layer advance, a dead slot's stay bit for bit what they were.
+The matrix products (the projections, the gates' four, the output's)
+are XLA's on every slot.  What lies between them has two routes, as
+ssm.py's step has.  `kda_step` is a Pallas kernel that updates both
+arrays IN PLACE: it walks the live slots only (their indices are scalar
+prefetch, `ssm._live_slots`) and takes the step's raw projections; for
+a live slot it reads the ``d_conv - 1`` kept rows and ``[H, d, d]`` of
+that layer once, convolves, writes the tail back a row further, applies
+silu and the unit norms, turns a, k and q into columns (one transpose a
+slot: they scale ROWS of the state), and takes ``r = S^T k`` and ``o =
+S^T q`` from the registers that hold the state, which it writes once.
+So a step touches 2 x (``4 H d d`` + ``4 (d_conv - 1) 3 H d``) bytes a
+live slot a layer and nothing of any other slot
+(`generation.kda_state_bytes`, `generation.kda_tail_bytes`).  The
 composed step is the same arithmetic in plain jax.numpy over every slot
-(``S^T (a k)`` and ``S^T (a q)`` in one pass, since ``o = (a S)^T q +
-(k . q) u``; then the update): the route under a mesh or for a state
-the kernel cannot tile (`kda_step_eligible`, a static rule on the
-state's shape, dtype and the mesh), and what the kernel is tested
-against.
+(the tail rejoined with the new row and sliced; ``S^T (a k)`` and ``S^T
+(a q)`` in one pass, since ``o = (a S)^T q + (k . q) u``; then the
+update; a dead slot's state and tail kept by a select): the route under
+a mesh or for extents the kernel cannot tile (`kda_step_eligible`, a
+static rule on the state's and the tails' shape, the dtype and the
+mesh), and what the kernel is tested against.
 
 Everything of the recurrence is float32 and its products run at
 `highest` precision (a few per cent of a chunk's matrix work), so that
@@ -84,7 +96,7 @@ from .ssm import _live_slots
 
 __all__ = ['SLOTS', 'weight_shapes', 'state_shapes', 'conv_channels',
            'prefill_mixer', 'step_mixer', 'chunk_scan', 'token_scan',
-           'state_bytes', 'kda_step', 'kda_step_eligible']
+           'state_bytes', 'tail_bytes', 'kda_step', 'kda_step_eligible']
 
 # the mixer's weights of one layer, after `layer_<i>_`.  The head
 # norm's scale ends in `norm`: whoever draws weights makes such a name
@@ -124,9 +136,12 @@ def weight_shapes(d_model, kda):
 
 
 def state_shapes(kda):
-    """(matrix state, convolution tail) of ONE slot in ONE layer."""
+    """(matrix state, convolution tail) of ONE slot in ONE layer.  A
+    tail row is the three convolutions' input of one position, q's heads,
+    k's, v's, as ``3 H`` rows of ``d``: whole tiles a slot, where ``[K-1,
+    3 H d]`` would be ``K-1`` sublanes of a tile of eight."""
     H, d = _dims(kda)
-    return ((H, d, d), (int(kda['d_conv']) - 1, conv_channels(kda)))
+    return ((H, d, d), (int(kda['d_conv']) - 1, 3 * H, d))
 
 
 def state_bytes(kda):
@@ -134,6 +149,12 @@ def state_bytes(kda):
     a step must read once and write once for a live stream."""
     H, d = _dims(kda)
     return 4 * H * d * d
+
+
+def tail_bytes(kda):
+    """Bytes of the convolution tail of one slot in one layer (float32):
+    what a step must read once and write once for a live stream."""
+    return 4 * (int(kda['d_conv']) - 1) * conv_channels(kda)
 
 
 def _project(w, p, h):
@@ -349,10 +370,11 @@ def _sizes(C):
 
 
 def prefill_mixer(w, p, cfg, h, S0, tail, true_count):
-    """One slot, one prefill chunk: h [C, D] normalised, S0 and tail the
-    slot's state of this layer (zeros where the prompt begins).  Returns
-    (out [C, D] float32, S, tail), the state as position ``true_count -
-    1`` leaves it; rows of ``out`` past it are padding's."""
+    """One slot, one prefill chunk: h [C, D] normalised, S0 [H, d, d] and
+    tail [K-1, 3 H, d] the slot's state of this layer (zeros where the
+    prompt begins).  Returns (out [C, D] float32, S, tail), the state as
+    position ``true_count - 1`` leaves it, in the extents it came in;
+    rows of ``out`` past it are padding's."""
     import jax
     import jax.numpy as jnp
     kda = cfg['kda']
@@ -361,10 +383,11 @@ def prefill_mixer(w, p, cfg, h, S0, tail, true_count):
     g, beta, gate = _gates(w, p, kda, h)
     with jax.named_scope('kda.conv'):
         taps = _taps(w, p)
-        full = jnp.concatenate([tail, x], axis=0)          # [K-1+C, ch]
+        full = jnp.concatenate([tail.reshape(tail.shape[0], -1), x],
+                               axis=0)                     # [K-1+C, ch]
         conv = sum(full[j:j + C] * taps[j] for j in range(taps.shape[0]))
-        tail = jax.lax.dynamic_slice_in_dim(full, true_count,
-                                            taps.shape[0] - 1)
+        tail = jax.lax.dynamic_slice_in_dim(
+            full, true_count, taps.shape[0] - 1).reshape(tail.shape)
     with jax.named_scope('kda.scan'):
         q, k, v = _heads(conv, kda)
         real = jnp.arange(C) < true_count
@@ -376,155 +399,203 @@ def prefill_mixer(w, p, cfg, h, S0, tail, true_count):
 
 # ------------------------------------------------ the step, in place
 
-# bytes of one slot's [H, d, d] state of one layer, the kernel's tile: it
-# holds four (in and out, double buffered)
+# bytes of one slot's [H, d, d] state of one layer, the kernel's largest
+# tile: it holds four (in and out, double buffered), and four of the
+# slot-layer's tail beside them
 _STEP_TILE_BYTES = 2 << 20
 
 
-def kda_step_eligible(state_shape, dtype, mesh=None):
+def kda_step_eligible(state_shape, tails_shape, dtype, mesh=None):
     """Static rule for `kda_step` over a ``[slots, layers, H, d, d]``
-    state: float32 on a single device; on an accelerator a head's ``[d,
-    d]`` must be whole tiles and one slot's heads must fit the kernel's
-    buffers."""
+    state and its ``[slots, layers, K-1, 3 H, d]`` tails: float32 on a
+    single device; on an accelerator a head's ``[d, d]``, the heads' ``[H,
+    d]`` and so a tail row's ``[3 H, d]`` must be whole tiles, and one
+    slot's heads (with its tail, which must be the smaller) must fit the
+    kernel's buffers."""
     import jax.numpy as jnp
     if jnp.dtype(dtype) != jnp.float32 or not _pallas.single_device(mesh):
         return False
     if _pallas.interpret():
         return True
     _slots, _layers, H, d, _ = state_shape
-    return d % 128 == 0 and H * d * d * 4 <= _STEP_TILE_BYTES
+    return d % 128 == 0 and H % 8 == 0 \
+        and math.prod(tails_shape[2:]) <= H * d * d <= _STEP_TILE_BYTES // 4
 
 
-def _kda_step_kernel(order_ref, count_ref, layer_ref, cols_ref, rows_ref,
-                     s_ref, out_ref, o_ref):
+def _kda_step_kernel(order_ref, count_ref, layer_ref, x_ref, taps_ref, a_ref,
+                     beta_ref, s_ref, tail_ref, s_out, tail_out, o_ref,
+                     cols_ref, v_ref):
+    import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     del order_ref, layer_ref              # the index maps read them
+    H, d = a_ref.shape
+    K = taps_ref.shape[0]
     pos, count = pl.program_id(0), count_ref[0]
 
     @pl.when(pos < count)
     def _():
-        for j in range(s_ref.shape[0]):
-            # a head's decay, key and query down the sublanes [d, 1]
-            a, k, q = (cols_ref[c, :, j:j + 1] for c in range(3))
+        # the three convolutions over the slot's K-1 kept rows and the new
+        # one, q's heads, k's, v's down the sublanes [3 H, d]; the tail
+        # moves up a row in place
+        x = x_ref[...]
+        conv = x * taps_ref[K - 1]
+        for j in range(K - 1):
+            conv = conv + tail_ref[j] * taps_ref[j]
+        for j in range(K - 2):
+            tail_out[j] = tail_ref[j + 1]
+        tail_out[K - 2] = x
+        y = conv * jax.nn.sigmoid(conv)                      # silu
+
+        def unit(r):
+            return r * jax.lax.rsqrt(
+                jnp.sum(r * r, axis=-1, keepdims=True) + 1e-6)
+
+        # a head's decay, key and query scale ROWS of its state: turned
+        # once a slot so that a head's is a column down the sublanes
+        cols_ref[0] = a_ref[...].T
+        cols_ref[1] = unit(y[H:2 * H]).T
+        cols_ref[2] = (unit(y[:H]) * d ** -0.5).T
+        v_ref[...] = y[2 * H:]
+        for j in range(H):
+            a, k, q = (cols_ref[c, :, j:j + 1] for c in range(3))   # [d, 1]
             S = a * s_ref[j]
             r = jnp.sum(k * S, axis=0, keepdims=True)        # [1, d]
-            u = rows_ref[0, j:j + 1, :] - rows_ref[1, j:j + 1, :] * r
+            u = beta_ref[:, j:j + 1] * (v_ref[j:j + 1, :] - r)
             S = S + k * u
-            out_ref[j] = S
+            s_out[j] = S
             o_ref[j:j + 1, :] = jnp.sum(q * S, axis=0, keepdims=True)
 
-    # no live slot: every grid position maps to ONE block, which goes
-    # back as it came
+    # no live slot: every grid position maps to ONE block of the state and
+    # one of the tails, which go back as they came
     @pl.when((count == 0) & (pos == 0))
     def _():
-        out_ref[...] = s_ref[...]
+        s_out[...] = s_ref[...]
+        tail_out[...] = tail_ref[...]
 
 
-def kda_step(a, k, q, v, beta, state, layer, active):
-    """One step of the delta rule for the LIVE slots, on the state array
-    in place.
+def kda_step(x, taps, a, beta, state, tails, layer, active):
+    """One step of a `kda` layer's recurrence for the LIVE slots, from
+    the projections on, on the state and the tails in place.
 
-    a (the decay, ``exp(g)``), k, q, v [S, H, d], beta [S, H], float32;
-    state [S, layers, H, d, d] float32, WHOLE: it is aliased to the
-    second result, so under donation XLA neither slices nor copies it;
-    layer an int32 scalar; active [S] bool, the live slots.  Returns (o
-    [S, H, d], state): the composed step's arithmetic for the live
-    slots; every other slot's state, and every other layer's, is not
+    x [S, 3 H d] the step's projections (`_project`), taps [K, 3 H d]
+    (`_taps`), a (the decay, ``exp(g)``) [S, H, d], beta [S, H], float32;
+    state [S, layers, H, d, d] and tails [S, layers, K-1, 3 H, d] float32,
+    WHOLE: each is aliased to a result, so under donation XLA neither
+    slices nor copies them; layer an int32 scalar; active [S] bool, the
+    live slots.  Returns (o [S, H, d], state, tails).  For a live slot
+    the kernel reads the ``K-1`` kept rows of that layer, convolves them
+    with the new row, writes the tail back a row further, applies silu,
+    brings q and k to unit length a head (q by ``d^(-1/2)``) and runs the
+    delta rule on the heads' state: `step_mixer`'s composed arithmetic.
+    Every other slot's state and tail, and every other layer's, is not
     touched, and a slot that is not live gets zeros for o.
 
     The grid walks the live slots' indices, compacted to the front
     (scalar prefetch); a position past the live count repeats the last
     live slot's index, so nothing is fetched or written for it.  The
+    filters are one block for every position, fetched once.  The
     vectors that scale ROWS of a head's state (a, k, q: one value a key
-    channel) come in transposed, ``[S, 3, d, H]``, so that a head's is a
-    column down the sublanes; v and beta come in as rows."""
+    channel) are transposed in the kernel, ``[H, d]`` to ``[d, H]`` once
+    a slot, so that a head's is a column down the sublanes; v and beta
+    stay rows."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     S, _layers, H, d, _ = state.shape
+    K = taps.shape[0]
 
     def slot_of(pos, order_ref, count_ref):
         return order_ref[jnp.clip(pos, 0, jnp.maximum(count_ref[0] - 1, 0))]
 
-    def vectors(pos, order_ref, count_ref, layer_ref):
-        return (slot_of(pos, order_ref, count_ref), 0, 0, 0)
+    def rows(pos, order_ref, count_ref, layer_ref):
+        return (slot_of(pos, order_ref, count_ref), 0, 0)
+
+    def filters(pos, order_ref, count_ref, layer_ref):
+        return (0, 0, 0)
 
     def tile(pos, order_ref, count_ref, layer_ref):
         return (slot_of(pos, order_ref, count_ref), layer_ref[0], 0, 0, 0)
 
-    def out_rows(pos, order_ref, count_ref, layer_ref):
-        return (slot_of(pos, order_ref, count_ref), 0, 0)
-
+    kept = pl.BlockSpec((None, None, H, d, d), tile), \
+        pl.BlockSpec((None, None, K - 1, 3 * H, d), tile)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S,),
-        in_specs=[pl.BlockSpec((None, 3, d, H), vectors),
-                  pl.BlockSpec((None, 2, H, d), vectors),
-                  pl.BlockSpec((None, None, H, d, d), tile)],
-        out_specs=[pl.BlockSpec((None, None, H, d, d), tile),
-                   pl.BlockSpec((None, H, d), out_rows)],
+        in_specs=[pl.BlockSpec((None, 3 * H, d), rows),
+                  pl.BlockSpec((K, 3 * H, d), filters),
+                  pl.BlockSpec((None, H, d), rows),
+                  pl.BlockSpec((None, 1, H), rows), *kept],
+        out_specs=[*kept, pl.BlockSpec((None, H, d), rows)],
+        scratch_shapes=[pltpu.VMEM((3, d, H), jnp.float32),
+                        pltpu.VMEM((H, d), jnp.float32)],
     )
     order, count = _live_slots(active)
-    cols = jnp.stack([a, k, q], axis=1).transpose(0, 1, 3, 2)
-    rows = jnp.stack([beta[..., None] * v,
-                      jnp.broadcast_to(beta[..., None], v.shape)], axis=1)
-    state, o = pl.pallas_call(
+    state, tails, o = pl.pallas_call(
         _kda_step_kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct(tails.shape, tails.dtype),
                    jax.ShapeDtypeStruct((S, H, d), jnp.float32)],
-        # operand 5 (after the three prefetched scalars): the state
-        input_output_aliases={5: 0},
+        # operands 7 and 8 (after the three prefetched scalars): the state
+        # and the tails
+        input_output_aliases={7: 0, 8: 1},
         name='kda_step',
         interpret=_pallas.interpret(),
-    )(order, count, jnp.asarray(layer, jnp.int32).reshape(1), cols, rows,
-      state)
+    )(order, count, jnp.asarray(layer, jnp.int32).reshape(1),
+      x.reshape(S, 3 * H, d), taps.reshape(K, 3 * H, d), a,
+      beta[:, None, :], state, tails)
     # a dead slot's rows of o were never written
-    return jnp.where(active[:, None, None], o, 0.0), state
+    return jnp.where(active[:, None, None], o, 0.0), state, tails
 
 
-def step_mixer(w, p, cfg, h, state, layer, tail, active, kernel):
+def step_mixer(w, p, cfg, h, state, layer, tails, active, kernel):
     """Every slot, one decode step of recurrent layer ``layer``: h
-    [slots, D] normalised, state [slots, layers, H, d, d] (the WHOLE
-    matrix state), tail [slots, K-1, ch] (this layer's), active [slots]
-    bool.  Returns (out [slots, D] float32, state, tail): the state of
-    the live slots advanced in this layer and nothing else of it
-    changed; the tail for ALL slots (the caller keeps an inactive slot's
-    old one).
+    [slots, D] normalised, state [slots, layers, H, d, d] and tails
+    [slots, layers, K-1, 3 H, d] (the WHOLE arrays), active [slots] bool.
+    Returns (out [slots, D] float32, state, tails): the state and the
+    tail of the live slots advanced in this layer and nothing else of
+    either changed.
 
-    ``kernel`` (`kda_step_eligible`, static) runs the recurrence in
-    place over the live slots (`kda_step`); otherwise every slot steps
-    and the dead ones' state is kept by a select."""
+    ``kernel`` (`kda_step_eligible`, static) runs the step from the
+    projections on in place over the live slots (`kda_step`); otherwise
+    every slot steps and the dead ones' state and tail are kept by a
+    select."""
     import jax
     import jax.numpy as jnp
     from ... import observability as _obs
     kda = cfg['kda']
     x = _project(w, p, h)
     g, beta, gate = _gates(w, p, kda, h)
-    with jax.named_scope('kda.conv'):
-        full = jnp.concatenate([tail, x[:, None]], axis=1)    # [S, K, ch]
-        conv = jnp.sum(full * _taps(w, p), axis=1)
-        tail = full[:, 1:]
-    with jax.named_scope('kda.step'):
-        q, k, v = _heads(conv, kda)
-        a = jnp.exp(g)
-        if kernel:
+    a = jnp.exp(g)
+    if kernel:
+        with jax.named_scope('kda.step'):
             _obs.metrics.counter('kda.step_kernel').inc()
-            o, state = kda_step(a, k, q, v, beta, state, layer, active)
-        else:
-            _obs.metrics.counter('kda.step_composed').inc()
-            S = state[:, layer]                            # [S, H, d, d]
+            o, state, tails = kda_step(x, _taps(w, p), a, beta, state,
+                                       tails, layer, active)
+    else:
+        _obs.metrics.counter('kda.step_composed').inc()
+        with jax.named_scope('kda.conv'):
+            old = tails[:, layer]                      # [S, K-1, 3 H, d]
+            full = jnp.concatenate([old.reshape(old.shape[:2] + (-1,)),
+                                    x[:, None]], axis=1)      # [S, K, ch]
+            conv = jnp.sum(full * _taps(w, p), axis=1)
+            tails = tails.at[:, layer].set(jnp.where(
+                active[:, None, None, None],
+                full[:, 1:].reshape(old.shape), old))
+        with jax.named_scope('kda.step'):
+            q, k, v = _heads(conv, kda)
+            S = state[:, layer]                        # [S, H, d, d]
             # S^T (a k) and S^T (a q) in ONE pass over the state
             both = jnp.sum(
                 S[:, :, None] * (a[:, :, None]
                                  * jnp.stack([k, q], axis=2))[..., None],
-                axis=-2)                                   # [S, H, 2, d]
+                axis=-2)                               # [S, H, 2, d]
             u = beta[..., None] * (v - both[:, :, 0])
             o = both[:, :, 1] + jnp.sum(k * q, axis=-1, keepdims=True) * u
             new = a[..., None] * S + k[..., None] * u[:, :, None, :]
             state = state.at[:, layer].set(
                 jnp.where(active[:, None, None, None], new, S))
     return _out(w, p, kda, o, gate, float(cfg.get('rms_eps', 1e-6))), \
-        state, tail
+        state, tails

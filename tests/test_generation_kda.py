@@ -299,18 +299,30 @@ def test_a_chunk_of_the_cells_size_takes_the_modules_blocks():
     assert kda._sizes(24) == (8, 8)
 
 
-def test_a_step_after_a_prefill_is_the_prefill_one_token_longer(rt):
-    """The single step and the chunk form agree on the same state: the
-    logits after prompt + 1 tokens, by a window's first step fed the sampled
-    token and by prefilling the longer prompt."""
+@pytest.mark.parametrize('path', ['step', 'window', 'window_chunk_window'])
+def test_a_step_after_a_prefill_is_the_prefill_one_token_longer(rt, path):
+    """The single step and the chunk form agree on the same state and the
+    same tail: the token after the context, by a window's last step fed the
+    sampled tokens and by prefilling the longer prompt.  One step; a window
+    of several; and a window, a chunk of one token and one more step on the
+    same slot: the tail a chunk writes is the tail the kernel reads, and
+    the other way round."""
     prompt = _prompt(13, 4)
     slot, first, _ = _prefill(rt, prompt)
-    tok = _window(rt, [slot], steps=1)[slot, 0]
+    toks = list(_window(rt, [slot], steps=1 if path == 'step'
+                        else WINDOW)[slot])
+    if path == 'window_chunk_window':
+        assert rt.ensure_capacity(slot, prompt.size + WINDOW + 2)
+        fed, _ = rt.prefill(slot, np.asarray(toks[-1:], np.int32),
+                            prompt.size + WINDOW, SamplingParams())
+        toks += [int(fed)]
+        toks += list(_window(rt, [slot], steps=1)[slot])
     state = {k: np.asarray(rt.state[k][slot]) for k in ('ssm', 'conv')}
+    assert np.abs(state['conv']).min(axis=(1, 2)).max() > 0   # every row
     rt.reset()
-    longer = np.concatenate([prompt, [first]]).astype(np.int32)
+    longer = np.concatenate([prompt, [first], toks[:-1]]).astype(np.int32)
     again, nxt, _ = _prefill(rt, longer)
-    assert again == slot and nxt == tok
+    assert again == slot and nxt == toks[-1]
     for name, was in state.items():
         np.testing.assert_allclose(np.asarray(rt.state[name][slot]), was,
                                    rtol=1e-4, atol=1e-5)
@@ -318,17 +330,29 @@ def test_a_step_after_a_prefill_is_the_prefill_one_token_longer(rt):
 
 # ------------------------------------------------- slots and their state
 
-def test_a_reused_slot_starts_from_a_zero_state(rt):
-    slot, _, fresh = _prefill(rt, _prompt(13, 2))
+@pytest.mark.parametrize('plen', [13, 2])
+def test_a_reused_slot_starts_from_a_zero_state(rt, plen):
+    """Also a prompt shorter than the convolutions' reach: the tail rows
+    from before its first token are zeros, not the last occupant's, and the
+    window behind it gives the tokens a fresh runtime gives."""
+    slot, _, fresh = _prefill(rt, _prompt(plen, 2))
+    fresh_toks = _window(rt, [slot])[slot]
     rt.reset()
     assert _prefill(rt, _prompt(19, 1))[0] == slot
+    _window(rt, [slot])
     assert float(jnp.abs(rt.state['ssm'][slot]).max()) > 0
+    assert float(jnp.abs(rt.state['conv'][slot]).min()) > 0
     rt.free_slot(slot)
     before = obs.counters().get('generation.state_resets', 0)
-    again, _, got = _prefill(rt, _prompt(13, 2))
+    again, _, got = _prefill(rt, _prompt(plen, 2))
     assert again == slot
     assert obs.counters()['generation.state_resets'] == before + 1
     np.testing.assert_array_equal(got, fresh)
+    tails = np.asarray(rt.state['conv'][slot])           # [L, K-1, 3 H, d]
+    kept = min(plen, tails.shape[1])
+    assert np.abs(tails[:, :tails.shape[1] - kept]).max(initial=0) == 0
+    assert np.abs(tails[:, tails.shape[1] - kept:]).min() > 0
+    np.testing.assert_array_equal(_window(rt, [slot])[slot], fresh_toks)
     rt.reset()
     assert float(jnp.abs(rt.state['ssm']).max()) == 0
     assert float(jnp.abs(rt.state['conv']).max()) == 0
@@ -352,6 +376,12 @@ def test_a_dead_slots_state_is_untouched_by_a_window(rt):
     assert c['generation.kda_state_bytes'] \
         == c['generation.window_kda_state_bytes'] \
         == WINDOW * 3 * 2 * kda.state_bytes(CFG['kda'])
+    # and the one live slot's tails: three rows of 3 H d
+    assert kda.tail_bytes(CFG['kda']) == 4 * 3 * 3 * 3 * 8 \
+        == 4 * rt.state['conv'][0, 0].size
+    assert c['generation.kda_tail_bytes'] \
+        == c['generation.window_kda_tail_bytes'] \
+        == WINDOW * 3 * 2 * kda.tail_bytes(CFG['kda'])
     assert c.get('generation.kda_chunk_tokens', 0) == 0
 
 
@@ -363,59 +393,147 @@ def test_the_composed_step_gives_the_kernels_tokens_and_state(weights, rt):
     def run(runtime):
         slots = [_prefill(runtime, p)[0] for p in prompts]
         idle = _prefill(runtime, _prompt(5, 9))[0]
-        kept = np.asarray(runtime.state['ssm'][idle])
+        kept = {k: np.asarray(runtime.state[k][idle])
+                for k in ('ssm', 'conv')}
         toks = _window(runtime, slots)[slots]
-        np.testing.assert_array_equal(
-            np.asarray(runtime.state['ssm'][idle]), kept)
-        return toks, np.asarray(runtime.state['ssm'])
+        for name, was in kept.items():
+            np.testing.assert_array_equal(
+                np.asarray(runtime.state[name][idle]), was)
+        return toks, np.asarray(runtime.state['ssm']), \
+            np.asarray(runtime.state['conv'])
 
-    want_toks, want_state = run(rt)
+    want_toks, want_state, want_tails = run(rt)
     composed = DecodeRuntime(weights, CFG, slots=3, prefill_chunk=CHUNK,
                              page_len=PAGE)
     composed.state_kernel = False
     before = dict(obs.counters())
-    toks, state = run(composed)
+    toks, state, tails = run(composed)
     c = {k: v - before.get(k, 0) for k, v in obs.counters().items()}
     assert c['kda.step_composed'] > 0 and c.get('kda.step_kernel', 0) == 0
     assert c['generation.state_slot_steps'] == 3 * WINDOW
     assert c['generation.kda_state_bytes'] \
         == WINDOW * 3 * 3 * 2 * kda.state_bytes(CFG['kda'])
+    # every slot's tails in every layer, the live ones' or not
+    assert c['generation.window_kda_tail_bytes'] \
+        == WINDOW * 3 * 3 * 2 * kda.tail_bytes(CFG['kda'])
+    # (the chunks count none: three prompts' went through them)
+    assert c['generation.kda_tail_bytes'] \
+        == c['generation.window_kda_tail_bytes']
     np.testing.assert_array_equal(toks, want_toks)
     np.testing.assert_allclose(state, want_state, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(tails, want_tails, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize('live', [[1, 0, 1, 1, 0], [0, 0, 0, 0, 0],
-                                  [1, 1, 1, 1, 1], [0, 0, 0, 0, 1]],
-                         ids=['some', 'none', 'all', 'last'])
-def test_the_kernel_steps_the_live_slots_of_one_layer_in_place(live):
-    rng = np.random.RandomState(0)
+_LIVE = pytest.mark.parametrize(
+    'live', [[1, 0, 1, 1, 0], [0, 0, 0, 0, 0], [1, 1, 1, 1, 1],
+             [0, 0, 0, 0, 1]], ids=['some', 'none', 'all', 'last'])
+
+
+def _step_inputs(S, L, H, d, taps, seed=0):
+    """(x [S, 3 H d], taps [K, 3 H d], a [S, H, d], beta [S, H], state [S,
+    L, H, d, d], tails [S, L, K-1, 3 H, d]) float32."""
+    rng = np.random.RandomState(seed)
+    f32 = jnp.float32
+    return (jnp.asarray(rng.randn(S, 3 * H * d), f32),
+            jnp.asarray(rng.randn(taps, 3 * H * d), f32),
+            jnp.asarray(np.exp(-np.abs(rng.randn(S, H, d))), f32),
+            jnp.asarray(rng.rand(S, H), f32),
+            jnp.asarray(rng.randn(S, L, H, d, d), f32),
+            jnp.asarray(rng.randn(S, L, taps - 1, 3 * H, d), f32))
+
+
+@pytest.mark.parametrize('taps', [4, 2])
+@_LIVE
+def test_the_kernel_steps_the_live_slots_of_one_layer_in_place(live, taps):
+    """`kda_step` from the projections on, against the convolution, silu
+    and the unit norms written out here and `token_scan` behind them: four
+    taps (the tail moves up a row) and two (the tail IS the last row)."""
     S, L, H, d = 5, 3, 3, 8
-    state = jnp.asarray(rng.randn(S, L, H, d, d), jnp.float32)
-    a = jnp.asarray(np.exp(-np.abs(rng.randn(S, H, d))), jnp.float32)
-    k, q, v = (jnp.asarray(rng.randn(S, H, d), jnp.float32)
-               for _ in range(3))
-    beta = jnp.asarray(rng.rand(S, H), jnp.float32)
+    x, filt, a, beta, state, tails = _step_inputs(S, L, H, d, taps)
     active = jnp.asarray(live, bool)
-    o, new = jax.jit(kda.kda_step)(a, k, q, v, beta, state, jnp.int32(1),
-                                   active)
-    want_o, want_S = [], []
+    o, new, new_tails = jax.jit(kda.kda_step)(
+        x, filt, a, beta, state, tails, jnp.int32(1), active)
+    want_o, want_S, want_tail = [], [], []
     for s in range(S):
-        step_o, step_S = kda.token_scan(q[s:s + 1], k[s:s + 1], v[s:s + 1],
-                                        jnp.log(a[s:s + 1]), beta[s:s + 1],
-                                        state[s, 1])
+        full = jnp.concatenate([tails[s, 1].reshape(taps - 1, -1), x[s:s + 1]])
+        conv = jnp.sum(full * filt, axis=0)
+        y = (conv / (1 + jnp.exp(-conv))).reshape(3, H, d)
+        q, k = (r / jnp.sqrt(jnp.sum(r * r, -1, keepdims=True) + 1e-6)
+                for r in y[:2])
+        step_o, step_S = kda.token_scan(
+            (q * d ** -0.5)[None], k[None], y[2][None], jnp.log(a[s:s + 1]),
+            beta[s:s + 1], state[s, 1])
         want_o.append(step_o[0])
         want_S.append(step_S)
+        want_tail.append(full[1:].reshape(taps - 1, 3 * H, d))
     mask = np.asarray(live, bool)
     np.testing.assert_allclose(
         o, np.where(mask[:, None, None], np.stack(want_o), 0.0),
         rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(new[:, 1])[mask],
                                np.stack(want_S)[mask], rtol=1e-5, atol=1e-5)
-    # a dead slot's state, and every other layer's, bit for bit
-    np.testing.assert_array_equal(np.asarray(new[:, 1])[~mask],
-                                  np.asarray(state[:, 1])[~mask])
-    np.testing.assert_array_equal(np.asarray(new[:, [0, 2]]),
-                                  np.asarray(state[:, [0, 2]]))
+    np.testing.assert_allclose(np.asarray(new_tails[:, 1])[mask],
+                               np.stack(want_tail)[mask], rtol=1e-5,
+                               atol=1e-5)
+    # a dead slot's state and tail, and every other layer's, bit for bit
+    for got, was in ((new, state), (new_tails, tails)):
+        np.testing.assert_array_equal(np.asarray(got[:, 1])[~mask],
+                                      np.asarray(was[:, 1])[~mask])
+        np.testing.assert_array_equal(np.asarray(got[:, [0, 2]]),
+                                      np.asarray(was[:, [0, 2]]))
+
+
+@_LIVE
+def test_the_composed_layer_is_the_kernels(weights, live):
+    """`step_mixer` by either route on one layer of the same state: the
+    layer's output for the live slots (a dead one's is masked by whoever
+    calls), the state and the tails of every slot."""
+    S, kd = 5, CFG['kda']
+    _x, _f, _a, _b, state, tails = _step_inputs(
+        S, 3, kd['n_heads'], kd['head_dim'], kd['d_conv'], seed=1)
+    w = {k: jnp.asarray(v) for k, v in weights.items()
+         if k.startswith('layer_1_kda_')}
+    h = jnp.asarray(np.random.RandomState(2).randn(S, CFG['d_model']),
+                    jnp.float32)
+    active = jnp.asarray(live, bool)
+
+    def layer(kernel):
+        return jax.jit(lambda h, state, tails: kda.step_mixer(
+            w, 'layer_1_', CFG, h, state, 1, tails, active, kernel))(
+                h, state, tails)
+
+    mask = np.asarray(live, bool)
+    for got, want in zip(layer(True), layer(False)):
+        got, want = np.asarray(got), np.asarray(want)
+        np.testing.assert_allclose(got[mask], want[mask], rtol=1e-5,
+                                   atol=1e-5)
+        if got.ndim > 2:                # state and tails: the dead slots'
+            np.testing.assert_array_equal(got[~mask], want[~mask])
+
+
+@pytest.mark.parametrize('shape,taps,dtype,devices,takes', [
+    ((128, 6, 32, 128, 128), 4, 'float32', 1, True),    # the cell's
+    ((128, 6, 32, 128, 128), 4, 'bfloat16', 1, False),
+    ((128, 6, 32, 128, 128), 4, 'float32', 2, False),   # under a mesh
+    ((128, 6, 32, 64, 64), 4, 'float32', 1, False),     # half a tile of lanes
+    ((128, 6, 12, 128, 128), 4, 'float32', 1, False),   # [H, d] splits a tile
+    ((128, 6, 64, 128, 128), 4, 'float32', 1, False),   # four tiles too many
+    ((8, 2, 8, 128, 128), 2, 'float32', 1, True),
+], ids=['cell', 'bf16', 'mesh', 'narrow_head', 'ragged_heads', 'too_large',
+        'small'])
+def test_the_kernel_is_chosen_by_what_the_state_is(monkeypatch, shape, taps,
+                                                   dtype, devices, takes):
+    """The rule is static and reads the arrays' extents, the dtype and the
+    mesh; the interpreter takes anything of one device's float32."""
+    from paddle_tpu.ops import _pallas
+    S, L, H, d, _ = shape
+    tails = (S, L, taps - 1, 3 * H, d)
+    mesh = None if devices == 1 else jax.make_mesh(
+        (devices,), ('x',), devices=jax.devices()[:devices])
+    assert kda.kda_step_eligible(shape, tails, dtype, mesh) \
+        == (dtype == 'float32' and devices == 1)
+    monkeypatch.setattr(_pallas, 'interpret', lambda: False)
+    assert kda.kda_step_eligible(shape, tails, dtype, mesh) == takes
 
 
 def test_a_stream_does_not_depend_on_its_neighbours(rt):
@@ -456,7 +574,7 @@ def test_the_pool_holds_the_layers_that_attend_and_the_state_the_others(
     assert rt.cache.pool_shape == (3 * 16 + 1, 1, PAGE, 128)
     assert rt.cache.page_bytes() == 4 * 1 * PAGE * 128
     assert rt.state['ssm'].shape == (3, 3, 3, 8, 8)
-    assert rt.state['conv'].shape == (3, 3, 3, 3 * 3 * 8)
+    assert rt.state['conv'].shape == (3, 3, 3, 3 * 3, 8)
     assert rt.cache.recurrent_bytes() \
         == 4 * (rt.state['ssm'].size + rt.state['conv'].size)
     assert rt.cache.bytes() == rt.cache.pages * rt.cache.page_bytes() \
@@ -509,9 +627,12 @@ def test_the_launches_carry_the_scopes(rt):
         rt._param_structs(), rt._state_structs(),
         sds((rt.cache.max_pages,), jnp.int32), sds((CHUNK,), jnp.int32),
         i32, i32, i32, i32, f32, i32).as_text(debug_info=True)
-    for scope in ('kda.proj', 'kda.conv', 'kda.gate', 'kda.out',
+    for scope in ('kda.proj', 'kda.gate', 'kda.out',
                   'attn.latent.q', 'attn.latent.scores', 'moe.route'):
         assert scope in window and scope in chunk, scope
+    # the kernel holds the step's convolutions: the window has no scope of
+    # theirs
+    assert 'kda.conv' in chunk and 'kda.conv' not in window
     assert 'kda.step' in window and 'kda.scan' not in window
     assert 'kda.scan' in chunk and 'kda.step' not in chunk
     assert 'latent_attention' in window and 'kda_step' in window

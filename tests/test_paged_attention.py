@@ -404,37 +404,62 @@ def test_mosaic_compiles_the_latent_kernel_at_real_widths(one_v5e_chip,
 def test_mosaic_compiles_the_delta_rule_step_at_real_widths(one_v5e_chip,
                                                             monkeypatch):
     """`kda.kda_step` at kimi_linear.many_streams_long_answers' extents:
-    128 slots of six layers' [32, 128, 128] float32 matrix state, 3.2 GB.
-    The state reaches the kernel as it lies and goes back aliased: nothing
-    of its size, nor of one layer's slice of it, is made."""
+    128 slots of six layers' [32, 128, 128] float32 matrix state, 3.2 GB,
+    and their [3, 96, 128] convolution tails, 113 MB.  Both reach the
+    kernel as they lie and go back aliased: nothing of their size, nor of
+    one layer's slice of them, is made; and a whole `kda` layer's step
+    around the kernel (`kda.step_mixer`) passes over no array of all the
+    slots' tails."""
     import jax
     from paddle_tpu.ops import _pallas
     from paddle_tpu.serving.generation import kda
     monkeypatch.setattr(_pallas, 'interpret', lambda: False)
-    slots, layers, H, d = 128, 6, 32, 128
+    slots, layers, H, d, taps, D, rank = 128, 6, 32, 128, 4, 2304, 128
     shape = (slots, layers, H, d, d)
-    assert kda.kda_step_eligible(shape, 'float32')
-    assert not kda.kda_step_eligible((slots, layers, H, 64, 64), 'float32')
-    assert not kda.kda_step_eligible((slots, layers, 64, d, d), 'float32')
+    tails = (slots, layers, taps - 1, 3 * H, d)
+    assert kda.kda_step_eligible(shape, tails, 'float32')
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
                                     sharding=one_v5e_chip)
 
-    vec = sds((slots, H, d), 'float32')
-    compiled = jax.jit(
-        lambda a, k, q, v, beta, state, active: kda.kda_step(
-            a, k, q, v, beta, state, 3, active),
-        donate_argnums=(5,)).lower(
-            vec, vec, vec, vec, sds((slots, H), 'float32'),
-            sds(shape, 'float32'), sds((slots,), 'bool')).compile()
-    text = compiled.as_text()
-    assert text.count('tpu_custom_call') == 1
-    state = '[%d,%d,%d,%d,%d]' % shape
-    assert not [ln for ln in text.splitlines() if state in ln.split('(')[0]
+    def kept(text):
+        """Lines of a compiled module that make an array of the state's or
+        the tails' extents, or of one layer's slice of them."""
+        marks = ['[%s]' % ','.join(str(n) for n in s) for s in (
+            shape, tails, shape[:1] + shape[2:], tails[:1] + tails[2:],
+            (slots, taps - 1, 3 * H * d), (slots, taps, 3 * H * d))]
+        return [ln for ln in text.splitlines()
+                if any(m in ln.split('(')[0] for m in marks)
                 and (' copy(' in ln or 'copy-start(' in ln
                      or ' fusion(' in ln)]
+
+    compiled = jax.jit(
+        lambda x, filt, a, beta, state, tail, active: kda.kda_step(
+            x, filt, a, beta, state, tail, 3, active),
+        donate_argnums=(4, 5)).lower(
+            sds((slots, 3 * H * d), 'float32'),
+            sds((taps, 3 * H * d), 'float32'),
+            sds((slots, H, d), 'float32'), sds((slots, H), 'float32'),
+            sds(shape, 'float32'), sds(tails, 'float32'),
+            sds((slots,), 'bool')).compile()
+    text = compiled.as_text()
+    assert text.count('tpu_custom_call') == 1
+    assert not kept(text)
     assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+    # the layer around it, with kimi_linear's weights (bfloat16)
+    kcfg = {'n_heads': H, 'head_dim': d, 'd_conv': taps, 'gate_rank': rank}
+    w = {'l_' + k: sds(s, 'bfloat16')
+         for k, s in kda.weight_shapes(D, kcfg).items()}
+    layer = jax.jit(
+        lambda w, h, state, tail, active: kda.step_mixer(
+            w, 'l_', {'kda': kcfg}, h, state, 3, tail, active, True),
+        donate_argnums=(2, 3)).lower(
+            w, sds((slots, D), 'float32'), sds(shape, 'float32'),
+            sds(tails, 'float32'), sds((slots,), 'bool')).compile()
+    text = layer.as_text()
+    assert text.count('tpu_custom_call') == 1
+    assert not kept(text)
 
 
 def test_mosaic_compiles_the_grouped_expert_products_at_real_widths(
