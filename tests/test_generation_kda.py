@@ -750,7 +750,9 @@ STANDING = {
         'latent': {'q_rank': 24, 'kv_rank': 16, 'nope': 8, 'rope': 4,
                    'v': 8, 'yarn': YARN},
         'moe': {'n_routed': 16, 'top_k': 4, 'd_expert': 24, 'n_shared': 1,
-                'scale': 2.5, 'ranks': 4, 'rank': 1}}}
+                'scale': 2.5, 'ranks': 4, 'rank': 1}},
+    # this file's own kimi-shaped model: `kda` layers beside a latent one
+    'kimi': CFG}
 # sha256 of the lowered StableHLO at the PARENT of PR 61 (commit 5c0fdb1):
 # the dense and falcon_h1 ones are tests/test_generation_pipeline.py's own
 # pins at its sizes (PR 38), the latent_moe ones were taken on the parent
@@ -775,10 +777,18 @@ PARENT_SHA256 = {
         '9075ee4dd4075621c0941d058dd557f32591760863d1a0e610f37e60a52cdf7a',
     ('latent_moe', 'verify'):
         'e71f40bc981998fb09f30b406075414b60874b5ea4bc3d47db45d49ba9177bb7',
+    # the kimi-shaped model (`CFG`), which PR 65's step last changed: taken
+    # on PR 66's parent (the code of a37207e) in that PR's first commit,
+    # before it touched a file of the package; a verify window is refused
+    # for a recurrent model
+    ('kimi', 'prefill'):
+        'c121ebca883f2138863d7a281666a18249400b87772ef90b05e581efca3e6834',
+    ('kimi', 'decode'):
+        '7b5cd06b1de95353a6ea6d61d7d6f53072101237871fa33615eb213224098331',
 }
 # (chunk, window, slots, page, weights' seed) each fixture was pinned at
 _SIZES = {'dense': (4, 3, 3, 4, 1), 'falcon_h1': (4, 3, 3, 4, 1),
-          'latent_moe': (8, 3, 3, 4, 5)}
+          'latent_moe': (8, 3, 3, 4, 5), 'kimi': (CHUNK, WINDOW, 3, PAGE, 5)}
 
 
 def _lowered(block, kind):
