@@ -171,6 +171,7 @@ WHOLE layer (``ranks: 1``; experts.py).  The standing programs are
 untouched by all of it (tests/test_generation_lfm2.py pins this kind's
 two launches as the other files pin theirs).
 """
+import collections
 import threading
 from collections.abc import Mapping
 
@@ -178,8 +179,8 @@ import numpy as np
 
 from ... import observability as _obs
 from ...core import compile_cache as _cc
-from ...ops.attention import (cached_attention, latent_attention_eligible,
-                              paged_attention, paged_attention_eligible,
+from ...ops.attention import (cached_attention, paged_attention,
+                              paged_attention_eligible,
                               paged_attention_rows, paged_pool_heads)
 from ...ops.sampling import sample_logits, sample_tokens_at, token_key
 from . import experts as _experts
@@ -189,197 +190,138 @@ from . import shortconv as _shortconv
 from . import ssm as _ssm
 from .kv_cache import (CacheConfig, PagePool, PrefixCache, SlotAllocator,
                        init_state)
+from .mixer import Chunk, Kernels, Mixer, Step
 
 __all__ = ['DecodeRuntime', 'dense_reference', 'weight_names',
            'weight_shapes', 'random_weights']
 
+# the dense layer's slots in the order a trained llama program names them
+# (models/llama.py): `weight_names` keeps it for the narrow stream
 _WEIGHT_SLOTS = ('att_q_w', 'att_k_w', 'att_v_w', 'att_o_w', 'att_norm',
                  'ffn_norm', 'ffn_fc1_w', 'ffn_fc2_w', 'ffn_fc3_w')
 
 
 _BLOCKS = ('dense', 'falcon_h1', 'latent_moe')
 
+# one layer of a model: ``index`` i (its weights are ``layer_<i>_*``), the
+# kinds of its ``mixers`` in order (keys of `_MIXERS`, each reading the
+# layer's one normalised input), its feed-forward ``ffn`` (``'dense'`` |
+# ``'experts'``), its index ``pool`` on the page pool's layer axis and
+# ``state`` on the recurrent arrays' (None where it stores none of that
+# sort), and the MODEL's stream convention ``wide``: the residual stream
+# is ``[T, D]`` float32, rounded at each product (`latent.rms`,
+# `latent.dot`), where without it it is ``[B, T, D]`` in the model's dtype
+# (`_rms`, `_qkv`, `_ffn`, `_head`)
+_Layer = collections.namedtuple(
+    '_Layer', ('index', 'mixers', 'ffn', 'pool', 'state', 'wide'))
 
-def _block(cfg):
-    """The model's block kind: ``'dense'`` (the default: GQA and one
-    SwiGLU), ``'falcon_h1'`` (the same beside a Mamba-2 mixer, ssm.py) or
-    ``'latent_moe'`` (latent attention, latent.py, and per layer the
-    feed-forward ``cfg['ffn']`` names, experts.py)."""
+
+def _layers(cfg):
+    """The model's layers, one `_Layer` each: THE place that reads
+    ``cfg['block']`` and what follows from it, and that refuses a wrong
+    model dict.  ``'dense'`` (the default) is GQA and one SwiGLU a layer;
+    ``'falcon_h1'`` the same with a Mamba-2 mixer beside the attention;
+    ``'latent_moe'`` a mixer a layer as ``cfg['mixer']`` names it (latent
+    attention without the key) and the feed-forward ``cfg['ffn']`` names,
+    under the wide stream.  A model fills ONE pool geometry and ONE state
+    geometry (`CacheConfig` holds one of each), and must attend."""
     block = cfg.get('block', 'dense')
     if block not in _BLOCKS:
         raise ValueError('block must be one of %s, got %r'
                          % (', '.join(map(repr, _BLOCKS)), block))
-    return block
+    L, wide = int(cfg['n_layer']), block == 'latent_moe'
+    if wide:
+        ffn = tuple(cfg['ffn'])
+        if len(ffn) != L or any(k not in ('dense', 'experts') for k in ffn):
+            raise ValueError("ffn must name 'dense' or 'experts' for each "
+                             'of the %d layers, got %r' % (L, ffn))
+        kinds = tuple(cfg.get('mixer', ('latent',) * L))
+        named = [k for k, m in _MIXERS.items() if m.wide]
+        if len(kinds) != L or any(k not in named for k in kinds) \
+                or not any(_MIXERS[k].pool for k in kinds):
+            raise ValueError(
+                'mixer must name %s or %r for each of the %d layers, and '
+                'attend in at least one, got %r'
+                % (', '.join(map(repr, named[:-1])), named[-1], L, kinds))
+        mixers = tuple((k,) for k in kinds)
+    else:
+        ffn = ('dense',) * L
+        mixers = (('gqa', 'ssm') if block == 'falcon_h1' else ('gqa',),) * L
+    for stores, what in (('pool', 'attends through'),
+                         ('recurrent', 'holds state through')):
+        both = [k for k in _MIXERS if getattr(_MIXERS[k], stores)
+                and any(k in m for m in mixers)]
+        if len(both) > 1:
+            raise ValueError('a model %s %r or %r layers, never both, got %r'
+                             % (what, both[0], both[1],
+                                tuple(k for m in mixers for k in m)))
+    out, n_pool, n_state = [], 0, 0
+    for i in range(L):
+        pool = any(_MIXERS[k].pool for k in mixers[i])
+        state = any(_MIXERS[k].recurrent for k in mixers[i])
+        out.append(_Layer(i, mixers[i], ffn[i], n_pool if pool else None,
+                          n_state if state else None, wide))
+        n_pool, n_state = n_pool + pool, n_state + state
+    return tuple(out)
 
 
-def _recurrent(cfg):
-    """Whether the model carries recurrent state: a ``'falcon_h1'``
-    block does in every layer (ssm.py), a ``'latent_moe'`` model in its
-    ``'kda'`` or ``'conv'`` layers, if it has any (`_mixer_kinds`,
-    kda.py, shortconv.py)."""
-    return _block(cfg) == 'falcon_h1' or (
-        _latent_moe(cfg) and _state_mixer(cfg) is not None)
-
-
-def _latent_moe(cfg):
-    """Whether the model's block is the ``'latent_moe'`` kind."""
-    return _block(cfg) == 'latent_moe'
-
-
-def _ffn_kinds(cfg):
-    """A ``latent_moe`` model's feed-forward kind per layer: ``cfg['ffn']``,
-    ``'dense'`` or ``'experts'`` for each of ``n_layer``."""
-    kinds = tuple(cfg['ffn'])
-    if len(kinds) != int(cfg['n_layer']) \
-            or any(k not in ('dense', 'experts') for k in kinds):
-        raise ValueError("ffn must name 'dense' or 'experts' for each of "
-                         'the %d layers, got %r' % (int(cfg['n_layer']),
-                                                    kinds))
-    return kinds
-
-
-# a `latent_moe` layer's mixer: the two that ATTEND (rows in the pool)
-# and the two that hold STATE (the recurrent arrays)
-_ATTENDING = ('latent', 'gqa')
-_STATEFUL = ('kda', 'conv')
-
-
-def _mixer_kinds(cfg):
-    """A ``latent_moe`` model's mixer per layer: ``cfg['mixer']``,
-    ``'latent'`` (latent.py), ``'gqa'`` (the dense block's attention),
-    ``'kda'`` (kda.py) or ``'conv'`` (shortconv.py) for each of
-    ``n_layer``; every layer ``'latent'`` for a model without the key.
-    One pool geometry and one state geometry a runtime: a model attends
-    through ``'latent'`` or ``'gqa'`` layers and holds state through
-    ``'kda'`` or ``'conv'`` layers, never both of a pair.  `_ffn_kinds`'
-    sibling, read by no other block kind."""
-    L = int(cfg['n_layer'])
-    kinds = tuple(cfg.get('mixer', ('latent',) * L))
-    if len(kinds) != L or any(k not in _ATTENDING + _STATEFUL
-                              for k in kinds) \
-            or not any(k in _ATTENDING for k in kinds):
-        raise ValueError("mixer must name 'latent', 'gqa', 'kda' or 'conv' "
-                         'for each of the %d layers, and attend in at least '
-                         'one, got %r' % (L, kinds))
-    for pair, what in ((_ATTENDING, 'attends through'),
-                       (_STATEFUL, 'holds state through')):
-        if all(k in kinds for k in pair):
-            raise ValueError('a model %s %r or %r layers, never both, got '
-                             '%r' % ((what,) + pair + (kinds,)))
-    return kinds
-
-
-def _attending_mixer(cfg):
-    """The mixer kind a ``latent_moe`` model attends through:
-    ``'latent'`` or ``'gqa'``."""
-    kinds = _mixer_kinds(cfg)
-    return next(k for k in _ATTENDING if k in kinds)
-
-
-def _state_mixer(cfg):
-    """The mixer kind a ``latent_moe`` model holds state through
-    (``'kda'`` or ``'conv'``), or None for one that holds none."""
-    kinds = _mixer_kinds(cfg)
-    return next((k for k in _STATEFUL if k in kinds), None)
-
-
-def _layer_axes(cfg):
-    """A ``latent_moe`` model's layers on their own axes: layer i's
-    index among the layers of ITS mixer kind, which is its index on the
-    pool's layer axis (``'latent'``, ``'gqa'``) or the recurrent state's
-    (``'kda'``, ``'conv'``)."""
-    seen, out = {}, []
-    for kind in _mixer_kinds(cfg):
-        out.append(seen.get(kind, 0))
-        seen[kind] = out[-1] + 1
-    return out
+def _kinds(lays):
+    """[(mixer kind, the layers that have it)] of a model, in the table's
+    order."""
+    return [(k, n) for k, n in
+            ((k, sum(k in lay.mixers for lay in lays)) for k in _MIXERS) if n]
 
 
 def _head_dim(cfg):
     return int(cfg.get('head_dim', int(cfg['d_model']) // int(cfg['n_head'])))
 
 
+def _layer_shapes(cfg, lay):
+    """{slot: shape} of one layer's weights, in `weight_shapes`' order:
+    the norms, the mixer's and the feed-forward's under the wide stream;
+    the first mixer's, the norms, the feed-forward's and any other
+    mixer's under the narrow one."""
+    d = int(cfg['d_model'])
+    norms = {'att_norm': (d,), 'ffn_norm': (d,)}
+    first, *rest = (_MIXERS[k].weight_shapes(cfg) for k in lay.mixers)
+    if lay.ffn == 'dense':
+        f = int(cfg['d_ffn'])
+        ffn = {'ffn_fc1_w': (d, f), 'ffn_fc3_w': (d, f), 'ffn_fc2_w': (f, d)}
+    else:
+        ffn = _experts.weight_shapes(d, cfg['moe'])
+    shapes = {}
+    for part in ((norms, first) if lay.wide else (first, norms)) \
+            + (ffn,) + tuple(rest):
+        shapes.update(part)
+    return shapes
+
+
 def weight_names(cfg):
-    """The decode-side parameter names — the same names a trained llama
-    program leaves in its scope (models/llama.py layout); a
-    ``falcon_h1`` block adds its mixer's (`ssm.SLOTS`).  A ``latent_moe``
-    block has its own: per layer the two norms, its mixer's (latent
-    attention's, `latent.slots`, `kda.SLOTS`, `shortconv.SLOTS` or the
-    dense block's four projections, `_gqa_shapes`) and, by the layer's
-    feed-forward kind, the dense SwiGLU's or the expert layer's
-    (`experts.weight_shapes`)."""
-    if _latent_moe(cfg):
-        return list(_latent_moe_shapes(cfg))
-    slots = _WEIGHT_SLOTS + (_ssm.SLOTS if _recurrent(cfg) else ())
+    """The decode-side parameter names: the embedding, the last norm, the
+    head, and per layer the two norms, its mixers' (`Mixer.weight_shapes`)
+    and its feed-forward's (the dense SwiGLU's or
+    `experts.weight_shapes`).  A dense layer's are the names a trained
+    llama program leaves in its scope, in its order (models/llama.py
+    layout)."""
     names = ['tok_emb', 'final_norm', 'lm_proj_w']
-    for i in range(int(cfg['n_layer'])):
-        names.extend('layer_%d_%s' % (i, s) for s in slots)
+    for lay in _layers(cfg):
+        slots = list(_layer_shapes(cfg, lay))
+        if not lay.wide:
+            # stable: a second mixer's stay behind, in their order
+            slots.sort(key=lambda s: _WEIGHT_SLOTS.index(s)
+                       if s in _WEIGHT_SLOTS else len(_WEIGHT_SLOTS))
+        names.extend('layer_%d_%s' % (lay.index, s) for s in slots)
     return names
 
 
 def weight_shapes(cfg):
     """{name: shape} of every weight under `weight_names(cfg)`, in the
     public layout (a projection is ``[in, out]``)."""
-    if _latent_moe(cfg):
-        return _latent_moe_shapes(cfg)
-    d, v, h = int(cfg['d_model']), int(cfg['vocab']), int(cfg['n_head'])
-    hkv, f = int(cfg['n_kv_head']), int(cfg['d_ffn'])
-    dh = _head_dim(cfg)
-    mixer = _ssm.weight_shapes(d, cfg['ssm']) if _recurrent(cfg) else {}
-    shapes = {'tok_emb': (v, d), 'final_norm': (d,), 'lm_proj_w': (d, v)}
-    for i in range(int(cfg['n_layer'])):
-        p = 'layer_%d_' % i
-        shapes.update({p + 'att_q_w': (d, h * dh),
-                       p + 'att_k_w': (d, hkv * dh),
-                       p + 'att_v_w': (d, hkv * dh),
-                       p + 'att_o_w': (h * dh, d),
-                       p + 'att_norm': (d,), p + 'ffn_norm': (d,),
-                       p + 'ffn_fc1_w': (d, f), p + 'ffn_fc3_w': (d, f),
-                       p + 'ffn_fc2_w': (f, d)})
-        shapes.update((p + k, s) for k, s in mixer.items())
-    return shapes
-
-
-def _gqa_shapes(cfg):
-    """{slot: shape} of a ``'gqa'`` mixer's weights: the dense block's
-    four projections, and with ``cfg['qk_norm']`` the two head norms'
-    scales (over a head's ``head_dim`` values, in the public order)."""
-    d, dh = int(cfg['d_model']), _head_dim(cfg)
-    h, hkv = int(cfg['n_head']), int(cfg['n_kv_head'])
-    shapes = {'att_q_w': (d, h * dh), 'att_k_w': (d, hkv * dh),
-              'att_v_w': (d, hkv * dh), 'att_o_w': (h * dh, d)}
-    if cfg.get('qk_norm'):
-        shapes.update({'att_q_norm': (dh,), 'att_k_norm': (dh,)})
-    return shapes
-
-
-def _latent_moe_shapes(cfg):
-    """`weight_shapes` of a ``latent_moe`` model, in `weight_names`'
-    order."""
     d, v = int(cfg['d_model']), int(cfg['vocab'])
-    mixers = _mixer_kinds(cfg)
-    mixer = {}
-    if 'latent' in mixers:
-        mixer['latent'] = _latent.weight_shapes(d, int(cfg['n_head']),
-                                                cfg['latent'])
-    if 'gqa' in mixers:
-        mixer['gqa'] = _gqa_shapes(cfg)
-    if 'kda' in mixers:
-        mixer['kda'] = _kda.weight_shapes(d, cfg['kda'])
-    if 'conv' in mixers:
-        mixer['conv'] = _shortconv.weight_shapes(d, cfg['conv'])
     shapes = {'tok_emb': (v, d), 'final_norm': (d,), 'lm_proj_w': (d, v)}
-    for i, kind in enumerate(_ffn_kinds(cfg)):
-        p = 'layer_%d_' % i
-        shapes.update({p + 'att_norm': (d,), p + 'ffn_norm': (d,)})
-        shapes.update((p + k, s) for k, s in mixer[mixers[i]].items())
-        if kind == 'dense':
-            f = int(cfg['d_ffn'])
-            shapes.update({p + 'ffn_fc1_w': (d, f), p + 'ffn_fc3_w': (d, f),
-                           p + 'ffn_fc2_w': (f, d)})
-        else:
-            shapes.update((p + k, s) for k, s in
-                          _experts.weight_shapes(d, cfg['moe']).items())
+    for lay in _layers(cfg):
+        shapes.update(('layer_%d_%s' % (lay.index, k), s)
+                      for k, s in _layer_shapes(cfg, lay).items())
     return shapes
 
 
@@ -424,9 +366,10 @@ def _prepare_qkv(q, k, v, dh):
     return (_head_rows(q.T, dh, True), _head_rows(k.T, dh, True), v.T)
 
 
-def _public_weight(slot, wt, dh):
-    """`_prepare_qkv` undone for ONE prepared array: bitwise the public
-    weight it was made from."""
+def _public_weight(slot, parts, dh):
+    """`_prepare_qkv` undone for ONE public slot from its prepared array:
+    bitwise the public weight it was made from."""
+    wt, = parts
     return (wt if slot == 'att_v_w' else _head_rows(wt, dh, False)).T
 
 
@@ -437,109 +380,78 @@ def _public_rows(k, dh):
         k.shape)
 
 
-def _qkv_layers(cfg):
-    """The layers whose q, k and v are held as `_prepare_qkv` makes them:
-    every layer of the dense and ``falcon_h1`` kinds, a ``latent_moe``
-    model's ``'gqa'`` layers."""
-    if _latent_moe(cfg):
-        return [i for i, kind in enumerate(_mixer_kinds(cfg))
-                if kind == 'gqa']
-    return list(range(int(cfg['n_layer'])))
-
-
 def _prepared_names(cfg):
-    """{public name: (slot, the executables' name for its prepared
-    form)} of the weights the runtime keeps prepared.  A ``latent_moe``
-    model's are latent attention's (`latent.prepared`), in the layers
-    that have it: a public weight there has one or two prepared parts,
-    and the second entry is the tuple of their names; in its ``'gqa'``
-    layers q, k and v as the dense block keeps them."""
-    if _latent_moe(cfg) and _attending_mixer(cfg) == 'latent':
-        return {'layer_%d_%s' % (i, slot):
-                (slot, tuple('layer_%d_%s' % (i, t) for t in stored))
-                for i, kind in enumerate(_mixer_kinds(cfg))
-                if kind == 'latent'
-                for slot, stored in _latent.prepared(cfg['latent']).items()}
-    return {'layer_%d_%s' % (i, slot): (slot, 'layer_%d_%s' % (i, stored))
-            for i in _qkv_layers(cfg) for slot, stored in _PREPARED.items()}
+    """{public name: (mixer kind, slot, the executables' names for its
+    prepared parts)} of the weights the runtime keeps prepared
+    (`Mixer.prepared`): q, k and v of a ``'gqa'`` layer, a latent layer's
+    three projections in their one or two parts."""
+    return {'layer_%d_%s' % (lay.index, slot):
+            (kind, slot, tuple('layer_%d_%s' % (lay.index, t) for t in parts))
+            for lay in _layers(cfg) for kind in lay.mixers
+            for slot, parts in _MIXERS[kind].prepared(cfg).items()}
 
 
 def _prepared_arrays(params, cfg):
     """Every prepared array of ``params``, a flat list."""
-    out = []
-    for _slot, stored in _prepared_names(cfg).values():
-        out.extend(params[n] for n in
-                   (stored if isinstance(stored, tuple) else (stored,)))
-    return out
-
-
-def _latent_dims(cfg):
-    lat = cfg['latent']
-    return (int(cfg['n_head']), int(lat['nope']), int(lat['rope']),
-            int(lat['v']))
+    return [params[t] for _kind, _slot, stored in
+            _prepared_names(cfg).values() for t in stored]
 
 
 def _params_from(weights, cfg):
     """``weights`` under `weight_names(cfg)` -> the parameters the
-    executables take: q, k and v of every layer prepared (one jitted
-    call a layer, one compilation per layer geometry), under their
-    `_PREPARED` names; every other weight as it is.  A layer's raw q, k
-    and v are held only while that layer is prepared."""
+    executables take: the weights a mixer keeps prepared made so
+    (`Mixer.prepare`: one jitted call a layer, one compilation per layer
+    geometry), under their prepared names; every other weight as it is.
+    A layer's raw weights are held only while that layer is prepared."""
     import jax
     import jax.numpy as jnp
     prepared = _prepared_names(cfg)
     params = {n: jnp.asarray(weights[n]) for n in weight_names(cfg)
               if n not in prepared}
-    if _latent_moe(cfg) and _attending_mixer(cfg) == 'latent':
-        # latent attention's: W_qb, W_kva, W_kvb -> `latent.PREPARED`
-        prepare = jax.jit(_latent.prepare, static_argnums=(3, 4, 5, 6))
-        slots = _latent.prepared(cfg['latent'])
-        stored = [t for parts in slots.values() for t in parts]
-        for i, kind in enumerate(_mixer_kinds(cfg)):
-            if kind != 'latent':
+    prepare = {}
+    for lay in _layers(cfg):
+        for kind in lay.mixers:
+            entry, p = _MIXERS[kind], 'layer_%d_' % lay.index
+            slots = entry.prepared(cfg)
+            if not slots:
                 continue
-            p = 'layer_%d_' % i
-            made = prepare(*(jnp.asarray(weights[p + s]) for s in slots),
-                           *_latent_dims(cfg))
-            params.update(zip((p + t for t in stored), made))
-        return params
-    dh = _head_dim(cfg)
-    prepare = jax.jit(_prepare_qkv, static_argnums=3)
-    for i in _qkv_layers(cfg):
-        p = 'layer_%d_' % i
-        made = prepare(*(jnp.asarray(weights[p + s]) for s in _PREPARED), dh)
-        params.update(zip((p + t for t in _PREPARED.values()), made))
+            dims = entry.dims(cfg)
+            if kind not in prepare:
+                prepare[kind] = jax.jit(entry.prepare, static_argnums=tuple(
+                    range(len(slots), len(slots) + len(dims))))
+            made = prepare[kind](*(jnp.asarray(weights[p + s])
+                                   for s in slots), *dims)
+            params.update(zip((p + t for parts in slots.values()
+                               for t in parts), made))
     return params
 
 
 class _PublicWeights(Mapping):
     """`DecodeRuntime.w`: the weights under `weight_names(cfg)`, in the
     public shapes and with the values that were passed in, bit for bit.
-    The runtime keeps q, k and v ONCE, in the prepared form
+    The runtime keeps a prepared weight ONCE, in the prepared form
     (`DecodeRuntime.params`); reading one of them here undoes the
-    preparation into a fresh array, every other name is the array the
-    executables read."""
+    preparation into a fresh array (`Mixer.public`), every other name is
+    the array the executables read."""
 
     def __init__(self, params, cfg):
         import jax
         self._params, self._names = params, weight_names(cfg)
         self._prepared = _prepared_names(cfg)
-        if _latent_moe(cfg) and _attending_mixer(cfg) == 'latent':
-            self._dims = _latent_dims(cfg)
-            self._undo = jax.jit(_latent.public,
-                                 static_argnums=(0, 2, 3, 4, 5))
-        else:
-            self._dh = _head_dim(cfg)
-            self._undo = jax.jit(_public_weight, static_argnums=(0, 2))
+        self._undo = {}
+        for kind, _slot, _stored in self._prepared.values():
+            if kind not in self._undo:
+                dims = _MIXERS[kind].dims(cfg)
+                self._undo[kind] = (dims, jax.jit(
+                    _MIXERS[kind].public, static_argnums=(0,) + tuple(
+                        range(2, 2 + len(dims)))))
 
     def __getitem__(self, name):
         if name not in self._prepared:
             return self._params[name]
-        slot, stored = self._prepared[name]
-        if isinstance(stored, tuple):
-            return self._undo(slot, tuple(self._params[t] for t in stored),
-                              *self._dims)
-        return self._undo(slot, self._params[stored], self._dh)
+        kind, slot, stored = self._prepared[name]
+        dims, undo = self._undo[kind]
+        return undo(slot, tuple(self._params[t] for t in stored), *dims)
 
     def __iter__(self):
         return iter(self._names)
@@ -713,40 +625,138 @@ def _gathered_rows(cache, st, bt):
     return int(k.shape[0]) * int(k.shape[2])
 
 
-# what a `latent_moe` launch counts on the device and hands back beside
-# its tokens: `experts.STATS` summed over its expert layers (and a
-# window's steps), then the latent rows it read
-_LAUNCH_STATS = _experts.STATS + ('latent_rows_read',)
-# (0 for a model that attends through `gqa` layers: what its kernel reads
-# is counted on the host, `DecodeRuntime._window_rows_read`)
-# and, of a model with `kda` layers, behind them: slot-layers whose matrix
-# state a window's steps read and wrote (`DecodeRuntime._count_stats`
-# turns them into bytes), tokens a chunk's scan took, slot-layers whose
-# convolution tails the steps read and wrote (bytes likewise)
-_KDA_STATS = ('kda_state_bytes', 'kda_chunk_tokens', 'kda_tail_bytes')
-_KDA_BYTES = {'kda_state_bytes': _kda.state_bytes,
-              'kda_tail_bytes': _kda.tail_bytes}
-
-
 def _launch_stats(cfg):
-    """The names of what a ``latent_moe`` launch of this model counts, in
-    the order of the array it hands back."""
-    return _LAUNCH_STATS + (_KDA_STATS if _state_mixer(cfg) == 'kda' else ())
+    """{counter after ``generation.``: what one counted unit adds to it}
+    of what a wide launch of this model counts on the device and hands
+    back beside its tokens, in the order of that array: `experts.STATS`
+    summed over its expert layers (and a window's steps), then each
+    mixer kind's own (`Mixer.stats`).  Empty for the narrow stream, whose
+    launches count nothing."""
+    lays = _layers(cfg)
+    if not lays[0].wide:
+        return {}
+    stats = dict.fromkeys(_experts.STATS, 1)
+    for kind, _n in _kinds(lays):
+        stats.update(_MIXERS[kind].stats(cfg))
+    return stats
 
 
-def _latent_moe_ffn(w, cfg, x, i, valid, experts_kernel):
-    """The feed-forward half of layer ``i`` of a ``latent_moe`` block: x
-    [T, D] float32 -> (x + the layer's feed-forward, `experts.STATS`);
-    ``valid`` [T] marks the tokens that route, ``experts_kernel`` is
-    `DecodeRuntime.experts_kernel` (experts.py)."""
-    p = 'layer_%d_' % i
+def _counted(lays, cache, stats, half, *where):
+    """A wide launch's whole array of counts: the expert layers' summed
+    ``stats``, then what each mixer kind counts for its layers
+    (`Mixer.counted`, ``half`` 0 for a chunk and 1 for a step)."""
+    import jax.numpy as jnp
+    handed = [stats]
+    for kind, n in _kinds(lays):
+        if _MIXERS[kind].counted is not None:
+            handed.extend(_MIXERS[kind].counted[half](n, cache, *where))
+    return jnp.concatenate(handed)
+
+
+def _wide_ffn(w, cfg, lay, x, valid, kernels):
+    """The feed-forward half of a layer under the wide stream: x [T, D]
+    float32 -> (x + the layer's feed-forward, `experts.STATS`); ``valid``
+    [T] marks the tokens that route (experts.py)."""
+    p = 'layer_%d_' % lay.index
     h = _latent.rms(x, w[p + 'ffn_norm'], _eps(cfg))
-    if _ffn_kinds(cfg)[i] == 'dense':
+    if lay.ffn == 'dense':
         y, stats = _experts.dense_layer(w, p, h)
     else:
         y, stats = _experts.expert_layer(w, p, cfg, h, valid,
-                                         experts_kernel)
+                                         kernels.experts)
     return x + y, stats
+
+
+# ------------------------------------------------- the ``'gqa'`` mixer
+# The one kind that serves under both streams, so its entry holds both
+# pairs of halves until the conventions are one (ROADMAP D1a).
+
+def _gqa_weights(cfg):
+    """{slot: shape} of a ``'gqa'`` mixer's weights: the four
+    projections, and with ``cfg['qk_norm']`` the two head norms' scales
+    (over a head's ``head_dim`` values, in the public order)."""
+    d, dh = int(cfg['d_model']), _head_dim(cfg)
+    h, hkv = int(cfg['n_head']), int(cfg['n_kv_head'])
+    shapes = {'att_q_w': (d, h * dh), 'att_k_w': (d, hkv * dh),
+              'att_v_w': (d, hkv * dh), 'att_o_w': (h * dh, d)}
+    if cfg.get('qk_norm'):
+        shapes.update({'att_q_norm': (dh,), 'att_k_norm': (dh,)})
+    return shapes
+
+
+def _gqa_pool(cfg, wide):
+    """The K and V pools' geometry: a head a row; under the wide stream a
+    narrow head's kv heads side by side in a row (`paged_pool_heads`)."""
+    heads, width = int(cfg['n_kv_head']), _head_dim(cfg)
+    if wide:
+        heads, width = paged_pool_heads(heads, width)
+    return dict(kv_heads=heads, head_dim=width)
+
+
+def _gqa_public_rows(cfg, k, v):
+    """`cache_row`'s K and V from the pool's rows: a packed pool's heads
+    apart again, K in the public (interleaved) order."""
+    dh = _head_dim(cfg)
+    return _public_rows(_head_rows_of(k, dh), dh), _head_rows_of(v, dh)
+
+
+def _gqa_prefill_narrow(w, cfg, cache, kernels, lay, h, st, at):
+    """A dense layer's attention over a prefill chunk: h [1, C, D]
+    normalised -> (what it adds to the stream, the state dict with the
+    chunk's rows written): write, gather and `cached_attention`, or the
+    exact ring over the whole prompt (``at.ring``, offset 0)."""
+    import jax
+    scope = jax.named_scope
+    i, theta = lay.index, float(cfg['theta'])
+    with scope('attn.qkv'):
+        q, k, v = _qkv(w, cfg, h, i)
+        q = _rope_at(q, at.pos, theta)
+        k = _rope_at(k, at.pos, theta)
+    with scope('kv.write'):
+        st = _write_rows(st, lay.pool, at.pg, at.rw, k[0].transpose(1, 0, 2),
+                         v[0].transpose(1, 0, 2), cache.quant == 'int8')
+    if at.ring is None:
+        with scope('kv.gather'):
+            kl, vl = _logical_rows(st, at.bt_row[None], lay.pool, cache)
+    with scope('attn.scores'):
+        if at.ring is not None:
+            from ...parallel.ring_attention import ring_attention
+            att = ring_attention(q, k, v, at.ring, causal=True)
+        else:
+            att = cached_attention(q, kl, vl, at.pos)
+        B, H, T = att.shape[0], att.shape[1], att.shape[2]
+        att = att.transpose(0, 2, 1, 3).reshape(B, T, H * _head_dim(cfg))
+        return _attn_out(w, cfg, att, i), st
+
+
+def _gqa_step_narrow(w, cfg, cache, kernels, lay, h, st, at):
+    """A dense layer's attention over a decode step: h [S, 1, D]
+    normalised -> (what it adds to the stream, the state dict with every
+    slot's row written): over the pool in place with ``kernels.paged``
+    (`ops.attention.paged_attention`: an active slot reads the pages its
+    length covers, an inactive one nothing), else the composed gather
+    (`_logical_rows` + `cached_attention`)."""
+    import jax
+    scope = jax.named_scope
+    i, theta, pos = lay.index, float(cfg['theta']), at.pos
+    with scope('attn.qkv'):
+        q, k, v = _qkv(w, cfg, h, i)
+        q = _rope_at(q, pos[:, None], theta)
+        k = _rope_at(k, pos[:, None], theta)
+    with scope('kv.write'):
+        st = _write_rows(st, lay.pool, at.pg, at.rw, k[:, :, 0, :],
+                         v[:, :, 0, :], cache.quant == 'int8')
+    if not kernels.paged:
+        with scope('kv.gather'):
+            kl, vl = _logical_rows(st, at.bt, lay.pool, cache)
+    with scope('attn.scores'):
+        if kernels.paged:
+            att = paged_attention(q[:, :, 0, :], st['k'], st['v'], at.bt,
+                                  at.n_attend, lay.pool)
+        else:
+            att = cached_attention(q, kl, vl, pos[:, None])
+            att = att.transpose(0, 2, 1, 3)
+        return _attn_out(w, cfg, att.reshape(h.shape[0], 1, -1), i), st
 
 
 def _gqa_qkv(w, cfg, h, i, pos, dtype):
@@ -793,47 +803,50 @@ def _head_rows_of(rows, dh):
         0, 1, 3, 2, 4).reshape(B, hp * (wide // dh), T, dh)
 
 
-def _gqa_prefill(w, cfg, cache, h, i, j, pos, st, pg, rw, bt_row):
-    """A ``'gqa'`` layer of a prefill chunk: h [C, D] float32 normalised
-    -> (the mixer's output [C, D] float32, the state dict with the
-    chunk's rows written into layer ``j`` of the pools): the dense
-    block's write, gather and `cached_attention`."""
+def _gqa_prefill(w, cfg, cache, kernels, lay, h, st, at):
+    """A ``'gqa'`` layer of a prefill chunk under the wide stream: h [C,
+    D] float32 normalised -> (the mixer's output [C, D] float32, the
+    state dict with the chunk's rows written into layer ``lay.pool`` of
+    the pools): the dense block's write, gather and `cached_attention`."""
     import jax
     scope = jax.named_scope
-    q, k, v = _gqa_qkv(w, cfg, h[None], i, pos, st['k'].dtype)
+    i, j = lay.index, lay.pool
+    q, k, v = _gqa_qkv(w, cfg, h[None], i, at.pos, st['k'].dtype)
     with scope('kv.write'):
-        st = _write_rows(st, j, pg, rw,
+        st = _write_rows(st, j, at.pg, at.rw,
                          _pool_rows(k[0].transpose(1, 0, 2), cache),
                          _pool_rows(v[0].transpose(1, 0, 2), cache), False)
     with scope('kv.gather'):
         kl, vl = (_head_rows_of(rows, q.shape[-1]) for rows in
-                  _logical_rows(st, bt_row[None], j, cache))
+                  _logical_rows(st, at.bt_row[None], j, cache))
     with scope('attn.scores'):
-        att = cached_attention(q, kl, vl, pos)            # [1, H, C, dh]
+        att = cached_attention(q, kl, vl, at.pos)         # [1, H, C, dh]
         att = att[0].transpose(1, 0, 2).reshape(h.shape[0], -1)
         return _latent.dot(att, w['layer_%d_att_o_w' % i]), st
 
 
-def _gqa_step(w, cfg, cache, h, i, j, pos, st, pg, rw, bt, n_attend, paged):
-    """A ``'gqa'`` layer of a decode step: h [S, D] float32 normalised ->
-    (the mixer's output [S, D] float32, the state dict with every slot's
-    row written): in place over the pool with ``paged``
-    (`ops.attention.paged_attention`), else the composed gather."""
+def _gqa_step(w, cfg, cache, kernels, lay, h, st, at):
+    """A ``'gqa'`` layer of a decode step under the wide stream: h [S, D]
+    float32 normalised -> (the mixer's output [S, D] float32, the state
+    dict with every slot's row written): in place over the pool with
+    ``kernels.paged`` (`ops.attention.paged_attention`), else the
+    composed gather."""
     import jax
     scope = jax.named_scope
-    S = h.shape[0]
+    i, j, pos, S = lay.index, lay.pool, at.pos, h.shape[0]
     q, k, v = _gqa_qkv(w, cfg, h[:, None], i, pos[:, None], st['k'].dtype)
     with scope('kv.write'):
-        st = _write_rows(st, j, pg, rw, _pool_rows(k[:, :, 0, :], cache),
+        st = _write_rows(st, j, at.pg, at.rw,
+                         _pool_rows(k[:, :, 0, :], cache),
                          _pool_rows(v[:, :, 0, :], cache), False)
-    if not paged:
+    if not kernels.paged:
         with scope('kv.gather'):
             kl, vl = (_head_rows_of(rows, q.shape[-1]) for rows in
-                      _logical_rows(st, bt, j, cache))
+                      _logical_rows(st, at.bt, j, cache))
     with scope('attn.scores'):
-        if paged:
-            att = paged_attention(q[:, :, 0, :], st['k'], st['v'], bt,
-                                  n_attend, j)
+        if kernels.paged:
+            att = paged_attention(q[:, :, 0, :], st['k'], st['v'], at.bt,
+                                  at.n_attend, j)
         else:
             att = cached_attention(q, kl, vl, pos[:, None]).transpose(
                 0, 2, 1, 3)
@@ -841,8 +854,38 @@ def _gqa_step(w, cfg, cache, h, i, j, pos, st, pg, rw, bt, n_attend, paged):
                            w['layer_%d_att_o_w' % i]), st
 
 
-def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False,
-                experts_kernel=False):
+def _of_no_layer(counted):
+    return lambda n, *where: counted(0, *where)
+
+
+# every kind of mixer a layer may have (mixer.py), in the order a wide
+# launch's counts follow: the kinds that attend, then those that hold
+# state.  ``'gqa'``'s wide launches carry latent attention's count of
+# rows, as zero (what its kernel reads is counted on the host,
+# `DecodeRuntime._window_rows_read`): the slot stood in every wide
+# launch's array before a model attended through anything else
+_MIXERS = {
+    'latent': _latent.MIXER,
+    'gqa': Mixer(
+        weight_shapes=_gqa_weights, pool=_gqa_pool,
+        prepared=lambda cfg: {s: (t,) for s, t in _PREPARED.items()},
+        dims=lambda cfg: (_head_dim(cfg),),
+        prepare=_prepare_qkv, public=_public_weight,
+        public_rows=_gqa_public_rows,
+        kernels=lambda cfg, cache, chunk, mesh: {
+            'paged': paged_attention_eligible(
+                cache.pool_shape, cache.store_dtype, mesh)},
+        stats=_latent.MIXER.stats,
+        counted=tuple(map(_of_no_layer, _latent.MIXER.counted)),
+        narrow=(_gqa_prefill_narrow, _gqa_step_narrow),
+        wide=(_gqa_prefill, _gqa_step)),
+    'kda': _kda.MIXER,
+    'conv': _shortconv.MIXER,
+    'ssm': _ssm.MIXER,
+}
+
+
+def _prefill_fn(cfg, cache, chunk, ring_mesh=None, kernels=Kernels()):
     """Build the one-chunk (or one-shot ring) prefill function.
 
     Scatters the chunk's K/V rows into the pages ``bt_row`` maps at the
@@ -853,34 +896,20 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False,
     stores it in tok[slot].  Only the final chunk's draw (the request's
     FIRST token, the TTFT token) survives.
 
-    A ``latent_moe`` model's layers take their own branch (the layer's
-    mixer, `latent.prefill` over the latent pool unless ``cfg['mixer']``
-    names another, then the layer's feed-forward kind), carry the
-    residual stream in float32, and the function returns a fourth value,
-    the chunk's `_LAUNCH_STATS`.  ``latent_kernel``
-    (`DecodeRuntime.prefill_kernel`) keeps that attention's scores on
-    chip (`ops.attention.latent_prefill`); otherwise its block loop is
-    composed of XLA operations.  ``experts_kernel``
-    (`DecodeRuntime.experts_kernel`) is `experts.routed`'s.
+    Every layer is its norm, its mixers' prefill halves (mixer.py; each
+    reads its own field of ``kernels``, `DecodeRuntime.kernels`) and its
+    feed-forward.  Under the wide stream the function returns a fourth
+    value, the chunk's `_launch_stats`.
     """
     import jax.numpy as jnp
-    L = int(cfg['n_layer'])
-    theta = float(cfg['theta'])
+    lays = _layers(cfg)
+    wide = lays[0].wide
     M, PL = cache.max_pages, cache.page_len
-    quant = cache.quant == 'int8'
-    recurrent = _recurrent(cfg)
-    latent_moe = _latent_moe(cfg)
-    dh = _head_dim(cfg)
-    if latent_moe:
-        mixers, axis = _mixer_kinds(cfg), _layer_axes(cfg)
-        n_latent = mixers.count('latent')
-        with_kda = 'kda' in mixers
 
-    if ring_mesh is not None:
-        if recurrent or latent_moe:
-            raise ValueError('ring prefill carries neither recurrent state '
-                             'nor a latent pool')
-        from ...parallel.ring_attention import ring_attention
+    if ring_mesh is not None and any(
+            lay.wide or lay.state is not None for lay in lays):
+        raise ValueError('ring prefill carries neither recurrent state '
+                         'nor a latent pool')
 
     def prefill(w, st, bt_row, tokens, slot, offset, true_count,
                 seed, temperature, top_k):
@@ -892,88 +921,38 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False,
         pg = jnp.where(valid,
                        bt_row[jnp.clip(p_abs // PL, 0, M - 1)], 0)
         rw = p_abs % PL
+        at = Chunk(slot, offset, true_count, pos, p_abs, valid, pg, rw,
+                   bt_row, ring_mesh)
         with scope('embed'):
             x = _embed(w, cfg, tokens)[None]              # [1, C, D]
-        if latent_moe:
+        if wide:
             x = x[0].astype(jnp.float32)                  # [C, D]
             stats = jnp.zeros((len(_experts.STATS),), jnp.int32)
-        for i in range(L):
+        for lay in lays:
+            norm = w['layer_%d_att_norm' % lay.index]
             # ONE scope name for every layer: an operation's op_name
             # says which part of the block it is, whatever its index
             with scope('layer'):
-                if latent_moe:
-                    h = _latent.rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
-                    j = axis[i]
-                    if mixers[i] == 'kda':
-                        # the slot's state as the last chunk left it; a
-                        # prompt's first chunk starts from zeros
-                        carried = offset > 0
-                        att, S, tail = _kda.prefill_mixer(
-                            w, 'layer_%d_' % i, cfg, h,
-                            jnp.where(carried, st['ssm'][slot, j], 0.0),
-                            jnp.where(carried, st['conv'][slot, j], 0.0),
-                            true_count)
-                        st = dict(st, ssm=st['ssm'].at[slot, j].set(S),
-                                  conv=st['conv'].at[slot, j].set(tail))
-                    elif mixers[i] == 'conv':
-                        # likewise the slot's tail, all the state it has
-                        att, tail = _shortconv.prefill_mixer(
-                            w, 'layer_%d_' % i, cfg, h,
-                            jnp.where(offset > 0, st['conv'][slot, j], 0.0),
-                            true_count)
-                        st = dict(st, conv=st['conv'].at[slot, j].set(tail))
-                    elif mixers[i] == 'gqa':
-                        att, st = _gqa_prefill(w, cfg, cache, h, i, j, pos,
-                                               st, pg, rw, bt_row)
-                    else:
-                        att, pool = _latent.prefill(
-                            w, 'layer_%d_' % i, cfg, h, p_abs,
-                            offset + true_count, st['k'], j, pg, rw, bt_row,
-                            latent_kernel)
-                        st = dict(st, k=pool)
+                if lay.wide:
+                    h = _latent.rms(x, norm, _eps(cfg))
+                    kind, = lay.mixers    # one a layer under this stream
+                    mixed, st = _MIXERS[kind].wide[0](
+                        w, cfg, cache, kernels, lay, h, st, at)
                     with scope('ffn'):
-                        x, counted = _latent_moe_ffn(
-                            w, cfg, x + att, i, valid, experts_kernel)
+                        x, counted = _wide_ffn(w, cfg, lay, x + mixed,
+                                               valid, kernels)
                     stats = stats + counted
                     continue
                 with scope('attn.qkv'):
-                    h = _rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
-                    q, k, v = _qkv(w, cfg, h, i)
-                    q = _rope_at(q, pos, theta)
-                    k = _rope_at(k, pos, theta)
-                with scope('kv.write'):
-                    st = _write_rows(st, i, pg, rw, k[0].transpose(1, 0, 2),
-                                     v[0].transpose(1, 0, 2), quant)
-                if ring_mesh is None:
-                    with scope('kv.gather'):
-                        kl, vl = _logical_rows(st, bt_row[None], i, cache)
-                with scope('attn.scores'):
-                    if ring_mesh is not None:
-                        # one-shot long-context prefill (offset == 0):
-                        # the exact ppermute ring over the whole prompt
-                        att = ring_attention(q, k, v, ring_mesh, causal=True)
-                    else:
-                        att = cached_attention(q, kl, vl, pos)
-                    B, H, T = att.shape[0], att.shape[1], att.shape[2]
-                    att = att.transpose(0, 2, 1, 3).reshape(B, T, H * dh)
-                    x = x + _attn_out(w, cfg, att, i)
-                if recurrent:
-                    # the slot's state as the last chunk left it; a
-                    # prompt's first chunk starts from zeros, whoever
-                    # held the slot before
-                    carried = offset > 0
-                    mix, S, tail = _ssm.prefill_mixer(
-                        w, 'layer_%d_' % i, cfg, h[0],
-                        jnp.where(carried, st['ssm'][slot, i], 0.0),
-                        jnp.where(carried, st['conv'][slot, i], 0.0),
-                        true_count)
-                    st = dict(st, ssm=st['ssm'].at[slot, i].set(S),
-                              conv=st['conv'].at[slot, i].set(tail))
-                    x = x + _scaled(cfg, mix[None], 'ssm_out')
+                    h = _rms(x, norm, _eps(cfg))
+                for kind in lay.mixers:
+                    mixed, st = _MIXERS[kind].narrow[0](
+                        w, cfg, cache, kernels, lay, h, st, at)
+                    x = x + mixed
                 with scope('ffn'):
-                    x = _ffn(w, cfg, x, i)
+                    x = _ffn(w, cfg, x, lay.index)
         with scope('lm_head'):
-            if latent_moe:
+            if wide:
                 last = jax.lax.dynamic_slice_in_dim(x, true_count - 1, 1)[0]
                 logits = _latent.dot(
                     _latent.rms(last, w['final_norm'], _eps(cfg)),
@@ -990,59 +969,33 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, latent_kernel=False,
         st = dict(st)
         st['lengths'] = st['lengths'].at[slot].set(new_len)
         st['tok'] = st['tok'].at[slot].set(nxt)
-        if latent_moe:
-            # the blocks of cached rows the chunk visited, in every layer
-            # that attends
-            rows = n_latent * _latent.prefill_rows(new_len, M * PL)
-            handed = [stats, rows.astype(jnp.int32).reshape(1)]
-            if with_kda:
-                handed.append(jnp.stack([jnp.int32(0),
-                                         true_count.astype(jnp.int32),
-                                         jnp.int32(0)]))
-            return st, nxt, logits, jnp.concatenate(handed)
+        if wide:
+            return st, nxt, logits, _counted(lays, cache, stats, 0, new_len,
+                                             true_count)
         return st, nxt, logits
 
     return prefill
 
 
-def _step_fn(cfg, cache, paged, state_kernel, experts_kernel=False):
+def _step_fn(cfg, cache, kernels):
     """One fused decode/verify step over ALL slots: write the fed token's
     K/V through the block table, attend, sample each slot's next token
     with the position-keyed stream, advance ACTIVE slots only.  Inactive
     slots compute masked garbage routed to page 0.
 
-    ``paged`` (`DecodeRuntime.paged`) attends over the pool in place
-    (`ops.attention.paged_attention`): an active slot reads the pages
-    its length covers, an inactive one nothing.  Otherwise the composed
-    path gathers every slot's logical row first (`_logical_rows` +
-    `cached_attention`).
-
-    ``state_kernel`` (`DecodeRuntime.state_kernel`) advances a recurrent
-    model's scan state in place over the live slots (`ssm.ssm_step`);
-    otherwise every slot's steps and the dead ones' is masked.
-
-    A ``latent_moe`` model's layers take their own branch: `latent.step`
-    (absorbed; with ``paged`` over the latent pool in place through
-    `ops.attention.latent_attention`), then the layer's feed-forward
-    kind, where a slot that rides along routes nowhere; a ``'kda'``
-    layer advances the live slots' matrix state and convolution tails
-    (`kda.step_mixer`: both in place through `kda.kda_step` with
-    ``state_kernel``, else every slot steps and a dead one's are kept), a
-    ``'conv'`` layer their
-    tails (`shortconv.step_mixer`), a ``'gqa'`` layer attends as the
-    dense block does (`_gqa_step`).  Its step returns a third
-    value, the step's `_launch_stats`; ``experts_kernel``
-    (`DecodeRuntime.experts_kernel`) is `experts.routed`'s."""
+    Every layer is its norm, its mixers' step halves (mixer.py) and its
+    feed-forward, where a slot that rides along routes nowhere.
+    ``kernels`` (`DecodeRuntime.kernels`) says which of them run in
+    place: ``paged`` attends over the pool, an active slot reading the
+    pages its length covers and an inactive one nothing, where the
+    composed path gathers every slot's logical row first; ``state``
+    advances the live slots' recurrent state, where otherwise every slot
+    steps and the dead ones' is masked.  Under the wide stream the step
+    returns a third value, its `_launch_stats`."""
     import jax.numpy as jnp
-    L = int(cfg['n_layer'])
-    theta = float(cfg['theta'])
+    lays = _layers(cfg)
+    wide = lays[0].wide
     M, PL = cache.max_pages, cache.page_len
-    quant = cache.quant == 'int8'
-    recurrent = _recurrent(cfg)
-    latent_moe = _latent_moe(cfg)
-    if latent_moe:
-        mixers, axis = _mixer_kinds(cfg), _layer_axes(cfg)
-        n_latent, n_kda = mixers.count('latent'), mixers.count('kda')
 
     def step(w, st, bt, fed, active, seeds, temps, topks):
         import jax
@@ -1053,73 +1006,35 @@ def _step_fn(cfg, cache, paged, state_kernel, experts_kernel=False):
         pg = jnp.where(active, pg, 0)
         rw = pos % PL
         n_attend = jnp.where(active, pos + 1, 0)          # [S]
+        at = Step(active, pos, pg, rw, bt, n_attend)
         with scope('embed'):
             x = _embed(w, cfg, fed)[:, None, :]           # [S, 1, D]
-        if latent_moe:
+        if wide:
             x = x[:, 0].astype(jnp.float32)               # [S, D]
             stats = jnp.zeros((len(_experts.STATS),), jnp.int32)
-        for i in range(L):
+        for lay in lays:
+            norm = w['layer_%d_att_norm' % lay.index]
             with scope('layer'):     # one name for every layer (prefill)
-                if latent_moe:
-                    h = _latent.rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
-                    j = axis[i]
-                    if mixers[i] == 'kda':
-                        # an inactive slot keeps both kinds of state
-                        att, matrices, tails = _kda.step_mixer(
-                            w, 'layer_%d_' % i, cfg, h, st['ssm'], j,
-                            st['conv'], active, state_kernel)
-                        st = dict(st, ssm=matrices, conv=tails)
-                    elif mixers[i] == 'conv':
-                        att, tail = _shortconv.step_mixer(
-                            w, 'layer_%d_' % i, cfg, h, st['conv'][:, j],
-                            active)
-                        st = dict(st, conv=st['conv'].at[:, j].set(tail))
-                    elif mixers[i] == 'gqa':
-                        att, st = _gqa_step(w, cfg, cache, h, i, j, pos, st,
-                                            pg, rw, bt, n_attend, paged)
-                    else:
-                        att, pool = _latent.step(
-                            w, 'layer_%d_' % i, cfg, h, pos, st['k'], j, pg,
-                            rw, bt, n_attend, paged)
-                        st = dict(st, k=pool)
+                if lay.wide:
+                    h = _latent.rms(x, norm, _eps(cfg))
+                    kind, = lay.mixers    # one a layer under this stream
+                    mixed, st = _MIXERS[kind].wide[1](
+                        w, cfg, cache, kernels, lay, h, st, at)
                     with scope('ffn'):
-                        x, counted = _latent_moe_ffn(
-                            w, cfg, x + att, i, active, experts_kernel)
+                        x, counted = _wide_ffn(w, cfg, lay, x + mixed,
+                                               active, kernels)
                     stats = stats + counted
                     continue
                 with scope('attn.qkv'):
-                    h = _rms(x, w['layer_%d_att_norm' % i], _eps(cfg))
-                    q, k, v = _qkv(w, cfg, h, i)
-                    q = _rope_at(q, pos[:, None], theta)
-                    k = _rope_at(k, pos[:, None], theta)
-                with scope('kv.write'):
-                    st = _write_rows(st, i, pg, rw, k[:, :, 0, :],
-                                     v[:, :, 0, :], quant)
-                if not paged:
-                    with scope('kv.gather'):
-                        kl, vl = _logical_rows(st, bt, i, cache)
-                with scope('attn.scores'):
-                    if paged:
-                        att = paged_attention(q[:, :, 0, :], st['k'],
-                                              st['v'], bt, n_attend, i)
-                    else:
-                        att = cached_attention(q, kl, vl, pos[:, None])
-                        att = att.transpose(0, 2, 1, 3)
-                    x = x + _attn_out(w, cfg, att.reshape(S, 1, -1), i)
-                if recurrent:
-                    # an inactive slot keeps both kinds of state
-                    mix, scan_state, tail = _ssm.step_mixer(
-                        w, 'layer_%d_' % i, cfg, h[:, 0], st['ssm'], i,
-                        st['conv'][:, i], active, state_kernel)
-                    st = dict(
-                        st, ssm=scan_state,
-                        conv=st['conv'].at[:, i].set(jnp.where(
-                            active[:, None, None], tail, st['conv'][:, i])))
-                    x = x + _scaled(cfg, mix[:, None], 'ssm_out')
+                    h = _rms(x, norm, _eps(cfg))
+                for kind in lay.mixers:
+                    mixed, st = _MIXERS[kind].narrow[1](
+                        w, cfg, cache, kernels, lay, h, st, at)
+                    x = x + mixed
                 with scope('ffn'):
-                    x = _ffn(w, cfg, x, i)
+                    x = _ffn(w, cfg, x, lay.index)
         with scope('lm_head'):
-            if latent_moe:
+            if wide:
                 logits = _latent.dot(
                     _latent.rms(x, w['final_norm'], _eps(cfg)),
                     w['lm_proj_w'])                       # [S, V] float32
@@ -1131,90 +1046,60 @@ def _step_fn(cfg, cache, paged, state_kernel, experts_kernel=False):
         st = dict(st)
         st['tok'] = jnp.where(active, nxt, st['tok'])
         st['lengths'] = jnp.where(active, pos + 1, pos)
-        if latent_moe:
-            # rows a layer reads: in place, the whole pages a live slot's
-            # positions cover (`paged_attention_rows`); gathered, every
-            # slot's ``max_len``
-            rows = jnp.sum(-(-n_attend // PL) * PL) if paged else S * M * PL
-            handed = [stats,
-                      jnp.asarray(n_latent * rows, jnp.int32).reshape(1)]
-            if n_kda:
-                # the kernel moves the live slots' state and tail in every
-                # `kda` layer, the composed step every slot's (kda.py)
-                moved = jnp.asarray(n_kda * (
-                    jnp.sum(active, dtype=jnp.int32) if state_kernel
-                    else S), jnp.int32)
-                handed.append(jnp.stack([moved, jnp.int32(0), moved]))
-            return st, nxt, jnp.concatenate(handed)
+        if wide:
+            return st, nxt, _counted(lays, cache, stats, 1, kernels, at)
         return st, nxt
 
     return step
 
 
-def _counted_window(step_body, st, xs, steps):
-    """`lax.scan` of a window whose step counts: ``step_body(carry, x)``
-    returns (state, tokens [S], `_LAUNCH_STATS`).  Returns (state, tokens
-    [S, K], the counts summed over the window)."""
+def _window_fn(cfg, cache, steps, kernels, verify):
+    """A K-step window over `_step_fn`: one `lax.scan`, the state dict
+    its donated carry, the block table closed-over DATA (an ordinary
+    traced argument).  A step feeds every slot's own carry token, or
+    with ``verify`` row j of ``fed`` [K, S].  Returns (state, tokens [S,
+    K]) and, where the step counts (`_launch_stats`), the counts summed
+    over the window."""
     import jax
 
-    def body(carry, x):
-        carry, nxt, stats = step_body(carry, x)
-        return carry, (nxt, stats)
+    step = _step_fn(cfg, cache, kernels)
+    counts = _layers(cfg)[0].wide
 
-    st, (toks, stats) = jax.lax.scan(body, st, xs, length=steps)
-    return st, toks.T, stats.sum(axis=0)
+    def run(w, st, bt, fed, active, seeds, temps, topks):
+        def body(carry, fed_t):
+            carry, *out = step(w, carry, bt,
+                               fed_t if verify else carry['tok'], active,
+                               seeds, temps, topks)
+            return carry, tuple(out)
+        st, out = jax.lax.scan(body, st, fed, length=steps)
+        if counts:
+            return st, out[0].T, out[1].sum(axis=0)
+        return st, out[0].T                               # [S, K]
+
+    return run
 
 
-def _decode_fn(cfg, cache, steps, paged, state_kernel, experts_kernel=False):
+def _decode_fn(cfg, cache, steps, kernels=Kernels()):
     """K-step fused decode window: each step feeds every slot's own
-    carry token.  One `lax.scan`; the state dict is donated carry; the
-    block table is closed-over DATA (an ordinary traced argument)."""
-    import jax
-
-    step = _step_fn(cfg, cache, paged, state_kernel, experts_kernel)
-
-    if _latent_moe(cfg):
-        def window(w, st, bt, active, seeds, temps, topks):
-            return _counted_window(
-                lambda carry, _: step(w, carry, bt, carry['tok'], active,
-                                      seeds, temps, topks), st, None, steps)
-        return window
+    carry token (`_window_fn`)."""
+    run = _window_fn(cfg, cache, steps, kernels, False)
 
     def window(w, st, bt, active, seeds, temps, topks):
-        def body(carry, _):
-            carry, nxt = step(w, carry, bt, carry['tok'], active, seeds,
-                              temps, topks)
-            return carry, nxt
-        st, toks = jax.lax.scan(body, st, None, length=steps)
-        return st, toks.T                                 # [S, K]
+        return run(w, st, bt, None, active, seeds, temps, topks)
 
     return window
 
 
-def _verify_fn(cfg, cache, steps, paged, state_kernel, experts_kernel=False):
+def _verify_fn(cfg, cache, steps, kernels=Kernels()):
     """K-step speculative VERIFY window: identical step body, but step j
     feeds ``fed[j]`` (host-built: last emitted token, then the draft's
     proposals) and the returned samples are the target model's verdicts
     g_j at each position.  Same `(seed, position)` sampling as decode —
     an accepted prefix is bitwise the sequential stream."""
-    import jax
-
-    step = _step_fn(cfg, cache, paged, state_kernel, experts_kernel)
-
-    if _latent_moe(cfg):
-        def window(w, st, bt, fed, active, seeds, temps, topks):
-            return _counted_window(
-                lambda carry, fed_t: step(w, carry, bt, fed_t, active, seeds,
-                                          temps, topks), st, fed, steps)
-        return window
+    run = _window_fn(cfg, cache, steps, kernels, True)
 
     def window(w, st, bt, fed, active, seeds, temps, topks):
-        def body(carry, fed_t):
-            carry, nxt = step(w, carry, bt, fed_t, active, seeds, temps,
-                              topks)
-            return carry, nxt
-        st, toks = jax.lax.scan(body, st, fed)            # fed: [K, S]
-        return st, toks.T                                 # [S, K]
+        return run(w, st, bt, fed, active, seeds, temps, topks)
 
     return window
 
@@ -1383,42 +1268,18 @@ class DecodeRuntime(object):
             init.args.update(prepared=len(made), prepared_bytes=made_bytes)
             _obs.metrics.gauge('generation.prepared_weight_bytes').set(
                 made_bytes)
-            self.recurrent = _recurrent(cfg)
-            self.latent_moe = _latent_moe(cfg)
-            if self.latent_moe:
-                _ffn_kinds(cfg)
-                # the pool's layers are those that attend, the state's
-                # those that hold one (`_mixer_kinds`)
-                mixers = _mixer_kinds(cfg)
-                attend = sum(k in _ATTENDING for k in mixers)
-                if _attending_mixer(cfg) == 'gqa':
-                    # the dense block's K and V pools, a narrow head's kv
-                    # heads side by side in a row (`paged_pool_heads`)
-                    heads, width = paged_pool_heads(int(cfg['n_kv_head']),
-                                                    _head_dim(cfg))
-                    geometry = dict(kv_heads=heads, head_dim=width,
-                                    layers=attend)
-                else:
-                    # the second pool geometry: one row a token a layer
-                    lat = cfg['latent']
-                    geometry = dict(kv_heads=1,
-                                    head_dim=_latent.stored_width(lat),
-                                    latent=int(lat['kv_rank']),
-                                    layers=attend)
-                if self.recurrent:
-                    geometry.update(
-                        recurrent=(
-                            _kda.state_shapes(cfg['kda'])
-                            if _state_mixer(cfg) == 'kda' else
-                            _shortconv.state_shapes(int(cfg['d_model']),
-                                                    cfg['conv'])),
-                        recurrent_layers=int(cfg['n_layer']) - attend)
-            else:
-                geometry = dict(kv_heads=int(cfg['n_kv_head']),
-                                head_dim=_head_dim(cfg),
-                                layers=int(cfg['n_layer']),
-                                recurrent=(_ssm.state_shapes(cfg['ssm'])
-                                           if self.recurrent else None))
+            # one pool geometry and one state geometry, each over the
+            # layers that have it (`_layers`), as the mixers describe them
+            self.layers = lays = _layers(cfg)
+            geometry = {}
+            for kind, n in _kinds(lays):
+                entry = _MIXERS[kind]
+                if entry.pool:
+                    geometry.update(entry.pool(cfg, lays[0].wide), layers=n)
+                    self._attends = entry
+                if entry.recurrent:
+                    geometry.update(recurrent=entry.recurrent(cfg),
+                                    recurrent_layers=n)
             self.cache = CacheConfig(
                 slots=slots, max_len=int(cfg['max_len']), dtype=cache_dtype,
                 page_len=page_len, pages=pages, quant=kv_quant, **geometry)
@@ -1441,44 +1302,26 @@ class DecodeRuntime(object):
             self.mesh = mesh
             self.ring_min_len = (int(ring_min_len) if ring_min_len is not None
                                  else 2 * self.prefill_chunk)
-            # the decode step attends over the pool in place where the
-            # kernel can run (a floating pool, one device); an int8 pool and
-            # a mesh of several devices keep the composed gather
-            if self.cache.latent is not None:
-                self.paged = latent_attention_eligible(
-                    self.cache.pool_shape, self.cache.store_dtype,
-                    self.cache.latent, mesh)
-            else:
-                self.paged = paged_attention_eligible(
-                    self.cache.pool_shape, self.cache.store_dtype, mesh)
-            # likewise the scan state (the matrix state) of a recurrent
-            # model: in place over the live slots where its kernel can run
-            # (float32, one device)
-            # (a model whose state is convolution tails alone has none)
-            self.state_kernel = 'ssm' in self.state and (
-                _kda.kda_step_eligible(
-                    self.state['ssm'].shape, self.state['conv'].shape,
-                    self.state['ssm'].dtype, mesh) if self.latent_moe
-                else _ssm.ssm_step_eligible(
-                    self.state['ssm'].shape, self.state['ssm'].dtype, mesh))
-            # and a latent chunk's scores: on chip where that kernel
-            # can run, else through HBM a block at a time
-            self.prefill_kernel = self.cache.latent is not None \
-                and _latent.prefill_kernel(
-                    cfg, self.cache, self.prefill_chunk, mesh)
-            # and the grouped route of its routed experts: only the
-            # matrices of the experts with rows, by a kernel, else by
-            # `ragged_dot`
-            self.experts_kernel = self.latent_moe and 'moe' in cfg \
-                and _experts.gmm_eligible(_experts.weight_shapes(
-                    int(cfg['d_model']), cfg['moe'])['moe_fc1_w'], mesh)
+            # which kernels may run here (a floating pool, one device,
+            # whole tiles), asked once of every mixer the model uses and
+            # of its expert layers: ONE record, which every launch is
+            # built with and a mixer reads its own field of (mixer.py)
+            may = {}
+            for kind, _n in _kinds(lays):
+                may.update(_MIXERS[kind].kernels(
+                    cfg, self.cache, self.prefill_chunk, mesh))
+            if any(lay.ffn == 'experts' for lay in lays):
+                may['experts'] = _experts.gmm_eligible(
+                    _experts.weight_shapes(int(cfg['d_model']),
+                                           cfg['moe'])['moe_fc1_w'], mesh)
+            self.kernels = Kernels(**may)
             self._execs = {}
-            # `latent_moe` launches' `_LAUNCH_STATS`, still on the device,
-            # oldest first, and how many launches' were already moved into
-            # the counters: that happens behind the next read of a launch
+            # wide launches' `_launch_stats`, still on the device, oldest
+            # first, and how many launches' were already moved into the
+            # counters: that happens behind the next read of a launch
             # that came after them (`_count_stats`)
             self._stats, self._counted = [], 0
-            self._stat_names = _launch_stats(cfg) if self.latent_moe else ()
+            self._stat_names = _launch_stats(cfg)
             # arguments uploaded ahead of their launch: {'prefill' | 'window':
             # [(host copy, device array), ...]} (`stage_prefill`, `stage_window`)
             self._staged = {}
@@ -1489,7 +1332,8 @@ class DecodeRuntime(object):
             # rows of K (or V) per layer one COMPOSED step gathers
             self._gathered = (
                 None if self.paged
-                else self.cache.slots * self.cache.max_len if self.latent_moe
+                else self.cache.slots * self.cache.max_len
+                if self.cache.latent is not None
                 else _gathered_rows(self.cache, self._state_structs(),
                                     self._bt_struct(self.cache.slots)))
             self._lock = threading.Lock()
@@ -1497,6 +1341,30 @@ class DecodeRuntime(object):
                 self.cache.bytes())
             _obs.metrics.gauge('generation.recurrent_state_bytes').set(
                 self.cache.recurrent_bytes())
+
+    # ----------------------------------- what the model and the record say
+    @property
+    def recurrent(self):
+        """Whether the model carries recurrent state in any layer."""
+        return any(lay.state is not None for lay in self.layers)
+
+    @property
+    def latent_moe(self):
+        """Whether the model's launches run the wide stream (`_layers`)
+        and hand back `_launch_stats`."""
+        return self.layers[0].wide
+
+    def _kernel(field):
+        """One field of `kernels` under the name it is read by.  Set
+        before the first launch, it builds that runtime's launches
+        without the kernel (a test's composed route)."""
+        def put(self, may):
+            self.kernels = self.kernels._replace(**{field: bool(may)})
+        return property(lambda self: getattr(self.kernels, field), put)
+
+    paged, state_kernel = _kernel('paged'), _kernel('state')
+    prefill_kernel, experts_kernel = _kernel('prefill'), _kernel('experts')
+    del _kernel
 
     # ------------------------------------------------------- geometry
     @property
@@ -1712,8 +1580,7 @@ class DecodeRuntime(object):
         def build():
             fn = _prefill_fn(self.cfg, self.cache, chunk,
                              ring_mesh=self.mesh if ring else None,
-                             latent_kernel=self.prefill_kernel,
-                             experts_kernel=self.experts_kernel)
+                             kernels=self.kernels)
             jitted = jax.jit(fn, donate_argnums=(1,))
             i32 = self._sds((), jax.numpy.int32)
             f32 = self._sds((), jax.numpy.float32)
@@ -1735,8 +1602,7 @@ class DecodeRuntime(object):
 
         def build():
             make = _verify_fn if kind == 'verify' else _decode_fn
-            fn = make(self.cfg, self.cache, steps, self.paged,
-                      self.state_kernel, self.experts_kernel)
+            fn = make(self.cfg, self.cache, steps, self.kernels)
             jitted = jax.jit(fn, donate_argnums=(1,))
             S = self.cache.slots
             vec = lambda dt: self._sds((S,), dt)  # noqa: E731
@@ -1858,17 +1724,15 @@ class DecodeRuntime(object):
         if not _obs.enabled():
             return
         for kind, stats in mine:
-            for name, n in zip(self._stat_names, np.asarray(stats)):
-                if name in _KDA_BYTES:
-                    # counted in slot-layers: each read once, written once
-                    n = int(n) * 2 * _KDA_BYTES[name](self.cfg['kda'])
-                _obs.metrics.counter('generation.' + name).inc(int(n))
+            for (name, unit), n in zip(self._stat_names.items(),
+                                       np.asarray(stats)):
+                n = int(n) * unit
+                _obs.metrics.counter('generation.' + name).inc(n)
                 if kind == 'window':
-                    _obs.metrics.counter(
-                        'generation.window_' + name).inc(int(n))
+                    _obs.metrics.counter('generation.window_' + name).inc(n)
 
     def _launched_stats(self, kind, stats):
-        """Keep a launch's `_LAUNCH_STATS` (on the device) until a read
+        """Keep a launch's `_launch_stats` (on the device) until a read
         behind it moves them into the counters."""
         stats.copy_to_host_async()
         self._stats.append((kind, stats))
@@ -2104,16 +1968,11 @@ class DecodeRuntime(object):
         bt = self.block_tables[int(slot)]
         L, Hkv = self.cache.layers, self.cache.kv_heads
         Tmax, dh = self.cache.max_len, self.cache.head_dim
-        if self.cache.latent is not None:
-            # the one row a token has: k [L, 1, Tmax, kv_rank + rope] in
-            # the public order (`latent.public_rows`), no v
-            rows = np.asarray(st['k'])[bt]         # [M, L, PL, W]
-            rows = rows.transpose(1, 0, 2, 3).reshape(L, 1, Tmax, dh)
-            return (_latent.public_rows(rows, self.cfg['latent']), None,
-                    int(np.asarray(st['lengths'][int(slot)])))
 
         def assemble(pool, scale):
-            rows = np.asarray(pool)[bt]        # [M, L, PL, Hkv, dh]
+            # a latent pool's one row a token is its one head's
+            rows = np.asarray(pool)[bt]        # [M, L, PL, (Hkv,) dh]
+            rows = rows.reshape(rows.shape[:3] + (Hkv, dh))
             rows = rows.transpose(1, 3, 0, 2, 4).reshape(L, Hkv, Tmax, dh)
             if scale is None:
                 return rows
@@ -2121,17 +1980,10 @@ class DecodeRuntime(object):
             sc = sc.transpose(1, 3, 0, 2).reshape(L, Hkv, Tmax)
             return rows.astype(np.float32) * sc[..., None]
 
-        if self.cache.quant == 'int8':
-            k = assemble(st['k'], st['k_scale'])
-            v = assemble(st['v'], st['v_scale'])
-        else:
-            k, v = assemble(st['k'], None), assemble(st['v'], None)
-        if self.latent_moe:
-            # a `gqa` mixer's pool may hold kv heads side by side
-            dh = _head_dim(self.cfg)
-            k, v = _head_rows_of(k, dh), _head_rows_of(v, dh)
-        return (_public_rows(k, dh), v,
-                int(np.asarray(st['lengths'][int(slot)])))
+        k, v = (assemble(st[n], st.get(n + '_scale')) if n in st else None
+                for n in ('k', 'v'))
+        return self._attends.public_rows(self.cfg, k, v) + (
+            int(np.asarray(st['lengths'][int(slot)])),)
 
     def generate(self, prompt, max_new, params=None, steps_per_window=4,
                  use_ring=False, speculative=False):

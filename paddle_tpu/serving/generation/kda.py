@@ -92,6 +92,7 @@ import math
 
 from ...ops import _pallas
 from .latent import dot as _dot, rms as _rms
+from .mixer import Mixer
 from .ssm import _live_slots
 
 __all__ = ['SLOTS', 'weight_shapes', 'state_shapes', 'conv_channels',
@@ -599,3 +600,64 @@ def step_mixer(w, p, cfg, h, state, layer, tails, active, kernel):
                 jnp.where(active[:, None, None, None], new, S))
     return _out(w, p, kda, o, gate, float(cfg.get('rms_eps', 1e-6))), \
         state, tails
+
+
+# ------------------------------------------------ the runtime's entry
+
+def _prefill_layer(w, cfg, cache, kernels, lay, h, st, at):
+    """`prefill_mixer` as a layer of a chunk (mixer.py): from the slot's
+    state as the last chunk left it; a prompt's first chunk starts from
+    zeros."""
+    import jax.numpy as jnp
+    j, carried = lay.state, at.offset > 0
+    out, S, tail = prefill_mixer(
+        w, 'layer_%d_' % lay.index, cfg, h,
+        jnp.where(carried, st['ssm'][at.slot, j], 0.0),
+        jnp.where(carried, st['conv'][at.slot, j], 0.0), at.true_count)
+    return out, dict(st, ssm=st['ssm'].at[at.slot, j].set(S),
+                     conv=st['conv'].at[at.slot, j].set(tail))
+
+
+def _step_layer(w, cfg, cache, kernels, lay, h, st, at):
+    """`step_mixer` as a layer of a step (mixer.py): an inactive slot
+    keeps both kinds of state."""
+    out, matrices, tails = step_mixer(
+        w, 'layer_%d_' % lay.index, cfg, h, st['ssm'], lay.state,
+        st['conv'], at.active, kernels.state)
+    return out, dict(st, ssm=matrices, conv=tails)
+
+
+def _chunk_counted(n, cache, new_len, true_count):
+    import jax.numpy as jnp
+    return [jnp.stack([jnp.int32(0), true_count.astype(jnp.int32),
+                       jnp.int32(0)])]
+
+
+def _step_counted(n, cache, kernels, at):
+    """Slot-layers whose state and tail the step moved: the kernel moves
+    the live slots' in every layer, the composed step every slot's."""
+    import jax.numpy as jnp
+    moved = jnp.asarray(n * (
+        jnp.sum(at.active, dtype=jnp.int32) if kernels.state
+        else at.active.shape[0]), jnp.int32)
+    return [jnp.stack([moved, jnp.int32(0), moved])]
+
+
+def _kernels(cfg, cache, chunk, mesh):
+    shapes = cache.recurrent_shapes()
+    return {'state': kda_step_eligible(shapes['ssm'], shapes['conv'],
+                                       'float32', mesh)}
+
+
+MIXER = Mixer(
+    weight_shapes=lambda cfg: weight_shapes(int(cfg['d_model']), cfg['kda']),
+    recurrent=lambda cfg: state_shapes(cfg['kda']),
+    kernels=_kernels,
+    # slot-layers whose matrix state a window's steps read and wrote (each
+    # read once, written once: bytes), tokens a chunk's scan took,
+    # slot-layers whose convolution tails the steps read and wrote
+    stats=lambda cfg: {'kda_state_bytes': 2 * state_bytes(cfg['kda']),
+                       'kda_chunk_tokens': 1,
+                       'kda_tail_bytes': 2 * tail_bytes(cfg['kda'])},
+    counted=(_chunk_counted, _step_counted),
+    wide=(_prefill_layer, _step_layer))

@@ -65,7 +65,9 @@ import math
 import numpy as np
 
 from ...ops.attention import (latent_attention, latent_attention_composed,
+                              latent_attention_eligible,
                               latent_prefill, latent_prefill_eligible)
+from .mixer import Mixer
 
 __all__ = ['SLOTS', 'PREPARED', 'slots', 'prepared', 'weight_shapes',
            'row_width', 'stored_width',
@@ -462,3 +464,68 @@ def step(w, p, cfg, h, pos, pool, layer, pg, rw, bt, n_attend, paged):
         o = jnp.einsum('shc,hcv->shv', o_lat.astype(wv.dtype), wv,
                        preferred_element_type=jnp.float32)
         return dot(o.reshape(S, -1), w[p + 'att_o_w']), pool
+
+
+# ------------------------------------------------ the runtime's entry
+
+def _prefill_layer(w, cfg, cache, kernels, lay, h, st, at):
+    """`prefill` as a layer of a chunk (mixer.py)."""
+    out, pool = prefill(w, 'layer_%d_' % lay.index, cfg, h, at.p_abs,
+                        at.offset + at.true_count, st['k'], lay.pool, at.pg,
+                        at.rw, at.bt_row, kernels.prefill)
+    return out, dict(st, k=pool)
+
+
+def _step_layer(w, cfg, cache, kernels, lay, h, st, at):
+    """`step` as a layer of a step (mixer.py)."""
+    out, pool = step(w, 'layer_%d_' % lay.index, cfg, h, at.pos, st['k'],
+                     lay.pool, at.pg, at.rw, at.bt, at.n_attend,
+                     kernels.paged)
+    return out, dict(st, k=pool)
+
+
+def _chunk_counted(n, cache, new_len, true_count):
+    """The blocks of cached rows the chunk visited, in every layer that
+    attends."""
+    import jax.numpy as jnp
+    rows = n * prefill_rows(new_len, cache.max_len)
+    return [rows.astype(jnp.int32).reshape(1)]
+
+
+def _step_counted(n, cache, kernels, at):
+    """Rows a layer reads: in place, the whole pages a live slot's
+    positions cover (`paged_attention_rows`); gathered, every slot's
+    ``max_len``."""
+    import jax.numpy as jnp
+    PL = cache.page_len
+    rows = jnp.sum(-(-at.n_attend // PL) * PL) if kernels.paged \
+        else at.bt.shape[0] * cache.max_len
+    return [jnp.asarray(n * rows, jnp.int32).reshape(1)]
+
+
+def _dims(cfg):
+    lat = cfg['latent']
+    return (int(cfg['n_head']), int(lat['nope']), int(lat['rope']),
+            int(lat['v']))
+
+
+def _kernels(cfg, cache, chunk, mesh):
+    return {'paged': latent_attention_eligible(
+                cache.pool_shape, cache.store_dtype, cache.latent, mesh),
+            'prefill': prefill_kernel(cfg, cache, chunk, mesh)}
+
+
+MIXER = Mixer(
+    weight_shapes=lambda cfg: weight_shapes(
+        int(cfg['d_model']), int(cfg['n_head']), cfg['latent']),
+    # the second pool geometry: one row a token a layer
+    pool=lambda cfg, wide: dict(
+        kv_heads=1, head_dim=stored_width(cfg['latent']),
+        latent=int(cfg['latent']['kv_rank'])),
+    prepared=lambda cfg: prepared(cfg['latent']), dims=_dims,
+    prepare=prepare, public=public,
+    public_rows=lambda cfg, k, v: (public_rows(k, cfg['latent']), None),
+    kernels=_kernels,
+    stats=lambda cfg: {'latent_rows_read': 1},
+    counted=(_chunk_counted, _step_counted),
+    wide=(_prefill_layer, _step_layer))

@@ -34,6 +34,7 @@ live slot's tail advances, a dead slot's stays bit for bit what it was.
 inputs in the weights' dtype and accumulate in float32.
 """
 from .latent import dot as _dot
+from .mixer import Mixer
 
 __all__ = ['SLOTS', 'weight_shapes', 'state_shapes', 'prefill_mixer',
            'step_mixer']
@@ -105,3 +106,32 @@ def step_mixer(w, p, cfg, h, tail, active):
         c = jnp.sum(full * w[p + 'conv_taps'].astype(jnp.float32), axis=1)
         tail = jnp.where(active[:, None, None], full[:, 1:], tail)
     return _out(w, p, gate * c), tail
+
+
+# ------------------------------------------------ the runtime's entry
+
+def _prefill_layer(w, cfg, cache, kernels, lay, h, st, at):
+    """`prefill_mixer` as a layer of a chunk (mixer.py): from the slot's
+    tail as the last chunk left it (zeros where the prompt begins)."""
+    import jax.numpy as jnp
+    j = lay.state
+    out, tail = prefill_mixer(
+        w, 'layer_%d_' % lay.index, cfg, h,
+        jnp.where(at.offset > 0, st['conv'][at.slot, j], 0.0),
+        at.true_count)
+    return out, dict(st, conv=st['conv'].at[at.slot, j].set(tail))
+
+
+def _step_layer(w, cfg, cache, kernels, lay, h, st, at):
+    """`step_mixer` as a layer of a step (mixer.py)."""
+    j = lay.state
+    out, tail = step_mixer(w, 'layer_%d_' % lay.index, cfg, h,
+                           st['conv'][:, j], at.active)
+    return out, dict(st, conv=st['conv'].at[:, j].set(tail))
+
+
+MIXER = Mixer(
+    weight_shapes=lambda cfg: weight_shapes(int(cfg['d_model']),
+                                            cfg['conv']),
+    recurrent=lambda cfg: state_shapes(int(cfg['d_model']), cfg['conv']),
+    wide=(_prefill_layer, _step_layer))

@@ -48,6 +48,7 @@ import math
 import numpy as np
 
 from ...ops import _pallas
+from .mixer import Mixer
 
 __all__ = ['SLOTS', 'part_sizes', 'conv_channels', 'weight_shapes',
            'state_shapes', 'prefill_mixer', 'step_mixer', 'scan_chunk',
@@ -420,3 +421,42 @@ def step_mixer(w, p, cfg, h, state, layer, tail, active, kernel):
     out = _gate_out(y.reshape(h.shape[0], -1), z, w, p, ssm,
                     float(cfg['rms_eps']))
     return out, state, tail
+
+
+# ------------------------------------------------ the runtime's entry
+
+def _prefill_layer(w, cfg, cache, kernels, lay, h, st, at):
+    """`prefill_mixer` as a layer of a chunk (mixer.py): from the slot's
+    state as the last chunk left it (a prompt's first chunk starts from
+    zeros, whoever held the slot before) into the slot's state."""
+    import jax.numpy as jnp
+    j, carried = lay.state, at.offset > 0
+    mix, S, tail = prefill_mixer(
+        w, 'layer_%d_' % lay.index, cfg, h[0],
+        jnp.where(carried, st['ssm'][at.slot, j], 0.0),
+        jnp.where(carried, st['conv'][at.slot, j], 0.0), at.true_count)
+    st = dict(st, ssm=st['ssm'].at[at.slot, j].set(S),
+              conv=st['conv'].at[at.slot, j].set(tail))
+    return mix[None] * cfg['multipliers']['ssm_out'], st
+
+
+def _step_layer(w, cfg, cache, kernels, lay, h, st, at):
+    """`step_mixer` as a layer of a step (mixer.py): an inactive slot
+    keeps both kinds of state."""
+    import jax.numpy as jnp
+    j = lay.state
+    mix, scan_state, tail = step_mixer(
+        w, 'layer_%d_' % lay.index, cfg, h[:, 0], st['ssm'], j,
+        st['conv'][:, j], at.active, kernels.state)
+    st = dict(st, ssm=scan_state,
+              conv=st['conv'].at[:, j].set(jnp.where(
+                  at.active[:, None, None], tail, st['conv'][:, j])))
+    return mix[:, None] * cfg['multipliers']['ssm_out'], st
+
+
+MIXER = Mixer(
+    weight_shapes=lambda cfg: weight_shapes(int(cfg['d_model']), cfg['ssm']),
+    recurrent=lambda cfg: state_shapes(cfg['ssm']),
+    kernels=lambda cfg, cache, chunk, mesh: {'state': ssm_step_eligible(
+        cache.recurrent_shapes()['ssm'], 'float32', mesh)},
+    narrow=(_prefill_layer, _step_layer))

@@ -78,6 +78,14 @@ def _prompt(n, seed=0):
         .astype(np.int32)
 
 
+def _own_axes(cfg):
+    """Each layer's index on the axis of what it stores: the pool's
+    layer axis where it attends, the recurrent arrays' where it holds
+    state."""
+    return [lay.state if lay.pool is None else lay.pool
+            for lay in decode._layers(cfg)]
+
+
 def _prefill(rt, prompt):
     slot = rt.alloc_slot()
     assert rt.try_begin(slot, prompt, WINDOW) == 0
@@ -580,7 +588,7 @@ def test_the_pool_holds_the_layers_that_attend_and_the_state_the_others(
     assert rt.cache.bytes() == rt.cache.pages * rt.cache.page_bytes() \
         + rt.cache.recurrent_bytes()
     assert rt.cache.spec()['recurrent_layers'] == 3
-    assert decode._layer_axes(CFG) == [0, 1, 0, 2]
+    assert _own_axes(CFG) == [0, 1, 0, 2]
     # a model that attends in every layer says nothing of it
     falcon = CacheConfig(slots=2, layers=3, kv_heads=2, max_len=16,
                          head_dim=8, recurrent=((2, 4, 4), (3, 8)))
@@ -615,15 +623,16 @@ def test_weights_follow_the_mixer_and_read_back_bit_for_bit(rt, weights):
 
 def test_the_launches_carry_the_scopes(rt):
     S, sds = rt.slots, rt._sds
-    fn = decode._decode_fn(rt.cfg, rt.cache, WINDOW, rt.paged,
-                           rt.state_kernel)
+    fn = decode._decode_fn(rt.cfg, rt.cache, WINDOW,
+                           rt.kernels._replace(experts=False))
     window = jax.jit(fn).lower(
         rt._param_structs(), rt._state_structs(), rt._bt_struct(S),
         sds((S,), jnp.bool_), sds((S,), jnp.int32), sds((S,), jnp.float32),
         sds((S,), jnp.int32)).as_text(debug_info=True)
     i32, f32 = sds((), jnp.int32), sds((), jnp.float32)
     chunk = jax.jit(decode._prefill_fn(
-        rt.cfg, rt.cache, CHUNK, latent_kernel=rt.prefill_kernel)).lower(
+        rt.cfg, rt.cache, CHUNK,
+        kernels=rt.kernels._replace(experts=False))).lower(
         rt._param_structs(), rt._state_structs(),
         sds((rt.cache.max_pages,), jnp.int32), sds((CHUNK,), jnp.int32),
         i32, i32, i32, i32, f32, i32).as_text(debug_info=True)
@@ -799,15 +808,16 @@ def _lowered(block, kind):
     sds = rt._sds
     i32, f32 = sds((), jnp.int32), sds((), jnp.float32)
     S = rt.slots
+    # the pins were taken without the grouped expert kernel
+    kernels = rt.kernels._replace(experts=False)
     if kind == 'prefill':
-        fn = decode._prefill_fn(rt.cfg, rt.cache, chunk,
-                                latent_kernel=rt.prefill_kernel)
+        fn = decode._prefill_fn(rt.cfg, rt.cache, chunk, kernels=kernels)
         args = [rt._param_structs(), rt._state_structs(),
                 sds((rt.cache.max_pages,), jnp.int32),
                 sds((chunk,), jnp.int32), i32, i32, i32, i32, f32, i32]
     else:
         make = decode._verify_fn if kind == 'verify' else decode._decode_fn
-        fn = make(rt.cfg, rt.cache, window, rt.paged, rt.state_kernel)
+        fn = make(rt.cfg, rt.cache, window, kernels)
         args = [rt._param_structs(), rt._state_structs(), rt._bt_struct(S)]
         if kind == 'verify':
             args.append(sds((window, S), jnp.int32))

@@ -272,7 +272,8 @@ def test_the_window_lowers_the_kernel_and_the_absorbed_form(rt):
     assert 'v' not in rt.state
     S = rt.slots
     sds = rt._sds
-    fn = decode._decode_fn(rt.cfg, rt.cache, WINDOW, True, False)
+    fn = decode._decode_fn(rt.cfg, rt.cache, WINDOW,
+                           decode.Kernels(paged=True))
     text = jax.jit(fn).lower(
         rt._param_structs(), rt._state_structs(), rt._bt_struct(S),
         sds((S,), jnp.bool_), sds((S,), jnp.int32), sds((S,), jnp.float32),
@@ -740,7 +741,8 @@ def test_the_chunk_lowers_the_route_the_rule_chose(rt, kernel):
     i32, f32 = sds((), jnp.int32), sds((), jnp.float32)
     before = dict(obs.counters())
     text = jax.jit(
-        decode._prefill_fn(rt.cfg, rt.cache, CHUNK, latent_kernel=kernel),
+        decode._prefill_fn(rt.cfg, rt.cache, CHUNK,
+                           kernels=decode.Kernels(prefill=kernel)),
         donate_argnums=(1,)).lower(
             rt._param_structs(), rt._state_structs(),
             sds((rt.cache.max_pages,), jnp.int32), sds((CHUNK,), jnp.int32),

@@ -104,8 +104,8 @@ def launches(request, one_v5e_chip):
         # Mosaic, not interpret mode: the kernels the chip would run
         patch.setattr(_pallas, 'interpret', lambda: False)
         window = jax.jit(
-            decode._decode_fn(cfg, cache, traffic['decode_window'], True,
-                              recurrent),
+            decode._decode_fn(cfg, cache, traffic['decode_window'],
+                              decode.Kernels(paged=True, state=recurrent)),
             donate_argnums=(1,)).lower(
                 params, state, sds((slots, cache.max_pages), 'int32'),
                 sds((slots,), 'bool'), sds((slots,), 'int32'),

@@ -344,7 +344,8 @@ def test_the_pool_holds_the_layers_that_attend_and_tails_the_others(rt):
     assert rt.cache.pool_shape == (3 * 16 + 1, 2, PAGE, 2, 16)
     assert rt.state['v'].shape == rt.state['k'].shape
     assert rt.state['conv'].shape == (3, 6, 2, 64)
-    assert decode._layer_axes(CFG) == [0, 1, 0, 2, 3, 4, 1, 5]
+    assert [lay.state if lay.pool is None else lay.pool
+            for lay in decode._layers(CFG)] == [0, 1, 0, 2, 3, 4, 1, 5]
     assert rt.cache.bytes() == rt.cache.pages * rt.cache.page_bytes() \
         + 4 * rt.state['conv'].size
     packed = _runtime('head32_packed')
@@ -364,7 +365,7 @@ def test_the_pool_holds_the_layers_that_attend_and_tails_the_others(rt):
 ])
 def test_one_pool_geometry_and_one_state_geometry_a_runtime(mixer, message):
     with pytest.raises(ValueError, match=message):
-        decode._mixer_kinds(dict(CFG, mixer=mixer))
+        decode._layers(dict(CFG, mixer=mixer))
 
 
 def test_weights_follow_the_mixer_and_read_back_bit_for_bit():
@@ -507,15 +508,12 @@ def _lower(rt, kind):
     S, sds = rt.slots, rt._sds
     i32, f32 = sds((), jnp.int32), sds((), jnp.float32)
     if kind == 'prefill':
-        fn = decode._prefill_fn(rt.cfg, rt.cache, CHUNK,
-                                latent_kernel=rt.prefill_kernel,
-                                experts_kernel=rt.experts_kernel)
+        fn = decode._prefill_fn(rt.cfg, rt.cache, CHUNK, kernels=rt.kernels)
         args = [rt._param_structs(), rt._state_structs(),
                 sds((rt.cache.max_pages,), jnp.int32),
                 sds((CHUNK,), jnp.int32), i32, i32, i32, i32, f32, i32]
     else:
-        fn = decode._decode_fn(rt.cfg, rt.cache, WINDOW, rt.paged,
-                               rt.state_kernel, rt.experts_kernel)
+        fn = decode._decode_fn(rt.cfg, rt.cache, WINDOW, rt.kernels)
         args = [rt._param_structs(), rt._state_structs(), rt._bt_struct(S),
                 sds((S,), jnp.bool_), sds((S,), jnp.int32),
                 sds((S,), jnp.float32), sds((S,), jnp.int32)]

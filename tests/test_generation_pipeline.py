@@ -556,7 +556,7 @@ def _lowered(rt, kind):
                 sds((CHUNK,), jnp.int32), i32, i32, i32, i32, f32, i32]
     else:
         make = decode._verify_fn if kind == 'verify' else decode._decode_fn
-        fn = make(rt.cfg, rt.cache, WINDOW, rt.paged, rt.state_kernel)
+        fn = make(rt.cfg, rt.cache, WINDOW, rt.kernels)
         args = [params, rt._state_structs(), rt._bt_struct(S)]
         if kind == 'verify':
             args.append(sds((WINDOW, S), jnp.int32))
