@@ -15,33 +15,19 @@ decode server (docs/generation.md):
     per-launch DATA, so one warm executable serves every page
     assignment; chunked/ring prefill; speculative verify windows;
     AOT-compiled and persisted through the compile-cache disk tier.
-  * `ssm` — the Mamba-2 mixer of the `falcon_h1` block kind (a model
-    dict with ``block: 'falcon_h1'``): the chunked scan a prefill chunk
-    runs from and into a slot's recurrent state, and the single step of
-    a decode window; that state lives beside the page pool in the same
-    donated state dict (`CacheConfig.recurrent`).
-  * `latent`, `experts` — the `latent_moe` block kind (a model dict
-    with ``block: 'latent_moe'``): multi-head latent attention over the
-    second pool geometry, ONE row ``[c_kv ; k_r]`` a token a layer
-    (`CacheConfig.latent`), expanded in a prefill chunk and absorbed in
-    a decode step; and per layer (``cfg['ffn']``) a dense SwiGLU or
-    routed experts beside a shared one, as one expert-parallel rank
-    holds them.
-  * `kda` — Kimi Delta Attention, the mixer of the layers a
-    `latent_moe` model marks ``'kda'`` in ``cfg['mixer']`` (a mixer per
-    layer as data): a gated delta rule over a float32 matrix state a
-    head with a decay a channel, in the chunk form for a prefill chunk
-    and a single step for a decode window; its state lives in the
-    recurrent arrays, whose layer axis counts the layers that hold
-    state while the pool's counts those that attend
-    (`CacheConfig.recurrent_layers`).
-  * `shortconv` — the gated short convolution, the mixer of the layers
-    a `latent_moe` model marks ``'conv'``: a causal 3-tap depthwise
-    filter over ``B * x`` under the gate ``C``, whose whole state is
-    the last two rows of its own input (a tail and no scan state in the
-    recurrent arrays).  Beside it a model may attend through ``'gqa'``
-    layers, the dense block's attention over the K and V pools with a
-    norm on every query and key head (``cfg['qk_norm']``).
+  * `mixer` — what a layer's MIXER answers to the runtime, one entry a
+    kind in `decode._MIXERS` (docs/generation.md, "A layer and its
+    mixers"); a model dict names them per layer (`decode._layers`):
+
+    kind      module      stores a layer            kernel of its step
+    'gqa'     decode      K and V rows in the pool  `paged_attention`
+    'latent'  latent      one row ``[c_kv ; k_r]``  `latent_attention`
+    'ssm'     ssm         scan state + conv tail    `ssm.ssm_step`
+    'kda'     kda         matrix state + conv tails `kda.kda_step`
+    'conv'    shortconv   its input's last rows     none
+
+  * `experts` — the feed-forward beside the dense SwiGLU: routed experts
+    beside a shared one (or none), as ONE expert-parallel rank holds them.
   * `sampling` — greedy / temperature / top-k draws keyed by
     ``(request seed, absolute position)`` only, so fused and sequential
     decode sample bitwise-identical streams (ops/sampling.py).

@@ -80,96 +80,51 @@ raw weights and so checks the preparation.
 tests/test_generation_layout.py holds the compiled text (no copy of a
 weight's extent in either launch) and the exactness.
 
-The model dict chooses the BLOCK.  Without a ``block`` key it is the
-dense decoder above (RMSNorm, GQA, RoPE, SwiGLU), whose ``head_dim``
-and ``rms_eps`` default to ``d_model // n_head`` and 1e-6.  ``block:
-'falcon_h1'`` is the Falcon-H1 block: the same attention and
-feed-forward under the model's ``multipliers``, and BESIDE the
-attention, reading the same normalised input, a Mamba-2 mixer (ssm.py)
-whose outputs are both added to the residual stream.  Such a model has
-a second kind of state: per slot and layer a float32 scan state and the
-convolution's last inputs (``ssm`` / ``conv`` in the state dict,
-kv_cache.py), donated and carried like the pools.  A prefill chunk
-starts from the slot's state (from zeros at offset 0) and leaves it at
-``true_count``; a decode step advances the live slots' scan state in
-place (`ssm.ssm_step`, a Pallas kernel that touches no other slot's;
-under a mesh `ssm.scan_step` steps every slot and masks:
-`DecodeRuntime.state_kernel`) and keeps an inactive slot's state as it
-was, bit for bit.  That state can neither be
-shared between prompts nor rolled back, so such a runtime takes no
-prefix-cache hit (`generation.prefix_refused_recurrent` counts the
-begins), no speculative window and no ring prefill.
+WHAT THE MODEL IS is asked in one place.  `_layers` reads the model
+dict (``block``, ``n_layer``, ``mixer``, ``ffn``) into one record a
+layer: its mixers' kinds, its feed-forward, its index on the pool's
+layer axis and on the recurrent arrays', and the model's stream
+convention; it alone refuses a wrong dict.  What follows from a mixer's
+KIND is its entry's in `_MIXERS` (mixer.py says what an entry answers;
+``'gqa'``'s is below, the others' in ssm.py, latent.py, kda.py and
+shortconv.py): its weights, which are kept prepared and how that is
+undone, the pool geometry or recurrent shapes it fills, whether its
+kernels may run here, what its launches count, and its halves of a
+prefill chunk and of a decode step.  Everything else here walks the
+layers and calls the table: a new mixer is one module with one entry.
 
-``block: 'latent_moe'`` is the third kind: multi-head LATENT attention
-(latent.py; or another mixer a layer, below) and, per layer as
-``cfg['ffn']`` says, a dense SwiGLU or routed experts beside a shared
-one (or none), held as ONE expert-parallel rank
-(experts.py; ``cfg['moe']`` says which of ``ranks`` this is).  Its
-weights have other names and shapes (`weight_shapes`), its prepared
-forms are not per-head q/k/v (`latent.PREPARED`: the up-projection
-split into its key and value halves for absorption, rope columns as
-rotated halves), and its pool has the second geometry: ONE row
-``[c_kv ; k_r]`` a token a layer and no V pool (`CacheConfig.latent`),
-under the same pages, block tables and prefix cache.  A prefill chunk
-expands keys and values from the gathered rows, its scores kept on chip
-(`ops.attention.latent_prefill`; a loop of XLA operations under a mesh:
-`DecodeRuntime.prefill_kernel`); a decode step attends
-in the absorbed form over the pool in place
-(`ops.attention.latent_attention`; gathered rows under a mesh:
-`DecodeRuntime.paged`).  The residual stream is float32.  Its two
-launches hand back, beside their tokens, a few counts
-(`_LAUNCH_STATS`: routing's and the latent rows read) that move into
-``generation.moe_*`` / ``generation.latent_rows_read`` behind the next
-read of a result (`DecodeRuntime._count_stats`): no launch and no wait
-of their own.  No ring prefill and no int8 rows.  The other two kinds'
-programs are untouched by it, bit for bit (tests/
-test_generation_pipeline.py pins their lowered text).
+Without a ``block`` key the model is the dense decoder above (RMSNorm,
+GQA, RoPE, SwiGLU; ``head_dim`` and ``rms_eps`` default to ``d_model //
+n_head`` and 1e-6).  ``block: 'falcon_h1'`` puts a Mamba-2 mixer beside
+the attention, on the same normalised input, under the model's
+``multipliers``.  ``block: 'latent_moe'`` gives a mixer a layer as data
+(``cfg['mixer']``, latent attention without the key) and per layer
+(``cfg['ffn']``) a dense SwiGLU or routed experts as ONE expert-parallel
+rank holds them (experts.py).  A runtime has one pool geometry and one
+state geometry (`CacheConfig`), so a model attends through one kind of
+mixer, in at least one layer, and holds state through at most one; the
+pool's layer axis counts the layers that attend, the recurrent arrays'
+those that hold state.  A model that holds state (`recurrent`) starts a
+chunk from the slot's state (zeros at offset 0), advances the live
+slots' in a step and keeps a dead slot's bit for bit; that state can
+neither be shared nor rolled back, so such a runtime takes no prefix-cache
+hit (`generation.prefix_refused_recurrent` counts the begins), no
+speculative window and no ring prefill.
 
-A ``latent_moe`` model may give a MIXER PER LAYER as data, as it gives
-its feed-forward: ``cfg['mixer']``, for each layer ``'latent'`` (the
-default, every layer), ``'kda'``, ``'gqa'`` or ``'conv'``; it must
-ATTEND in at least one layer, through ``'latent'`` layers or through
-``'gqa'`` ones (it need have no ``'latent'`` layer, and then no
-``cfg['latent']``).  A ``'kda'`` layer is Kimi Delta
-Attention (kda.py): a float32 matrix state a head and three short
-convolutions' tails, per slot, in the recurrent arrays of the state
-dict (``[slots, layers, H, d, d]`` and ``[slots, layers, d_conv - 1,
-3 H, d]``), and NO rows in the pool.  The pool's layer axis then counts the
-layers that attend and the state's the layers that hold state
-(`CacheConfig.layers` / ``recurrent_layers``; `_layer_axes` gives each
-layer its index on its own).  Such a model is `recurrent` like
-``falcon_h1``: a chunk starts from the slot's state (zeros at offset
-0), a step advances the live slots' and keeps the dead ones' bit for
-bit, and the runtime takes no prefix-cache hit, no speculative window
-and no ring prefill.  Its launches' stats carry three counts more
-(`_launch_stats`: the state and the tails the windows moved, the tokens
-the chunk scan took).  A model without the key lowers to the program it had
-(tests/test_generation_kda.py pins the text).
-
-Two more mixers may stand there.  ``'gqa'`` is the dense block's own
-attention as a layer's mixer: `_qkv`, `_rope_at`, `_write_rows` and
-`ops.attention.paged_attention` over the K and V pools of the FIRST
-geometry, whose layer axis counts the layers that attend; with
-``cfg['qk_norm']`` a learned RMS norm on every query and key head
-between the projection and the rotation (weights ``att_q_norm`` /
-``att_k_norm`` ``[head_dim]``, the pool's K row is the normed, rotated
-key).  A model attends through ``'latent'`` OR ``'gqa'`` layers, never
-both (one pool geometry a runtime), and in at least one.  Such
-a layer's pool is laid out for the paged kernel
-(`ops.attention.paged_pool_heads`): a head of 64 lies two kv heads to a
-128-lane row, ``[pages, layers, page_len, kv_heads / 2, 128]``, the same
-bytes in the same order (`_pool_rows` / `_head_rows_of`), so the step
-attends in place as at 128 and no lane of a row is padding.  ``'conv'``
-is the gated short convolution (shortconv.py): its whole state is the
-last ``taps - 1`` rows of its own input, the ``conv`` array of the
-state dict with NO ``ssm`` beside it
-(`CacheConfig.recurrent` ``(None, tail)``); such a model is `recurrent`
-like one with ``'kda'`` layers (it holds state through ``'kda'`` OR
-``'conv'`` layers, never both: one state geometry a runtime).  Its
-feed-forward may be an expert layer with NO shared expert, and the
-WHOLE layer (``ranks: 1``; experts.py).  The standing programs are
-untouched by all of it (tests/test_generation_lfm2.py pins this kind's
-two launches as the other files pin theirs).
+TWO STREAM CONVENTIONS stand, on purpose (ROADMAP D1a).  The dense and
+``falcon_h1`` kinds carry the residual stream ``[B, T, D]`` in the
+model's dtype (`_rms`, `_qkv`, `_ffn`, `_head`); ``latent_moe`` carries
+``[T, D]`` float32 and rounds at each product (`latent.rms`,
+`latent.dot`), takes no ring prefill and no int8 rows, and its launches
+hand back, beside their tokens, a few counts (`_launch_stats`: routing's
+and each mixer's own) that move into ``generation.*`` counters behind
+the next read of a result (`DecodeRuntime._count_stats`): no launch and
+no wait of their own.  The layer loops choose their body by that one
+field (`_Layer.wide`) and ask the model nothing else.  Which kernels run
+is one record (`DecodeRuntime.kernels`, mixer.py) every launch is built
+with.  No standing program moves when a kind is added: tests/
+test_generation_pipeline.py, test_generation_kda.py and
+test_generation_lfm2.py pin every kind's lowered text.
 """
 import collections
 import threading
@@ -201,34 +156,30 @@ _WEIGHT_SLOTS = ('att_q_w', 'att_k_w', 'att_v_w', 'att_o_w', 'att_norm',
                  'ffn_norm', 'ffn_fc1_w', 'ffn_fc2_w', 'ffn_fc3_w')
 
 
-_BLOCKS = ('dense', 'falcon_h1', 'latent_moe')
-
 # one layer of a model: ``index`` i (its weights are ``layer_<i>_*``), the
-# kinds of its ``mixers`` in order (keys of `_MIXERS`, each reading the
-# layer's one normalised input), its feed-forward ``ffn`` (``'dense'`` |
-# ``'experts'``), its index ``pool`` on the page pool's layer axis and
-# ``state`` on the recurrent arrays' (None where it stores none of that
-# sort), and the MODEL's stream convention ``wide``: the residual stream
-# is ``[T, D]`` float32, rounded at each product (`latent.rms`,
-# `latent.dot`), where without it it is ``[B, T, D]`` in the model's dtype
-# (`_rms`, `_qkv`, `_ffn`, `_head`)
+# kinds of its ``mixers`` in order (keys of `_MIXERS`), its feed-forward
+# ``ffn`` (``'dense'`` | ``'experts'``), its index ``pool`` on the page
+# pool's layer axis and ``state`` on the recurrent arrays' (None where it
+# stores none of that sort), and the MODEL's stream convention ``wide``:
+# the residual stream is ``[T, D]`` float32, rounded at each product,
+# where without it it is ``[B, T, D]`` in the model's dtype
 _Layer = collections.namedtuple(
     '_Layer', ('index', 'mixers', 'ffn', 'pool', 'state', 'wide'))
 
 
 def _layers(cfg):
     """The model's layers, one `_Layer` each: THE place that reads
-    ``cfg['block']`` and what follows from it, and that refuses a wrong
-    model dict.  ``'dense'`` (the default) is GQA and one SwiGLU a layer;
-    ``'falcon_h1'`` the same with a Mamba-2 mixer beside the attention;
-    ``'latent_moe'`` a mixer a layer as ``cfg['mixer']`` names it (latent
-    attention without the key) and the feed-forward ``cfg['ffn']`` names,
-    under the wide stream.  A model fills ONE pool geometry and ONE state
-    geometry (`CacheConfig` holds one of each), and must attend."""
+    ``cfg['block']`` and refuses a wrong model dict.  ``'dense'`` (the
+    default) is GQA and one SwiGLU a layer; ``'falcon_h1'`` the same with
+    a Mamba-2 mixer beside the attention; ``'latent_moe'`` a mixer a
+    layer as ``cfg['mixer']`` names it (latent attention without the key)
+    and the feed-forward ``cfg['ffn']`` names, under the wide stream.  A
+    model fills ONE pool geometry and ONE state geometry, and attends."""
+    blocks = ('dense', 'falcon_h1', 'latent_moe')
     block = cfg.get('block', 'dense')
-    if block not in _BLOCKS:
+    if block not in blocks:
         raise ValueError('block must be one of %s, got %r'
-                         % (', '.join(map(repr, _BLOCKS)), block))
+                         % (', '.join(map(repr, blocks)), block))
     L, wide = int(cfg['n_layer']), block == 'latent_moe'
     if wide:
         ffn = tuple(cfg['ffn'])
@@ -268,8 +219,8 @@ def _layers(cfg):
 def _kinds(lays):
     """[(mixer kind, the layers that have it)] of a model, in the table's
     order."""
-    return [(k, n) for k, n in
-            ((k, sum(k in lay.mixers for lay in lays)) for k in _MIXERS) if n]
+    counts = {k: sum(k in lay.mixers for lay in lays) for k in _MIXERS}
+    return [(k, n) for k, n in counts.items() if n]
 
 
 def _head_dim(cfg):
@@ -391,12 +342,6 @@ def _prepared_names(cfg):
             for slot, parts in _MIXERS[kind].prepared(cfg).items()}
 
 
-def _prepared_arrays(params, cfg):
-    """Every prepared array of ``params``, a flat list."""
-    return [params[t] for _kind, _slot, stored in
-            _prepared_names(cfg).values() for t in stored]
-
-
 def _params_from(weights, cfg):
     """``weights`` under `weight_names(cfg)` -> the parameters the
     executables take: the weights a mixer keeps prepared made so
@@ -411,16 +356,15 @@ def _params_from(weights, cfg):
     prepare = {}
     for lay in _layers(cfg):
         for kind in lay.mixers:
-            entry, p = _MIXERS[kind], 'layer_%d_' % lay.index
-            slots = entry.prepared(cfg)
+            slots, p = _MIXERS[kind].prepared(cfg), 'layer_%d_' % lay.index
             if not slots:
                 continue
-            dims = entry.dims(cfg)
+            dims = _MIXERS[kind].dims(cfg)
             if kind not in prepare:
-                prepare[kind] = jax.jit(entry.prepare, static_argnums=tuple(
-                    range(len(slots), len(slots) + len(dims))))
+                prepare[kind] = jax.jit(_MIXERS[kind].prepare,
+                                        static_argnames=tuple(dims))
             made = prepare[kind](*(jnp.asarray(weights[p + s])
-                                   for s in slots), *dims)
+                                   for s in slots), **dims)
             params.update(zip((p + t for parts in slots.values()
                                for t in parts), made))
     return params
@@ -438,20 +382,18 @@ class _PublicWeights(Mapping):
         import jax
         self._params, self._names = params, weight_names(cfg)
         self._prepared = _prepared_names(cfg)
-        self._undo = {}
-        for kind, _slot, _stored in self._prepared.values():
-            if kind not in self._undo:
-                dims = _MIXERS[kind].dims(cfg)
-                self._undo[kind] = (dims, jax.jit(
-                    _MIXERS[kind].public, static_argnums=(0,) + tuple(
-                        range(2, 2 + len(dims)))))
+        self._dims = {kind: _MIXERS[kind].dims(cfg)
+                      for kind, _slot, _stored in self._prepared.values()}
+        self._undo = {kind: jax.jit(_MIXERS[kind].public, static_argnums=0,
+                                    static_argnames=tuple(dims))
+                      for kind, dims in self._dims.items()}
 
     def __getitem__(self, name):
         if name not in self._prepared:
             return self._params[name]
         kind, slot, stored = self._prepared[name]
-        dims, undo = self._undo[kind]
-        return undo(slot, tuple(self._params[t] for t in stored), *dims)
+        return self._undo[kind](slot, tuple(self._params[t] for t in stored),
+                                **self._dims[kind])
 
     def __iter__(self):
         return iter(self._names)
@@ -859,17 +801,16 @@ def _of_no_layer(counted):
 
 
 # every kind of mixer a layer may have (mixer.py), in the order a wide
-# launch's counts follow: the kinds that attend, then those that hold
-# state.  ``'gqa'``'s wide launches carry latent attention's count of
-# rows, as zero (what its kernel reads is counted on the host,
-# `DecodeRuntime._window_rows_read`): the slot stood in every wide
-# launch's array before a model attended through anything else
+# launch's counts follow.  ``'gqa'``'s wide launches carry latent
+# attention's count of rows, as zero (its kernel's reads are counted on
+# the host, `DecodeRuntime._window_rows_read`): the slot stood in every
+# wide launch's array before a model attended through anything else
 _MIXERS = {
     'latent': _latent.MIXER,
     'gqa': Mixer(
         weight_shapes=_gqa_weights, pool=_gqa_pool,
         prepared=lambda cfg: {s: (t,) for s, t in _PREPARED.items()},
-        dims=lambda cfg: (_head_dim(cfg),),
+        dims=lambda cfg: {'dh': _head_dim(cfg)},
         prepare=_prepare_qkv, public=_public_weight,
         public_rows=_gqa_public_rows,
         kernels=lambda cfg, cache, chunk, mesh: {
@@ -885,6 +826,46 @@ _MIXERS = {
 }
 
 
+def _through_layers(w, cfg, cache, kernels, lays, half, x, st, at, routes):
+    """The embedded stream ``x`` through every layer of one launch:
+    ``half`` is 0 for a prefill chunk and 1 for a decode step (which of a
+    mixer's halves runs, mixer.py), ``at`` where the launch stands,
+    ``routes`` [T] the tokens an expert layer routes.  A layer is its
+    norm, its mixers' halves and its feed-forward; the body is chosen by
+    the model's stream convention, the one thing the loop asks of the
+    model.  Returns (x, the state dict, `experts.STATS` summed over the
+    layers or None for the narrow stream)."""
+    import jax
+    import jax.numpy as jnp
+    scope = jax.named_scope
+    stats = jnp.zeros((len(_experts.STATS),), jnp.int32) \
+        if lays[0].wide else None
+    for lay in lays:
+        norm = w['layer_%d_att_norm' % lay.index]
+        # ONE scope name for every layer: an operation's op_name says
+        # which part of the block it is, whatever its index
+        with scope('layer'):
+            if lay.wide:
+                h = _latent.rms(x, norm, _eps(cfg))
+                kind, = lay.mixers        # one a layer under this stream
+                mixed, st = _MIXERS[kind].wide[half](
+                    w, cfg, cache, kernels, lay, h, st, at)
+                with scope('ffn'):
+                    x, counted = _wide_ffn(w, cfg, lay, x + mixed, routes,
+                                           kernels)
+                stats = stats + counted
+                continue
+            with scope('attn.qkv'):
+                h = _rms(x, norm, _eps(cfg))
+            for kind in lay.mixers:
+                mixed, st = _MIXERS[kind].narrow[half](
+                    w, cfg, cache, kernels, lay, h, st, at)
+                x = x + mixed
+            with scope('ffn'):
+                x = _ffn(w, cfg, x, lay.index)
+    return x, st, stats
+
+
 def _prefill_fn(cfg, cache, chunk, ring_mesh=None, kernels=Kernels()):
     """Build the one-chunk (or one-shot ring) prefill function.
 
@@ -896,10 +877,9 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, kernels=Kernels()):
     stores it in tok[slot].  Only the final chunk's draw (the request's
     FIRST token, the TTFT token) survives.
 
-    Every layer is its norm, its mixers' prefill halves (mixer.py; each
-    reads its own field of ``kernels``, `DecodeRuntime.kernels`) and its
-    feed-forward.  Under the wide stream the function returns a fourth
-    value, the chunk's `_launch_stats`.
+    The layers are `_through_layers`'; each mixer reads its own field of
+    ``kernels`` (`DecodeRuntime.kernels`).  Under the wide stream the
+    function returns a fourth value, the chunk's `_launch_stats`.
     """
     import jax.numpy as jnp
     lays = _layers(cfg)
@@ -921,36 +901,14 @@ def _prefill_fn(cfg, cache, chunk, ring_mesh=None, kernels=Kernels()):
         pg = jnp.where(valid,
                        bt_row[jnp.clip(p_abs // PL, 0, M - 1)], 0)
         rw = p_abs % PL
-        at = Chunk(slot, offset, true_count, pos, p_abs, valid, pg, rw,
-                   bt_row, ring_mesh)
+        at = Chunk(slot, offset, true_count, pos, p_abs, pg, rw, bt_row,
+                   ring_mesh)
         with scope('embed'):
             x = _embed(w, cfg, tokens)[None]              # [1, C, D]
         if wide:
             x = x[0].astype(jnp.float32)                  # [C, D]
-            stats = jnp.zeros((len(_experts.STATS),), jnp.int32)
-        for lay in lays:
-            norm = w['layer_%d_att_norm' % lay.index]
-            # ONE scope name for every layer: an operation's op_name
-            # says which part of the block it is, whatever its index
-            with scope('layer'):
-                if lay.wide:
-                    h = _latent.rms(x, norm, _eps(cfg))
-                    kind, = lay.mixers    # one a layer under this stream
-                    mixed, st = _MIXERS[kind].wide[0](
-                        w, cfg, cache, kernels, lay, h, st, at)
-                    with scope('ffn'):
-                        x, counted = _wide_ffn(w, cfg, lay, x + mixed,
-                                               valid, kernels)
-                    stats = stats + counted
-                    continue
-                with scope('attn.qkv'):
-                    h = _rms(x, norm, _eps(cfg))
-                for kind in lay.mixers:
-                    mixed, st = _MIXERS[kind].narrow[0](
-                        w, cfg, cache, kernels, lay, h, st, at)
-                    x = x + mixed
-                with scope('ffn'):
-                    x = _ffn(w, cfg, x, lay.index)
+        x, st, stats = _through_layers(w, cfg, cache, kernels, lays, 0, x,
+                                       st, at, valid)
         with scope('lm_head'):
             if wide:
                 last = jax.lax.dynamic_slice_in_dim(x, true_count - 1, 1)[0]
@@ -981,17 +939,17 @@ def _step_fn(cfg, cache, kernels):
     """One fused decode/verify step over ALL slots: write the fed token's
     K/V through the block table, attend, sample each slot's next token
     with the position-keyed stream, advance ACTIVE slots only.  Inactive
-    slots compute masked garbage routed to page 0.
+    slots compute masked garbage routed to page 0, and route nowhere in
+    an expert layer.
 
-    Every layer is its norm, its mixers' step halves (mixer.py) and its
-    feed-forward, where a slot that rides along routes nowhere.
-    ``kernels`` (`DecodeRuntime.kernels`) says which of them run in
-    place: ``paged`` attends over the pool, an active slot reading the
-    pages its length covers and an inactive one nothing, where the
-    composed path gathers every slot's logical row first; ``state``
-    advances the live slots' recurrent state, where otherwise every slot
-    steps and the dead ones' is masked.  Under the wide stream the step
-    returns a third value, its `_launch_stats`."""
+    The layers are `_through_layers`'.  ``kernels``
+    (`DecodeRuntime.kernels`) says which of their steps run in place:
+    ``paged`` attends over the pool, an active slot reading the pages its
+    length covers and an inactive one nothing, where the composed path
+    gathers every slot's logical row first; ``state`` advances the live
+    slots' recurrent state, where otherwise every slot steps and the dead
+    ones' is masked.  Under the wide stream the step returns a third
+    value, its `_launch_stats`."""
     import jax.numpy as jnp
     lays = _layers(cfg)
     wide = lays[0].wide
@@ -1011,28 +969,8 @@ def _step_fn(cfg, cache, kernels):
             x = _embed(w, cfg, fed)[:, None, :]           # [S, 1, D]
         if wide:
             x = x[:, 0].astype(jnp.float32)               # [S, D]
-            stats = jnp.zeros((len(_experts.STATS),), jnp.int32)
-        for lay in lays:
-            norm = w['layer_%d_att_norm' % lay.index]
-            with scope('layer'):     # one name for every layer (prefill)
-                if lay.wide:
-                    h = _latent.rms(x, norm, _eps(cfg))
-                    kind, = lay.mixers    # one a layer under this stream
-                    mixed, st = _MIXERS[kind].wide[1](
-                        w, cfg, cache, kernels, lay, h, st, at)
-                    with scope('ffn'):
-                        x, counted = _wide_ffn(w, cfg, lay, x + mixed,
-                                               active, kernels)
-                    stats = stats + counted
-                    continue
-                with scope('attn.qkv'):
-                    h = _rms(x, norm, _eps(cfg))
-                for kind in lay.mixers:
-                    mixed, st = _MIXERS[kind].narrow[1](
-                        w, cfg, cache, kernels, lay, h, st, at)
-                    x = x + mixed
-                with scope('ffn'):
-                    x = _ffn(w, cfg, x, lay.index)
+        x, st, stats = _through_layers(w, cfg, cache, kernels, lays, 1, x,
+                                       st, at, active)
         with scope('lm_head'):
             if wide:
                 logits = _latent.dot(
@@ -1056,16 +994,20 @@ def _step_fn(cfg, cache, kernels):
 def _window_fn(cfg, cache, steps, kernels, verify):
     """A K-step window over `_step_fn`: one `lax.scan`, the state dict
     its donated carry, the block table closed-over DATA (an ordinary
-    traced argument).  A step feeds every slot's own carry token, or
-    with ``verify`` row j of ``fed`` [K, S].  Returns (state, tokens [S,
-    K]) and, where the step counts (`_launch_stats`), the counts summed
-    over the window."""
+    traced argument).  Its arguments are the parameters, the state, the
+    block table, with ``verify`` the rows to feed [K, S], then active,
+    seeds, temps and topks [S].  Returns (state, tokens [S, K]) and,
+    where the step counts (`_launch_stats`), the counts summed over the
+    window."""
     import jax
 
     step = _step_fn(cfg, cache, kernels)
     counts = _layers(cfg)[0].wide
 
-    def run(w, st, bt, fed, active, seeds, temps, topks):
+    def window(w, st, bt, *args):
+        fed = args[0] if verify else None
+        active, seeds, temps, topks = args[-4:]
+
         def body(carry, fed_t):
             carry, *out = step(w, carry, bt,
                                fed_t if verify else carry['tok'], active,
@@ -1076,18 +1018,13 @@ def _window_fn(cfg, cache, steps, kernels, verify):
             return st, out[0].T, out[1].sum(axis=0)
         return st, out[0].T                               # [S, K]
 
-    return run
+    return window
 
 
 def _decode_fn(cfg, cache, steps, kernels=Kernels()):
     """K-step fused decode window: each step feeds every slot's own
-    carry token (`_window_fn`)."""
-    run = _window_fn(cfg, cache, steps, kernels, False)
-
-    def window(w, st, bt, active, seeds, temps, topks):
-        return run(w, st, bt, None, active, seeds, temps, topks)
-
-    return window
+    carry token."""
+    return _window_fn(cfg, cache, steps, kernels, False)
 
 
 def _verify_fn(cfg, cache, steps, kernels=Kernels()):
@@ -1096,12 +1033,7 @@ def _verify_fn(cfg, cache, steps, kernels=Kernels()):
     proposals) and the returned samples are the target model's verdicts
     g_j at each position.  Same `(seed, position)` sampling as decode —
     an accepted prefix is bitwise the sequential stream."""
-    run = _window_fn(cfg, cache, steps, kernels, True)
-
-    def window(w, st, bt, fed, active, seeds, temps, topks):
-        return run(w, st, bt, fed, active, seeds, temps, topks)
-
-    return window
+    return _window_fn(cfg, cache, steps, kernels, True)
 
 
 def _interleaved_rope(x, pos, theta):
@@ -1235,15 +1167,11 @@ class DecodeRuntime(object):
     shortage as a clean False/None the scheduler turns into
     backpressure or a terminal ``kv_oom``.
 
-    A model that carries recurrent state (``block: 'falcon_h1'``, or a
-    ``latent_moe`` model with ``'kda'`` or ``'conv'`` layers;
-    `recurrent`) runs
-    WITHOUT the prefix cache whatever
-    ``prefix_cache`` says (a hit would skip tokens the scan state never
-    saw), and refuses speculative windows and ring prefill.  A
-    ``latent_moe`` model (`latent_moe`) keeps the prefix cache (its
-    pages hold latent rows, shared like any other) and speculative
-    windows (unless it is recurrent), and refuses ring prefill and
+    A model that carries recurrent state in any layer (`recurrent`)
+    runs WITHOUT the prefix cache whatever ``prefix_cache`` says (a hit
+    would skip tokens the state never saw), and refuses speculative
+    windows and ring prefill.  A model under the wide stream
+    (`latent_moe`) refuses ring prefill, and a latent pool
     ``kv_quant='int8'``.
     """
 
@@ -1263,7 +1191,9 @@ class DecodeRuntime(object):
             _cc.ensure_xla_cache_backstop()
             self.params = _params_from(weights, cfg)
             self.w = _PublicWeights(self.params, cfg)
-            made = jax.block_until_ready(_prepared_arrays(self.params, cfg))
+            made = jax.block_until_ready(
+                [self.params[t] for _kind, _slot, stored in
+                 _prepared_names(cfg).values() for t in stored])
             made_bytes = sum(int(a.nbytes) for a in made)
             init.args.update(prepared=len(made), prepared_bytes=made_bytes)
             _obs.metrics.gauge('generation.prepared_weight_bytes').set(
@@ -1354,17 +1284,11 @@ class DecodeRuntime(object):
         and hand back `_launch_stats`."""
         return self.layers[0].wide
 
-    def _kernel(field):
-        """One field of `kernels` under the name it is read by.  Set
-        before the first launch, it builds that runtime's launches
-        without the kernel (a test's composed route)."""
-        def put(self, may):
-            self.kernels = self.kernels._replace(**{field: bool(may)})
-        return property(lambda self: getattr(self.kernels, field), put)
-
-    paged, state_kernel = _kernel('paged'), _kernel('state')
-    prefill_kernel, experts_kernel = _kernel('prefill'), _kernel('experts')
-    del _kernel
+    # the fields of `kernels`, under the names they are read by
+    paged = property(lambda self: self.kernels.paged)
+    state_kernel = property(lambda self: self.kernels.state)
+    prefill_kernel = property(lambda self: self.kernels.prefill)
+    experts_kernel = property(lambda self: self.kernels.experts)
 
     # ------------------------------------------------------- geometry
     @property
@@ -1630,12 +1554,6 @@ class DecodeRuntime(object):
                                         self.cache.page_len)
         return steps * self._gathered
 
-    def _decode_exec(self, steps):
-        return self._window_exec('decode', steps)
-
-    def _verify_exec(self, steps):
-        return self._window_exec('verify', steps)
-
     def warmup(self, steps=None, speculative=False):
         """Compile (or disk-load) the steady-state executables up front
         so the first request pays no compile latency.  With
@@ -1644,9 +1562,9 @@ class DecodeRuntime(object):
                        counter='generation.warmup_s'):
             self._prefill_exec(self.prefill_chunk)
             if steps:
-                self._decode_exec(int(steps))
+                self._window_exec('decode', int(steps))
                 if speculative:
-                    self._verify_exec(int(steps))
+                    self._window_exec('verify', int(steps))
 
     # ------------------------------------------------------ launching
     # A launch is upload -> dispatch, and returns what the executable

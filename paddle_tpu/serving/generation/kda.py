@@ -370,15 +370,19 @@ def _sizes(C):
     return sub, math.gcd(sub, _BLOCK)
 
 
-def prefill_mixer(w, p, cfg, h, S0, tail, true_count):
-    """One slot, one prefill chunk: h [C, D] normalised, S0 [H, d, d] and
-    tail [K-1, 3 H, d] the slot's state of this layer (zeros where the
-    prompt begins).  Returns (out [C, D] float32, S, tail), the state as
-    position ``true_count - 1`` leaves it, in the extents it came in;
-    rows of ``out`` past it are padding's."""
+def prefill_mixer(w, cfg, cache, kernels, lay, h, st, at):
+    """One slot, one prefill chunk of a layer (mixer.py): h [C, D]
+    normalised; the slot's matrix state [H, d, d] and tail [K-1, 3 H, d]
+    of this layer as the last chunk left them (zeros where the prompt
+    begins).  Returns (out [C, D] float32, the state dict with the slot's
+    state as position ``true_count - 1`` leaves it); rows of ``out`` past
+    it are padding's."""
     import jax
     import jax.numpy as jnp
-    kda = cfg['kda']
+    kda, p, j = cfg['kda'], 'layer_%d_' % lay.index, lay.state
+    carried, true_count = at.offset > 0, at.true_count
+    S0 = jnp.where(carried, st['ssm'][at.slot, j], 0.0)
+    tail = jnp.where(carried, st['conv'][at.slot, j], 0.0)
     C = h.shape[0]
     x = _project(w, p, h)
     g, beta, gate = _gates(w, p, kda, h)
@@ -386,7 +390,7 @@ def prefill_mixer(w, p, cfg, h, S0, tail, true_count):
         taps = _taps(w, p)
         full = jnp.concatenate([tail.reshape(tail.shape[0], -1), x],
                                axis=0)                     # [K-1+C, ch]
-        conv = sum(full[j:j + C] * taps[j] for j in range(taps.shape[0]))
+        conv = sum(full[t:t + C] * taps[t] for t in range(taps.shape[0]))
         tail = jax.lax.dynamic_slice_in_dim(
             full, true_count, taps.shape[0] - 1).reshape(tail.shape)
     with jax.named_scope('kda.scan'):
@@ -395,7 +399,9 @@ def prefill_mixer(w, p, cfg, h, S0, tail, true_count):
         g = jnp.where(real[:, None, None], g, 0.0)
         beta = jnp.where(real[:, None], beta, 0.0)
         o, S = chunk_scan(q, k, v, g, beta, S0, *_sizes(C))
-    return _out(w, p, kda, o, gate, float(cfg.get('rms_eps', 1e-6))), S, tail
+    out = _out(w, p, kda, o, gate, float(cfg.get('rms_eps', 1e-6)))
+    return out, dict(st, ssm=st['ssm'].at[at.slot, j].set(S),
+                     conv=st['conv'].at[at.slot, j].set(tail))
 
 
 # ------------------------------------------------ the step, in place
@@ -602,22 +608,6 @@ def step_mixer(w, p, cfg, h, state, layer, tails, active, kernel):
         state, tails
 
 
-# ------------------------------------------------ the runtime's entry
-
-def _prefill_layer(w, cfg, cache, kernels, lay, h, st, at):
-    """`prefill_mixer` as a layer of a chunk (mixer.py): from the slot's
-    state as the last chunk left it; a prompt's first chunk starts from
-    zeros."""
-    import jax.numpy as jnp
-    j, carried = lay.state, at.offset > 0
-    out, S, tail = prefill_mixer(
-        w, 'layer_%d_' % lay.index, cfg, h,
-        jnp.where(carried, st['ssm'][at.slot, j], 0.0),
-        jnp.where(carried, st['conv'][at.slot, j], 0.0), at.true_count)
-    return out, dict(st, ssm=st['ssm'].at[at.slot, j].set(S),
-                     conv=st['conv'].at[at.slot, j].set(tail))
-
-
 def _step_layer(w, cfg, cache, kernels, lay, h, st, at):
     """`step_mixer` as a layer of a step (mixer.py): an inactive slot
     keeps both kinds of state."""
@@ -653,11 +643,10 @@ MIXER = Mixer(
     weight_shapes=lambda cfg: weight_shapes(int(cfg['d_model']), cfg['kda']),
     recurrent=lambda cfg: state_shapes(cfg['kda']),
     kernels=_kernels,
-    # slot-layers whose matrix state a window's steps read and wrote (each
-    # read once, written once: bytes), tokens a chunk's scan took,
-    # slot-layers whose convolution tails the steps read and wrote
+    # counted in slot-layers whose state, and tails, a window's steps read
+    # once and wrote once (bytes); and the tokens a chunk's scan took
     stats=lambda cfg: {'kda_state_bytes': 2 * state_bytes(cfg['kda']),
                        'kda_chunk_tokens': 1,
                        'kda_tail_bytes': 2 * tail_bytes(cfg['kda'])},
     counted=(_chunk_counted, _step_counted),
-    wide=(_prefill_layer, _step_layer))
+    wide=(prefill_mixer, _step_layer))

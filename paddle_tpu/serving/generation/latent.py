@@ -432,27 +432,30 @@ def prefill(w, p, cfg, h, pos, n_keys, pool, layer, pg, rw, bt_row, kernel):
         return dot(att.reshape(C, H * v), w[p + 'att_o_w']), pool
 
 
-def step(w, p, cfg, h, pos, pool, layer, pg, rw, bt, n_attend, paged):
-    """Every slot, one decode step of layer ``layer``: h [S, D]
-    normalised, pos [S] write positions, pg / rw [S] their page (0 for
-    a slot that rides along) and in-page row, bt [S, max_pages],
-    n_attend [S] positions each slot attends (0: none).  Writes the
-    step's rows and attends in the ABSORBED form: over the pool in place
-    where ``paged`` (`latent_attention`), else on gathered rows.
-    Returns (the attention's output [S, D] float32, the pool)."""
+def step(w, cfg, cache, kernels, lay, h, st, at):
+    """Every slot, one decode step of a layer (mixer.py): h [S, D]
+    normalised, ``at.pos`` [S] the write positions, ``at.pg`` / ``at.rw``
+    [S] their page (0 for a slot that rides along) and in-page row,
+    ``at.n_attend`` [S] positions each slot attends (0: none).  Writes
+    the step's rows and attends in the ABSORBED form: over the pool in
+    place where ``kernels.paged`` (`latent_attention`), else on gathered
+    rows.  Returns (the attention's output [S, D] float32, the state dict
+    with the pool written)."""
     import jax
     import jax.numpy as jnp
-    lat = cfg['latent']
+    lat, p, layer = cfg['latent'], 'layer_%d_' % lay.index, lay.pool
+    pos, bt, n_attend = at.pos, at.bt, at.n_attend
     S = h.shape[0]
     q_nope, q_rope = _queries(w, p, cfg, h)
-    pool = pool.at[pg, layer, rw].set(_row(w, p, cfg, h, pos, pool.dtype))
+    pool = st['k'].at[at.pg, layer, at.rw].set(
+        _row(w, p, cfg, h, pos, st['k'].dtype))
     with jax.named_scope('attn.latent.scores'):
         q_r = _turned(q_rope, pos[:, None], cfg)              # [S, H, rope]
         wk = w[p + 'att_kvb_k']
         q_lat = jnp.einsum('shn,hnc->shc', q_nope.astype(wk.dtype), wk,
                            preferred_element_type=jnp.float32)
         scale = score_scale(lat)
-        if paged:
+        if kernels.paged:
             o_lat = latent_attention(q_lat, _padded(q_r, lat), pool, bt,
                                      n_attend, layer, scale)
         else:
@@ -463,24 +466,14 @@ def step(w, p, cfg, h, pos, pool, layer, pg, rw, bt, n_attend, paged):
         wv = w[p + 'att_kvb_v']
         o = jnp.einsum('shc,hcv->shv', o_lat.astype(wv.dtype), wv,
                        preferred_element_type=jnp.float32)
-        return dot(o.reshape(S, -1), w[p + 'att_o_w']), pool
+        return dot(o.reshape(S, -1), w[p + 'att_o_w']), dict(st, k=pool)
 
-
-# ------------------------------------------------ the runtime's entry
 
 def _prefill_layer(w, cfg, cache, kernels, lay, h, st, at):
     """`prefill` as a layer of a chunk (mixer.py)."""
     out, pool = prefill(w, 'layer_%d_' % lay.index, cfg, h, at.p_abs,
                         at.offset + at.true_count, st['k'], lay.pool, at.pg,
                         at.rw, at.bt_row, kernels.prefill)
-    return out, dict(st, k=pool)
-
-
-def _step_layer(w, cfg, cache, kernels, lay, h, st, at):
-    """`step` as a layer of a step (mixer.py)."""
-    out, pool = step(w, 'layer_%d_' % lay.index, cfg, h, at.pos, st['k'],
-                     lay.pool, at.pg, at.rw, at.bt, at.n_attend,
-                     kernels.paged)
     return out, dict(st, k=pool)
 
 
@@ -503,12 +496,6 @@ def _step_counted(n, cache, kernels, at):
     return [jnp.asarray(n * rows, jnp.int32).reshape(1)]
 
 
-def _dims(cfg):
-    lat = cfg['latent']
-    return (int(cfg['n_head']), int(lat['nope']), int(lat['rope']),
-            int(lat['v']))
-
-
 def _kernels(cfg, cache, chunk, mesh):
     return {'paged': latent_attention_eligible(
                 cache.pool_shape, cache.store_dtype, cache.latent, mesh),
@@ -522,10 +509,13 @@ MIXER = Mixer(
     pool=lambda cfg, wide: dict(
         kv_heads=1, head_dim=stored_width(cfg['latent']),
         latent=int(cfg['latent']['kv_rank'])),
-    prepared=lambda cfg: prepared(cfg['latent']), dims=_dims,
+    prepared=lambda cfg: prepared(cfg['latent']),
+    dims=lambda cfg: dict(
+        n_head=int(cfg['n_head']), nope=int(cfg['latent']['nope']),
+        rope=int(cfg['latent']['rope']), v=int(cfg['latent']['v'])),
     prepare=prepare, public=public,
     public_rows=lambda cfg, k, v: (public_rows(k, cfg['latent']), None),
     kernels=_kernels,
     stats=lambda cfg: {'latent_rows_read': 1},
     counted=(_chunk_counted, _step_counted),
-    wide=(_prefill_layer, _step_layer))
+    wide=(_prefill_layer, step))

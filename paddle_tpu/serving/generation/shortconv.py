@@ -108,8 +108,6 @@ def step_mixer(w, p, cfg, h, tail, active):
     return _out(w, p, gate * c), tail
 
 
-# ------------------------------------------------ the runtime's entry
-
 def _prefill_layer(w, cfg, cache, kernels, lay, h, st, at):
     """`prefill_mixer` as a layer of a chunk (mixer.py): from the slot's
     tail as the last chunk left it (zeros where the prompt begins)."""

@@ -7,8 +7,8 @@ RECURRENT state beside its pages: per layer the scan state ``S``
 ``[heads, head_dim, d_state]`` (float32: a half-width state would round
 at every step of the recurrence) and the convolution's last ``d_conv -
 1`` inputs.  Both live in the runtime's donated state dict
-(kv_cache.init_state); this module only maps (input, state) to (output,
-state):
+(kv_cache.init_state); this module's two halves map (input, the dict) to
+(output, the dict):
 
     p          = ((h * ssm_in) @ W_in) * m       m piecewise constant over
     z, xBC, dt = split(p)                        the parts z, x, B, C, dt
@@ -359,14 +359,19 @@ def ssm_step(x, dt, A, B, C, D, state, layer, active):
     return jnp.where(active[:, None, None], y, 0.0), state
 
 
-def prefill_mixer(w, p, cfg, h, S0, tail, true_count):
-    """One slot, one prefill chunk: h [C, D] normalised, S0 and tail the
-    slot's state of this layer (zeros where the prompt begins).  Returns
-    (out [C, D], S, tail), the state as position ``true_count - 1``
-    leaves it; rows of ``out`` past it are padding's."""
+def prefill_mixer(w, cfg, cache, kernels, lay, h, st, at):
+    """One slot, one prefill chunk of a layer (mixer.py): h [1, C, D]
+    normalised; the slot's scan state and tail of this layer as the last
+    chunk left them (zeros where the prompt begins, whoever held the slot
+    before).  Returns (what the layer adds to the stream [1, C, D], the
+    state dict with the slot's state as position ``true_count - 1``
+    leaves it); rows of the output past it are padding's."""
     import jax
     import jax.numpy as jnp
-    ssm = cfg['ssm']
+    ssm, p, j = cfg['ssm'], 'layer_%d_' % lay.index, lay.state
+    carried, h, true_count = at.offset > 0, h[0], at.true_count
+    S0 = jnp.where(carried, st['ssm'][at.slot, j], 0.0)
+    tail = jnp.where(carried, st['conv'][at.slot, j], 0.0)
     C = h.shape[0]
     z, xbc, dt = _in_proj(h, w, p, ssm, cfg['multipliers'])
     with jax.named_scope('ssm.conv'):
@@ -383,24 +388,28 @@ def prefill_mixer(w, p, cfg, h, S0, tail, true_count):
         y, S = scan_chunk(x, dt, A, B, Cm, D, S0,
                           math.gcd(C, int(ssm['chunk'])))
     out = _gate_out(y.reshape(C, -1), z, w, p, ssm, float(cfg['rms_eps']))
-    return out, S, tail
+    st = dict(st, ssm=st['ssm'].at[at.slot, j].set(S),
+              conv=st['conv'].at[at.slot, j].set(tail))
+    return out[None] * cfg['multipliers']['ssm_out'], st
 
 
-def step_mixer(w, p, cfg, h, state, layer, tail, active, kernel):
-    """Every slot, one decode step of layer ``layer``: h [slots, D]
-    normalised, state [slots, layers, H, P, N] (the WHOLE scan state),
-    tail [slots, K-1, ch] (this layer's), active [slots] bool.  Returns
-    (out [slots, D], state, tail): the scan state of the live slots
-    advanced in this layer and nothing else of it changed; the tail for
-    ALL slots (the caller keeps an inactive slot's old one).
+def step_mixer(w, cfg, cache, kernels, lay, h, st, at):
+    """Every slot, one decode step of a layer (mixer.py): h [slots, 1, D]
+    normalised, over the WHOLE scan state [slots, layers, H, P, N] and
+    this layer's tails [slots, K-1, ch].  Returns (what the layer adds to
+    the stream [slots, 1, D], the state dict): the scan state and the
+    tail of the live slots advanced in this layer, an inactive slot's
+    kept, both kinds, and nothing else changed.
 
-    ``kernel`` (`ssm_step_eligible`, static) runs the recurrence in
-    place over the live slots (`ssm_step`); otherwise every slot steps
+    ``kernels.state`` (`ssm_step_eligible`, static) runs the recurrence
+    in place over the live slots (`ssm_step`); otherwise every slot steps
     (`scan_step`) and the dead ones' result is masked away."""
     import jax
     import jax.numpy as jnp
     from ... import observability as _obs
-    ssm = cfg['ssm']
+    ssm, p, j = cfg['ssm'], 'layer_%d_' % lay.index, lay.state
+    h, state, active = h[:, 0], st['ssm'], at.active
+    tail = st['conv'][:, j]
     z, xbc, dt = _in_proj(h, w, p, ssm, cfg['multipliers'])
     with jax.named_scope('ssm.conv'):
         full = jnp.concatenate([tail, xbc[:, None]], axis=1)  # [S, K, ch]
@@ -410,53 +419,26 @@ def step_mixer(w, p, cfg, h, state, layer, tail, active, kernel):
         xbc = jax.nn.silu(conv)
     with jax.named_scope('ssm.step'):
         x, B, Cm, dt, A, D = _heads(xbc, dt, w, p, ssm)
-        if kernel:
+        if kernels.state:
             _obs.metrics.counter('ssm.step_kernel').inc()
-            y, state = ssm_step(x, dt, A, B, Cm, D, state, layer, active)
+            y, state = ssm_step(x, dt, A, B, Cm, D, state, j, active)
         else:
             _obs.metrics.counter('ssm.step_composed').inc()
-            y, S = scan_step(x, dt, A, B, Cm, D, state[:, layer])
-            state = state.at[:, layer].set(jnp.where(
-                active[:, None, None, None], S, state[:, layer]))
+            y, S = scan_step(x, dt, A, B, Cm, D, state[:, j])
+            state = state.at[:, j].set(jnp.where(
+                active[:, None, None, None], S, state[:, j]))
     out = _gate_out(y.reshape(h.shape[0], -1), z, w, p, ssm,
                     float(cfg['rms_eps']))
-    return out, state, tail
-
-
-# ------------------------------------------------ the runtime's entry
-
-def _prefill_layer(w, cfg, cache, kernels, lay, h, st, at):
-    """`prefill_mixer` as a layer of a chunk (mixer.py): from the slot's
-    state as the last chunk left it (a prompt's first chunk starts from
-    zeros, whoever held the slot before) into the slot's state."""
-    import jax.numpy as jnp
-    j, carried = lay.state, at.offset > 0
-    mix, S, tail = prefill_mixer(
-        w, 'layer_%d_' % lay.index, cfg, h[0],
-        jnp.where(carried, st['ssm'][at.slot, j], 0.0),
-        jnp.where(carried, st['conv'][at.slot, j], 0.0), at.true_count)
-    st = dict(st, ssm=st['ssm'].at[at.slot, j].set(S),
-              conv=st['conv'].at[at.slot, j].set(tail))
-    return mix[None] * cfg['multipliers']['ssm_out'], st
-
-
-def _step_layer(w, cfg, cache, kernels, lay, h, st, at):
-    """`step_mixer` as a layer of a step (mixer.py): an inactive slot
-    keeps both kinds of state."""
-    import jax.numpy as jnp
-    j = lay.state
-    mix, scan_state, tail = step_mixer(
-        w, 'layer_%d_' % lay.index, cfg, h[:, 0], st['ssm'], j,
-        st['conv'][:, j], at.active, kernels.state)
-    st = dict(st, ssm=scan_state,
+    st = dict(st, ssm=state,
               conv=st['conv'].at[:, j].set(jnp.where(
-                  at.active[:, None, None], tail, st['conv'][:, j])))
-    return mix[:, None] * cfg['multipliers']['ssm_out'], st
+                  active[:, None, None], tail, st['conv'][:, j])))
+    return out[:, None] * cfg['multipliers']['ssm_out'], st
 
 
+# the runtime's entry (mixer.py)
 MIXER = Mixer(
     weight_shapes=lambda cfg: weight_shapes(int(cfg['d_model']), cfg['ssm']),
     recurrent=lambda cfg: state_shapes(cfg['ssm']),
     kernels=lambda cfg, cache, chunk, mesh: {'state': ssm_step_eligible(
         cache.recurrent_shapes()['ssm'], 'float32', mesh)},
-    narrow=(_prefill_layer, _step_layer))
+    narrow=(prefill_mixer, step_mixer))

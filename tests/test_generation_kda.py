@@ -413,7 +413,7 @@ def test_the_composed_step_gives_the_kernels_tokens_and_state(weights, rt):
     want_toks, want_state, want_tails = run(rt)
     composed = DecodeRuntime(weights, CFG, slots=3, prefill_chunk=CHUNK,
                              page_len=PAGE)
-    composed.state_kernel = False
+    composed.kernels = composed.kernels._replace(state=False)
     before = dict(obs.counters())
     toks, state, tails = run(composed)
     c = {k: v - before.get(k, 0) for k, v in obs.counters().items()}

@@ -125,7 +125,7 @@ def test_a_chunk_visits_its_context_block_by_block(weights, reference,
     small = DecodeRuntime(weights, CFG, slots=2, prefill_chunk=CHUNK,
                           page_len=PAGE)
     assert small.prefill_kernel
-    small.prefill_kernel = kernel
+    small.kernels = small.kernels._replace(prefill=kernel)
     before = dict(obs.counters())
     context, got = _through_the_pool(small, _prompt(37, 12))
     assert _rel(got, reference.last_logits(small.w, CFG, context)) < 2e-4
@@ -670,7 +670,8 @@ def test_the_composed_route_gives_the_same_tokens(weights, rt):
     want = rt.generate(_prompt(10, 11), 6, steps_per_window=WINDOW)
     plain = DecodeRuntime(weights, CFG, slots=3, prefill_chunk=CHUNK,
                           page_len=PAGE)
-    plain.paged, plain._gathered = False, 3 * CFG['max_len']
+    plain.kernels = plain.kernels._replace(paged=False)
+    plain._gathered = 3 * CFG['max_len']
     before = dict(obs.counters())
     assert plain.generate(_prompt(10, 11), 6, steps_per_window=WINDOW) == want
     c = {k: v - before.get(k, 0) for k, v in obs.counters().items()}
@@ -766,7 +767,7 @@ def test_the_composed_chunk_gives_the_same_tokens(weights, rt):
     want = rt.generate(_prompt(21, 4), 6, steps_per_window=WINDOW)
     plain = DecodeRuntime(weights, CFG, slots=3, prefill_chunk=CHUNK,
                           page_len=PAGE)
-    plain.prefill_kernel = False
+    plain.kernels = plain.kernels._replace(prefill=False)
     before = dict(obs.counters())
     assert plain.generate(_prompt(21, 4), 6, steps_per_window=WINDOW) == want
     c = {k: v - before.get(k, 0) for k, v in obs.counters().items()}
