@@ -1,11 +1,13 @@
 """D002/D003/D004: shape/dtype abstract interpretation.
 
 Propagates jax.ShapeDtypeStruct through every registered op with
-`jax.eval_shape` (the same machinery framework.Block._infer_shapes uses
-at build time), but over the WHOLE program at once — so it also covers
-ops appended with infer_shape=False (optimizer updates, detection
-heads), programs loaded from disk via io.desc_to_program (which never
-ran build-time inference), and hand-edited descs.
+`core.infer_memo.abstract_eval` (the entry point
+framework.Block._infer_shapes uses at build time: a signature the build
+evaluated is answered from the memo), but over the WHOLE program at
+once — so it also covers ops appended with infer_shape=False (optimizer
+updates, detection heads), programs loaded from disk via
+io.desc_to_program (which never ran build-time inference), and
+hand-edited descs.
 
 Like build-time inference, the batch dim stays symbolic: every -1 dim is
 probed with two trial sizes (7 and 11) and output dims that differ
@@ -22,28 +24,17 @@ is exactly the set that would fail identically mid-trace.
 """
 import numpy as np
 
-from ...core import registry
+from ...core import infer_memo, registry
 from ...core.dtypes import convert_dtype, jax_dtype
 from ..engine import register_pass
 
 __all__ = ['run']
 
-_PROBE_B1, _PROBE_B2 = 7, 11
+_PROBE_B1, _PROBE_B2 = infer_memo.PROBE_BATCHES
 
 # executor-native op types: lowered by core/control_flow_exec.py /
 # the __backward__ vjp path, not through the registry
 _BACKWARD_OP = '__backward__'
-
-# registered ops whose output extents are data-dependent (selected boxes,
-# decoded paths, ...): build-time inference is skipped for them
-# (infer_shape=False call sites), so the linter must not re-derive and
-# compare shapes either — outputs become unknown
-_DATA_DEPENDENT = {
-    'multiclass_nms', 'generate_proposals', 'generate_proposal_labels',
-    'generate_mask_labels', 'rpn_target_assign', 'bipartite_match',
-    'beam_search', 'beam_search_decode', 'ctc_align', 'edit_distance',
-    'detection_map', 'py_func',
-}
 
 _UNKNOWN = object()
 
@@ -172,7 +163,6 @@ class _AbstractInterp(object):
 
     # -------------------------------------------------- the block walk
     def walk_block(self, block, env):
-        import jax
         program = self.ctx.program
         for i, op in enumerate(block.ops):
             sub = op.attrs.get('sub_block')
@@ -202,24 +192,18 @@ class _AbstractInterp(object):
                 self._mark_outputs_unknown(op, env)
                 continue
             self._check_64bit_attrs(op, i, block)
-            if op.type in _DATA_DEPENDENT:
+            if op.type in infer_memo.DATA_DEPENDENT:
+                # output extents depend on the data: nothing to re-derive
                 self._mark_outputs_unknown(op, env)
                 continue
-            impl = registry.get_op(op.type).impl
-            results = []
-            err = None
-            for B in (_PROBE_B1, _PROBE_B2):
-                ins = self._inputs_for(op, env, B, block)
-                if ins is None:
-                    results = None
-                    break
-                ictx = registry.InferCtx(op)
+            probes = [self._inputs_for(op, env, B, block)
+                      for B in (_PROBE_B1, _PROBE_B2)]
+            results = err = None
+            if None not in probes:
                 try:
-                    results.append(jax.eval_shape(
-                        lambda kw: impl(ictx, kw, op.attrs), ins))
+                    results = infer_memo.abstract_eval(op, probes)
                 except Exception as e:  # noqa: BLE001 - reported as D003
                     err = e
-                    break
             if err is not None:
                 in_vars = ', '.join(op.input_names()) or '<none>'
                 self.diags.append(self.ctx.diag(

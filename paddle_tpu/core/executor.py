@@ -17,6 +17,7 @@ from . import registry
 from . import async_runtime as _async
 from . import compile_cache as _cc
 from . import emit as _emit
+from . import infer_memo as _infer_memo
 from . import passes as _passes
 from .framework import Variable, default_main_program, TPUPlace
 from .. import observability as _obs
@@ -1185,11 +1186,14 @@ class Executor(object):
         # PT_LINT gate on the RAW program, BEFORE the rewriter: a user's
         # def-use/shape bug must be named here, not DCE'd out of sight
         with _obs.span('executor.lint', cat='compile',
-                       counter='executor.lint_s'):
+                       counter='executor.lint_s') as sp:
             from ..analysis import apply_lint_policy, lint_mode
+            memo0 = _infer_memo.counts()
             apply_lint_policy(program, feed_names=feed_names,
                               fetch_names=fetch_names, mode=lint_mode(),
                               header='program lint failed before lowering')
+            if obs_on:
+                sp.args.update(_infer_memo.span_args(memo0))
         # Program->Program rewriter (core/passes): the tracer sees the
         # optimized twin; every cache key/RNG stream stays keyed on the
         # RAW program (PT_OPT toggling is part of the hot key + launch

@@ -15,6 +15,7 @@ import numpy as np
 
 from . import unique_name
 from .dtypes import convert_dtype, dtype_str
+from . import infer_memo
 from . import registry
 from .. import observability as _obs
 
@@ -633,9 +634,6 @@ class Operator(object):
 
 # ---------------------------------------------------------------- Block
 
-_INFER_B1, _INFER_B2 = 7, 11
-
-
 class Block(object):
     def __init__(self, program, idx, parent_idx=-1):
         self.program = program
@@ -730,40 +728,33 @@ class Block(object):
         return op
 
     def _infer_shapes(self, op):
-        """Dual-batch abstract eval: run the op's JAX impl under
-        jax.eval_shape with batch placeholder 7 and again with 11; output
-        dims that differ between the two runs are batch dims (-1)."""
+        """Dual-batch abstract eval: the op's JAX impl evaluated abstractly
+        (core/infer_memo.py: once a signature in a process) with batch
+        placeholder 7 and again with 11; output dims that differ between
+        the two runs are batch dims (-1)."""
         import jax
 
-        impl = registry.get_op(op.type).impl
-        results = []
-        for B in (_INFER_B1, _INFER_B2):
+        probes = []
+        for B in infer_memo.PROBE_BATCHES:
             ins = {}
-            ok = True
             for slot, names in op.inputs.items():
                 structs = []
                 for n in names:
                     v = self._find_var_recursive(n)
                     if v is None or v.shape is None:
-                        ok = False
-                        break
+                        return  # cannot infer (shapeless input): as-is
                     shape = tuple(B if d in (-1, None) else int(d)
                                   for d in v.shape)
                     structs.append(
                         jax.ShapeDtypeStruct(shape, v.np_dtype))
-                if not ok:
-                    break
                 ins[slot] = structs if op.input_is_list[slot] else structs[0]
-            if not ok:
-                return  # cannot infer (e.g. shapeless input); leave as-is
-            ctx = registry.InferCtx(op)
-            try:
-                out = jax.eval_shape(lambda kw: impl(ctx, kw, op.attrs), ins)
-            except Exception as e:
-                raise RuntimeError(
-                    "shape inference failed for op %s: %s\n%s" %
-                    (op.type, e, op.to_string()))
-            results.append(out)
+            probes.append(ins)
+        try:
+            results = infer_memo.abstract_eval(op, probes)
+        except Exception as e:
+            raise RuntimeError(
+                "shape inference failed for op %s: %s\n%s" %
+                (op.type, e, op.to_string()))
         r1, r2 = results
         for slot, names in op.outputs.items():
             o1 = r1.get(slot) if isinstance(r1, dict) else None
@@ -1081,6 +1072,7 @@ def program_guard(main_program, startup_program=None):
         old_start = switch_startup_program(startup_program)
     with (_obs.span('program.build', cat='build', counter='program.build_s')
           if outermost else contextlib.nullcontext()) as build:
+        memo0 = infer_memo.counts()
         try:
             yield
         finally:
@@ -1090,4 +1082,5 @@ def program_guard(main_program, startup_program=None):
                 switch_startup_program(old_start)
             if outermost and _obs.enabled():
                 block = main_program.global_block()
-                build.args.update(ops=len(block.ops), vars=len(block.vars))
+                build.args.update(infer_memo.span_args(memo0),
+                                  ops=len(block.ops), vars=len(block.vars))

@@ -320,10 +320,15 @@ def test_program_build_counts_the_outermost_guard_once():
             fluid.optimizer.SGD(0.1).minimize(loss)
     build, = [e for e in _spans() if e['name'] == 'program.build']
     block = main.global_block()
-    assert build['args'] == {'ops': len(block.ops), 'vars': len(block.vars)}
+    c = _delta(obs.counters(), before)
+    # its own delta of the memo of abstract evaluation (PR 67)
+    assert build['args'] == {
+        'ops': len(block.ops), 'vars': len(block.vars),
+        'infer_hits': c.get('infer.memo_hits', 0),
+        'infer_misses': c.get('infer.memo_misses', 0)}
+    assert build['args']['infer_hits'] + build['args']['infer_misses'] > 0
     # append_backward and minimize ran inside it
     assert any(op.type == 'sgd' for op in block.ops)
-    c = _delta(obs.counters(), before)
     assert c['program.build_s'] == pytest.approx(build['dur'] / 1e6)
     # a second outermost guard is a second build
     with fluid.program_guard(main, startup):
